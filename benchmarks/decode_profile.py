@@ -34,7 +34,7 @@ sys.path.insert(0, os.path.abspath(
     os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")))
 
 from benchmarks._profile_common import (  # noqa: E402
-    HBM_GBS,
+    hbm_bytes_per_s,
     build_engine,
     install_params_holder,
     params_bytes,
@@ -287,13 +287,17 @@ def main() -> None:
     pbytes = params_bytes(core_params_holder[0])
     kv_bytes_step = (CTX * B * mc.num_kv_heads * mc.head_dim * 2 * 2
                      * mc.num_layers)
-    floors = {
-        "weights_read_per_burst_s": round(K * pbytes / HBM_GBS, 4),
-        "kv_read_per_burst_s": round(K * kv_bytes_step / HBM_GBS, 4),
-    }
-    floors["combined_floor_s"] = round(
-        floors["weights_read_per_burst_s"] + floors["kv_read_per_burst_s"],
-        4)
+    peak = hbm_bytes_per_s()
+    floors = gap = None  # no device peak (the CPU): no floor to compare
+    if peak is not None:
+        floors = {
+            "weights_read_per_burst_s": round(K * pbytes / peak, 4),
+            "kv_read_per_burst_s": round(K * kv_bytes_step / peak, 4),
+        }
+        floors["combined_floor_s"] = round(
+            floors["weights_read_per_burst_s"]
+            + floors["kv_read_per_burst_s"], 4)
+        gap = round(full / floors["combined_floor_s"], 2)
 
     out = {
         "metric": "decode_profile",
@@ -303,7 +307,7 @@ def main() -> None:
         **{k: round(v, 4) for k, v in results.items()},
         "components": comp,
         "floors": floors,
-        "gap_vs_combined_floor": round(full / floors["combined_floor_s"], 2),
+        "gap_vs_combined_floor": gap,
     }
     print(json.dumps(out))
 

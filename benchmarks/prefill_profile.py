@@ -47,7 +47,7 @@ sys.path.insert(0, os.path.abspath(
     os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")))
 
 from benchmarks._profile_common import (  # noqa: E402
-    HBM_GBS,
+    hbm_bytes_per_s,
     build_engine,
     install_params_holder,
     params_bytes,
@@ -236,7 +236,7 @@ def _kernel_ab_leg(core, chunk: int, rows: list, reps: int) -> dict:
     TPU_STACK_FORCE_XLA_ATTENTION override."""
     import numpy as np
 
-    from production_stack_tpu.ops.attention import prefill_attention_path
+    from production_stack_tpu.ops.attention import attention_path
 
     mc = core.model_config
     cfg = core.config
@@ -271,7 +271,7 @@ def _kernel_ab_leg(core, chunk: int, rows: list, reps: int) -> dict:
     drop = 1.0 - (read_flash / read_xla) if read_xla else 0.0
 
     leg = {
-        "path_configured": prefill_attention_path(
+        "path_configured": attention_path(
             cfg.block_size, mc.num_kv_heads, mc.head_dim, quantized),
         "interpret_parity": {
             "bf16_max_abs_err": round(_kernel_parity(False), 8),
@@ -472,11 +472,14 @@ def main(argv=None) -> None:
     kv_token_bytes = (mc.num_kv_heads * mc.head_dim * 2
                       * mc.num_layers
                       * (1 if core.config.kv_cache_dtype == "int8" else 2))
-    floors = {
-        "weights_read_per_chunk_s": round(pbytes / HBM_GBS, 6),
-        "kv_write_per_chunk_s": round(
-            args.chunk * kv_token_bytes / HBM_GBS, 6),
-    }
+    peak = hbm_bytes_per_s()
+    floors = None  # no device peak (the CPU): no floor to compare with
+    if peak is not None:
+        floors = {
+            "weights_read_per_chunk_s": round(pbytes / peak, 6),
+            "kv_write_per_chunk_s": round(
+                args.chunk * kv_token_bytes / peak, 6),
+        }
 
     out = {
         "metric": "prefill_profile",

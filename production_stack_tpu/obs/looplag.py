@@ -124,8 +124,9 @@ class LoopComponentTimers:
         """Context manager timing a synchronous on-loop section."""
         return _MeasureCtx(self, component)
 
-    def wrap(self, component: str, coro):
-        """Awaitable wrapper measuring ``coro``'s on-loop time.
+    async def wrap(self, component: str, coro):
+        """Coroutine measuring ``coro``'s on-loop time (a real coroutine,
+        not the bare generator below: ``create_task`` takes nothing else).
 
         Drives the coroutine resume-by-resume: each ``send``/``throw``
         runs synchronously on the event loop, so the sum of those
@@ -134,7 +135,7 @@ class LoopComponentTimers:
         counted. The total is recorded once, when the coroutine
         finishes, errors, or is cancelled.
         """
-        return _drive(coro, lambda s: self.add(component, s))
+        return await _drive(coro, lambda s: self.add(component, s))
 
 
 class _MeasureCtx:

@@ -39,19 +39,40 @@ from typing import Dict, List, Optional
 STEP_KINDS = ("prefill", "prefill_chunk", "decode_burst", "spec_verify",
               "fused")
 
-# Device HBM bandwidth floor (bytes/s) for the utilization gauge. The
-# default is the v5e figure used to derive the decode floors in
-# BENCH_DECODE_PROFILE_r05.json; override per deployment with
-# TPU_STACK_HBM_GBS (decimal bytes/s).
-DEFAULT_HBM_BYTES_PER_S = 819e9
+# Published peaks of one device, keyed by JAX's ``device_kind``: the one
+# table every roofline figure in the repo reads. Source: Google Cloud TPU
+# documentation, the per-generation system-architecture pages ("TPU v4",
+# "TPU v5e", "TPU v5p", "TPU v6e"). Decimal units.
+DEVICE_PEAKS = {
+    "TPU v4": {"hbm_bytes_per_s": 1228e9, "bf16_flops_per_s": 275e12},
+    "TPU v5 lite": {"hbm_bytes_per_s": 819e9, "bf16_flops_per_s": 197e12},
+    "TPU v5e": {"hbm_bytes_per_s": 819e9, "bf16_flops_per_s": 197e12},
+    "TPU v5p": {"hbm_bytes_per_s": 2765e9, "bf16_flops_per_s": 459e12},
+    "TPU v5": {"hbm_bytes_per_s": 2765e9, "bf16_flops_per_s": 459e12},
+    "TPU v6 lite": {"hbm_bytes_per_s": 1640e9, "bf16_flops_per_s": 918e12},
+    "TPU v6e": {"hbm_bytes_per_s": 1640e9, "bf16_flops_per_s": 918e12},
+}
 
 
-def device_hbm_bytes_per_s() -> float:
+def device_hbm_bytes_per_s(device=None) -> Optional[float]:
+    """Peak HBM bytes/s of ``device`` (a ``jax.Device``) for the
+    utilization gauge, or None where there is no peak to compare with
+    (no device given, or not a TPU): the utilization is then absent, not
+    computed against another chip's figure. ``TPU_STACK_HBM_GBS``
+    (decimal bytes/s) overrides the table for a deployment. A TPU whose
+    kind is not in the table is an error, not a default."""
+    override = os.environ.get("TPU_STACK_HBM_GBS", "")
+    if override:
+        return float(override)
+    if device is None or device.platform != "tpu":
+        return None
     try:
-        return float(os.environ.get("TPU_STACK_HBM_GBS", "") or
-                     DEFAULT_HBM_BYTES_PER_S)
-    except ValueError:
-        return DEFAULT_HBM_BYTES_PER_S
+        return DEVICE_PEAKS[device.device_kind]["hbm_bytes_per_s"]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for TPU device_kind "
+            f"{device.device_kind!r}: add it to obs/steps.py::DEVICE_PEAKS "
+            f"with its source") from None
 
 
 class StepRecorder:
@@ -75,9 +96,9 @@ class StepRecorder:
         # lazily before the first record.
         self.param_bytes = int(param_bytes)
         self.kv_token_bytes = int(kv_token_bytes)
-        self.hbm_bytes_per_s = float(
-            hbm_bytes_per_s if hbm_bytes_per_s is not None
-            else device_hbm_bytes_per_s())
+        # None: no peak is known for this device (the CPU), and the
+        # utilization is absent.
+        self.hbm_bytes_per_s = hbm_bytes_per_s
         self.window_s = float(window_s)
         self._ring: deque = deque(maxlen=self.capacity)
         self._lock = threading.Lock()
@@ -154,11 +175,15 @@ class StepRecorder:
                 for k, v in self._kinds.items()
             }
 
-    def bandwidth_utilization(self, now: Optional[float] = None) -> float:
+    def bandwidth_utilization(
+            self, now: Optional[float] = None) -> Optional[float]:
         """Achieved HBM bytes/s over the recent step window divided by the
         device floor: estimated bytes moved by steps that STARTED inside
         the window, over their summed wall time (model-active seconds, not
-        wall-clock — idle gaps between steps are not a bandwidth claim)."""
+        wall-clock — idle gaps between steps are not a bandwidth claim).
+        None where the device has no published peak."""
+        if self.hbm_bytes_per_s is None:
+            return None
         if now is None:
             now = time.time()
         cutoff = now - self.window_s
@@ -182,6 +207,6 @@ class StepRecorder:
             "kv_token_bytes": self.kv_token_bytes,
             "hbm_bytes_per_s": self.hbm_bytes_per_s,
             "window_s": self.window_s,
-            "bandwidth_utilization": round(self.bandwidth_utilization(), 6),
+            "bandwidth_utilization": self.bandwidth_utilization(),
             "kinds": self.kind_stats(),
         }

@@ -15,11 +15,14 @@ All softmax accumulation is float32 regardless of compute dtype.
 
 from __future__ import annotations
 
-import functools
+import collections
+import contextlib
+import contextvars
 import os
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 NEG_INF = -1e30
 
@@ -57,39 +60,105 @@ def quantize_kv(x: jax.Array):
 def _use_pallas() -> bool:
     if os.environ.get("TPU_STACK_FORCE_XLA_ATTENTION"):
         return False
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # noqa: BLE001
-        return False
+    return jax.devices()[0].platform == "tpu"
 
 
-def _page_tile_ok(block_size: int, kvh: int, head_dim: int,
-                  quantized: bool) -> bool:
+def _page_tile_ok(block_size: int, kvh: int, head_dim: int) -> bool:
     """Trace-time tile-alignment gate shared by the paged kernels. The
     manual page DMAs slice [bs, KVH, D] out of HBM: Mosaic requires the
     sliced dims tile-aligned (KVH to the 8-row sublane, D to the 128
-    lanes, bs to 8); the int8 kernels additionally DMA per-page scale
-    rows [bs*KVH], whose last dim must fill whole 128-lane tiles.
-    Misaligned models (e.g. OPT: 12 kv-heads, head_dim 64) take the XLA
-    reference — and this MUST be decided at trace time: a Mosaic
-    failure surfaces at AOT compile where no fallback is possible."""
-    ok = block_size % 8 == 0 and kvh % 8 == 0 and head_dim % 128 == 0
-    if quantized:
-        ok = ok and (block_size * kvh) % 128 == 0
-    return ok
+    lanes, bs to 8). Misaligned models (e.g. OPT: 12 kv-heads, head_dim
+    64) take the XLA reference — and this MUST be decided at trace
+    time: a Mosaic failure surfaces when the enclosing jit compiles,
+    where no fallback is possible."""
+    return block_size % 8 == 0 and kvh % 8 == 0 and head_dim % 128 == 0
 
 
-def prefill_attention_path(block_size: int, kvh: int, head_dim: int,
-                           quantized: bool) -> str:
-    """Which backend a cached-prefill dispatch with these (static) page
-    shapes will take: ``"pallas"`` or ``"xla"``. Evaluates the same
-    trace-time predicate as the dispatcher plus the runtime platform/env
-    gate — the engine calls this per dispatch to label
-    ``tpu:prefill_attention_dispatch_total`` (the env override can flip
-    between steps)."""
-    if _page_tile_ok(block_size, kvh, head_dim, quantized) and _use_pallas():
+# (mesh, axis) while a model whose KV pool is sharded over kv heads is
+# being traced; set by the engine around its forward (kv_head_sharding).
+_KV_SHARD: contextvars.ContextVar = contextvars.ContextVar(
+    "kv_head_sharding", default=None)
+
+# Trace-time dispatch decisions, keyed (op, path): what each compiled
+# program will run. Counts traces, not calls.
+TRACED_PATHS: collections.Counter = collections.Counter()
+
+
+@contextlib.contextmanager
+def kv_head_sharding(mesh, axis: str):
+    """Tell the attention dispatchers, for the traces made inside, that
+    the KV pool is sharded over its kv-head axis on ``mesh``'s ``axis``.
+    The compiler cannot partition a pallas_call (under a sharded jit it
+    refuses: "Mosaic kernels cannot be automatically partitioned"), so
+    the kernels then run per shard inside an explicit shard_map."""
+    token = _KV_SHARD.set((mesh, axis))
+    try:
+        yield
+    finally:
+        _KV_SHARD.reset(token)
+
+
+def shard_paged_kernels(apply, pages_sharding):
+    """``(apply, kv_shards)`` for a model whose pool has this sharding:
+    where its kv-head axis is sharded over ``tp``, ``apply`` is wrapped
+    so its traces run under :func:`kv_head_sharding`."""
+    if "tp" not in pages_sharding.spec or "pp" in pages_sharding.spec:
+        return apply, 1
+    mesh = pages_sharding.mesh
+
+    def sharded_apply(*args, **kwargs):
+        with kv_head_sharding(mesh, "tp"):
+            return apply(*args, **kwargs)
+
+    return sharded_apply, mesh.shape["tp"]
+
+
+def attention_path(block_size: int, kvh: int, head_dim: int,
+                   quantized: bool, kv_shards: int = 1) -> str:
+    """Which backend a paged-attention dispatch with these (static) page
+    shapes takes: ``"pallas"`` or ``"xla"``. THE decision — both
+    dispatchers and the engine's dispatch counter evaluate it, from
+    shapes, platform and the env override only. ``kv_shards`` is the
+    number of ways the pool's kv-head axis is sharded: the kernel then
+    sees ``kvh / kv_shards`` heads per chip. A sharded int8 pool takes
+    the reference: its token-major scales are replicated and interleave
+    the heads, so a shard cannot address its own."""
+    if kv_shards > 1 and quantized:
+        return "xla"
+    if (_page_tile_ok(block_size, kvh // kv_shards, head_dim)
+            and _use_pallas()):
         return "pallas"
     return "xla"
+
+
+def _traced_path(op: str, k_pages) -> str:
+    """The dispatch decision for the trace in progress, counted."""
+    _, _, bs, kvh, head_dim = kv_page_data(k_pages).shape
+    shard = _KV_SHARD.get()
+    path = attention_path(
+        bs, kvh, head_dim, isinstance(k_pages, tuple),
+        shard[0].shape[shard[1]] if shard else 1)
+    TRACED_PATHS[op, path] += 1
+    return path
+
+
+def _per_kv_shard(kernel, head_ranks, n_replicated: int):
+    """``kernel(*head_operands, k_pages, v_pages, *replicated)`` as it is,
+    or — while a pool sharded over kv heads is traced — run per shard
+    inside a ``shard_map``. Head operands (q, a chunk's fresh K/V; their
+    ranks in ``head_ranks``) and the output (shaped like the first) carry
+    heads on their second-to-last axis, kv-head-major, so one split
+    serves query and kv heads alike; pages carry them on axis 3."""
+    shard = _KV_SHARD.get()
+    if shard is None:
+        return kernel
+    mesh, axis = shard
+    heads = [P(*[None] * (rank - 2), axis, None) for rank in head_ranks]
+    pages = P(None, None, None, axis, None)
+    return jax.shard_map(
+        kernel, mesh=mesh,
+        in_specs=(*heads, pages, pages) + (P(),) * n_replicated,
+        out_specs=heads[0], check_vma=False)
 
 
 def prefill_attention(
@@ -179,24 +248,24 @@ def context_prefill_attention(
     ``total_lens = positions[:, 0] + suffix_lens`` for live rows.
     Elsewhere (misaligned shapes, CPU, fresh values not provided) the
     XLA gather reference below runs — identical math, so the dispatch
-    choice never changes results beyond accumulation order."""
-    if k_new is not None and v_new is not None and suffix_lens is not None:
-        k_data = kv_page_data(k_pages)
-        if (_page_tile_ok(k_data.shape[2], k_data.shape[3], k_data.shape[4],
-                          isinstance(k_pages, tuple))
-                and _use_pallas()):
-            from production_stack_tpu.ops.pallas_prefill_attention import (
-                pallas_prefill_attention,
-            )
+    choice never changes results beyond accumulation order. The choice
+    is made once, at trace time (:func:`attention_path`); a kernel that
+    then fails to compile or run raises."""
+    if (k_new is not None and v_new is not None and suffix_lens is not None
+            and _traced_path("prefill", k_pages) == "pallas"):
+        from production_stack_tpu.ops.pallas_prefill_attention import (
+            pallas_prefill_attention,
+        )
 
-            try:
-                return pallas_prefill_attention(
-                    q, k_pages, v_pages, block_tables, positions,
-                    total_lens, layer, k_new, v_new, suffix_lens,
-                    scale=scale,
-                )
-            except Exception:  # noqa: BLE001 - fall back, don't fail serving
-                pass
+        def kernel(q, k_new, v_new, k_pages, v_pages, block_tables,
+                   positions, total_lens, layer, suffix_lens):
+            return pallas_prefill_attention(
+                q, k_pages, v_pages, block_tables, positions, total_lens,
+                layer, k_new, v_new, suffix_lens, scale=scale)
+
+        return _per_kv_shard(kernel, (4, 4, 4), 5)(
+            q, k_new, v_new, k_pages, v_pages, block_tables, positions,
+            total_lens, layer, suffix_lens)
     return _context_prefill_reference(
         q, k_pages, v_pages, block_tables, positions, total_lens, layer,
         scale=scale,
@@ -371,23 +440,18 @@ def paged_decode_attention(
     scale: float,
 ) -> jax.Array:
     """Dispatch to the pallas kernel on TPU, XLA reference elsewhere."""
-    k_data = kv_page_data(k_pages)
-    block_size = k_data.shape[2]
-    kvh, head_dim = k_data.shape[3], k_data.shape[4]
-    tile_ok = _page_tile_ok(block_size, kvh, head_dim,
-                            isinstance(k_pages, tuple))
-    if tile_ok and _use_pallas():
+    if _traced_path("decode", k_pages) == "pallas":
         from production_stack_tpu.ops.pallas_paged_attention import (
             pallas_paged_attention,
         )
 
-        try:
+        def kernel(q, k_pages, v_pages, block_tables, context_lens, layer):
             return pallas_paged_attention(
                 q, k_pages, v_pages, block_tables, context_lens, layer,
-                scale=scale,
-            )
-        except Exception:  # noqa: BLE001 - fall back rather than fail serving
-            pass
+                scale=scale)
+
+        return _per_kv_shard(kernel, (3,), 3)(
+            q, k_pages, v_pages, block_tables, context_lens, layer)
     return paged_attention_reference(
         q, k_pages, v_pages, block_tables, context_lens, layer, scale=scale
     )

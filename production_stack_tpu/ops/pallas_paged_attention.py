@@ -46,21 +46,81 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
-# DMA ring depth: chunks prefetched ahead of compute. The round-5 sweep
-# measured depth 6 (with the default 8-page chunks: ~12 MB of the
-# ~16 MB VMEM) fastest — deep enough to cover DMA issue->complete
+# Deepest DMA ring (chunks prefetched ahead of compute) and widest chunk
+# the tile chooser will pick. The round-5 sweep measured depth 6 with
+# 8-page chunks fastest — deep enough to cover DMA issue->complete
 # latency across sequence boundaries.
 RING = 6
+MAX_PAGES_PER_BLOCK = 8
+
+# What one kernel may plan to keep in VMEM. Mosaic's scoped limit on the
+# v5e is 16 MiB and is enforced when the ENCLOSING jit compiles, where
+# no fallback is possible, so tiles are sized from the shapes up front.
+# The slack under the limit is the compiler's own (spills, relayouts),
+# which the estimates below cannot see; tests/test_chip_compile.py holds
+# the estimates to the real compiler at the serving shapes.
+VMEM_BUDGET = 13 << 20
+
+
+def ring_bytes(ring: int, pages: int, block_size: int, kvh: int,
+               head_dim: int, itemsize: int) -> int:
+    """VMEM bytes of the K and V page rings [ring, pages, bs, KVH, D]."""
+    return 2 * ring * pages * block_size * kvh * head_dim * itemsize
+
+
+def choose_tile(fits, tables_width: int, block_size: int,
+                ring_floor: int = 3):
+    """(pages_per_block, ring) for a paged kernel: the widest chunk and
+    then the deepest ring that ``fits(pages, ring)``. A chunk spans
+    whole 128-lane tiles of tokens (the scores' and the int8 scale
+    rows' last dim) and no more pages than the table, rounded up to a
+    power of two. Rings shallower than ``ring_floor`` are tried only
+    after every chunk width failed at the floor. Returns None when
+    nothing fits."""
+    cap = 1
+    while cap < min(tables_width, MAX_PAGES_PER_BLOCK):
+        cap *= 2
+    widths = [p for p in (8, 4, 2, 1)
+              if p <= cap and (p * block_size) % 128 == 0]
+    widths = widths or [-(-128 // block_size)]
+    for rings in (range(RING, ring_floor - 1, -1),
+                  range(ring_floor - 1, 1, -1)):
+        for p in widths:
+            for r in rings:
+                if fits(p, r):
+                    return p, r
+    return None
+
+
+def pad_tables(block_tables: jax.Array, pages_per_block: int) -> jax.Array:
+    """Pad the table width to a multiple of the chunk width (page 0; a
+    padded entry lies past every context length, so it is never copied
+    or attended)."""
+    pad = -block_tables.shape[1] % pages_per_block
+    if pad:
+        block_tables = jnp.pad(block_tables, ((0, 0), (0, pad)))
+    return block_tables.astype(jnp.int32)
+
+
+def gather_scale_rows(scales: jax.Array, block_tables: jax.Array, layer,
+                      block_size: int, kvh: int) -> jax.Array:
+    """Per-sequence int8 scales as head-major lane rows [B, KVH, S].
+
+    The pool keeps scales token-major ([L, NB, bs*KVH], the layout the
+    page scatter writes); the kernels need one lane vector per kv head.
+    Mosaic cannot make that lane->sublane move in VMEM (it refuses the
+    shape cast), so the table's scale rows — ~3% of the int8 bytes they
+    describe — are gathered and transposed here, in XLA, and reach the
+    kernel as an ordinary pipelined block."""
+    L, NB, _ = scales.shape
+    B, MAXB = block_tables.shape
+    rows = scales.reshape(L * NB, block_size, kvh)[layer * NB + block_tables]
+    return rows.transpose(0, 3, 1, 2).reshape(B, kvh, MAXB * block_size)
 
 
 def _chunk_copies(k_hbm, v_hbm, k_buf, v_buf, sems, bt_ref, layer,
-                  b, chunk, slot, pages_per_block,
-                  ks_hbm=None, vs_hbm=None, ks_buf=None, vs_buf=None):
-    """Async-copy descriptors for one chunk's pages into ring slot `slot`.
-
-    With a quantized cache two extra per-page copies move the f32 scale
-    rows ([bs*KVH] each — ~3% of the bf16 page bytes they replace) on
-    semaphore lanes 2/3."""
+                  b, chunk, slot, pages_per_block):
+    """Async-copy descriptors for one chunk's pages into ring slot `slot`."""
     copies = []
     for p in range(pages_per_block):
         page = bt_ref[b, chunk * pages_per_block + p]
@@ -68,13 +128,6 @@ def _chunk_copies(k_hbm, v_hbm, k_buf, v_buf, sems, bt_ref, layer,
             k_hbm.at[layer, page], k_buf.at[slot, p], sems.at[slot, 0, p]))
         copies.append(pltpu.make_async_copy(
             v_hbm.at[layer, page], v_buf.at[slot, p], sems.at[slot, 1, p]))
-        if ks_hbm is not None:
-            copies.append(pltpu.make_async_copy(
-                ks_hbm.at[layer, page], ks_buf.at[slot, p],
-                sems.at[slot, 2, p]))
-            copies.append(pltpu.make_async_copy(
-                vs_hbm.at[layer, page], vs_buf.at[slot, p],
-                sems.at[slot, 3, p]))
     return copies
 
 
@@ -97,12 +150,11 @@ def _decode_kernel(
     q_ref,  # [1, KVH * g_pad, D] (VMEM block for sequence b; pre-scaled)
     k_hbm_ref,  # [L, NB, bs, KVH, D] in ANY/HBM (int8 when quantized)
     v_hbm_ref,
-    # quantized only: ks_hbm_ref / vs_hbm_ref [L, NB, bs*KVH] f32 in ANY,
-    # then output o_ref [1, KVH*g_pad, D], then scratch: k_buf/v_buf
-    # VMEM [RING, P, bs, KVH, D], (quantized: ks_buf/vs_buf VMEM
-    # [RING, P, bs*KVH] f32,) sems DMA [RING, 2|4, P], s_ref
-    # [KVH*g_pad, span] f32, acc_ref [KVH*g_pad, D] f32, m_ref/l_ref
-    # [KVH*g_pad, 128] f32.
+    # quantized only: ks_ref / vs_ref [1, KVH, span] f32 VMEM blocks (this
+    # chunk's per-head scale rows), then output o_ref [1, KVH*g_pad, D],
+    # then scratch: k_buf/v_buf VMEM [RING, P, bs, KVH, D], sems DMA
+    # [RING, 2, P], s_ref [KVH*g_pad, span] f32, acc_ref [KVH*g_pad, D]
+    # f32, m_ref/l_ref [KVH*g_pad, 128] f32.
     *refs,
     block_size: int,
     kvh: int,
@@ -112,13 +164,8 @@ def _decode_kernel(
     quantized: bool,
 ):
     if quantized:
-        (ks_hbm_ref, vs_hbm_ref, o_ref, k_buf, v_buf, ks_buf, vs_buf,
-         sems, s_ref, acc_ref, m_ref, l_ref) = refs
-        scale_kwargs = dict(ks_hbm=ks_hbm_ref, vs_hbm=vs_hbm_ref,
-                            ks_buf=ks_buf, vs_buf=vs_buf)
-    else:
-        (o_ref, k_buf, v_buf, sems, s_ref, acc_ref, m_ref, l_ref) = refs
-        scale_kwargs = {}
+        ks_ref, vs_ref, *refs = refs
+    (o_ref, k_buf, v_buf, sems, s_ref, acc_ref, m_ref, l_ref) = refs
     b = pl.program_id(0)
     c = pl.program_id(1)
     nc = pl.num_programs(1)
@@ -144,8 +191,7 @@ def _decode_kernel(
             def _(gb=gb, gc=gc, k=k):
                 _start_chunk_copy(
                     k_hbm_ref, v_hbm_ref, k_buf, v_buf, sems,
-                    block_tables_ref, layer, gb, gc, k % ring, P,
-                    **scale_kwargs)
+                    block_tables_ref, layer, gb, gc, k % ring, P)
 
     @pl.when(c == 0)
     def _init():
@@ -165,36 +211,34 @@ def _decode_kernel(
     def _prefetch():
         _start_chunk_copy(k_hbm_ref, v_hbm_ref, k_buf, v_buf, sems,
                           block_tables_ref, layer, b_pre, c_pre,
-                          jax.lax.rem(g_pre, ring), P, **scale_kwargs)
+                          jax.lax.rem(g_pre, ring), P)
 
     @pl.when(chunk_start < ctx)
     def _compute():
         _wait_chunk_copy(k_hbm_ref, v_hbm_ref, k_buf, v_buf, sems,
-                         block_tables_ref, layer, b, c, slot, P,
-                         **scale_kwargs)
+                         block_tables_ref, layer, b, c, slot, P)
         # Per-head QK dots into ONE scores scratch, then every VPU stage
         # (mask, max, exp, l/acc updates) runs once over all heads' rows.
         # Operands are cast to f32 first — measured FASTER than feeding
         # bf16 straight to the MXU at these tiny tile shapes (ring sweep,
         # round 5: bf16 operands cost +66%; Mosaic's repacking of skinny
         # bf16 tiles outweighs the cast traffic).
-        if quantized:
-            # [P, bs*KVH] -> token-major [span, KVH]: row p*bs+t, col h.
-            k_sc = ks_buf[slot].reshape(span_tokens, kvh)
-            v_sc = vs_buf[slot].reshape(span_tokens, kvh)
         for h in range(kvh):  # static unroll over kv heads
             rows = slice(h * g_pad, (h + 1) * g_pad)
             q = q_ref[0, rows, :].astype(jnp.float32)  # [g_pad, D]
             k = (k_buf[slot, :, :, h, :]
                  .reshape(span_tokens, -1).astype(jnp.float32))
-            if quantized:
-                # Dequantize on-chip: the HBM stream stayed int8; the
-                # [span, 1] column broadcast is sublane-aligned.
-                k = k * k_sc[:, h:h + 1]
-            s_ref[rows, :] = jax.lax.dot_general(
+            s_h = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
+            if quantized:
+                # Dequantize on-chip: the HBM stream stayed int8. A
+                # token's scale is constant along D, so it factors out
+                # of the dot and multiplies the scores ([1, span] lane
+                # row, broadcast over the head's query rows).
+                s_h = s_h * ks_ref[0, h:h + 1, :]
+            s_ref[rows, :] = s_h
         span = chunk_start + jax.lax.broadcasted_iota(
             jnp.int32, (1, span_tokens), 1
         )
@@ -215,15 +259,35 @@ def _decode_kernel(
             rows = slice(h * g_pad, (h + 1) * g_pad)
             v = (v_buf[slot, :, :, h, :]
                  .reshape(span_tokens, -1).astype(jnp.float32))
+            p_h = p_[rows, :]
             if quantized:
-                v = v * v_sc[:, h:h + 1]
+                p_h = p_h * vs_ref[0, h:h + 1, :]
             acc_ref[rows, :] = acc_ref[rows, :] + jax.lax.dot(
-                p_[rows, :], v, preferred_element_type=jnp.float32)
+                p_h, v, preferred_element_type=jnp.float32)
 
     @pl.when(c == nc - 1)
     def _finalize():
         denom = jnp.maximum(l_ref[:, :1], 1e-30)
         o_ref[0] = (acc_ref[...] / denom).astype(o_ref.dtype)
+
+
+def decode_tile(block_size: int, kvh: int, head_dim: int, g_pad: int,
+                itemsize: int, tables_width: int, quantized: bool):
+    """(pages_per_block, ring) the decode kernel runs with at these
+    shapes, chosen to fit :data:`VMEM_BUDGET`; None when nothing fits."""
+    rows = kvh * g_pad
+
+    def fits(pages: int, ring: int) -> bool:
+        span = pages * block_size
+        total = ring_bytes(ring, pages, block_size, kvh, head_dim, itemsize)
+        total += 4 * rows * (span + head_dim + 256)  # s/acc/m/l scratch
+        total += 2 * 4 * rows * span  # masked scores and probabilities
+        total += 2 * 2 * rows * head_dim * 2  # q and o blocks, 2 buffers
+        if quantized:
+            total += 2 * 2 * 4 * max(kvh, 8) * span  # scale blocks
+        return total <= VMEM_BUDGET
+
+    return choose_tile(fits, tables_width, block_size)
 
 
 @functools.partial(
@@ -237,8 +301,8 @@ def pallas_paged_attention(
     layer,  # scalar layer index (traced)
     *,
     scale: float,
-    pages_per_block: int = 0,  # 0 -> min(8, MAXB)
-    ring: int = 0,  # DMA ring depth; 0 -> RING default
+    pages_per_block: int = 0,  # 0 -> from the VMEM budget (decode_tile)
+    ring: int = 0,  # DMA ring depth; 0 -> from the VMEM budget
     interpret: bool = False,
 ) -> jax.Array:
     quantized = isinstance(k_pages, tuple)
@@ -247,29 +311,27 @@ def pallas_paged_attention(
         v_pages, v_scales = v_pages
     B, H, D = q.shape
     L, NB, bs, KVH, _ = k_pages.shape
-    MAXB = block_tables.shape[1]
     group = H // KVH
-    if pages_per_block:
-        P = pages_per_block
-    else:
-        # Largest chunk width <= 8 that divides the table width (the
-        # engine's buckets are powers of two, but the TOP bucket is
-        # clamped at max_blocks_per_seq, which need not be — P=1 then
-        # degrades gracefully instead of asserting into the XLA
-        # fallback).
-        P = next(p for p in (8, 4, 2, 1) if MAXB % p == 0)
-    if MAXB % P != 0:
-        raise ValueError(
-            f"pages_per_block {P} does not divide table width {MAXB}")
-    nc = MAXB // P
     # Pad each query-head group to the float32 sublane tile (8 rows).
     g_pad = max(group, 8)
+    tile = decode_tile(bs, KVH, D, g_pad, k_pages.dtype.itemsize,
+                       block_tables.shape[1], quantized)
+    if tile is None and not (pages_per_block and ring):
+        raise ValueError(
+            f"no decode tile fits VMEM at block_size={bs} kv_heads={KVH} "
+            f"head_dim={D}")
+    P = pages_per_block or tile[0]
+    R = ring or tile[1]
+    # The table is padded to whole chunks, so the chunk width need not
+    # divide the engine's table bucket (its top bucket is clamped at
+    # max_blocks_per_seq, which need not be a power of two).
+    block_tables = pad_tables(block_tables, P)
+    nc = block_tables.shape[1] // P
     qg = (q * scale).astype(q.dtype).reshape(B, KVH, group, D)
     if g_pad != group:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, g_pad - group), (0, 0)))
     qg = qg.reshape(B, KVH * g_pad, D)
 
-    R = ring or RING
     kernel = functools.partial(
         _decode_kernel, block_size=bs, kvh=KVH, g_pad=g_pad,
         pages_per_block=P, ring=R, quantized=quantized,
@@ -282,21 +344,23 @@ def pallas_paged_attention(
         pl.BlockSpec(memory_space=pl.ANY),
         pl.BlockSpec(memory_space=pl.ANY),
     ]
+    operands = [qg, k_pages, v_pages]
+    if quantized:
+        # This chunk's scale rows [KVH, span] per side. Past a
+        # sequence's last live chunk the block index stops moving, so
+        # dead grid steps fetch nothing.
+        def scale_block(b, c, bt, cl, lr):
+            last = jnp.maximum(cl[b] - 1, 0) // (P * bs)
+            return (b, 0, jnp.minimum(c, last))
+
+        in_specs += [pl.BlockSpec((1, KVH, P * bs), scale_block)] * 2
+        operands += [
+            gather_scale_rows(k_scales, block_tables, layer, bs, KVH),
+            gather_scale_rows(v_scales, block_tables, layer, bs, KVH)]
     scratch_shapes = [
         pltpu.VMEM((R, P, bs, KVH, D), k_pages.dtype),
         pltpu.VMEM((R, P, bs, KVH, D), v_pages.dtype),
-    ]
-    operands = [qg, k_pages, v_pages]
-    if quantized:
-        # Scale arrays ride two extra DMA lanes; their ring scratch is
-        # [R, P, bs*KVH] f32 (a page's scale row is one 1D copy).
-        in_specs += [pl.BlockSpec(memory_space=pl.ANY),
-                     pl.BlockSpec(memory_space=pl.ANY)]
-        scratch_shapes += [pltpu.VMEM((R, P, bs * KVH), jnp.float32),
-                           pltpu.VMEM((R, P, bs * KVH), jnp.float32)]
-        operands += [k_scales, v_scales]
-    scratch_shapes += [
-        pltpu.SemaphoreType.DMA((R, 4 if quantized else 2, P)),
+        pltpu.SemaphoreType.DMA((R, 2, P)),
         pltpu.VMEM((KVH * g_pad, P * bs), jnp.float32),
         pltpu.VMEM((KVH * g_pad, D), jnp.float32),
         pltpu.VMEM((KVH * g_pad, 128), jnp.float32),
@@ -315,7 +379,6 @@ def pallas_paged_attention(
         ),
         out_shape=jax.ShapeDtypeStruct((B, KVH * g_pad, D), q.dtype),
         interpret=interpret,
-    )(block_tables.astype(jnp.int32), context_lens.astype(jnp.int32),
-      layer_arr, *operands)
+    )(block_tables, context_lens.astype(jnp.int32), layer_arr, *operands)
     out = out.reshape(B, KVH, g_pad, D)[:, :, :group, :]
     return out.reshape(B, H, D)

@@ -45,17 +45,16 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from production_stack_tpu.ops.pallas_paged_attention import (
-    RING,
+    VMEM_BUDGET,
     _start_chunk_copy,
     _wait_chunk_copy,
+    choose_tile,
+    gather_scale_rows,
+    pad_tables,
+    ring_bytes,
 )
 
 NEG_INF = -1e30
-
-# Head-batched score rows per (b, qi) program: KVH * group * TQ. Capped
-# so the f32 scratch set (scores [rows, span] + acc [rows, D] + m/l
-# [rows, 128] x2) plus the DMA ring stays well inside ~16 MB VMEM.
-_MAX_TILE_ROWS = 4096
 
 
 def _prefill_kernel(
@@ -67,13 +66,12 @@ def _prefill_kernel(
     q_ref,  # [1, 1, KVH*gq, D] query tile for (b, qi); pre-scaled
     k_hbm_ref,  # [L, NB, bs, KVH, D] in ANY/HBM (int8 when quantized)
     v_hbm_ref,
-    # quantized only: ks_hbm_ref / vs_hbm_ref [L, NB, bs*KVH] f32 in
-    # ANY; then outputs o_acc [1, 1, KVH*gq, D] f32 (unnormalized),
-    # o_m / o_l [1, 1, KVH*gq, 128] f32; then scratch: k_buf/v_buf
-    # VMEM [RING, P, bs, KVH, D], (quantized: ks_buf/vs_buf VMEM
-    # [RING, P, bs*KVH] f32,) sems DMA [RING, 2|4, P], s_ref
-    # [KVH*gq, span] f32, acc_ref [KVH*gq, D] f32, m_ref/l_ref
-    # [KVH*gq, 128] f32.
+    # quantized only: ks_ref / vs_ref [1, KVH, span] f32 VMEM blocks (this
+    # chunk's per-head scale rows); then outputs o_acc [1, 1, KVH*gq, D]
+    # f32 (unnormalized), o_m / o_l [1, 1, KVH*gq, 128] f32; then
+    # scratch: k_buf/v_buf VMEM [RING, P, bs, KVH, D], sems DMA
+    # [RING, 2, P], s_ref [KVH*gq, span] f32, acc_ref [KVH*gq, D] f32,
+    # m_ref/l_ref [KVH*gq, 128] f32.
     *refs,
     block_size: int,
     kvh: int,
@@ -83,15 +81,9 @@ def _prefill_kernel(
     quantized: bool,
 ):
     if quantized:
-        (ks_hbm_ref, vs_hbm_ref, o_acc_ref, o_m_ref, o_l_ref,
-         k_buf, v_buf, ks_buf, vs_buf, sems,
-         s_ref, acc_ref, m_ref, l_ref) = refs
-        scale_kwargs = dict(ks_hbm=ks_hbm_ref, vs_hbm=vs_hbm_ref,
-                            ks_buf=ks_buf, vs_buf=vs_buf)
-    else:
-        (o_acc_ref, o_m_ref, o_l_ref, k_buf, v_buf, sems,
-         s_ref, acc_ref, m_ref, l_ref) = refs
-        scale_kwargs = {}
+        ks_ref, vs_ref, *refs = refs
+    (o_acc_ref, o_m_ref, o_l_ref, k_buf, v_buf, sems,
+     s_ref, acc_ref, m_ref, l_ref) = refs
     b = pl.program_id(0)
     qi = pl.program_id(1)
     c = pl.program_id(2)
@@ -121,8 +113,7 @@ def _prefill_kernel(
             def _(gb=gb, gc=gc, k=k):
                 _start_chunk_copy(
                     k_hbm_ref, v_hbm_ref, k_buf, v_buf, sems,
-                    block_tables_ref, layer, gb, gc, k % ring, P,
-                    **scale_kwargs)
+                    block_tables_ref, layer, gb, gc, k % ring, P)
 
     @pl.when(c == 0)
     def _init():
@@ -142,29 +133,27 @@ def _prefill_kernel(
     def _prefetch():
         _start_chunk_copy(k_hbm_ref, v_hbm_ref, k_buf, v_buf, sems,
                           block_tables_ref, layer, b_pre, c_pre,
-                          jax.lax.rem(g_pre, ring), P, **scale_kwargs)
+                          jax.lax.rem(g_pre, ring), P)
 
     @pl.when(chunk_start < prefix)
     def _compute():
         _wait_chunk_copy(k_hbm_ref, v_hbm_ref, k_buf, v_buf, sems,
-                         block_tables_ref, layer, b, c, slot, P,
-                         **scale_kwargs)
-        if quantized:
-            # [P, bs*KVH] -> token-major [span, KVH]: row p*bs+t, col h.
-            k_sc = ks_buf[slot].reshape(span_tokens, kvh)
-            v_sc = vs_buf[slot].reshape(span_tokens, kvh)
+                         block_tables_ref, layer, b, c, slot, P)
         for h in range(kvh):  # static unroll over kv heads
             rows = slice(h * gq, (h + 1) * gq)
             q = q_ref[0, 0, rows, :].astype(jnp.float32)  # [gq, D]
             k = (k_buf[slot, :, :, h, :]
                  .reshape(span_tokens, -1).astype(jnp.float32))
-            if quantized:
-                # Dequantize on-chip: the HBM stream stayed int8.
-                k = k * k_sc[:, h:h + 1]
-            s_ref[rows, :] = jax.lax.dot_general(
+            s_h = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
+            if quantized:
+                # Dequantize on-chip: the HBM stream stayed int8; the
+                # per-token scale factors out of the dot (see the
+                # decode kernel).
+                s_h = s_h * ks_ref[0, h:h + 1, :]
+            s_ref[rows, :] = s_h
         # Every query row in the chunk sits at an absolute position
         # >= prefix, so the prefix side needs NO per-row causal mask —
         # only the prefix-length bound. (The causal structure lives
@@ -189,10 +178,11 @@ def _prefill_kernel(
             rows = slice(h * gq, (h + 1) * gq)
             v = (v_buf[slot, :, :, h, :]
                  .reshape(span_tokens, -1).astype(jnp.float32))
+            p_h = p_[rows, :]
             if quantized:
-                v = v * v_sc[:, h:h + 1]
+                p_h = p_h * vs_ref[0, h:h + 1, :]
             acc_ref[rows, :] = acc_ref[rows, :] + jax.lax.dot(
-                p_[rows, :], v, preferred_element_type=jnp.float32)
+                p_h, v, preferred_element_type=jnp.float32)
 
     @pl.when(c == nc - 1)
     def _finalize():
@@ -205,13 +195,39 @@ def _prefill_kernel(
         o_l_ref[0, 0] = l_ref[...]
 
 
-def _query_tile(T: int, H: int) -> int:
-    """Static query-tile width: a multiple of 8 (sublane alignment of
-    the per-head row slices), capped so KVH*group*TQ = H*TQ scratch
-    rows stay within the VMEM budget."""
-    cap = max(8, (_MAX_TILE_ROWS // max(H, 1)) // 8 * 8)
+def prefill_tile(T: int, H: int, block_size: int, kvh: int, head_dim: int,
+                 itemsize: int, tables_width: int, quantized: bool):
+    """(q_tile, pages_per_block, ring) the kernel runs with at these
+    shapes, chosen to fit :data:`VMEM_BUDGET`; None when nothing fits.
+
+    The widest query tile first: every tile re-streams its row's whole
+    prefix, and the f32 partial outputs [H * q_tile, D + 256] (double
+    buffered) count as much as the page ring. The kernel reuses each
+    chunk across H * q_tile query rows, so it is bound by the MXU long
+    before the page stream: a ring of 3 already hides the copies, and
+    2 is taken before a narrower tile."""
     t_pad = (T + 7) // 8 * 8
-    return min(128, cap, t_pad)
+    for tq in (128, 64, 32, 16, 8):
+        if tq > t_pad and tq > 8:
+            continue
+        rows = H * tq
+
+        def fits(pages: int, ring: int) -> bool:
+            span = pages * block_size
+            total = ring_bytes(ring, pages, block_size, kvh, head_dim,
+                               itemsize)
+            total += 4 * rows * (span + head_dim + 256)  # s/acc/m/l
+            total += 2 * 4 * rows * span  # masked scores, probabilities
+            total += 2 * 4 * rows * (head_dim + 256)  # partial outputs
+            total += 2 * 2 * rows * head_dim  # bf16 query block
+            if quantized:
+                total += 2 * 2 * 4 * max(kvh, 8) * span  # scale blocks
+            return total <= VMEM_BUDGET
+
+        tile = choose_tile(fits, tables_width, block_size, ring_floor=2)
+        if tile is not None:
+            return (tq,) + tile
+    return None
 
 
 @functools.partial(
@@ -231,9 +247,9 @@ def pallas_prefill_attention(
     suffix_lens: jax.Array,  # [B] valid fresh tokens (= seq_lens)
     *,
     scale: float,
-    pages_per_block: int = 0,  # 0 -> largest of (8,4,2,1) dividing MAXB
-    ring: int = 0,  # DMA ring depth; 0 -> RING default
-    q_tile: int = 0,  # query-tile width; 0 -> heuristic
+    pages_per_block: int = 0,  # 0 -> from the VMEM budget (prefill_tile)
+    ring: int = 0,  # DMA ring depth; 0 -> from the VMEM budget
+    q_tile: int = 0,  # query-tile width; 0 -> from the VMEM budget
     interpret: bool = False,
 ) -> jax.Array:
     quantized = isinstance(k_pages, tuple)
@@ -242,14 +258,18 @@ def pallas_prefill_attention(
         v_pages, v_scales = v_pages
     B, T, H, D = q.shape
     L, NB, bs, KVH, _ = k_pages.shape
-    MAXB = block_tables.shape[1]
     group = H // KVH
-    P = pages_per_block or next(p for p in (8, 4, 2, 1) if MAXB % p == 0)
-    if MAXB % P != 0:
+    tile = prefill_tile(T, H, bs, KVH, D, k_pages.dtype.itemsize,
+                        block_tables.shape[1], quantized)
+    if tile is None and not (pages_per_block and ring and q_tile):
         raise ValueError(
-            f"pages_per_block {P} does not divide table width {MAXB}")
-    nc = MAXB // P
-    TQ = q_tile or _query_tile(T, H)
+            f"no prefill tile fits VMEM at heads={H} block_size={bs} "
+            f"kv_heads={KVH} head_dim={D}")
+    TQ = q_tile or tile[0]
+    P = pages_per_block or tile[1]
+    R = ring or tile[2]
+    block_tables = pad_tables(block_tables, P)
+    nc = block_tables.shape[1] // P
     T_pad = (T + TQ - 1) // TQ * TQ
     nq = T_pad // TQ
     gq = group * TQ
@@ -271,7 +291,6 @@ def pallas_prefill_attention(
     qt = qt.reshape(B, KVH, group, nq, TQ, D).transpose(0, 3, 1, 2, 4, 5)
     qt = qt.reshape(B, nq, KVH * gq, D)
 
-    R = ring or RING
     kernel = functools.partial(
         _prefill_kernel, block_size=bs, kvh=KVH, gq=gq,
         pages_per_block=P, ring=R, quantized=quantized,
@@ -284,19 +303,22 @@ def pallas_prefill_attention(
         pl.BlockSpec(memory_space=pl.ANY),
         pl.BlockSpec(memory_space=pl.ANY),
     ]
+    operands = [qt, k_pages, v_pages]
+    if quantized:
+        # This chunk's scale rows [KVH, span] per side; the block index
+        # stops at the row's last live chunk (see the decode kernel).
+        def scale_block(b, qi, c, bt, pfx, lr):
+            last = jnp.maximum(pfx[b] - 1, 0) // (P * bs)
+            return (b, 0, jnp.minimum(c, last))
+
+        in_specs += [pl.BlockSpec((1, KVH, P * bs), scale_block)] * 2
+        operands += [
+            gather_scale_rows(k_scales, block_tables, layer, bs, KVH),
+            gather_scale_rows(v_scales, block_tables, layer, bs, KVH)]
     scratch_shapes = [
         pltpu.VMEM((R, P, bs, KVH, D), k_pages.dtype),
         pltpu.VMEM((R, P, bs, KVH, D), v_pages.dtype),
-    ]
-    operands = [qt, k_pages, v_pages]
-    if quantized:
-        in_specs += [pl.BlockSpec(memory_space=pl.ANY),
-                     pl.BlockSpec(memory_space=pl.ANY)]
-        scratch_shapes += [pltpu.VMEM((R, P, bs * KVH), jnp.float32),
-                           pltpu.VMEM((R, P, bs * KVH), jnp.float32)]
-        operands += [k_scales, v_scales]
-    scratch_shapes += [
-        pltpu.SemaphoreType.DMA((R, 4 if quantized else 2, P)),
+        pltpu.SemaphoreType.DMA((R, 2, P)),
         pltpu.VMEM((KVH * gq, P * bs), jnp.float32),
         pltpu.VMEM((KVH * gq, D), jnp.float32),
         pltpu.VMEM((KVH * gq, 128), jnp.float32),
@@ -322,7 +344,7 @@ def pallas_prefill_attention(
             jax.ShapeDtypeStruct((B, nq, KVH * gq, 128), jnp.float32),
         ],
         interpret=interpret,
-    )(block_tables.astype(jnp.int32), prefix_lens, layer_arr, *operands)
+    )(block_tables, prefix_lens, layer_arr, *operands)
 
     def _untile(x):
         # [B, nq, KVH*gq, ...] -> [B, KVH, group, T, ...]

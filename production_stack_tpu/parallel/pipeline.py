@@ -19,7 +19,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
-from production_stack_tpu.parallel.compat import pcast, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
@@ -60,9 +59,9 @@ def pipeline_forward(
             # pcast-to-varying: carries mix with per-stage (varying) values
             # inside the loop, so their types must be varying over the pp
             # axis too.
-            zero = pcast(
+            zero = jax.lax.pcast(
                 jnp.zeros_like(x_all[0]), (axis_name,), to="varying")
-            outputs = pcast(
+            outputs = jax.lax.pcast(
                 jnp.zeros_like(x_all), (axis_name,), to="varying")
 
             def tick(t, carry):
@@ -70,7 +69,7 @@ def pipeline_forward(
                 # Stage 0 injects microbatch t (when in range); others take
                 # the activation handed over from the previous stage.
                 m_for_stage0 = jnp.clip(t, 0, M - 1)
-                injected = pcast(
+                injected = jax.lax.pcast(
                     jax.lax.dynamic_index_in_dim(
                         x_all, m_for_stage0, 0, False),
                     (axis_name,), to="varying",
@@ -100,7 +99,7 @@ def pipeline_forward(
             stage_has = (idx == pp - 1).astype(outputs.dtype)
             return jax.lax.psum(outputs * stage_has, axis_name)
 
-        out = shard_map(
+        out = jax.shard_map(
             stage_body, mesh=mesh,
             in_specs=(p_spec, x_spec), out_specs=x_spec,
         )(

@@ -32,7 +32,6 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from production_stack_tpu.parallel.compat import pcast, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from production_stack_tpu.models.config import ModelConfig
@@ -109,7 +108,7 @@ def make_pp_apply(mesh: Mesh, microbatches: int = 1):
         )
 
         def to_varying(a):
-            return pcast(a, ("pp",), to="varying")
+            return jax.lax.pcast(a, ("pp",), to="varying")
 
         def stage_body(layers_loc, lora_loc, scaling, k_loc, v_loc,
                        x_mb, pos_mb, slots_mb, tables_mb, ctx_mb, seq_mb,
@@ -193,7 +192,7 @@ def make_pp_apply(mesh: Mesh, microbatches: int = 1):
             ).astype(outputs.dtype)
             return outputs, k_loc, v_loc
 
-        hidden_mb, k_all, v_all = shard_map(
+        hidden_mb, k_all, v_all = jax.shard_map(
             stage_body,
             mesh=mesh,
             in_specs=(layer_spec, lora_spec, P(), P("pp"), P("pp"),

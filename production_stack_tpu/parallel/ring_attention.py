@@ -26,7 +26,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from production_stack_tpu.parallel.compat import pcast, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 NEG_INF = -1e30
@@ -63,12 +62,12 @@ def ring_attention_fwd(
     # Online-softmax accumulators (float32). pcast marks them as varying
     # over the ring axis so the fori_loop carry types line up with the
     # per-device outputs.
-    m = pcast(
+    m = jax.lax.pcast(
         jnp.full((B, KVH, G, C), -jnp.inf, jnp.float32), (axis_name,),
         to="varying")
-    l = pcast(
+    l = jax.lax.pcast(
         jnp.zeros((B, KVH, G, C), jnp.float32), (axis_name,), to="varying")
-    o = pcast(
+    o = jax.lax.pcast(
         jnp.zeros((B, KVH, G, C, D), jnp.float32), (axis_name,),
         to="varying")
 
@@ -126,7 +125,7 @@ def make_ring_attention(
     def run(q, k, v):
         body = functools.partial(
             ring_attention_fwd, axis_name=axis_name, scale=scale)
-        return shard_map(
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=(seq_spec, seq_spec, seq_spec),
             out_specs=seq_spec,

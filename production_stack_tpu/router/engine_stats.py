@@ -176,6 +176,14 @@ class EngineStatsScraper(metaclass=SingletonMeta):
 
 
 def initialize_engine_stats_scraper(scrape_interval: float = 10.0) -> EngineStatsScraper:
+    """A NEW scraper for a new router app. The class is a process-wide
+    singleton and an app closes its scraper on cleanup (the thread
+    exits), so the second app built in one process would otherwise be
+    handed the first one's dead scraper: /health 503, no engine stats."""
+    previous = SingletonMeta._instances.get(EngineStatsScraper)
+    if previous is not None:
+        previous.close()
+        SingletonMeta._reset_instance(EngineStatsScraper)
     return EngineStatsScraper(scrape_interval)
 
 

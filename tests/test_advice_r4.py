@@ -5,6 +5,8 @@ pipe offer cap, per-core HBM table entries, and n>1 abort hygiene."""
 import asyncio
 import math
 
+import pytest
+
 from production_stack_tpu.engine.config import EngineConfig
 from production_stack_tpu.engine.core import EngineCore
 from production_stack_tpu.engine.sampling import MAX_LOGIT_BIAS
@@ -173,12 +175,28 @@ def test_device_pipe_offer_cap():
     assert pipe.offer(["k", "v"]) is not None  # slot was rolled back
 
 
-def test_hbm_table_uses_per_core_capacities():
-    """JAX enumerates v2/v3 per-core (8/16 GB per device); the
-    memory_stats-less fallback must not size the KV pool from per-chip
-    figures. Entries are DECIMAL vendor bytes (16e9, not 16<<30) — the
-    GiB figure oversizes ~7% and OOMs margin-sized configs."""
-    table = dict(EngineCore._HBM_BY_KIND)
-    assert table["v2"] == int(8e9)
-    assert table["v3"] == int(16e9)
-    assert table["v5e"] == int(16e9)
+def test_pool_is_sized_from_memory_stats_or_not_at_all():
+    """The KV pool is sized from what the runtime says is free. A TPU
+    that returns no memory_stats() is an error at start-up — no capacity
+    table, no environment override, no workspace guess stands in for it;
+    the CPU (no such figure) gets the minimal pool."""
+
+    class _Dev:
+        def __init__(self, platform, stats):
+            self.platform, self._stats = platform, stats
+
+        def memory_stats(self):
+            return self._stats
+
+    class _Core:
+        _free_hbm_bytes = EngineCore._free_hbm_bytes
+
+        def __init__(self, dev):
+            self._local_device = lambda: dev
+
+    assert _Core(_Dev("tpu", {"bytes_limit": 16, "bytes_in_use": 5})
+                 )._free_hbm_bytes() == 11
+    assert _Core(_Dev("cpu", None))._free_hbm_bytes() is None
+    with pytest.raises(RuntimeError, match="memory_stats"):
+        _Core(_Dev("tpu", None))._free_hbm_bytes()
+    assert not hasattr(EngineCore, "_HBM_BY_KIND")

@@ -4,6 +4,7 @@ requests with 401, probes/scrapes stay open, and the router's header
 forwarding lets one shared deployment key authenticate end to end."""
 
 import asyncio
+import time
 
 import pytest
 
@@ -191,6 +192,41 @@ def test_router_edge_auth_and_shared_key_passthrough():
 
     asyncio.run(run())
     engine.core.stop()
+
+
+def test_second_router_app_in_one_process_is_healthy():
+    """A router app closes its engine-stats scraper on cleanup; the next
+    app built in the same process must get a live one (the scraper class
+    is a singleton, and handing back the dead instance made /health 503
+    for whichever test file a worker happened to run second)."""
+    from aiohttp import web
+
+    from production_stack_tpu.router.app import build_app
+    from production_stack_tpu.router.parser import build_parser
+
+    async def health_of_new_app():
+        import aiohttp
+
+        args = build_parser().parse_args([])
+        args.service_discovery = "static"
+        args.static_backends = "http://127.0.0.1:9"  # never contacted
+        args.static_models = "tiny-llama"
+        args.routing_logic = "roundrobin"
+        runner = web.AppRunner(build_app(args))
+        await runner.setup()
+        site = web.TCPSite(runner, "127.0.0.1", 0)
+        await site.start()
+        port = site._server.sockets[0].getsockname()[1]
+        try:
+            async with aiohttp.ClientSession() as s:
+                async with s.get(f"http://127.0.0.1:{port}/health") as resp:
+                    return resp.status
+        finally:
+            await runner.cleanup()
+
+    assert asyncio.run(health_of_new_app()) == 200
+    time.sleep(0.3)  # the closed scraper's thread notices and exits
+    assert asyncio.run(health_of_new_app()) == 200
 
 
 def _debug_routes(app):

@@ -1,0 +1,174 @@
+"""The chip's compiler on the kernels of the main path, at real widths.
+
+Interpret mode cannot see what Mosaic refuses: a slice off the tiling, a
+shape cast it cannot lay out, more scoped VMEM than a kernel may take, a
+kernel the partitioner cannot split. The TPU compiler is installed here
+and compiles for a chip that is described, not attached — so every
+kernel shape the default engine compiles for ``tpu-llama-3b`` and
+``tpu-llama-1b`` (block_size 64) is compiled here for a v5e, about two
+seconds each, at no chip time. A compile that passes is not a chip run.
+
+Everything that touches the topology lives in fixtures of THIS file (one
+process at a time may load the TPU library: see the on-chip-measurement
+guide, section 2).
+"""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from production_stack_tpu.models import get_model_config
+from production_stack_tpu.ops import attention as att
+from production_stack_tpu.ops.pallas_paged_attention import (
+    pallas_paged_attention,
+)
+from production_stack_tpu.ops.pallas_prefill_attention import (
+    pallas_prefill_attention,
+)
+
+BLOCK_SIZE = 64
+LAYERS, NUM_BLOCKS = 4, 256  # pool dims the kernels only index into
+MODELS = ("tpu-llama-3b", "tpu-llama-1b")
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without the chip (the next one warns and
+    compiles again), so the cache is off around these tests."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+def _pages(sharding, kvh, head_dim, quantized):
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    data = (LAYERS, NUM_BLOCKS, BLOCK_SIZE, kvh, head_dim)
+    if quantized:
+        return (spec(data, jnp.int8),
+                spec((LAYERS, NUM_BLOCKS, BLOCK_SIZE * kvh), jnp.float32))
+    return spec(data, jnp.bfloat16)
+
+
+def _compile_with_kernel(fn, *args):
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    return text
+
+
+@pytest.mark.parametrize("tables", [4, 128])  # smallest, largest bucket
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("model", MODELS)
+def test_decode_kernel_compiles_for_v5e(one_chip, model, quantized, tables):
+    mc = get_model_config(model)
+    B = 8  # the server's default max_num_seqs
+
+    def spec(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pages = _pages(one_chip, mc.num_kv_heads, mc.head_dim, quantized)
+    _compile_with_kernel(
+        lambda q, k, v, bt, cl, layer: pallas_paged_attention(
+            q, k, v, bt, cl, layer, scale=mc.head_dim ** -0.5),
+        spec((B, mc.num_heads, mc.head_dim), jnp.bfloat16), pages, pages,
+        spec((B, tables)), spec((B,)), spec(()))
+
+
+# (prefill rows, chunk bucket) the default engine compiles, each with the
+# smallest table bucket that holds the chunk and the largest (8192 tokens).
+@pytest.mark.parametrize("rows,chunk,tables", [
+    (1, 1024, 16), (1, 1024, 128), (4, 1024, 16), (4, 1024, 128),
+    (1, 2048, 32), (1, 2048, 128)])
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("model", MODELS)
+def test_prefill_kernel_compiles_for_v5e(one_chip, model, quantized, rows,
+                                         chunk, tables):
+    mc = get_model_config(model)
+
+    def spec(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pages = _pages(one_chip, mc.num_kv_heads, mc.head_dim, quantized)
+    fresh = spec((rows, chunk, mc.num_kv_heads, mc.head_dim), jnp.bfloat16)
+    _compile_with_kernel(
+        lambda q, k, v, bt, pos, tl, layer, kn, vn, sl:
+        pallas_prefill_attention(q, k, v, bt, pos, tl, layer, kn, vn, sl,
+                                 scale=mc.head_dim ** -0.5),
+        spec((rows, chunk, mc.num_heads, mc.head_dim), jnp.bfloat16),
+        pages, pages, spec((rows, tables)), spec((rows, chunk)),
+        spec((rows,)), spec(()), fresh, fresh, spec((rows,)))
+
+
+def test_sharded_decode_attention_on_four_chips(topo, monkeypatch):
+    """A pool sharded over kv heads on four chips. The partitioner
+    refuses a bare pallas_call, so the dispatcher runs the kernel per
+    shard where a shard's heads pass the tile gate (32 kv heads -> 8 per
+    chip) and takes the reference, by a counted trace-time decision,
+    where they do not (Llama's 8 -> 2 per chip). Either way attention
+    stays local: no collective, and each chip is handed a quarter of the
+    pool, not all of it."""
+    mesh = Mesh(np.asarray(topo.devices).reshape(1, 1, 4),
+                ("dp", "pp", "tp"))
+    monkeypatch.setattr(att, "_use_pallas", lambda: True)
+    D, B, tables = 128, 8, 16
+
+    def spec(shape, dtype=jnp.int32, axes=P()):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=NamedSharding(mesh, axes))
+
+    def compiled(kvh, heads, fn):
+        pages = spec((LAYERS, NUM_BLOCKS, BLOCK_SIZE, kvh, D), jnp.bfloat16,
+                     P(None, None, None, "tp", None))
+        return jax.jit(fn).lower(
+            spec((B, heads, D), jnp.bfloat16, P(None, "tp", None)),
+            pages, pages, spec((B, tables)), spec((B,)), spec(())).compile()
+
+    def dispatch(q, k, v, bt, cl, layer):
+        with att.kv_head_sharding(mesh, "tp"):
+            return att.paged_decode_attention(q, k, v, bt, cl, layer,
+                                              scale=D ** -0.5)
+
+    with pytest.raises(Exception, match="shard_map"):
+        compiled(32, 64, lambda q, k, v, bt, cl, layer:
+                 pallas_paged_attention(q, k, v, bt, cl, layer,
+                                        scale=D ** -0.5))
+
+    pool_side = LAYERS * NUM_BLOCKS * BLOCK_SIZE * D * 2
+    for kvh, heads, path in ((32, 64, "pallas"), (8, 24, "xla")):
+        att.TRACED_PATHS.clear()
+        program = compiled(kvh, heads, dispatch)
+        text = program.as_text()
+        assert dict(att.TRACED_PATHS) == {("decode", path): 1}
+        assert ("tpu_custom_call" in text) == (path == "pallas")
+        assert not re.search(
+            r"\b(all-gather|all-reduce|all-to-all|collective-permute)", text)
+        held = program.memory_analysis().argument_size_in_bytes
+        assert held < 2 * pool_side * kvh / 4 * 1.1

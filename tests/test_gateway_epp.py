@@ -16,10 +16,11 @@ sys.path.insert(0, os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
     "deploy", "gateway"))
 
-from production_stack_tpu.native import available  # noqa: E402
 
-pytestmark = pytest.mark.skipif(
-    not available(), reason="native picker library not built")
+@pytest.fixture(autouse=True)
+def _native(native_build):
+    """Every test here needs native/build (conftest.py builds it): the
+    picker library behind the Python EPP, and the C++ binaries."""
 
 
 @pytest.fixture()
@@ -592,8 +593,6 @@ _CORPUS_DIR = os.path.join(
     "native", "epp", "corpus")
 
 
-@pytest.mark.skipif(not os.path.exists(_FUZZ_BIN),
-                    reason="native fuzz harness not built")
 def test_native_h2fuzz_smoke():
     import subprocess
 

@@ -4,7 +4,6 @@ binary reconciling against a fake Kubernetes API server."""
 import asyncio
 import json
 import os
-import shutil
 import subprocess
 
 import pytest
@@ -15,25 +14,9 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BUILD_DIR = os.path.join(REPO, "native", "build")
 
 
-@pytest.fixture(scope="session", autouse=True)
-def build_native():
-    if not shutil.which("cmake"):
-        pytest.skip("cmake not available")
-    subprocess.run(
-        ["cmake", "-S", os.path.join(REPO, "native"), "-B", BUILD_DIR,
-         "-G", "Ninja" if shutil.which("ninja") else "Unix Makefiles"],
-        check=True, capture_output=True,
-    )
-    subprocess.run(
-        ["cmake", "--build", BUILD_DIR], check=True, capture_output=True,
-    )
-    os.environ["TPU_STACK_NATIVE_LIB"] = BUILD_DIR
-    # Force a re-probe after setting the env var.
-    import production_stack_tpu.native as native
-
-    native._load_attempted = False
-    native._lib = None
-    assert native.available()
+@pytest.fixture(autouse=True)
+def _native(native_build):
+    """Every test here needs native/build (conftest.py builds it)."""
 
 
 def test_xxhash64_parity():

@@ -29,12 +29,11 @@ from production_stack_tpu.ops.attention import (
     _gather_ctx,
     _page_tile_ok,
     context_prefill_attention,
-    prefill_attention_path,
+    attention_path,
     quantize_kv,
 )
 from production_stack_tpu.ops.pallas_prefill_attention import (
-    _MAX_TILE_ROWS,
-    _query_tile,
+    prefill_tile,
     pallas_prefill_attention,
 )
 
@@ -202,41 +201,57 @@ def test_dispatcher_falls_back_on_misaligned_shapes():
     np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
 
 
-def test_dispatcher_exception_fallback(monkeypatch):
-    """With the platform gate forced open on CPU, the pallas call fails
-    to lower — the try/except must land on the reference, not fail the
-    forward (the decode dispatch convention, replicated)."""
+def test_dispatcher_kernel_failure_raises(monkeypatch):
+    """With the platform gate forced open on CPU, the pallas call cannot
+    lower. The dispatcher chose the kernel at trace time from shapes and
+    platform; a kernel that then fails raises — nothing turns the failure
+    into the reference path (on the chip it would surface when the
+    enclosing jit compiles, where no fallback is possible anyway)."""
     B, T, KVH, group, D, L, bs, MAXB = 2, 8, 8, 1, 128, 1, 8, 4
     NB = B * MAXB
     s = _setup_prefill(B, T, KVH, group, D, L, NB, bs, MAXB, seed=19,
                        layer=0)
-    ref = context_prefill_attention(
-        s["q"], s["k_pages"], s["v_pages"], s["tables"], s["positions"],
-        s["total"], s["layer"], scale=0.1)
     monkeypatch.setattr(att, "_use_pallas", lambda: True)
-    got = context_prefill_attention(
-        s["q"], s["k_pages"], s["v_pages"], s["tables"], s["positions"],
-        s["total"], s["layer"], scale=0.1,
-        k_new=s["k_new"], v_new=s["v_new"], suffix_lens=s["take"])
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
+    with pytest.raises(Exception, match="(?i)pallas|mosaic|interpret"):
+        context_prefill_attention(
+            s["q"], s["k_pages"], s["v_pages"], s["tables"], s["positions"],
+            s["total"], s["layer"], scale=0.1,
+            k_new=s["k_new"], v_new=s["v_new"], suffix_lens=s["take"])
+
+
+_use_pallas_real = att._use_pallas
+
+
+class _FakeTpu:
+    platform = "tpu"
 
 
 def test_page_tile_gate_and_path_label():
-    assert _page_tile_ok(8, 8, 128, False)
-    assert _page_tile_ok(16, 8, 128, True)  # 16*8 = 128 scale lanes
-    assert not _page_tile_ok(8, 8, 128, True)  # 8*8 = 64: scale row short
-    assert not _page_tile_ok(8, 12, 128, False)  # OPT kv heads
-    assert not _page_tile_ok(8, 8, 64, False)  # head_dim
-    assert not _page_tile_ok(4, 8, 128, False)  # block_size
+    assert _page_tile_ok(8, 8, 128)
+    assert _page_tile_ok(16, 8, 128)
+    assert not _page_tile_ok(8, 12, 128)  # OPT kv heads
+    assert not _page_tile_ok(8, 8, 64)  # head_dim
+    assert not _page_tile_ok(4, 8, 128)  # block_size
     # On the CPU test mesh the runtime gate closes the pallas path.
-    assert prefill_attention_path(16, 8, 128, True) == "xla"
-    assert prefill_attention_path(8, 12, 128, False) == "xla"
+    assert attention_path(16, 8, 128, True) == "xla"
+    assert attention_path(8, 12, 128, False) == "xla"
 
 
 def test_path_label_env_override(monkeypatch):
     monkeypatch.setattr(att, "_use_pallas", lambda: True)
-    assert prefill_attention_path(16, 8, 128, True) == "pallas"
-    assert prefill_attention_path(8, 12, 128, False) == "xla"
+    assert attention_path(16, 8, 128, True) == "pallas"
+    assert attention_path(8, 8, 128, True) == "pallas"  # no scale-row gate
+    assert attention_path(8, 12, 128, False) == "xla"
+    # A pool sharded over kv heads: the kernel sees kvh / shards heads.
+    assert attention_path(64, 32, 128, False, kv_shards=4) == "pallas"
+    assert attention_path(64, 8, 128, False, kv_shards=4) == "xla"
+    assert attention_path(64, 32, 128, True, kv_shards=4) == "xla"
+    monkeypatch.setenv("TPU_STACK_FORCE_XLA_ATTENTION", "1")
+    monkeypatch.setattr(att, "_use_pallas", _use_pallas_real)
+    monkeypatch.setattr(att.jax, "devices", lambda: [_FakeTpu()])
+    assert attention_path(64, 8, 128, False) == "xla"  # the override
+    monkeypatch.delenv("TPU_STACK_FORCE_XLA_ATTENTION")
+    assert attention_path(64, 8, 128, False) == "pallas"
 
 
 def test_gather_ctx_accumulation_dtype_explicit():
@@ -267,11 +282,26 @@ def test_gather_ctx_accumulation_dtype_explicit():
     np.testing.assert_array_equal(np.asarray(got16), want)
 
 
-def test_query_tile_caps_vmem_rows():
-    for T, H in [(12, 16), (128, 32), (2048, 64), (64, 256), (8, 8)]:
-        tq = _query_tile(T, H)
-        assert tq % 8 == 0 and tq >= 8
-        assert H * tq <= max(_MAX_TILE_ROWS, H * 8)
+def test_prefill_tile_fits_vmem_budget():
+    """The tile chooser: aligned tiles inside the budget at every serving
+    width, narrower query tiles as the head count grows."""
+    from production_stack_tpu.ops.pallas_paged_attention import (
+        VMEM_BUDGET, ring_bytes)
+
+    widths = {}
+    for T, H in [(12, 16), (1024, 16), (1024, 24), (2048, 32), (1024, 64)]:
+        for quantized in (False, True):
+            tq, pages, ring = prefill_tile(
+                T, H, 64, 8, 128, 1 if quantized else 2, 128, quantized)
+            assert tq % 8 == 0 and tq >= 8
+            assert (pages * 64) % 128 == 0 and ring >= 2
+            assert ring_bytes(ring, pages, 64, 8, 128,
+                              1 if quantized else 2) < VMEM_BUDGET
+            widths[T, H, quantized] = tq
+    assert widths[1024, 64, False] < widths[1024, 16, False]
+    # Nothing fits: the caller is told, not handed a tile that will not
+    # compile.
+    assert prefill_tile(1024, 4096, 64, 8, 128, 2, 128, False) is None
 
 
 # ---------------------------------------------------------------------------

@@ -19,7 +19,7 @@ from production_stack_tpu.engine.core import EngineCore
 from production_stack_tpu.engine.sampling import SamplingParams
 from production_stack_tpu.obs.debug import add_step_debug_routes
 from production_stack_tpu.obs.steps import (
-    DEFAULT_HBM_BYTES_PER_S,
+    DEVICE_PEAKS,
     STEP_KINDS,
     StepRecorder,
     device_hbm_bytes_per_s,
@@ -86,13 +86,32 @@ def test_bandwidth_utilization_window():
     assert rec.bandwidth_utilization(now=r["ts_unix"] + 59.0) == 0.0
 
 
-def test_device_hbm_floor_env_override(monkeypatch):
+class _Device:
+    def __init__(self, platform, device_kind):
+        self.platform, self.device_kind = platform, device_kind
+
+
+def test_device_hbm_peak_comes_from_the_table(monkeypatch):
+    """One table keyed by device_kind. A device without a published peak
+    (the CPU) has no utilization — absent, not computed against another
+    chip's figure; an unknown TPU is an error, not a default."""
     monkeypatch.delenv("TPU_STACK_HBM_GBS", raising=False)
-    assert device_hbm_bytes_per_s() == DEFAULT_HBM_BYTES_PER_S
+    assert device_hbm_bytes_per_s(_Device("tpu", "TPU v5 lite")) == 819e9
+    assert DEVICE_PEAKS["TPU v5e"]["hbm_bytes_per_s"] == 819e9
+    assert device_hbm_bytes_per_s() is None
+    assert device_hbm_bytes_per_s(_Device("cpu", "cpu")) is None
+    with pytest.raises(ValueError, match="TPU v99"):
+        device_hbm_bytes_per_s(_Device("tpu", "TPU v99"))
+    rec = StepRecorder(capacity=4, param_bytes=1)
+    rec.record("decode_burst", 1.0)
+    assert rec.bandwidth_utilization() is None
+    assert rec.summary()["bandwidth_utilization"] is None
+    # The deployment's override wins, on any device, and must parse.
     monkeypatch.setenv("TPU_STACK_HBM_GBS", "1e9")
-    assert device_hbm_bytes_per_s() == 1e9
+    assert device_hbm_bytes_per_s(_Device("cpu", "cpu")) == 1e9
     monkeypatch.setenv("TPU_STACK_HBM_GBS", "not-a-number")
-    assert device_hbm_bytes_per_s() == DEFAULT_HBM_BYTES_PER_S
+    with pytest.raises(ValueError):
+        device_hbm_bytes_per_s()
 
 
 # ---------------------------------------------------------------------------
@@ -226,7 +245,8 @@ def test_engine_populates_recorder_and_stats():
         stats = eng.stats()
         assert stats["step_records_total"] == rec.recorded_total > 0
         assert stats["step_kind_stats"]["prefill"]["count"] >= 1
-        assert "model_bandwidth_utilization" in stats
+        # No published peak for the CPU: the utilization is absent.
+        assert stats["model_bandwidth_utilization"] is None
     finally:
         eng.stop()
 
@@ -319,7 +339,7 @@ def test_prefill_profile_hermetic_schema():
         for key in ("attention_est_s", "copy_est_s", "matmul_est_s"):
             assert key in row["components"], key
         assert row["full_s"] > 0 and row["bare_matmul_s"] > 0
-    assert doc["floors"]["weights_read_per_chunk_s"] > 0
+    assert doc["floors"] is None  # the CPU has no HBM peak to floor by
     # The committed artifact must match the schema the profiler emits
     # today (drift check for BENCH_PREFILL_PROFILE_*.json).
     committed = os.path.join(REPO_ROOT, "BENCH_PREFILL_PROFILE_r11.json")

@@ -387,19 +387,26 @@ def test_engine_violation_counted_on_truncation(eng):
 def test_engine_spec_decode_structured_parity(eng):
     """Speculative decoding must be byte-identical under greedy for a
     structured request: drafts are verified under per-position masks."""
-    body = {"temperature": 0, "max_tokens": 16,
+    # A grammar whose every member is shorter than max_tokens: a free
+    # integer (or string) may ramble under random weights until the
+    # length cap cuts it mid-structure, which is counted as a violation
+    # in BOTH engines and says nothing about the drafts.
+    body = {"temperature": 0, "max_tokens": 32,
             "guided_json": {"type": "object",
-                            "properties": {"n": {"type": "integer"}},
-                            "required": ["n"]}}
+                            "properties": {"ok": {"type": "boolean"},
+                                           "n": {"enum": [1, 22, 333]}},
+                            "required": ["ok", "n"]}}
     prompt = eng.tokenizer.encode("spec parity")
     plain, _ = _collect(eng, prompt, dict(body), rid="sp-p")
     spec_eng = _make_engine(speculative_num_tokens=4)
     try:
-        spec, _ = _collect(spec_eng, prompt, dict(body), rid="sp-s")
+        spec, finish = _collect(spec_eng, prompt, dict(body), rid="sp-s")
         assert spec_eng.stats()["structured_violations_total"] == 0
     finally:
         spec_eng.stop()
     assert plain == spec
+    assert finish == "stop" and json.loads(_text(eng, spec))["n"] in (
+        1, 22, 333)
 
 
 def test_engine_chunked_prefill_structured(eng):
