@@ -1,0 +1,9 @@
+"""The benchmark's tests import ``chipbench`` from the checkout's root."""
+
+import os
+import sys
+
+REPO = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+if REPO not in sys.path:
+    sys.path.insert(0, REPO)
