@@ -493,12 +493,6 @@ class EngineCore:
         self.generation_tokens_total = 0
         self.requests_finished_total = 0
         self.step_count = 0
-        # Wall-clock split of the engine thread (perf diagnosis): prefill
-        # spans (dispatch+sync), decode-burst dispatches, burst readbacks.
-        self.prefill_time_total = 0.0
-        self.decode_time_total = 0.0
-        self.flush_time_total = 0.0
-        self.prefill_count = 0
         # Storm-scoped batched prefills: groups dispatched / prompts they
         # carried (tail-latency diagnosis needs to know whether the storm
         # path actually engaged).
@@ -513,9 +507,6 @@ class EngineCore:
         # Mid-prefill sequences evicted by extend-time OOM (distinct from
         # scheduler-level preemptions, which have their own counter).
         self.prefill_chunk_requeues_total = 0
-        self.decode_burst_count = 0
-        self.dispatch_count_total = 0
-        self.dispatch_enqueue_s = 0.0
         # Fused step program: prefill-span + decode-burst pairs executed
         # as ONE dispatch (scheduler action "fused"); and cached-prefill
         # dispatches by attention path — "pallas" when the flash prefix
@@ -555,19 +546,23 @@ class EngineCore:
         self._mask_row_bytes = mask_row_bytes(self.model_config.vocab_size)
         self.structured_requests_total = 0
         self.structured_violations_total = 0
-        # Step flight recorder: one record per model step (kind, batch
-        # composition, wall time, roofline HBM byte estimate). The step
-        # functions stash a pending info dict ONLY when the recorder is
-        # on; _loop completes it with the measured wall time — so the
-        # recorder-off path adds a single attribute check per step.
+        # Step flight recorder, and the one clock of the engine loop:
+        # ``_steps`` times every step and its phases (the wall-clock
+        # split in stats() is its per-kind and per-phase totals) whether
+        # or not the recorder is on. With the recorder on
+        # (``step_recorder``, the same object) each step also makes a
+        # record (kind, batch composition, wall time and phases, roofline
+        # HBM byte estimate) and the phases are annotated into profiler
+        # traces. The step functions stash a pending info dict; _loop
+        # completes it with the measured wall time.
+        self._steps = StepRecorder(
+            capacity=config.step_record_capacity,
+            kv_token_bytes=(
+                self._kv_bytes_per_block() // config.block_size),
+            hbm_bytes_per_s=device_hbm_bytes_per_s(self._local_device()),
+        )
         self.step_recorder: Optional[StepRecorder] = (
-            StepRecorder(
-                capacity=config.step_record_capacity,
-                kv_token_bytes=(
-                    self._kv_bytes_per_block() // config.block_size),
-                hbm_bytes_per_s=device_hbm_bytes_per_s(
-                    self._local_device()),
-            ) if config.step_recorder else None)
+            self._steps if config.step_recorder else None)
         self._step_info: Optional[dict] = None
         # Warmup variant counts per program family (compile-budget
         # regression tests read this; also logged at the end of warmup).
@@ -841,32 +836,39 @@ class EngineCore:
                 block_tables, context_lens, seq_lens,
                 mode=mode, adapter_ids=adapter_ids, last_token=last_idx,
             )
-            last = logits[:, 0]
-            B = last.shape[0]
-            shaped = last.at[jnp.arange(B)[:, None], bias_ids].add(bias_vals)
-            if eos_id >= 0:  # min_tokens: mask EOS for the first token
-                shaped = jnp.where(
-                    suppress_eos[:, None]
-                    & (jnp.arange(shaped.shape[1])[None, :] == eos_id),
-                    -jnp.inf, shaped)
-            # stop_token_ids share the min_tokens mask (finite sentinel:
-            # -inf * 0 padding would make NaNs).
-            shaped = shaped.at[jnp.arange(B)[:, None], stop_ids].add(
-                -1e30 * stop_valid
-                * suppress_eos.astype(jnp.float32)[:, None])
-            # Structured output: grammar FSM mask (packed bitset rows;
-            # all-off for unconstrained sequences).
-            shaped = apply_fsm_mask(shaped, mask_bits, mask_on)
-            keys = make_rng_keys(seed_static, steps.max(), seq_seeds + steps)
-            sampled = sample_tokens(
-                shaped, keys, temperature, top_k, top_p, max_top_k=max_top_k
-            )
-            # Logprobs reflect the distribution actually sampled from
-            # (logit_bias + min_tokens masking applied), matching
-            # OpenAI/vLLM post-processor logprob semantics.
-            lp, top_lp, top_ids = logprob_outputs(shaped, sampled)
-            return (sampled, lp, top_lp, top_ids), kv
+            with jax.named_scope("sample"):
+                last = logits[:, 0]
+                B = last.shape[0]
+                shaped = last.at[
+                    jnp.arange(B)[:, None], bias_ids].add(bias_vals)
+                if eos_id >= 0:  # min_tokens: mask EOS for the first token
+                    shaped = jnp.where(
+                        suppress_eos[:, None]
+                        & (jnp.arange(shaped.shape[1])[None, :]
+                           == eos_id),
+                        -jnp.inf, shaped)
+                # stop_token_ids share the min_tokens mask (finite
+                # sentinel: -inf * 0 padding would make NaNs).
+                shaped = shaped.at[jnp.arange(B)[:, None], stop_ids].add(
+                    -1e30 * stop_valid
+                    * suppress_eos.astype(jnp.float32)[:, None])
+                # Structured output: grammar FSM mask (packed bitset rows;
+                # all-off for unconstrained sequences).
+                shaped = apply_fsm_mask(shaped, mask_bits, mask_on)
+                keys = make_rng_keys(
+                    seed_static, steps.max(), seq_seeds + steps)
+                sampled = sample_tokens(
+                    shaped, keys, temperature, top_k, top_p,
+                    max_top_k=max_top_k)
+                # Logprobs reflect the distribution actually sampled from
+                # (logit_bias + min_tokens masking applied), matching
+                # OpenAI/vLLM post-processor logprob semantics.
+                lp, top_lp, top_ids = logprob_outputs(shaped, sampled)
+                return (sampled, lp, top_lp, top_ids), kv
 
+        # The program's name in a profiler trace (the XLA Modules line)
+        # and in the step records: ``prefill`` or ``prefill_cached``.
+        fwd.__name__ = mode
         # Sampled tokens / logprobs are read back on the host: pin them
         # fully replicated so device_get works from any process of a
         # multi-host mesh (and is a no-copy local read).
@@ -904,18 +906,20 @@ class EngineCore:
             # burst N); tok_idx selects each sequence's last valid step;
             # host_tokens/use_host override rows for sequences that just
             # prefilled. Other args: [B] or [B, K] as before.
-            tokens0 = jnp.where(
-                use_host, host_tokens,
-                jnp.take_along_axis(tokens_prev, tok_idx[:, None], 1)[:, 0],
-            )
-            # Freshly prefilled slots start a new output: zero their
-            # penalty-count rows in-burst (no extra dispatch), then count
-            # the slot's first output token (sampled during prefill, it
-            # arrives here as tokens0) so penalties see it too.
-            counts = jnp.where(reset_counts[:, None], 0, counts)
-            B = tokens0.shape[0]
-            counts = counts.at[jnp.arange(B), tokens0].add(
-                reset_counts.astype(jnp.int32))
+            with jax.named_scope("sample"):
+                tokens0 = jnp.where(
+                    use_host, host_tokens,
+                    jnp.take_along_axis(
+                        tokens_prev, tok_idx[:, None], 1)[:, 0],
+                )
+                # Freshly prefilled slots start a new output: zero their
+                # penalty-count rows in-burst (no extra dispatch), then count
+                # the slot's first output token (sampled during prefill, it
+                # arrives here as tokens0) so penalties see it too.
+                counts = jnp.where(reset_counts[:, None], 0, counts)
+                B = tokens0.shape[0]
+                counts = counts.at[jnp.arange(B), tokens0].add(
+                    reset_counts.astype(jnp.int32))
 
             def body(carry, step_slots):
                 tokens, kv, counts, s = carry
@@ -925,47 +929,48 @@ class EngineCore:
                     jnp.ones_like(context0), mode="decode",
                     adapter_ids=adapter_ids,
                 )
-                raw = logits[:, 0]
-                # OpenAI presence/frequency penalties over the slot's
-                # OUTPUT tokens, plus sparse logit_bias and min_tokens
-                # EOS masking. Logprobs are computed from these shaped
-                # logits (OpenAI/vLLM post-processor semantics).
-                penalized = (
-                    raw
-                    - frequency_penalty[:, None] * counts
-                    - presence_penalty[:, None] * (counts > 0)
-                )
-                penalized = penalized.at[
-                    jnp.arange(B)[:, None], bias_ids].add(bias_vals)
-                suppress = (out_len0 + s) < min_tokens  # [B]
-                if eos_id >= 0:
-                    penalized = jnp.where(
-                        suppress[:, None]
-                        & (jnp.arange(penalized.shape[1])[None, :]
-                           == eos_id),
-                        -jnp.inf, penalized)
-                # stop_token_ids share the min_tokens mask (finite
-                # sentinel: -inf * 0 padding would make NaNs).
-                penalized = penalized.at[
-                    jnp.arange(B)[:, None], stop_ids].add(
-                    -1e30 * stop_valid
-                    * suppress.astype(jnp.float32)[:, None])
-                # Structured output: the FSM mask is constant across the
-                # scan (the host advances the automaton only at burst
-                # boundaries), so structured rows are scheduled with
-                # allow=1 — steps past the first are discarded at
-                # emission and their stale mask never reaches a stream.
-                penalized = apply_fsm_mask(penalized, mask_bits, mask_on)
-                keys = make_rng_keys(seed, 0, seed_base + s)
-                sampled = sample_tokens(
-                    penalized, keys, temperature, top_k, top_p,
-                    max_top_k=max_top_k,
-                )
-                lp, top_lp, top_ids = logprob_outputs(penalized, sampled)
-                # Only steps whose page slot is live count (masked
-                # speculative steps are discarded at emission).
-                live = (step_slots >= 0).astype(jnp.int32)
-                counts = counts.at[jnp.arange(B), sampled].add(live)
+                with jax.named_scope("sample"):
+                    raw = logits[:, 0]
+                    # OpenAI presence/frequency penalties over the slot's
+                    # OUTPUT tokens, plus sparse logit_bias and min_tokens
+                    # EOS masking. Logprobs are computed from these shaped
+                    # logits (OpenAI/vLLM post-processor semantics).
+                    penalized = (
+                        raw
+                        - frequency_penalty[:, None] * counts
+                        - presence_penalty[:, None] * (counts > 0)
+                    )
+                    penalized = penalized.at[
+                        jnp.arange(B)[:, None], bias_ids].add(bias_vals)
+                    suppress = (out_len0 + s) < min_tokens  # [B]
+                    if eos_id >= 0:
+                        penalized = jnp.where(
+                            suppress[:, None]
+                            & (jnp.arange(penalized.shape[1])[None, :]
+                               == eos_id),
+                            -jnp.inf, penalized)
+                    # stop_token_ids share the min_tokens mask (finite
+                    # sentinel: -inf * 0 padding would make NaNs).
+                    penalized = penalized.at[
+                        jnp.arange(B)[:, None], stop_ids].add(
+                        -1e30 * stop_valid
+                        * suppress.astype(jnp.float32)[:, None])
+                    # Structured output: the FSM mask is constant across the
+                    # scan (the host advances the automaton only at burst
+                    # boundaries), so structured rows are scheduled with
+                    # allow=1 — steps past the first are discarded at
+                    # emission and their stale mask never reaches a stream.
+                    penalized = apply_fsm_mask(penalized, mask_bits, mask_on)
+                    keys = make_rng_keys(seed, 0, seed_base + s)
+                    sampled = sample_tokens(
+                        penalized, keys, temperature, top_k, top_p,
+                        max_top_k=max_top_k,
+                    )
+                    lp, top_lp, top_ids = logprob_outputs(penalized, sampled)
+                    # Only steps whose page slot is live count (masked
+                    # speculative steps are discarded at emission).
+                    live = (step_slots >= 0).astype(jnp.int32)
+                    counts = counts.at[jnp.arange(B), sampled].add(live)
                 return ((sampled, kv, counts, s + 1),
                         (sampled, lp, top_lp, top_ids))
 
@@ -978,15 +983,17 @@ class EngineCore:
             # tokens_prev keeps one static shape across adaptive burst
             # widths (decode_steps_pressure) — otherwise each (K_cur,
             # K_prev) pair would compile its own program.
-            out_fb = out
-            if K < K_max:
-                out_fb = jnp.concatenate(
-                    [out, jnp.zeros((K_max - K,) + out.shape[1:],
-                                    out.dtype)], axis=0)
-            # [K, B, ...] -> [B, K, ...]
-            return (out_fb.T, lps.T, top_lps.swapaxes(0, 1),
-                    top_idxs.swapaxes(0, 1)), kv, counts
+            with jax.named_scope("sample"):
+                out_fb = out
+                if K < K_max:
+                    out_fb = jnp.concatenate(
+                        [out, jnp.zeros((K_max - K,) + out.shape[1:],
+                                        out.dtype)], axis=0)
+                # [K, B, ...] -> [B, K, ...]
+                return (out_fb.T, lps.T, top_lps.swapaxes(0, 1),
+                        top_idxs.swapaxes(0, 1)), kv, counts
 
+        fwd.__name__ = f"decode_k{K}"
         return jax.jit(
             fwd, donate_argnums=(1, 2),
             out_shardings=((self._repl,) * 4, self._kv_pair_sharding,
@@ -1043,43 +1050,45 @@ class EngineCore:
                 jnp.full((B,), K, jnp.int32),
                 mode="prefill_cached", adapter_ids=adapter_ids,
             )
-            # Per-position logit shaping + sampling, identical to the
-            # decode scan body (K is small — unrolled).
-            outs, lp_l, top_lp_l, top_id_l = [], [], [], []
-            for s in range(K):
-                penalized = logits[:, s].at[
-                    jnp.arange(B)[:, None], bias_ids].add(bias_vals)
-                suppress = (out_len0 + s) < min_tokens  # [B]
-                if eos_id >= 0:
-                    penalized = jnp.where(
-                        suppress[:, None]
-                        & (jnp.arange(penalized.shape[1])[None, :]
-                           == eos_id),
-                        -jnp.inf, penalized)
-                penalized = penalized.at[
-                    jnp.arange(B)[:, None], stop_ids].add(
-                    -1e30 * stop_valid
-                    * suppress.astype(jnp.float32)[:, None])
-                # Structured output: position s's mask is precomputed on
-                # the host from the FSM state AFTER drafts 0..s-1 —
-                # exactly the mask plain decode would apply at that step,
-                # so drafts that exit the language are rejected here by
-                # the same term (mask_bits [B, K, MB], mask_on [B, K]).
-                penalized = apply_fsm_mask(
-                    penalized, mask_bits[:, s], mask_on[:, s])
-                keys = make_rng_keys(seed, 0, seed_base + s)
-                sampled = sample_tokens(
-                    penalized, keys, temperature, top_k, top_p,
-                    max_top_k=max_top_k,
-                )
-                lp, top_lp, top_ids = logprob_outputs(penalized, sampled)
-                outs.append(sampled)
-                lp_l.append(lp)
-                top_lp_l.append(top_lp)
-                top_id_l.append(top_ids)
-            return (jnp.stack(outs, 1), jnp.stack(lp_l, 1),
-                    jnp.stack(top_lp_l, 1), jnp.stack(top_id_l, 1)), kv
+            with jax.named_scope("sample"):
+                # Per-position logit shaping + sampling, identical to the
+                # decode scan body (K is small — unrolled).
+                outs, lp_l, top_lp_l, top_id_l = [], [], [], []
+                for s in range(K):
+                    penalized = logits[:, s].at[
+                        jnp.arange(B)[:, None], bias_ids].add(bias_vals)
+                    suppress = (out_len0 + s) < min_tokens  # [B]
+                    if eos_id >= 0:
+                        penalized = jnp.where(
+                            suppress[:, None]
+                            & (jnp.arange(penalized.shape[1])[None, :]
+                               == eos_id),
+                            -jnp.inf, penalized)
+                    penalized = penalized.at[
+                        jnp.arange(B)[:, None], stop_ids].add(
+                        -1e30 * stop_valid
+                        * suppress.astype(jnp.float32)[:, None])
+                    # Structured output: position s's mask is precomputed on
+                    # the host from the FSM state AFTER drafts 0..s-1 —
+                    # exactly the mask plain decode would apply at that step,
+                    # so drafts that exit the language are rejected here by
+                    # the same term (mask_bits [B, K, MB], mask_on [B, K]).
+                    penalized = apply_fsm_mask(
+                        penalized, mask_bits[:, s], mask_on[:, s])
+                    keys = make_rng_keys(seed, 0, seed_base + s)
+                    sampled = sample_tokens(
+                        penalized, keys, temperature, top_k, top_p,
+                        max_top_k=max_top_k,
+                    )
+                    lp, top_lp, top_ids = logprob_outputs(penalized, sampled)
+                    outs.append(sampled)
+                    lp_l.append(lp)
+                    top_lp_l.append(top_lp)
+                    top_id_l.append(top_ids)
+                return (jnp.stack(outs, 1), jnp.stack(lp_l, 1),
+                        jnp.stack(top_lp_l, 1), jnp.stack(top_id_l, 1)), kv
 
+        fwd.__name__ = f"spec_verify_k{K}"
         return jax.jit(
             fwd, donate_argnums=(1,),
             out_shardings=((self._repl,) * 4, self._kv_pair_sharding))
@@ -1155,8 +1164,12 @@ class EngineCore:
             self._fused_capture = None
             self._drain_captured(cap)
         mh = self._mh
-        t0 = time.perf_counter()
-        try:
+        # The loop's ``enqueue`` phase: how much engine-thread wall time
+        # goes into ENQUEUEING programs (microseconds each on an attached
+        # chip); its count and seconds are stats()'s dispatch_count_total
+        # and dispatch_enqueue_s. Readback waits are the ``readback``
+        # phase.
+        with self._steps.phase("enqueue"):
             if mh is None:
                 return self._exec_op(name, static, arrays)
             with mh.lock:  # (send, enqueue) must be atomic for op ordering
@@ -1179,13 +1192,6 @@ class EngineCore:
                         "partial fan-out)", name)
                     raise RuntimeError(self.fatal_error) from e
                 return self._exec_op(name, static, arrays)
-        finally:
-            # Dispatch accounting: how much engine-thread wall time goes
-            # into ENQUEUEING programs (microseconds each on an attached
-            # chip). Readback waits are counted separately
-            # (flush_time_total / the prefill device_get).
-            self.dispatch_count_total += 1
-            self.dispatch_enqueue_s += time.perf_counter() - t0
 
     def _drain_captured(self, cap: list) -> None:
         """Issue captured-but-unexecuted ops as individual dispatches, in
@@ -1227,11 +1233,13 @@ class EngineCore:
         if name == "prefill":
             fn = (self._prefill_cached_fn if static["cached"]
                   else self._prefill_fn)
+            self._steps.note_program(fn.__name__)
             out, self.kv = fn(self.params, self.kv, *arrays)
             return out
         if name == "decode":
             K = static["K"]
             fn = self._multi_decode_fn(K)
+            self._steps.note_program(fn.__name__)
             B = self.config.max_num_seqs
             # Feedback tokens always carry the FULL decode_steps width
             # (bursts pad their output) so adaptive widths share shapes.
@@ -1264,6 +1272,7 @@ class EngineCore:
             # next burst feeds from host tokens, never from device
             # feedback (use_prev is False throughout spec mode).
             fn = self._spec_verify_fn(static["K"])
+            self._steps.note_program(fn.__name__)
             outs, self.kv = fn(self.params, self.kv, *arrays)
             return outs
         if name == "draft_forward":
@@ -1271,10 +1280,12 @@ class EngineCore:
             # against the DRAFTER's params and pages — never compiles or
             # touches a target-model program.
             d = self._draft
+            self._steps.note_program(d.forward_fn.__name__)
             out, d.kv = d.forward_fn(d.params, d.kv, *arrays)
             return out
         if name == "draft_scan":
             d = self._draft
+            self._steps.note_program(d.scan_fn.__name__)
             out, d.kv = d.scan_fn(d.params, d.kv, *arrays)
             return out
         if name == "set_counts_row":
@@ -2318,6 +2329,11 @@ class EngineCore:
         alloc = self.kv_mgr.allocator
         budget = self.scheduler.token_budget if \
             self.scheduler.chunked_prefill else 0
+        # Wall-clock split of the engine thread, from the loop's one
+        # clock: steps by kind (a fused step counts under its own kind in
+        # step_kind_stats, not here) and the loop's phases.
+        kinds = self._steps.kind_stats()
+        phases = self._steps.phase_stats()
         return {
             # Mid-prefill chunked sequences count as running: they hold KV
             # pages and will take a slot, and routers treat "running" as
@@ -2350,10 +2366,15 @@ class EngineCore:
             "kv_cache_bytes_per_token": (
                 self._kv_bytes_per_block() // self.config.block_size),
             "is_sleeping": self._sleeping,
-            "prefill_time_total": round(self.prefill_time_total, 3),
-            "decode_time_total": round(self.decode_time_total, 3),
-            "flush_time_total": round(self.flush_time_total, 3),
-            "prefill_count": self.prefill_count,
+            "prefill_time_total": round(
+                kinds["prefill"]["wall_s"]
+                + kinds["prefill_chunk"]["wall_s"], 3),
+            "decode_time_total": round(
+                kinds["decode_burst"]["wall_s"]
+                + kinds["spec_verify"]["wall_s"], 3),
+            "flush_time_total": round(phases["readback"]["seconds"], 3),
+            "prefill_count": (kinds["prefill"]["count"]
+                              + kinds["prefill_chunk"]["count"]),
             "prefill_group_count": self.prefill_group_count,
             "prefill_group_rows": self.prefill_group_rows,
             "prefill_chunks_total": self.prefill_chunks_total,
@@ -2365,12 +2386,13 @@ class EngineCore:
             "rejected_requests": dict(self.scheduler.rejected_total),
             "preempted_by_priority":
                 dict(self.scheduler.preempted_by_priority),
-            "decode_burst_count": self.decode_burst_count,
+            "decode_burst_count": (kinds["decode_burst"]["count"]
+                                   + kinds["spec_verify"]["count"]),
             "fused_steps_total": self.fused_steps_total,
             "prefill_attention_dispatch_total":
                 dict(self.prefill_attention_dispatch_total),
-            "dispatch_count_total": self.dispatch_count_total,
-            "dispatch_enqueue_s": round(self.dispatch_enqueue_s, 3),
+            "dispatch_count_total": phases["enqueue"]["count"],
+            "dispatch_enqueue_s": round(phases["enqueue"]["seconds"], 3),
             "decode_forward_steps_total": self.decode_forward_steps_total,
             "spec_proposed_tokens_total": self.spec_proposed_tokens_total,
             "spec_accepted_tokens_total": self.spec_accepted_tokens_total,
@@ -2403,107 +2425,116 @@ class EngineCore:
     # engine loop
     # ------------------------------------------------------------------ #
     def _loop(self) -> None:
+        steps = self._steps
         while True:
-            with self._lock:
-                while self._running and not self._pending_burst and (
-                    self._sleeping or not self.scheduler.has_work()
-                ):
-                    self._lock.wait(timeout=0.1)
-                if not self._running:
+            with steps.loop_step(self.step_recorder is not None):
+                if not self._loop_once():
                     return
-                action, req = self.scheduler.next_action()
-            self._step_info = None  # never carry info across a failed step
-            try:
-                with self._step_lock:
-                    if self._sleeping or self.params is None:
-                        self._flush_pending_burst()
-                        # sleep() won the race after next_action popped a
-                        # request: requeue it for wake-up instead of failing.
-                        # (Chunked plans pop nothing — their members stay in
-                        # scheduler.prefilling and resume on wake.)
-                        if action == "prefill" and req is not None:
-                            with self._lock:
-                                self.scheduler.requeue(req)
-                        continue
-                    if action == "prefill":
-                        t0 = time.perf_counter()
-                        self._do_prefill(req)
-                        if req.trace is not None and req.trace.prefill_start:
-                            req.trace.prefill_end = time.time()
-                        dt = time.perf_counter() - t0
-                        self.prefill_time_total += dt
-                        self.prefill_count += 1
-                        self._record_step(dt)
-                    elif action == "prefill_step":
-                        t0 = time.perf_counter()
-                        self._do_prefill_step(req)
-                        dt = time.perf_counter() - t0
-                        self.prefill_time_total += dt
-                        self.prefill_count += 1
-                        self._record_step(dt)
-                    elif action == "fused":
-                        t0 = time.perf_counter()
-                        self._do_fused(req)
-                        dt = time.perf_counter() - t0
-                        # prefill/decode split accounting happens inside
-                        # _do_fused (per leg).
-                        self._record_step(dt)
-                    elif action == "decode":
-                        t0 = time.perf_counter()
-                        self._do_decode()
-                        dt = time.perf_counter() - t0
-                        self.decode_time_total += dt
-                        self.decode_burst_count += 1
-                        self._record_step(dt)
-                    else:
-                        self._flush_pending_prefills()
-                        self._flush_pending_burst()
-                        time.sleep(0.001)
-            except Exception as e:  # noqa: BLE001
-                logger.exception("Engine step failed: %s", e)
-                failed_reqs = []
-                if action in ("prefill_step", "fused") and req:
-                    with self._lock:
-                        for pc in req:  # req is the [PrefillChunk] plan
-                            if pc.req in self.scheduler.prefilling:
-                                self.scheduler.prefilling.remove(pc.req)
-                                self.kv_mgr.free(pc.req.request_id)
-                                self.scheduler._requests.pop(
-                                    pc.req.request_id, None)
-                                failed_reqs.append(pc.req)
-                elif action == "prefill" and req is not None:
-                    with self._lock:
-                        self.scheduler._requests.pop(req.request_id, None)
-                    failed_reqs.append(req)
-                for r in failed_reqs:
-                    r.on_token(None, "error")
-                if self.fatal_error is not None:
-                    # Lockstep is broken (op-channel fan-out failed
-                    # mid-send): keeping the loop alive would silently
-                    # diverge from the followers. Fail every request —
-                    # queued AND in-flight (their clients would otherwise
-                    # hang forever) — and stop stepping; /health is
-                    # already 503.
-                    logger.error(
-                        "Engine loop halting on fatal error: %s",
-                        self.fatal_error)
-                    with self._lock:
-                        self._running = False
-                        for seq in self.scheduler.running():
-                            self.scheduler.finish(seq, "error")
-                        for r in self.scheduler.drain_waiting():
-                            r.on_token(None, "error")
-                    return
-            self.step_count += 1
 
-    def _record_step(self, wall_s: float) -> None:
-        """Complete the step record the step function stashed (if any)
-        with the wall time _loop measured around it. No-ops in a single
-        attribute check when the recorder is off or the step dispatched
-        nothing (e.g. an alloc-starved prefill that requeued)."""
-        rec, info = self.step_recorder, self._step_info
+    def _loop_once(self) -> bool:
+        """One iteration of the engine loop: wait for work, schedule, run
+        the step. Every interval of it is timed as a phase of the loop's
+        one clock (obs/steps.py): ``idle_wait`` and ``schedule`` here,
+        ``build`` around the step function with ``enqueue``, ``readback``
+        and ``emit`` inside it where the work happens. False when the
+        loop should end."""
+        steps = self._steps
+        with steps.phase("schedule"), self._lock:
+            while self._running and not self._pending_burst and (
+                self._sleeping or not self.scheduler.has_work()
+            ):
+                with steps.phase("idle_wait"):
+                    self._lock.wait(timeout=0.1)
+            if not self._running:
+                return False
+            action, req = self.scheduler.next_action()
+            if self.step_recorder is not None:
+                live, cached, free = self.kv_mgr.block_counts()
+                steps.note(waiting=self.scheduler.num_waiting,
+                           running=self.scheduler.num_running,
+                           kv_blocks_live=live, kv_blocks_cached=cached,
+                           kv_blocks_free=free)
+        self._step_info = None  # never carry info across a failed step
+        try:
+            with self._step_lock:
+                if self._sleeping or self.params is None:
+                    self._flush_pending_burst()
+                    # sleep() won the race after next_action popped a
+                    # request: requeue it for wake-up instead of failing.
+                    # (Chunked plans pop nothing — their members stay in
+                    # scheduler.prefilling and resume on wake.)
+                    if action == "prefill" and req is not None:
+                        with self._lock:
+                            self.scheduler.requeue(req)
+                    return True
+                if action in ("prefill", "prefill_step", "fused", "decode"):
+                    steps.start()
+                    with steps.phase("build"):
+                        if action == "prefill":
+                            self._do_prefill(req)
+                        elif action == "prefill_step":
+                            self._do_prefill_step(req)
+                        elif action == "fused":
+                            # _do_fused records its legs itself where
+                            # the fusion degrades.
+                            self._do_fused(req)
+                        else:
+                            self._do_decode()
+                    if (action == "prefill" and req.trace is not None
+                            and req.trace.prefill_start):
+                        req.trace.prefill_end = time.time()
+                    self._record_step()
+                else:
+                    self._flush_pending_prefills()
+                    self._flush_pending_burst()
+                    time.sleep(0.001)
+        except Exception as e:  # noqa: BLE001
+            logger.exception("Engine step failed: %s", e)
+            failed_reqs = []
+            if action in ("prefill_step", "fused") and req:
+                with self._lock:
+                    for pc in req:  # req is the [PrefillChunk] plan
+                        if pc.req in self.scheduler.prefilling:
+                            self.scheduler.prefilling.remove(pc.req)
+                            self.kv_mgr.free(pc.req.request_id)
+                            self.scheduler._requests.pop(
+                                pc.req.request_id, None)
+                            failed_reqs.append(pc.req)
+            elif action == "prefill" and req is not None:
+                with self._lock:
+                    self.scheduler._requests.pop(req.request_id, None)
+                failed_reqs.append(req)
+            for r in failed_reqs:
+                r.on_token(None, "error")
+            if self.fatal_error is not None:
+                # Lockstep is broken (op-channel fan-out failed
+                # mid-send): keeping the loop alive would silently
+                # diverge from the followers. Fail every request —
+                # queued AND in-flight (their clients would otherwise
+                # hang forever) — and stop stepping; /health is
+                # already 503.
+                logger.error(
+                    "Engine loop halting on fatal error: %s",
+                    self.fatal_error)
+                with self._lock:
+                    self._running = False
+                    for seq in self.scheduler.running():
+                        self.scheduler.finish(seq, "error")
+                    for r in self.scheduler.drain_waiting():
+                        r.on_token(None, "error")
+                return False
+        self.step_count += 1
+        return True
+
+    def _record_step(self, wall_s: Optional[float] = None) -> None:
+        """Complete the step the step function stashed (if any): its wall
+        time runs from the loop's ``start`` to now, unless ``wall_s``
+        gives it (the legs of a degraded fused step). No-op when the step
+        dispatched nothing (e.g. an alloc-starved prefill that requeued).
+        With the recorder off only the per-kind totals are kept."""
+        rec, info = self._steps, self._step_info
         self._step_info = None
-        if rec is None or info is None:
+        if info is None:
             return
         if rec.param_bytes == 0 and self.params is not None:
             # Weight bytes for the roofline: resolved lazily because the
@@ -2514,7 +2545,8 @@ class EngineCore:
                     for leaf in jax.tree_util.tree_leaves(self.params))
             except (TypeError, ValueError, AttributeError):
                 rec.param_bytes = 0
-        rec.record(info.pop("kind"), wall_s, **info)
+        rec.record(info.pop("kind"), wall_s,
+                   ring=self.step_recorder is not None, **info)
 
     # -- prefill -----------------------------------------------------------
     def _allocate_for_prefill(self, req: EngineRequest, limit=None):
@@ -2631,17 +2663,16 @@ class EngineCore:
             sampled = self._prefill_span(
                 req, tokens, block_ids, start, end)
             start = end
-        if self.step_recorder is not None:
-            n_chunks = max(1, -(-(n - cached) // max(chunk, 1)))
-            self._step_info = {
-                "kind": "prefill", "rows": 1, "tokens": n - cached,
-                "forwards": n_chunks,
-                # Chunk i's queries attend to the cached + previously
-                # prefilled context via the HBM pages.
-                "kv_read_tokens": (n_chunks * cached
-                                   + chunk * (n_chunks * (n_chunks - 1)) // 2),
-                "kv_write_tokens": n - cached,
-            }
+        n_chunks = max(1, -(-(n - cached) // max(chunk, 1)))
+        self._step_info = {
+            "kind": "prefill", "rows": 1, "tokens": n - cached,
+            "forwards": n_chunks,
+            # Chunk i's queries attend to the cached + previously
+            # prefilled context via the HBM pages.
+            "kv_read_tokens": (n_chunks * cached
+                               + chunk * (n_chunks * (n_chunks - 1)) // 2),
+            "kv_write_tokens": n - cached,
+        }
         # Read back the in-flight burst while the chunks execute on device.
         self._flush_pending_burst()
         # Settle the PREVIOUS prefill now — after this one's dispatch —
@@ -2744,23 +2775,22 @@ class EngineCore:
                     req, tokens, block_ids, start, end), 0)
         self.prefill_chunks_total += len(ready)
         self.last_step_batched_tokens = step_tokens
-        if self.step_recorder is not None:
-            path = self._prefill_attn_path()
-            self._step_info = {
-                "kind": "prefill_chunk", "rows": len(ready),
-                "tokens": step_tokens,
-                "forwards": 1 if batched else len(ready),
-                # Each chunk's queries attend to its request's context so
-                # far (cached prefix + earlier chunks). The flash kernel
-                # streams ONLY the prefix pages (the chunk's own K/V is
-                # attended from VMEM before it ever leaves the chip); the
-                # XLA gather path re-reads the full written context —
-                # prefix AND the just-scattered suffix.
-                "kv_read_tokens": sum(
-                    (s if path == "pallas" else e)
-                    for (_r, _t, _b, s, e) in ready),
-                "kv_write_tokens": step_tokens, "batched": batched,
-            }
+        path = self._prefill_attn_path()
+        self._step_info = {
+            "kind": "prefill_chunk", "rows": len(ready),
+            "tokens": step_tokens,
+            "forwards": 1 if batched else len(ready),
+            # Each chunk's queries attend to its request's context so
+            # far (cached prefix + earlier chunks). The flash kernel
+            # streams ONLY the prefix pages (the chunk's own K/V is
+            # attended from VMEM before it ever leaves the chip); the
+            # XLA gather path re-reads the full written context —
+            # prefix AND the just-scattered suffix.
+            "kv_read_tokens": sum(
+                (s if path == "pallas" else e)
+                for (_r, _t, _b, s, e) in ready),
+            "kv_write_tokens": step_tokens, "batched": batched,
+        }
 
         # Same pipelining as the unchunked paths: read back the in-flight
         # burst and the previous prefill while these chunks execute.
@@ -2830,8 +2860,6 @@ class EngineCore:
         fused = False
         info_p = info_d = None
         dt_p = dt_d = 0.0
-        pc0 = self.prefill_chunks_total
-        df0 = self.decode_forward_steps_total
         try:
             t0 = time.perf_counter()
             self._do_prefill_step(plan)
@@ -2866,36 +2894,27 @@ class EngineCore:
                 # Degraded (capture aborted, or a leg dispatched
                 # nothing): issue whatever is still pending one by one.
                 self._drain_captured(cap)
-        # Wall-time attribution: the legs ran back to back; charge each
-        # to its own split only if it actually dispatched work.
-        if self.prefill_chunks_total > pc0:
-            self.prefill_time_total += dt_p
-            self.prefill_count += 1
-        if self.decode_forward_steps_total > df0:
-            self.decode_time_total += dt_d
-            self.decode_burst_count += 1
-        if self.step_recorder is not None:
-            if fused and info_p is not None and info_d is not None:
-                self._step_info = {
-                    "kind": "fused",
-                    "rows": info_p["rows"] + info_d["rows"],
-                    "tokens": info_p["tokens"] + info_d["tokens"],
-                    "forwards": info_p["forwards"] + info_d["forwards"],
-                    "kv_read_tokens": (info_p["kv_read_tokens"]
-                                       + info_d["kv_read_tokens"]),
-                    "kv_write_tokens": (info_p["kv_write_tokens"]
-                                        + info_d["kv_write_tokens"]),
-                    "batched": info_p.get("batched", False),
-                }  # _loop records it with the full step wall time
-            else:
-                # Degraded: record the legs as the individual step kinds
-                # they actually were, with their own wall times.
-                if info_p is not None:
-                    self._step_info = info_p
-                    self._record_step(dt_p)
-                if info_d is not None:
-                    self._step_info = info_d
-                    self._record_step(dt_d)
+        if fused and info_p is not None and info_d is not None:
+            self._step_info = {
+                "kind": "fused",
+                "rows": info_p["rows"] + info_d["rows"],
+                "tokens": info_p["tokens"] + info_d["tokens"],
+                "forwards": info_p["forwards"] + info_d["forwards"],
+                "kv_read_tokens": (info_p["kv_read_tokens"]
+                                   + info_d["kv_read_tokens"]),
+                "kv_write_tokens": (info_p["kv_write_tokens"]
+                                    + info_d["kv_write_tokens"]),
+                "batched": info_p.get("batched", False),
+            }  # _loop records it with the full step wall time
+        else:
+            # Degraded: record the legs as the individual step kinds
+            # they actually were, with their own wall times.
+            if info_p is not None:
+                self._step_info = info_p
+                self._record_step(dt_p)
+            if info_d is not None:
+                self._step_info = info_d
+                self._record_step(dt_d)
 
     def _flush_pending_prefills(self) -> None:
         """Read back and emit deferred prefill first tokens, in dispatch
@@ -2905,74 +2924,76 @@ class EngineCore:
             return
         pending, self._pending_prefills = self._pending_prefills, []
         keep: "list[dict]" = []
-        t0 = time.perf_counter()
-        for entry in pending:
-            sampled = entry["sampled"]
-            if isinstance(sampled, _FusedPlaceholder) and not sampled.ready:
-                # Captured for a fused dispatch that has not issued yet:
-                # the readback waits for the fused op. Unready entries
-                # are always the queue's tail (they were captured this
-                # step), so dispatch-order emission still holds.
-                keep.append(entry)
-                continue
-            req, seq, slot = entry["req"], entry["seq"], entry["slot"]
-            row_i = entry.get("row", 0)  # batched prefills: row per req
-            try:
-                s_arr, lp_arr, top_lp_arr, top_id_arr = (
-                    np.asarray(a)
-                    for a in jax.device_get(_unwrap_fused(sampled)))
-            except Exception:  # noqa: BLE001 - async device failure
-                # The deferred readback failed AFTER the dispatch
-                # succeeded: the request would otherwise hang with its
-                # slot leaked (the loop's error handler only covers the
-                # current action's req). Finish it with an error.
-                logger.exception(
-                    "Deferred prefill readback failed for %s",
-                    req.request_id)
+        steps = self._steps
+        with steps.phase("emit"):
+            for entry in pending:
+                sampled = entry["sampled"]
+                if (isinstance(sampled, _FusedPlaceholder)
+                        and not sampled.ready):
+                    # Captured for a fused dispatch that has not issued yet:
+                    # the readback waits for the fused op. Unready entries
+                    # are always the queue's tail (they were captured this
+                    # step), so dispatch-order emission still holds.
+                    keep.append(entry)
+                    continue
+                req, seq, slot = entry["req"], entry["seq"], entry["slot"]
+                row_i = entry.get("row", 0)  # batched prefills: row per req
+                try:
+                    with steps.phase("readback"):
+                        s_arr, lp_arr, top_lp_arr, top_id_arr = (
+                            np.asarray(a)
+                            for a in jax.device_get(_unwrap_fused(sampled)))
+                except Exception:  # noqa: BLE001 - async device failure
+                    # The deferred readback failed AFTER the dispatch
+                    # succeeded: the request would otherwise hang with its
+                    # slot leaked (the loop's error handler only covers the
+                    # current action's req). Finish it with an error.
+                    logger.exception(
+                        "Deferred prefill readback failed for %s",
+                        req.request_id)
+                    with self._lock:
+                        if self.scheduler.slots[slot] is seq:
+                            self.scheduler.finish(seq, "error")
+                    continue
                 with self._lock:
-                    if self.scheduler.slots[slot] is seq:
-                        self.scheduler.finish(seq, "error")
-                continue
-            with self._lock:
-                if self.scheduler.slots[slot] is not seq:
-                    continue  # aborted/finished before its first token
-            token = int(s_arr[row_i])
-            lp = None
-            if req.sampling.logprobs is not None:
-                k = min(req.sampling.logprobs, top_lp_arr.shape[1])
-                lp = {"logprob": float(lp_arr[row_i]),
-                      "top": [(int(top_id_arr[row_i, j]),
-                               float(top_lp_arr[row_i, j]))
-                              for j in range(k)]}
-            prior = req.output_token_ids
-            if prior and (req.sampling.presence_penalty
-                          or req.sampling.frequency_penalty):
-                # Resume after preemption with penalties active: rebuild
-                # the slot's count row from the carried-forward outputs
-                # instead of resetting it (the row may hold another
-                # request's counts). Rare path — one extra dispatch only
-                # when it matters.
-                row = np.zeros((self.model_config.vocab_size,), np.int32)
-                # prior outputs + the continuation token just sampled
-                # (the in-burst tokens0 count only runs for reset slots).
-                ids = np.clip(np.asarray(prior + [token], np.int64), 0,
-                              self.model_config.vocab_size - 1)
-                np.add.at(row, ids, 1)
-                self._dispatch("set_counts_row", {}, [np.int32(slot), row])
-                with self._lock:
-                    self._counts_reset.discard(slot)
-            else:
-                with self._lock:
-                    # Fresh output in this slot: its penalty counts reset
-                    # at the next burst (which also counts this token).
-                    self._counts_reset.add(slot)
-            self._emit_token(seq, token, lp)
-            # Decode position bookkeeping starts from the emitted tokens
-            # (a re-prefill after preemption carries prior outputs).
-            req.scheduled_steps = len(req.output_token_ids)
+                    if self.scheduler.slots[slot] is not seq:
+                        continue  # aborted/finished before its first token
+                token = int(s_arr[row_i])
+                lp = None
+                if req.sampling.logprobs is not None:
+                    k = min(req.sampling.logprobs, top_lp_arr.shape[1])
+                    lp = {"logprob": float(lp_arr[row_i]),
+                          "top": [(int(top_id_arr[row_i, j]),
+                                   float(top_lp_arr[row_i, j]))
+                                  for j in range(k)]}
+                prior = req.output_token_ids
+                if prior and (req.sampling.presence_penalty
+                              or req.sampling.frequency_penalty):
+                    # Resume after preemption with penalties active: rebuild
+                    # the slot's count row from the carried-forward outputs
+                    # instead of resetting it (the row may hold another
+                    # request's counts). Rare path — one extra dispatch only
+                    # when it matters.
+                    row = np.zeros((self.model_config.vocab_size,), np.int32)
+                    # prior outputs + the continuation token just sampled
+                    # (the in-burst tokens0 count only runs for reset slots).
+                    ids = np.clip(np.asarray(prior + [token], np.int64), 0,
+                                  self.model_config.vocab_size - 1)
+                    np.add.at(row, ids, 1)
+                    self._dispatch("set_counts_row", {}, [np.int32(slot), row])
+                    with self._lock:
+                        self._counts_reset.discard(slot)
+                else:
+                    with self._lock:
+                        # Fresh output in this slot: its penalty counts reset
+                        # at the next burst (which also counts this token).
+                        self._counts_reset.add(slot)
+                self._emit_token(seq, token, lp)
+                # Decode position bookkeeping starts from the emitted tokens
+                # (a re-prefill after preemption carries prior outputs).
+                req.scheduled_steps = len(req.output_token_ids)
         if keep:
             self._pending_prefills = keep + self._pending_prefills
-        self.flush_time_total += time.perf_counter() - t0
 
     def _cached_prefix_len(self, tokens: List[int],
                            adapter: str = "") -> int:
@@ -3135,16 +3156,15 @@ class EngineCore:
         self._flush_pending_burst()
         self._flush_pending_prefills()
         group_end = time.time()
-        if self.step_recorder is not None:
-            new_tokens = sum(
-                len(m["req"].all_token_ids) - m["cached"] for m in group)
-            self._step_info = {
-                "kind": "prefill", "rows": len(group),
-                "tokens": new_tokens, "forwards": max_spans,
-                "kv_read_tokens": sum(
-                    s for s_list in spans.values() for (s, _e) in s_list),
-                "kv_write_tokens": new_tokens, "batched": True,
-            }
+        new_tokens = sum(
+            len(m["req"].all_token_ids) - m["cached"] for m in group)
+        self._step_info = {
+            "kind": "prefill", "rows": len(group),
+            "tokens": new_tokens, "forwards": max_spans,
+            "kv_read_tokens": sum(
+                s for s_list in spans.values() for (s, _e) in s_list),
+            "kv_write_tokens": new_tokens, "batched": True,
+        }
         for m, sampled, row in finished:
             req_m = m["req"]
             if req_m.trace is not None:
@@ -3519,18 +3539,17 @@ class EngineCore:
                 mask_bits, mask_on,
             ])
         self.decode_forward_steps_total += K
-        if self.step_recorder is not None:
-            sched = sum(allows.get(s.req.request_id, 1) for s in active)
-            self._step_info = {
-                "kind": "decode_burst", "rows": len(active),
-                "tokens": sched, "forwards": K,
-                # Every scan step re-reads each live row's full context
-                # through paged attention (growing by one per step; the
-                # context0 snapshot is the roofline's lower bound).
-                "kv_read_tokens": K * int(
-                    sum(context0[s.slot] for s in active)),
-                "kv_write_tokens": sched,
-            }
+        sched = sum(allows.get(s.req.request_id, 1) for s in active)
+        self._step_info = {
+            "kind": "decode_burst", "rows": len(active),
+            "tokens": sched, "forwards": K,
+            # Every scan step re-reads each live row's full context
+            # through paged attention (growing by one per step; the
+            # context0 snapshot is the roofline's lower bound).
+            "kv_read_tokens": K * int(
+                sum(context0[s.slot] for s in active)),
+            "kv_write_tokens": sched,
+        }
         # Read back the PREVIOUS burst (overlaps this burst's execution).
         self._flush_pending_burst()
         self._pending_burst = {
@@ -3911,15 +3930,14 @@ class EngineCore:
             ])
         self.spec_verify_bursts_total += 1
         self.decode_forward_steps_total += 1
-        if self.step_recorder is not None:
-            sched = sum(allows.get(s.req.request_id, 1) for s in active)
-            self._step_info = {
-                "kind": "spec_verify", "rows": len(active),
-                "tokens": sched, "forwards": 1,
-                "kv_read_tokens": int(
-                    sum(context0[s.slot] for s in active)),
-                "kv_write_tokens": sched,
-            }
+        sched = sum(allows.get(s.req.request_id, 1) for s in active)
+        self._step_info = {
+            "kind": "spec_verify", "rows": len(active),
+            "tokens": sched, "forwards": 1,
+            "kv_read_tokens": int(
+                sum(context0[s.slot] for s in active)),
+            "kv_write_tokens": sched,
+        }
         self._pending_burst = {
             "out": outs, "active": active, "allows": allows,
             "spec": True, "drafts": drafts,
@@ -3937,14 +3955,21 @@ class EngineCore:
             # placeholder before returning.)
             return
         self._pending_burst = None
-        t0 = time.perf_counter()
-        sampled, lps, top_lps, top_idxs = (
-            np.asarray(a) for a in jax.device_get(_unwrap_fused(out))
-        )  # [B, K], [B, K], [B, K, LOGPROB_K] x2
-        self.flush_time_total += time.perf_counter() - t0
-        if pending.get("spec"):
-            self._flush_spec_burst(pending, sampled, lps, top_lps, top_idxs)
-            return
+        with self._steps.phase("readback"):
+            sampled, lps, top_lps, top_idxs = (
+                np.asarray(a) for a in jax.device_get(_unwrap_fused(out))
+            )  # [B, K], [B, K], [B, K, LOGPROB_K] x2
+        with self._steps.phase("emit"):
+            if pending.get("spec"):
+                self._flush_spec_burst(
+                    pending, sampled, lps, top_lps, top_idxs)
+            else:
+                self._emit_burst(pending, sampled, lps, top_lps, top_idxs)
+
+    def _emit_burst(self, pending, sampled, lps, top_lps,
+                    top_idxs) -> None:
+        """Emit a plain decode burst's tokens, each sequence as far as
+        it was allowed and still runs."""
         emitted_seqs = []
         for seq in pending["active"]:
             allow = pending["allows"].get(seq.req.request_id, 1)

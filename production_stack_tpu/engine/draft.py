@@ -162,9 +162,11 @@ class DraftModel:
                 mode="prefill_cached", adapter_ids=None,
                 last_token=last_idx,
             )
-            shaped = apply_fsm_mask(logits[:, 0], mask_bits, mask_on)
-            return (jnp.argmax(shaped, axis=-1).astype(jnp.int32), kv)
+            with jax.named_scope("sample"):
+                shaped = apply_fsm_mask(logits[:, 0], mask_bits, mask_on)
+                return (jnp.argmax(shaped, axis=-1).astype(jnp.int32), kv)
 
+        fwd.__name__ = "draft_forward"  # its name in traces and records
         return jax.jit(
             fwd, donate_argnums=(1,),
             out_shardings=(self._repl,
@@ -185,13 +187,15 @@ class DraftModel:
                     jnp.ones_like(context0), mode="decode",
                     adapter_ids=None,
                 )
-                nxt = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
+                with jax.named_scope("sample"):
+                    nxt = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
                 return (nxt, kv, s + 1), nxt
 
             (_, kv, _), out = jax.lax.scan(
                 body, (token0, kv, jnp.int32(0)), slot_mat.T, length=S)
             return out.T, kv
 
+        fwd.__name__ = f"draft_scan_k{S}"
         return jax.jit(
             fwd, donate_argnums=(1,),
             out_shardings=(self._repl,

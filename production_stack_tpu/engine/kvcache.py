@@ -196,10 +196,20 @@ class KVCacheManager:
         return self.allocator.num_free + self._evictable() >= needed
 
     def _evictable(self) -> int:
+        blocks = self.allocator.blocks
         return sum(
-            1 for _, bid in self.allocator.prefix_map.items()
-            if self.allocator.blocks[bid].ref_count == 0
+            1 for bid in self.allocator.prefix_map.values()
+            if blocks[bid].ref_count == 0
         )
+
+    def block_counts(self) -> Tuple[int, int, int]:
+        """(live, cached, free) blocks of the pool: held by a running or
+        prefilling sequence; held only by the prefix cache (evictable);
+        free. They sum to the pool's size. Callers hold the engine's
+        lock."""
+        free = self.allocator.num_free
+        cached = self._evictable()
+        return self.allocator.num_blocks - free - cached, cached, free
 
     def allocate_prompt(
         self, seq_id: str, tokens: List[int], adapter: str = "",

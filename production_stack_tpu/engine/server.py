@@ -913,7 +913,22 @@ class EngineServer:
             prompt_tokens=clock.prompt_tokens, tokens=clock.tokens,
         )
         queue_end = clock.prefill_start or now
-        trace.add_span("engine.queue", clock.arrival, queue_end, parent=root)
+        # Queue wait by cause: the steps of other requests that held the
+        # loop between arrival and this request's prefill, read off the
+        # step recorder's ring here, once per request and off the engine
+        # thread. The three parts sum to the span.
+        steps = self.core.step_recorder
+        behind = (steps.busy_between(clock.arrival, queue_end)
+                  if steps is not None
+                  else {"decode": 0.0, "prefill": 0.0, "steps": 0})
+        trace.add_span(
+            "engine.queue", clock.arrival, queue_end, parent=root,
+            behind_decode_s=round(behind["decode"], 6),
+            behind_prefill_s=round(behind["prefill"], 6),
+            behind_other_s=round(max(
+                0.0, queue_end - clock.arrival - behind["decode"]
+                - behind["prefill"]), 6),
+            steps_waited=behind["steps"])
         if clock.prefill_start:
             trace.add_span(
                 "engine.prefill", clock.prefill_start,
@@ -926,6 +941,12 @@ class EngineServer:
                 prefill_chunks=clock.prefill_chunks,
             )
         if clock.first_token:
+            if clock.prefill_end:
+                # How long the sampled first token sat on the device and
+                # in the loop before the next flush emitted it.
+                trace.add_span(
+                    "engine.first_token", clock.prefill_end,
+                    max(clock.first_token, clock.prefill_end), parent=root)
             decode_start = clock.prefill_end or clock.first_token
             trace.add_span(
                 "engine.decode", decode_start,
@@ -1676,17 +1697,24 @@ class EngineServer:
 
     # -- programmatic profiler capture (POST /debug/profile) ------------- #
 
-    def _run_profile_capture(self, out_dir: str, duration_s: float) -> dict:
+    def _run_profile_capture(self, out_dir: str, duration_s: float,
+                             python_tracer: bool = False) -> dict:
         """Blocking jax.profiler capture, run in an executor thread. The
         engine thread keeps stepping — that's the point: the trace shows
-        real serving steps, not an idle device. No-op friendly: platforms
-        without profiler support report the failure instead of
-        500ing."""
+        real serving steps, not an idle device. The Python tracer is off
+        unless asked for: under serving load it writes some hundred
+        thousand events a second, and a trace of that size comes back
+        without the device's plane (PERF.md, PR 24, finding 2); the
+        engine loop's own ``engine.*`` annotations say what the host did.
+        No-op friendly: platforms without profiler support report the
+        failure instead of 500ing."""
         import jax
 
         os.makedirs(out_dir, exist_ok=True)
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 1 if python_tracer else 0
         try:
-            jax.profiler.start_trace(out_dir)
+            jax.profiler.start_trace(out_dir, profiler_options=options)
         except Exception as e:  # noqa: BLE001 — backend-specific errors
             return {"ok": False, "error": f"profiler unavailable: {e}"}
         try:
@@ -1706,9 +1734,11 @@ class EngineServer:
 
     async def handle_debug_profile(self, request: web.Request) -> web.Response:
         """Time-bounded ``jax.profiler`` trace into the served artifact
-        dir. Body: ``{"duration_s": 2.0}`` (clamped to (0, 60]). One
-        capture at a time; a second request while one is running gets
-        409. Privileged: requires the deployment key when one is set."""
+        dir. Body: ``{"duration_s": 2.0, "python_tracer": false}``
+        (duration clamped to (0, 60]; the Python tracer is off by default,
+        see ``_run_profile_capture``). One capture at a time; a second
+        request while one is running gets 409. Privileged: requires the
+        deployment key when one is set."""
         body = await _json_body(request)
         try:
             duration_s = float(body.get("duration_s", 2.0))
@@ -1717,6 +1747,9 @@ class EngineServer:
         if not duration_s > 0:
             raise _bad_request("duration_s must be > 0")
         duration_s = min(duration_s, 60.0)
+        python_tracer = body.get("python_tracer", False)
+        if not isinstance(python_tracer, bool):
+            raise _bad_request("python_tracer must be true or false")
         if not self._profile_lock.acquire(blocking=False):
             return web.json_response(
                 {"error": {"message": "a profile capture is already running",
@@ -1727,12 +1760,14 @@ class EngineServer:
                         f"{time.strftime('%Y%m%d-%H%M%S')}")
             out_dir = os.path.join(self.profile_dir, run_name)
             result = await asyncio.get_running_loop().run_in_executor(
-                None, self._run_profile_capture, out_dir, duration_s)
+                None, self._run_profile_capture, out_dir, duration_s,
+                python_tracer)
         finally:
             self._profile_lock.release()
         status = 200 if result.get("ok") else 503
         return web.json_response({
             "duration_s": duration_s,
+            "python_tracer": python_tracer,
             "run": run_name,
             "artifact_dir": out_dir,
             "artifacts_url": "/debug/profile/artifacts",

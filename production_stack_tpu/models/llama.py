@@ -125,6 +125,7 @@ def _proj(h: jax.Array, p: Dict, name: str) -> jax.Array:
     return h @ w
 
 
+@jax.named_scope("lora")
 def _lora_delta(h, a, b, scaling, adapter_ids):
     """Per-sequence LoRA delta: h [B,T,Hd] @ A[sel] @ B[sel] * scale."""
     a_sel = a[adapter_ids]  # [B, Hd, R]
@@ -157,50 +158,58 @@ def _layer(
     scale = 1.0 / (D ** 0.5)
     k_pages, v_pages = kv
 
-    h = rms_norm(x, p["attn_norm"], cfg.rms_norm_eps)
-    q_flat = _proj(h, p, "wq")
-    v_flat = _proj(h, p, "wv")
-    if lora is not None:
-        q_flat = q_flat + _lora_delta(
-            h, lora["wq_a"], lora["wq_b"], lora_scaling, adapter_ids
-        )
-        v_flat = v_flat + _lora_delta(
-            h, lora["wv_a"], lora["wv_b"], lora_scaling, adapter_ids
-        )
-    q = q_flat.reshape(B, T, H, D)
-    k = _proj(h, p, "wk").reshape(B, T, KVH, D)
-    v = v_flat.reshape(B, T, KVH, D)
-    q = rope(q, positions, cfg.rope_theta)
-    k = rope(k, positions, cfg.rope_theta)
+    # The named scopes are what a profiler trace files the device's time
+    # under (docs/profiling.md): metadata only, no operation changes.
+    with jax.named_scope("attn_proj"):
+        h = rms_norm(x, p["attn_norm"], cfg.rms_norm_eps)
+        q_flat = _proj(h, p, "wq")
+        v_flat = _proj(h, p, "wv")
+        if lora is not None:
+            q_flat = q_flat + _lora_delta(
+                h, lora["wq_a"], lora["wq_b"], lora_scaling, adapter_ids
+            )
+            v_flat = v_flat + _lora_delta(
+                h, lora["wv_a"], lora["wv_b"], lora_scaling, adapter_ids
+            )
+        q = q_flat.reshape(B, T, H, D)
+        k = _proj(h, p, "wk").reshape(B, T, KVH, D)
+        v = v_flat.reshape(B, T, KVH, D)
+        q = rope(q, positions, cfg.rope_theta)
+        k = rope(k, positions, cfg.rope_theta)
 
     k_pages, v_pages = write_kv_pages(
         k_pages, v_pages, k, v, slot_mapping, layer)
 
-    if mode == "prefill":
-        attn = prefill_attention(q, k, v, scale=scale, seq_lens=seq_lens)
-    elif mode == "prefill_cached":
-        # Suffix prefill after a prefix-cache hit: attend over HBM pages
-        # (cached prefix + just-written suffix). The chunk's own fresh
-        # k/v ride along so the flash kernel can serve the suffix from
-        # VMEM and stream only the cached prefix pages.
-        attn = context_prefill_attention(
-            q, k_pages, v_pages, block_tables, positions, context_lens,
-            layer, scale=scale, k_new=k, v_new=v, suffix_lens=seq_lens,
-        )
-    else:
-        attn = paged_decode_attention(
-            q[:, 0], k_pages, v_pages, block_tables, context_lens, layer,
-            scale=scale,
-        )[:, None]
-    x = x + _proj(attn.reshape(B, T, H * D), p, "wo")
+    with jax.named_scope("attention"):
+        if mode == "prefill":
+            attn = prefill_attention(
+                q, k, v, scale=scale, seq_lens=seq_lens)
+        elif mode == "prefill_cached":
+            # Suffix prefill after a prefix-cache hit: attend over HBM
+            # pages (cached prefix + just-written suffix). The chunk's own
+            # fresh k/v ride along so the flash kernel can serve the
+            # suffix from VMEM and stream only the cached prefix pages.
+            attn = context_prefill_attention(
+                q, k_pages, v_pages, block_tables, positions, context_lens,
+                layer, scale=scale, k_new=k, v_new=v, suffix_lens=seq_lens,
+            )
+        else:
+            attn = paged_decode_attention(
+                q[:, 0], k_pages, v_pages, block_tables, context_lens,
+                layer, scale=scale,
+            )[:, None]
+    with jax.named_scope("attn_proj"):
+        x = x + _proj(attn.reshape(B, T, H * D), p, "wo")
 
-    h = rms_norm(x, p["mlp_norm"], cfg.rms_norm_eps)
-    gate = jax.nn.silu(
-        _proj(h, p, "w_gate").astype(jnp.float32)).astype(h.dtype)
-    x = x + _proj(gate * _proj(h, p, "w_up"), p, "w_down")
+    with jax.named_scope("mlp"):
+        h = rms_norm(x, p["mlp_norm"], cfg.rms_norm_eps)
+        gate = jax.nn.silu(
+            _proj(h, p, "w_gate").astype(jnp.float32)).astype(h.dtype)
+        x = x + _proj(gate * _proj(h, p, "w_up"), p, "w_down")
     return x, (k_pages, v_pages)
 
 
+@jax.named_scope("embed")
 def embed_tokens(params: Dict, cfg: ModelConfig, token_ids: jax.Array,
                  adapter_ids: jax.Array | None):
     """Shared forward preamble: input embeddings + LoRA leaf plumbing.
@@ -227,6 +236,7 @@ def embed_tokens(params: Dict, cfg: ModelConfig, token_ids: jax.Array,
     return x, lora_layers, lora_scaling, adapter_ids
 
 
+@jax.named_scope("head")
 def project_out(params: Dict, cfg: ModelConfig, x: jax.Array,
                 output_hidden: bool) -> jax.Array:
     """Shared forward tail: final norm, then hidden states or logits."""
@@ -318,5 +328,6 @@ def apply(
             params["layers"], length=L,
         )
     if last_token is not None:
-        x = jnp.take_along_axis(x, last_token[:, None, None], axis=1)
+        with jax.named_scope("head"):
+            x = jnp.take_along_axis(x, last_token[:, None, None], axis=1)
     return project_out(params, cfg, x, output_hidden), (k_all, v_all)

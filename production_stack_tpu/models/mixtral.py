@@ -86,30 +86,38 @@ def _layer(
     scale = 1.0 / (D ** 0.5)
     k_pages, v_pages = kv  # stacked [L, NB, bs, KVH, D]
 
-    h = rms_norm(x, p["attn_norm"], cfg.rms_norm_eps)
-    q = rope((h @ p["wq"]).reshape(B, T, H, D), positions, cfg.rope_theta)
-    k = rope((h @ p["wk"]).reshape(B, T, KVH, D), positions, cfg.rope_theta)
-    v = (h @ p["wv"]).reshape(B, T, KVH, D)
+    # Scope names as in llama._layer (docs/profiling.md): metadata only.
+    with jax.named_scope("attn_proj"):
+        h = rms_norm(x, p["attn_norm"], cfg.rms_norm_eps)
+        q = rope((h @ p["wq"]).reshape(B, T, H, D), positions,
+                 cfg.rope_theta)
+        k = rope((h @ p["wk"]).reshape(B, T, KVH, D), positions,
+                 cfg.rope_theta)
+        v = (h @ p["wv"]).reshape(B, T, KVH, D)
     k_pages, v_pages = write_kv_pages(
         k_pages, v_pages, k, v, slot_mapping, layer)
-    if mode == "prefill":
-        attn = prefill_attention(q, k, v, scale=scale, seq_lens=seq_lens)
-    elif mode == "prefill_cached":
-        # Suffix prefill after a prefix-cache hit: attend over HBM pages
-        # (cached prefix + just-written suffix).
-        attn = context_prefill_attention(
-            q, k_pages, v_pages, block_tables, positions, context_lens,
-            layer, scale=scale, k_new=k, v_new=v, suffix_lens=seq_lens,
-        )
-    else:
-        attn = paged_decode_attention(
-            q[:, 0], k_pages, v_pages, block_tables, context_lens, layer,
-            scale=scale,
-        )[:, None]
-    x = x + attn.reshape(B, T, H * D) @ p["wo"]
+    with jax.named_scope("attention"):
+        if mode == "prefill":
+            attn = prefill_attention(
+                q, k, v, scale=scale, seq_lens=seq_lens)
+        elif mode == "prefill_cached":
+            # Suffix prefill after a prefix-cache hit: attend over HBM
+            # pages (cached prefix + just-written suffix).
+            attn = context_prefill_attention(
+                q, k_pages, v_pages, block_tables, positions, context_lens,
+                layer, scale=scale, k_new=k, v_new=v, suffix_lens=seq_lens,
+            )
+        else:
+            attn = paged_decode_attention(
+                q[:, 0], k_pages, v_pages, block_tables, context_lens,
+                layer, scale=scale,
+            )[:, None]
+    with jax.named_scope("attn_proj"):
+        x = x + attn.reshape(B, T, H * D) @ p["wo"]
 
-    h = rms_norm(x, p["mlp_norm"], cfg.rms_norm_eps)
-    x = x + moe_mlp(cfg, p, h)
+    with jax.named_scope("mlp"):
+        h = rms_norm(x, p["mlp_norm"], cfg.rms_norm_eps)
+        x = x + moe_mlp(cfg, p, h)
     return x, (k_pages, v_pages)
 
 
@@ -121,7 +129,8 @@ def apply(
     last_token=None,
 ) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
     del adapter_ids  # LoRA slots are a Llama-family feature for now
-    x = params["embed"][token_ids].astype(cfg.jnp_dtype)
+    with jax.named_scope("embed"):
+        x = params["embed"][token_ids].astype(cfg.jnp_dtype)
     k_all, v_all = kv_pages
     layer_fn = functools.partial(
         _layer, cfg, mode,
@@ -142,12 +151,14 @@ def apply(
         scan_body, (x, k_all, v_all, jnp.int32(0)), params["layers"],
         length=L,
     )
-    if last_token is not None:
-        # Prefill sampling reads ONE position: slice before norm + head
-        # (positionwise ops commute with the slice; see llama.apply).
-        x = jnp.take_along_axis(x, last_token[:, None, None], axis=1)
-    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
-    if output_hidden:
-        return x.astype(jnp.float32), (k_all, v_all)
-    logits = (x @ params["lm_head"]).astype(jnp.float32)
-    return logits, (k_all, v_all)
+    with jax.named_scope("head"):
+        if last_token is not None:
+            # Prefill sampling reads ONE position: slice before norm +
+            # head (positionwise ops commute with the slice; see
+            # llama.apply).
+            x = jnp.take_along_axis(x, last_token[:, None, None], axis=1)
+        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+        if output_hidden:
+            return x.astype(jnp.float32), (k_all, v_all)
+        logits = (x @ params["lm_head"]).astype(jnp.float32)
+        return logits, (k_all, v_all)

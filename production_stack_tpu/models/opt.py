@@ -82,31 +82,37 @@ def _layer(
     scale = 1.0 / (D ** 0.5)
     k_pages, v_pages = kv  # stacked [L, NB, bs, KVH, D]
 
-    h = layer_norm(x, p["ln1_w"], p["ln1_b"])
-    q = (h @ p["wq"] + p["wq_b"]).reshape(B, T, H, D)
-    k = (h @ p["wk"] + p["wk_b"]).reshape(B, T, H, D)
-    v = (h @ p["wv"] + p["wv_b"]).reshape(B, T, H, D)
+    # Scope names as in llama._layer (docs/profiling.md): metadata only.
+    with jax.named_scope("attn_proj"):
+        h = layer_norm(x, p["ln1_w"], p["ln1_b"])
+        q = (h @ p["wq"] + p["wq_b"]).reshape(B, T, H, D)
+        k = (h @ p["wk"] + p["wk_b"]).reshape(B, T, H, D)
+        v = (h @ p["wv"] + p["wv_b"]).reshape(B, T, H, D)
     k_pages, v_pages = write_kv_pages(
         k_pages, v_pages, k, v, slot_mapping, layer)
-    if mode == "prefill":
-        attn = prefill_attention(q, k, v, scale=scale, seq_lens=seq_lens)
-    elif mode == "prefill_cached":
-        # Suffix prefill after a prefix-cache hit: attend over HBM pages
-        # (cached prefix + just-written suffix).
-        attn = context_prefill_attention(
-            q, k_pages, v_pages, block_tables, positions, context_lens,
-            layer, scale=scale, k_new=k, v_new=v, suffix_lens=seq_lens,
-        )
-    else:
-        attn = paged_decode_attention(
-            q[:, 0], k_pages, v_pages, block_tables, context_lens, layer,
-            scale=scale,
-        )[:, None]
-    x = x + attn.reshape(B, T, H * D) @ p["wo"] + p["wo_b"]
+    with jax.named_scope("attention"):
+        if mode == "prefill":
+            attn = prefill_attention(
+                q, k, v, scale=scale, seq_lens=seq_lens)
+        elif mode == "prefill_cached":
+            # Suffix prefill after a prefix-cache hit: attend over HBM
+            # pages (cached prefix + just-written suffix).
+            attn = context_prefill_attention(
+                q, k_pages, v_pages, block_tables, positions, context_lens,
+                layer, scale=scale, k_new=k, v_new=v, suffix_lens=seq_lens,
+            )
+        else:
+            attn = paged_decode_attention(
+                q[:, 0], k_pages, v_pages, block_tables, context_lens,
+                layer, scale=scale,
+            )[:, None]
+    with jax.named_scope("attn_proj"):
+        x = x + attn.reshape(B, T, H * D) @ p["wo"] + p["wo_b"]
 
-    h = layer_norm(x, p["ln2_w"], p["ln2_b"])
-    h = jax.nn.relu(h @ p["fc1"] + p["fc1_b"])
-    x = x + h @ p["fc2"] + p["fc2_b"]
+    with jax.named_scope("mlp"):
+        h = layer_norm(x, p["ln2_w"], p["ln2_b"])
+        h = jax.nn.relu(h @ p["fc1"] + p["fc1_b"])
+        x = x + h @ p["fc2"] + p["fc2_b"]
     return x, (k_pages, v_pages)
 
 
@@ -118,8 +124,10 @@ def apply(
     last_token=None,
 ) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
     del adapter_ids  # LoRA slots are a Llama-family feature for now
-    x = params["embed"][token_ids].astype(cfg.jnp_dtype)
-    x = x + params["pos_embed"][positions + POS_OFFSET].astype(cfg.jnp_dtype)
+    with jax.named_scope("embed"):
+        x = params["embed"][token_ids].astype(cfg.jnp_dtype)
+        x = x + params["pos_embed"][positions + POS_OFFSET].astype(
+            cfg.jnp_dtype)
     k_all, v_all = kv_pages
     layer_fn = functools.partial(
         _layer, cfg, mode,
@@ -140,12 +148,14 @@ def apply(
         scan_body, (x, k_all, v_all, jnp.int32(0)), params["layers"],
         length=L,
     )
-    if last_token is not None:
-        # Prefill sampling reads ONE position: slice before norm + head
-        # (positionwise ops commute with the slice; see llama.apply).
-        x = jnp.take_along_axis(x, last_token[:, None, None], axis=1)
-    x = layer_norm(x, params["final_ln_w"], params["final_ln_b"])
-    if output_hidden:
-        return x.astype(jnp.float32), (k_all, v_all)
-    logits = (x @ params["embed"].T).astype(jnp.float32)
-    return logits, (k_all, v_all)
+    with jax.named_scope("head"):
+        if last_token is not None:
+            # Prefill sampling reads ONE position: slice before norm +
+            # head (positionwise ops commute with the slice; see
+            # llama.apply).
+            x = jnp.take_along_axis(x, last_token[:, None, None], axis=1)
+        x = layer_norm(x, params["final_ln_w"], params["final_ln_b"])
+        if output_hidden:
+            return x.astype(jnp.float32), (k_all, v_all)
+        logits = (x @ params["embed"].T).astype(jnp.float32)
+        return logits, (k_all, v_all)

@@ -18,10 +18,20 @@ That is the same weights+KV traffic model behind
 recent step window vs the device HBM floor) is directly comparable to
 the profiled ``gap_vs_combined_floor``.
 
+The recorder also times the engine loop (``phase``): where the host
+spent each step — scheduling, building arrays, enqueueing, waiting for
+the device in ``device_get``, emitting tokens — goes into the step's
+record and, under the same names, into the host plane of any
+``jax.profiler`` trace (``engine.<phase>`` inside one ``engine.step`` per
+loop iteration), so a gap on the device can be read against what the
+host was doing, on the profiler's own clock.
+
 Everything here is stdlib-only and cheap: one dict append under a lock
-per engine step (steps are milliseconds to seconds of device time; the
-record is microseconds of host time — the recorder-overhead A/B test
-holds it to <1% tokens/s).
+per engine step and a dozen timed phases (steps are milliseconds to
+seconds of device time; the record is microseconds of host time — the
+recorder-overhead A/B test holds it to <1% tokens/s). JAX is imported
+only when the first phase is annotated, and its absence makes the
+annotations no-ops: the router imports this package.
 """
 
 from __future__ import annotations
@@ -38,6 +48,16 @@ from typing import Dict, List, Optional
 # lands.
 STEP_KINDS = ("prefill", "prefill_chunk", "decode_burst", "spec_verify",
               "fused")
+
+# What a waiting request is behind while a step of each kind holds the
+# loop (``busy_between``; a fused step carries a decode burst).
+KIND_CLASS = {"prefill": "prefill", "prefill_chunk": "prefill",
+              "decode_burst": "decode", "spec_verify": "decode",
+              "fused": "decode"}
+
+# Phases of the engine loop, in the order a step meets them. The first two
+# lie before a step's start (in ``gap_before_s``), the rest inside it.
+PHASES = ("idle_wait", "schedule", "build", "enqueue", "readback", "emit")
 
 # Published peaks of one device, keyed by JAX's ``device_kind``: the one
 # table every roofline figure in the repo reads. Source: Google Cloud TPU
@@ -75,11 +95,101 @@ def device_hbm_bytes_per_s(device=None) -> Optional[float]:
             f"with its source") from None
 
 
+def _profiler_annotations():
+    """``jax.profiler``'s (TraceAnnotation, StepTraceAnnotation), or
+    (None, None) where JAX is not installed: the phases are then timed
+    and not annotated."""
+    try:
+        from jax.profiler import StepTraceAnnotation, TraceAnnotation
+    except ImportError:
+        return None, None
+    return TraceAnnotation, StepTraceAnnotation
+
+
+class _Phase:
+    """One timed interval of the loop (``StepRecorder.phase``). Phases
+    nest, and a phase's time is its own: what the phases inside it took
+    is theirs, so the phases of a step add up to no more than its wall
+    time."""
+
+    __slots__ = ("rec", "name", "t0", "inner", "into", "ann")
+
+    def __init__(self, rec: "StepRecorder", name: str):
+        self.rec, self.name = rec, name
+
+    def __enter__(self):
+        rec = self.rec
+        self.inner = 0.0
+        self.into = self.ann = None
+        if threading.get_ident() == rec._loop_thread:
+            self.into = rec._into
+            rec._open.append(self)
+            if rec._annotate is not None:
+                self.ann = rec._annotate("engine." + self.name)
+                self.ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        took = time.perf_counter() - self.t0
+        rec, name = self.rec, self.name
+        own = took - self.inner
+        if self.ann is not None:
+            self.ann.__exit__(*exc)
+        if self.into is not None:
+            rec._open.pop()
+            if rec._open:
+                rec._open[-1].inner += took
+            self.into[name] = self.into.get(name, 0.0) + own
+        with rec._lock:
+            total = rec._phase_totals.setdefault(name, [0.0, 0])
+            total[0] += own
+            total[1] += 1
+        return False
+
+
+class _LoopStep:
+    """One iteration of the engine loop (``StepRecorder.loop_step``)."""
+
+    __slots__ = ("rec", "annotate", "ann")
+
+    def __init__(self, rec: "StepRecorder", annotate: bool):
+        self.rec, self.annotate, self.ann = rec, annotate, None
+
+    def __enter__(self):
+        rec = self.rec
+        rec._loop_thread = threading.get_ident()
+        rec._start = None
+        rec._into = rec._gap
+        rec._notes = {}
+        rec._programs = []
+        rec._annotate = None
+        if self.annotate:
+            if rec._annotations is None:
+                rec._annotations = _profiler_annotations()
+            rec._annotate, step_annotation = rec._annotations
+            if step_annotation is not None:
+                self.ann = step_annotation(
+                    "engine.step", step_num=rec.recorded_total + 1)
+                self.ann.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        if self.ann is not None:
+            self.ann.__exit__(*exc)
+        return False
+
+
 class StepRecorder:
-    """Bounded ring buffer of per-step records plus per-kind rollups.
+    """Bounded ring buffer of per-step records plus per-kind rollups, and
+    the one clock of the engine loop (``loop_step``, ``phase``, ``start``,
+    ``record``).
 
     Thread-safe: the engine thread records, ``/metrics`` and
-    ``/debug/steps`` read concurrently from the event loop.
+    ``/debug/steps`` read concurrently from the event loop. The phases of
+    a step are the engine thread's; a ``phase`` entered on another thread
+    (an embedding or an adapter load dispatching under the step lock)
+    counts in the totals only.
     """
 
     def __init__(
@@ -106,13 +216,61 @@ class StepRecorder:
         self._kinds: Dict[str, List[float]] = {
             k: [0.0, 0, 0, 0] for k in STEP_KINDS}
         self.recorded_total = 0
+        # phase -> [own seconds, entries], kept whether or not the steps
+        # go to the ring.
+        self._phase_totals: Dict[str, List[float]] = {
+            p: [0.0, 0] for p in PHASES}
+        # The loop's clock (engine thread only). ``_into`` is where a
+        # phase entered now adds its time: the gap before a step until
+        # ``start``, the step's own phases from there to ``record``.
+        self._loop_thread: Optional[int] = None
+        self._annotations = None  # jax.profiler's classes, found once
+        self._annotate = None
+        self._open: List[_Phase] = []
+        self._gap: Dict[str, float] = {}
+        self._into: Dict[str, float] = self._gap
+        self._start: Optional[tuple] = None
+        self._notes: dict = {}
+        self._programs: List[str] = []
+        self._last_end: Optional[float] = None  # perf_counter
+
+    # -- the loop's clock -------------------------------------------------
+
+    def loop_step(self, annotate: bool = True) -> _LoopStep:
+        """Context manager around one iteration of the engine loop. With
+        ``annotate`` the iteration is an ``engine.step`` in a profiler
+        trace, numbered like the record it will make, and each phase
+        inside it an ``engine.<phase>``."""
+        return _LoopStep(self, annotate)
+
+    def phase(self, name: str) -> _Phase:
+        """Context manager that times ``name`` (one of ``PHASES``) into
+        the totals, into the current step's record, and into the
+        profiler's trace."""
+        return _Phase(self, name)
+
+    def start(self) -> None:
+        """The step proper starts here: scheduling is done, what follows
+        until ``record`` is the step's wall time."""
+        self._into = {}
+        self._start = (time.perf_counter(), time.time(), self._into)
+
+    def note(self, **fields) -> None:
+        """Fields of the record the current iteration will make
+        (``waiting``, ``running``, the pool counts)."""
+        self._notes.update(fields)
+
+    def note_program(self, name: str) -> None:
+        """A step program dispatched in this iteration."""
+        if name not in self._programs:
+            self._programs.append(name)
 
     # -- recording --------------------------------------------------------
 
     def record(
         self,
         kind: str,
-        wall_s: float,
+        wall_s: Optional[float] = None,
         *,
         rows: int = 0,
         tokens: int = 0,
@@ -120,28 +278,60 @@ class StepRecorder:
         kv_read_tokens: int = 0,
         kv_write_tokens: int = 0,
         batched: bool = False,
-    ) -> dict:
-        """Append one step record; returns it (tests inspect the shape)."""
+        ring: bool = True,
+    ) -> Optional[dict]:
+        """Append one step record; returns it (tests inspect the shape).
+        Without ``wall_s`` the record is the step opened by ``start``: its
+        wall time runs from there to now, and it carries the phases and
+        the gap before it. With ``wall_s`` the record stands alone.
+        ``ring=False`` keeps the rollups and makes no record."""
+        now_perf, now = time.perf_counter(), time.time()
+        phases: Dict[str, float] = {}
+        gap: Dict[str, float] = {}
+        notes: dict = {}
+        gap_before = 0.0
+        if wall_s is None:
+            start_perf, start_unix, phases = self._start
+            wall_s = now_perf - start_perf
+            gap, self._gap = self._gap, {}
+            notes = dict(self._notes, program="+".join(self._programs))
+            if self._last_end is not None:
+                gap_before = start_perf - self._last_end
+            self._last_end = now_perf
+        else:
+            start_unix = now - wall_s
+        self._start = None
+        self._into = self._gap
         hbm_bytes = (
             forwards * self.param_bytes
             + (kv_read_tokens + kv_write_tokens) * self.kv_token_bytes
         )
+        rec = None
         with self._lock:
-            self.recorded_total += 1
-            rec = {
-                "step": self.recorded_total,
-                "ts_unix": time.time(),
-                "kind": kind,
-                "wall_s": round(wall_s, 6),
-                "rows": rows,
-                "tokens": tokens,
-                "forwards": forwards,
-                "kv_read_tokens": kv_read_tokens,
-                "kv_write_tokens": kv_write_tokens,
-                "hbm_bytes": hbm_bytes,
-                "batched": batched,
-            }
-            self._ring.append(rec)
+            if ring:
+                self.recorded_total += 1
+                rec = {
+                    "step": self.recorded_total,
+                    "ts_unix": now,
+                    "kind": kind,
+                    "wall_s": round(wall_s, 6),
+                    "rows": rows,
+                    "tokens": tokens,
+                    "forwards": forwards,
+                    "kv_read_tokens": kv_read_tokens,
+                    "kv_write_tokens": kv_write_tokens,
+                    "hbm_bytes": hbm_bytes,
+                    "batched": batched,
+                    "start_unix": start_unix,
+                    "end_unix": now,
+                    "phases": {k: round(v, 6) for k, v in phases.items()},
+                    "gap_before_s": round(gap_before, 6),
+                    "gap_phases": {k: round(v, 6) for k, v in gap.items()},
+                    "program": "", "waiting": 0, "running": 0,
+                    "kv_blocks_live": 0, "kv_blocks_cached": 0,
+                    "kv_blocks_free": 0, **notes,
+                }
+                self._ring.append(rec)
             agg = self._kinds.setdefault(kind, [0.0, 0, 0, 0])
             agg[0] += wall_s
             agg[1] += 1
@@ -174,6 +364,31 @@ class StepRecorder:
                     "hbm_bytes": v[3]}
                 for k, v in self._kinds.items()
             }
+
+    def phase_stats(self) -> Dict[str, dict]:
+        """Lifetime seconds and entries of each phase (its own time: the
+        phases nested in it are counted under their names)."""
+        with self._lock:
+            return {k: {"seconds": v[0], "count": v[1]}
+                    for k, v in self._phase_totals.items()}
+
+    def busy_between(self, t0: float, t1: float) -> dict:
+        """Seconds of ``[t0, t1]`` (unix) during which a step that ended
+        inside it held the loop, by ``KIND_CLASS``, and how many such
+        steps: what a request that arrived at ``t0`` and started its
+        prefill at ``t1`` stood behind. Its own prefill step ends after
+        ``t1`` and is left out. Only as far back as the ring reaches."""
+        out = {"decode": 0.0, "prefill": 0.0, "steps": 0}
+        with self._lock:
+            for rec in reversed(self._ring):
+                if rec["end_unix"] <= t0:
+                    break
+                cls = KIND_CLASS.get(rec["kind"])
+                if rec["end_unix"] > t1 or cls is None:
+                    continue
+                out[cls] += rec["end_unix"] - max(rec["start_unix"], t0)
+                out["steps"] += 1
+        return out
 
     def bandwidth_utilization(
             self, now: Optional[float] = None) -> Optional[float]:
@@ -209,4 +424,5 @@ class StepRecorder:
             "window_s": self.window_s,
             "bandwidth_utilization": self.bandwidth_utilization(),
             "kinds": self.kind_stats(),
+            "phases": self.phase_stats(),
         }

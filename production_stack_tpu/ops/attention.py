@@ -358,6 +358,7 @@ def _context_prefill_reference(
     return out.reshape(B, T, H, D)
 
 
+@jax.named_scope("kv_write")
 def write_kv_pages(
     k_pages,  # [L, NB, bs, KVH, D] stacked pages (or (data, scales))
     v_pages,  # [L, NB, bs, KVH, D] (or (data, scales))

@@ -10,6 +10,7 @@ import json
 import os
 import re
 
+import pytest
 import yaml
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -246,3 +247,90 @@ def test_emitted_metrics_are_documented():
     assert not missing, (
         f"metrics missing from observability/README.md: {missing}")
     assert len(mod.emitted_metrics()) > 40  # scanner actually scanned
+
+
+def test_queue_wait_by_cause_and_first_token_span():
+    """Concurrent requests on a real engine server: every request's
+    ``engine.queue`` span splits into what it stood behind (decode steps,
+    prefill steps of other requests, the rest), the parts summing to the
+    span; ``engine.first_token`` runs from the end of prefill to the
+    first emitted token; and ``POST /debug/profile`` takes the Python
+    tracer as an option that is off by default."""
+    import asyncio
+
+    import aiohttp
+
+    from production_stack_tpu.engine.config import EngineConfig
+    from production_stack_tpu.engine.server import (
+        EngineServer,
+        run_engine_server,
+    )
+
+    server = EngineServer(EngineConfig(
+        model="tiny-llama", max_model_len=256, max_num_seqs=4,
+        num_blocks=128, max_loras=0))
+    rids = [f"cause-{i}" for i in range(6)]
+
+    async def run():
+        runner = await run_engine_server(server, "127.0.0.1", 0)
+        port = list(runner.sites)[0]._server.sockets[0].getsockname()[1]
+        url = f"http://127.0.0.1:{port}"
+        try:
+            async with aiohttp.ClientSession() as s:
+                async def one(rid, delay):
+                    await asyncio.sleep(delay)
+                    async with s.post(url + "/v1/completions", json={
+                        "model": "tiny-llama", "prompt": f"hello {rid} " * 3,
+                        "max_tokens": 24, "temperature": 0.0,
+                        "ignore_eos": True,
+                    }, headers={"X-Request-Id": rid}) as r:
+                        assert r.status == 200
+                        await r.read()
+
+                # The later ones arrive while the earlier ones decode.
+                await asyncio.gather(*[one(rid, 0.15 * i)
+                                       for i, rid in enumerate(rids)])
+                traces = []
+                for rid in rids:
+                    async with s.get(url + f"/debug/traces/{rid}") as r:
+                        assert r.status == 200
+                        traces.append(await r.json())
+                async with s.post(url + "/debug/profile", json={
+                        "duration_s": 0.2, "python_tracer": "yes"}) as r:
+                    assert r.status == 400
+                async with s.post(url + "/debug/profile",
+                                  json={"duration_s": 0.2}) as r:
+                    profile = await r.json()
+                return traces, profile
+        finally:
+            await runner.cleanup()
+
+    try:
+        traces, profile = asyncio.run(run())
+    finally:
+        server.core.stop()
+
+    assert profile["python_tracer"] is False
+    behind_something = 0
+    for trace in traces:
+        spans = {sp["name"]: sp for sp in trace["spans"]}
+        queue = spans["engine.queue"]
+        attrs = queue["attributes"]
+        parts = (attrs["behind_decode_s"], attrs["behind_prefill_s"],
+                 attrs["behind_other_s"])
+        assert all(p >= 0.0 for p in parts)
+        assert sum(parts) == pytest.approx(queue["duration_s"], abs=1e-3)
+        assert attrs["steps_waited"] >= 0
+        if attrs["behind_decode_s"] + attrs["behind_prefill_s"] > 0:
+            assert attrs["steps_waited"] >= 1
+            behind_something += 1
+        first = spans["engine.first_token"]
+        prefill = spans["engine.prefill"]
+        assert first["parent_span_id"] == spans["engine.request"]["span_id"]
+        assert prefill["end_unix"] <= first["start_unix"] + 1e-6
+        assert first["start_unix"] <= first["end_unix"]
+        # The decode span keeps its start: the end of prefill.
+        assert spans["engine.decode"]["start_unix"] == pytest.approx(
+            first["start_unix"], abs=1e-6)
+    # Requests that arrived while others decoded waited behind them.
+    assert behind_something >= 1

@@ -1,7 +1,8 @@
 """Step flight recorder: roofline math and ring semantics, the
 ``/debug/steps`` surface, engine integration (records appear with the
 right kinds during real generation), the recorder-overhead A/B bound,
-and the hermetic prefill-profile artifact schema."""
+the loop's phases and the fields they add to a record, and the hermetic
+prefill-profile artifact schema."""
 
 import asyncio
 import json
@@ -311,6 +312,232 @@ def test_recorder_overhead_under_one_percent():
     finally:
         eng.step_recorder = recorder
         eng.stop()
+
+
+# ---------------------------------------------------------------------------
+# The loop's one clock: phases, the new record fields, stats() from totals
+# ---------------------------------------------------------------------------
+
+OLD_STATS_KEYS = ("prefill_time_total", "decode_time_total",
+                  "flush_time_total", "dispatch_enqueue_s", "prefill_count",
+                  "decode_burst_count", "dispatch_count_total")
+
+
+def test_phases_nest_and_each_keeps_its_own_time():
+    rec = StepRecorder(capacity=4)
+    with rec.loop_step(annotate=False):
+        with rec.phase("schedule"):
+            with rec.phase("idle_wait"):
+                time.sleep(0.02)
+        rec.note(waiting=3, running=1)
+        rec.start()
+        with rec.phase("build"):
+            time.sleep(0.01)
+            with rec.phase("enqueue"):
+                time.sleep(0.02)
+            rec.note_program("decode_k8")
+            rec.note_program("decode_k8")
+        r = rec.record("decode_burst", rows=1)
+    # The step's phases are those after start(); what came before is the
+    # gap. A phase's time excludes the phases inside it.
+    assert set(r["phases"]) == {"build", "enqueue"}
+    assert set(r["gap_phases"]) == {"schedule", "idle_wait"}
+    assert r["phases"]["enqueue"] >= 0.02 > r["phases"]["build"] >= 0.01
+    assert r["gap_phases"]["idle_wait"] >= 0.02 > r["gap_phases"]["schedule"]
+    assert sum(r["phases"].values()) <= r["wall_s"] + 1e-3
+    assert r["wall_s"] == pytest.approx(sum(r["phases"].values()), abs=2e-3)
+    assert r["start_unix"] <= r["end_unix"] == r["ts_unix"]
+    assert r["end_unix"] - r["start_unix"] == pytest.approx(r["wall_s"],
+                                                            abs=2e-3)
+    assert (r["program"], r["waiting"], r["running"]) == ("decode_k8", 3, 1)
+    totals = rec.phase_stats()
+    assert totals["enqueue"]["count"] == 1
+    assert totals["enqueue"]["seconds"] == pytest.approx(
+        r["phases"]["enqueue"], abs=1e-5)
+    assert totals["readback"] == {"seconds": 0.0, "count": 0}
+    assert rec.summary()["phases"] == totals
+    # A record made with its own wall time stands alone.
+    alone = rec.record("prefill", 0.5)
+    assert alone["phases"] == {} and alone["program"] == ""
+    assert alone["end_unix"] - alone["start_unix"] == pytest.approx(0.5)
+
+
+def test_phase_from_another_thread_counts_in_the_totals_only():
+    import threading
+
+    rec = StepRecorder(capacity=4)
+    with rec.loop_step(annotate=False):
+        rec.start()
+        def dispatch_elsewhere():
+            with rec.phase("enqueue"):
+                pass
+
+        other = threading.Thread(target=dispatch_elsewhere)
+        other.start()
+        other.join(timeout=10)
+        assert not other.is_alive()
+        r = rec.record("decode_burst")
+    assert r["phases"] == {}
+    assert rec.phase_stats()["enqueue"]["count"] == 1
+
+
+def test_busy_between_clips_to_the_interval_and_skips_the_own_step():
+    rec = StepRecorder(capacity=8)
+    now = time.time()
+    ring = [("decode_burst", now - 1.0, now - 0.9),
+            ("prefill", now - 0.8, now - 0.7),
+            ("spec_verify", now - 0.6, now - 0.5),
+            ("experimental", now - 0.45, now - 0.42),
+            ("prefill", now - 0.4, now - 0.1)]  # the request's own step
+    for kind, start, end in ring:
+        r = rec.record(kind, end - start)
+        r["start_unix"], r["end_unix"] = start, end
+    # Arrived in the middle of the first burst, started prefill inside the
+    # last record.
+    got = rec.busy_between(now - 0.95, now - 0.3)
+    assert got["decode"] == pytest.approx(0.05 + 0.1)
+    assert got["prefill"] == pytest.approx(0.1)
+    assert got["steps"] == 3
+    assert rec.busy_between(now - 0.05, now) == {
+        "decode": 0.0, "prefill": 0.0, "steps": 0}
+
+
+def test_engine_records_phases_programs_and_pool_counts():
+    eng = _make_engine()
+    try:
+        _generate(eng, "ph-1", 20)
+        _generate(eng, "ph-2", 20)
+        rec = eng.step_recorder
+        records = rec.snapshot()
+        assert {r["kind"] for r in records} >= {"prefill", "decode_burst"}
+        for r in records:
+            assert r["start_unix"] <= r["end_unix"]
+            # Phases are timed inside the step and exclude one another.
+            assert sum(r["phases"].values()) <= r["wall_s"] + 1e-3
+            assert r["wall_s"] - sum(r["phases"].values()) <= 0.05
+            assert set(r["phases"]) <= {"build", "enqueue", "readback",
+                                        "emit"}
+            assert "schedule" in r["gap_phases"]
+            assert r["gap_before_s"] >= 0.0
+            assert (r["kv_blocks_live"] + r["kv_blocks_cached"]
+                    + r["kv_blocks_free"]) == eng.num_blocks == 96
+            assert r["program"]
+        by_kind = {r["kind"]: r["program"] for r in records}
+        assert by_kind["decode_burst"] == "decode_k8"
+        assert by_kind["prefill"].startswith("prefill")
+        assert by_kind["prefill"] != by_kind["decode_burst"]
+        # The second prompt repeats the first: its blocks were cached.
+        assert any(r["kv_blocks_cached"] > 0 for r in records)
+        assert any(r["kv_blocks_live"] > 0 and r["running"] > 0
+                   for r in records if r["kind"] == "decode_burst")
+        # Records follow one another on one clock.
+        ordered = sorted(records, key=lambda r: r["step"])
+        for a, b in zip(ordered, ordered[1:]):
+            assert a["end_unix"] <= b["start_unix"] + 1e-4
+    finally:
+        eng.stop()
+
+
+def _wait_idle(eng, timeout=30):
+    """Until the loop has settled its last burst: the final record of a
+    request is made after its last token is delivered."""
+    deadline = time.time() + timeout
+    while time.time() < deadline:
+        with eng._lock:
+            idle = (not eng.scheduler.has_work()
+                    and eng._pending_burst is None)
+        if idle:
+            time.sleep(0.2)  # the step in flight, if any, records
+            return
+        time.sleep(0.01)
+    raise TimeoutError("the engine loop did not go idle")
+
+
+def test_stats_keeps_its_keys_with_the_recorders_totals():
+    eng = _make_engine()
+    try:
+        _generate(eng, "st-1", 12)
+        _wait_idle(eng)
+        stats = eng.stats()
+        kinds = eng.step_recorder.kind_stats()
+        phases = eng.step_recorder.phase_stats()
+        for key in OLD_STATS_KEYS:
+            assert key in stats, key
+        assert stats["prefill_count"] == kinds["prefill"]["count"] == 1
+        assert stats["decode_burst_count"] == kinds["decode_burst"]["count"]
+        assert stats["decode_burst_count"] >= 2
+        assert stats["prefill_time_total"] == round(
+            kinds["prefill"]["wall_s"] + kinds["prefill_chunk"]["wall_s"], 3)
+        assert stats["decode_time_total"] == round(
+            kinds["decode_burst"]["wall_s"], 3)
+        assert stats["flush_time_total"] == round(
+            phases["readback"]["seconds"], 3)
+        assert stats["dispatch_enqueue_s"] == round(
+            phases["enqueue"]["seconds"], 3)
+        # One dispatch per step program call: the prefill and each burst.
+        assert stats["dispatch_count_total"] == phases["enqueue"]["count"] \
+            == stats["prefill_count"] + stats["decode_burst_count"]
+        assert stats["prefill_time_total"] > 0 < stats["decode_time_total"]
+    finally:
+        eng.stop()
+
+
+def test_recorder_off_keeps_the_totals_and_makes_no_record():
+    eng = _make_engine(step_recorder=False)
+    try:
+        _generate(eng, "off-1", 12)
+        assert eng.step_recorder is None
+        assert eng._steps.recorded_total == 0 and eng._steps.snapshot() == []
+        stats = eng.stats()
+        assert stats["prefill_count"] == 1
+        assert stats["decode_burst_count"] >= 2
+        assert stats["dispatch_count_total"] >= 3
+        assert stats["prefill_time_total"] > 0 < stats["decode_time_total"]
+        # Off means not annotated either: the profiler's classes were
+        # never looked up.
+        assert eng._steps._annotations is None
+    finally:
+        eng.stop()
+
+
+def test_debug_steps_shows_the_new_fields():
+    rec = StepRecorder(capacity=4)
+    with rec.loop_step(annotate=False):
+        rec.note(kv_blocks_live=2, kv_blocks_cached=1, kv_blocks_free=5)
+        rec.start()
+        with rec.phase("build"):
+            rec.note_program("prefill")
+            rec.note_program("prefill_cached")
+        rec.record("prefill", rows=1, tokens=8)
+    status, doc = _get_json(rec, "/debug/steps")
+    assert status == 200
+    assert set(doc["phases"]) >= {"idle_wait", "schedule", "build",
+                                  "enqueue", "readback", "emit"}
+    (r,) = doc["steps"]
+    for key in ("start_unix", "end_unix", "phases", "gap_before_s",
+                "gap_phases", "program", "waiting", "running",
+                "kv_blocks_live", "kv_blocks_cached", "kv_blocks_free"):
+        assert key in r, key
+    assert r["program"] == "prefill+prefill_cached"
+    assert (r["kv_blocks_live"], r["kv_blocks_cached"],
+            r["kv_blocks_free"]) == (2, 1, 5)
+
+
+def test_obs_imports_without_jax():
+    code = ("import sys\n"
+            "sys.modules['jax'] = None  # any import of it now fails\n"
+            "from production_stack_tpu.obs import debug, steps\n"
+            "rec = steps.StepRecorder()\n"
+            "with rec.loop_step(annotate=True):\n"
+            "    rec.start()\n"
+            "    with rec.phase('build'):\n"
+            "        pass\n"
+            "    assert rec.record('prefill')['phases'].keys() == {'build'}\n"
+            "print('ok')\n")
+    proc = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                          text=True, timeout=120, cwd=REPO_ROOT)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert proc.stdout.strip() == "ok"
 
 
 # ---------------------------------------------------------------------------
