@@ -6,6 +6,17 @@ import dataclasses
 from typing import Optional
 
 
+# The plain-prefill ladder's fine part (``EngineConfig.prefill_buckets``).
+# Below the chip's ridge a forward costs the read of the weights whatever
+# its width, so padding is free there and the powers of two stay; above it
+# device time is proportional to the padded tokens. The ridge of a v5e over
+# bf16 weights is 197e12 / 819e9 = 240 tokens, hence 256; other chips and
+# weight widths move it, not the direction. The step is the MXU's tile and
+# the lane width: a narrower step buys no device time.
+PLAIN_PREFILL_FINE_FROM = 256
+PLAIN_PREFILL_STEP = 128
+
+
 @dataclasses.dataclass
 class EngineConfig:
     model: str = "tiny-llama"
@@ -235,6 +246,13 @@ class EngineConfig:
             return self.prefill_chunk_size
         return self.max_model_len
 
+    @property
+    def max_prefill_span(self) -> int:
+        """The most tokens one prefill dispatch carries: the chunk, or the
+        whole context where chunking is off."""
+        return min(self.prefill_chunk_size or self.max_model_len,
+                   self.max_model_len)
+
     def chunk_tokens(self) -> int:
         """Per-chunk token count: the largest *already-compiled* prefill
         bucket that fits the budget. Warmup caps buckets at
@@ -250,17 +268,27 @@ class EngineConfig:
                 best = b
         return best
 
-    def prefill_buckets(self) -> "list[int]":
+    def prefill_buckets(self, plain: bool = False) -> "list[int]":
+        """The widths a prefill span is padded to. Every program pads to
+        the powers of two from ``min_prefill_bucket`` to ``max_model_len``;
+        the ``plain`` (uncached, context == span) program also gets the
+        multiples of ``PLAIN_PREFILL_STEP`` between
+        ``PLAIN_PREFILL_FINE_FROM`` and the chunk, where it pays for its
+        padding (see the constants)."""
         buckets = []
         b = self.min_prefill_bucket
         while b < self.max_model_len:
             buckets.append(b)
             b *= 2
         buckets.append(self.max_model_len)
+        if plain:
+            fine = range(PLAIN_PREFILL_FINE_FROM + PLAIN_PREFILL_STEP,
+                         self.max_prefill_span, PLAIN_PREFILL_STEP)
+            buckets = sorted(set(buckets).union(fine))
         return buckets
 
-    def bucket_for(self, length: int) -> int:
-        for b in self.prefill_buckets():
+    def bucket_for(self, length: int, plain: bool = False) -> int:
+        for b in self.prefill_buckets(plain):
             if length <= b:
                 return b
         raise ValueError(

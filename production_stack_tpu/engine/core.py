@@ -490,6 +490,9 @@ class EngineCore:
         # -- counters (exported via /metrics) ------------------------------
         self.prompt_tokens_total = 0
         self.cached_tokens_total = 0  # prompt tokens skipped via prefix cache
+        # Token positions the prefill programs computed on: spans padded to
+        # their bucket (and batched groups to [prefill_batch, chunk]).
+        self.prefill_padded_tokens_total = 0
         self.generation_tokens_total = 0
         self.requests_finished_total = 0
         self.step_count = 0
@@ -1233,7 +1236,11 @@ class EngineCore:
         if name == "prefill":
             fn = (self._prefill_cached_fn if static["cached"]
                   else self._prefill_fn)
-            self._steps.note_program(fn.__name__)
+            # arrays[0] is the [rows, bucket] token array: what the
+            # program computes on, padding included.
+            self.prefill_padded_tokens_total += arrays[0].size
+            self._steps.note_program(fn.__name__,
+                                     padded_tokens=arrays[0].size)
             out, self.kv = fn(self.params, self.kv, *arrays)
             return out
         if name == "decode":
@@ -1814,15 +1821,12 @@ class EngineCore:
         cfg = self.config
         t0 = time.time()
         with self._step_lock:
-            buckets = cfg.prefill_buckets()
-            if cfg.prefill_chunk_size:
-                buckets = [
-                    b for b in buckets
-                    if b <= cfg.bucket_for(
-                        min(cfg.prefill_chunk_size, cfg.max_model_len))
-                ]
+            top = cfg.bucket_for(cfg.max_prefill_span)
+            cached_buckets = set(cfg.prefill_buckets())
             n_prefill = 0
-            for bucket in buckets:
+            for bucket in cfg.prefill_buckets(plain=True):
+                if bucket > top:
+                    break
                 blocks_needed = (bucket + cfg.block_size - 1) // cfg.block_size
                 tight = 4
                 while tight < blocks_needed:
@@ -1845,13 +1849,16 @@ class EngineCore:
                         np.zeros((1, self._mask_row_bytes), np.uint8),
                         np.zeros((1,), bool))
                 # Plain prefill only ever sees context == span -> one tight
-                # table width per bucket.
+                # table width per bucket, so its ladder can afford the finer
+                # rungs of prefill_buckets(plain=True).
                 _, self.kv = self._prefill_fn(
                     self.params, self.kv, token_arr, positions,
                     slot_mapping, np.zeros((1, tight), np.int32),
                     context_lens, seq_lens, adapter_ids, *samp,
                 )
                 n_prefill += 1
+                if bucket not in cached_buckets:
+                    continue
                 # Cached prefill: context (and so the table bucket) can be
                 # anything >= the span; compile every reachable width.
                 maxb = tight
@@ -2346,6 +2353,7 @@ class EngineCore:
             "prefix_cache_queries": alloc.prefix_queries,
             "prompt_tokens_total": self.prompt_tokens_total,
             "cached_tokens_total": self.cached_tokens_total,
+            "prefill_padded_tokens_total": self.prefill_padded_tokens_total,
             "generation_tokens_total": self.generation_tokens_total,
             "offload": self.offload.stats() if self.offload else None,
             # Page residency split: HBM pages currently allocated vs
@@ -3264,7 +3272,7 @@ class EngineCore:
         attention over the block table sees the full prefix."""
         cfg = self.config
         take = end - start
-        bucket = cfg.bucket_for(take)
+        bucket = cfg.bucket_for(take, plain=start == 0)
         # Bucket the block-table width (power of two, min 4) so
         # cached-prefill attention cost scales with the real context, not
         # max_model_len — and so warmup() can precompile every variant.

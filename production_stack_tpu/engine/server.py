@@ -2473,6 +2473,9 @@ class EngineServer:
             f"tpu:engine_draining{{{labels}}} {int(self.draining)}",
             "# TYPE tpu:cached_prompt_tokens counter",
             f"tpu:cached_prompt_tokens_total{{{labels}}} {s['cached_tokens_total']}",
+            "# TYPE tpu:prefill_padded_tokens counter",
+            f"tpu:prefill_padded_tokens_total{{{labels}}} "
+            f"{s['prefill_padded_tokens_total']}",
             # Disaggregated-prefill KV handoff (the NIXL-pipe equivalent).
             "# TYPE tpu:kv_transfer_tx_bytes counter",
             f"tpu:kv_transfer_tx_bytes_total{{{labels}}} {self.kv_transfer_tx_bytes}",

@@ -260,10 +260,16 @@ class StepRecorder:
         (``waiting``, ``running``, the pool counts)."""
         self._notes.update(fields)
 
-    def note_program(self, name: str) -> None:
-        """A step program dispatched in this iteration."""
+    def note_program(self, name: str, padded_tokens: int = 0) -> None:
+        """A step program dispatched in this iteration, and for a prefill
+        program the token positions it computes on (its rows x bucket,
+        padding included: ``padded_tokens`` of the record, beside the
+        real ``tokens``)."""
         if name not in self._programs:
             self._programs.append(name)
+        if padded_tokens:
+            self._notes["padded_tokens"] = (
+                self._notes.get("padded_tokens", 0) + padded_tokens)
 
     # -- recording --------------------------------------------------------
 
@@ -327,7 +333,8 @@ class StepRecorder:
                     "phases": {k: round(v, 6) for k, v in phases.items()},
                     "gap_before_s": round(gap_before, 6),
                     "gap_phases": {k: round(v, 6) for k, v in gap.items()},
-                    "program": "", "waiting": 0, "running": 0,
+                    "program": "", "padded_tokens": 0,
+                    "waiting": 0, "running": 0,
                     "kv_blocks_live": 0, "kv_blocks_cached": 0,
                     "kv_blocks_free": 0, **notes,
                 }
