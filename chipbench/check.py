@@ -43,6 +43,8 @@ import threading
 
 import numpy as np
 
+from chipbench.registry import model_keys
+
 # entries under this share of their tensor's root mean square are "small"
 SMALL = 0.5
 
@@ -214,7 +216,7 @@ def run_check(registry, config: dict, seed: int, core, *,
     its activations rounded to that type stands in its place."""
     check = config["check"]
     reference = registry.module("reference", config["reference"])
-    hf = {k: v for k, v in config.items() if not isinstance(v, (dict, list))}
+    hf = model_keys(config)
     prompts = sample_prompts(check, hf["vocab_size"], seed)
     if reference_activations is None:
         outputs = engine_outputs(core, prompts, check["gen_tokens"],

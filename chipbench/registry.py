@@ -20,6 +20,20 @@ import os
 HERE = os.path.dirname(os.path.abspath(__file__))
 REPO = os.path.dirname(HERE)
 
+# The keys of a configuration file that are the harness's own, by name.
+# Every other key is the model's, as its source publishes it.
+HARNESS_KEYS = frozenset((
+    "source", "reduced", "published", "assumed", "stands_for", "reference",
+    "server_flags", "server_flag_notes", "controls", "check"))
+
+
+def model_keys(config: dict) -> dict:
+    """The model's part of a configuration file: every key but the
+    harness's own, nested blocks and lists included. It is what the
+    program finds in ``config.json`` and what the reference is given as
+    ``hf``."""
+    return {k: v for k, v in config.items() if k not in HARNESS_KEYS}
+
 
 class Registry:
     def __init__(self, root: str = REPO):

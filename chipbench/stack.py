@@ -12,19 +12,18 @@ import json
 import os
 import time
 
+from chipbench.registry import model_keys
+
 
 def write_model_dir(config: dict, work: str, name: str) -> str:
-    """A model directory holding ``config.json`` with the configuration's
-    sizes under their published keys: the program reads a local
-    directory like a HuggingFace checkpoint and, finding no weights
-    there, draws them from its ``--seed``."""
+    """A model directory holding ``config.json`` with the model's keys
+    of the configuration as published, nested blocks included: the
+    program reads a local directory like a HuggingFace checkpoint and,
+    finding no weights there, draws them from its ``--seed``."""
     path = os.path.join(work, "models", name)
     os.makedirs(path, exist_ok=True)
-    hf = {k: v for k, v in config.items()
-          if not isinstance(v, (dict, list))
-          and k not in ("source", "stands_for", "reference")}
     with open(os.path.join(path, "config.json"), "w") as f:
-        json.dump(hf, f, indent=1, sort_keys=True)
+        json.dump(model_keys(config), f, indent=1, sort_keys=True)
     return path
 
 
@@ -41,8 +40,9 @@ def seeded_weights(args, seed: int):
     cfg = engine_config_from_args(args)
     mc = get_model_config(cfg.model).replace(dtype=cfg.dtype)
     init_fn, _ = build_model(mc)
+    # every init of the program takes these; one without adapters drops them
     lora = ({"lora_slots": cfg.max_loras, "lora_rank": cfg.max_lora_rank}
-            if mc.arch == "llama" and cfg.max_loras > 0 else {})
+            if cfg.max_loras > 0 else {})
 
     def init(key):
         params = init_fn(mc, key, **lora)

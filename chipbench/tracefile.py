@@ -32,7 +32,7 @@ from chipbench.registry import REPO
 WORK = os.path.join(REPO, ".chipbench_work")
 MODULES_LINE = "XLA Modules"
 # jax.named_scope names the program gives its model parts
-# (production_stack_tpu/models/llama.py, docs/profiling.md); "lora" nests
+# (production_stack_tpu/models/, docs/profiling.md); "lora" nests
 # in "attn_proj", so the innermost one decides.
 SCOPES = ("embed", "attn_proj", "lora", "kv_write", "attention", "mlp",
           "head", "sample")

@@ -4,67 +4,54 @@ later PR adds as files; what must fail."""
 import io
 import json
 import os
-import shutil
 import subprocess
 import sys
 import types
 from contextlib import redirect_stdout
 
+import chipbench_rules as rules
+import later_pr
 import pytest
 
-from chipbench.registry import REPO, Registry
+from chipbench.registry import HARNESS_KEYS, REPO, Registry, model_keys
 
 DATA = os.path.join(os.path.dirname(__file__), "data")
 
 
 @pytest.fixture(scope="module")
 def added(tmp_path_factory):
-    """A checkout-like directory in which a later PR has added a cell, a
-    traffic mix and a per-layer metric (with a reader of its own) as new
-    files plus entries in BENCHMARK.json, and edited nothing."""
-    root = tmp_path_factory.mktemp("later_pr")
-    shutil.copytree(os.path.join(DATA, "chipbench"),
-                    root / "chipbench")
-    bench = json.load(open(os.path.join(DATA, "BENCHMARK.json")))
-    mix = json.load(open(root / "chipbench" / "traffic" /
-                         "sessions-tiny.json"))
-    mix["traffic_seed"] = 99
-    mix["params"]["rate_per_s"] = 4.0
-    (root / "chipbench" / "traffic" / "extra-mix.json").write_text(
-        json.dumps(mix))
-    bench["workloads"].append({"name": "extra-cell", "config": "tiny-llama",
-                               "traffic": "extra-mix", "chips": 1,
-                               "why": "added by a test"})
-    for m in bench["end_to_end"]:
-        if "workloads" in m and "tiny-sessions" in m["workloads"]:
-            m["workloads"].append("extra-cell")
-    bench["per_layer"].append({
-        "name": "answers_total", "unit": "count", "better": "higher",
-        "source": "program_counter", "layer": "load generator",
-        "moves": "ttft_p90_s", "workloads": ["extra-cell"]})
-    os.makedirs(root / "chipbench" / "metrics")
-    os.makedirs(root / "chipbench" / "readers")
-    (root / "chipbench" / "metrics" / "answers_total.json").write_text(
-        json.dumps({"reader": "count_ok", "params": {"scale": 1}}))
-    (root / "chipbench" / "readers" / "count_ok.py").write_text(
-        "def read(ctx, params):\n"
-        "    return float(params['scale'] * sum(r['ok'] for r in ctx.due))\n")
-    (root / "BENCHMARK.json").write_text(json.dumps(bench))
-    return str(root)
+    """A checkout-like directory in which later PRs have added, as new
+    files plus entries in BENCHMARK.json and with no edit to a file that
+    was there: a cell, a traffic mix and a per-layer metric with a reader
+    of its own; and a configuration of other widths with its reference
+    module and a cell on it."""
+    root = later_pr.checkout(tmp_path_factory.mktemp("later_pr") / "root")
+    later_pr.add_cell_and_metric(root)
+    later_pr.add_configuration(root)
+    return root
 
 
-@pytest.fixture(scope="module")
-def result(added):
-    """One run of the added cell through the command's own entry."""
+def _run(root, cell):
+    """One run of a cell through the command's own entry."""
     from chipbench import run
 
     out = io.StringIO()
     with redirect_stdout(out):
-        run.main(["--workload", "extra-cell", "--seed", str(2 ** 31 + 12345),
-                  "--seconds", "3", "--trace", "0", "--root", added],
+        run.main(["--workload", cell, "--seed", str(2 ** 31 + 12345),
+                  "--seconds", "3", "--trace", "0", "--root", root],
                  platform="cpu")
     lines = out.getvalue().strip().splitlines()
     return lines, json.loads(lines[-1])
+
+
+@pytest.fixture(scope="module")
+def result(added):
+    return _run(added, "extra-cell")
+
+
+@pytest.fixture(scope="module")
+def wide_result(added):
+    return _run(added, later_pr.WIDE_CELL)
 
 
 def test_last_line_has_exactly_the_contract_keys(result):
@@ -133,6 +120,82 @@ def test_added_metric_is_found_and_read_from_its_own_files(added):
     # and the cells that were there still find theirs beside the package
     assert reg.module("readers", "late") is not None
     assert reg.traffic("extra-mix")["traffic_seed"] == 99
+
+
+def test_added_configuration_differs_as_the_tiny_preset_cannot(added):
+    """What the added configuration's file has that nothing in the data
+    had: other widths, a nested block and a list among the model's keys,
+    no ``sliding_window``, a cut under ``reduced`` and ``published``."""
+    reg = Registry(added)
+    tiny = reg.config(reg.bench["configs"][0]["name"])
+    wide = reg.config(later_pr.WIDE)
+    for key in ("hidden_size", "intermediate_size", "num_attention_heads",
+                "num_key_value_heads", "vocab_size", "num_hidden_layers"):
+        assert wide[key] != tiny[key]
+    model = model_keys(wide)
+    assert isinstance(model["rope_scaling"], dict)
+    assert isinstance(model["layer_types"], list)
+    assert "sliding_window" not in wide
+    assert wide["reduced"] == ["num_hidden_layers", "vocab_size"]
+    assert all(wide[k] < wide["published"][k] for k in wide["reduced"])
+    assert wide["reference"] != tiny["reference"]
+    assert reg.find("reference", wide["reference"] + ".py").startswith(added)
+
+
+def test_added_configuration_runs_through_run_cell(wide_result):
+    lines, obj = wide_result
+    assert obj["correct"] is True and obj["failed"] == 0
+    assert obj["attempted"] >= 5
+    assert set(obj["metrics"]) == {"ttft_p90_s", "itl_p99_s", "tpot_p50_s",
+                                   "setup_s"}
+    compared = {ln.split()[1] for ln in lines if ln.startswith("compared: ")}
+    assert {"logprob_rms", "kv_small_rel_rms"} <= compared
+
+
+def test_nested_keys_reach_the_program_and_the_reference(added, wide_result):
+    """The ``config.json`` the program was started on and the ``hf`` the
+    reference received are the model's keys, nested block and list
+    included, and none of the harness's."""
+    want = model_keys(Registry(added).config(later_pr.WIDE))
+    work = os.path.join(added, ".chipbench_work")
+    with open(os.path.join(work, later_pr.WIDE_CELL, "models", later_pr.WIDE,
+                           "config.json")) as f:
+        program = json.load(f)
+    with open(os.path.join(work, "reference_hf.json")) as f:
+        reference = json.load(f)
+    for got in (program, reference):
+        assert got == want
+        assert got["rope_scaling"] == {"rope_type": "linear", "factor": 1.0}
+        assert got["layer_types"] == ["full_attention"] * 4
+        assert not HARNESS_KEYS & set(got)
+
+
+def test_added_root_keeps_the_contracts_and_the_schedules_rules(added):
+    """The rules the repo's own root is held to
+    (``test_chipbench_contract``, ``test_chipbench_schedule``) pass on a
+    root in which a later PR has only added."""
+    reg = Registry(added)
+    assert rules.contract_faults(reg) == []
+    assert rules.schedule_faults(reg, 3) == []
+    assert {c["name"] for c in reg.bench["configs"]} > {later_pr.WIDE}
+    assert not rules.window_binds(reg.config(later_pr.WIDE))
+
+
+def test_every_init_of_the_program_takes_the_adapter_sizes():
+    """``stack.seeded_weights`` hands the adapter slots to whichever init
+    the configuration's architecture has, as the engine does for the one
+    that has adapters; the others drop them."""
+    import inspect
+
+    from production_stack_tpu.models import build_model
+    from production_stack_tpu.models.config import _PRESETS
+
+    one_of_each = {mc.arch: mc for mc in _PRESETS.values()}
+    assert len(one_of_each) >= 2
+    for arch, mc in one_of_each.items():
+        params = inspect.signature(build_model(mc)[0]).parameters
+        assert "lora_slots" in params or any(
+            p.kind is p.VAR_KEYWORD for p in params.values()), arch
 
 
 def test_a_reader_that_finds_nothing_is_left_out():

@@ -4,6 +4,8 @@ import json
 import os
 import random
 
+import chipbench_rules as rules
+import later_pr
 import pytest
 
 from chipbench import schedule
@@ -88,13 +90,70 @@ def test_backlog_prompts_share_nothing(real):
 
 
 def test_contexts_stay_within_the_assumed_window(real):
-    for cell in real.bench["workloads"]:
-        config = real.config(cell["config"])
-        traffic = real.traffic(cell["traffic"])
-        s = schedule.build(real, traffic, 15, config["vocab_size"])
-        longest = max(len(r["prompt"]) + r["max_tokens"]
-                      for r in s["requests"])
-        assert longest <= config["sliding_window"]
-        assert longest <= int(config["server_flags"][
-            config["server_flags"].index("--max-model-len") + 1])
+    """Every cell's longest context fits its ``--max-model-len``, and a
+    published ``sliding_window`` that the program does not apply
+    (``assumed`` says so) where there is one. Both hold for both cells of
+    the configuration the benchmark has."""
+    assert rules.schedule_faults(real) == []
+    cells = [c for c in real.bench["workloads"]
+             if c["config"] == "mistral-7b-l16"]
+    assert len(cells) >= 2
+    config = real.config("mistral-7b-l16")
+    assert rules.window_binds(config)
+    assert config["sliding_window"] == rules.max_model_len(config) == 4096
 
+
+def _root_with(tmp_path, **changes):
+    """The added configuration with ``changes`` laid over its file; its
+    cell runs the tiny sessions mix (contexts up to 200)."""
+    return Registry(later_pr.root_with_configuration(tmp_path / "root",
+                                                     **changes))
+
+
+WINDOWS = {
+    # no such key: nothing to read, nothing to hold (it was a KeyError)
+    "no sliding_window": ({}, False, None),
+    "a null one": ({"sliding_window": None,
+                    "assumed": {**later_pr.WIDE_ASSUMED,
+                                "sliding_window": "null as published"}},
+                   False, None),
+    "one the program applies": ({"sliding_window": 64}, False, None),
+    "one it does not apply, wide enough": (
+        {"sliding_window": 256,
+         "assumed": {**later_pr.WIDE_ASSUMED,
+                     "sliding_window": "not applied; contexts stay in it"}},
+        True, None),
+    "one it does not apply, too narrow": (
+        {"sliding_window": 64,
+         "assumed": {**later_pr.WIDE_ASSUMED,
+                     "sliding_window": "not applied; contexts stay in it"}},
+        True, "passes the sliding_window 64"),
+    "contexts over --max-model-len": (
+        {"server_flags": ["--max-model-len", "100", "--no-warmup"]},
+        False, "passes --max-model-len 100"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(WINDOWS))
+def test_contexts_stay_within_what_the_configuration_states(tmp_path, case):
+    changes, binds, wanted = WINDOWS[case]
+    reg = _root_with(tmp_path, **changes)
+    assert rules.window_binds(reg.config(later_pr.WIDE)) is binds
+    faults = [f for f in rules.schedule_faults(reg, 3)
+              if f.startswith(later_pr.WIDE_CELL)]
+    if wanted is None:
+        assert faults == []
+    else:
+        assert len(faults) == 1 and wanted in faults[0]
+
+
+def test_tokens_come_from_the_rows_held(tmp_path):
+    """A sliced vocabulary is a smaller vocabulary: the schedule draws
+    its ids from the rows the configuration holds."""
+    reg = _root_with(tmp_path)
+    config = reg.config(later_pr.WIDE)
+    s = schedule.build(reg, reg.traffic("sessions-tiny"), 3,
+                       config["vocab_size"])
+    top = max(t for r in s["requests"] for t in r["prompt"])
+    assert 259 <= top < config["vocab_size"] < config["published"][
+        "vocab_size"]
