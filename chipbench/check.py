@@ -7,9 +7,10 @@ together) with greedy sampling and the top log-probabilities asked for.
 The reference (``reference/<arch>.py``) then computes, in float32 and
 from the seed alone, the log-probabilities of the same tokens at the same
 positions by one full forward pass over each sequence, and the keys and
-values of the first layer. Two numbers are compared with their limits
-(the configuration's ``check`` block, with the readings they came from
-in ``PERF.md``):
+values of the layers the ``check`` block names under ``kv_layers`` (the
+first layer alone where it names none). Two numbers are compared with
+their limits (the configuration's ``check`` block, with the readings
+they came from in ``PERF.md``):
 
 - ``logprob_rms``: root mean square of (engine - reference) over every
   reported log-probability of every generated position. Covers the whole
@@ -19,7 +20,12 @@ in ``PERF.md``):
   mean square. The program gives the pages out through its KV-transfer
   surface (``extract_kv``). Deeper layers add the error of the
   activations before them, the same in any page format, so the first
-  layer is where the page format itself shows.
+  layer is where the page format itself shows. A model whose layers are
+  of several kinds (a full layer at 0, window layers after it) names one
+  layer of each kind under ``kv_layers``; every ``kv_*`` number is then
+  the largest over the layers named, and ``kv_small_rel_rms_layer<n>``
+  is each layer's own, which ``limits`` may name: a sound run reads a
+  deeper layer higher than the first.
 
 - ``kv_small_rel_rms``: the same error over the entries whose reference
   value is under half the root mean square, still relative to the root
@@ -105,30 +111,42 @@ def engine_outputs(core, prompts, gen_tokens: int, top: int):
     return [(t, p) for t, p, _ in results]
 
 
-def _pages_to_tokens(side) -> np.ndarray:
-    """[N, L, bs, KVH, D] pages (or (int8 data, scales)) of layer 0 ->
+def _pages_to_tokens(side, layer: int = 0) -> np.ndarray:
+    """[N, L, bs, KVH, D] pages (or (int8 data, scales)) of ``layer`` ->
     float32 [N*bs, KVH, D]."""
     if isinstance(side, tuple):
         data, scales = side
-        data = np.asarray(data)[:, 0].astype(np.float32)
+        data = np.asarray(data)[:, layer].astype(np.float32)
         n, bs, kvh, d = data.shape
-        scales = np.asarray(scales)[:, 0].astype(np.float32).reshape(
+        scales = np.asarray(scales)[:, layer].astype(np.float32).reshape(
             n, bs, kvh, 1)
         return (data * scales).reshape(n * bs, kvh, d)
-    data = np.asarray(side)[:, 0].astype(np.float32)
+    data = np.asarray(side)[:, layer].astype(np.float32)
     return data.reshape(-1, *data.shape[2:])
 
 
 def engine_pages(core, prompts):
-    """i -> (keys, values) of prompt i's tokens in the engine's
-    first-layer pages, float32 [n, KVH, D]."""
-    def pages(i):
-        got = core.extract_kv(list(prompts[i]))
+    """(i, layer) -> (keys, values) of prompt i's tokens in the engine's
+    pages of that layer, float32 [n, KVH, D]."""
+    last = {}  # the prompt asked for last: its layers are read in turn
+
+    def pages(i, layer=0):
+        if i not in last:
+            last.clear()
+            last[i] = core.extract_kv(list(prompts[i]))
+        got = last[i]
         if got is None:
             raise RuntimeError("the engine holds no pages of a check prompt")
         n = got["num_tokens"]
-        return (_pages_to_tokens(got["k"])[:n], _pages_to_tokens(got["v"])[:n])
+        return (_pages_to_tokens(got["k"], layer)[:n],
+                _pages_to_tokens(got["v"], layer)[:n])
     return pages
+
+
+def kv_layers_of(check: dict) -> tuple:
+    """The layers whose cache the check compares: ``kv_layers`` of the
+    ``check`` block, the first layer alone where it names none."""
+    return tuple(check.get("kv_layers", (0,)))
 
 
 def reference_in_place(reference, hf: dict, seed: int, check: dict, prompts,
@@ -149,7 +167,8 @@ def reference_in_place(reference, hf: dict, seed: int, check: dict, prompts,
     keep_from = min(len(p) for p in prompts) - 1
     logp, kv = reference.forward(hf, seed, tokens, lens, keep_from=keep_from,
                                  quantization=check.get("quantization"),
-                                 kv_layers=(0,), activations=activations)
+                                 kv_layers=kv_layers_of(check),
+                                 activations=activations)
     outputs = []
     for i, (p, a) in enumerate(zip(prompts, answers)):
         tops = []
@@ -158,15 +177,46 @@ def reference_in_place(reference, hf: dict, seed: int, check: dict, prompts,
             best = {int(t) for t in np.argsort(row)[-top:]} | {tok}
             tops.append(sorted((t, float(row[t])) for t in best))
         outputs.append((a, tops))
-    k, v = kv[0]
-    return outputs, lambda i: (k[i, :len(prompts[i])].astype(np.float32),
-                               v[i, :len(prompts[i])].astype(np.float32))
+    return outputs, lambda i, layer=0: tuple(
+        side[i, :len(prompts[i])].astype(np.float32) for side in kv[layer])
+
+
+class _PageError:
+    """Sums of one layer's cache error over the prompts."""
+
+    def __init__(self):
+        self.err2 = {"k": 0.0, "v": 0.0}
+        self.ref2 = {"k": 0.0, "v": 0.0}
+        self.small_err2 = self.small_n = self.entries = 0.0
+
+    def add(self, side: str, want, mine) -> None:
+        sq = (mine - want).astype(np.float64) ** 2
+        self.err2[side] += float(sq.sum())
+        self.ref2[side] += float(np.sum(want.astype(np.float64) ** 2))
+        small = np.abs(want) < SMALL * math.sqrt(
+            float(np.mean(want.astype(np.float64) ** 2)))
+        self.small_err2 += float(sq[small].sum())
+        self.small_n += float(small.sum())
+        self.entries += want.size
+
+    def numbers(self) -> dict:
+        total_ref2 = self.ref2["k"] + self.ref2["v"]
+        return {"kv_rel_rms": math.sqrt(
+                    (self.err2["k"] + self.err2["v"]) / total_ref2),
+                "kv_k_rel_rms": math.sqrt(self.err2["k"] / self.ref2["k"]),
+                "kv_v_rel_rms": math.sqrt(self.err2["v"] / self.ref2["v"]),
+                "kv_small_rel_rms": math.sqrt(
+                    (self.small_err2 / self.small_n)
+                    / (total_ref2 / self.entries))}
 
 
 def compare(reference, hf: dict, seed: int, quantization, prompts, outputs,
-            pages) -> dict:
+            pages, kv_layers=(0,)) -> dict:
     """The numbers, with their parts. ``outputs`` and ``pages`` are the
-    program's (or a control's in its place)."""
+    program's (or a control's in its place). Each ``kv_*`` number is the
+    largest over ``kv_layers``; with more than one layer named,
+    ``kv_small_rel_rms_layer<n>`` gives each layer's, for ``limits`` to
+    name one by one."""
     gen = len(outputs[0][0])
     lens = [len(p) + gen for p in prompts]
     width = max(lens)
@@ -176,37 +226,32 @@ def compare(reference, hf: dict, seed: int, quantization, prompts, outputs,
     keep_from = min(len(p) for p in prompts) - 1
     logp, kv = reference.forward(hf, seed, tokens, lens,
                                  keep_from=keep_from,
-                                 quantization=quantization, kv_layers=(0,))
+                                 quantization=quantization,
+                                 kv_layers=tuple(kv_layers))
     diffs = []
     for i, (p, (_, tops)) in enumerate(zip(prompts, outputs)):
         for j, entries in enumerate(tops):
             row = logp[i, len(p) - 1 + j - keep_from]
             diffs.extend(v - float(row[t]) for t, v in entries)
     diffs = np.asarray(diffs, np.float64)
-    ref_k, ref_v = kv[0]
-    err2, ref2 = {"k": 0.0, "v": 0.0}, {"k": 0.0, "v": 0.0}
-    small_err2 = small_n = entries = 0.0
+    errors = {layer: _PageError() for layer in kv_layers}
     for i in range(len(prompts)):
-        for side, ref, mine in zip("kv", (ref_k, ref_v), pages(i)):
-            want = ref[i, :len(mine)].astype(np.float32)
-            sq = (mine - want).astype(np.float64) ** 2
-            err2[side] += float(sq.sum())
-            ref2[side] += float(np.sum(want.astype(np.float64) ** 2))
-            small = np.abs(want) < SMALL * math.sqrt(
-                float(np.mean(want.astype(np.float64) ** 2)))
-            small_err2 += float(sq[small].sum())
-            small_n += float(small.sum())
-            entries += want.size
-    total_ref2 = ref2["k"] + ref2["v"]
-    return {"logprob_rms": float(np.sqrt(np.mean(diffs ** 2))),
-            "logprob_max": float(np.max(np.abs(diffs))),
-            "logprobs_compared": int(diffs.size),
-            "kv_rel_rms": math.sqrt((err2["k"] + err2["v"]) / total_ref2),
-            "kv_k_rel_rms": math.sqrt(err2["k"] / ref2["k"]),
-            "kv_v_rel_rms": math.sqrt(err2["v"] / ref2["v"]),
-            "kv_small_rel_rms": math.sqrt(
-                (small_err2 / small_n) / (total_ref2 / entries)),
-            "kv_entries_compared": int(entries)}
+        for layer, error in errors.items():
+            for side, ref, mine in zip("kv", kv[layer], pages(i, layer)):
+                error.add(side, ref[i, :len(mine)].astype(np.float32), mine)
+    by_layer = {layer: error.numbers() for layer, error in errors.items()}
+    numbers = {"logprob_rms": float(np.sqrt(np.mean(diffs ** 2))),
+               "logprob_max": float(np.max(np.abs(diffs))),
+               "logprobs_compared": int(diffs.size)}
+    for name in ("kv_rel_rms", "kv_k_rel_rms", "kv_v_rel_rms",
+                 "kv_small_rel_rms"):
+        numbers[name] = max(n[name] for n in by_layer.values())
+    numbers["kv_entries_compared"] = int(sum(
+        e.entries for e in errors.values()))
+    if len(by_layer) > 1:
+        for layer, n in by_layer.items():
+            numbers[f"kv_small_rel_rms_layer{layer}"] = n["kv_small_rel_rms"]
+    return numbers
 
 
 def run_check(registry, config: dict, seed: int, core, *,
@@ -226,7 +271,7 @@ def run_check(registry, config: dict, seed: int, core, *,
         outputs, pages = reference_in_place(reference, hf, seed, check,
                                             prompts, reference_activations)
     numbers = compare(reference, hf, seed, check.get("quantization"),
-                      prompts, outputs, pages)
+                      prompts, outputs, pages, kv_layers_of(check))
     limits = check["limits"]
     ok = all(numbers[name] <= limit for name, limit in limits.items())
     return {"numbers": numbers, "limits": limits, "ok": ok}

@@ -9,7 +9,9 @@ the compile cache, the traffic's preload (histories that exist when the
 window opens are prefilled), traffic start, ``ramp_s`` of ramp, the
 window of ``--seconds`` (all of that before the window is ``setup_s``),
 the drain, then the correctness check against the plain reference. The
-last line of standard output is the result object. Without a TPU the run
+last line of standard output is the result object; each number compared
+stands beside its limit under its last key, ``compared``, and on the last
+lines of standard error. Without a TPU the run
 fails: there is no CPU leg (the tests steer a tiny model through the
 same code by calling :func:`run_cell` with ``platform="cpu"``).
 """
@@ -357,6 +359,9 @@ class Cell:
                     due, limits["ttft_limit_s"], limits["tpot_limit_s"]),
                 "ttft_p50_s": e2e.get("ttft_p50_s"),
                 "ttft_p95_s": e2e.get("ttft_p95_s")}
+        # last in the line: what a record of a run that is not correct keeps
+        out["compared"] = {name: {"value": value, "limit": limit}
+                           for name, value, limit in compared}
         return out
 
 
@@ -388,6 +393,9 @@ def main(argv=None, **kwargs) -> None:
     result = run_cell(a.workload, a.seed, a.seconds, bool(a.trace),
                       root=a.root, **kwargs)
     print(json.dumps(result), flush=True)
+    for name, c in result["compared"].items():
+        print(f"compared: {name} = {c['value']} (limit {c['limit']})",
+              file=sys.stderr, flush=True)
 
 
 if __name__ == "__main__":

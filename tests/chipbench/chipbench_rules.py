@@ -43,6 +43,9 @@ WIDTH = re.compile(
     r"|expansion|factor)(_|$)"
     r"|head_size|per_tok|top_?k|^d_[a-z]+$")
 
+# A number that ``check.compare`` gives for one layer of several compared.
+PER_LAYER_NUMBER = re.compile(r"^kv_small_rel_rms_layer(\d+)$")
+
 # Floors of a cut, so that what is left is still the model.
 MIN_LAYERS_AFTER_DENSE = 4
 MIN_ROUTED_EXPERTS = 8
@@ -55,6 +58,39 @@ def is_width(key: str) -> bool:
 
 def _first(body: dict, keys):
     return next((k for k in keys if k in body), None)
+
+
+def leading_dense_layers(body: dict) -> int:
+    """How many of the held layers are dense ones ahead of the sparse
+    ones, by whichever key the file has: ``first_k_dense_replace``, the
+    entries of ``mlp_only_layers``, or the leading run of ``"dense"`` in
+    ``mlp_layer_types``; the largest where it has several."""
+    kinds = body.get("mlp_layer_types") or []
+    run = next((i for i, kind in enumerate(kinds) if kind != "dense"),
+               len(kinds))
+    return max(body.get("first_k_dense_replace") or 0,
+               len(body.get("mlp_only_layers") or ()), run)
+
+
+def check_block_faults(check: dict, layers_held: int) -> list:
+    """Faults of a ``check`` block's ``kv_layers`` (the layers whose
+    cache is compared: held ones, each once; ``[0]`` where it names
+    none) and of the per-layer numbers its ``limits`` name, which
+    ``check.compare`` gives only for several layers named."""
+    named = check.get("kv_layers", [0])
+    faults = []
+    if (not named or len(set(named)) != len(named)
+            or any(not isinstance(n, int) or not 0 <= n < layers_held
+                   for n in named)):
+        faults.append(f"check.kv_layers is {named}: not a list of "
+                      f"different layers among the {layers_held} held")
+    for number in check.get("limits", {}):
+        layer = PER_LAYER_NUMBER.match(number)
+        if layer and (len(named) < 2 or int(layer.group(1)) not in named):
+            faults.append(f"check.limits names {number}, which the check "
+                          f"gives only for several kv_layers that hold "
+                          f"layer {layer.group(1)}")
+    return faults
 
 
 def config_faults(reg, entry: dict) -> list:
@@ -78,6 +114,11 @@ def config_faults(reg, entry: dict) -> list:
         fault("source differs between the file and BENCHMARK.json")
     if not str(body.get("stands_for") or "").strip():
         fault("stands_for is empty: which deployment is this chip one of?")
+    try:
+        reg.find("reference", f"{body.get('reference')}.py")
+    except FileNotFoundError:
+        fault(f"reference names {body.get('reference')!r}: no such module "
+              f"under reference/")
     if not any(w["config"] == name for w in reg.bench["workloads"]):
         fault("no cell runs it")
     published = body.get("published")
@@ -108,12 +149,22 @@ def config_faults(reg, entry: dict) -> list:
     if layers is None:
         fault(f"none of {LAYER_KEYS} says how many layers are held")
     else:
-        dense = body.get("first_k_dense_replace", 0)
-        floor = min(MIN_LAYERS_AFTER_DENSE,
-                    published.get(layers, body[layers]) - dense)
+        dense = leading_dense_layers(body)
+        all_layers = published.get(layers, body[layers])
+        floor = min(MIN_LAYERS_AFTER_DENSE, all_layers - dense)
         if body[layers] - dense < floor:
             fault(f"{body[layers]} layers of which {dense} dense: fewer than "
                   f"{floor} after the leading dense ones")
+        for key, value in model_keys(body).items():
+            if (body[layers] < all_layers and isinstance(value, list)
+                    and len(value) == body[layers]):
+                fault(f"{key} has {len(value)} entries, one for each layer "
+                      f"held: keep per-layer lists as published "
+                      f"({all_layers} entries): the program and the "
+                      f"reference read the first {layers} entries")
+        for text in check_block_faults(body.get("check") or {},
+                                       body[layers]):
+            fault(text)
     experts = _first(body, EXPERT_KEYS)
     if experts is not None and body[experts]:
         floor = min(MIN_ROUTED_EXPERTS, published.get(experts, body[experts]))

@@ -1,5 +1,6 @@
 """``BENCHMARK.json`` against the contract, and every name it gives
-against the files the harness will look for."""
+against the files the harness will look for. ``reg`` (``conftest``) is
+the repo's own root, then its copy with a later PR's addition."""
 
 import json
 import os
@@ -15,10 +16,9 @@ NAME = rules.NAME
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
 
-
-@pytest.fixture(scope="module")
-def reg():
-    return Registry(REPO)
+# The configurations that existed at PR 27's parent, whose rule dropped
+# every dict- or list-valued key: they stay held to that parent's bytes.
+AT_PR27_PARENT = ("mistral-7b-l16",)
 
 
 def test_top_level_keys(reg):
@@ -26,7 +26,8 @@ def test_top_level_keys(reg):
                               "workloads", "end_to_end", "per_layer"}
     assert reg.bench["paths"] == ["chipbench", "tests/chipbench"]
     assert 1 <= reg.bench["run_seconds"] <= 51
-    assert os.path.getsize(os.path.join(REPO, "BENCHMARK.json")) < 64 * 1024
+    assert os.path.getsize(
+        os.path.join(reg.root, "BENCHMARK.json")) < 64 * 1024
 
 
 def test_configs(reg):
@@ -96,6 +97,31 @@ BROKEN = {
     "three layers after the leading dense one": (
         {"first_k_dense_replace": 1},
         ["4 layers of which 1 dense: fewer than 4 after"]),
+    "a dense layer named by mlp_only_layers": (
+        {"mlp_only_layers": [0]},
+        ["4 layers of which 1 dense: fewer than 4 after"]),
+    "a leading dense run in mlp_layer_types": (
+        {"mlp_layer_types": ["dense"] + ["sparse"] * 7},
+        ["4 layers of which 1 dense: fewer than 4 after"]),
+    "a per-layer list cut to the layers held": (
+        {"layer_types": ["full_attention"] * 4},
+        ["layer_types has 4 entries, one for each layer held: keep "
+         "per-layer lists as published (8 entries): the program and the "
+         "reference read the first num_hidden_layers entries"]),
+    "a reference module that is not there": (
+        {"reference": "nowhere"},
+        ["reference names 'nowhere': no such module under reference/"]),
+    "a layer's cache compared that is not held": (
+        {"check": {"kv_layers": [0, 4], "limits": {}}},
+        ["check.kv_layers is [0, 4]: not a list of different layers "
+         "among the 4 held"]),
+    "a per-layer limit on a layer that is not compared": (
+        {"check": {"kv_layers": [0, 1],
+                   "limits": {"kv_small_rel_rms_layer2": 0.01}}},
+        ["check.limits names kv_small_rel_rms_layer2"]),
+    "a per-layer limit with one layer compared": (
+        {"check": {"limits": {"kv_small_rel_rms_layer0": 0.01}}},
+        ["check.limits names kv_small_rel_rms_layer0"]),
     "a published value missing": (
         {"published": {"vocab_size": 2048, "chips_per_layer": 1}},
         ["reduced names num_hidden_layers, published does not give"]),
@@ -126,25 +152,130 @@ def test_configs_hold_the_floors_to_eight_experts_and_an_eighth(tmp_path):
     assert _broken(
         tmp_path, n_routed_experts=8, first_k_dense_replace=1,
         num_hidden_layers=5, vocab_size=1024,
-        layer_types=["full_attention"] * 5,
+        mlp_layer_types=["dense"] + ["sparse"] * 7,
         reduced=["num_hidden_layers", "vocab_size", "n_routed_experts"],
         published={**later_pr.WIDE_PUBLISHED, "n_routed_experts": 64,
                    "vocab_size": 8192}) == []
 
 
-def test_configs_refuse_a_pinned_width_changed(tmp_path, reg):
-    """The table of pinned configurations: the repo's own BENCHMARK.json
-    over a file whose hidden size is not its source's."""
-    body = reg.config("mistral-7b-l16")
+PASSING = {
+    "a per-layer list of the held length where no layer is cut": {
+        "num_hidden_layers": 8, "reduced": ["vocab_size"],
+        "published": {"vocab_size": 2048, "chips_per_layer": 1}},
+    "a list as long as the layers held that is no per-layer list, uncut": {
+        "num_hidden_layers": 8, "eos_token_id": list(range(8)),
+        "reduced": ["vocab_size"],
+        "published": {"vocab_size": 2048, "chips_per_layer": 1}},
+    "two layers' caches compared, each with a limit of its own": {
+        "check": {"kv_layers": [0, 3],
+                  "limits": {"kv_small_rel_rms_layer0": 0.003,
+                             "kv_small_rel_rms_layer3": 0.02}}},
+    "five layers behind a dense one named three ways": {
+        "num_hidden_layers": 5, "first_k_dense_replace": 1,
+        "mlp_only_layers": [0],
+        "mlp_layer_types": ["dense"] + ["sparse"] * 7},
+}
+
+
+@pytest.mark.parametrize("case", sorted(PASSING))
+def test_configs_pass(tmp_path, case):
+    assert _broken(tmp_path, **PASSING[case]) == []
+
+
+@pytest.mark.parametrize("body, dense", [
+    ({}, 0), ({"first_k_dense_replace": 3}, 3),
+    ({"mlp_only_layers": [0]}, 1), ({"mlp_only_layers": []}, 0),
+    ({"mlp_layer_types": ["dense", "dense", "sparse", "dense"]}, 2),
+    ({"mlp_layer_types": ["sparse", "dense"]}, 0),
+    ({"mlp_layer_types": ["dense"] * 3}, 3),
+    ({"first_k_dense_replace": 1, "mlp_only_layers": [0, 1],
+      "mlp_layer_types": ["dense", "sparse"]}, 2)])
+def test_leading_dense_layers_by_whichever_key_the_file_has(body, dense):
+    assert rules.leading_dense_layers(body) == dense
+
+
+def test_configs_refuse_a_pinned_width_changed(tmp_path, fresh_root):
+    """The table of pinned configurations: a copy of the root in which
+    the pinned configuration's file, found by its name, has a hidden
+    size that is not its source's. Every other configuration's file is
+    there, and none of them is at fault."""
+    broken = Registry(fresh_root(tmp_path / "root"))
+    entry = next(c for c in broken.bench["configs"]
+                 if c["name"] == "mistral-7b-l16")
+    body = broken.config(entry["name"])
     body["hidden_size"] = 2048
-    path = tmp_path / reg.bench["configs"][0]["file"]
-    os.makedirs(path.parent)
-    path.write_text(json.dumps(body))
-    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
-        (tmp_path / "BENCHMARK.json").write_text(f.read())
-    faults = rules.contract_faults(Registry(str(tmp_path)))
-    assert faults == ["mistral-7b-l16: hidden_size is 2048, its source "
-                      "says 4096"]
+    with open(os.path.join(broken.root, entry["file"]), "w") as f:
+        json.dump(body, f)
+    assert rules.contract_faults(broken) == [
+        "mistral-7b-l16: hidden_size is 2048, its source says 4096"]
+
+
+def _files(root) -> dict:
+    found = {}
+    for base, _, names in os.walk(root):
+        for name in names:
+            path = os.path.join(base, name)
+            with open(path, "rb") as f:
+                found[os.path.relpath(path, root)] = f.read()
+    return found
+
+
+def test_the_addition_to_the_repos_root_edits_nothing_that_was_there(
+        tmp_path):
+    """``chipbench/README.md``, "Adding things", held to its word on a
+    copy of the repo's own root: after a second configuration has gone
+    in with a cell on each traffic mix, every file that was there has
+    the bytes it had, and every entry of ``BENCHMARK.json`` that was
+    there is unchanged but for ``workloads`` lists that gained names."""
+    root = later_pr.repo_checkout(tmp_path / "root")
+    files, bench = _files(root), Registry(root).bench
+    for rel, data in files.items():  # the copy is the repo's own bytes
+        with open(os.path.join(REPO, rel), "rb") as f:
+            assert f.read() == data, rel
+    later_pr.add_second_configuration(root)
+    now_files, now = _files(root), Registry(root)
+    assert set(now_files) - set(files) == {
+        f"chipbench/configs/{later_pr.SECOND}.json",
+        f"chipbench/reference/{later_pr.SECOND_REFERENCE}.py"}
+    assert {rel for rel in files if now_files[rel] != files[rel]} == {
+        "BENCHMARK.json"}
+    for key in ("command", "paths", "run_seconds"):
+        assert now.bench[key] == bench[key]
+    for group in ("configs", "workloads"):
+        assert now.bench[group][:len(bench[group])] == bench[group]
+    assert len(now.bench["configs"]) == len(bench["configs"]) + 1
+    assert len(now.bench["workloads"]) == len(bench["workloads"]) + 2
+    likes = {next(w["name"] for w in bench["workloads"]
+                  if w["traffic"] == traffic): cell
+             for traffic, cell in later_pr.SECOND_CELLS.items()}
+    for group in ("end_to_end", "per_layer"):
+        assert len(now.bench[group]) == len(bench[group])
+        for was, entry in zip(bench[group], now.bench[group]):
+            assert ({k: v for k, v in entry.items() if k != "workloads"}
+                    == {k: v for k, v in was.items() if k != "workloads"})
+            assert ("workloads" in entry) == ("workloads" in was)
+            listed, had = entry.get("workloads", []), was.get("workloads", [])
+            assert listed[:len(had)] == had
+            assert sorted(listed[len(had):]) == sorted(
+                likes[cell] for cell in had if cell in likes)
+    # each new cell reports what the cell already on its mix reports
+    for like, cell in likes.items():
+        assert now.workload(cell)["config"] == later_pr.SECOND
+        for group in ("end_to_end", "per_layer"):
+            assert ([m["name"] for m in now.metrics_for(group, cell)]
+                    == [m["name"] for m in now.metrics_for(group, like)])
+    # and it is of the shape that PR 27's parent could not take
+    model = model_keys(now.config(later_pr.SECOND))
+    assert set(model["rope_parameters"]) == {"full_attention",
+                                             "sliding_attention"}
+    published = now.config(later_pr.SECOND)["published"]
+    for key in ("layer_types", "mlp_layer_types",
+                "num_attention_heads_per_layer"):
+        assert len(model[key]) == published["num_hidden_layers"] > model[
+            "num_hidden_layers"]
+    assert model["mlp_only_layers"] == [0]
+    assert now.config(later_pr.SECOND)["check"]["kv_layers"] == [0, 1]
+    assert rules.contract_faults(now) == []
 
 
 def test_configs_refuse_one_without_a_cell(tmp_path):
@@ -168,28 +299,50 @@ def _parents_config_json(config: dict) -> str:
     return json.dumps(hf, indent=1, sort_keys=True)
 
 
+def _by_name(config: dict) -> dict:
+    """The model's keys told by name, written out: what ``model_keys``
+    has to give, nested blocks and lists as the file has them."""
+    return {k: v for k, v in config.items() if k not in HARNESS_KEYS}
+
+
 def test_config_json_is_byte_for_byte_the_parents(reg, tmp_path):
+    """The program's model directory. A configuration that PR 27's
+    parent had: the bytes that parent wrote. Any other: exactly the
+    model's keys by name, nested blocks and lists read back equal."""
     from chipbench.stack import write_model_dir
 
-    for c in reg.bench["configs"]:
-        config = reg.config(c["name"])
-        path = write_model_dir(config, str(tmp_path), c["name"])
+    names = [c["name"] for c in reg.bench["configs"]]
+    assert set(AT_PR27_PARENT) <= set(names)
+    for name in names:
+        config = reg.config(name)
+        path = write_model_dir(config, str(tmp_path), name)
         with open(os.path.join(path, "config.json")) as f:
-            assert f.read() == _parents_config_json(config), c["name"]
+            text = f.read()
+        if name in AT_PR27_PARENT:
+            assert text == _parents_config_json(config), name
+        else:
+            assert json.loads(text) == _by_name(config), name
 
 
 def test_reference_is_given_what_the_parent_gave_it(reg):
-    """The parent's rule in ``run_check`` let three of the harness's
-    strings through, which no reference reads; every other key is the
-    same."""
-    for c in reg.bench["configs"]:
-        config = reg.config(c["name"])
-        parents = {k: v for k, v in config.items()
-                   if not isinstance(v, (dict, list))}
+    """The reference's ``hf``. A configuration that PR 27's parent had:
+    that parent's rule in ``run_check`` let three of the harness's
+    strings through, which no reference reads, and every other key is
+    the same. Any other: every key of the file but the harness's, by
+    name, nested blocks and lists as the file has them."""
+    names = [c["name"] for c in reg.bench["configs"]]
+    assert set(AT_PR27_PARENT) <= set(names)
+    for name in names:
+        config = reg.config(name)
         hf = model_keys(config)
-        assert set(parents) - set(hf) == {"source", "stands_for",
-                                          "reference"}
-        assert hf == {k: parents[k] for k in hf}
+        if name in AT_PR27_PARENT:
+            parents = {k: v for k, v in config.items()
+                       if not isinstance(v, (dict, list))}
+            assert set(parents) - set(hf) == {"source", "stands_for",
+                                              "reference"}
+            assert hf == {k: parents[k] for k in hf}
+        else:
+            assert hf == _by_name(config), name
 
 
 def test_harness_keys_are_told_by_name_not_by_type():

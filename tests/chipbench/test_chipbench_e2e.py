@@ -7,7 +7,7 @@ import os
 import subprocess
 import sys
 import types
-from contextlib import redirect_stdout
+from contextlib import redirect_stderr, redirect_stdout
 
 import chipbench_rules as rules
 import later_pr
@@ -31,16 +31,20 @@ def added(tmp_path_factory):
     return root
 
 
+STDERR = {}  # a cell's standard error, by _run
+
+
 def _run(root, cell):
     """One run of a cell through the command's own entry."""
     from chipbench import run
 
-    out = io.StringIO()
-    with redirect_stdout(out):
+    out, err = io.StringIO(), io.StringIO()
+    with redirect_stdout(out), redirect_stderr(err):
         run.main(["--workload", cell, "--seed", str(2 ** 31 + 12345),
                   "--seconds", "3", "--trace", "0", "--root", root],
                  platform="cpu")
     lines = out.getvalue().strip().splitlines()
+    STDERR[cell] = err.getvalue().strip().splitlines()
     return lines, json.loads(lines[-1])
 
 
@@ -55,9 +59,15 @@ def wide_result(added):
 
 
 def test_last_line_has_exactly_the_contract_keys(result):
-    _, obj = result
-    assert set(obj) - {"extra"} == {"correct", "attempted", "failed",
-                                    "metrics", "device"}
+    lines, obj = result
+    assert set(obj) - {"extra", "compared"} == {
+        "correct", "attempted", "failed", "metrics", "device"}
+    # each number compared beside its limit, last in the line
+    assert list(obj)[-1] == "compared"
+    assert [f"compared: {name} = {c['value']} (limit {c['limit']})"
+            for name, c in obj["compared"].items()
+            ] == [ln for ln in lines if ln.startswith("compared: ")
+                  ] == STDERR["extra-cell"][-len(obj["compared"]):]
     assert set(obj["device"]) == {"platform", "kind", "count",
                                   "memory_peak_bytes"}
     assert obj["device"]["platform"] == "cpu" and obj["device"]["count"] == 1
@@ -166,7 +176,7 @@ def test_nested_keys_reach_the_program_and_the_reference(added, wide_result):
     for got in (program, reference):
         assert got == want
         assert got["rope_scaling"] == {"rope_type": "linear", "factor": 1.0}
-        assert got["layer_types"] == ["full_attention"] * 4
+        assert got["layer_types"] == ["full_attention"] * 8
         assert not HARNESS_KEYS & set(got)
 
 
@@ -198,8 +208,7 @@ def test_every_init_of_the_program_takes_the_adapter_sizes():
             p.kind is p.VAR_KEYWORD for p in params.values()), arch
 
 
-def test_a_reader_that_finds_nothing_is_left_out():
-    reg = Registry(REPO)
+def test_a_reader_that_finds_nothing_is_left_out(reg):
     ctx = types.SimpleNamespace(device=None, due=[], steps=[], traces=[],
                                 traffic={"limits": {}})
     for name in ("kernel_share", "device_idle", "paged_attn_roofline",
@@ -232,8 +241,8 @@ def test_unknown_device_has_no_peaks():
         peaks.peaks_for("cpu")
 
 
-def test_roofline_bytes_are_the_live_kv():
+def test_roofline_bytes_are_the_live_kv(reg):
     from chipbench import peaks
 
-    hf = Registry(REPO).config("mistral-7b-l16")
+    hf = reg.config("mistral-7b-l16")
     assert peaks.kv_bytes_per_token_per_layer(hf) == 2 * 8 * 128 * 2

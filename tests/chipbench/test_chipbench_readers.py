@@ -13,6 +13,10 @@ copy of the small recorded-format trace (``data/phases.xplane.pbtxt``):
                 fusion.50 19000..20000               (head)
   host          engine.step 11000..19500, engine.readback 12500..13500,
                 engine.build 15500..18500
+
+``reg`` (``conftest``) is the repo's own root, then its copy with a later
+PR's addition: the metric files come from the root, the readers from
+beside the module.
 """
 
 import os
@@ -21,14 +25,8 @@ import types
 import pytest
 
 from chipbench import xplane
-from chipbench.registry import REPO, Registry
 
 DATA = os.path.join(os.path.dirname(__file__), "data")
-
-
-@pytest.fixture(scope="module")
-def reg():
-    return Registry(REPO)
 
 
 def _read(reg, metric, ctx):

@@ -137,3 +137,120 @@ def test_rounded_activations_leave_the_reference_alone_when_off(tiny):
                           activations="float8_e4m3fn")
     assert np.array_equal(a, b) and np.array_equal(kv_a[0][0], kv_b[0][0])
     assert float(np.sqrt(np.mean((a - c) ** 2))) > 0.05
+
+
+# -- ``check.kv_layers``: which layers' cache is compared ----------------
+
+@pytest.fixture(scope="module")
+def tiny_engine(tmp_path_factory):
+    """(registry, configuration, engine) of the tiny preset, built as
+    ``chipbench.control`` builds one."""
+    from chipbench.stack import Stack, write_model_dir
+
+    registry = Registry(DATA)
+    config = registry.config("tiny-llama")
+    model_dir = write_model_dir(config, str(tmp_path_factory.mktemp("kv")),
+                                "tiny-llama")
+    stack = Stack(model_dir, "tiny-llama", config["server_flags"], 31,
+                  devices=jax.devices()[:1])
+    yield registry, config, stack.core
+    stack.core.stop()
+    stack.free_device_memory()
+
+
+class _KeysOff:
+    """The engine with the keys of one layer's pages off by a quarter
+    where the check reads them: the fault a cache of another kind (a
+    window's ring behind the full layer at 0) would bring."""
+
+    def __init__(self, core, layer):
+        self._core, self._layer = core, layer
+
+    def __getattr__(self, name):
+        return getattr(self._core, name)
+
+    def extract_kv(self, tokens):
+        got = dict(self._core.extract_kv(tokens))
+        keys = np.array(got["k"], np.float32)
+        keys[:, self._layer] *= 1.25
+        got["k"] = keys
+        return got
+
+
+def _two_layers(config: dict) -> dict:
+    """The configuration with both layers' pages compared. A sound run
+    reads the second layer's at 0.0077-0.0084 where the first reads
+    0.0017-0.0018 (three seeds, CPU): the bf16 activations before it.
+    So the largest over the layers gets a limit of its own, twice that,
+    and the first layer keeps the one it had."""
+    check = config["check"]
+    first = check["limits"]["kv_small_rel_rms"]
+    return {**config, "check": {**check, "kv_layers": [0, 1], "limits": {
+        **check["limits"], "kv_small_rel_rms": 0.016,
+        "kv_small_rel_rms_layer0": first}}}
+
+
+def test_kv_layers_names_the_layers_whose_pages_are_compared(tiny_engine):
+    from chipbench.check import run_check
+
+    registry, config, core = tiny_engine
+    assert "kv_layers" not in config["check"]
+    both = _two_layers(config)
+    first = run_check(registry, config, 31, core)
+    two = run_check(registry, both, 31, core)
+    assert first["ok"] and two["ok"], (first["numbers"], two["numbers"])
+    assert not any(name.startswith("kv_small_rel_rms_layer")
+                   for name in first["numbers"])
+    assert (two["numbers"]["kv_small_rel_rms_layer0"]
+            == first["numbers"]["kv_small_rel_rms"])
+    assert two["numbers"]["kv_small_rel_rms"] == max(
+        two["numbers"]["kv_small_rel_rms_layer0"],
+        two["numbers"]["kv_small_rel_rms_layer1"])
+    assert (two["numbers"]["kv_entries_compared"]
+            == 2 * first["numbers"]["kv_entries_compared"])
+    # a fault in layer 1's pages: seen only where the block names layer 1
+    faulty = _KeysOff(core, 1)
+    assert run_check(registry, config, 31, faulty)["ok"]
+    seen = run_check(registry, both, 31, faulty)
+    over = {name for name, limit in seen["limits"].items()
+            if seen["numbers"][name] > limit}
+    assert not seen["ok"] and over == {"kv_small_rel_rms"}
+    assert seen["numbers"]["kv_small_rel_rms_layer1"] > 3 * 0.016
+    # the same fault in layer 0 is seen by the default block too
+    assert not run_check(registry, config, 31, _KeysOff(core, 0))["ok"]
+    in_first = run_check(registry, both, 31, _KeysOff(core, 0))
+    assert (in_first["numbers"]["kv_small_rel_rms_layer0"]
+            > in_first["limits"]["kv_small_rel_rms_layer0"])
+
+
+def test_kv_layers_reach_the_control_in_the_programs_place(tiny):
+    """The reference in the program's place hands out the pages of every
+    layer named, and the control fails on each."""
+    from chipbench.check import run_check
+
+    verdict = run_check(Registry(DATA), _two_layers(tiny), 5, None,
+                        reference_activations="float8_e4m3fn")
+    numbers, limits = verdict["numbers"], verdict["limits"]
+    assert not verdict["ok"]
+    assert numbers["kv_small_rel_rms_layer0"] > limits[
+        "kv_small_rel_rms_layer0"]
+    assert numbers["kv_small_rel_rms_layer1"] > limits["kv_small_rel_rms"]
+
+
+@pytest.mark.parametrize("layer", [0, 1, 2])
+def test_pages_of_a_layer_are_that_layers(layer):
+    """[N, L, bs, KVH, D] pages, plain and as (int8 data, scales)."""
+    from chipbench.check import _pages_to_tokens
+
+    rng = np.random.default_rng(7)
+    pages = rng.standard_normal((3, 4, 2, 2, 8)).astype(np.float32)
+    want = pages[:, layer].reshape(6, 2, 8)
+    assert np.array_equal(_pages_to_tokens(pages, layer), want)
+    data = rng.integers(-127, 128, size=pages.shape).astype(np.int8)
+    scales = rng.random((3, 4, 2, 2)).astype(np.float32)
+    got = _pages_to_tokens((data, scales), layer)
+    assert np.array_equal(got, (data[:, layer].astype(np.float32)
+                                * scales[:, layer][..., None]
+                                ).reshape(6, 2, 8))
+    if layer == 0:
+        assert np.array_equal(_pages_to_tokens(pages), want)

@@ -1,4 +1,6 @@
-"""The schedule is drawn from the traffic file alone."""
+"""The schedule is drawn from the traffic file alone. ``reg``
+(``conftest``) is the repo's own root, then its copy with a later PR's
+addition."""
 
 import json
 import os
@@ -9,22 +11,17 @@ import later_pr
 import pytest
 
 from chipbench import schedule
-from chipbench.registry import REPO, Registry
+from chipbench.registry import Registry
 
 DATA = os.path.join(os.path.dirname(__file__), "data")
 
 
-@pytest.fixture(scope="module")
-def real():
-    return Registry(REPO)
-
-
 @pytest.mark.parametrize("traffic", ["sessions", "backlog"])
-def test_same_file_same_bytes(real, traffic):
-    t = real.traffic(traffic)
-    a = json.dumps(schedule.build(real, t, 20, 32000), sort_keys=True)
+def test_same_file_same_bytes(reg, traffic):
+    t = reg.traffic(traffic)
+    a = json.dumps(schedule.build(reg, t, 20, 32000), sort_keys=True)
     random.seed(12345)  # the global generator is not what it draws from
-    b = json.dumps(schedule.build(real, t, 20, 32000), sort_keys=True)
+    b = json.dumps(schedule.build(reg, t, 20, 32000), sort_keys=True)
     assert a == b
 
 
@@ -42,17 +39,17 @@ def test_seed_never_reaches_the_schedule():
     assert "self.seed" not in src.split("_drive(")[0]
 
 
-def test_a_longer_window_extends_the_same_schedule(real):
-    t = real.traffic("sessions")
-    short = schedule.build(real, t, 10, 32000)["requests"]
-    long = schedule.build(real, t, 20, 32000)["requests"]
+def test_a_longer_window_extends_the_same_schedule(reg):
+    t = reg.traffic("sessions")
+    short = schedule.build(reg, t, 10, 32000)["requests"]
+    long = schedule.build(reg, t, 20, 32000)["requests"]
     assert long[:len(short)] == short and len(long) > len(short)
 
 
-def test_sessions_schedule_has_the_stated_shape(real):
-    t = real.traffic("sessions")
+def test_sessions_schedule_has_the_stated_shape(reg):
+    t = reg.traffic("sessions")
     p = t["params"]
-    s = schedule.build(real, t, 30, 32000)
+    s = schedule.build(reg, t, 30, 32000)
     assert len(s["preload"]) == p["sessions"]
     starts = {tuple(h[:p["system_prompt_tokens"]]) for h in s["preload"]}
     assert len(starts) == p["sessions"] // p["sessions_per_system_prompt"]
@@ -75,9 +72,9 @@ def test_sessions_schedule_has_the_stated_shape(real):
         seen[r["session"]] = r["prompt"]
 
 
-def test_backlog_prompts_share_nothing(real):
-    t = real.traffic("backlog")
-    s = schedule.build(real, t, 20, 32000)
+def test_backlog_prompts_share_nothing(reg):
+    t = reg.traffic("backlog")
+    s = schedule.build(reg, t, 20, 32000)
     assert len(s["requests"]) == t["params"]["requests"]
     assert all(r["due"] == 0.0 for r in s["requests"])
     firsts = [tuple(r["prompt"][:64]) for r in s["requests"]]
@@ -89,16 +86,16 @@ def test_backlog_prompts_share_nothing(real):
         assert len(r["prompt"]) + r["max_tokens"] <= 4096
 
 
-def test_contexts_stay_within_the_assumed_window(real):
+def test_contexts_stay_within_the_assumed_window(reg):
     """Every cell's longest context fits its ``--max-model-len``, and a
     published ``sliding_window`` that the program does not apply
     (``assumed`` says so) where there is one. Both hold for both cells of
     the configuration the benchmark has."""
-    assert rules.schedule_faults(real) == []
-    cells = [c for c in real.bench["workloads"]
+    assert rules.schedule_faults(reg) == []
+    cells = [c for c in reg.bench["workloads"]
              if c["config"] == "mistral-7b-l16"]
     assert len(cells) >= 2
-    config = real.config("mistral-7b-l16")
+    config = reg.config("mistral-7b-l16")
     assert rules.window_binds(config)
     assert config["sliding_window"] == rules.max_model_len(config) == 4096
 

@@ -92,14 +92,12 @@ def test_a_trace_without_a_device_plane_is_an_error():
         xplane.reduce({"/host:CPU": {"t": [("x", 0.0, 1.0)]}})
 
 
-def test_roofline_counts_the_bursts_of_the_traced_span_only(reduced):
+def test_roofline_counts_the_bursts_of_the_traced_span_only(reduced, reg):
     """Bytes from the step recorder's bursts inside the traced span, time
-    from the trace: bursts elsewhere in the window do not enter."""
+    from the trace: bursts elsewhere in the window do not enter. On the
+    repo's own root and on its copy with a later PR's addition."""
     import types
 
-    from chipbench.registry import REPO, Registry
-
-    reg = Registry(REPO)
     spec = reg.load_json("metrics", "paged_attn_roofline_pct.batch")
     reader = reg.module("readers", spec["reader"])
     burst = {"kind": "decode_burst", "forwards": 8, "kv_read_tokens": 8 * 4096}
