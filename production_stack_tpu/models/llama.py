@@ -5,6 +5,14 @@ Functional JAX implementation built for serving with a paged KV cache:
 - parameters are a pytree with per-layer leaves stacked on a leading axis so
   the decoder runs as one ``lax.scan`` over layers (single-layer trace →
   fast XLA compiles even at 80 layers);
+- the attention block's three input projections are ONE leaf,
+  ``layers/wqkv`` ``[L, Hd, KVH * (G + 2) * D]`` (:func:`fuse_qkv`: for
+  each KV head its ``G = H / KVH`` query heads, then its key, then its
+  value), read by one flat matmul whose weight operand is a slice of that
+  leaf in place, like ``wo`` and the MLP matrices. Three leaves, each
+  reshaped to heads right after its matmul, made the TPU compiler copy
+  and transpose all three out of the stack in every layer of every
+  forward (PERF.md section 6, PR 30);
 - every forward writes fresh K/V into HBM pages (``ops.write_kv_pages``) and
   attends either causally within the prompt (prefill) or over the pages via
   paged attention (decode);
@@ -22,6 +30,7 @@ from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from production_stack_tpu.models.config import ModelConfig
 from production_stack_tpu.ops.attention import (
@@ -57,6 +66,39 @@ def rope(
     return out.astype(x.dtype)
 
 
+def fuse_qkv(wq, wk, wv, num_kv_heads: int):
+    """The ``wqkv`` leaf from the three projections ``[..., Hd, H * D]``,
+    ``[..., Hd, KVH * D]`` x 2: columns grouped by KV head, for each its
+    query heads (in the standard order: query head ``h`` belongs to KV
+    head ``h // G``), then its key, then its value. A re-arrangement of
+    columns and nothing else, so per-column int8 scales and every
+    contraction over ``Hd`` are what they were, and a ``tp`` shard of the
+    last axis holds whole groups. Works on host (numpy) and device arrays
+    alike: the checkpoint loader joins on the host."""
+    lead = wq.shape[:-1]
+    head_dim = wk.shape[-1] // num_kv_heads
+    xp = np if isinstance(wq, np.ndarray) else jnp
+    groups = [w.reshape(lead + (num_kv_heads, -1, head_dim))
+              for w in (wq, wk, wv)]
+    return xp.concatenate(groups, axis=-2).reshape(lead + (-1,))
+
+
+def _split_qkv(y: jax.Array, cfg: ModelConfig):
+    """Flat q, k, v ``[B, T, heads * D]`` from the fused projection's
+    output ``[B, T, KVH * (G + 2) * D]``. The barrier keeps the matmul
+    flat: without it the compiler folds the reshape into the dot, wants
+    the weight with ``Hd`` minor, and copies + transposes it out of the
+    stacked leaf in every layer (PERF.md section 6, PR 30). What is split
+    here is the activations, 6,144 values a token at Mistral's widths."""
+    B, T, _ = y.shape
+    H, KVH, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    G = H // KVH
+    y = jax.lax.optimization_barrier(y).reshape(B, T, KVH, G + 2, D)
+    return (y[:, :, :, :G].reshape(B, T, H * D),
+            y[:, :, :, G].reshape(B, T, KVH * D),
+            y[:, :, :, G + 1].reshape(B, T, KVH * D))
+
+
 def init_params(
     cfg: ModelConfig,
     rng: jax.Array,
@@ -88,9 +130,12 @@ def init_params(
         "embed": (0.02 * jax.random.normal(keys[0], (V, Hd), jnp.float32)).astype(dtype),
         "layers": {
             "attn_norm": jnp.ones((L, Hd), dtype),
-            "wq": stack(keys[1], (Hd, H * D), Hd),
-            "wk": stack(keys[2], (Hd, KVH * D), Hd),
-            "wv": stack(keys[3], (Hd, KVH * D), Hd),
+            # Drawn as three matrices (the recipe chipbench's reference
+            # redraws by itself), served as one leaf.
+            "wqkv": fuse_qkv(
+                stack(keys[1], (Hd, H * D), Hd),
+                stack(keys[2], (Hd, KVH * D), Hd),
+                stack(keys[3], (Hd, KVH * D), Hd), KVH),
             "wo": stack(keys[4], (H * D, Hd), H * D),
             "mlp_norm": jnp.ones((L, Hd), dtype),
             "w_gate": stack(keys[5], (Hd, I), Hd),
@@ -162,8 +207,7 @@ def _layer(
     # under (docs/profiling.md): metadata only, no operation changes.
     with jax.named_scope("attn_proj"):
         h = rms_norm(x, p["attn_norm"], cfg.rms_norm_eps)
-        q_flat = _proj(h, p, "wq")
-        v_flat = _proj(h, p, "wv")
+        q_flat, k_flat, v_flat = _split_qkv(_proj(h, p, "wqkv"), cfg)
         if lora is not None:
             q_flat = q_flat + _lora_delta(
                 h, lora["wq_a"], lora["wq_b"], lora_scaling, adapter_ids
@@ -172,7 +216,7 @@ def _layer(
                 h, lora["wv_a"], lora["wv_b"], lora_scaling, adapter_ids
             )
         q = q_flat.reshape(B, T, H, D)
-        k = _proj(h, p, "wk").reshape(B, T, KVH, D)
+        k = k_flat.reshape(B, T, KVH, D)
         v = v_flat.reshape(B, T, KVH, D)
         q = rope(q, positions, cfg.rope_theta)
         k = rope(k, positions, cfg.rope_theta)

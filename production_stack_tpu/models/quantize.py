@@ -33,7 +33,10 @@ import numpy as np
 
 # Weight leaves quantized for the llama family; everything else (norms,
 # LoRA slots) stays bf16 — they are a rounding error of the total bytes.
-_LLAMA_LAYER_KEYS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
+# ``wqkv`` is the three input projections' columns joined
+# (models/llama.py::fuse_qkv): a scale is per output column over Hd, so
+# each column's int8 values and scale are what its own matrix would give.
+_LLAMA_LAYER_KEYS = ("wqkv", "wo", "w_gate", "w_up", "w_down")
 
 # Symmetric int8 range. 127 (not 128) keeps the scale exact for the max.
 _QMAX = 127.0

@@ -5,7 +5,10 @@ The reference stack mounts HF weights into PVCs and lets vLLM load them
 (``helm/values.yaml`` pvcStorage + modelURL); here the engine loads them
 natively. Layer leaves are stacked on a leading axis (the models run one
 ``lax.scan`` over layers), and projection matrices are transposed from
-HF's ``[out, in]`` to our ``x @ W`` ``[in, out]`` layout.
+HF's ``[out, in]`` to our ``x @ W`` ``[in, out]`` layout. The llama
+loader joins each layer's ``q_proj``/``k_proj``/``v_proj`` on the host
+into the one ``wqkv`` leaf the model reads (models/llama.py::fuse_qkv),
+so the three never sit beside it on the device.
 
 Entry point: :func:`load_checkpoint` — returns a params pytree matching
 ``init_params`` of the target architecture, or raises with the list of
@@ -24,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from production_stack_tpu.models.config import ModelConfig
+from production_stack_tpu.models.llama import fuse_qkv
 from production_stack_tpu.utils.log import init_logger
 
 logger = init_logger(__name__)
@@ -65,18 +69,20 @@ def _load_llama(cfg: ModelConfig, path: str) -> Dict:
     dtype = cfg.jnp_dtype
     per_layer: Dict[str, List] = {
         k: [None] * L for k in (
-            "attn_norm", "wq", "wk", "wv", "wo",
+            "attn_norm", "wqkv", "wo",
             "mlp_norm", "w_gate", "w_up", "w_down",
         )
     }
+    # q/k/v of a layer wait here, on the host, until all three are read.
+    qkv_parts: List[Dict[str, np.ndarray]] = [{} for _ in range(L)]
     top: Dict[str, jnp.ndarray] = {}
     unmapped = []
 
     layer_map = {
         "input_layernorm.weight": ("attn_norm", False),
-        "self_attn.q_proj.weight": ("wq", True),
-        "self_attn.k_proj.weight": ("wk", True),
-        "self_attn.v_proj.weight": ("wv", True),
+        "self_attn.q_proj.weight": ("q", True),
+        "self_attn.k_proj.weight": ("k", True),
+        "self_attn.v_proj.weight": ("v", True),
         "self_attn.o_proj.weight": ("wo", True),
         "post_attention_layernorm.weight": ("mlp_norm", False),
         "mlp.gate_proj.weight": ("w_gate", True),
@@ -100,16 +106,28 @@ def _load_llama(cfg: ModelConfig, path: str) -> Dict:
                 unmapped.append(name)
                 continue
             key, transpose = entry
-            per_layer[key][i] = _to_dtype(
-                arr.T if transpose else arr, dtype)
+            if transpose:
+                arr = arr.T
+            if key in ("q", "k", "v"):
+                parts = qkv_parts[i]
+                parts[key] = arr
+                if len(parts) == 3:
+                    per_layer["wqkv"][i] = _to_dtype(
+                        fuse_qkv(parts.pop("q"), parts.pop("k"),
+                                 parts.pop("v"), cfg.num_kv_heads), dtype)
+                continue
+            per_layer[key][i] = _to_dtype(arr, dtype)
         elif name.endswith("rotary_emb.inv_freq"):
             continue  # computed, not a parameter
         else:
             unmapped.append(name)
 
     missing = [
-        f"layers.{k}[{i}]" for k, v in per_layer.items()
+        f"layers.{k}[{i}]" for k, v in per_layer.items() if k != "wqkv"
         for i, leaf in enumerate(v) if leaf is None
+    ] + [
+        f"layers.{k}_proj[{i}]" for i, parts in enumerate(qkv_parts)
+        if per_layer["wqkv"][i] is None for k in "qkv" if k not in parts
     ]
     for req_key in ("embed", "final_norm"):
         if req_key not in top:

@@ -4,7 +4,10 @@ Megatron-style tensor parallelism expressed as NamedSharding specs — XLA
 GSPMD inserts the all-reduces over ICI (this replaces the NCCL collectives
 inside the reference's vLLM engines):
 
-- attention qkv projections: column-parallel on the head dimension;
+- attention qkv projections: column-parallel on the head dimension
+  (Llama's one ``wqkv`` leaf has its columns grouped by KV head,
+  models/llama.py::fuse_qkv, so a shard of its last axis holds whole
+  groups: a KV head with its query heads, like the KV pages' shard);
   ``wo``: row-parallel (all-reduce after).
 - MLP up/gate: column-parallel on intermediate; down: row-parallel.
 - MoE experts: sharded on the expert axis (``ep`` == ``tp`` axis here).
@@ -35,9 +38,7 @@ _LLAMA_SPECS = {
     ("lm_head",): P(None, "tp"),
     ("layers", "attn_norm"): P(None, None),
     ("layers", "mlp_norm"): P(None, None),
-    ("layers", "wq"): P(None, None, "tp"),
-    ("layers", "wk"): P(None, None, "tp"),
-    ("layers", "wv"): P(None, None, "tp"),
+    ("layers", "wqkv"): P(None, None, "tp"),
     ("layers", "wo"): P(None, "tp", None),
     ("layers", "w_gate"): P(None, None, "tp"),
     ("layers", "w_up"): P(None, None, "tp"),

@@ -22,7 +22,8 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
-from production_stack_tpu.models import get_model_config
+from production_stack_tpu.models import get_model_config, llama
+from production_stack_tpu.models.config import ModelConfig
 from production_stack_tpu.ops import attention as att
 from production_stack_tpu.ops.pallas_paged_attention import (
     pallas_paged_attention,
@@ -172,3 +173,54 @@ def test_sharded_decode_attention_on_four_chips(topo, monkeypatch):
             r"\b(all-gather|all-reduce|all-to-all|collective-permute)", text)
         held = program.memory_analysis().argument_size_in_bytes
         assert held < 2 * pool_side * kvh / 4 * 1.1
+
+
+@pytest.mark.parametrize("mode,rows,width", [
+    ("decode", 32, 1), ("prefill", 1, 256), ("prefill_cached", 1, 256)])
+def test_layer_scan_reads_stacked_weights_in_place(one_chip, monkeypatch,
+                                                   mode, rows, width):
+    """Every matrix of a layer is read by its matmul straight out of the
+    stacked ``[L, ...]`` leaf. With three projection leaves, each reshaped
+    to heads right after its matmul, the compiler sliced all three out of
+    the stack into VMEM and transposed them, in every layer of every
+    forward (PERF.md section 6, PR 30): a loop fusion or a copy whose
+    result is as large as a layer's smallest matrix is that again.
+    Mistral-7B's widths, the default server's LoRA slots; activations at
+    these rows stay under that size."""
+    cfg = ModelConfig(
+        name="mistral-7b-widths", arch="llama", vocab_size=32000,
+        hidden_size=4096, num_layers=LAYERS, num_heads=32, num_kv_heads=8,
+        head_dim=128, intermediate_size=14336, rope_theta=10000.0)
+    monkeypatch.setattr(att, "_use_pallas", lambda: True)
+
+    def spec(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda x: spec(x.shape, x.dtype),
+        jax.eval_shape(lambda: llama.init_params(
+            cfg, jax.random.key(0), lora_slots=8, lora_rank=16)))
+    pages = _pages(one_chip, cfg.num_kv_heads, cfg.head_dim, False)
+    # The pool is donated, as the engine's step programs donate it:
+    # otherwise the program's entry copies it whole.
+    text = jax.jit(
+        lambda p, tok, pos, kv, slot, bt, cl, sl, aid: llama.apply(
+            p, cfg, tok, pos, kv, slot, bt, cl, sl, mode=mode,
+            adapter_ids=aid), donate_argnums=(3,)).lower(
+        params, spec((rows, width)), spec((rows, width)), (pages, pages),
+        spec((rows, width)), spec((rows, 64)), spec((rows,)),
+        spec((rows,)), spec((rows,))).compile().as_text()
+    assert "tpu_custom_call" in text or mode == "prefill"
+
+    smallest = cfg.hidden_size * cfg.num_kv_heads * cfg.head_dim
+    moved, fused = [], False
+    for line in text.splitlines():
+        if not line.startswith(" "):  # a computation's header, or its end
+            fused = line.startswith("%fused_computation")
+        m = re.match(r"\s+(?:ROOT )?%([\w.\-]+) = bf16\[([\d,]+)\]", line)
+        # Instructions of the program itself, not of a fusion's inside
+        # (where a matmul's own slice of its operand lives).
+        if m and not fused and (" copy(" in line or "kind=kLoop" in line):
+            if np.prod([int(d) for d in m.group(2).split(",")]) >= smallest:
+                moved.append((m.group(1), m.group(2)))
+    assert not moved, moved

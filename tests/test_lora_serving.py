@@ -29,6 +29,7 @@ from production_stack_tpu.engine.config import EngineConfig
 from production_stack_tpu.engine.core import EngineCore
 from production_stack_tpu.engine.sampling import SamplingParams
 from production_stack_tpu.models import get_model_config
+from production_stack_tpu.models.llama import fuse_qkv
 
 ADAPTER = "sql-expert"
 RANK = 16  # must equal max_lora_rank: the slot scatter takes full-rank operands
@@ -125,10 +126,12 @@ def test_served_adapter_matches_offline_merged_weights():
                                  weights["wv_a"], weights["wv_b"])
         with eng2._lock:
             layers = dict(eng2.params["layers"])
-            layers["wq"] = layers["wq"] + jnp.asarray(
-                dq, layers["wq"].dtype)
-            layers["wv"] = layers["wv"] + jnp.asarray(
-                dv, layers["wv"].dtype)
+            # The deltas' columns in the fused leaf's order: q and v
+            # move, k (a zero delta) does not.
+            dk = np.zeros_like(dv)
+            layers["wqkv"] = layers["wqkv"] + jnp.asarray(
+                fuse_qkv(dq, dk, dv, eng2.model_config.num_kv_heads),
+                layers["wqkv"].dtype)
             eng2.params = {**eng2.params, "layers": layers}
         merged, merged_fin = _collect(eng2, prompt, greedy, rid="merged-1")
     finally:

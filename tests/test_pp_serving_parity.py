@@ -88,10 +88,10 @@ def test_pp_sharded_matches_single_device(pp, tp, baseline_tokens):
     core = _build(pp=pp, tp=tp)
     # The mesh really has a pp axis and the layer stack really stage-shards.
     assert core.mesh.shape["pp"] == pp
-    wq_spec = str(core.params["layers"]["wq"].sharding.spec)
-    assert "pp" in wq_spec
+    wqkv_spec = str(core.params["layers"]["wqkv"].sharding.spec)
+    assert "pp" in wqkv_spec
     if tp > 1:
-        assert "tp" in wq_spec
+        assert "tp" in wqkv_spec
     kv_spec = str(core.kv[0].sharding.spec)
     assert "pp" in kv_spec
     core.start()

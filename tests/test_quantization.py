@@ -36,13 +36,14 @@ def test_int8_logits_close_to_bf16():
     params = init_params(cfg, jax.random.key(0))
     qparams = jax.jit(lambda p: quantize_tree(p, "llama"))(params)
 
-    assert qparams["layers"]["wq"].dtype == jnp.int8
+    assert qparams["layers"]["wqkv"].dtype == jnp.int8
     # embed / lm_head stay bf16 by default (head/embedding quantization
     # disproportionately hurts output quality for ~no HBM win).
     assert qparams["embed"].dtype == cfg.jnp_dtype
     assert "embed_scale" not in qparams
-    assert qparams["layers"]["wq_scale"].shape == (
-        cfg.num_layers, 1, cfg.num_heads * cfg.head_dim)
+    assert qparams["layers"]["wqkv_scale"].shape == (
+        cfg.num_layers, 1,
+        (cfg.num_heads + 2 * cfg.num_kv_heads) * cfg.head_dim)
     q_all = jax.jit(
         lambda p: quantize_tree(p, "llama", quantize_embeddings=True)
     )(params)
@@ -75,15 +76,15 @@ def test_quantize_loaded_matches_quantize_tree():
     # XLA's fused division can differ from numpy by a ULP, flipping
     # round-to-nearest at exact ties on a tiny fraction of weights —
     # allow |diff| <= 1 on <0.1% of entries, scales must match tightly.
-    for dev, hostq in ((q_dev["layers"]["wq"], q_host["layers"]["wq"]),
+    for dev, hostq in ((q_dev["layers"]["wqkv"], q_host["layers"]["wqkv"]),
                        (q_dev["embed"], q_host["embed"])):
         diff = np.abs(np.asarray(dev, np.int32)
                       - np.asarray(hostq, np.int32))
         assert diff.max() <= 1
         assert (diff != 0).mean() < 1e-3
     np.testing.assert_allclose(
-        np.asarray(q_dev["layers"]["wq_scale"]),
-        q_host["layers"]["wq_scale"], rtol=1e-6)
+        np.asarray(q_dev["layers"]["wqkv_scale"]),
+        q_host["layers"]["wqkv_scale"], rtol=1e-6)
 
 
 def test_engine_serves_with_int8_and_halves_weight_bytes():
@@ -115,7 +116,7 @@ def test_engine_serves_with_int8_and_halves_weight_bytes():
             big_bytes = sum(
                 leaf.nbytes for leaf in
                 jax.tree_util.tree_leaves(core.params["layers"]))
-            return toks, big_bytes, core.params["layers"]["wq"].dtype
+            return toks, big_bytes, core.params["layers"]["wqkv"].dtype
         finally:
             core.stop()
 
@@ -167,7 +168,7 @@ def test_no_bf16_full_weight_leaf_live_after_int8_init():
         core.start()
         try:
             cfg = core.model_config
-            # Smallest full-weight leaf in bf16: the stacked wq stack.
+            # Smallest full-weight leaf in bf16: the stacked wo stack.
             threshold = (cfg.num_layers * cfg.hidden_size
                          * cfg.num_heads * cfg.head_dim * 2)
             leaves = jax.tree_util.tree_leaves(core.params)

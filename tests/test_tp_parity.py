@@ -63,8 +63,8 @@ def test_tp_sharded_matches_single_device(tp):
     sharded = build(tp)
     # Sanity: the mesh really has tp devices and weights really shard.
     assert sharded.mesh.shape["tp"] == tp
-    wq_shard = sharded.params["layers"]["wq"].sharding
-    assert "tp" in str(wq_shard.spec)
+    wqkv_shard = sharded.params["layers"]["wqkv"].sharding
+    assert "tp" in str(wqkv_shard.spec)
     sharded.start()
     try:
         out_sharded = _run(sharded, prompt)

@@ -11,6 +11,7 @@ from production_stack_tpu.engine.config import EngineConfig
 from production_stack_tpu.engine.core import EngineCore
 from production_stack_tpu.engine.sampling import SamplingParams
 from production_stack_tpu.models import build_model, get_model_config
+from production_stack_tpu.models.llama import fuse_qkv
 from production_stack_tpu.models.weights import has_checkpoint, load_checkpoint
 
 
@@ -48,10 +49,14 @@ def test_llama_leaf_mapping(llama_ckpt):
     np.testing.assert_allclose(
         np.asarray(params["embed"]),
         sd["model.embed_tokens.weight"].numpy(), atol=1e-6)
-    # Projections are transposed into x @ W layout; layer leaves stacked.
+    # Projections are transposed into x @ W layout; layer leaves stacked;
+    # q/k/v joined into the fused leaf, columns grouped by KV head.
+    attn = "model.layers.1.self_attn."
     np.testing.assert_allclose(
-        np.asarray(params["layers"]["wq"][1]),
-        sd["model.layers.1.self_attn.q_proj.weight"].numpy().T, atol=1e-6)
+        np.asarray(params["layers"]["wqkv"][1]),
+        fuse_qkv(*(sd[attn + f"{n}_proj.weight"].numpy().T for n in "qkv"),
+                 cfg.num_kv_heads), atol=1e-6)
+    assert "wq" not in params["layers"]
     np.testing.assert_allclose(
         np.asarray(params["layers"]["w_down"][0]),
         sd["model.layers.0.mlp.down_proj.weight"].numpy().T, atol=1e-6)
