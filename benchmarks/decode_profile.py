@@ -107,15 +107,16 @@ def _ablate(core, *, attn=None, write=None, sample=False):
     import jax.numpy as jnp
 
     from production_stack_tpu.engine import core as core_mod
-    from production_stack_tpu.models import llama
+    from production_stack_tpu.models import decoder
 
     saved = {}
     if attn is not None:
-        saved[("llama", "paged_decode_attention")] = llama.paged_decode_attention
-        llama.paged_decode_attention = attn
+        saved[("decoder", "paged_decode_attention")] = (
+            decoder.paged_decode_attention)
+        decoder.paged_decode_attention = attn
     if write is not None:
-        saved[("llama", "write_kv_pages")] = llama.write_kv_pages
-        llama.write_kv_pages = write
+        saved[("decoder", "write_kv_pages")] = decoder.write_kv_pages
+        decoder.write_kv_pages = write
     if sample:
         saved[("core", "sample_tokens")] = core_mod.sample_tokens
         saved[("core", "logprob_outputs")] = core_mod.logprob_outputs
@@ -130,7 +131,7 @@ def _ablate(core, *, attn=None, write=None, sample=False):
 
     def restore():
         for (mod, name), v in saved.items():
-            setattr(llama if mod == "llama" else core_mod, name, v)
+            setattr(decoder if mod == "decoder" else core_mod, name, v)
 
     return restore
 

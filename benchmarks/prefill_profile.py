@@ -119,11 +119,12 @@ def _time_chunk(core, fn, chunk, offset, reps):
 
 
 def _ablate(*, attn=False, write=False):
-    """Patch the llama-module component globals; returns a restore
+    """Patch the attention entry points where the models call them
+    (models/decoder.py::attend, every family's); returns a restore
     callback. Fresh programs built afterwards trace the patched ops."""
     import jax.numpy as jnp
 
-    from production_stack_tpu.models import llama
+    from production_stack_tpu.models import decoder
 
     saved = {}
 
@@ -139,17 +140,17 @@ def _ablate(*, attn=False, write=False):
         return k_pages, v_pages
 
     if attn:
-        saved["prefill_attention"] = llama.prefill_attention
-        saved["context_prefill_attention"] = llama.context_prefill_attention
-        llama.prefill_attention = zero_prefill_attn
-        llama.context_prefill_attention = zero_context_attn
+        saved["prefill_attention"] = decoder.prefill_attention
+        saved["context_prefill_attention"] = decoder.context_prefill_attention
+        decoder.prefill_attention = zero_prefill_attn
+        decoder.context_prefill_attention = zero_context_attn
     if write:
-        saved["write_kv_pages"] = llama.write_kv_pages
-        llama.write_kv_pages = id_write
+        saved["write_kv_pages"] = decoder.write_kv_pages
+        decoder.write_kv_pages = id_write
 
     def restore():
         for name, v in saved.items():
-            setattr(llama, name, v)
+            setattr(decoder, name, v)
 
     return restore
 

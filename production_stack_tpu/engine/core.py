@@ -49,6 +49,7 @@ from production_stack_tpu.structured.tokenfsm import (
     mask_row_bytes,
 )
 from production_stack_tpu.models import build_model, get_model_config
+from production_stack_tpu.models.registry import get_family
 from production_stack_tpu.parallel import multihost
 from production_stack_tpu.parallel.mesh import build_mesh
 from production_stack_tpu.parallel.sharding import (
@@ -57,6 +58,7 @@ from production_stack_tpu.parallel.sharding import (
     kv_scale_block_sharding,
     kv_scale_sharding,
     param_shardings,
+    place_checkpoint,
 )
 from production_stack_tpu.utils.log import init_logger
 
@@ -265,6 +267,7 @@ class EngineCore:
         self._repl = NamedSharding(self.mesh, PartitionSpec())
 
         self._init_fn, self._apply = build_model(self.model_config)
+        family = get_family(self.model_config.arch)
         if pp > 1:
             # Stage-sharded serving: swap the layer stack for the GPipe
             # pipeline over the pp mesh axis. Same signature, so prefill /
@@ -272,7 +275,7 @@ class EngineCore:
             # top of it unchanged.
             from production_stack_tpu.parallel.pp_serving import make_pp_apply
 
-            if self.model_config.arch != "llama":
+            if not family.pipeline:
                 raise ValueError(
                     "pipeline_parallel_size > 1 is supported for the Llama "
                     f"family (model arch {self.model_config.arch!r})"
@@ -283,18 +286,18 @@ class EngineCore:
                     f"divisible by pipeline_parallel_size {pp}"
                 )
             self._apply = make_pp_apply(
-                self.mesh, microbatches=config.pp_microbatches or pp
+                self.mesh, family, microbatches=config.pp_microbatches or pp
             )
 
         # -- parameters (sharded over the mesh) ----------------------------
         lora_kwargs = {}
-        if self.model_config.arch == "llama" and config.max_loras > 0:
+        if family.lora and config.max_loras > 0:
             lora_kwargs = {
                 "lora_slots": config.max_loras,
                 "lora_rank": config.max_lora_rank,
             }
         rng = jax.random.key(config.seed)
-        if config.quantization and self.model_config.arch != "llama":
+        if config.quantization and not family.quant_keys:
             raise ValueError(
                 "int8 quantization is supported for the llama family "
                 f"(model arch {self.model_config.arch!r})")
@@ -637,31 +640,9 @@ class EngineCore:
                 loaded, self.model_config.arch,
                 quantize_embeddings=self.config.quantize_embeddings)
 
-        from jax.sharding import NamedSharding, PartitionSpec
-
-        replicated = NamedSharding(self.mesh, PartitionSpec())
-
-        def merge(dst: dict, src: dict, shard: dict) -> None:
-            for key, val in src.items():
-                if isinstance(val, dict):
-                    merge(dst.setdefault(key, {}), val, shard.get(key, {}))
-                else:
-                    # put_global: each process contributes its local
-                    # shards (device_put cannot target non-addressable
-                    # devices of a multi-host mesh; every process loads
-                    # the same checkpoint from its own disk).
-                    dst[key] = multihost.put_global(
-                        val, shard.get(key, replicated))
-
-        params = dict(self.params)
-        params["layers"] = dict(params["layers"])
-        merge(params, loaded, self._param_shardings)
-        if self.model_config.arch == "llama" and "lm_head" not in loaded:
-            # Tied-embedding checkpoint: drop the random head so apply()
-            # falls back to embed.T.
-            params.pop("lm_head", None)
-            params.pop("lm_head_scale", None)
-        self.params = params
+        self.params = place_checkpoint(
+            self.model_config, self.mesh, self.params, loaded,
+            self._param_shardings)
         # The host staging tree holds the FULL checkpoint (bf16 unless
         # quantize_loaded already shrank it) — on an 8B model that is
         # ~16 GB of host RAM pinned for the rest of the process if left

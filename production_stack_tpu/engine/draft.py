@@ -42,10 +42,10 @@ from production_stack_tpu.engine.kvcache import KVCacheManager
 from production_stack_tpu.engine.sampling import apply_fsm_mask
 from production_stack_tpu.models import build_model, get_model_config
 from production_stack_tpu.ops.attention import shard_paged_kernels
-from production_stack_tpu.parallel import multihost
 from production_stack_tpu.parallel.sharding import (
     kv_pages_sharding,
     param_shardings,
+    place_checkpoint,
 )
 from production_stack_tpu.utils.log import init_logger
 
@@ -128,25 +128,9 @@ class DraftModel:
         if not has_checkpoint(self.name):
             return
         loaded = load_checkpoint(self.model_config, self.name)
-        from jax.sharding import NamedSharding, PartitionSpec
-
-        replicated = NamedSharding(self.mesh, PartitionSpec())
-
-        def merge(dst: dict, src: dict, shard: dict) -> None:
-            for key, val in src.items():
-                if isinstance(val, dict):
-                    merge(dst.setdefault(key, {}), val, shard.get(key, {}))
-                else:
-                    dst[key] = multihost.put_global(
-                        val, shard.get(key, replicated))
-
-        params = dict(self.params)
-        params["layers"] = dict(params["layers"])
-        merge(params, loaded, self._param_shardings)
-        if self.model_config.arch == "llama" and "lm_head" not in loaded:
-            params.pop("lm_head", None)
-            params.pop("lm_head_scale", None)
-        self.params = params
+        self.params = place_checkpoint(
+            self.model_config, self.mesh, self.params, loaded,
+            self._param_shardings)
 
     # -- compiled programs -------------------------------------------------
     def _make_forward(self):

@@ -132,9 +132,11 @@ def _from_hf_config_json(path: str, name: str) -> ModelConfig:
     """Build a ModelConfig from a local HuggingFace config.json."""
     with open(path) as f:
         cfg = json.load(f)
-    model_type = cfg.get("model_type", "llama")
-    arch = {"llama": "llama", "mistral": "llama", "mixtral": "mixtral",
-            "opt": "opt"}.get(model_type, "llama")
+    # Outside input: a checkpoint no family claims (q/k/v biases, a
+    # window, another norm) would be served wrong as Llama, so it raises.
+    from production_stack_tpu.models.registry import arch_of_model_type
+
+    arch = arch_of_model_type(cfg.get("model_type", "llama"))
     heads = cfg.get("num_attention_heads", 32)
     hidden = cfg.get("hidden_size", 4096)
     return ModelConfig(

@@ -1,0 +1,158 @@
+"""The decoder skeleton every model family shares.
+
+A family (models/registry.py::Family) brings ``embed``, ``layer`` and
+``head``; what is the same for all of them is written here once:
+
+- :func:`attend`: every forward writes fresh K/V into HBM pages
+  (``ops.write_kv_pages``) and attends either causally within the prompt
+  (prefill), over the pages plus the fresh suffix (cached prefill) or over
+  the pages alone (decode). The only code under ``models/`` that imports
+  ``ops.attention`` and the only code that spells the three mode names:
+  a window mask, a latent cache or a second kind of cache state has this
+  one call site to change;
+- :func:`scan_layers`: one ``lax.scan`` over the layer-stacked leaves
+  (single-layer trace, fast compiles even at 80 layers) with the carry
+  convention of the paged pool;
+- :func:`apply`: embed -> layers -> the ``last_token`` slice -> head.
+  ``models.<family>.apply`` is this function bound to the family, and
+  ``parallel/pp_serving.py`` runs the same three parts as pipeline stages.
+"""
+
+from __future__ import annotations
+
+from typing import NamedTuple, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from production_stack_tpu.models.config import ModelConfig
+from production_stack_tpu.ops.attention import (
+    context_prefill_attention,
+    paged_decode_attention,
+    prefill_attention,
+    write_kv_pages,
+)
+
+
+class Batch(NamedTuple):
+    """What a layer reads of one forward beside its weights. Every field
+    but ``lora_scaling`` (per slot ``[S]``) has the batch axis first, so a
+    pipeline stage can cut them into microbatches."""
+
+    positions: jax.Array  # [B, T]
+    slot_mapping: jax.Array  # [B, T]
+    block_tables: jax.Array  # [B, MAXB]
+    context_lens: jax.Array  # [B]
+    seq_lens: jax.Array  # [B] valid prompt lengths (prefill padding mask)
+    adapter_ids: jax.Array | None = None  # [B] LoRA slot per sequence
+    lora_scaling: jax.Array | None = None
+
+
+def attend(
+    mode: str,  # "prefill" | "prefill_cached" | "decode"  (static)
+    q: jax.Array,  # [B, T, H, D]
+    k: jax.Array,  # [B, T, KVH, D]
+    v: jax.Array,  # [B, T, KVH, D]
+    kv: Tuple,  # STACKED pages ([L, NB, bs, KVH, D], same)
+    layer: jax.Array,  # scalar layer index
+    batch: Batch,
+    *,
+    scale: float,
+):
+    """Write this layer's fresh k/v into its pages, then attend. Returns
+    (attention output [B, T, H, D], updated pages)."""
+    k_pages, v_pages = write_kv_pages(
+        *kv, k, v, batch.slot_mapping, layer)
+    # The named scopes are what a profiler trace files the device's time
+    # under (docs/profiling.md): metadata only, no operation changes.
+    with jax.named_scope("attention"):
+        if mode == "prefill":
+            attn = prefill_attention(
+                q, k, v, scale=scale, seq_lens=batch.seq_lens)
+        elif mode == "prefill_cached":
+            # Suffix prefill after a prefix-cache hit: attend over HBM
+            # pages (cached prefix + just-written suffix). The chunk's own
+            # fresh k/v ride along so the flash kernel can serve the
+            # suffix from VMEM and stream only the cached prefix pages.
+            attn = context_prefill_attention(
+                q, k_pages, v_pages, batch.block_tables, batch.positions,
+                batch.context_lens, layer, scale=scale, k_new=k, v_new=v,
+                suffix_lens=batch.seq_lens,
+            )
+        else:
+            attn = paged_decode_attention(
+                q[:, 0], k_pages, v_pages, batch.block_tables,
+                batch.context_lens, layer, scale=scale,
+            )[:, None]
+    return attn, (k_pages, v_pages)
+
+
+def scan_layers(layer_fn, x: jax.Array, kv_pages: Tuple, xs):
+    """``layer_fn(x, per_layer, kv, l) -> (x, kv)`` over the leading axis
+    of every leaf of ``xs`` (``None`` is an empty pytree: a family
+    without LoRA slots scans ``(layers, None)``).
+
+    The STACKED KV pages ride the scan carry whole; every op addresses
+    them through the scalar layer index (flat scatter / page-level
+    gather). Loop carries alias in place under XLA, so only the touched
+    pages move: per-layer slices (or pages in the scan ys) would copy
+    the entire pool every forward step. With an int8 cache each side is
+    a (data, scales) tuple that rides the carry the same way."""
+    k_all, v_all = kv_pages
+
+    def body(carry, per_layer):
+        x, k_all, v_all, l = carry
+        x, (k_all, v_all) = layer_fn(x, per_layer, (k_all, v_all), l)
+        return (x, k_all, v_all, l + 1), None
+
+    (x, k_all, v_all, _), _ = jax.lax.scan(
+        body, (x, k_all, v_all, jnp.int32(0)), xs)
+    return x, (k_all, v_all)
+
+
+def take_last_token(x: jax.Array, last_token: jax.Array | None):
+    """Prefill sampling reads ONE position's logits: slice the hidden
+    states to it BEFORE the norm + head (positionwise ops commute with
+    the slice), so the vocab projection runs on [B, 1, Hd] instead of the
+    whole chunk. For a 128k-vocab model that removes a multi-GB f32
+    logits temp and ~0.8 TFLOP per 2048-token chunk, with bit-identical
+    results."""
+    if last_token is None:
+        return x
+    with jax.named_scope("head"):
+        return jnp.take_along_axis(x, last_token[:, None, None], axis=1)
+
+
+def apply(
+    family,
+    params,
+    cfg: ModelConfig,
+    token_ids: jax.Array,  # [B, T]
+    positions: jax.Array,  # [B, T]
+    kv_pages: Tuple,  # ([L,NB,bs,KVH,D], [L,NB,bs,KVH,D])
+    slot_mapping: jax.Array,  # [B, T]
+    block_tables: jax.Array,  # [B, MAXB]
+    context_lens: jax.Array,  # [B]
+    seq_lens: jax.Array,  # [B]
+    *,
+    mode: str,  # "prefill" | "prefill_cached" | "decode"  (static)
+    adapter_ids: jax.Array | None = None,  # [B] LoRA slot per sequence
+    output_hidden: bool = False,  # return final hidden states, not logits
+    last_token: jax.Array | None = None,  # [B] position whose logits to keep
+):
+    """Full forward. Returns (logits [B, T, V], updated kv_pages), or the
+    post-norm hidden states [B, T, Hd] instead of logits when
+    ``output_hidden`` (the /v1/embeddings pass); with ``last_token``, of
+    that one position (:func:`take_last_token`)."""
+    x, lora_layers, lora_scaling, adapter_ids = family.embed(
+        params, cfg, token_ids, positions, adapter_ids)
+    batch = Batch(positions, slot_mapping, block_tables, context_lens,
+                  seq_lens, adapter_ids, lora_scaling)
+
+    def layer_fn(x, per_layer, kv, l):
+        return family.layer(cfg, mode, x, per_layer, kv, l, batch)
+
+    x, kv_pages = scan_layers(
+        layer_fn, x, kv_pages, (params["layers"], lora_layers))
+    x = take_last_token(x, last_token)
+    return family.head(params, cfg, x, output_hidden), kv_pages

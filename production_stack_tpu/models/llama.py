@@ -1,6 +1,10 @@
 """Llama-family decoder (covers Llama 2/3, Mistral, TinyLlama via config).
 
-Functional JAX implementation built for serving with a paged KV cache:
+Functional JAX implementation built for serving with a paged KV cache. The
+layer loop, the page write and the three attention modes are the shared
+skeleton's (models/decoder.py); this module is what makes a model Llama:
+its tree, its layer, its checkpoint names, and its record
+(models/registry.py::Family) at the foot of the file.
 
 - parameters are a pytree with per-layer leaves stacked on a leading axis so
   the decoder runs as one ``lax.scan`` over layers (single-layer trace →
@@ -13,9 +17,6 @@ Functional JAX implementation built for serving with a paged KV cache:
   reshaped to heads right after its matmul, made the TPU compiler copy
   and transpose all three out of the stack in every layer of every
   forward (PERF.md section 6, PR 30);
-- every forward writes fresh K/V into HBM pages (``ops.write_kv_pages``) and
-  attends either causally within the prompt (prefill) or over the pages via
-  paged attention (decode);
 - weights use bfloat16 by default; all norms/softmax accumulate in float32.
 
 The reference stack runs these models inside vLLM CUDA images
@@ -26,18 +27,20 @@ TPU-native replacement at the engine layer.
 from __future__ import annotations
 
 import functools
-from typing import Dict, Tuple
+from typing import Callable, Dict, List, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
+from production_stack_tpu.models import decoder
 from production_stack_tpu.models.config import ModelConfig
-from production_stack_tpu.ops.attention import (
-    context_prefill_attention,
-    paged_decode_attention,
-    prefill_attention,
-    write_kv_pages,
+from production_stack_tpu.models.registry import Family
+from production_stack_tpu.models.weights import (
+    _iter_checkpoint_tensors,
+    _to_dtype,
+    report_incomplete,
 )
 
 
@@ -181,27 +184,22 @@ def _lora_delta(h, a, b, scaling, adapter_ids):
     return out * s_sel[:, None, None].astype(out.dtype)
 
 
-def _layer(
+def attention_half(
     cfg: ModelConfig,
     mode: str,
     x: jax.Array,  # [B, T, Hd]
-    layer_params: Dict,  # un-stacked (one layer's leaves)
+    p: Dict,  # one layer's un-stacked leaves
     lora: Dict | None,  # un-stacked per-layer LoRA leaves, or None
-    kv: Tuple[jax.Array, jax.Array],  # STACKED pages [L, NB, bs, KVH, D]
+    kv: Tuple,  # STACKED pages [L, NB, bs, KVH, D]
     layer: jax.Array,  # scalar layer index
-    positions: jax.Array,
-    slot_mapping: jax.Array,
-    block_tables: jax.Array,
-    context_lens: jax.Array,
-    seq_lens: jax.Array,
-    lora_scaling: jax.Array | None,
-    adapter_ids: jax.Array | None,
+    batch: decoder.Batch,
 ):
-    p = layer_params
+    """RMS norm, the fused ``wqkv`` projection behind the barrier, LoRA
+    deltas if slots are given, rotary, ``decoder.attend``, ``wo``: the
+    residual stream after attention, and the pages. Mixtral's layer is
+    this plus its expert MLP (models/mixtral.py)."""
     B, T, Hd = x.shape
     H, KVH, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    scale = 1.0 / (D ** 0.5)
-    k_pages, v_pages = kv
 
     # The named scopes are what a profiler trace files the device's time
     # under (docs/profiling.md): metadata only, no operation changes.
@@ -210,47 +208,36 @@ def _layer(
         q_flat, k_flat, v_flat = _split_qkv(_proj(h, p, "wqkv"), cfg)
         if lora is not None:
             q_flat = q_flat + _lora_delta(
-                h, lora["wq_a"], lora["wq_b"], lora_scaling, adapter_ids
+                h, lora["wq_a"], lora["wq_b"], batch.lora_scaling,
+                batch.adapter_ids
             )
             v_flat = v_flat + _lora_delta(
-                h, lora["wv_a"], lora["wv_b"], lora_scaling, adapter_ids
+                h, lora["wv_a"], lora["wv_b"], batch.lora_scaling,
+                batch.adapter_ids
             )
         q = q_flat.reshape(B, T, H, D)
         k = k_flat.reshape(B, T, KVH, D)
         v = v_flat.reshape(B, T, KVH, D)
-        q = rope(q, positions, cfg.rope_theta)
-        k = rope(k, positions, cfg.rope_theta)
+        q = rope(q, batch.positions, cfg.rope_theta)
+        k = rope(k, batch.positions, cfg.rope_theta)
 
-    k_pages, v_pages = write_kv_pages(
-        k_pages, v_pages, k, v, slot_mapping, layer)
-
-    with jax.named_scope("attention"):
-        if mode == "prefill":
-            attn = prefill_attention(
-                q, k, v, scale=scale, seq_lens=seq_lens)
-        elif mode == "prefill_cached":
-            # Suffix prefill after a prefix-cache hit: attend over HBM
-            # pages (cached prefix + just-written suffix). The chunk's own
-            # fresh k/v ride along so the flash kernel can serve the
-            # suffix from VMEM and stream only the cached prefix pages.
-            attn = context_prefill_attention(
-                q, k_pages, v_pages, block_tables, positions, context_lens,
-                layer, scale=scale, k_new=k, v_new=v, suffix_lens=seq_lens,
-            )
-        else:
-            attn = paged_decode_attention(
-                q[:, 0], k_pages, v_pages, block_tables, context_lens,
-                layer, scale=scale,
-            )[:, None]
+    attn, kv = decoder.attend(
+        mode, q, k, v, kv, layer, batch, scale=1.0 / (D ** 0.5))
     with jax.named_scope("attn_proj"):
         x = x + _proj(attn.reshape(B, T, H * D), p, "wo")
+    return x, kv
 
+
+def _layer(cfg: ModelConfig, mode: str, x: jax.Array, per_layer, kv,
+           layer: jax.Array, batch: decoder.Batch):
+    p, lora = per_layer
+    x, kv = attention_half(cfg, mode, x, p, lora, kv, layer, batch)
     with jax.named_scope("mlp"):
         h = rms_norm(x, p["mlp_norm"], cfg.rms_norm_eps)
         gate = jax.nn.silu(
             _proj(h, p, "w_gate").astype(jnp.float32)).astype(h.dtype)
         x = x + _proj(gate * _proj(h, p, "w_up"), p, "w_down")
-    return x, (k_pages, v_pages)
+    return x, kv
 
 
 @jax.named_scope("embed")
@@ -303,75 +290,165 @@ def project_out(params: Dict, cfg: ModelConfig, x: jax.Array,
     return (x @ emb.T).astype(jnp.float32)
 
 
-def apply(
-    params: Dict,
+
+
+# --------------------------------------------------------------------- #
+# Checkpoint (HF llama / mistral)
+# --------------------------------------------------------------------- #
+
+# HF leaf under ``model.layers.<i>.`` -> (our key, transpose [out, in] to
+# ``x @ W``'s [in, out]). "q" / "k" / "v" are joined into ``wqkv``.
+_ATTN_LEAVES = {
+    "input_layernorm.weight": ("attn_norm", False),
+    "self_attn.q_proj.weight": ("q", True),
+    "self_attn.k_proj.weight": ("k", True),
+    "self_attn.v_proj.weight": ("v", True),
+    "self_attn.o_proj.weight": ("wo", True),
+    "post_attention_layernorm.weight": ("mlp_norm", False),
+}
+_MLP_LEAVES = {
+    "mlp.gate_proj.weight": ("w_gate", True),
+    "mlp.up_proj.weight": ("w_up", True),
+    "mlp.down_proj.weight": ("w_down", True),
+}
+
+
+def load_checkpoint(
     cfg: ModelConfig,
-    token_ids: jax.Array,  # [B, T]
-    positions: jax.Array,  # [B, T]
-    kv_pages: Tuple[jax.Array, jax.Array],  # ([L,NB,bs,KVH,D], [L,NB,bs,KVH,D])
-    slot_mapping: jax.Array,  # [B, T]
-    block_tables: jax.Array,  # [B, MAXB]
-    context_lens: jax.Array,  # [B]
-    seq_lens: jax.Array,  # [B] valid prompt lengths (prefill padding mask)
+    path: str,
     *,
-    mode: str,  # "prefill" | "prefill_cached" | "decode"  (static)
-    adapter_ids: jax.Array | None = None,  # [B] LoRA slot per sequence
-    output_hidden: bool = False,  # return final hidden states, not logits
-    last_token: jax.Array | None = None,  # [B] position whose logits to keep
-) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
-    """Full forward. Returns (logits [B, T, V], updated kv_pages), or the
-    post-norm hidden states [B, T, Hd] instead of logits when
-    ``output_hidden`` (the /v1/embeddings pass). With ``last_token``
-    (prefill sampling: only one position's logits are ever read), the
-    hidden states are sliced to that position BEFORE the norm + head, so
-    the vocab projection runs on [B, 1, Hd] instead of the whole chunk —
-    for a 128k-vocab model that removes a multi-GB f32 logits temp and
-    ~0.8 TFLOP per 2048-token chunk, with bit-identical results."""
-    x, lora_layers, lora_scaling, adapter_ids = embed_tokens(
-        params, cfg, token_ids, adapter_ids)
-    k_all, v_all = kv_pages
+    mlp_leaves: Dict = _MLP_LEAVES,
+    other_leaf: Callable | None = None,
+    head_required: bool = False,
+) -> Dict:
+    """The tree of an HF Llama-shaped checkpoint. Each layer's ``q_proj``
+    / ``k_proj`` / ``v_proj`` are joined on the host into the one
+    ``wqkv`` leaf the model reads (:func:`fuse_qkv`), so the three never
+    sit beside it on the device.
 
-    layer_fn = functools.partial(
-        _layer, cfg, mode,
-        positions=positions, slot_mapping=slot_mapping,
-        block_tables=block_tables, context_lens=context_lens,
-        seq_lens=seq_lens, lora_scaling=lora_scaling, adapter_ids=adapter_ids,
-    )
+    A family whose attention half is Llama's names its own MLP leaves:
+    ``mlp_leaves`` for the ones stacked per layer, ``other_leaf(i, leaf,
+    arr) -> bool`` for what it gathers itself (Mixtral's experts)."""
+    L = cfg.num_layers
+    dtype = cfg.jnp_dtype
+    layer_map = {**_ATTN_LEAVES, **mlp_leaves}
+    per_layer: Dict[str, List] = {
+        k: [None] * L for k in ["wqkv"] + [
+            k for k, _ in layer_map.values() if k not in ("q", "k", "v")]
+    }
+    # q/k/v of a layer wait here, on the host, until all three are read.
+    qkv_parts: List[Dict[str, np.ndarray]] = [{} for _ in range(L)]
+    top: Dict[str, jnp.ndarray] = {}
+    unmapped = []
 
-    # The STACKED KV pages ride the scan carry whole; every op addresses
-    # them through the scalar layer index (flat scatter / page-level
-    # gather). Loop carries alias in place under XLA, so only the touched
-    # pages move — per-layer slices (or pages in the scan ys) would copy
-    # the entire pool every forward step. With an int8 cache each side is
-    # a (data, scales) tuple that rides the carry the same way.
-    L = (k_all[0] if isinstance(k_all, tuple) else k_all).shape[0]
+    for name, arr in _iter_checkpoint_tensors(path):
+        if name == "model.embed_tokens.weight":
+            top["embed"] = _to_dtype(arr, dtype)
+        elif name == "model.norm.weight":
+            top["final_norm"] = _to_dtype(arr, dtype)
+        elif name == "lm_head.weight":
+            top["lm_head"] = _to_dtype(arr.T, dtype)
+        elif name.startswith("model.layers."):
+            rest = name[len("model.layers."):]
+            idx_str, leaf = rest.split(".", 1)
+            i = int(idx_str)
+            entry = layer_map.get(leaf)
+            if entry is None or i >= L:
+                if not (other_leaf and i < L and other_leaf(i, leaf, arr)):
+                    unmapped.append(name)
+                continue
+            key, transpose = entry
+            if transpose:
+                arr = arr.T
+            if key in ("q", "k", "v"):
+                parts = qkv_parts[i]
+                parts[key] = arr
+                if len(parts) == 3:
+                    per_layer["wqkv"][i] = _to_dtype(
+                        fuse_qkv(parts.pop("q"), parts.pop("k"),
+                                 parts.pop("v"), cfg.num_kv_heads), dtype)
+                continue
+            per_layer[key][i] = _to_dtype(arr, dtype)
+        elif name.endswith("rotary_emb.inv_freq"):
+            continue  # computed, not a parameter
+        else:
+            unmapped.append(name)
 
-    if lora_layers is not None:
-        def scan_body(carry, per_layer):
-            x, k_all, v_all, l = carry
-            layer_params, lora_p = per_layer
-            x, (k_all, v_all) = layer_fn(
-                x, layer_params, lora_p, (k_all, v_all), l
-            )
-            return (x, k_all, v_all, l + 1), None
+    missing = [
+        f"layers.{k}[{i}]" for k, v in per_layer.items() if k != "wqkv"
+        for i, leaf in enumerate(v) if leaf is None
+    ] + [
+        f"layers.{k}_proj[{i}]" for i, parts in enumerate(qkv_parts)
+        if per_layer["wqkv"][i] is None for k in "qkv" if k not in parts
+    ]
+    required = ("embed", "final_norm") + (
+        ("lm_head",) if head_required else ())
+    missing += [k for k in required if k not in top]
+    report_incomplete(path, missing, unmapped)
 
-        (x, k_all, v_all, _), _ = jax.lax.scan(
-            scan_body, (x, k_all, v_all, jnp.int32(0)),
-            (params["layers"], lora_layers), length=L,
-        )
-    else:
-        def scan_body(carry, layer_params):
-            x, k_all, v_all, l = carry
-            x, (k_all, v_all) = layer_fn(
-                x, layer_params, None, (k_all, v_all), l
-            )
-            return (x, k_all, v_all, l + 1), None
+    params: Dict = {
+        "embed": top["embed"],
+        "final_norm": top["final_norm"],
+        "layers": {k: jnp.stack(v) for k, v in per_layer.items()},
+    }
+    # Without a head in the tree ``project_out`` falls back to embed.T.
+    if "lm_head" in top and (head_required or not cfg.tie_word_embeddings):
+        params["lm_head"] = top["lm_head"]
+    return params
 
-        (x, k_all, v_all, _), _ = jax.lax.scan(
-            scan_body, (x, k_all, v_all, jnp.int32(0)),
-            params["layers"], length=L,
-        )
-    if last_token is not None:
-        with jax.named_scope("head"):
-            x = jnp.take_along_axis(x, last_token[:, None, None], axis=1)
-    return project_out(params, cfg, x, output_hidden), (k_all, v_all)
+
+# --------------------------------------------------------------------- #
+# The family's record
+# --------------------------------------------------------------------- #
+
+# The attention half's leaves, for any family that calls it: qkv
+# column-parallel on the head dimension (``wqkv``'s columns are grouped
+# by KV head, so a shard of its last axis holds whole groups: a KV head
+# with its query heads, like the KV pages' shard), ``wo`` row-parallel
+# (all-reduce after); embeddings replicated, the head vocab-sharded.
+ATTN_SPECS = {
+    ("embed",): P(None, None),
+    ("final_norm",): P(None),
+    ("lm_head",): P(None, "tp"),
+    ("layers", "attn_norm"): P(None, None),
+    ("layers", "mlp_norm"): P(None, None),
+    ("layers", "wqkv"): P(None, None, "tp"),
+    ("layers", "wo"): P(None, "tp", None),
+}
+
+def _embed(params, cfg, token_ids, positions, adapter_ids):
+    del positions  # rotary: they enter in the layers
+    return embed_tokens(params, cfg, token_ids, adapter_ids)
+
+
+FAMILY = Family(
+    model_types=("llama", "mistral"),
+    init_params=init_params,
+    embed=_embed,
+    layer=_layer,
+    head=project_out,
+    load=load_checkpoint,
+    specs={
+        **ATTN_SPECS,
+        # MLP up/gate column-parallel on intermediate, down row-parallel.
+        ("layers", "w_gate"): P(None, None, "tp"),
+        ("layers", "w_up"): P(None, None, "tp"),
+        ("layers", "w_down"): P(None, "tp", None),
+        # LoRA slot tensors follow their base projections.
+        ("lora", "wq_a"): P(None, None, None, None),
+        ("lora", "wq_b"): P(None, None, None, "tp"),
+        ("lora", "wv_a"): P(None, None, None, None),
+        ("lora", "wv_b"): P(None, None, None, "tp"),
+        ("lora", "scaling"): P(None),
+    },
+    # Everything else (norms, LoRA slots) stays bf16: a rounding error of
+    # the total bytes. ``wqkv`` is the three projections' columns joined:
+    # a scale is per output column over Hd, so each column's int8 values
+    # and scale are what its own matrix would give.
+    quant_keys=("wqkv", "wo", "w_gate", "w_up", "w_down"),
+    lora=True,
+    pipeline=True,
+    head_may_tie=True,
+)
+
+apply = functools.partial(decoder.apply, FAMILY)

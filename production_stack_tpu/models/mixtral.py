@@ -1,29 +1,29 @@
 """Mixtral-style sparse-MoE decoder (BASELINE config 5: Mixtral-8x7B).
 
-Llama attention + a top-k routed expert MLP. Expert compute is expressed as
-a dense einsum over all experts weighted by the routing mask — on TPU this
-keeps the MXU busy with one big batched matmul and avoids dynamic shapes;
-with an ``ep`` mesh axis the expert dimension shards across chips and XLA
-inserts the all-to-all. (Capacity-based token dropping is not needed because
-every token computes its top-k experts exactly.)
+Llama's attention half (models/llama.py::attention_half, the fused
+``wqkv`` leaf included) + a top-k routed expert MLP. Expert compute is
+expressed as a dense einsum over all experts weighted by the routing mask
+— on TPU this keeps the MXU busy with one big batched matmul and avoids
+dynamic shapes; with an ``ep`` mesh axis the expert dimension shards
+across chips and XLA inserts the all-to-all. (Capacity-based token
+dropping is not needed because every token computes its top-k experts
+exactly.) No LoRA slots, no pipeline stages, no int8 weights yet: the
+record at the foot of the file says so.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Dict, Tuple
+from typing import Dict, List
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
+from production_stack_tpu.models import decoder, llama
 from production_stack_tpu.models.config import ModelConfig
-from production_stack_tpu.models.llama import rms_norm, rope
-from production_stack_tpu.ops.attention import (
-    context_prefill_attention,
-    paged_decode_attention,
-    prefill_attention,
-    write_kv_pages,
-)
+from production_stack_tpu.models.registry import Family
+from production_stack_tpu.models.weights import _to_dtype, report_incomplete
 
 
 def init_params(cfg: ModelConfig, rng: jax.Array, **_unused) -> Dict:
@@ -41,9 +41,11 @@ def init_params(cfg: ModelConfig, rng: jax.Array, **_unused) -> Dict:
         "embed": (0.02 * jax.random.normal(keys[0], (V, Hd), jnp.float32)).astype(dtype),
         "layers": {
             "attn_norm": jnp.ones((L, Hd), dtype),
-            "wq": stack(keys[1], (Hd, H * D), Hd),
-            "wk": stack(keys[2], (Hd, KVH * D), Hd),
-            "wv": stack(keys[3], (Hd, KVH * D), Hd),
+            # Drawn as three matrices, served as one leaf (llama.fuse_qkv).
+            "wqkv": llama.fuse_qkv(
+                stack(keys[1], (Hd, H * D), Hd),
+                stack(keys[2], (Hd, KVH * D), Hd),
+                stack(keys[3], (Hd, KVH * D), Hd), KVH),
             "wo": stack(keys[4], (H * D, Hd), H * D),
             "mlp_norm": jnp.ones((L, Hd), dtype),
             "router": stack(keys[5], (Hd, E), Hd),
@@ -77,88 +79,66 @@ def moe_mlp(cfg: ModelConfig, p: Dict, h: jax.Array) -> jax.Array:
     ).astype(h.dtype)
 
 
-def _layer(
-    cfg: ModelConfig, mode: str, x, p, kv, layer,
-    positions, slot_mapping, block_tables, context_lens, seq_lens,
-):
-    B, T, Hd = x.shape
-    H, KVH, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    scale = 1.0 / (D ** 0.5)
-    k_pages, v_pages = kv  # stacked [L, NB, bs, KVH, D]
-
-    # Scope names as in llama._layer (docs/profiling.md): metadata only.
-    with jax.named_scope("attn_proj"):
-        h = rms_norm(x, p["attn_norm"], cfg.rms_norm_eps)
-        q = rope((h @ p["wq"]).reshape(B, T, H, D), positions,
-                 cfg.rope_theta)
-        k = rope((h @ p["wk"]).reshape(B, T, KVH, D), positions,
-                 cfg.rope_theta)
-        v = (h @ p["wv"]).reshape(B, T, KVH, D)
-    k_pages, v_pages = write_kv_pages(
-        k_pages, v_pages, k, v, slot_mapping, layer)
-    with jax.named_scope("attention"):
-        if mode == "prefill":
-            attn = prefill_attention(
-                q, k, v, scale=scale, seq_lens=seq_lens)
-        elif mode == "prefill_cached":
-            # Suffix prefill after a prefix-cache hit: attend over HBM
-            # pages (cached prefix + just-written suffix).
-            attn = context_prefill_attention(
-                q, k_pages, v_pages, block_tables, positions, context_lens,
-                layer, scale=scale, k_new=k, v_new=v, suffix_lens=seq_lens,
-            )
-        else:
-            attn = paged_decode_attention(
-                q[:, 0], k_pages, v_pages, block_tables, context_lens,
-                layer, scale=scale,
-            )[:, None]
-    with jax.named_scope("attn_proj"):
-        x = x + attn.reshape(B, T, H * D) @ p["wo"]
-
+def _layer(cfg: ModelConfig, mode: str, x, per_layer, kv, layer, batch):
+    p, _no_lora = per_layer
+    x, kv = llama.attention_half(cfg, mode, x, p, None, kv, layer, batch)
     with jax.named_scope("mlp"):
-        h = rms_norm(x, p["mlp_norm"], cfg.rms_norm_eps)
+        h = llama.rms_norm(x, p["mlp_norm"], cfg.rms_norm_eps)
         x = x + moe_mlp(cfg, p, h)
-    return x, (k_pages, v_pages)
+    return x, kv
 
 
-def apply(
-    params: Dict,
-    cfg: ModelConfig,
-    token_ids, positions, kv_pages, slot_mapping, block_tables,
-    context_lens, seq_lens, *, mode: str, adapter_ids=None, output_hidden: bool = False,
-    last_token=None,
-) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
-    del adapter_ids  # LoRA slots are a Llama-family feature for now
-    with jax.named_scope("embed"):
-        x = params["embed"][token_ids].astype(cfg.jnp_dtype)
-    k_all, v_all = kv_pages
-    layer_fn = functools.partial(
-        _layer, cfg, mode,
-        positions=positions, slot_mapping=slot_mapping,
-        block_tables=block_tables, context_lens=context_lens, seq_lens=seq_lens,
-    )
+def load_checkpoint(cfg: ModelConfig, path: str) -> Dict:
+    """An HF Mixtral checkpoint is Llama's with a router and E experts
+    where the MLP was: ``block_sparse_moe.experts.<e>.w1 / w3 / w2``
+    become ``w_gate`` / ``w_up`` / ``w_down`` ``[L, E, ...]``."""
+    L, E = cfg.num_layers, cfg.num_experts
+    experts: Dict[str, List] = {
+        k: [[None] * E for _ in range(L)]
+        for k in ("w_gate", "w_up", "w_down")
+    }
+    expert_map = {"w1": "w_gate", "w3": "w_up", "w2": "w_down"}
 
-    # Stacked KV pages ride the scan carry whole (in-place under XLA);
-    # see llama.apply.
-    L = (k_all[0] if isinstance(k_all, tuple) else k_all).shape[0]
+    def expert_leaf(i: int, leaf: str, arr) -> bool:
+        if not leaf.startswith("block_sparse_moe.experts."):
+            return False
+        parts = leaf.split(".")
+        e, w = int(parts[2]), expert_map.get(parts[3])
+        if w is None or e >= E:
+            return False
+        experts[w][i][e] = _to_dtype(arr.T, cfg.jnp_dtype)
+        return True
 
-    def scan_body(carry, layer_params):
-        x, k_all, v_all, l = carry
-        x, (k_all, v_all) = layer_fn(x, layer_params, (k_all, v_all), l)
-        return (x, k_all, v_all, l + 1), None
+    params = llama.load_checkpoint(
+        cfg, path,
+        mlp_leaves={"block_sparse_moe.gate.weight": ("router", True)},
+        other_leaf=expert_leaf, head_required=True)
+    report_incomplete(path, [
+        f"experts.{k}[{i}][{e}]" for k, le in experts.items()
+        for i, row in enumerate(le) for e, leaf in enumerate(row)
+        if leaf is None], [])
+    for k, le in experts.items():
+        params["layers"][k] = jnp.stack(
+            [jnp.stack(row) for row in le])  # [L, E, ...]
+    return params
 
-    (x, k_all, v_all, _), _ = jax.lax.scan(
-        scan_body, (x, k_all, v_all, jnp.int32(0)), params["layers"],
-        length=L,
-    )
-    with jax.named_scope("head"):
-        if last_token is not None:
-            # Prefill sampling reads ONE position: slice before norm +
-            # head (positionwise ops commute with the slice; see
-            # llama.apply).
-            x = jnp.take_along_axis(x, last_token[:, None, None], axis=1)
-        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
-        if output_hidden:
-            return x.astype(jnp.float32), (k_all, v_all)
-        logits = (x @ params["lm_head"]).astype(jnp.float32)
-        return logits, (k_all, v_all)
+
+FAMILY = Family(
+    model_types=("mixtral",),
+    init_params=init_params,
+    embed=llama.FAMILY.embed,  # no lora leaf in this tree: x alone
+    layer=_layer,
+    head=llama.project_out,
+    load=load_checkpoint,
+    specs={
+        **llama.ATTN_SPECS,
+        ("layers", "router"): P(None, None, None),
+        # Experts shard across the tp axis (expert parallelism on the
+        # same mesh).
+        ("layers", "w_gate"): P(None, "tp", None, None),
+        ("layers", "w_up"): P(None, "tp", None, None),
+        ("layers", "w_down"): P(None, "tp", None, None),
+    },
+)
+
+apply = functools.partial(decoder.apply, FAMILY)

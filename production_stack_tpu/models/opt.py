@@ -9,17 +9,19 @@ of RMSNorm, ReLU MLP, no RoPE, MHA only. Same paged-KV serving interface.
 from __future__ import annotations
 
 import functools
-from typing import Dict, Tuple
+from typing import Dict, List
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
+from production_stack_tpu.models import decoder
 from production_stack_tpu.models.config import ModelConfig
-from production_stack_tpu.ops.attention import (
-    context_prefill_attention,
-    paged_decode_attention,
-    prefill_attention,
-    write_kv_pages,
+from production_stack_tpu.models.registry import Family
+from production_stack_tpu.models.weights import (
+    _iter_checkpoint_tensors,
+    _to_dtype,
+    report_incomplete,
 )
 
 POS_OFFSET = 2  # OPT's learned-position quirk
@@ -73,39 +75,19 @@ def init_params(cfg: ModelConfig, rng: jax.Array, **_unused) -> Dict:
     }
 
 
-def _layer(
-    cfg: ModelConfig, mode: str, x, p, kv, layer,
-    positions, slot_mapping, block_tables, context_lens, seq_lens,
-):
+def _layer(cfg: ModelConfig, mode: str, x, per_layer, kv, layer, batch):
+    p, _no_lora = per_layer
     B, T, Hd = x.shape
     H, D = cfg.num_heads, cfg.head_dim
-    scale = 1.0 / (D ** 0.5)
-    k_pages, v_pages = kv  # stacked [L, NB, bs, KVH, D]
 
-    # Scope names as in llama._layer (docs/profiling.md): metadata only.
+    # Scope names as in llama.attention_half (docs/profiling.md).
     with jax.named_scope("attn_proj"):
         h = layer_norm(x, p["ln1_w"], p["ln1_b"])
         q = (h @ p["wq"] + p["wq_b"]).reshape(B, T, H, D)
         k = (h @ p["wk"] + p["wk_b"]).reshape(B, T, H, D)
         v = (h @ p["wv"] + p["wv_b"]).reshape(B, T, H, D)
-    k_pages, v_pages = write_kv_pages(
-        k_pages, v_pages, k, v, slot_mapping, layer)
-    with jax.named_scope("attention"):
-        if mode == "prefill":
-            attn = prefill_attention(
-                q, k, v, scale=scale, seq_lens=seq_lens)
-        elif mode == "prefill_cached":
-            # Suffix prefill after a prefix-cache hit: attend over HBM
-            # pages (cached prefix + just-written suffix).
-            attn = context_prefill_attention(
-                q, k_pages, v_pages, block_tables, positions, context_lens,
-                layer, scale=scale, k_new=k, v_new=v, suffix_lens=seq_lens,
-            )
-        else:
-            attn = paged_decode_attention(
-                q[:, 0], k_pages, v_pages, block_tables, context_lens,
-                layer, scale=scale,
-            )[:, None]
+    attn, kv = decoder.attend(
+        mode, q, k, v, kv, layer, batch, scale=1.0 / (D ** 0.5))
     with jax.named_scope("attn_proj"):
         x = x + attn.reshape(B, T, H * D) @ p["wo"] + p["wo_b"]
 
@@ -113,49 +95,122 @@ def _layer(
         h = layer_norm(x, p["ln2_w"], p["ln2_b"])
         h = jax.nn.relu(h @ p["fc1"] + p["fc1_b"])
         x = x + h @ p["fc2"] + p["fc2_b"]
-    return x, (k_pages, v_pages)
+    return x, kv
 
 
-def apply(
-    params: Dict,
-    cfg: ModelConfig,
-    token_ids, positions, kv_pages, slot_mapping, block_tables,
-    context_lens, seq_lens, *, mode: str, adapter_ids=None, output_hidden: bool = False,
-    last_token=None,
-) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
-    del adapter_ids  # LoRA slots are a Llama-family feature for now
-    with jax.named_scope("embed"):
-        x = params["embed"][token_ids].astype(cfg.jnp_dtype)
-        x = x + params["pos_embed"][positions + POS_OFFSET].astype(
-            cfg.jnp_dtype)
-    k_all, v_all = kv_pages
-    layer_fn = functools.partial(
-        _layer, cfg, mode,
-        positions=positions, slot_mapping=slot_mapping,
-        block_tables=block_tables, context_lens=context_lens, seq_lens=seq_lens,
-    )
+@jax.named_scope("embed")
+def _embed(params: Dict, cfg: ModelConfig, token_ids, positions,
+           adapter_ids):
+    del adapter_ids  # no LoRA slots in this tree
+    x = params["embed"][token_ids].astype(cfg.jnp_dtype)
+    x = x + params["pos_embed"][positions + POS_OFFSET].astype(cfg.jnp_dtype)
+    return x, None, None, None
 
-    # Stacked KV pages ride the scan carry whole (in-place under XLA);
-    # see llama.apply.
-    L = (k_all[0] if isinstance(k_all, tuple) else k_all).shape[0]
 
-    def scan_body(carry, layer_params):
-        x, k_all, v_all, l = carry
-        x, (k_all, v_all) = layer_fn(x, layer_params, (k_all, v_all), l)
-        return (x, k_all, v_all, l + 1), None
+@jax.named_scope("head")
+def _head(params: Dict, cfg: ModelConfig, x, output_hidden: bool):
+    x = layer_norm(x, params["final_ln_w"], params["final_ln_b"])
+    if output_hidden:
+        return x.astype(jnp.float32)
+    return (x @ params["embed"].T).astype(jnp.float32)  # always tied
 
-    (x, k_all, v_all, _), _ = jax.lax.scan(
-        scan_body, (x, k_all, v_all, jnp.int32(0)), params["layers"],
-        length=L,
-    )
-    with jax.named_scope("head"):
-        if last_token is not None:
-            # Prefill sampling reads ONE position: slice before norm +
-            # head (positionwise ops commute with the slice; see
-            # llama.apply).
-            x = jnp.take_along_axis(x, last_token[:, None, None], axis=1)
-        x = layer_norm(x, params["final_ln_w"], params["final_ln_b"])
-        if output_hidden:
-            return x.astype(jnp.float32), (k_all, v_all)
-        logits = (x @ params["embed"].T).astype(jnp.float32)
-        return logits, (k_all, v_all)
+
+# HF leaf under ``model.decoder.layers.<i>.`` -> (our key, transpose).
+_LAYER_LEAVES = {
+    "self_attn_layer_norm.weight": ("ln1_w", False),
+    "self_attn_layer_norm.bias": ("ln1_b", False),
+    "self_attn.q_proj.weight": ("wq", True),
+    "self_attn.q_proj.bias": ("wq_b", False),
+    "self_attn.k_proj.weight": ("wk", True),
+    "self_attn.k_proj.bias": ("wk_b", False),
+    "self_attn.v_proj.weight": ("wv", True),
+    "self_attn.v_proj.bias": ("wv_b", False),
+    "self_attn.out_proj.weight": ("wo", True),
+    "self_attn.out_proj.bias": ("wo_b", False),
+    "final_layer_norm.weight": ("ln2_w", False),
+    "final_layer_norm.bias": ("ln2_b", False),
+    "fc1.weight": ("fc1", True),
+    "fc1.bias": ("fc1_b", False),
+    "fc2.weight": ("fc2", True),
+    "fc2.bias": ("fc2_b", False),
+}
+_TOP_LEAVES = {
+    "embed_tokens.weight": "embed",
+    "embed_positions.weight": "pos_embed",
+    "final_layer_norm.weight": "final_ln_w",
+    "final_layer_norm.bias": "final_ln_b",
+}
+
+
+def load_checkpoint(cfg: ModelConfig, path: str) -> Dict:
+    L = cfg.num_layers
+    dtype = cfg.jnp_dtype
+    per_layer: Dict[str, List] = {
+        k: [None] * L for k, _ in _LAYER_LEAVES.values()}
+    top: Dict[str, jnp.ndarray] = {}
+    unmapped = []
+
+    prefix = "model.decoder."
+    for name, arr in _iter_checkpoint_tensors(path):
+        short = name[len(prefix):] if name.startswith(prefix) else name
+        if short in _TOP_LEAVES:
+            top[_TOP_LEAVES[short]] = _to_dtype(arr, dtype)
+        elif short == "lm_head.weight":
+            continue  # OPT ties lm_head to embeddings
+        elif short.startswith("layers."):
+            rest = short[len("layers."):]
+            idx_str, leaf = rest.split(".", 1)
+            i = int(idx_str)
+            entry = _LAYER_LEAVES.get(leaf)
+            if entry is None or i >= L:
+                unmapped.append(name)
+                continue
+            key, transpose = entry
+            per_layer[key][i] = _to_dtype(
+                arr.T if transpose else arr, dtype)
+        else:
+            unmapped.append(name)
+
+    missing = [
+        f"layers.{k}[{i}]" for k, v in per_layer.items()
+        for i, leaf in enumerate(v) if leaf is None
+    ] + [k for k in _TOP_LEAVES.values() if k not in top]
+    report_incomplete(path, missing, unmapped)
+    return {**top,
+            "layers": {k: jnp.stack(v) for k, v in per_layer.items()}}
+
+
+FAMILY = Family(
+    model_types=("opt",),
+    init_params=init_params,
+    embed=_embed,
+    layer=_layer,
+    head=_head,
+    load=load_checkpoint,
+    specs={
+        ("embed",): P(None, None),
+        ("pos_embed",): P(None, None),
+        ("final_ln_w",): P(None),
+        ("final_ln_b",): P(None),
+        ("layers", "ln1_w"): P(None, None),
+        ("layers", "ln1_b"): P(None, None),
+        ("layers", "ln2_w"): P(None, None),
+        ("layers", "ln2_b"): P(None, None),
+        # qkv and fc1 column-parallel (their biases with them), wo and
+        # fc2 row-parallel (biases added after the all-reduce).
+        ("layers", "wq"): P(None, None, "tp"),
+        ("layers", "wq_b"): P(None, "tp"),
+        ("layers", "wk"): P(None, None, "tp"),
+        ("layers", "wk_b"): P(None, "tp"),
+        ("layers", "wv"): P(None, None, "tp"),
+        ("layers", "wv_b"): P(None, "tp"),
+        ("layers", "wo"): P(None, "tp", None),
+        ("layers", "wo_b"): P(None, None),
+        ("layers", "fc1"): P(None, None, "tp"),
+        ("layers", "fc1_b"): P(None, "tp"),
+        ("layers", "fc2"): P(None, "tp", None),
+        ("layers", "fc2_b"): P(None, None),
+    },
+)
+
+apply = functools.partial(decoder.apply, FAMILY)

@@ -31,12 +31,7 @@ from typing import Dict
 import jax.numpy as jnp
 import numpy as np
 
-# Weight leaves quantized for the llama family; everything else (norms,
-# LoRA slots) stays bf16 — they are a rounding error of the total bytes.
-# ``wqkv`` is the three input projections' columns joined
-# (models/llama.py::fuse_qkv): a scale is per output column over Hd, so
-# each column's int8 values and scale are what its own matrix would give.
-_LLAMA_LAYER_KEYS = ("wqkv", "wo", "w_gate", "w_up", "w_down")
+from production_stack_tpu.models.registry import get_family
 
 # Symmetric int8 range. 127 (not 128) keeps the scale exact for the max.
 _QMAX = 127.0
@@ -60,19 +55,26 @@ def _quantize_np(w: np.ndarray, reduce_axis: int):
 
 def _apply_tree(params: Dict, arch: str, quant,
                 quantize_embeddings: bool) -> Dict:
-    if arch != "llama":
+    """Only the leaves ``params`` carries, of those the family's record
+    lets int8 take (a host-loaded checkpoint may hold fewer than the
+    init's tree)."""
+    keys = get_family(arch).quant_keys
+    if not keys:
         raise ValueError(
             f"int8 quantization is supported for the llama family "
             f"(got arch {arch!r})")
     out = dict(params)
-    layers = dict(params["layers"])
-    for name in _LLAMA_LAYER_KEYS:
-        if name in layers:
+
+    def take(tree: Dict, name: str, reduce_axis: int) -> None:
+        if name in tree:
+            tree[name], tree[name + "_scale"] = quant(
+                tree[name], reduce_axis)
+
+    if "layers" in params:
+        out["layers"] = dict(params["layers"])
+        for name in keys:
             # [L, in, out] -> int8 [L, in, out] + scale [L, 1, out]
-            q, s = quant(layers[name], -2)
-            layers[name] = q
-            layers[name + "_scale"] = s
-    out["layers"] = layers
+            take(out["layers"], name, -2)
     # embed / lm_head stay bf16 by default: quantizing them hurts output
     # quality disproportionately (standard weight-only recipes exclude
     # them) while saving only ~1 GB of an 8 B model's bytes — the HBM win
@@ -81,13 +83,8 @@ def _apply_tree(params: Dict, arch: str, quant,
         # embed [V, Hd]: per-ROW scales [V, 1] — correct for both the
         # lookup (dequant the gathered rows) and the tied head
         # (x @ embed.T scales per output/vocab channel).
-        q, s = quant(params["embed"], -1)
-        out["embed"] = q
-        out["embed_scale"] = s
-        if "lm_head" in params:
-            q, s = quant(params["lm_head"], -2)  # [Hd, V] -> scale [1, V]
-            out["lm_head"] = q
-            out["lm_head_scale"] = s
+        take(out, "embed", -1)
+        take(out, "lm_head", -2)  # [Hd, V] -> scale [1, V]
     return out
 
 
@@ -99,28 +96,5 @@ def quantize_tree(params: Dict, arch: str, *,
 
 def quantize_loaded(loaded: Dict, arch: str, *,
                     quantize_embeddings: bool = False) -> Dict:
-    """Numpy twin of :func:`quantize_tree` for host-loaded checkpoints.
-    Only quantizes the leaves the checkpoint actually carries."""
-    if arch != "llama":
-        raise ValueError(
-            f"int8 quantization is supported for the llama family "
-            f"(got arch {arch!r})")
-    out = dict(loaded)
-    if "layers" in loaded:
-        layers = dict(loaded["layers"])
-        for name in _LLAMA_LAYER_KEYS:
-            if name in layers:
-                q, s = _quantize_np(layers[name], -2)
-                layers[name] = q
-                layers[name + "_scale"] = s
-        out["layers"] = layers
-    if quantize_embeddings:
-        if "embed" in loaded:
-            q, s = _quantize_np(loaded["embed"], -1)
-            out["embed"] = q
-            out["embed_scale"] = s
-        if "lm_head" in loaded:
-            q, s = _quantize_np(loaded["lm_head"], -2)
-            out["lm_head"] = q
-            out["lm_head_scale"] = s
-    return out
+    """Numpy twin of :func:`quantize_tree` for host-loaded checkpoints."""
+    return _apply_tree(loaded, arch, _quantize_np, quantize_embeddings)

@@ -1,20 +1,91 @@
-"""Model registry: arch name -> (init_params, apply)."""
+"""Model registry: one record per family, and ``arch`` -> its module.
+
+What a family *is* is said once, in the :class:`Family` its module
+defines beside the ``init_params`` that fixes its tree; the engine, the
+sharding rules, the checkpoint loader and the quantiser ask the record
+and never the family's name. A new family is its own module, one line of
+``ARCH_MODULES`` and a preset if it wants one (docs/engine.md, "Adding a
+model family").
+"""
 
 from __future__ import annotations
 
-from typing import Callable, Dict, Tuple
+import dataclasses
+import importlib
+from typing import Callable, Mapping, Tuple
 
 from production_stack_tpu.models.config import ModelConfig
+
+# arch -> module, imported on first use (an engine pays for one family).
+ARCH_MODULES = {
+    "llama": "production_stack_tpu.models.llama",
+    "opt": "production_stack_tpu.models.opt",
+    "mixtral": "production_stack_tpu.models.mixtral",
+}
+
+
+@dataclasses.dataclass(frozen=True)
+class Family:
+    """``embed``, ``layer`` and ``head`` are the three parts
+    models/decoder.py::apply (and the pipeline stages of
+    parallel/pp_serving.py) run:
+
+    - ``embed(params, cfg, token_ids, positions, adapter_ids)`` ->
+      ``(x [B, T, Hd], lora_layers, lora_scaling, adapter_ids)``, the
+      last three ``None`` for a tree without LoRA slots;
+    - ``layer(cfg, mode, x, (layer_params, lora), kv, l, batch)`` ->
+      ``(x, kv)``: one layer on its un-stacked leaves, attention through
+      ``decoder.attend``;
+    - ``head(params, cfg, x, output_hidden)`` -> logits, or the normed
+      hidden states.
+    """
+
+    # Hugging Face ``model_type`` strings whose checkpoints this family
+    # serves exactly (models/config.py refuses any no family claims).
+    model_types: Tuple[str, ...]
+    init_params: Callable  # (cfg, rng, **lora_kwargs) -> params
+    embed: Callable
+    layer: Callable
+    head: Callable
+    # (cfg, path) -> the tree of an HF checkpoint directory, in
+    # ``init_params``' layout (file formats: models/weights.py).
+    load: Callable
+    # leaf path -> PartitionSpec template, "tp" substituted; the leading
+    # axis of a "layers" leaf is the stacked layer axis
+    # (parallel/sharding.py applies the rules).
+    specs: Mapping[Tuple[str, ...], object]
+    # ``layers`` leaves that weight-only int8 takes; empty = unsupported.
+    quant_keys: Tuple[str, ...] = ()
+    lora: bool = False  # init_params takes lora_slots / lora_rank
+    pipeline: bool = False  # its layers run as stages over a pp axis
+    # A checkpoint without ``lm_head`` ties the head to ``embed``: the
+    # random head of the init is dropped and ``head`` reads ``embed.T``.
+    head_may_tie: bool = False
+
+
+def _module(arch: str):
+    try:
+        return importlib.import_module(ARCH_MODULES[arch])
+    except KeyError:
+        raise ValueError(f"Unknown arch {arch!r}") from None
+
+
+def get_family(arch: str) -> Family:
+    return _module(arch).FAMILY
+
+
+def arch_of_model_type(model_type: str) -> str:
+    """The ``arch`` whose family answers to a checkpoint's ``model_type``."""
+    known = {t: arch for arch in ARCH_MODULES
+             for t in get_family(arch).model_types}
+    if model_type not in known:
+        raise ValueError(
+            f"Unknown model_type {model_type!r}: no model family serves "
+            f"it (known: {sorted(known)})")
+    return known[model_type]
 
 
 def build_model(cfg: ModelConfig) -> Tuple[Callable, Callable]:
     """Return (init_params(cfg, rng) -> params, apply(params, cfg, ...))."""
-    if cfg.arch == "llama":
-        from production_stack_tpu.models import llama as mod
-    elif cfg.arch == "opt":
-        from production_stack_tpu.models import opt as mod
-    elif cfg.arch == "mixtral":
-        from production_stack_tpu.models import mixtral as mod
-    else:
-        raise ValueError(f"Unknown arch {cfg.arch!r}")
+    mod = _module(cfg.arch)
     return mod.init_params, mod.apply

@@ -4,8 +4,9 @@
 The reference deploys pipeline-parallel engines by orchestrating multi-node
 vLLM with KubeRay (``helm/templates/ray-cluster.yaml``,
 ``docs/source/use_cases/pipeline-parallelism-kuberay.rst``); on TPU the same
-capability is a mesh axis inside one program. ``make_pp_apply`` wraps the
-Llama-family per-layer function in a GPipe schedule:
+capability is a mesh axis inside one program. ``make_pp_apply`` wraps a
+family's per-layer function (models/registry.py::Family) in a GPipe
+schedule:
 
 - layer-stacked parameters AND the paged KV pool shard their leading (layer)
   axis over ``pp`` — each stage's HBM holds only its layers' weights and
@@ -26,7 +27,6 @@ embeddings — runs unchanged on top of it.
 
 from __future__ import annotations
 
-import functools
 from typing import Tuple
 
 import jax
@@ -35,6 +35,11 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from production_stack_tpu.models.config import ModelConfig
+from production_stack_tpu.models.decoder import (
+    Batch,
+    scan_layers,
+    take_last_token,
+)
 
 
 def _microbatch_count(batch: int, requested: int) -> int:
@@ -45,20 +50,15 @@ def _microbatch_count(batch: int, requested: int) -> int:
     return m
 
 
-def make_pp_apply(mesh: Mesh, microbatches: int = 1):
-    """Build a pipeline-parallel ``apply`` for the Llama family.
+def make_pp_apply(mesh: Mesh, family, microbatches: int = 1):
+    """Build a pipeline-parallel ``apply`` from a family's ``embed`` /
+    ``layer`` / ``head`` (one whose record says ``pipeline``).
 
     ``microbatches`` bounds the GPipe microbatch count per forward (the
     actual count is the largest divisor of the batch size, so any batch
     shape works). Returns a function with the exact signature of
-    :func:`production_stack_tpu.models.llama.apply`.
+    ``models.<family>.apply``.
     """
-    from production_stack_tpu.models.llama import (
-        _layer,
-        embed_tokens,
-        project_out,
-    )
-
     pp = mesh.shape["pp"]
     ring = [(i, (i + 1) % pp) for i in range(pp)]
 
@@ -83,64 +83,28 @@ def make_pp_apply(mesh: Mesh, microbatches: int = 1):
         Bm = B // M
         n_ticks = M + pp - 1
 
-        x, lora_layers, lora_scaling, adapter_ids = embed_tokens(
-            params, cfg, token_ids, adapter_ids)  # x: [B, T, Hd]
+        x, lora_layers, lora_scaling, adapter_ids = family.embed(
+            params, cfg, token_ids, positions, adapter_ids)  # x: [B, T, Hd]
 
         def mb(a):
             return a.reshape((M, Bm) + a.shape[1:])
 
+        # Every per-sequence input of a layer, cut into microbatches
+        # (no adapter ids: an empty leaf, which every map passes over).
+        batch_mb = jax.tree_util.tree_map(mb, Batch(
+            positions, slot_mapping, block_tables, context_lens, seq_lens,
+            adapter_ids))
         x_mb = mb(x)
-        pos_mb = mb(positions)
-        slots_mb = mb(slot_mapping)
-        tables_mb = mb(block_tables)
-        ctx_mb = mb(context_lens)
-        seq_mb = mb(seq_lens)
-        aid_mb = (
-            mb(adapter_ids) if adapter_ids is not None
-            else jnp.zeros((M, Bm), jnp.int32)
-        )
 
         k_all, v_all = kv_pages
-        layer_spec = jax.tree_util.tree_map(lambda _: P("pp"), params["layers"])
-        lora_spec = (
-            jax.tree_util.tree_map(lambda _: P("pp"), lora_layers)
-            if lora_layers is not None else None
-        )
+        layer_spec = jax.tree_util.tree_map(
+            lambda _: P("pp"), (params["layers"], lora_layers))
 
         def to_varying(a):
             return jax.lax.pcast(a, ("pp",), to="varying")
 
-        def stage_body(layers_loc, lora_loc, scaling, k_loc, v_loc,
-                       x_mb, pos_mb, slots_mb, tables_mb, ctx_mb, seq_mb,
-                       aid_mb):
+        def stage_body(xs_loc, scaling, k_loc, v_loc, x_mb, batch_mb):
             idx = jax.lax.axis_index("pp")
-
-            def run_local(x, k_loc, v_loc, pos, slots, tables, ctx, seq,
-                          aid):
-                layer_fn = functools.partial(
-                    _layer, cfg, mode,
-                    positions=pos, slot_mapping=slots, block_tables=tables,
-                    context_lens=ctx, seq_lens=seq,
-                    lora_scaling=scaling, adapter_ids=aid,
-                )
-
-                def body(carry, per_layer):
-                    x, k, v, l = carry
-                    if lora_loc is not None:
-                        lp, lo = per_layer
-                    else:
-                        lp, lo = per_layer, None
-                    x, (k, v) = layer_fn(x, lp, lo, (k, v), l)
-                    return (x, k, v, l + 1), None
-
-                xs = (
-                    (layers_loc, lora_loc) if lora_loc is not None
-                    else layers_loc
-                )
-                (x, k_loc, v_loc, _), _ = jax.lax.scan(
-                    body, (x, k_loc, v_loc, jnp.int32(0)), xs,
-                )
-                return x, k_loc, v_loc
 
             # Microbatch metadata indexed by this stage's CURRENT microbatch
             # (varying index -> pcast the operand to varying first).
@@ -157,19 +121,23 @@ def make_pp_apply(mesh: Mesh, microbatches: int = 1):
                 m = jnp.clip(m_raw, 0, M - 1)
                 active = jnp.logical_and(m_raw >= 0, m_raw < M)
                 x_in = jnp.where(idx == 0, pick(x_mb, m), inflow)
-                pos = pick(pos_mb, m)
-                tables = pick(tables_mb, m)
-                ctx = pick(ctx_mb, m)
-                seq = pick(seq_mb, m)
-                aid = pick(aid_mb, m)
+                batch = jax.tree_util.tree_map(
+                    lambda a: pick(a, m), batch_mb)
                 # Bubble ticks compute on garbage; masking their page writes
                 # to slot -1 (dropped by the scatter) keeps the cache exact.
-                picked_slots = pick(slots_mb, m)
-                slots = jnp.where(
-                    active, picked_slots,
-                    jnp.asarray(-1, picked_slots.dtype))
-                y, k_loc, v_loc = run_local(
-                    x_in, k_loc, v_loc, pos, slots, tables, ctx, seq, aid)
+                batch = batch._replace(
+                    slot_mapping=jnp.where(
+                        active, batch.slot_mapping,
+                        jnp.asarray(-1, batch.slot_mapping.dtype)),
+                    lora_scaling=scaling)
+
+                def layer_fn(x, per_layer, kv, l):
+                    return family.layer(cfg, mode, x, per_layer, kv, l, batch)
+
+                # This stage's layers only: the local index addresses the
+                # local shard of the pool.
+                y, (k_loc, v_loc) = scan_layers(
+                    layer_fn, x_in, (k_loc, v_loc), xs_loc)
                 commit = jnp.logical_and(idx == pp - 1, active)
                 outputs = jax.lax.cond(
                     commit,
@@ -195,17 +163,13 @@ def make_pp_apply(mesh: Mesh, microbatches: int = 1):
         hidden_mb, k_all, v_all = jax.shard_map(
             stage_body,
             mesh=mesh,
-            in_specs=(layer_spec, lora_spec, P(), P("pp"), P("pp"),
-                      P(), P(), P(), P(), P(), P(), P()),
+            in_specs=(layer_spec, P(), P("pp"), P("pp"), P(), P()),
             out_specs=(P(), P("pp"), P("pp")),
             axis_names={"pp"},
-        )(params["layers"], lora_layers, lora_scaling, k_all, v_all,
-          x_mb, pos_mb, slots_mb, tables_mb, ctx_mb, seq_mb, aid_mb)
+        )((params["layers"], lora_layers), lora_scaling, k_all, v_all,
+          x_mb, batch_mb)
 
-        x = hidden_mb.reshape(B, T, -1)
-        if last_token is not None:
-            # Prefill sampling reads ONE position (see llama.apply).
-            x = jnp.take_along_axis(x, last_token[:, None, None], axis=1)
-        return project_out(params, cfg, x, output_hidden), (k_all, v_all)
+        x = take_last_token(hidden_mb.reshape(B, T, -1), last_token)
+        return family.head(params, cfg, x, output_hidden), (k_all, v_all)
 
     return pp_apply

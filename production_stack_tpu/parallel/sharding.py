@@ -4,17 +4,13 @@ Megatron-style tensor parallelism expressed as NamedSharding specs — XLA
 GSPMD inserts the all-reduces over ICI (this replaces the NCCL collectives
 inside the reference's vLLM engines):
 
-- attention qkv projections: column-parallel on the head dimension
-  (Llama's one ``wqkv`` leaf has its columns grouped by KV head,
-  models/llama.py::fuse_qkv, so a shard of its last axis holds whole
-  groups: a KV head with its query heads, like the KV pages' shard);
-  ``wo``: row-parallel (all-reduce after).
-- MLP up/gate: column-parallel on intermediate; down: row-parallel.
-- MoE experts: sharded on the expert axis (``ep`` == ``tp`` axis here).
+- each family's leaf -> PartitionSpec table stands beside the
+  ``init_params`` that fixes its shapes (models/registry.py::Family.specs:
+  qkv and MLP up/gate column-parallel, ``wo`` and down row-parallel,
+  experts on the expert axis, the head vocab-sharded); this module holds
+  the rules that apply a table to a mesh;
 - KV pages: sharded on the kv-head axis, so paged attention is fully local
   to each chip (queries for a chip's heads only touch that chip's pages).
-- embeddings/lm_head: vocab-sharded lm_head, replicated input embedding.
-- LoRA slot tensors follow their base projections.
 
 When a dimension does not divide the tp size the leaf falls back to
 replicated (correct, just not distributed) — this keeps tiny test models
@@ -29,72 +25,7 @@ import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from production_stack_tpu.models.config import ModelConfig
-
-# Per-arch leaf -> PartitionSpec templates. Leading axis of "layers" leaves is
-# the stacked layer axis (never sharded). Axis name "tp" is substituted.
-_LLAMA_SPECS = {
-    ("embed",): P(None, None),
-    ("final_norm",): P(None),
-    ("lm_head",): P(None, "tp"),
-    ("layers", "attn_norm"): P(None, None),
-    ("layers", "mlp_norm"): P(None, None),
-    ("layers", "wqkv"): P(None, None, "tp"),
-    ("layers", "wo"): P(None, "tp", None),
-    ("layers", "w_gate"): P(None, None, "tp"),
-    ("layers", "w_up"): P(None, None, "tp"),
-    ("layers", "w_down"): P(None, "tp", None),
-    ("lora", "wq_a"): P(None, None, None, None),
-    ("lora", "wq_b"): P(None, None, None, "tp"),
-    ("lora", "wv_a"): P(None, None, None, None),
-    ("lora", "wv_b"): P(None, None, None, "tp"),
-    ("lora", "scaling"): P(None),
-}
-
-_OPT_SPECS = {
-    ("embed",): P(None, None),
-    ("pos_embed",): P(None, None),
-    ("final_ln_w",): P(None),
-    ("final_ln_b",): P(None),
-    ("layers", "ln1_w"): P(None, None),
-    ("layers", "ln1_b"): P(None, None),
-    ("layers", "ln2_w"): P(None, None),
-    ("layers", "ln2_b"): P(None, None),
-    ("layers", "wq"): P(None, None, "tp"),
-    ("layers", "wq_b"): P(None, "tp"),
-    ("layers", "wk"): P(None, None, "tp"),
-    ("layers", "wk_b"): P(None, "tp"),
-    ("layers", "wv"): P(None, None, "tp"),
-    ("layers", "wv_b"): P(None, "tp"),
-    ("layers", "wo"): P(None, "tp", None),
-    ("layers", "wo_b"): P(None, None),
-    ("layers", "fc1"): P(None, None, "tp"),
-    ("layers", "fc1_b"): P(None, "tp"),
-    ("layers", "fc2"): P(None, "tp", None),
-    ("layers", "fc2_b"): P(None, None),
-}
-
-_MIXTRAL_SPECS = {
-    ("embed",): P(None, None),
-    ("final_norm",): P(None),
-    ("lm_head",): P(None, "tp"),
-    ("layers", "attn_norm"): P(None, None),
-    ("layers", "mlp_norm"): P(None, None),
-    ("layers", "wq"): P(None, None, "tp"),
-    ("layers", "wk"): P(None, None, "tp"),
-    ("layers", "wv"): P(None, None, "tp"),
-    ("layers", "wo"): P(None, "tp", None),
-    ("layers", "router"): P(None, None, None),
-    # Experts shard across the tp axis (expert parallelism on the same mesh).
-    ("layers", "w_gate"): P(None, "tp", None, None),
-    ("layers", "w_up"): P(None, "tp", None, None),
-    ("layers", "w_down"): P(None, "tp", None, None),
-}
-
-
-def _specs_for(arch: str) -> Dict:
-    return {
-        "llama": _LLAMA_SPECS, "opt": _OPT_SPECS, "mixtral": _MIXTRAL_SPECS
-    }[arch]
+from production_stack_tpu.models.registry import get_family
 
 
 def _divisible(shape, spec: P, mesh: Mesh) -> bool:
@@ -132,7 +63,7 @@ def param_shardings(
 
     ``params_shape`` may be the params themselves or their ShapeDtypeStructs.
     """
-    specs = _specs_for(cfg.arch)
+    specs = get_family(cfg.arch).specs
     replicated = NamedSharding(mesh, P())
 
     flat, treedef = jax.tree_util.tree_flatten_with_path(params_shape)
@@ -155,6 +86,35 @@ def param_shardings(
         else:
             out.append(replicated)
     return jax.tree_util.tree_unflatten(treedef, out)
+
+
+def place_checkpoint(cfg: ModelConfig, mesh: Mesh, params: Dict,
+                     loaded: Dict, shardings: Any) -> Dict:
+    """``params`` with a checkpoint's host leaves put in their places on
+    the mesh. Leaves the checkpoint doesn't carry (LoRA slots) keep their
+    init values, except the random head of a family that ties it: a
+    checkpoint without ``lm_head`` reads ``embed.T``."""
+    from production_stack_tpu.parallel.multihost import put_global
+
+    replicated = NamedSharding(mesh, P())
+
+    def merge(dst: dict, src: dict, shard: dict) -> None:
+        for key, val in src.items():
+            if isinstance(val, dict):
+                merge(dst.setdefault(key, {}), val, shard.get(key, {}))
+            else:
+                # put_global: each process contributes its local shards
+                # (device_put cannot target non-addressable devices of a
+                # multi-host mesh; every process loads the same
+                # checkpoint from its own disk).
+                dst[key] = put_global(val, shard.get(key, replicated))
+
+    params = dict(params, layers=dict(params["layers"]))
+    merge(params, loaded, shardings)
+    if get_family(cfg.arch).head_may_tie and "lm_head" not in loaded:
+        params.pop("lm_head", None)
+        params.pop("lm_head_scale", None)
+    return params
 
 
 def kv_pages_sharding(cfg: ModelConfig, mesh: Mesh) -> NamedSharding:

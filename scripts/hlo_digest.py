@@ -1,0 +1,105 @@
+"""Optimised v5e HLO of models.llama.apply at mistral-7b-l16's widths, for
+a refactor that must not change the program (PR 31): run it on a copy of
+the parent and on the change and compare the digests; no chip needed.
+
+    JAX_PLATFORMS=cpu python scripts/hlo_digest.py <repo-root> <out-dir>
+
+Three modes (decode 32 x 1, plain prefill 1 x 512, cached prefill 1 x 256,
+the server's default 8 LoRA slots) x {bf16, int8 weights}, compiled by the
+TPU compiler for a described v5e. Stripped before hashing, as metadata:
+``metadata={...}`` of every instruction, the header's source-location
+tables, and the MLIR locations inside each Mosaic kernel's serialized body.
+Writes ``<mode>.<weights>.hlo`` and ``digests.json``.
+"""
+import hashlib
+import json
+import os
+import re
+import sys
+
+root, out = sys.argv[1], sys.argv[2]
+sys.path.insert(0, root)
+os.makedirs(out, exist_ok=True)
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import topologies
+from jax.sharding import SingleDeviceSharding
+
+jax.config.update("jax_enable_compilation_cache", False)
+
+from production_stack_tpu.models import llama
+from production_stack_tpu.models.config import ModelConfig
+from production_stack_tpu.models.quantize import quantize_tree
+from production_stack_tpu.ops import attention as att
+
+assert os.path.realpath(llama.__file__).startswith(os.path.realpath(root)), llama.__file__
+
+att._use_pallas = lambda: True
+topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+one = SingleDeviceSharding(topo.devices[0])
+
+cfg = ModelConfig(
+    name="mistral-7b-l16", arch="llama", vocab_size=32000, hidden_size=4096,
+    num_layers=16, num_heads=32, num_kv_heads=8, head_dim=128,
+    intermediate_size=14336, max_position=32768, rope_theta=10000.0)
+BS, NB, MAXB = 64, 2048, 64
+
+
+def _body_without_locations(m):
+    import base64
+    from jax._src.interpreters import mlir as jmlir
+    from jax._src.lib.mlir import ir
+    ctx = jmlir.make_ir_context()
+    ctx.allow_unregistered_dialects = True
+    with ctx:
+        asm = ir.Module.parse(base64.b64decode(m.group(1))) \
+            .operation.get_asm(enable_debug_info=False)
+    return '"body_sha256":"%s"' % hashlib.sha256(asm.encode()).hexdigest()
+
+
+def spec(shape, dtype=jnp.int32):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+
+def params_spec(int8):
+    def init():
+        p = llama.init_params(cfg, jax.random.key(0), lora_slots=8, lora_rank=16)
+        return quantize_tree(p, "llama") if int8 else p
+    return jax.tree_util.tree_map(
+        lambda x: spec(x.shape, x.dtype), jax.eval_shape(init))
+
+
+pages = spec((cfg.num_layers, NB, BS, cfg.num_kv_heads, cfg.head_dim), jnp.bfloat16)
+digests = {}
+for weights in ("bf16", "int8"):
+    params = params_spec(weights == "int8")
+    for mode, rows, width in (("decode", 32, 1), ("prefill", 1, 512),
+                              ("prefill_cached", 1, 256)):
+        last = mode != "decode"
+
+        def fn(p, tok, pos, kv, slot, bt, cl, sl, aid, lt):
+            return llama.apply(
+                p, cfg, tok, pos, kv, slot, bt, cl, sl, mode=mode,
+                adapter_ids=aid, last_token=lt if last else None)
+
+        text = jax.jit(fn, donate_argnums=(3,)).lower(
+            params, spec((rows, width)), spec((rows, width)), (pages, pages),
+            spec((rows, width)), spec((rows, MAXB)), spec((rows,)),
+            spec((rows,)), spec((rows,)), spec((rows,))).compile().as_text()
+        # The source-location tables of the header are metadata too.
+        text = re.sub(
+            r"\nFileNames\n.*?\nStackFrames\n.*?\n\n", "\n", text, flags=re.S)
+        text = re.sub(r", metadata=\{[^}]*\}", "", text)
+        text = re.sub(r"metadata=\{[^}]*\}", "", text)
+        # A Mosaic kernel's serialized body carries the MLIR locations of
+        # its call stack (file, line of every frame): parse it and print
+        # it without them.
+        text = re.sub(r'"body":"([^"]*)"', _body_without_locations, text)
+        name = f"{mode}.{weights}"
+        with open(os.path.join(out, name + ".hlo"), "w") as f:
+            f.write(text)
+        digests[name] = hashlib.sha256(text.encode()).hexdigest()
+        print(name, digests[name], len(text), flush=True)
+with open(os.path.join(out, "digests.json"), "w") as f:
+    json.dump(digests, f, indent=1)

@@ -22,7 +22,7 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
-from production_stack_tpu.models import get_model_config, llama
+from production_stack_tpu.models import build_model, get_model_config
 from production_stack_tpu.models.config import ModelConfig
 from production_stack_tpu.ops import attention as att
 from production_stack_tpu.ops.pallas_paged_attention import (
@@ -175,10 +175,11 @@ def test_sharded_decode_attention_on_four_chips(topo, monkeypatch):
         assert held < 2 * pool_side * kvh / 4 * 1.1
 
 
-@pytest.mark.parametrize("mode,rows,width", [
-    ("decode", 32, 1), ("prefill", 1, 256), ("prefill_cached", 1, 256)])
+@pytest.mark.parametrize("arch,mode,rows,width", [
+    ("llama", "decode", 32, 1), ("llama", "prefill", 1, 256),
+    ("llama", "prefill_cached", 1, 256), ("mixtral", "decode", 32, 1)])
 def test_layer_scan_reads_stacked_weights_in_place(one_chip, monkeypatch,
-                                                   mode, rows, width):
+                                                   arch, mode, rows, width):
     """Every matrix of a layer is read by its matmul straight out of the
     stacked ``[L, ...]`` leaf. With three projection leaves, each reshaped
     to heads right after its matmul, the compiler sliced all three out of
@@ -186,11 +187,15 @@ def test_layer_scan_reads_stacked_weights_in_place(one_chip, monkeypatch,
     forward (PERF.md section 6, PR 30): a loop fusion or a copy whose
     result is as large as a layer's smallest matrix is that again.
     Mistral-7B's widths, the default server's LoRA slots; activations at
-    these rows stay under that size."""
+    these rows stay under that size. Mixtral's attention half is Llama's
+    (models/llama.py::attention_half), so with two experts of those widths
+    its scan holds to the same."""
     cfg = ModelConfig(
-        name="mistral-7b-widths", arch="llama", vocab_size=32000,
+        name="mistral-7b-widths", arch=arch, vocab_size=32000,
         hidden_size=4096, num_layers=LAYERS, num_heads=32, num_kv_heads=8,
-        head_dim=128, intermediate_size=14336, rope_theta=10000.0)
+        head_dim=128, intermediate_size=14336, rope_theta=10000.0,
+        num_experts=2 if arch == "mixtral" else 0)
+    init_params, apply = build_model(cfg)
     monkeypatch.setattr(att, "_use_pallas", lambda: True)
 
     def spec(shape, dtype=jnp.int32):
@@ -198,13 +203,13 @@ def test_layer_scan_reads_stacked_weights_in_place(one_chip, monkeypatch,
 
     params = jax.tree_util.tree_map(
         lambda x: spec(x.shape, x.dtype),
-        jax.eval_shape(lambda: llama.init_params(
+        jax.eval_shape(lambda: init_params(
             cfg, jax.random.key(0), lora_slots=8, lora_rank=16)))
     pages = _pages(one_chip, cfg.num_kv_heads, cfg.head_dim, False)
     # The pool is donated, as the engine's step programs donate it:
     # otherwise the program's entry copies it whole.
     text = jax.jit(
-        lambda p, tok, pos, kv, slot, bt, cl, sl, aid: llama.apply(
+        lambda p, tok, pos, kv, slot, bt, cl, sl, aid: apply(
             p, cfg, tok, pos, kv, slot, bt, cl, sl, mode=mode,
             adapter_ids=aid), donate_argnums=(3,)).lower(
         params, spec((rows, width)), spec((rows, width)), (pages, pages),

@@ -7,21 +7,18 @@ is a re-arrangement of those columns, so every comparison is between the
 same numbers contracted over the same ``Hd``.
 """
 
+import dataclasses
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from production_stack_tpu.models import llama
+from production_stack_tpu.models import decoder, llama
 from production_stack_tpu.models.config import ModelConfig
 from production_stack_tpu.models.quantize import quantize_tree
 from production_stack_tpu.models.weights import load_checkpoint
-from production_stack_tpu.ops.attention import (
-    context_prefill_attention,
-    paged_decode_attention,
-    prefill_attention,
-    write_kv_pages,
-)
 
 BLOCK, NUM_BLOCKS, MAXB = 4, 16, 8
 # H == KVH (G = 1) and H = 4 KVH; widths chosen so that no other matrix of
@@ -73,41 +70,39 @@ def _quantized_alone(draws):
             {n: q[b + "_scale"] for n, b in zip(names, borrowed)})
 
 
-def _three_matmul_layer(cfg, mode, x, p, lora, kv, layer, positions,
-                        slot_mapping, block_tables, context_lens, seq_lens,
-                        lora_scaling, adapter_ids):
+def _three_matmul_layer(cfg, mode, x, per_layer, kv, layer, batch):
     """The layer as it was with three leaves: one matmul each, reshaped to
     heads; everything after the projections is the served layer's code."""
+    p, lora = per_layer
     B, T, _ = x.shape
     H, KVH, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    scale = 1.0 / (D ** 0.5)
-    k_pages, v_pages = kv
     h = llama.rms_norm(x, p["attn_norm"], cfg.rms_norm_eps)
     q_flat, k_flat, v_flat = h @ p["wq"], h @ p["wk"], h @ p["wv"]
     if lora is not None:
         q_flat = q_flat + llama._lora_delta(
-            h, lora["wq_a"], lora["wq_b"], lora_scaling, adapter_ids)
+            h, lora["wq_a"], lora["wq_b"], batch.lora_scaling,
+            batch.adapter_ids)
         v_flat = v_flat + llama._lora_delta(
-            h, lora["wv_a"], lora["wv_b"], lora_scaling, adapter_ids)
-    q = llama.rope(q_flat.reshape(B, T, H, D), positions, cfg.rope_theta)
-    k = llama.rope(k_flat.reshape(B, T, KVH, D), positions, cfg.rope_theta)
-    v = v_flat.reshape(B, T, KVH, D)
-    k_pages, v_pages = write_kv_pages(
-        k_pages, v_pages, k, v, slot_mapping, layer)
-    if mode == "prefill":
-        attn = prefill_attention(q, k, v, scale=scale, seq_lens=seq_lens)
-    elif mode == "prefill_cached":
-        attn = context_prefill_attention(
-            q, k_pages, v_pages, block_tables, positions, context_lens,
-            layer, scale=scale, k_new=k, v_new=v, suffix_lens=seq_lens)
-    else:
-        attn = paged_decode_attention(
-            q[:, 0], k_pages, v_pages, block_tables, context_lens, layer,
-            scale=scale)[:, None]
+            h, lora["wv_a"], lora["wv_b"], batch.lora_scaling,
+            batch.adapter_ids)
+    q = llama.rope(q_flat.reshape(B, T, H, D), batch.positions,
+                   cfg.rope_theta)
+    k = llama.rope(k_flat.reshape(B, T, KVH, D), batch.positions,
+                   cfg.rope_theta)
+    attn, kv = decoder.attend(
+        mode, q, k, v_flat.reshape(B, T, KVH, D), kv, layer, batch,
+        scale=1.0 / (D ** 0.5))
     x = x + attn.reshape(B, T, H * D) @ p["wo"]
     h = llama.rms_norm(x, p["mlp_norm"], cfg.rms_norm_eps)
     gate = jax.nn.silu((h @ p["w_gate"]).astype(jnp.float32)).astype(h.dtype)
-    return x + (gate * (h @ p["w_up"])) @ p["w_down"], (k_pages, v_pages)
+    return x + (gate * (h @ p["w_up"])) @ p["w_down"], kv
+
+
+# The same skeleton, embed and head around the three-matrix layer: what a
+# family is (models/registry.py::Family) is what a reference swaps.
+_three_matmul_apply = functools.partial(
+    decoder.apply,
+    dataclasses.replace(llama.FAMILY, layer=_three_matmul_layer))
 
 
 def _pages(cfg):
@@ -116,7 +111,7 @@ def _pages(cfg):
     return jnp.zeros(shape, cfg.jnp_dtype), jnp.zeros(shape, cfg.jnp_dtype)
 
 
-def _three_modes(params, cfg, adapter_ids=None):
+def _three_modes(params, cfg, adapter_ids=None, apply=llama.apply):
     """Logits of one batch in all three modes: a plain prefill of 8
     tokens a row, a cached prefill of 4 more over those pages, then a
     decode step."""
@@ -130,7 +125,7 @@ def _three_modes(params, cfg, adapter_ids=None):
                          ("decode", 12, 13)):
         pos = np.tile(np.arange(lo, hi, dtype=np.int32), (B, 1))
         slots = np.take_along_axis(bt, pos // BLOCK, 1) * BLOCK + pos % BLOCK
-        out[mode], kv = llama.apply(
+        out[mode], kv = apply(
             params, cfg, jnp.asarray(tokens[:, lo:hi]), jnp.asarray(pos),
             kv, jnp.asarray(slots, jnp.int32), jnp.asarray(bt),
             jnp.full((B,), hi, jnp.int32), jnp.full((B,), hi - lo, jnp.int32),
@@ -162,14 +157,13 @@ def test_init_leaf_is_the_three_draws_rearranged(heads, kv_heads):
 
 @pytest.mark.parametrize("mode", ["prefill", "prefill_cached", "decode"])
 @pytest.mark.parametrize("heads,kv_heads", HEADS)
-def test_apply_matches_three_matmul_forward(heads, kv_heads, mode,
-                                            monkeypatch):
+def test_apply_matches_three_matmul_forward(heads, kv_heads, mode):
     cfg = _cfg(heads, kv_heads, "bfloat16")
     rng = jax.random.key(11)
     params = llama.init_params(cfg, rng)
     got = _three_modes(params, cfg)[mode]
-    monkeypatch.setattr(llama, "_layer", _three_matmul_layer)
-    want = _three_modes(_unfused(params, cfg, rng), cfg)[mode]
+    want = _three_modes(_unfused(params, cfg, rng), cfg,
+                        apply=_three_matmul_apply)[mode]
     # Same columns, same contraction: at most one bf16 step (2 ** -8 of
     # a value) where a backend sums a wider matmul in another order.
     np.testing.assert_allclose(got, want, rtol=2 ** -7, atol=2 ** -7)
@@ -191,8 +185,7 @@ def test_int8_leaf_is_the_three_quantized_column_for_column(heads, kv_heads):
 
 
 @pytest.mark.parametrize("heads,kv_heads", HEADS)
-def test_int8_apply_matches_three_matmul_forward(heads, kv_heads,
-                                                 monkeypatch):
+def test_int8_apply_matches_three_matmul_forward(heads, kv_heads):
     cfg = _cfg(heads, kv_heads)
     rng = jax.random.key(6)
     params = llama.init_params(cfg, rng)
@@ -206,15 +199,15 @@ def test_int8_apply_matches_three_matmul_forward(heads, kv_heads,
         ints[name], scales[name] = rest[name], rest[name + "_scale"]
     deq = {**unfused["layers"],
            **{n: ints[n].astype(jnp.float32) * scales[n] for n in ints}}
-    monkeypatch.setattr(llama, "_layer", _three_matmul_layer)
-    want = _three_modes({**unfused, "layers": deq}, cfg)
+    want = _three_modes({**unfused, "layers": deq}, cfg,
+                        apply=_three_matmul_apply)
     for mode in want:
         np.testing.assert_allclose(got[mode], want[mode],
                                    rtol=2e-4, atol=2e-4)
 
 
 @pytest.mark.parametrize("heads,kv_heads", HEADS)
-def test_lora_deltas_land_on_q_and_v_only(heads, kv_heads, monkeypatch):
+def test_lora_deltas_land_on_q_and_v_only(heads, kv_heads):
     cfg = _cfg(heads, kv_heads)
     rng = jax.random.key(8)
     params = llama.init_params(cfg, rng, lora_slots=2, lora_rank=4)
@@ -228,8 +221,8 @@ def test_lora_deltas_land_on_q_and_v_only(heads, kv_heads, monkeypatch):
 
     got = _three_modes(params, cfg, ids)
     base = _three_modes(params, cfg, jnp.zeros((3,), jnp.int32))
-    monkeypatch.setattr(llama, "_layer", _three_matmul_layer)
-    want = _three_modes(_unfused(params, cfg, rng), cfg, ids)
+    want = _three_modes(_unfused(params, cfg, rng), cfg, ids,
+                        apply=_three_matmul_apply)
     for mode in want:
         # q and v carry the delta exactly as the three-matrix layer adds
         # it, k none: any delta on k's columns would show here.

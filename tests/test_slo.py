@@ -253,7 +253,10 @@ async def _complete(s, rurl, **extra):
         return status
 
 
-async def _wait_counts(state, total, timeout_s=5.0):
+async def _wait_counts(state, total, timeout_s=60.0):
+    # A liveness deadline, not a speed: the poll ends as soon as the
+    # outcomes are in, however long a host shared by six test workers
+    # takes to classify them.
     deadline = time.monotonic() + timeout_s
     while time.monotonic() < deadline:
         if sum(state.slo.counts().values()) >= total:
@@ -264,21 +267,26 @@ async def _wait_counts(state, total, timeout_s=5.0):
 
 async def test_outcome_classification_ok_slow_failed(tmp_path):
     """One request per latency outcome plus an unroutable model, each
-    classified exactly once (counts sum to requests seen)."""
+    classified exactly once (counts sum to requests seen). Both bounds
+    follow from the delay the fake engine injects before its first token,
+    not from how fast this host relays it: the first is a thousand times
+    that delay, the second half of it, which no host can meet."""
+    injected_ttft = 0.05
     path = _slo_file(tmp_path, {
-        "default": {"ttft_p99_s": 30.0, "inter_token_p99_s": 30.0},
-        # The slow tenant's TTFT bound is unmeetable, so its (successful)
-        # request classifies slow.
-        "models": {"test-model": {"ttft_p99_s": 30.0}},
+        "default": {"ttft_p99_s": 1000 * injected_ttft,
+                    "inter_token_p99_s": 1000 * injected_ttft},
+        "models": {"test-model": {"ttft_p99_s": 1000 * injected_ttft}},
     })
     engine, eurl, app, rurl, runners = await _router_one_engine(
+        engine=FakeEngine(model="test-model", ttft=injected_ttft,
+                          tokens_per_sec=500.0),
         slo_config=path)
     state = app["state"]
     assert state.slo is not None and state.slo.source == path
     try:
         async with aiohttp.ClientSession() as s:
             assert await _complete(s, rurl) == 200            # -> ok
-            state.slo.models["test-model"]["ttft_p99_s"] = 1e-9
+            state.slo.models["test-model"]["ttft_p99_s"] = injected_ttft / 2
             assert await _complete(s, rurl) == 200            # -> slow
             assert await _complete(s, rurl, model="nope") == 400  # -> failed
             counts = await _wait_counts(state, 3)

@@ -5,11 +5,14 @@ the loop's phases and the fields they add to a record, and the hermetic
 prefill-profile artifact schema."""
 
 import asyncio
+import collections
 import json
+import math
 import os
 import subprocess
 import sys
 import time
+import timeit
 
 import jax
 import pytest
@@ -264,54 +267,85 @@ def test_recorder_disabled_by_config():
         eng.stop()
 
 
+# The pace the bound is held against: a decode forward of the benchmark's
+# configuration takes 11.4-11.8 ms on a v5e (PERF_LEDGER.jsonl,
+# ``decode_token_step_ms.*``, PR 30), and one row gains one token a forward.
+CHIP_TOKEN_S = 0.010
+
+
+def _best_s(fn, number=2000, repeat=7):
+    """Seconds one call of ``fn`` takes, alone in this process: the best
+    of ``repeat`` timings of ``number`` calls, which a busy neighbour can
+    only raise."""
+    return min(timeit.repeat(fn, number=number, repeat=repeat)) / number
+
+
 def test_recorder_overhead_under_one_percent():
-    """A/B the same engine with the recorder toggled: tokens/s with the
-    recorder on must be within 1% of recorder-off. The recorder is one
-    dict stash + one locked append per step, so on a CPU engine where a
-    leg is tens of milliseconds the true cost is ~0.1%; the estimator
-    has to beat scheduler jitter, not the recorder. Legs are
-    interleaved with alternating order (cancels warming drift) and the
-    bound compares the mean of each side's fastest quartile (stabler
-    than a raw min-of-N)."""
+    """What the recorder does for one request, counted, times what each
+    call costs alone, is under 1% of the time a chip takes to stream the
+    request's tokens. (The walls of two CPU generations, recorder on and
+    off, are a speed of a host that five other test workers share: not
+    compared.) Counted are
+    the calls the engine made on the loop's clock while it served 64
+    tokens; ``idle_wait`` is left out, being entered only while the
+    engine has nothing to do."""
     eng = _make_engine()
-    recorder = eng.step_recorder
-    assert recorder is not None
     n_tokens = 64
+    methods = ("loop_step", "phase", "start", "note", "note_program",
+               "record")
     try:
-        # Warm both code paths (compile + caches) before timing.
-        _generate(eng, "warm-on", n_tokens)
-        eng.step_recorder = None
-        _generate(eng, "warm-off", n_tokens)
-        walls = {"on": [], "off": []}
-
-        def floor_s(leg):
-            best = sorted(walls[leg])[:max(1, len(walls[leg]) // 4)]
-            return sum(best) / len(best)
-
-        # Accumulate interleaved legs until the floors converge under
-        # the bound (the floor estimate only improves with samples); a
-        # genuine >1% regression keeps failing through every batch.
-        tok_s_on = tok_s_off = 0.0
-        for i in range(36):
-            order = (("on", recorder), ("off", None))
-            if i % 2:
-                order = order[::-1]
-            for leg, rec in order:
-                eng.step_recorder = rec
-                t0 = time.perf_counter()
-                got = _generate(eng, f"ab-{leg}-{i}", n_tokens)
-                walls[leg].append(time.perf_counter() - t0)
-                assert got == n_tokens
-            tok_s_on = n_tokens / floor_s("on")
-            tok_s_off = n_tokens / floor_s("off")
-            if i >= 5 and tok_s_on >= 0.99 * tok_s_off:
-                break
-        assert tok_s_on >= 0.99 * tok_s_off, (
-            f"recorder overhead above 1%: on={tok_s_on:.1f} tok/s "
-            f"off={tok_s_off:.1f} tok/s over {len(walls['on'])} legs")
+        _generate(eng, "warm", n_tokens)
+        rec = eng.step_recorder
+        assert rec is eng._steps
+        calls = collections.Counter()
+        for name in methods:
+            def counted(*args, _fn=getattr(rec, name), _name=name, **kwargs):
+                calls[_name] += 1
+                return _fn(*args, **kwargs)
+            setattr(rec, name, counted)
+        before = rec.phase_stats()["idle_wait"]["count"], rec.recorded_total
+        assert _generate(eng, "counted", n_tokens) == n_tokens
+        calls["phase"] -= rec.phase_stats()["idle_wait"]["count"] - before[0]
+        steps = rec.recorded_total - before[1]
     finally:
-        eng.step_recorder = recorder
         eng.stop()
+    # A prefill and at least one burst, each in the ring.
+    assert 2 <= steps <= calls["record"], calls
+    assert calls["phase"] >= 4 * steps  # schedule, build, enqueue, emit
+
+    alone = StepRecorder(capacity=1024, param_bytes=1, kv_token_bytes=1)
+    with alone.loop_step(True):
+        def phase():
+            with alone.phase("build"):
+                pass
+
+        def step():
+            alone.start()
+            alone.record("decode_burst", rows=1, tokens=8, forwards=8)
+
+        unit = {
+            "phase": _best_s(phase),
+            "note": _best_s(lambda: alone.note(
+                waiting=0, running=1, kv_blocks_live=1, kv_blocks_cached=0,
+                kv_blocks_free=63)),
+            "note_program": _best_s(
+                lambda: alone.note_program("decode_k8", 8)),
+            "start": 0.0,  # timed with the record it opens
+            "record": _best_s(step),
+        }
+
+    def iteration():
+        with alone.loop_step(True):
+            pass
+
+    unit["loop_step"] = _best_s(iteration)
+    cost = sum(calls[name] * unit[name] for name in methods)
+    budget = 0.01 * n_tokens * CHIP_TOKEN_S
+    assert cost <= budget, (
+        f"recorder overhead above 1%: {cost * 1e6:.0f} us for {n_tokens} "
+        f"tokens ({dict(calls)} calls at "
+        f"{ {k: round(v * 1e6, 2) for k, v in unit.items()} } us) against "
+        f"{budget * 1e6:.0f} us")
 
 
 # ---------------------------------------------------------------------------
@@ -565,7 +599,11 @@ def test_prefill_profile_hermetic_schema():
             assert row[key] is not None
         for key in ("attention_est_s", "copy_est_s", "matmul_est_s"):
             assert key in row["components"], key
-        assert row["full_s"] > 0 and row["bare_matmul_s"] > 0
+        # A number, of either sign: each is the difference of two walls
+        # of a tiny CPU program, which a neighbouring test worker moves
+        # by more than the program takes. The schema is what is held.
+        assert math.isfinite(row["full_s"])
+        assert math.isfinite(row["bare_matmul_s"])
     assert doc["floors"] is None  # the CPU has no HBM peak to floor by
     # The committed artifact must match the schema the profiler emits
     # today (drift check for BENCH_PREFILL_PROFILE_*.json).

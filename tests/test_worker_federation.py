@@ -12,10 +12,13 @@ import argparse
 import asyncio
 import json
 import os
+import pathlib
 import signal
 import socket
 import subprocess
 import sys
+import tempfile
+import time
 import urllib.request
 
 import aiohttp
@@ -246,12 +249,18 @@ async def test_flag_off_parity_no_worker_series_no_worker_label():
 # ---------------------------------------------------------------------------
 
 
-def _get(url: str, timeout: float = 10.0) -> bytes:
+# The smoke test injects no delay, so none of its waits asserts a speed:
+# each is a liveness deadline far beyond a busy host's (six xdist workers
+# share it), and every poll ends as soon as its condition holds.
+LIVENESS_S = 60.0
+
+
+def _get(url: str, timeout: float = LIVENESS_S) -> bytes:
     with urllib.request.urlopen(url, timeout=timeout) as resp:
         return resp.read()
 
 
-def _post_completion(url: str, timeout: float = 10.0) -> int:
+def _post_completion(url: str, timeout: float = LIVENESS_S) -> int:
     req = urllib.request.Request(
         url + "/v1/completions",
         data=json.dumps({"model": "test-model", "prompt": "hi",
@@ -262,11 +271,24 @@ def _post_completion(url: str, timeout: float = 10.0) -> int:
         return resp.status
 
 
-async def test_two_worker_smoke_aggregated_scrape_and_teardown():
+@pytest.fixture
+def short_tmp_path():
+    """A directory of this test's own whose path is short: a worker's
+    socket path has to fit AF_UNIX's 108 bytes, and pytest's ``tmp_path``
+    under xdist does not leave room."""
+    with tempfile.TemporaryDirectory(prefix="fed-") as path:
+        yield pathlib.Path(path)
+
+
+async def test_two_worker_smoke_aggregated_scrape_and_teardown(
+        short_tmp_path):
     """Spawn ``--router-workers 2``, serve a couple of requests, and
     assert the aggregated ``/metrics`` shows both worker labels and a
     summed request counter; SIGTERM must exit 0 leaving no child
-    processes and no socket directory behind."""
+    processes and no socket directory behind. The router gets a
+    temporary directory of its own: the shared one may hold the socket
+    directory of another test file's two-worker router
+    (tests/test_relay_pump.py, on another xdist worker)."""
     engine = FakeEngine(model="test-model", ttft=0.0)
     erunner, eurl = await _start(engine.make_app())
     with socket.socket() as s:
@@ -281,17 +303,21 @@ async def test_two_worker_smoke_aggregated_scrape_and_teardown():
          "--routing-logic", "roundrobin",
          "--engine-stats-interval", "60",
          "--log-level", "warning"],
-        env=dict(os.environ, TPU_STACK_LOG_LEVEL="warning"))
+        env=dict(os.environ, TPU_STACK_LOG_LEVEL="warning",
+                 TMPDIR=str(short_tmp_path)))
     try:
-        for _ in range(150):
+        deadline = time.monotonic() + LIVENESS_S
+        while True:
             try:
                 await asyncio.to_thread(_get, rurl + "/health", 2.0)
                 break
             except OSError:
+                if time.monotonic() > deadline:
+                    raise RuntimeError(
+                        "2-worker router never became healthy") from None
                 await asyncio.sleep(0.2)
-        else:
-            raise RuntimeError("2-worker router never became healthy")
 
+        assert len(list(short_tmp_path.glob("tpu-router-workers-*"))) == 1
         n_requests = 4
         for _ in range(n_requests):
             assert await asyncio.to_thread(
@@ -305,8 +331,9 @@ async def test_two_worker_smoke_aggregated_scrape_and_teardown():
         assert len(pids) == 2
 
         # The finished-request gauge lags the response by the relay's
-        # bookkeeping; poll the aggregated scrape briefly.
-        for _ in range(50):
+        # bookkeeping; poll the aggregated scrape until it has them.
+        deadline = time.monotonic() + LIVENESS_S
+        while True:
             exposition = (await asyncio.to_thread(
                 _get, rurl + "/metrics")).decode()
             total = sum(
@@ -314,7 +341,7 @@ async def test_two_worker_smoke_aggregated_scrape_and_teardown():
                 for line in exposition.splitlines()
                 if line.startswith(
                     "vllm_router:num_finished_requests{"))
-            if total == n_requests:
+            if total == n_requests or time.monotonic() > deadline:
                 break
             await asyncio.sleep(0.1)
         # Unlabeled per-process gauges export from every worker, so both
@@ -326,7 +353,7 @@ async def test_two_worker_smoke_aggregated_scrape_and_teardown():
     finally:
         proc.send_signal(signal.SIGTERM)
         try:
-            rc = proc.wait(timeout=20)
+            rc = proc.wait(timeout=LIVENESS_S)
         except subprocess.TimeoutExpired:
             proc.kill()
             raise
@@ -337,7 +364,4 @@ async def test_two_worker_smoke_aggregated_scrape_and_teardown():
     # the UDS directory was removed.
     with pytest.raises(OSError):
         await asyncio.to_thread(_get, rurl + "/health", 2.0)
-    import glob
-    import tempfile
-    assert glob.glob(os.path.join(
-        tempfile.gettempdir(), "tpu-router-workers-*")) == []
+    assert list(short_tmp_path.glob("tpu-router-workers-*")) == []
