@@ -1,6 +1,9 @@
 #!/usr/bin/env python3
 """Sweep KV page size (block_size) at fixed total context: fewer, bigger
-DMAs per kernel invocation.
+DMAs per kernel invocation. With ``--cells``: the decode kernel alone, at
+the tile it chooses itself, on the contexts the benchmark's cells hand it
+(:data:`CELL_CASES`; the numbers of the kernel's docstring and of PERF.md
+section 6, PR 32).
 
 Timing methodology (benchmarks/timing.py): every timed sequence ends in
 a real ``device_get`` readback, and per-iteration cost is recovered by
@@ -30,6 +33,80 @@ from timing import timed_per_call  # noqa: E402
 
 B = 16
 CTX = int(os.environ.get("CHECK_CTX", "3000"))
+
+# The serving shapes of the benchmark's cells (Mistral-7B's heads, 64-token
+# pages, the 16-layer pool of 1,559 blocks, 32 rows) and the contexts they
+# hand the kernel: name -> (contexts, table width in pages).
+CELL_L, CELL_NB, CELL_BS, CELL_KVH, CELL_D, CELL_H = 16, 1559, 64, 8, 128, 32
+_SESSIONS = [3235, 1080, 3178, 2317, 1870, 1731, 1449, 1569, 2561, 2660,
+             1667, 3517]  # 12 live rows, uniform in 600-3,600
+_BACKLOG = [1636, 844, 208, 363, 462, 784, 1348, 769, 512, 840, 575, 718,
+            450, 334, 1316, 545, 693, 467, 481, 535, 933, 1270, 876, 479,
+            2500, 1031, 1092, 481, 568, 908, 249, 424]  # lognormal, median 634
+CELL_CASES = {
+    "a_32x1_w64": ([1] * 32, 64),
+    "b_backlog_w32": ([min(c, 2048) for c in _BACKLOG], 32),
+    "b_backlog_w64": (_BACKLOG, 64),
+    "c_12live_20x1_w64": (_SESSIONS + [1] * 20, 64),
+    "d_12live_20x0_w64": (_SESSIONS + [0] * 20, 64),
+    "e_16x3000_w64": ([3000] * 16, 64),
+    "f_32x700_w16": ([700] * 32, 16),
+}
+
+
+def cell_tables(contexts, width: int, bs: int, nb: int, rng) -> np.ndarray:
+    """[B, width]: each row's live pages distinct and scattered over the
+    pool, zero past them (as the engine leaves a table)."""
+    tables = np.zeros((len(contexts), width), np.int32)
+    free = rng.permutation(np.arange(1, nb))
+    at = 0
+    for b, c in enumerate(contexts):
+        n = min(-(-c // bs), width)
+        tables[b, :n] = free[at:at + n]
+        at += n
+    return tables
+
+
+def time_cells():
+    """One JSON line a case: us a call (one layer) of the kernel alone,
+    the same scan without it taken off, beside the time its live tokens'
+    bytes need at 819 GB/s."""
+    L, NB, bs, KVH, D, H = (CELL_L, CELL_NB, CELL_BS, CELL_KVH, CELL_D,
+                            CELL_H)
+    rng = np.random.default_rng(32)
+    k1, k2, k3 = jax.random.split(jax.random.key(0), 3)
+    k_pages = jax.random.normal(k1, (L, NB, bs, KVH, D), jnp.bfloat16)
+    v_pages = jax.random.normal(k2, (L, NB, bs, KVH, D), jnp.bfloat16)
+    reps = 8  # calls of each layer in one timed scan
+
+    def scan_of(with_kernel: bool):
+        @jax.jit
+        def run(q, k_pages, v_pages, bt, cl):
+            def body(acc, l):
+                o = (pallas_paged_attention(
+                    q, k_pages, v_pages, bt, cl, l % L, scale=D ** -0.5)
+                    if with_kernel else q * (l % L).astype(q.dtype))
+                return acc + o.astype(jnp.float32), None
+            out, _ = jax.lax.scan(
+                body, jnp.zeros(q.shape, jnp.float32),
+                jnp.arange(L * reps))
+            return out
+        return run
+
+    for name, (contexts, width) in CELL_CASES.items():
+        q = jax.random.normal(k3, (len(contexts), H, D), jnp.bfloat16)
+        bt = jnp.asarray(cell_tables(contexts, width, bs, NB, rng))
+        cl = jnp.asarray(contexts, jnp.int32)
+        args = (q, k_pages, v_pages, bt, cl)
+        per_scan = (timed_per_call(scan_of(True), *args)
+                    - timed_per_call(scan_of(False), *args))
+        live = sum(contexts)
+        print(json.dumps({
+            "case": name, "rows": len(contexts), "table_pages": width,
+            "live_tokens": live,
+            "us_per_call": round(per_scan / (L * reps) * 1e6, 1),
+            "floor_us": round(live * 2 * KVH * D * 2 / 819e9 * 1e6, 1),
+        }), flush=True)
 
 
 def main():
@@ -88,4 +165,7 @@ def main():
 
 
 if __name__ == "__main__":
-    main()
+    if "--cells" in sys.argv[1:]:
+        time_cells()
+    else:
+        main()

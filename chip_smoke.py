@@ -83,7 +83,9 @@ def make_prompt(n_tokens: int, tag: str) -> str:
 
 def kernel_parity(plan: Plan) -> None:
     """Both kernels, over bf16 pages (the default path) and int8 pages
-    (``--kv-cache-dtype int8``), at the model's real page shape."""
+    (``--kv-cache-dtype int8``), at the model's real page shape; for a
+    model whose pages the dispatchers send to the reference (the
+    rehearsal's tiny one), at the narrowest page they send to the kernels."""
     import jax
     import jax.numpy as jnp
 
@@ -91,6 +93,7 @@ def kernel_parity(plan: Plan) -> None:
     from production_stack_tpu.models import get_model_config
     from production_stack_tpu.ops.attention import (
         _context_prefill_reference,
+        _page_tile_ok,
         paged_attention_reference,
         quantize_kv,
     )
@@ -105,6 +108,8 @@ def kernel_parity(plan: Plan) -> None:
     bs = build_arg_parser().parse_args(
         [plan.model, *plan.engine_flags]).block_size
     H, KVH, D = mc.num_heads, mc.num_kv_heads, mc.head_dim
+    if not _page_tile_ok(bs, KVH, D):
+        H, KVH, D = 8 * (H // KVH), 8, 128  # same query group
     L, layer, scale = 2, jnp.int32(1), D ** -0.5
     B, MAXB, T = 4, 16, 4 * bs
     S, NB = MAXB * bs, 4 * 16 + 3

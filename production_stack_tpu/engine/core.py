@@ -496,6 +496,7 @@ class EngineCore:
         # Token positions the prefill programs computed on: spans padded to
         # their bucket (and batched groups to [prefill_batch, chunk]).
         self.prefill_padded_tokens_total = 0
+        self.kv_fetch_tokens_total = 0  # copied by the decode kernel
         self.generation_tokens_total = 0
         self.requests_finished_total = 0
         self.step_count = 0
@@ -2335,6 +2336,7 @@ class EngineCore:
             "prompt_tokens_total": self.prompt_tokens_total,
             "cached_tokens_total": self.cached_tokens_total,
             "prefill_padded_tokens_total": self.prefill_padded_tokens_total,
+            "kv_fetch_tokens_total": self.kv_fetch_tokens_total,
             "generation_tokens_total": self.generation_tokens_total,
             "offload": self.offload.stats() if self.offload else None,
             # Page residency split: HBM pages currently allocated vs
@@ -2764,7 +2766,7 @@ class EngineCore:
                     req, tokens, block_ids, start, end), 0)
         self.prefill_chunks_total += len(ready)
         self.last_step_batched_tokens = step_tokens
-        path = self._prefill_attn_path()
+        path = self._paged_attn_path()
         self._step_info = {
             "kind": "prefill_chunk", "rows": len(ready),
             "tokens": step_tokens,
@@ -2815,12 +2817,13 @@ class EngineCore:
                 {"req": req, "seq": seq, "slot": slot,
                  "sampled": sampled, "row": row})
 
-    def _prefill_attn_path(self) -> str:
-        """Which attention path cached-prefill dispatches take at this
-        engine's page shape: "pallas" (flash prefix kernel) or "xla"
+    def _paged_attn_path(self) -> str:
+        """Which attention path cached-prefill and decode dispatches take
+        at this engine's page shape: "pallas" (the paged kernels) or "xla"
         (gather reference). Trace-time static — labels
         tpu:prefill_attention_dispatch_total and the roofline's
-        KV-read-byte model."""
+        KV-read-byte model, and decides whether a decode burst counts
+        ``kv_fetch_tokens``."""
         from production_stack_tpu.ops.attention import attention_path
 
         mc = self.model_config
@@ -3235,7 +3238,7 @@ class EngineCore:
             # already advanced through the automaton at emission).
             self._fill_mask_row(mask_bits, mask_on, i, req)
 
-        self.prefill_attention_dispatch_total[self._prefill_attn_path()] += 1
+        self.prefill_attention_dispatch_total[self._paged_attn_path()] += 1
         return self._dispatch("prefill", {"cached": True}, [
             token_arr, positions, slot_mapping,
             block_table, context_lens, seq_lens, adapter_ids,
@@ -3297,7 +3300,7 @@ class EngineCore:
 
         if start > 0:
             self.prefill_attention_dispatch_total[
-                self._prefill_attn_path()] += 1
+                self._paged_attn_path()] += 1
         return self._dispatch("prefill", {"cached": start > 0}, [
             token_arr, positions, slot_mapping,
             block_table, context_lens, seq_lens, adapter_ids,
@@ -3519,6 +3522,21 @@ class EngineCore:
             self._fill_mask_row(mask_bits, mask_on, i, r)
             r.scheduled_steps += allow
 
+        if self._paged_attn_path() == "pallas":
+            # What the decode kernel copies for this burst, beside what
+            # it has to: per scan step, the live pages of the rows that
+            # write a token in it, and the tokens they hold (the host's
+            # twin of decoder.attend's contexts).
+            from production_stack_tpu.ops.pallas_paged_attention import (
+                fetch_tokens,
+            )
+
+            live = np.where(
+                slot_mat >= 0, context0[:, None] + np.arange(K), 0)
+            fetched = fetch_tokens(live, cfg.block_size, maxb)
+            self.kv_fetch_tokens_total += fetched
+            self._steps.note(
+                kv_fetch_tokens=fetched, kv_live_tokens=int(live.sum()))
         outs = self._dispatch(
             "decode", {"K": K, "use_prev": prev is not None}, [
                 reset_counts, tok_idx, host_tokens, use_host, positions0,

@@ -2476,6 +2476,9 @@ class EngineServer:
             "# TYPE tpu:prefill_padded_tokens counter",
             f"tpu:prefill_padded_tokens_total{{{labels}}} "
             f"{s['prefill_padded_tokens_total']}",
+            "# TYPE tpu:kv_fetch_tokens counter",
+            f"tpu:kv_fetch_tokens_total{{{labels}}} "
+            f"{s['kv_fetch_tokens_total']}",
             # Disaggregated-prefill KV handoff (the NIXL-pipe equivalent).
             "# TYPE tpu:kv_transfer_tx_bytes counter",
             f"tpu:kv_transfer_tx_bytes_total{{{labels}}} {self.kv_transfer_tx_bytes}",

@@ -80,9 +80,15 @@ def attend(
                 suffix_lens=batch.seq_lens,
             )
         else:
+            # A row that writes no token this step (slot -1: a row no
+            # sequence holds, or a burst step past what its sequence may
+            # use) has its sampled token discarded, so it attends over
+            # nothing: the kernel copies no page for a context of 0.
+            live = jnp.where(
+                batch.slot_mapping[:, 0] >= 0, batch.context_lens, 0)
             attn = paged_decode_attention(
-                q[:, 0], k_pages, v_pages, batch.block_tables,
-                batch.context_lens, layer, scale=scale,
+                q[:, 0], k_pages, v_pages, batch.block_tables, live,
+                layer, scale=scale,
             )[:, None]
     return attn, (k_pages, v_pages)
 

@@ -7,8 +7,8 @@ stack consumes via container images) with TPU-native equivalents:
   XLA fuses this into MXU-friendly batched matmuls.
 - decode: query length 1 per sequence against KV pages scattered in HBM.
   The pallas kernel (:mod:`production_stack_tpu.ops.pallas_paged_attention`)
-  walks only the live blocks of each sequence; the XLA fallback gathers the
-  padded context (correct everywhere, used on CPU test meshes).
+  walks only the live pages of the rows that hold a token; the XLA fallback
+  gathers the padded context (correct everywhere, used on CPU test meshes).
 
 All softmax accumulation is float32 regardless of compute dtype.
 """
@@ -410,7 +410,9 @@ def paged_attention_reference(
     *,
     scale: float,
 ) -> jax.Array:
-    """XLA fallback: gather the padded context, mask, soft-max. [B, H, D]."""
+    """XLA fallback: gather the padded context, mask, soft-max. [B, H, D];
+    zeros for a row whose context is 0 or less (it holds nothing), as the
+    kernel gives."""
     B, H, D = q.shape
     k_data = kv_page_data(k_pages)
     bs, KVH = k_data.shape[2], k_data.shape[3]
@@ -427,6 +429,7 @@ def paged_attention_reference(
     scores = jnp.where(mask[:, None, None, :], scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bkgs,bskd->bkgd", probs.astype(v_ctx.dtype), v_ctx)
+    out = jnp.where(context_lens[:, None, None, None] > 0, out, 0)
     return out.reshape(B, H, D)
 
 

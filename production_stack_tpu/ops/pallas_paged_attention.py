@@ -5,26 +5,54 @@ scattered in HBM — the TPU counterpart of vLLM's CUDA PagedAttention
 kernel, which the reference stack consumes via engine images
 (ref helm/templates/deployment-vllm-multi.yaml:108-199).
 
-v3 (round 5). Round-5 profiling (benchmarks/kernel_dma_only.py) showed
-the v2 kernel's double-buffered page DMAs already stream at ~705 GB/s —
-1.16x the HBM floor — while the full kernel ran at 2.3x: the per-chunk
-softmax compute was NOT overlapping the DMA stream (total ~= DMA +
-compute instead of max(DMA, compute)). v3 restructures for overlap and
-for fewer vector-op issues:
+v3 (round 5) gave the kernel its shape: a ring of page chunks copied
+``RING - 1`` chunks ahead of the one being computed, along a walk that
+crosses sequence boundaries (while one row's last chunks compute, the
+next row's first pages are in flight); one scores scratch
+``[KVH * g_pad, span]`` filled by per-head QK dots, so masking, running
+max, exp and the l/acc updates run once over all heads' rows; q scaled
+outside the kernel.
 
-- **Ring buffer, depth R=4** (was 2): page copies are issued ``R-1``
-  chunks ahead along a GLOBAL step index ``g = b * nc + c``, so the
-  prefetch window crosses sequence boundaries — while sequence ``b``'s
-  last chunks compute, sequence ``b+1``'s first pages are already in
-  flight (the v2 kernel paid a cold refill at every ``c == 0``).
-- **Head-batched softmax**: one scores scratch ``[KVH * g_pad, span]``
-  is filled by per-head QK dots, then masking, running max, exp, and
-  the l/acc updates run ONCE over all heads' rows (v2 issued every
-  VPU stage 8x per chunk, once per kv head).
-- q is pre-scaled by ``scale`` outside the kernel (one [B, H, D]
-  multiply) instead of scaling every [g_pad, span] score tile.
+v4 (PR 32): the kernel's work follows the live tokens of the live rows.
+On a v5e at the serving shapes (32 rows, 32 query / 8 KV heads of 128,
+64-token pages; times of one call, one layer) v3 took 238 us for 32 rows
+of context 1 and nothing useful, 131 us for 20 such rows beside 12 live
+ones (411 against 280 us), 81 us more under a 64-page table than under a
+32-page one (352 against 272), and 272 us for 24k live tokens whose bytes
+need 121 us at 819 GB/s. Four things changed:
 
-Structure credit: the grid/BlockSpec shape follows
+- **A row that holds nothing does nothing.** A context of 0 or less
+  starts no copy, waits on none, computes nothing and writes zeros. The
+  model's decode step hands such a context for every row that writes no
+  token (``models/decoder.py::attend``).
+- **The grid is the rows; a row's chunks are a loop** whose trip count is
+  ``cdiv(live pages, P)``. A chunk past the context costs no step and the
+  table's width decides nothing. The walk (which chunk to copy next, how
+  many were started and consumed) lives in SMEM across the grid's rows,
+  so the prefetch window still crosses from one live row into the next.
+- **Only live pages are copied** (:func:`live_pages`; start and wait
+  under one predicate). What the p @ v dot reads past the context is
+  zeroed in VMEM in the row's last chunk: a probability of exactly 0
+  does not silence a NaN.
+- **A head's rows are read by strided loads.** A page keeps its heads on
+  the sublanes, and v3's ``buf[slot, :, :, h, :]`` made Mosaic gather
+  every token's row one by one and convert it: the kernel was bound by
+  that, not by its copies (a 512-token chunk took 4.4 us to compute and
+  2.6 us to copy). Read 32 bits wide with a sublane stride, one load
+  brings two bf16 heads (four int8) and a shift makes each its f32
+  (:func:`_decode_kernel`'s ``head_loads``; after
+  ``jax.experimental.pallas.ops.tpu.ragged_paged_attention``).
+
+With all four the 24k-token call takes 141 us (86% of the HBM roofline),
+16 rows of 3,000 tokens 264 us (416; 91%), 12 live rows beside 20 that
+hold nothing 153 us (411), and 2-, 4- and 8-page chunks read within 1%
+of each other: the copies bound the kernel now, so the tile stays the
+widest that fits (8 pages, ring 6, as v3's; a row's tail computes at most
+512 tokens for nothing, hidden under the copies). PERF.md section 6,
+PR 32, has the table; ``benchmarks/kernel_bs_sweep.py --cells`` times
+these cases.
+
+Structure credit: the scalar-prefetch / manual-DMA shape follows
 ``jax.experimental.pallas.ops.tpu.paged_attention`` (which cannot be
 used directly: it wants per-layer page arrays, and slicing our
 layer-stacked pool [L, NB, bs, KVH, D] per layer would copy the whole
@@ -41,6 +69,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -49,7 +78,9 @@ NEG_INF = -1e30
 # Deepest DMA ring (chunks prefetched ahead of compute) and widest chunk
 # the tile chooser will pick. The round-5 sweep measured depth 6 with
 # 8-page chunks fastest — deep enough to cover DMA issue->complete
-# latency across sequence boundaries.
+# latency across sequence boundaries. The decode kernel, whose copies
+# bound it since v4, reads the same at 2, 4 and 8 pages and at rings of
+# 6 to 12 (PR 32's sweep).
 RING = 6
 MAX_PAGES_PER_BLOCK = 8
 
@@ -118,43 +149,68 @@ def gather_scale_rows(scales: jax.Array, block_tables: jax.Array, layer,
     return rows.transpose(0, 3, 1, 2).reshape(B, kvh, MAXB * block_size)
 
 
-def _chunk_copies(k_hbm, v_hbm, k_buf, v_buf, sems, bt_ref, layer,
-                  b, chunk, slot, pages_per_block):
-    """Async-copy descriptors for one chunk's pages into ring slot `slot`."""
-    copies = []
+def live_pages(context_len, block_size: int):
+    """Pages of a row that hold a live token: what the decode kernel
+    copies for it, and nothing else. Plain arithmetic, so it takes the
+    kernel's traced scalars and the host's numpy arrays alike; a
+    context of 0 or less is a row that holds nothing."""
+    return (context_len + block_size - 1) // block_size * (context_len > 0)
+
+
+def fetch_tokens(context_lens, block_size: int, tables_width: int) -> int:
+    """Token slots one decode call copies out of HBM for these contexts
+    (a host array) under a table ``tables_width`` pages wide: each row's
+    live pages, whole. The chunk's width and the rows that hold nothing
+    do not enter, nor does the table's width while it holds every
+    context: a context past it is cut to it, as the kernel cuts it."""
+    cut = np.minimum(context_lens, tables_width * block_size)
+    return int(live_pages(cut, block_size).sum()) * block_size
+
+
+def _for_chunk_copies(fn, k_hbm, v_hbm, k_buf, v_buf, sems, bt_ref, layer,
+                      b, chunk, slot, pages_per_block, row_pages=None):
+    """``fn`` on the async-copy descriptors of one chunk's pages into ring
+    slot ``slot``; with ``row_pages`` (the row's :func:`live_pages`) only
+    on the pages under it. Start and wait walk the same descriptors under
+    the same predicate, so every started copy is waited exactly once."""
     for p in range(pages_per_block):
-        page = bt_ref[b, chunk * pages_per_block + p]
-        copies.append(pltpu.make_async_copy(
-            k_hbm.at[layer, page], k_buf.at[slot, p], sems.at[slot, 0, p]))
-        copies.append(pltpu.make_async_copy(
-            v_hbm.at[layer, page], v_buf.at[slot, p], sems.at[slot, 1, p]))
-    return copies
+        def one(p=p):
+            page = bt_ref[b, chunk * pages_per_block + p]
+            fn(pltpu.make_async_copy(
+                k_hbm.at[layer, page], k_buf.at[slot, p], sems.at[slot, 0, p]))
+            fn(pltpu.make_async_copy(
+                v_hbm.at[layer, page], v_buf.at[slot, p], sems.at[slot, 1, p]))
+
+        if row_pages is None:
+            one()
+        else:
+            pl.when(chunk * pages_per_block + p < row_pages)(one)
 
 
 def _start_chunk_copy(*args, **kwargs):
-    for c in _chunk_copies(*args, **kwargs):
-        c.start()
+    _for_chunk_copies(lambda c: c.start(), *args, **kwargs)
 
 
 def _wait_chunk_copy(*args, **kwargs):
-    for c in _chunk_copies(*args, **kwargs):
-        c.wait()
+    _for_chunk_copies(lambda c: c.wait(), *args, **kwargs)
 
 
 def _decode_kernel(
     # scalar prefetch
     block_tables_ref,  # [B, MAXB]
-    context_lens_ref,  # [B]
+    context_lens_ref,  # [B]; <= 0: the row holds nothing
     layer_ref,  # [1]
     # inputs
     q_ref,  # [1, KVH * g_pad, D] (VMEM block for sequence b; pre-scaled)
     k_hbm_ref,  # [L, NB, bs, KVH, D] in ANY/HBM (int8 when quantized)
     v_hbm_ref,
-    # quantized only: ks_ref / vs_ref [1, KVH, span] f32 VMEM blocks (this
-    # chunk's per-head scale rows), then output o_ref [1, KVH*g_pad, D],
+    # quantized only: ks_hbm / vs_hbm [B, KVH, S] f32 in ANY/HBM (the
+    # table's per-head scale rows); then output o_ref [1, KVH*g_pad, D];
     # then scratch: k_buf/v_buf VMEM [RING, P, bs, KVH, D], sems DMA
-    # [RING, 2, P], s_ref [KVH*g_pad, span] f32, acc_ref [KVH*g_pad, D]
-    # f32, m_ref/l_ref [KVH*g_pad, 128] f32.
+    # [RING, 2, P], (quantized: ks_buf/vs_buf VMEM [RING, KVH, span] f32,
+    # scale_sems DMA [RING, 2],) s_ref [KVH*g_pad, span] f32, acc_ref
+    # [KVH*g_pad, D] f32, m_ref/l_ref [KVH*g_pad, 128] f32, walk_ref SMEM
+    # [4] int32.
     *refs,
     block_size: int,
     kvh: int,
@@ -164,70 +220,169 @@ def _decode_kernel(
     quantized: bool,
 ):
     if quantized:
-        ks_ref, vs_ref, *refs = refs
-    (o_ref, k_buf, v_buf, sems, s_ref, acc_ref, m_ref, l_ref) = refs
+        ks_hbm_ref, vs_hbm_ref, *refs = refs
+        (o_ref, k_buf, v_buf, sems, ks_buf, vs_buf, scale_sems,
+         s_ref, acc_ref, m_ref, l_ref, walk_ref) = refs
+    else:
+        (o_ref, k_buf, v_buf, sems,
+         s_ref, acc_ref, m_ref, l_ref, walk_ref) = refs
     b = pl.program_id(0)
-    c = pl.program_id(1)
-    nc = pl.num_programs(1)
     nb = pl.num_programs(0)
     layer = layer_ref[0]
-    ctx = context_lens_ref[b]
     P = pages_per_block
     span_tokens = P * block_size
-    chunk_start = c * span_tokens
-    g = b * nc + c  # global step: the prefetch window crosses sequences
-    slot = jax.lax.rem(g, ring)
 
-    @pl.when(g == 0)
+    # The walk over live chunks, in SMEM across the grid's rows:
+    # [0], [1]: (row, chunk) of the next chunk to copy, row == nb when
+    # none is left; [2]: chunks started; [3]: chunks consumed. Chunk g
+    # lands in ring slot g % ring, RING-1 chunks ahead of the one being
+    # computed, whichever live row it belongs to.
+    NEXT_ROW, NEXT_CHUNK, STARTED, CONSUMED = range(4)
+
+    def row_pages(row):
+        return live_pages(context_lens_ref[row], block_size)
+
+    def head_loads(buf, slot):
+        """The chunk in ring slot ``slot`` as one f32 [span, D] per kv
+        head. A page keeps its heads on the sublanes ([bs, KVH, D]), so a
+        head is every KVH-th row of the slot's [span * KVH, D] view: read
+        32 bits wide, one strided load brings the rows of as many heads
+        as a word packs (two bf16, four int8), and a shift turns each
+        into its f32 (a bf16 is the high half of its f32)."""
+        packing = 4 // buf.dtype.itemsize
+        words = buf.at[slot].reshape(
+            span_tokens * kvh, buf.shape[-1]).bitcast(jnp.uint32)
+        heads = []
+        for first in range(0, kvh, packing):
+            w = words[first // packing::kvh // packing, :]
+            for i in range(packing):
+                if packing == 1:
+                    x = pltpu.bitcast(w, jnp.float32)
+                elif packing == 2:
+                    x = pltpu.bitcast(
+                        w << 16 if i == 0 else w & jnp.uint32(0xFFFF0000),
+                        jnp.float32)
+                else:
+                    x = (pltpu.bitcast(w << (24 - 8 * i), jnp.int32)
+                         >> 24).astype(jnp.float32)
+                heads.append(x)
+        return heads
+
+    def first_live_row(row):
+        """The first row at or after ``row`` that holds a token."""
+        def ctx_at(r):
+            return context_lens_ref[jnp.minimum(r, nb - 1)]
+
+        row, _ = jax.lax.while_loop(
+            lambda rc: jnp.logical_and(rc[0] < nb, rc[1] <= 0),
+            lambda rc: (rc[0] + 1, ctx_at(rc[0] + 1)),
+            (row, ctx_at(row)))
+        return row
+
+    def scale_copies(row, chunk, slot):
+        at = pl.multiple_of(chunk * span_tokens, 128)
+        return [
+            pltpu.make_async_copy(
+                hbm.at[row, :, pl.ds(at, span_tokens)], buf.at[slot],
+                scale_sems.at[slot, side])
+            for side, (hbm, buf) in enumerate(
+                ((ks_hbm_ref, ks_buf), (vs_hbm_ref, vs_buf)))]
+
+    def start_next():
+        row = walk_ref[NEXT_ROW]
+
+        @pl.when(row < nb)
+        def _():
+            chunk = walk_ref[NEXT_CHUNK]
+            slot = jax.lax.rem(walk_ref[STARTED], ring)
+            pages = row_pages(row)
+            _start_chunk_copy(
+                k_hbm_ref, v_hbm_ref, k_buf, v_buf, sems, block_tables_ref,
+                layer, row, chunk, slot, P, row_pages=pages)
+            if quantized:
+                for c in scale_copies(row, chunk, slot):
+                    c.start()
+            walk_ref[STARTED] = walk_ref[STARTED] + 1
+            more = (chunk + 1) * P < pages
+
+            @pl.when(more)
+            def _():
+                walk_ref[NEXT_CHUNK] = chunk + 1
+
+            @pl.when(jnp.logical_not(more))
+            def _():
+                walk_ref[NEXT_ROW] = first_live_row(row + 1)
+                walk_ref[NEXT_CHUNK] = 0
+
+    @pl.when(b == 0)
     def _fill():
-        # Cold start: fill the ring for the first live chunks of the
-        # leading sequences (liveness-guarded per chunk; the guard is
-        # the same predicate the consumer uses, so every started copy
-        # is waited exactly once).
-        for k in range(min(ring - 1, nb * nc)):
-            gb, gc = divmod(k, nc)
+        walk_ref[NEXT_ROW] = first_live_row(jnp.int32(0))
+        walk_ref[NEXT_CHUNK] = 0
+        walk_ref[STARTED] = 0
+        walk_ref[CONSUMED] = 0
+        for _ in range(ring - 1):
+            start_next()
 
-            @pl.when(gc * span_tokens < context_lens_ref[gb])
-            def _(gb=gb, gc=gc, k=k):
-                _start_chunk_copy(
-                    k_hbm_ref, v_hbm_ref, k_buf, v_buf, sems,
-                    block_tables_ref, layer, gb, gc, k % ring, P)
+    ctx = context_lens_ref[b]
+    pages = row_pages(b)
 
-    @pl.when(c == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    @pl.when(pages <= 0)
+    def _empty():
+        o_ref[0] = jnp.zeros_like(o_ref[0])
 
-    # Issue the chunk RING-1 global steps ahead (lands in the slot just
-    # consumed, which the serial grid has already finished reading).
-    g_pre = g + ring - 1
-    b_pre = g_pre // nc
-    c_pre = jax.lax.rem(g_pre, nc)
+    def chunk_step(c, carry):
+        start_next()  # into the slot the previous step finished reading
+        slot = jax.lax.rem(walk_ref[CONSUMED], ring)
+        walk_ref[CONSUMED] = walk_ref[CONSUMED] + 1
+        chunk_start = c * span_tokens
+        _wait_chunk_copy(
+            k_hbm_ref, v_hbm_ref, k_buf, v_buf, sems, block_tables_ref,
+            layer, b, c, slot, P, row_pages=pages)
+        if quantized:
+            for cp in scale_copies(b, c, slot):
+                cp.wait()
 
-    @pl.when(jnp.logical_and(
-        b_pre < nb,
-        c_pre * span_tokens < context_lens_ref[jnp.minimum(b_pre, nb - 1)]))
-    def _prefetch():
-        _start_chunk_copy(k_hbm_ref, v_hbm_ref, k_buf, v_buf, sems,
-                          block_tables_ref, layer, b_pre, c_pre,
-                          jax.lax.rem(g_pre, ring), P)
+        # The row's tail. A probability of exactly 0 does not silence
+        # what it multiplies (0 x NaN is NaN in the p @ v dot), so the
+        # part of the chunk past the context is zeroed where that dot
+        # reads it: pages that were not copied hold whatever an earlier
+        # chunk left in the slot, and the last page's own tail holds what
+        # an earlier owner of the page wrote. K needs none of this: its
+        # scores are replaced, not multiplied.
+        @pl.when(chunk_start + span_tokens > ctx)
+        def _tail():
+            if quantized:
+                # int8 values are finite whatever they are; their scales
+                # carry the zero.
+                lane = chunk_start + jax.lax.broadcasted_iota(
+                    jnp.int32, (1, span_tokens), 1)
+                vs_buf[slot] = jnp.where(lane < ctx, vs_buf[slot], 0.0)
+            else:
+                for p in range(P):
+                    page_start = chunk_start + p * block_size
 
-    @pl.when(chunk_start < ctx)
-    def _compute():
-        _wait_chunk_copy(k_hbm_ref, v_hbm_ref, k_buf, v_buf, sems,
-                         block_tables_ref, layer, b, c, slot, P)
+                    @pl.when(page_start >= ctx)
+                    def _(p=p):
+                        v_buf[slot, p] = jnp.zeros_like(v_buf[slot, p])
+
+                    @pl.when(jnp.logical_and(
+                        page_start < ctx, page_start + block_size > ctx))
+                    def _(p=p, page_start=page_start):
+                        tok = page_start + jax.lax.broadcasted_iota(
+                            jnp.int32, v_buf.shape[2:], 0)
+                        v_buf[slot, p] = jnp.where(
+                            tok < ctx, v_buf[slot, p],
+                            jnp.zeros_like(v_buf[slot, p]))
+
         # Per-head QK dots into ONE scores scratch, then every VPU stage
         # (mask, max, exp, l/acc updates) runs once over all heads' rows.
         # Operands are cast to f32 first — measured FASTER than feeding
         # bf16 straight to the MXU at these tiny tile shapes (ring sweep,
         # round 5: bf16 operands cost +66%; Mosaic's repacking of skinny
         # bf16 tiles outweighs the cast traffic).
-        for h in range(kvh):  # static unroll over kv heads
+        for h, k in enumerate(head_loads(k_buf, slot)):  # static unroll
             rows = slice(h * g_pad, (h + 1) * g_pad)
             q = q_ref[0, rows, :].astype(jnp.float32)  # [g_pad, D]
-            k = (k_buf[slot, :, :, h, :]
-                 .reshape(span_tokens, -1).astype(jnp.float32))
             s_h = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
@@ -237,7 +392,7 @@ def _decode_kernel(
                 # token's scale is constant along D, so it factors out
                 # of the dot and multiplies the scores ([1, span] lane
                 # row, broadcast over the head's query rows).
-                s_h = s_h * ks_ref[0, h:h + 1, :]
+                s_h = s_h * ks_buf[slot, h:h + 1, :]
             s_ref[rows, :] = s_h
         span = chunk_start + jax.lax.broadcasted_iota(
             jnp.int32, (1, span_tokens), 1
@@ -255,18 +410,23 @@ def _decode_kernel(
         )
         m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
         acc_ref[...] = acc_ref[...] * alpha  # one batched rescale
-        for h in range(kvh):
+        for h, v in enumerate(head_loads(v_buf, slot)):
             rows = slice(h * g_pad, (h + 1) * g_pad)
-            v = (v_buf[slot, :, :, h, :]
-                 .reshape(span_tokens, -1).astype(jnp.float32))
             p_h = p_[rows, :]
             if quantized:
-                p_h = p_h * vs_ref[0, h:h + 1, :]
+                p_h = p_h * vs_buf[slot, h:h + 1, :]
             acc_ref[rows, :] = acc_ref[rows, :] + jax.lax.dot(
                 p_h, v, preferred_element_type=jnp.float32)
+        return carry
 
-    @pl.when(c == nc - 1)
-    def _finalize():
+    @pl.when(pages > 0)
+    def _row():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        # A trip count that follows the context: a chunk past it costs
+        # no step, whatever the table's width.
+        jax.lax.fori_loop(0, (pages + P - 1) // P, chunk_step, None)
         denom = jnp.maximum(l_ref[:, :1], 1e-30)
         o_ref[0] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
@@ -284,7 +444,7 @@ def decode_tile(block_size: int, kvh: int, head_dim: int, g_pad: int,
         total += 2 * 4 * rows * span  # masked scores and probabilities
         total += 2 * 2 * rows * head_dim * 2  # q and o blocks, 2 buffers
         if quantized:
-            total += 2 * 2 * 4 * max(kvh, 8) * span  # scale blocks
+            total += 2 * ring * 4 * max(kvh, 8) * span  # scale rings
         return total <= VMEM_BUDGET
 
     return choose_tile(fits, tables_width, block_size)
@@ -297,7 +457,7 @@ def pallas_paged_attention(
     k_pages,  # [L, NB, bs, KVH, D] stacked pages (or (data, scales))
     v_pages,  # [L, NB, bs, KVH, D] (or (data, scales))
     block_tables: jax.Array,  # [B, MAXB] int32
-    context_lens: jax.Array,  # [B] int32
+    context_lens: jax.Array,  # [B] int32; <= 0: the row holds nothing
     layer,  # scalar layer index (traced)
     *,
     scale: float,
@@ -305,15 +465,24 @@ def pallas_paged_attention(
     ring: int = 0,  # DMA ring depth; 0 -> from the VMEM budget
     interpret: bool = False,
 ) -> jax.Array:
+    """[B, H, D]; zeros for a row whose context is 0 or less."""
     quantized = isinstance(k_pages, tuple)
     if quantized:
         k_pages, k_scales = k_pages
         v_pages, v_scales = v_pages
     B, H, D = q.shape
     L, NB, bs, KVH, _ = k_pages.shape
+    if KVH % (4 // k_pages.dtype.itemsize):
+        raise ValueError(
+            f"kv_heads={KVH} of {k_pages.dtype} do not fill the 32-bit "
+            "words the kernel reads a page's heads by")
     group = H // KVH
     # Pad each query-head group to the float32 sublane tile (8 rows).
     g_pad = max(group, 8)
+    # A context past the table is cut to it: the kernel's copies and its
+    # trip count follow the context, and the table bounds neither.
+    context_lens = jnp.minimum(
+        context_lens.astype(jnp.int32), block_tables.shape[1] * bs)
     tile = decode_tile(bs, KVH, D, g_pad, k_pages.dtype.itemsize,
                        block_tables.shape[1], quantized)
     if tile is None and not (pages_per_block and ring):
@@ -326,7 +495,6 @@ def pallas_paged_attention(
     # divide the engine's table bucket (its top bucket is clamped at
     # max_blocks_per_seq, which need not be a power of two).
     block_tables = pad_tables(block_tables, P)
-    nc = block_tables.shape[1] // P
     qg = (q * scale).astype(q.dtype).reshape(B, KVH, group, D)
     if g_pad != group:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, g_pad - group), (0, 0)))
@@ -337,48 +505,49 @@ def pallas_paged_attention(
         pages_per_block=P, ring=R, quantized=quantized,
     )
     layer_arr = jnp.asarray(layer, jnp.int32).reshape(1)
+    row_block = pl.BlockSpec(
+        (1, KVH * g_pad, D), lambda b, bt, cl, lr: (b, 0, 0))
     in_specs = [
-        pl.BlockSpec(
-            (1, KVH * g_pad, D), lambda b, c, bt, cl, lr: (b, 0, 0)
-        ),
+        row_block,
         pl.BlockSpec(memory_space=pl.ANY),
         pl.BlockSpec(memory_space=pl.ANY),
     ]
     operands = [qg, k_pages, v_pages]
-    if quantized:
-        # This chunk's scale rows [KVH, span] per side. Past a
-        # sequence's last live chunk the block index stops moving, so
-        # dead grid steps fetch nothing.
-        def scale_block(b, c, bt, cl, lr):
-            last = jnp.maximum(cl[b] - 1, 0) // (P * bs)
-            return (b, 0, jnp.minimum(c, last))
-
-        in_specs += [pl.BlockSpec((1, KVH, P * bs), scale_block)] * 2
-        operands += [
-            gather_scale_rows(k_scales, block_tables, layer, bs, KVH),
-            gather_scale_rows(v_scales, block_tables, layer, bs, KVH)]
     scratch_shapes = [
         pltpu.VMEM((R, P, bs, KVH, D), k_pages.dtype),
         pltpu.VMEM((R, P, bs, KVH, D), v_pages.dtype),
         pltpu.SemaphoreType.DMA((R, 2, P)),
+    ]
+    if quantized:
+        # The table's scale rows stay in HBM; a live chunk's [KVH, span]
+        # per side is copied beside its pages.
+        in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * 2
+        operands += [
+            gather_scale_rows(k_scales, block_tables, layer, bs, KVH),
+            gather_scale_rows(v_scales, block_tables, layer, bs, KVH)]
+        scratch_shapes += [
+            pltpu.VMEM((R, KVH, P * bs), jnp.float32),
+            pltpu.VMEM((R, KVH, P * bs), jnp.float32),
+            pltpu.SemaphoreType.DMA((R, 2)),
+        ]
+    scratch_shapes += [
         pltpu.VMEM((KVH * g_pad, P * bs), jnp.float32),
         pltpu.VMEM((KVH * g_pad, D), jnp.float32),
         pltpu.VMEM((KVH * g_pad, 128), jnp.float32),
         pltpu.VMEM((KVH * g_pad, 128), jnp.float32),
+        pltpu.SMEM((4,), jnp.int32),
     ]
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(B, nc),
+            grid=(B,),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec(
-                (1, KVH * g_pad, D), lambda b, c, bt, cl, lr: (b, 0, 0)
-            ),
+            out_specs=row_block,
             scratch_shapes=scratch_shapes,
         ),
         out_shape=jax.ShapeDtypeStruct((B, KVH * g_pad, D), q.dtype),
         interpret=interpret,
-    )(block_tables, context_lens.astype(jnp.int32), layer_arr, *operands)
+    )(block_tables, context_lens, layer_arr, *operands)
     out = out.reshape(B, KVH, g_pad, D)[:, :, :group, :]
     return out.reshape(B, H, D)

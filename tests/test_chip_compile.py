@@ -103,6 +103,34 @@ def test_decode_kernel_compiles_for_v5e(one_chip, model, quantized, tables):
         spec((B, tables)), spec((B,)), spec(()))
 
 
+@pytest.mark.parametrize("tables", [4, 8, 16, 32, 64])
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+def test_decode_kernel_compiles_at_the_cells_shapes(one_chip, quantized,
+                                                    tables):
+    """The benchmark's serving shapes (Mistral-7B's heads, 32 rows, 64-token
+    pages) at every table bucket the engine compiles for a 4,096-token
+    model: the tile comes from the VMEM estimate (``decode_tile``), whose
+    rings, scale rings and scratch the v5e compiler has to accept, and the
+    32-bit strided view of the page ring has to lay out for bf16 and for
+    int8 pages alike. The chunk is 8 pages, or the table where it is
+    narrower, and the ring 6 deep."""
+    from production_stack_tpu.ops.pallas_paged_attention import decode_tile
+
+    B, H, KVH, D = 32, 32, 8, 128
+
+    def spec(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    assert decode_tile(BLOCK_SIZE, KVH, D, 8, 1 if quantized else 2, tables,
+                       quantized) == (min(tables, 8), 6)
+    pages = _pages(one_chip, KVH, D, quantized)
+    _compile_with_kernel(
+        lambda q, k, v, bt, cl, layer: pallas_paged_attention(
+            q, k, v, bt, cl, layer, scale=D ** -0.5),
+        spec((B, H, D), jnp.bfloat16), pages, pages,
+        spec((B, tables)), spec((B,)), spec(()))
+
+
 # (prefill rows, chunk bucket) the default engine compiles, each with the
 # smallest table bucket that holds the chunk and the largest (8192 tokens).
 @pytest.mark.parametrize("rows,chunk,tables", [
@@ -135,17 +163,18 @@ def test_sharded_decode_attention_on_four_chips(topo, monkeypatch):
     chip) and takes the reference, by a counted trace-time decision,
     where they do not (Llama's 8 -> 2 per chip). Either way attention
     stays local: no collective, and each chip is handed a quarter of the
-    pool, not all of it."""
+    pool, not all of it. The per-shard kernel at the smallest, a middle
+    and the widest table bucket of a 4,096-token model."""
     mesh = Mesh(np.asarray(topo.devices).reshape(1, 1, 4),
                 ("dp", "pp", "tp"))
     monkeypatch.setattr(att, "_use_pallas", lambda: True)
-    D, B, tables = 128, 8, 16
+    D, B = 128, 8
 
     def spec(shape, dtype=jnp.int32, axes=P()):
         return jax.ShapeDtypeStruct(
             shape, dtype, sharding=NamedSharding(mesh, axes))
 
-    def compiled(kvh, heads, fn):
+    def compiled(kvh, heads, fn, tables=16):
         pages = spec((LAYERS, NUM_BLOCKS, BLOCK_SIZE, kvh, D), jnp.bfloat16,
                      P(None, None, None, "tp", None))
         return jax.jit(fn).lower(
@@ -163,9 +192,11 @@ def test_sharded_decode_attention_on_four_chips(topo, monkeypatch):
                                         scale=D ** -0.5))
 
     pool_side = LAYERS * NUM_BLOCKS * BLOCK_SIZE * D * 2
-    for kvh, heads, path in ((32, 64, "pallas"), (8, 24, "xla")):
+    for kvh, heads, path, tables in (
+            (32, 64, "pallas", 4), (32, 64, "pallas", 16),
+            (32, 64, "pallas", 64), (8, 24, "xla", 16)):
         att.TRACED_PATHS.clear()
-        program = compiled(kvh, heads, dispatch)
+        program = compiled(kvh, heads, dispatch, tables)
         text = program.as_text()
         assert dict(att.TRACED_PATHS) == {("decode", path): 1}
         assert ("tpu_custom_call" in text) == (path == "pallas")

@@ -21,6 +21,7 @@ from production_stack_tpu.ops.attention import (
     quantize_kv,
     write_kv_pages,
 )
+from test_pallas_attention import dead_slots, live_tables
 
 
 def make_engine(**over) -> EngineCore:
@@ -224,6 +225,106 @@ def test_pallas_int8_kernel_matches_reference():
             scale=0.1, interpret=True)
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(ref), rtol=2e-3, atol=2e-3)
+
+
+def _int8_rows(ctx, maxb, bs=16, seed=31):
+    """Rows with these contexts over an int8 pool (the tables of
+    ``test_pallas_attention.live_tables``)."""
+    B, H, KVH, D, L = len(ctx), 16, 8, 128, 2
+    rng = np.random.default_rng(seed)
+    tables, NB = live_tables(ctx, maxb, bs, rng)
+    q = jnp.asarray(rng.normal(size=(B, H, D)), jnp.float32)
+    sides = []
+    for _ in range(2):
+        data, scales = quantize_kv(jnp.asarray(
+            rng.normal(size=(L, NB, bs, KVH, D)), jnp.float32))
+        sides.append((data, scales.reshape(L, NB, bs * KVH)))
+    return (q, sides[0], sides[1], jnp.asarray(tables),
+            jnp.asarray(np.asarray(ctx, np.int32)))
+
+
+@pytest.mark.parametrize("empty", [0, -3], ids=["zero", "negative"])
+def test_pallas_int8_rows_that_hold_nothing_give_zeros(empty):
+    """The int8 kernel too: no copy (pages or scale rows) and zeros for a
+    context of 0 or less, the live rows bit for bit what they are alone,
+    at contexts on both sides of a page's and a chunk's edge."""
+    from production_stack_tpu.ops.pallas_paged_attention import (
+        pallas_paged_attention,
+    )
+
+    ctx = [empty, 17, empty, 128, 129, empty, 1, 300, empty]
+    q, k_pages, v_pages, tables, ctx = _int8_rows(ctx, 32)
+    live = np.asarray(ctx) > 0
+    got = pallas_paged_attention(
+        q, k_pages, v_pages, tables, ctx, jnp.int32(1), scale=0.1,
+        interpret=True)
+    ref = paged_attention_reference(
+        q, k_pages, v_pages, tables, ctx, jnp.int32(1), scale=0.1)
+    assert not np.asarray(got)[~live].any()
+    assert not np.asarray(ref)[~live].any()
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(ref), rtol=2e-3, atol=2e-3)
+    alone = pallas_paged_attention(
+        q[live], k_pages, v_pages, tables[live], ctx[live], jnp.int32(1),
+        scale=0.1, interpret=True)
+    np.testing.assert_array_equal(np.asarray(got)[live], np.asarray(alone))
+
+
+@pytest.mark.parametrize("maxb", [3, 8])
+def test_pallas_int8_context_past_the_table_is_cut_to_it(maxb):
+    """A context that claims more tokens than its table has pages for
+    reads the table's tokens (pages and scale rows alike), as the
+    reference does; maxb 3: a table that is no whole chunk."""
+    from production_stack_tpu.ops.pallas_paged_attention import (
+        pallas_paged_attention,
+    )
+
+    full = maxb * 16
+    q, k_pages, v_pages, tables, held = _int8_rows([full, 5, full, 0], maxb)
+    claimed = jnp.asarray([full + 1, 5, full + 1000, 0], jnp.int32)
+    got = pallas_paged_attention(
+        q, k_pages, v_pages, tables, claimed, jnp.int32(1), scale=0.1,
+        interpret=True)
+    ref = paged_attention_reference(
+        q, k_pages, v_pages, tables, claimed, jnp.int32(1), scale=0.1)
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(ref), rtol=2e-3, atol=2e-3)
+    cut = pallas_paged_attention(
+        q, k_pages, v_pages, tables, held, jnp.int32(1), scale=0.1,
+        interpret=True)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(cut))
+
+
+def test_pallas_int8_nothing_past_a_context_reaches_the_result():
+    """Scales of every token slot no live context holds set to NaN (keys)
+    and Inf (values), their int8 values to the extremes: the same finite
+    output, bit for bit. An int8 value is finite whatever it is, so the
+    zero the p @ v dot needs rides on the value scales."""
+    from production_stack_tpu.ops.pallas_paged_attention import (
+        pallas_paged_attention,
+    )
+
+    bs, KVH = 16, 8
+    ctx = [2 * 128 + bs + 3, 5, 0, bs, 129, 1, bs + 1, 2 * bs - 1, 127, -1]
+    q, k_pages, v_pages, tables, ctx = _int8_rows(ctx, 32, seed=37)
+    clean = pallas_paged_attention(
+        q, k_pages, v_pages, tables, ctx, jnp.int32(1), scale=0.1,
+        interpret=True)
+    NB = k_pages[0].shape[1]
+    dead = dead_slots(tables, ctx, NB, bs)
+
+    def poison(pages, value, extreme):
+        data, scales = pages
+        slot = dead[None, :, :, None]
+        return (jnp.where(slot[..., None], jnp.int8(extreme), data),
+                jnp.where(slot, value, scales.reshape(-1, NB, bs, KVH))
+                .reshape(scales.shape))
+
+    got = pallas_paged_attention(
+        q, poison(k_pages, jnp.nan, 127), poison(v_pages, jnp.inf, -127),
+        tables, ctx, jnp.int32(1), scale=0.1, interpret=True)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(clean))
 
 
 def test_flag_off_bf16_path_structurally_unchanged():

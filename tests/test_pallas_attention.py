@@ -137,3 +137,203 @@ def test_kernel_ring_crosses_many_short_sequences():
             scale=0.1, interpret=True)
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(ref), rtol=2e-3, atol=2e-3)
+
+
+# -- work follows the live tokens of the live rows (PR 32) -----------------
+#
+# bs 16, so a chunk is 8 pages: the widest the tile chooser takes, and at
+# this page size the narrowest too (whole 128-lane tiles of tokens).
+BS, CHUNK = 16, 128
+
+
+def live_tables(ctx, maxb, bs, rng):
+    """(tables [B, maxb], NB): each row's live pages distinct and
+    shuffled over a pool of NB, the table zero past them (as the engine
+    leaves it); page 0 belongs to no row."""
+    pages = [-(-max(int(c), 0) // bs) for c in ctx]
+    NB = sum(pages) + 3
+    free = rng.permutation(np.arange(1, NB))
+    tables = np.zeros((len(ctx), maxb), np.int32)
+    at = 0
+    for b, n in enumerate(pages):
+        tables[b, :n] = free[at:at + n]
+        at += n
+    return tables, NB
+
+
+def dead_slots(tables, ctx, NB, bs):
+    """[NB, bs] bool: the token slots no live context holds."""
+    dead = np.ones((NB, bs), bool)
+    for row, c in zip(np.asarray(tables), np.asarray(ctx)):
+        for i in range(max(int(c), 0)):
+            dead[row[i // bs], i % bs] = False
+    return jnp.asarray(dead)
+
+
+def _live_setup(ctx, MAXB, seed=0, H=16, KVH=8, D=128, L=2, dtype=jnp.float32):
+    rng = np.random.default_rng(seed)
+    tables, NB = live_tables(ctx, MAXB, BS, rng)
+    q = jnp.asarray(rng.normal(size=(len(ctx), H, D)), dtype)
+    k_pages = jnp.asarray(rng.normal(size=(L, NB, BS, KVH, D)), dtype)
+    v_pages = jnp.asarray(rng.normal(size=(L, NB, BS, KVH, D)), dtype)
+    return (q, k_pages, v_pages, jnp.asarray(tables),
+            jnp.asarray(np.asarray(ctx, np.int32)))
+
+
+def _edges(MAXB):
+    full = MAXB * BS
+    short = [1, BS - 1, BS, BS + 1, CHUNK - 1, CHUNK, CHUNK + 1]
+    return [min(c, full) for c in short]
+
+
+@pytest.mark.parametrize("empty", [0, -3], ids=["zero", "negative"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_rows_that_hold_nothing_give_zeros(empty, dtype):
+    """A context of 0 or less starts no copy and computes nothing: its
+    output is zeros (the reference's too), and the live rows read bit for
+    bit what they read with no such row among them."""
+    ctx = [empty, 40, empty, empty, CHUNK + 5, 3 * CHUNK, empty, 1, empty]
+    q, k_pages, v_pages, tables, ctx = _live_setup(ctx, 32, dtype=dtype)
+    live = np.asarray(ctx) > 0
+    got = pallas_paged_attention(
+        q, k_pages, v_pages, tables, ctx, jnp.int32(1), scale=0.1,
+        interpret=True)
+    ref = paged_attention_reference(
+        q, k_pages, v_pages, tables, ctx, jnp.int32(1), scale=0.1)
+    assert not np.asarray(got, np.float32)[~live].any()
+    assert not np.asarray(ref, np.float32)[~live].any()
+    tol = 2e-3 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), np.asarray(ref, np.float32),
+        rtol=tol, atol=tol)
+    alone = pallas_paged_attention(
+        q[live], k_pages, v_pages, tables[live], ctx[live], jnp.int32(1),
+        scale=0.1, interpret=True)
+    np.testing.assert_array_equal(
+        np.asarray(got, np.float32)[live], np.asarray(alone, np.float32))
+
+
+@pytest.mark.parametrize("MAXB", [4, 8, 16, 32, 64])
+def test_context_edges_with_one_long_row_among_short_ones(MAXB):
+    """Contexts at every edge of a page and of a chunk, and one row that
+    fills the table, for each table bucket: the table's width decides
+    nothing but the longest context it can hold."""
+    ctx = _edges(MAXB)
+    ctx.insert(3, MAXB * BS)
+    q, k_pages, v_pages, tables, ctx = _live_setup(ctx, MAXB, seed=MAXB)
+    ref = paged_attention_reference(
+        q, k_pages, v_pages, tables, ctx, jnp.int32(1), scale=0.1)
+    got = pallas_paged_attention(
+        q, k_pages, v_pages, tables, ctx, jnp.int32(1), scale=0.1,
+        interpret=True)
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(ref), rtol=2e-3, atol=2e-3)
+    if MAXB < 64:
+        # the same rows under a wider table read the same, bit for bit
+        short = np.asarray(ctx) < MAXB * BS
+        wide = jnp.pad(tables, ((0, 0), (0, 64 - MAXB)))
+        again = pallas_paged_attention(
+            q, k_pages, v_pages, wide, ctx, jnp.int32(1), scale=0.1,
+            interpret=True)
+        np.testing.assert_array_equal(
+            np.asarray(got)[short], np.asarray(again)[short])
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_nothing_past_a_context_reaches_the_result(dtype):
+    """Every page no live context holds and every token slot past a
+    context filled with NaN (keys) and Inf (values): the output is the
+    same, bit for bit, and finite. A probability of exactly 0 does not
+    silence a NaN in the p @ v dot, and a page that was not copied leaves
+    in its ring slot what an earlier chunk brought: the short rows here
+    land in slots the long row's poisoned tail has been through."""
+    ctx = [2 * CHUNK + BS + 3, 5, 0, BS, CHUNK + 1, 1, BS + 1, 2 * BS - 1,
+           CHUNK - 1, -1, 7]
+    q, k_pages, v_pages, tables, ctx = _live_setup(ctx, 32, seed=5,
+                                                   dtype=dtype)
+    clean = pallas_paged_attention(
+        q, k_pages, v_pages, tables, ctx, jnp.int32(1), scale=0.1,
+        interpret=True)
+    dead = dead_slots(tables, ctx, k_pages.shape[1], BS)[None, :, :, None,
+                                                         None]
+    k_bad = jnp.where(dead, jnp.nan, k_pages)
+    v_bad = jnp.where(dead, jnp.inf, v_pages)
+    got = pallas_paged_attention(
+        q, k_bad, v_bad, tables, ctx, jnp.int32(1), scale=0.1,
+        interpret=True)
+    assert np.isfinite(np.asarray(got, np.float32)).all()
+    np.testing.assert_array_equal(
+        np.asarray(got, np.float32), np.asarray(clean, np.float32))
+
+
+def test_heads_that_fill_no_word_are_refused_at_trace_time():
+    """The kernel reads a page's heads 32 bits wide, two bf16 heads a
+    word: a page with an odd count of them never reaches Mosaic (the
+    dispatcher's tile gate sends it to the reference; a direct caller is
+    told why)."""
+    q, k_pages, v_pages, tables, ctx = _live_setup(
+        [40, 5], 4, H=3, KVH=3, dtype=jnp.bfloat16)
+    with pytest.raises(ValueError, match="kv_heads=3"):
+        pallas_paged_attention(
+            q, k_pages, v_pages, tables, ctx, jnp.int32(0), scale=0.1,
+            interpret=True)
+
+
+@pytest.mark.parametrize("MAXB", [3, 8, 20])
+def test_a_context_past_the_table_is_cut_to_it(MAXB):
+    """The copies and the trip count follow the context, so the kernel
+    bounds the context by the table itself: a row whose context claims
+    more tokens than its table has pages for reads the table's tokens,
+    as the reference does (its mask ends where the table ends), and
+    never a table entry past the width. MAXB 3 and 20: a table that is
+    no whole number of chunks."""
+    full = MAXB * BS
+    held = [full, 5, full, 0, full - 1]
+    claimed = np.asarray([full + 1, 5, full + 9 * CHUNK, 0, full - 1],
+                         np.int32)
+    q, k_pages, v_pages, tables, held = _live_setup(held, MAXB, seed=MAXB)
+    got = pallas_paged_attention(
+        q, k_pages, v_pages, tables, jnp.asarray(claimed), jnp.int32(1),
+        scale=0.1, interpret=True)
+    ref = paged_attention_reference(
+        q, k_pages, v_pages, tables, jnp.asarray(claimed), jnp.int32(1),
+        scale=0.1)
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(ref), rtol=2e-3, atol=2e-3)
+    cut = pallas_paged_attention(
+        q, k_pages, v_pages, tables, held, jnp.int32(1), scale=0.1,
+        interpret=True)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(cut))
+
+
+@pytest.mark.parametrize("block_size", [16, 64])
+def test_fetch_tokens_follows_the_live_pages(block_size):
+    """The count the step records carry: each row's live pages, whole.
+    At most one page a row over the live tokens (so under live + rows x
+    chunk for any chunk), nothing for a row that holds nothing, the same
+    under any table that holds the contexts, and cut to the table like
+    the kernel's own contexts under one that does not."""
+    from production_stack_tpu.ops.pallas_paged_attention import (
+        fetch_tokens,
+        live_pages,
+    )
+
+    rng = np.random.default_rng(block_size)
+    ctx = rng.integers(1, 4096, size=32)
+    width = 4096 // block_size
+    fetched = fetch_tokens(ctx, block_size, width)
+    assert ctx.sum() <= fetched < ctx.sum() + 32 * block_size
+    assert fetched % block_size == 0
+    assert fetch_tokens(ctx, block_size, 4 * width) == fetched
+    assert fetch_tokens(np.array([0, -1, -64, -4096]), block_size, 64) == 0
+    assert fetch_tokens(np.array([0, 1, -5]), block_size, 64) == block_size
+    assert fetch_tokens(np.array([9000, 1, 0]), block_size, 8) == (
+        9 * block_size)
+    edges = np.array([1, block_size - 1, block_size, block_size + 1])
+    assert list(live_pages(edges, block_size)) == [1, 1, 1, 2]
+    # per scan step of a burst ([B, K]) as the engine counts it
+    burst = ctx[:, None] + np.arange(8)
+    assert fetch_tokens(burst, block_size, width + 1) == sum(
+        fetch_tokens(burst[:, s], block_size, width + 1) for s in range(8))
