@@ -488,10 +488,14 @@ def run(plan: Plan, chips: int) -> dict:
     """Run the plan on the attached device; returns the result object."""
     import jax
 
+    from production_stack_tpu.ops.attention import TRACED_PATHS
     from production_stack_tpu.utils.compile_cache import (
         configure_compile_cache,
     )
 
+    # The counter is the process's: ``engine_counters`` judges this run's
+    # programs, not what something else in the process traced before it.
+    TRACED_PATHS.clear()
     check(not os.environ.get("TPU_STACK_FORCE_XLA_ATTENTION"),
           "TPU_STACK_FORCE_XLA_ATTENTION is set: the kernels would be "
           "bypassed")

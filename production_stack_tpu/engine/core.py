@@ -11,6 +11,7 @@ launches in each pod (``helm/templates/deployment-vllm-multi.yaml:108-199``).
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import functools
 import gc
@@ -208,6 +209,26 @@ def _unwrap_fused(x):
     return x
 
 
+class _StatsTap:
+    """A step program of a family that counts (``Family.stats``), called
+    like the program of one that does not: its last output, the counts
+    summed over its ``forwards`` forwards (a device array), goes to
+    ``sink`` and the rest is returned. Everything else about the jitted
+    program (``__name__``, ``lower``, ``_cache_size``) is the program's."""
+
+    def __init__(self, program, sink, forwards: int, prefix: str):
+        self._program, self._sink = program, sink
+        self._forwards, self._prefix = forwards, prefix
+
+    def __call__(self, *args):
+        *out, stats = self._program(*args)
+        self._sink((self._prefix, self._forwards, stats))
+        return tuple(out)
+
+    def __getattr__(self, name):
+        return getattr(self._program, name)
+
+
 class EngineCore:
     def __init__(
         self,
@@ -268,6 +289,14 @@ class EngineCore:
 
         self._init_fn, self._apply = build_model(self.model_config)
         family = get_family(self.model_config.arch)
+        # What the family's forward counts (models/registry.py::
+        # Family.stats; the expert layer's assignment counts): the step
+        # programs return the sums beside their tokens, and the step that
+        # finds them ready carries them in its record.
+        self._stat_names = family.stats
+        self._stats_pending: collections.deque = collections.deque(
+            maxlen=256)
+        self.family_stats_total = {name: 0 for name in family.stats}
         if pp > 1:
             # Stage-sharded serving: swap the layer stack for the GPipe
             # pipeline over the pp mesh axis. Same signature, so prefill /
@@ -791,6 +820,42 @@ class EngineCore:
                 self.pool_shrink_retries_total += 1
                 gc.collect()  # drop the failed allocation's host refs
 
+    def _stats_outputs(self):
+        """(keyword for ``apply``, the out_shardings of what it adds to a
+        step program's outputs): both empty for a family that counts
+        nothing, whose programs are then what they always were."""
+        if not self._stat_names:
+            return {}, ()
+        return {"with_stats": True}, (self._repl,)
+
+    def _tap_stats(self, program, forwards: int, prefix: str = ""):
+        if not self._stat_names:
+            return program
+        return _StatsTap(program, self._stats_pending.append, forwards,
+                         prefix)
+
+    def _note_family_stats(self) -> None:
+        """The counts of the step programs that have finished since the
+        last step go into this step's record and into the lifetime
+        totals: a decode burst's under the family's names beside
+        ``stats_forwards`` (how many forwards they cover), a prefill
+        program's under the same names behind ``prefill_``. A program
+        still running keeps its counts for a later step: nothing here
+        waits for the device, so a record carries the counts of the
+        programs read back in its step, not of the one it dispatched."""
+        noted: Dict[str, int] = {}
+        while self._stats_pending and self._stats_pending[0][2].is_ready():
+            prefix, forwards, stats = self._stats_pending.popleft()
+            counted = dict(zip(self._stat_names,
+                               (int(v) for v in np.asarray(stats))))
+            for name, value in counted.items():
+                self.family_stats_total[name] += value
+            counted["stats_forwards"] = forwards
+            for name, value in counted.items():
+                noted[prefix + name] = noted.get(prefix + name, 0) + value
+        if noted:
+            self._steps.note(**noted)
+
     def _make_forward(self, mode: str):
         """Prefill program: forward + on-device sampling of the last real
         token's logits fused into ONE dispatch (the token is the only value
@@ -800,6 +865,7 @@ class EngineCore:
         cfg = self.model_config
         max_top_k = self.config.max_top_k
         seed_static = self.config.seed
+        with_stats, stats_sharding = self._stats_outputs()
 
         _eos = getattr(self.tokenizer, "eos_token_id", None)
         eos_id = int(_eos) if _eos is not None else -1  # 0 is a valid id
@@ -816,10 +882,11 @@ class EngineCore:
             # pure waste).
             last_idx = (None if mode == "decode"
                         else jnp.maximum(seq_lens - 1, 0))
-            logits, kv = apply(
+            logits, kv, *stats = apply(
                 params, cfg, token_ids, positions, kv, slot_mapping,
                 block_tables, context_lens, seq_lens,
                 mode=mode, adapter_ids=adapter_ids, last_token=last_idx,
+                **with_stats,
             )
             with jax.named_scope("sample"):
                 last = logits[:, 0]
@@ -849,7 +916,7 @@ class EngineCore:
                 # (logit_bias + min_tokens masking applied), matching
                 # OpenAI/vLLM post-processor logprob semantics.
                 lp, top_lp, top_ids = logprob_outputs(shaped, sampled)
-                return (sampled, lp, top_lp, top_ids), kv
+                return ((sampled, lp, top_lp, top_ids), kv, *stats)
 
         # The program's name in a profiler trace (the XLA Modules line)
         # and in the step records: ``prefill`` or ``prefill_cached``.
@@ -857,9 +924,10 @@ class EngineCore:
         # Sampled tokens / logprobs are read back on the host: pin them
         # fully replicated so device_get works from any process of a
         # multi-host mesh (and is a no-copy local read).
-        return jax.jit(
+        return self._tap_stats(jax.jit(
             fwd, donate_argnums=(1,),
-            out_shardings=((self._repl,) * 4, self._kv_pair_sharding))
+            out_shardings=((self._repl,) * 4, self._kv_pair_sharding)
+            + stats_sharding), 1, "prefill_")
 
     def _make_multi_decode(self, K: int):
         """Fused K-step decode: forward + on-device sampling (keys derived
@@ -875,6 +943,7 @@ class EngineCore:
         max_top_k = self.config.max_top_k
         seed = self.config.seed
         K_max = max(self.config.decode_steps, 1)
+        with_stats, stats_sharding = self._stats_outputs()
 
         _eos = getattr(self.tokenizer, "eos_token_id", None)
         eos_id = int(_eos) if _eos is not None else -1  # 0 is a valid id
@@ -908,11 +977,11 @@ class EngineCore:
 
             def body(carry, step_slots):
                 tokens, kv, counts, s = carry
-                logits, kv = apply(
+                logits, kv, *stats = apply(
                     params, cfg, tokens[:, None], (positions0 + s)[:, None],
                     kv, step_slots[:, None], block_tables, context0 + s,
                     jnp.ones_like(context0), mode="decode",
-                    adapter_ids=adapter_ids,
+                    adapter_ids=adapter_ids, **with_stats,
                 )
                 with jax.named_scope("sample"):
                     raw = logits[:, 0]
@@ -957,10 +1026,10 @@ class EngineCore:
                     live = (step_slots >= 0).astype(jnp.int32)
                     counts = counts.at[jnp.arange(B), sampled].add(live)
                 return ((sampled, kv, counts, s + 1),
-                        (sampled, lp, top_lp, top_ids))
+                        (sampled, lp, top_lp, top_ids, *stats))
 
             ((_, kv, counts, _),
-             (out, lps, top_lps, top_idxs)) = jax.lax.scan(
+             (out, lps, top_lps, top_idxs, *stats)) = jax.lax.scan(
                 body, (tokens0, kv, counts, jnp.int32(0)), slot_mat.T,
                 length=K,
             )
@@ -974,15 +1043,17 @@ class EngineCore:
                     out_fb = jnp.concatenate(
                         [out, jnp.zeros((K_max - K,) + out.shape[1:],
                                         out.dtype)], axis=0)
-                # [K, B, ...] -> [B, K, ...]
-                return (out_fb.T, lps.T, top_lps.swapaxes(0, 1),
-                        top_idxs.swapaxes(0, 1)), kv, counts
+                # [K, B, ...] -> [B, K, ...]; the family's counts summed
+                # over the burst's K forwards
+                return ((out_fb.T, lps.T, top_lps.swapaxes(0, 1),
+                         top_idxs.swapaxes(0, 1)), kv, counts,
+                        *(st.sum(axis=0) for st in stats))
 
         fwd.__name__ = f"decode_k{K}"
-        return jax.jit(
+        return self._tap_stats(jax.jit(
             fwd, donate_argnums=(1, 2),
             out_shardings=((self._repl,) * 4, self._kv_pair_sharding,
-                           self._repl))
+                           self._repl) + stats_sharding), K)
 
     def _multi_decode_fn(self, K: int):
         fn = self._multi_decode_fns.get(K)
@@ -1967,6 +2038,7 @@ class EngineCore:
             "prefill": n_prefill, "decode": n_decode, "spec": n_spec,
             "draft": n_draft,
         }
+        self._stats_pending.clear()  # the warm-up's forwards count nothing
         self.warmup_seconds = time.time() - t0
         logger.info("Warmup compiled %d prefill + %d decode + %d spec-verify "
                     "+ %d draft variants in %.1f s", n_prefill, n_decode,
@@ -2337,6 +2409,7 @@ class EngineCore:
             "cached_tokens_total": self.cached_tokens_total,
             "prefill_padded_tokens_total": self.prefill_padded_tokens_total,
             "kv_fetch_tokens_total": self.kv_fetch_tokens_total,
+            "family_stats_total": dict(self.family_stats_total),
             "generation_tokens_total": self.generation_tokens_total,
             "offload": self.offload.stats() if self.offload else None,
             # Page residency split: HBM pages currently allocated vs
@@ -2527,6 +2600,8 @@ class EngineCore:
         self._step_info = None
         if info is None:
             return
+        if self._stat_names:
+            self._note_family_stats()
         if rec.param_bytes == 0 and self.params is not None:
             # Weight bytes for the roofline: resolved lazily because the
             # checkpoint may replace the init tree after construction.
@@ -3537,6 +3612,16 @@ class EngineCore:
             self.kv_fetch_tokens_total += fetched
             self._steps.note(
                 kv_fetch_tokens=fetched, kv_live_tokens=int(live.sum()))
+            window = self.model_config.sliding_window
+            if window:
+                # The same two counts for a call of a sliding layer,
+                # whose first live token is ``context - window``: the
+                # counts above are a full layer's.
+                self._steps.note(
+                    kv_fetch_tokens_window=fetch_tokens(
+                        live, cfg.block_size, maxb, window),
+                    kv_live_tokens_window=int(
+                        np.minimum(live, window).sum()))
         outs = self._dispatch(
             "decode", {"K": K, "use_prev": prev is not None}, [
                 reset_counts, tok_idx, host_tokens, use_host, positions0,

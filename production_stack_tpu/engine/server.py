@@ -2479,6 +2479,14 @@ class EngineServer:
             "# TYPE tpu:kv_fetch_tokens counter",
             f"tpu:kv_fetch_tokens_total{{{labels}}} "
             f"{s['kv_fetch_tokens_total']}",
+            # The expert layer's counts (models/moe.py::STATS), 0 for a
+            # model without one.
+            "# TYPE tpu:moe_assignments counter",
+            f"tpu:moe_assignments_total{{{labels}}} "
+            f"{s['family_stats_total'].get('moe_assignments', 0)}",
+            "# TYPE tpu:moe_experts_hit counter",
+            f"tpu:moe_experts_hit_total{{{labels}}} "
+            f"{s['family_stats_total'].get('moe_experts_hit', 0)}",
             # Disaggregated-prefill KV handoff (the NIXL-pipe equivalent).
             "# TYPE tpu:kv_transfer_tx_bytes counter",
             f"tpu:kv_transfer_tx_bytes_total{{{labels}}} {self.kv_transfer_tx_bytes}",

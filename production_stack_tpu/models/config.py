@@ -13,9 +13,28 @@ from __future__ import annotations
 import dataclasses
 import json
 import os
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax.numpy as jnp
+
+FULL_ATTENTION = "full_attention"
+SLIDING_ATTENTION = "sliding_attention"
+
+
+@dataclasses.dataclass(frozen=True)
+class RopeParams:
+    """One rotary block of a config's ``rope_parameters``: ``default`` or
+    ``yarn`` (Hugging Face's ``_compute_yarn_parameters``), over the first
+    ``partial_rotary_factor`` of each head's dims."""
+
+    rope_type: str = "default"
+    rope_theta: float = 10000.0
+    partial_rotary_factor: float = 1.0
+    factor: float = 1.0
+    original_max_position_embeddings: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: Optional[float] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -35,9 +54,24 @@ class ModelConfig:
     tie_word_embeddings: bool = False
     # OPT-specific
     do_layer_norm_before: bool = True
-    # MoE (mixtral)
+    # MoE (mixtral, laguna): ``num_experts`` is the count held HERE.
     num_experts: int = 0
     experts_per_token: int = 2
+    # Layers of several kinds (laguna). The per-layer tuples are as long
+    # as ``num_layers``; empty = every layer full attention, ``num_heads``.
+    layer_types: Tuple[str, ...] = ()
+    heads_per_layer: Tuple[int, ...] = ()
+    sliding_window: int = 0  # of the ``sliding_attention`` layers
+    rope_by_kind: Tuple[Tuple[str, RopeParams], ...] = ()
+    moe_intermediate_size: int = 0  # a routed expert's width
+    shared_expert_size: int = 0  # 0 = no shared expert
+    routed_scaling: float = 1.0
+    dense_layers: int = 0  # leading layers whose MLP is dense
+    # The deployment: ``chips_per_layer`` chips share each layer, each
+    # holds ``num_experts`` of the ``num_experts * chips_per_layer`` the
+    # router scores, and this one holds block ``layer_share``.
+    chips_per_layer: int = 1
+    layer_share: int = 0
     dtype: str = "bfloat16"
 
     @property
@@ -47,6 +81,29 @@ class ModelConfig:
     @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def published_experts(self) -> int:
+        """The router's width: the experts of the whole layer."""
+        return self.num_experts * self.chips_per_layer
+
+    def layer_kind(self, layer: int) -> str:
+        return self.layer_types[layer] if self.layer_types else FULL_ATTENTION
+
+    def layer_heads(self, layer: int) -> int:
+        return (self.heads_per_layer[layer] if self.heads_per_layer
+                else self.num_heads)
+
+    def window_of(self, kind: str) -> Optional[int]:
+        """The static window bound ``decoder.attend`` takes for a layer
+        of this kind: None where it sees its whole context."""
+        return (self.sliding_window
+                if kind == SLIDING_ATTENTION and self.sliding_window > 0
+                else None)
+
+    def rope_of(self, kind: str) -> RopeParams:
+        return dict(self.rope_by_kind).get(
+            kind, RopeParams(rope_theta=self.rope_theta))
 
     def replace(self, **kwargs) -> "ModelConfig":
         return dataclasses.replace(self, **kwargs)
@@ -64,6 +121,27 @@ _PRESETS = {
         num_layers=2, num_heads=4, num_kv_heads=2, head_dim=32,
         intermediate_size=256, max_position=2048, rope_theta=10000.0,
         num_experts=4, experts_per_token=2,
+    ),
+    # Both layer kinds with their own head counts and rotary blocks, a
+    # window shorter than the tests' contexts, a dense first layer, a
+    # shared expert, the second of two shares of 8 experts.
+    "tiny-laguna": ModelConfig(
+        name="tiny-laguna", arch="laguna", vocab_size=512, hidden_size=128,
+        num_layers=6, num_heads=4, num_kv_heads=2, head_dim=32,
+        intermediate_size=256, max_position=2048, rms_norm_eps=1e-6,
+        num_experts=4, experts_per_token=3, moe_intermediate_size=64,
+        shared_expert_size=64, routed_scaling=2.5, dense_layers=1,
+        chips_per_layer=2, layer_share=1, sliding_window=24,
+        layer_types=(FULL_ATTENTION, SLIDING_ATTENTION,
+                     SLIDING_ATTENTION) * 2,
+        heads_per_layer=(4, 6, 6) * 2,
+        rope_by_kind=(
+            (FULL_ATTENTION, RopeParams(
+                rope_type="yarn", rope_theta=500000.0,
+                partial_rotary_factor=0.5, factor=8.0,
+                original_max_position_embeddings=256,
+                attention_factor=1.2079441541679836)),
+            (SLIDING_ATTENTION, RopeParams(rope_theta=10000.0))),
     ),
     "tiny-opt": ModelConfig(
         name="tiny-opt", arch="opt", vocab_size=512, hidden_size=128,
@@ -139,12 +217,14 @@ def _from_hf_config_json(path: str, name: str) -> ModelConfig:
     arch = arch_of_model_type(cfg.get("model_type", "llama"))
     heads = cfg.get("num_attention_heads", 32)
     hidden = cfg.get("hidden_size", 4096)
+    layers = cfg.get("num_hidden_layers", cfg.get("num_layers", 32))
     return ModelConfig(
+        **_layer_kind_keys(cfg, arch, layers),
         name=name,
         arch=arch,
         vocab_size=cfg.get("vocab_size", 32000),
         hidden_size=hidden,
-        num_layers=cfg.get("num_hidden_layers", cfg.get("num_layers", 32)),
+        num_layers=layers,
         num_heads=heads,
         num_kv_heads=cfg.get("num_key_value_heads", heads),
         # some configs carry an explicit null head_dim
@@ -155,8 +235,62 @@ def _from_hf_config_json(path: str, name: str) -> ModelConfig:
         rms_norm_eps=cfg.get("rms_norm_eps", 1e-5),
         tie_word_embeddings=cfg.get("tie_word_embeddings", False),
         do_layer_norm_before=cfg.get("do_layer_norm_before", True),
-        num_experts=cfg.get("num_local_experts", 0),
+        num_experts=cfg.get("num_local_experts", cfg.get("num_experts", 0)),
         experts_per_token=cfg.get("num_experts_per_tok", 2),
+    )
+
+
+def _rope_params(block: dict) -> RopeParams:
+    kinds = {f.name: f.type for f in dataclasses.fields(RopeParams)}
+    return RopeParams(**{
+        k: float(v) if kinds[k] in ("float", "Optional[float]") else v
+        for k, v in block.items() if k in kinds})
+
+
+def _layer_kind_keys(cfg: dict, arch: str, layers: int) -> dict:
+    """The ``ModelConfig`` fields of a family whose layers are of several
+    kinds, from the keys its config publishes. Per-layer lists are read
+    for their first ``num_hidden_layers`` entries (a cut in layers keeps
+    the lists at their published length). A family that needs them
+    (``Family.per_layer_keys``) refuses a file without them; any other
+    family reads none of them."""
+    from production_stack_tpu.models.registry import get_family
+
+    wanted = get_family(arch).per_layer_keys
+    if not wanted:
+        return {}
+    for key in wanted:
+        held = cfg.get(key)
+        if not isinstance(held, list) or len(held) < layers:
+            raise ValueError(
+                f"a {cfg.get('model_type')!r} config.json needs the "
+                f"per-layer list {key!r} with at least num_hidden_layers "
+                f"= {layers} entries")
+    mlp_kinds = cfg["mlp_layer_types"][:layers]
+    dense = next((i for i, kind in enumerate(mlp_kinds) if kind != "dense"),
+                 layers)
+    if "dense" in mlp_kinds[dense:] or sorted(
+            cfg.get("mlp_only_layers", range(dense))) != list(range(dense)):
+        raise ValueError("dense MLP layers are served as a leading run "
+                         f"only; got {mlp_kinds}")
+    if cfg.get("moe_router_logit_softcapping") or cfg.get(
+            "moe_apply_router_weight_on_input"):
+        raise ValueError("router soft-capping and router weights on the "
+                         "expert's input are not implemented")
+    return dict(
+        layer_types=tuple(cfg["layer_types"][:layers]),
+        heads_per_layer=tuple(cfg["num_attention_heads_per_layer"][:layers]),
+        sliding_window=cfg.get("sliding_window") or 0,
+        rope_by_kind=tuple(
+            (kind, _rope_params(block))
+            for kind, block in sorted(cfg.get("rope_parameters", {}).items())
+            if isinstance(block, dict)),
+        moe_intermediate_size=cfg.get("moe_intermediate_size", 0),
+        shared_expert_size=cfg.get("shared_expert_intermediate_size", 0),
+        routed_scaling=float(cfg.get("moe_routed_scaling_factor", 1.0)),
+        dense_layers=dense,
+        chips_per_layer=cfg.get("chips_per_layer", 1),
+        layer_share=cfg.get("layer_share", 0),
     )
 
 

@@ -13,7 +13,8 @@ A family (models/registry.py::Family) brings ``embed``, ``layer`` and
 - :func:`scan_layers`: one ``lax.scan`` over the layer-stacked leaves
   (single-layer trace, fast compiles even at 80 layers) with the carry
   convention of the paged pool;
-- :func:`apply`: embed -> layers -> the ``last_token`` slice -> head.
+- :func:`apply`: embed -> layers (that scan, or the loop of a family
+  whose layers are of several kinds) -> the ``last_token`` slice -> head.
   ``models.<family>.apply`` is this function bound to the family, and
   ``parallel/pp_serving.py`` runs the same three parts as pipeline stages.
 """
@@ -58,17 +59,26 @@ def attend(
     batch: Batch,
     *,
     scale: float,
+    window: int | None = None,  # static, per layer kind
 ):
     """Write this layer's fresh k/v into its pages, then attend. Returns
-    (attention output [B, T, H, D], updated pages)."""
+    (attention output [B, T, H, D], updated pages). With ``window`` a
+    query at position p sees the keys at ``p - window < j <= p`` in all
+    three modes: a band mask in plain prefill, a lower bound in the
+    cached-prefill kernel, and in the decode kernel a first live token
+    ``context - window`` before which it copies and computes nothing.
+    The pages stay per token (the window saves a layer's bandwidth and
+    compute, not its memory)."""
     k_pages, v_pages = write_kv_pages(
         *kv, k, v, batch.slot_mapping, layer)
+    bound = {} if window is None else {"window": window}
     # The named scopes are what a profiler trace files the device's time
     # under (docs/profiling.md): metadata only, no operation changes.
     with jax.named_scope("attention"):
         if mode == "prefill":
             attn = prefill_attention(
-                q, k, v, scale=scale, seq_lens=batch.seq_lens)
+                q, k, v, scale=scale, seq_lens=batch.seq_lens,
+                **bound)
         elif mode == "prefill_cached":
             # Suffix prefill after a prefix-cache hit: attend over HBM
             # pages (cached prefix + just-written suffix). The chunk's own
@@ -77,7 +87,7 @@ def attend(
             attn = context_prefill_attention(
                 q, k_pages, v_pages, batch.block_tables, batch.positions,
                 batch.context_lens, layer, scale=scale, k_new=k, v_new=v,
-                suffix_lens=batch.seq_lens,
+                suffix_lens=batch.seq_lens, **bound,
             )
         else:
             # A row that writes no token this step (slot -1: a row no
@@ -88,7 +98,7 @@ def attend(
                 batch.slot_mapping[:, 0] >= 0, batch.context_lens, 0)
             attn = paged_decode_attention(
                 q[:, 0], k_pages, v_pages, batch.block_tables, live,
-                layer, scale=scale,
+                layer, scale=scale, **bound,
             )[:, None]
     return attn, (k_pages, v_pages)
 
@@ -145,20 +155,36 @@ def apply(
     adapter_ids: jax.Array | None = None,  # [B] LoRA slot per sequence
     output_hidden: bool = False,  # return final hidden states, not logits
     last_token: jax.Array | None = None,  # [B] position whose logits to keep
+    with_stats: bool = False,  # also what the family's loop counts
 ):
     """Full forward. Returns (logits [B, T, V], updated kv_pages), or the
     post-norm hidden states [B, T, Hd] instead of logits when
     ``output_hidden`` (the /v1/embeddings pass); with ``last_token``, of
-    that one position (:func:`take_last_token`)."""
+    that one position (:func:`take_last_token`); with ``with_stats`` a
+    third value, the counts of a family that brings its own layer loop
+    (``Family.loop`` / ``Family.stats``)."""
     x, lora_layers, lora_scaling, adapter_ids = family.embed(
         params, cfg, token_ids, positions, adapter_ids)
     batch = Batch(positions, slot_mapping, block_tables, context_lens,
                   seq_lens, adapter_ids, lora_scaling)
+    if family.loop is not None:
+        x, kv_pages, stats = family.loop(cfg, mode, x, params, kv_pages,
+                                         batch)
+    else:
+        # Leaves the layer takes as whole stacks (``Family.whole_leaves``)
+        # stay out of the scan's slices and reach every layer as they are.
+        layers = params["layers"]
+        whole = {k: layers[k] for k in family.whole_leaves}
+        sliced = {k: v for k, v in layers.items() if k not in whole}
 
-    def layer_fn(x, per_layer, kv, l):
-        return family.layer(cfg, mode, x, per_layer, kv, l, batch)
+        def layer_fn(x, per_layer, kv, l):
+            p, lora = per_layer
+            return family.layer(cfg, mode, x, ({**p, **whole}, lora), kv, l,
+                                batch)
 
-    x, kv_pages = scan_layers(
-        layer_fn, x, kv_pages, (params["layers"], lora_layers))
+        x, kv_pages = scan_layers(layer_fn, x, kv_pages,
+                                  (sliced, lora_layers))
+        stats = None
     x = take_last_token(x, last_token)
-    return family.head(params, cfg, x, output_hidden), kv_pages
+    out = family.head(params, cfg, x, output_hidden)
+    return (out, kv_pages, stats) if with_stats else (out, kv_pages)

@@ -1,0 +1,348 @@
+"""Laguna-style decoder: window and full attention layers with their own
+head counts and rotary blocks, a per-head output gate, a leading dense
+layer, then sparse layers of routed experts beside a shared one
+(poolside/Laguna-S-2.1's ``config.json``; the three functions the config
+names without spelling them are listed in ``assumed`` of
+``chipbench/configs/laguna-s-2.1-l8e64.json`` and marked below).
+
+What is the skeleton's stays the skeleton's: ``decoder.attend`` (page
+write + the three attention modes, with this family's static ``window``
+for the sliding layers), ``llama.rms_norm``, the fused ``wqkv`` leaf with
+its ``_split_qkv`` barrier, embedding and head. What this module brings:
+
+- **Leaves stacked per layer kind.** Full layers have 48 query heads and
+  sliding ones 72, so ``wqkv`` / ``wg`` / ``wo`` are ``[n_full, ...]`` and
+  ``[n_sliding, ...]`` under ``attn/<kind>``; the dense MLP's under
+  ``dense`` ``[dense_layers, ...]``; router, routed and shared experts
+  under ``moe`` ``[sparse layers, ...]``. No kind is padded to another's
+  width.
+- **One program body per kind of layer, whatever the depth.** The layer
+  loop is ONE ``lax.scan`` over the layers. Its body holds each kind of
+  attention and each kind of MLP once, and a layer picks its own by its
+  number (``lax.cond``), reading its leaves at its own index of its
+  kind's stack (a dynamic slice, read in place). A program so holds one
+  full and one sliding attention, the dense MLP and one expert layer
+  however many layers there are: compile time and the executable's size
+  (which the persistent compile cache has to hold beside the other
+  configurations' programs, PERF.md section 6) do not grow with depth.
+- **The expert layer** is models/moe.py's: this chip holds
+  ``cfg.num_experts`` of the ``cfg.published_experts`` the router scores,
+  block ``cfg.layer_share``. The shared expert and the dense layer are
+  whole on every chip.
+
+No LoRA slots, no pipeline stages, no int8 weights, no tensor-parallel
+rules yet (every leaf is replicated over a mesh): the record at the foot
+of the file says so.
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+from typing import Dict, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from production_stack_tpu.models import decoder, llama, moe
+from jax.sharding import PartitionSpec as P
+
+from production_stack_tpu.models.config import (
+    FULL_ATTENTION,
+    SLIDING_ATTENTION,
+    ModelConfig,
+    RopeParams,
+)
+from production_stack_tpu.models.registry import Family
+
+
+# --------------------------------------------------------------------- #
+# Which layers there are
+# --------------------------------------------------------------------- #
+
+def kind_heads(cfg: ModelConfig) -> Dict[str, Tuple[int, int]]:
+    """kind -> (layers of it, its query heads); one head count a kind."""
+    out: Dict[str, Tuple[int, int]] = {}
+    for l in range(cfg.num_layers):
+        kind, heads = cfg.layer_kind(l), cfg.layer_heads(l)
+        n, seen = out.get(kind, (0, heads))
+        if seen != heads:
+            raise ValueError(
+                f"{kind} layers with {seen} and {heads} query heads: the "
+                "leaves are stacked per kind, one head count each")
+        out[kind] = (n + 1, heads)
+    return out
+
+
+# --------------------------------------------------------------------- #
+# Parameters
+# --------------------------------------------------------------------- #
+
+def init_params(cfg: ModelConfig, rng: jax.Array, **_unused) -> Dict:
+    """Random tree: normal / sqrt(fan_in) in float32, rounded to the
+    served dtype (``chipbench/reference/laguna.py`` redraws it by its own
+    copy of this recipe: key ``i`` of 24, element ``n`` of the stacked
+    leaf)."""
+    dtype = cfg.jnp_dtype
+    KVH, D, Hd, V = (cfg.num_kv_heads, cfg.head_dim, cfg.hidden_size,
+                     cfg.vocab_size)
+    I, Im, Is = (cfg.intermediate_size, cfg.moe_intermediate_size,
+                 cfg.shared_expert_size)
+    nd = cfg.dense_layers
+    ns = cfg.num_layers - nd
+    keys = jax.random.split(rng, 24)
+
+    def winit(key, shape, fan_in):
+        return (jax.random.normal(key, shape, jnp.float32)
+                / jnp.sqrt(fan_in)).astype(dtype)
+
+    attn = {}
+    for ki, (kind, (n, H)) in enumerate(sorted(kind_heads(cfg).items())):
+        k = keys[2 + 5 * ki:7 + 5 * ki]
+        attn[kind] = {
+            "attn_norm": jnp.ones((n, Hd), dtype),
+            # Drawn as three matrices, served as one leaf (llama.fuse_qkv).
+            "wqkv": llama.fuse_qkv(
+                winit(k[0], (n, Hd, H * D), Hd),
+                winit(k[1], (n, Hd, KVH * D), Hd),
+                winit(k[2], (n, Hd, KVH * D), Hd), KVH),
+            "wg": winit(k[3], (n, Hd, H), Hd),
+            "wo": winit(k[4], (n, H * D, Hd), H * D),
+            "mlp_norm": jnp.ones((n, Hd), dtype),
+        }
+    params = {
+        "embed": (0.02 * jax.random.normal(keys[0], (V, Hd), jnp.float32)
+                  ).astype(dtype),
+        "final_norm": jnp.ones((Hd,), dtype),
+        "lm_head": winit(keys[1], (Hd, V), Hd),
+        "attn": attn,
+    }
+    if nd:
+        params["dense"] = {
+            "w_gate": winit(keys[12], (nd, Hd, I), Hd),
+            "w_up": winit(keys[13], (nd, Hd, I), Hd),
+            "w_down": winit(keys[14], (nd, I, Hd), I),
+        }
+    if ns:
+        E, held = cfg.published_experts, cfg.num_experts
+        params["moe"] = {
+            # The router keeps its published width: it scores every
+            # expert of the layer, held here or not.
+            "router": winit(keys[15], (ns, Hd, E), Hd),
+            "w_gate": winit(keys[16], (ns, held, Hd, Im), Hd),
+            "w_up": winit(keys[17], (ns, held, Hd, Im), Hd),
+            "w_down": winit(keys[18], (ns, held, Im, Hd), Im),
+        }
+        if Is:
+            params["moe"].update({
+                "shared_gate": winit(keys[19], (ns, Hd, Is), Hd),
+                "shared_up": winit(keys[20], (ns, Hd, Is), Hd),
+                "shared_down": winit(keys[21], (ns, Is, Hd), Is),
+            })
+    return params
+
+
+# --------------------------------------------------------------------- #
+# Rotary embedding
+# --------------------------------------------------------------------- #
+
+def rope_frequencies(rp: RopeParams, head_dim: int):
+    """(inverse frequencies [rotary dims / 2] as numpy float32, the
+    factor cos and sin are multiplied by). ``yarn`` is Hugging Face's
+    ``_compute_yarn_parameters``: extrapolated and interpolated
+    frequencies blended by the linear ramp between the two correction
+    dims."""
+    dim = int(head_dim * rp.partial_rotary_factor)
+    pos_freqs = rp.rope_theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    if rp.rope_type == "default":
+        return (1.0 / pos_freqs).astype(np.float32), 1.0
+    if rp.rope_type != "yarn":
+        raise ValueError(f"rope_type {rp.rope_type!r} is not implemented")
+    factor = rp.factor
+    attention_factor = (rp.attention_factor if rp.attention_factor is not None
+                        else 0.1 * math.log(factor) + 1.0)
+
+    def correction_dim(rotations):
+        return (dim * math.log(rp.original_max_position_embeddings
+                               / (rotations * 2 * math.pi))
+                ) / (2 * math.log(rp.rope_theta))
+
+    low = max(math.floor(correction_dim(rp.beta_fast)), 0)
+    high = min(math.ceil(correction_dim(rp.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
+                   / (high - low), 0, 1)
+    extrapolation = 1.0 - ramp
+    inv_freq = ((1.0 / (factor * pos_freqs)) * (1 - extrapolation)
+                + (1.0 / pos_freqs) * extrapolation)
+    return inv_freq.astype(np.float32), float(attention_factor)
+
+
+def rope(x: jax.Array, positions: jax.Array, rp: RopeParams) -> jax.Array:
+    """Rotary embedding over the first ``partial_rotary_factor`` of each
+    head's dims (half-split layout within them); the rest pass."""
+    inv_freq, factor = rope_frequencies(rp, x.shape[-1])
+    rot = 2 * inv_freq.shape[0]
+    angles = positions[..., None].astype(jnp.float32) * inv_freq
+    cos = (jnp.cos(angles) * factor)[:, :, None, :]
+    sin = (jnp.sin(angles) * factor)[:, :, None, :]
+    xf = x.astype(jnp.float32)
+    x1, x2, rest = xf[..., :rot // 2], xf[..., rot // 2:rot], xf[..., rot:]
+    out = jnp.concatenate(
+        [x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest], axis=-1)
+    return out.astype(x.dtype)
+
+
+# --------------------------------------------------------------------- #
+# One layer
+# --------------------------------------------------------------------- #
+
+def _take(stack: Dict, index) -> Dict:
+    """One layer's leaves of a stack, at a traced index."""
+    return {k: jax.lax.dynamic_index_in_dim(v, index, 0, keepdims=False)
+            for k, v in stack.items()}
+
+
+def _attention(cfg: ModelConfig, mode: str, x, p: Dict, kv, layer, batch,
+               kind: str, H: int):
+    B, T, _ = x.shape
+    KVH, D = cfg.num_kv_heads, cfg.head_dim
+    rp = cfg.rope_of(kind)
+    with jax.named_scope("attn_proj"):
+        h = llama.rms_norm(x, p["attn_norm"], cfg.rms_norm_eps)
+        q_flat, k_flat, v_flat = llama._split_qkv(h @ p["wqkv"], cfg, heads=H)
+        # assumed (a): the per-head gate is a sigmoid of a linear map of
+        # the layer's normed input.
+        gate = jax.nn.sigmoid(
+            jnp.dot(h, p["wg"], preferred_element_type=jnp.float32))
+        q = rope(q_flat.reshape(B, T, H, D), batch.positions, rp)
+        k = rope(k_flat.reshape(B, T, KVH, D), batch.positions, rp)
+        v = v_flat.reshape(B, T, KVH, D)
+    attn, kv = decoder.attend(
+        mode, q, k, v, kv, layer, batch, scale=1.0 / (D ** 0.5),
+        window=cfg.window_of(kind))
+    with jax.named_scope("attn_proj"):
+        attn = (attn.astype(jnp.float32) * gate[..., None]).astype(x.dtype)
+        x = x + attn.reshape(B, T, H * D) @ p["wo"]
+    return x, kv
+
+
+def _by_layer(flags: np.ndarray, layer, if_true, if_false, *operands):
+    """``if_true(*operands)`` for the layers ``flags`` marks, ``if_false``
+    for the others: ``lax.cond`` on the traced ``layer`` where both
+    occur, the one branch itself where only one does."""
+    if flags.all() or not flags.any():
+        return (if_true if flags.all() else if_false)(*operands)
+    return jax.lax.cond(jnp.asarray(flags)[layer], if_true, if_false,
+                        *operands)
+
+
+def run_layers(cfg: ModelConfig, mode: str, x, params: Dict, kv_pages,
+               batch: decoder.Batch):
+    """The layer loop: one scan over the layers whose body holds each
+    kind of attention and each kind of MLP once (the module's docstring).
+    Returns (x, kv_pages, the expert layers' stats summed over layers)."""
+    L, d = cfg.num_layers, cfg.dense_layers
+    kinds = [cfg.layer_kind(l) for l in range(L)]
+    full = np.asarray([kind == FULL_ATTENTION for kind in kinds])
+    dense = np.arange(L) < d
+    # Layer l reads entry at[l] of its kind's stack.
+    at = jnp.asarray([kinds[:l].count(kinds[l]) for l in range(L)], jnp.int32)
+
+    def attention(kind):
+        def run(x, kv, layer):
+            p = _take(params["attn"][kind], at[layer])
+            x, kv = _attention(cfg, mode, x, p, kv, layer, batch, kind,
+                               p["wg"].shape[-1])
+            return x, kv, p["mlp_norm"]
+        return run
+
+    def dense_mlp(x, h, layer):
+        w = _take(params["dense"], layer)
+        # assumed (c): hidden_act is silu.
+        x = x + moe.swiglu(h, w["w_gate"], w["w_up"], w["w_down"])
+        return x, jnp.zeros((len(moe.STATS),), jnp.int32)
+
+    def sparse_mlp(x, h, layer):
+        # The routed experts' stacks go to the grouped matmul whole, with
+        # the layer's index (models/moe.py): a slice of them would be
+        # copied out in every forward.
+        stacks = {k: params["moe"][k] for k in ("w_gate", "w_up", "w_down")}
+        w = _take({k: v for k, v in params["moe"].items()
+                   if k not in stacks}, layer - d)
+        # assumed (b): the router's scores are a softmax over all
+        # published experts.
+        routed, stats = moe.expert_layer(
+            h, {"router": w["router"], **stacks}, at=layer - d,
+            k=cfg.experts_per_token, share=cfg.layer_share,
+            scaling=cfg.routed_scaling, valid=batch.slot_mapping >= 0)
+        x = x + routed
+        if "shared_gate" in w:
+            with jax.named_scope("moe_shared"):
+                x = x + moe.swiglu(h, w["shared_gate"], w["shared_up"],
+                                   w["shared_down"])
+        return x, stats
+
+    def body(carry, layer):
+        x, k_all, v_all, stats = carry
+        x, kv, mlp_norm = _by_layer(
+            full, layer, attention(FULL_ATTENTION),
+            attention(SLIDING_ATTENTION), x, (k_all, v_all), layer)
+        with jax.named_scope("mlp"):
+            h = llama.rms_norm(x, mlp_norm, cfg.rms_norm_eps)
+            x, s = _by_layer(dense, layer, dense_mlp, sparse_mlp, x, h, layer)
+        return (x, *kv, stats + s), None
+
+    carry = (x, *kv_pages, jnp.zeros((len(moe.STATS),), jnp.int32))
+    (x, k_all, v_all, stats), _ = jax.lax.scan(
+        body, carry, jnp.arange(L, dtype=jnp.int32))
+    return x, (k_all, v_all), stats
+
+
+def load_checkpoint(cfg: ModelConfig, path: str) -> Dict:
+    raise NotImplementedError(
+        "no checkpoint loader for the laguna family yet: its tensor names "
+        "are not published where this repo can read them; a directory with "
+        "config.json alone is served with random weights from --seed")
+
+
+def _no_single_layer(*args, **kwargs):
+    raise NotImplementedError(
+        "laguna's layers are of several kinds: models/laguna.py::run_layers "
+        "is its loop, and it has no pipeline stages yet")
+
+
+def _replicated(*paths_and_ranks):
+    return {path: P(*[None] * rank) for path, rank in paths_and_ranks}
+
+
+FAMILY = Family(
+    model_types=("laguna",),
+    init_params=init_params,
+    embed=llama.FAMILY.embed,
+    layer=_no_single_layer,
+    loop=run_layers,
+    head=llama.project_out,
+    load=load_checkpoint,
+    # Every leaf, each replicated over a mesh: no tensor-parallel rules
+    # yet (the ``ep`` axis of ROADMAP M1 would split ``moe/w_*``'s second
+    # axis, as ``layer_share`` splits it across processes today).
+    specs=_replicated(
+        (("embed",), 2), (("final_norm",), 1), (("lm_head",), 2),
+        *(((("attn", kind, leaf), rank))
+          for kind in (FULL_ATTENTION, SLIDING_ATTENTION)
+          for leaf, rank in (("attn_norm", 2), ("mlp_norm", 2), ("wqkv", 3),
+                             ("wg", 3), ("wo", 3))),
+        *((("dense", leaf), 3) for leaf in ("w_gate", "w_up", "w_down")),
+        (("moe", "router"), 3),
+        *((("moe", leaf), 4) for leaf in ("w_gate", "w_up", "w_down")),
+        *((("moe", leaf), 3)
+          for leaf in ("shared_gate", "shared_up", "shared_down"))),
+    per_layer_keys=("layer_types", "mlp_layer_types",
+                    "num_attention_heads_per_layer"),
+    stats=moe.STATS,
+)
+
+apply = functools.partial(decoder.apply, FAMILY)

@@ -86,15 +86,17 @@ def fuse_qkv(wq, wk, wv, num_kv_heads: int):
     return xp.concatenate(groups, axis=-2).reshape(lead + (-1,))
 
 
-def _split_qkv(y: jax.Array, cfg: ModelConfig):
+def _split_qkv(y: jax.Array, cfg: ModelConfig, heads: int | None = None):
     """Flat q, k, v ``[B, T, heads * D]`` from the fused projection's
     output ``[B, T, KVH * (G + 2) * D]``. The barrier keeps the matmul
     flat: without it the compiler folds the reshape into the dot, wants
     the weight with ``Hd`` minor, and copies + transposes it out of the
     stacked leaf in every layer (PERF.md section 6, PR 30). What is split
-    here is the activations, 6,144 values a token at Mistral's widths."""
+    here is the activations, 6,144 values a token at Mistral's widths.
+    ``heads``: the layer's own query heads where a family's layers differ
+    (models/laguna.py)."""
     B, T, _ = y.shape
-    H, KVH, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    H, KVH, D = heads or cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     G = H // KVH
     y = jax.lax.optimization_barrier(y).reshape(B, T, KVH, G + 2, D)
     return (y[:, :, :, :G].reshape(B, T, H * D),

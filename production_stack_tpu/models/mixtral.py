@@ -1,14 +1,13 @@
 """Mixtral-style sparse-MoE decoder (BASELINE config 5: Mixtral-8x7B).
 
 Llama's attention half (models/llama.py::attention_half, the fused
-``wqkv`` leaf included) + a top-k routed expert MLP. Expert compute is
-expressed as a dense einsum over all experts weighted by the routing mask
-— on TPU this keeps the MXU busy with one big batched matmul and avoids
-dynamic shapes; with an ``ep`` mesh axis the expert dimension shards
-across chips and XLA inserts the all-to-all. (Capacity-based token
-dropping is not needed because every token computes its top-k experts
-exactly.) No LoRA slots, no pipeline stages, no int8 weights yet: the
-record at the foot of the file says so.
+``wqkv`` leaf included) + a top-k routed expert MLP: the expert layer of
+models/moe.py with every expert held here (assignments sorted by expert,
+one grouped matmul per projection, each token computing exactly its
+top-k experts: no capacity, no dropped token, and not all E experts for
+every token as the dense einsum this replaced did). No LoRA slots, no
+pipeline stages, no int8 weights yet: the record at the foot of the file
+says so.
 """
 
 from __future__ import annotations
@@ -20,7 +19,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from production_stack_tpu.models import decoder, llama
+from production_stack_tpu.models import decoder, llama, moe
 from production_stack_tpu.models.config import ModelConfig
 from production_stack_tpu.models.registry import Family
 from production_stack_tpu.models.weights import _to_dtype, report_incomplete
@@ -60,31 +59,20 @@ def init_params(cfg: ModelConfig, rng: jax.Array, **_unused) -> Dict:
     }
 
 
-def moe_mlp(cfg: ModelConfig, p: Dict, h: jax.Array) -> jax.Array:
-    """Top-k routed expert MLP. h: [B, T, Hd] -> [B, T, Hd]."""
-    B, T, Hd = h.shape
-    E, K = cfg.num_experts, cfg.experts_per_token
-    router_logits = (h @ p["router"]).astype(jnp.float32)  # [B,T,E]
-    topk_vals, topk_idx = jax.lax.top_k(router_logits, K)
-    topk_w = jax.nn.softmax(topk_vals, axis=-1)  # [B,T,K]
-    one_hot = jax.nn.one_hot(topk_idx, E, dtype=jnp.float32)  # [B,T,K,E]
-    dense_w = jnp.einsum("btk,btke->bte", topk_w, one_hot)  # [B,T,E]
-    # All-expert compute, weighted combine (MXU-dense, EP-shardable).
-    gate = jnp.einsum("bth,ehi->btei", h, p["w_gate"])
-    up = jnp.einsum("bth,ehi->btei", h, p["w_up"])
-    act = jax.nn.silu(gate.astype(jnp.float32)).astype(h.dtype) * up
-    out = jnp.einsum("btei,eih->bteh", act, p["w_down"])
-    return jnp.einsum(
-        "bteh,bte->bth", out.astype(jnp.float32), dense_w
-    ).astype(h.dtype)
+EXPERT_LEAVES = ("w_gate", "w_up", "w_down")
 
 
 def _layer(cfg: ModelConfig, mode: str, x, per_layer, kv, layer, batch):
+    """``p`` holds the layer's slice of every leaf but the experts', which
+    are the whole stacks ``[L, E, ...]`` (``Family.whole_leaves``): the
+    expert layer hands them to its grouped matmuls with ``layer``."""
     p, _no_lora = per_layer
     x, kv = llama.attention_half(cfg, mode, x, p, None, kv, layer, batch)
     with jax.named_scope("mlp"):
         h = llama.rms_norm(x, p["mlp_norm"], cfg.rms_norm_eps)
-        x = x + moe_mlp(cfg, p, h)
+        routed, _stats = moe.expert_layer(
+            h, p, k=cfg.experts_per_token, at=layer)
+        x = x + routed
     return x, kv
 
 
@@ -130,6 +118,7 @@ FAMILY = Family(
     layer=_layer,
     head=llama.project_out,
     load=load_checkpoint,
+    whole_leaves=EXPERT_LEAVES,
     specs={
         **llama.ATTN_SPECS,
         ("layers", "router"): P(None, None, None),

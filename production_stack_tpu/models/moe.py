@@ -1,0 +1,114 @@
+"""The routed expert layer both MoE families share (Mixtral, Laguna).
+
+One chip of the ``chips`` that share a layer holds a block of the
+experts: ``held = E / chips`` of them, block ``share``. The router keeps
+its published width and scores all ``E``; every token takes its top
+``k``; the assignments that land on this chip's block are sorted by
+expert and go through ONE grouped matmul per projection
+(``jax.lax.ragged_dot``, which the TPU compiler lowers to a grouped
+Mosaic matmul that visits only the row tiles of the groups it is given:
+step 0 of PR 33 on the chip, PERF.md section 6); the outputs are weighted
+and summed back per token. No capacity and no dropped token: the rows
+are as many as there are assignments, ``tokens x k``, and what lands on
+another chip's experts is sorted past the last group, where the grouped
+matmul does no work and the combine reads zeros. What the other chips'
+experts would add is theirs to add: nothing here stands in for them.
+
+With every expert held (``chips = 1``: Mixtral) this is the whole layer,
+and softmax over all scores renormalised over the top k equals Mixtral's
+softmax over the top-k logits.
+
+**The expert leaves reach the grouped matmul whole.** A grouped matmul is
+a kernel, and a kernel's operand is a buffer: handed one layer's slice of
+a layer-stacked leaf, the compiler copies the slice out first, every
+expert of the layer in every forward (1.2 GB a layer at Laguna's widths:
+the v5e compiler ran out of memory on the copies alone). So a family
+passes its expert leaves as they are stacked over its sparse layers,
+with the layer's index ``at``: the stack ``[layers, held, ...]`` is read
+as ``layers x held`` groups (a reshape of leading dims,
+no copy) whose sizes are zero but for this layer's block, and the kernel
+visits only the row tiles of groups that have rows.
+"""
+
+from __future__ import annotations
+
+from typing import Dict, Tuple
+
+import jax
+import jax.numpy as jnp
+
+# What :func:`expert_layer` counts of one call, in this order.
+STATS = ("moe_assignments", "moe_experts_hit", "moe_max_expert_load")
+
+
+def swiglu(h: jax.Array, w_gate, w_up, w_down) -> jax.Array:
+    """``(silu(h Wgate) * (h Wup)) Wdown``: the dense MLP, the shared
+    expert, and what each routed expert computes on its rows."""
+    gate = jax.nn.silu((h @ w_gate).astype(jnp.float32)).astype(h.dtype)
+    return (gate * (h @ w_up)) @ w_down
+
+
+def route(h: jax.Array, router: jax.Array, k: int, *, scaling: float = 1.0
+          ) -> Tuple[jax.Array, jax.Array]:
+    """(weights [N, k] float32, experts [N, k]) of the tokens ``h [N, Hd]``
+    over all of ``router``'s outputs: float32 softmax, top k, weights
+    renormalised to sum 1 and multiplied by ``scaling``."""
+    logits = jnp.dot(h, router, preferred_element_type=jnp.float32)
+    weights, experts = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+    weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    return weights * scaling, experts
+
+
+def expert_layer(
+    h: jax.Array,  # [B, T, Hd], normed
+    p: Dict,  # router [Hd, E]; w_gate, w_up [layers, held, Hd, I]; w_down
+    #           [layers, held, I, Hd]: the stacks over the sparse layers
+    *,
+    k: int,
+    at,  # which layer of the stacks this is (static or traced)
+    share: int = 0,  # this chip's block of experts: [share * held, ...)
+    scaling: float = 1.0,
+    valid: jax.Array | None = None,  # [B, T] bool: padding routes nowhere
+) -> Tuple[jax.Array, jax.Array]:
+    """The routed experts held here on ``h``. Returns (their weighted sum
+    per token [B, T, Hd], the :data:`STATS` of the call as int32 [3]: the
+    assignments the held experts received, how many of them received one,
+    and the largest number one received)."""
+    B, T, Hd = h.shape
+    N = B * T
+    held = p["w_gate"].shape[1]
+    x = h.reshape(N, Hd)
+    with jax.named_scope("moe_router"):
+        weights, experts = route(x, p["router"], k, scaling=scaling)
+    with jax.named_scope("moe_experts"):
+        local = experts - share * held
+        mine = (local >= 0) & (local < held)
+        if valid is not None:
+            mine = mine & valid.reshape(N, 1)
+        # ``held`` is the group of everything that is not computed here:
+        # it sorts last, past the rows the grouped matmul is given.
+        group = jnp.where(mine, local, held).reshape(N * k)
+        order = jnp.argsort(group, stable=True)
+        sizes = jnp.zeros((held + 1,), jnp.int32).at[group].add(1)[:held]
+        rows = x[order // k]  # [N * k, Hd], by expert
+
+        def grouped(lhs, name):
+            w = p[name]
+            layers = w.shape[0]
+            in_stack = jax.lax.dynamic_update_slice(
+                jnp.zeros((layers * held,), jnp.int32), sizes,
+                (jnp.asarray(at, jnp.int32) * held,))
+            return jax.lax.ragged_dot(
+                lhs, w.reshape((layers * held,) + w.shape[2:]), in_stack)
+
+        gate = grouped(rows, "w_gate")
+        up = grouped(rows, "w_up")
+        act = jax.nn.silu(gate.astype(jnp.float32)).astype(h.dtype) * up
+        out = grouped(act, "w_down")  # [N * k, Hd]
+        # Back to the token's order; a row past the groups is not the
+        # grouped matmul's to define, so it is replaced, not multiplied.
+        out = out[jnp.argsort(order)].reshape(N, k, Hd)
+        out = jnp.where(mine[..., None], out.astype(jnp.float32), 0.0)
+        y = jnp.einsum("nkh,nk->nh", out, weights).astype(h.dtype)
+    stats = jnp.stack([jnp.sum(sizes), jnp.sum(sizes > 0), jnp.max(sizes)])
+    return y.reshape(B, T, Hd), stats

@@ -21,6 +21,7 @@ ARCH_MODULES = {
     "llama": "production_stack_tpu.models.llama",
     "opt": "production_stack_tpu.models.opt",
     "mixtral": "production_stack_tpu.models.mixtral",
+    "laguna": "production_stack_tpu.models.laguna",
 }
 
 
@@ -61,6 +62,27 @@ class Family:
     # A checkpoint without ``lm_head`` ties the head to ``embed``: the
     # random head of the init is dropped and ``head`` reads ``embed.T``.
     head_may_tie: bool = False
+    # The family's own layer loop, where its layers are of several kinds
+    # and one scan over one stack cannot run them (models/laguna.py):
+    # ``loop(cfg, mode, x, params, kv_pages, batch)`` -> ``(x, kv_pages,
+    # counts)``. None: ``decoder.scan_layers`` over ``params["layers"]``
+    # with ``layer``.
+    loop: Callable | None = None
+    # ``layers`` leaves that ``layer`` is handed whole, as the stack
+    # ``[L, ...]``, beside the layer's own slice of every other leaf: the
+    # operands of a kernel that takes the layer's index itself (the
+    # expert layer's grouped matmuls, models/moe.py), which a slice of
+    # the stack would be copied out for in every forward.
+    whole_leaves: Tuple[str, ...] = ()
+    # Per-layer lists its ``config.json`` must hold, each at least
+    # ``num_hidden_layers`` long (models/config.py reads their first
+    # ``num_hidden_layers`` entries, and refuses a file without them).
+    per_layer_keys: Tuple[str, ...] = ()
+    # What ``loop`` counts of one forward: ``apply(..., with_stats=True)``
+    # returns it as a third value, an int32 vector with one entry per
+    # name, which the step programs sum over their forwards and the step
+    # record carries under these names (engine/core.py, obs/steps.py).
+    stats: Tuple[str, ...] = ()
 
 
 def _module(arch: str):

@@ -168,6 +168,7 @@ def prefill_attention(
     *,
     scale: float,
     seq_lens: jax.Array | None = None,  # [B] valid lengths (padding masked)
+    window: int | None = None,  # static: query i sees key j iff i - j < window
 ) -> jax.Array:
     """Causal attention over a prompt chunk. Returns [B, T, H, D]."""
     B, T, H, D = q.shape
@@ -183,6 +184,8 @@ def prefill_attention(
     if seq_lens is not None:
         valid = pos[None, None, :] < seq_lens[:, None, None]  # [B,1,S]
         mask = causal & valid
+    if window is not None:
+        mask = mask & (pos[None, :, None] - pos[None, None, :] < window)
     scores = jnp.where(mask[:, None, None, :, :], scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum(
@@ -230,6 +233,7 @@ def context_prefill_attention(
     k_new: jax.Array | None = None,  # [B, T, KVH, D] the chunk's fresh K
     v_new: jax.Array | None = None,  # [B, T, KVH, D]
     suffix_lens: jax.Array | None = None,  # [B] valid fresh tokens
+    window: int | None = None,  # static: position p sees p - window < j <= p
 ) -> jax.Array:
     """Prefill attention for a suffix whose K/V (and the cached prefix's)
     already live in HBM pages: query at absolute position p attends to page
@@ -259,16 +263,19 @@ def context_prefill_attention(
 
         def kernel(q, k_new, v_new, k_pages, v_pages, block_tables,
                    positions, total_lens, layer, suffix_lens):
+            # ``window`` only where there is one: a program without it
+            # traces the call it always traced.
+            bound = {} if window is None else {"window": window}
             return pallas_prefill_attention(
                 q, k_pages, v_pages, block_tables, positions, total_lens,
-                layer, k_new, v_new, suffix_lens, scale=scale)
+                layer, k_new, v_new, suffix_lens, scale=scale, **bound)
 
         return _per_kv_shard(kernel, (4, 4, 4), 5)(
             q, k_new, v_new, k_pages, v_pages, block_tables, positions,
             total_lens, layer, suffix_lens)
     return _context_prefill_reference(
         q, k_pages, v_pages, block_tables, positions, total_lens, layer,
-        scale=scale,
+        scale=scale, window=window,
     )
 
 
@@ -282,6 +289,7 @@ def _context_prefill_reference(
     layer: jax.Array,  # scalar layer index
     *,
     scale: float,
+    window: int | None = None,
 ) -> jax.Array:
     """XLA reference: gather the whole padded context (suffix included —
     it was scattered to the pages by write_kv_pages one op earlier),
@@ -324,12 +332,20 @@ def _context_prefill_reference(
             span_c = ci * chunk + jnp.arange(chunk)
             causal = span_c[None, None, :] <= positions[:, :, None]
             valid = span_c[None, None, :] < total_lens[:, None, None]
-            s = jnp.where((causal & valid)[:, None, None, :, :],
-                          s, NEG_INF)
+            seen = causal & valid
+            if window is not None:
+                seen = seen & (
+                    span_c[None, None, :] > positions[:, :, None] - window)
+            s = jnp.where(seen[:, None, None, :, :], s, NEG_INF)
             m_cur = jnp.max(s, axis=-1, keepdims=True)
             m_new = jnp.maximum(m, m_cur)
             alpha = jnp.exp(m - m_new)
             p = jnp.exp(s - m_new)
+            if window is not None:
+                # A query whose window starts past this chunk sees none
+                # of it: its running max is still NEG_INF and exp(0)
+                # would count every masked key as one.
+                p = jnp.where(seen[:, None, None, :, :], p, 0.0)
             l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
             upd = jnp.einsum("bkgts,bskd->bkgtd", p.astype(v_c.dtype),
                              v_c).astype(jnp.float32)
@@ -352,6 +368,8 @@ def _context_prefill_reference(
     causal = span[None, None, :] <= positions[:, :, None]  # [B, T, S]
     valid = span[None, None, :] < total_lens[:, None, None]
     mask = causal & valid
+    if window is not None:
+        mask = mask & (span[None, None, :] > positions[:, :, None] - window)
     scores = jnp.where(mask[:, None, None, :, :], scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bkgts,bskd->btkgd", probs.astype(v_ctx.dtype), v_ctx)
@@ -409,6 +427,7 @@ def paged_attention_reference(
     layer: jax.Array,  # scalar layer index
     *,
     scale: float,
+    window: int | None = None,  # static: the last ``window`` tokens only
 ) -> jax.Array:
     """XLA fallback: gather the padded context, mask, soft-max. [B, H, D];
     zeros for a row whose context is 0 or less (it holds nothing), as the
@@ -426,6 +445,8 @@ def paged_attention_reference(
     ) * scale
     span = jnp.arange(MAXB * bs)
     mask = span[None, :] < context_lens[:, None]  # [B, S]
+    if window is not None:
+        mask = mask & (span[None, :] >= context_lens[:, None] - window)
     scores = jnp.where(mask[:, None, None, :], scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bkgs,bskd->bkgd", probs.astype(v_ctx.dtype), v_ctx)
@@ -442,6 +463,7 @@ def paged_decode_attention(
     layer: jax.Array,  # scalar layer index
     *,
     scale: float,
+    window: int | None = None,  # static: the last ``window`` tokens only
 ) -> jax.Array:
     """Dispatch to the pallas kernel on TPU, XLA reference elsewhere."""
     if _traced_path("decode", k_pages) == "pallas":
@@ -450,12 +472,14 @@ def paged_decode_attention(
         )
 
         def kernel(q, k_pages, v_pages, block_tables, context_lens, layer):
+            bound = {} if window is None else {"window": window}
             return pallas_paged_attention(
                 q, k_pages, v_pages, block_tables, context_lens, layer,
-                scale=scale)
+                scale=scale, **bound)
 
         return _per_kv_shard(kernel, (3,), 3)(
             q, k_pages, v_pages, block_tables, context_lens, layer)
     return paged_attention_reference(
-        q, k_pages, v_pages, block_tables, context_lens, layer, scale=scale
+        q, k_pages, v_pages, block_tables, context_lens, layer, scale=scale,
+        window=window,
     )

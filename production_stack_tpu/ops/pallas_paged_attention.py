@@ -157,22 +157,37 @@ def live_pages(context_len, block_size: int):
     return (context_len + block_size - 1) // block_size * (context_len > 0)
 
 
-def fetch_tokens(context_lens, block_size: int, tables_width: int) -> int:
+def first_live_page(context_len, block_size: int, window: int):
+    """The page that holds a row's first live token under a window:
+    token ``max(context - window, 0)``. Pages before it are not copied.
+    Plain arithmetic, like :func:`live_pages`."""
+    return (context_len - window) * (context_len > window) // block_size
+
+
+def fetch_tokens(context_lens, block_size: int, tables_width: int,
+                 window: int | None = None) -> int:
     """Token slots one decode call copies out of HBM for these contexts
     (a host array) under a table ``tables_width`` pages wide: each row's
-    live pages, whole. The chunk's width and the rows that hold nothing
+    live pages, whole; with ``window`` those from the page of its first
+    live token on. The chunk's width and the rows that hold nothing
     do not enter, nor does the table's width while it holds every
     context: a context past it is cut to it, as the kernel cuts it."""
     cut = np.minimum(context_lens, tables_width * block_size)
-    return int(live_pages(cut, block_size).sum()) * block_size
+    pages = live_pages(cut, block_size)
+    if window is not None:
+        pages = pages - first_live_page(cut, block_size, window)
+    return int(pages.sum()) * block_size
 
 
 def _for_chunk_copies(fn, k_hbm, v_hbm, k_buf, v_buf, sems, bt_ref, layer,
-                      b, chunk, slot, pages_per_block, row_pages=None):
+                      b, chunk, slot, pages_per_block, row_pages=None,
+                      first_page=None):
     """``fn`` on the async-copy descriptors of one chunk's pages into ring
     slot ``slot``; with ``row_pages`` (the row's :func:`live_pages`) only
-    on the pages under it. Start and wait walk the same descriptors under
-    the same predicate, so every started copy is waited exactly once."""
+    on the pages under it, and with ``first_page`` (the row's
+    :func:`first_live_page`) only from that page on. Start and wait walk
+    the same descriptors under the same predicate, so every started copy
+    is waited exactly once."""
     for p in range(pages_per_block):
         def one(p=p):
             page = bt_ref[b, chunk * pages_per_block + p]
@@ -183,8 +198,12 @@ def _for_chunk_copies(fn, k_hbm, v_hbm, k_buf, v_buf, sems, bt_ref, layer,
 
         if row_pages is None:
             one()
-        else:
+        elif first_page is None:
             pl.when(chunk * pages_per_block + p < row_pages)(one)
+        else:
+            page = chunk * pages_per_block + p
+            pl.when(jnp.logical_and(page >= first_page,
+                                    page < row_pages))(one)
 
 
 def _start_chunk_copy(*args, **kwargs):
@@ -218,6 +237,7 @@ def _decode_kernel(
     pages_per_block: int,
     ring: int,
     quantized: bool,
+    window: int | None = None,
 ):
     if quantized:
         ks_hbm_ref, vs_hbm_ref, *refs = refs
@@ -241,6 +261,20 @@ def _decode_kernel(
 
     def row_pages(row):
         return live_pages(context_lens_ref[row], block_size)
+
+    # Under a window a row's walk starts at the chunk that holds its
+    # first live token, and copies from that token's page on: every
+    # statement that knows of it is behind ``window is not None``, so a
+    # model without one traces the kernel it always traced.
+    def row_first_page(row):
+        return first_live_page(context_lens_ref[row], block_size, window)
+
+    def row_first_chunk(row):
+        return 0 if window is None else row_first_page(row) // P
+
+    def window_copies(row):
+        return {} if window is None else {
+            "first_page": row_first_page(row)}
 
     def head_loads(buf, slot):
         """The chunk in ring slot ``slot`` as one f32 [span, D] per kv
@@ -298,7 +332,8 @@ def _decode_kernel(
             pages = row_pages(row)
             _start_chunk_copy(
                 k_hbm_ref, v_hbm_ref, k_buf, v_buf, sems, block_tables_ref,
-                layer, row, chunk, slot, P, row_pages=pages)
+                layer, row, chunk, slot, P, row_pages=pages,
+                **window_copies(row))
             if quantized:
                 for c in scale_copies(row, chunk, slot):
                     c.start()
@@ -311,13 +346,19 @@ def _decode_kernel(
 
             @pl.when(jnp.logical_not(more))
             def _():
-                walk_ref[NEXT_ROW] = first_live_row(row + 1)
-                walk_ref[NEXT_CHUNK] = 0
+                nxt = first_live_row(row + 1)
+                walk_ref[NEXT_ROW] = nxt
+                walk_ref[NEXT_CHUNK] = (
+                    0 if window is None
+                    else row_first_chunk(jnp.minimum(nxt, nb - 1)))
 
     @pl.when(b == 0)
     def _fill():
-        walk_ref[NEXT_ROW] = first_live_row(jnp.int32(0))
-        walk_ref[NEXT_CHUNK] = 0
+        row0 = first_live_row(jnp.int32(0))
+        walk_ref[NEXT_ROW] = row0
+        walk_ref[NEXT_CHUNK] = (
+            0 if window is None
+            else row_first_chunk(jnp.minimum(row0, nb - 1)))
         walk_ref[STARTED] = 0
         walk_ref[CONSUMED] = 0
         for _ in range(ring - 1):
@@ -337,10 +378,31 @@ def _decode_kernel(
         chunk_start = c * span_tokens
         _wait_chunk_copy(
             k_hbm_ref, v_hbm_ref, k_buf, v_buf, sems, block_tables_ref,
-            layer, b, c, slot, P, row_pages=pages)
+            layer, b, c, slot, P, row_pages=pages, **window_copies(b))
         if quantized:
             for cp in scale_copies(b, c, slot):
                 cp.wait()
+
+        if window is not None:
+            # The row's front: the pages of its first chunk that lie
+            # before its first live token were not copied, and hold what
+            # an earlier chunk left in the slot. Zeroed where the p @ v
+            # dot reads them, as the tail's are (0 x NaN is NaN).
+            first_tok = jnp.maximum(ctx - window, 0)
+            front = row_first_page(b) * block_size
+
+            @pl.when(chunk_start < front)
+            def _front():
+                if quantized:
+                    lane = chunk_start + jax.lax.broadcasted_iota(
+                        jnp.int32, (1, span_tokens), 1)
+                    vs_buf[slot] = jnp.where(lane >= front, vs_buf[slot],
+                                             0.0)
+                else:
+                    for p in range(P):
+                        @pl.when(chunk_start + (p + 1) * block_size <= front)
+                        def _(p=p):
+                            v_buf[slot, p] = jnp.zeros_like(v_buf[slot, p])
 
         # The row's tail. A probability of exactly 0 does not silence
         # what it multiplies (0 x NaN is NaN in the p @ v dot), so the
@@ -398,6 +460,8 @@ def _decode_kernel(
             jnp.int32, (1, span_tokens), 1
         )
         valid = span < ctx  # [1, span]
+        if window is not None:
+            valid = jnp.logical_and(valid, span >= first_tok)
         s = jnp.where(valid, s_ref[...], NEG_INF)  # [KVH*g_pad, span]
         m_prev = m_ref[:, :1]
         m_cur = jnp.max(s, axis=1, keepdims=True)
@@ -426,7 +490,8 @@ def _decode_kernel(
         acc_ref[...] = jnp.zeros_like(acc_ref)
         # A trip count that follows the context: a chunk past it costs
         # no step, whatever the table's width.
-        jax.lax.fori_loop(0, (pages + P - 1) // P, chunk_step, None)
+        jax.lax.fori_loop(row_first_chunk(b), (pages + P - 1) // P,
+                          chunk_step, None)
         denom = jnp.maximum(l_ref[:, :1], 1e-30)
         o_ref[0] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
@@ -451,7 +516,8 @@ def decode_tile(block_size: int, kvh: int, head_dim: int, g_pad: int,
 
 
 @functools.partial(
-    jax.jit, static_argnames=("scale", "pages_per_block", "ring", "interpret"))
+    jax.jit, static_argnames=("scale", "pages_per_block", "ring", "interpret",
+                              "window"))
 def pallas_paged_attention(
     q: jax.Array,  # [B, H, D]
     k_pages,  # [L, NB, bs, KVH, D] stacked pages (or (data, scales))
@@ -464,8 +530,12 @@ def pallas_paged_attention(
     pages_per_block: int = 0,  # 0 -> from the VMEM budget (decode_tile)
     ring: int = 0,  # DMA ring depth; 0 -> from the VMEM budget
     interpret: bool = False,
+    window: int | None = None,  # the row's last ``window`` tokens only
 ) -> jax.Array:
-    """[B, H, D]; zeros for a row whose context is 0 or less."""
+    """[B, H, D]; zeros for a row whose context is 0 or less. With
+    ``window`` a row's first live token is ``max(context - window, 0)``:
+    no page before that token's is copied and no chunk before its chunk
+    is computed."""
     quantized = isinstance(k_pages, tuple)
     if quantized:
         k_pages, k_scales = k_pages
@@ -503,6 +573,7 @@ def pallas_paged_attention(
     kernel = functools.partial(
         _decode_kernel, block_size=bs, kvh=KVH, g_pad=g_pad,
         pages_per_block=P, ring=R, quantized=quantized,
+        **({} if window is None else {"window": window}),
     )
     layer_arr = jnp.asarray(layer, jnp.int32).reshape(1)
     row_block = pl.BlockSpec(

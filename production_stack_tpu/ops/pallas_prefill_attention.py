@@ -79,6 +79,8 @@ def _prefill_kernel(
     pages_per_block: int,
     ring: int,
     quantized: bool,
+    window: int | None = None,
+    q_tile: int = 0,  # with ``window``: TQ, to give each row its position
 ):
     if quantized:
         ks_ref, vs_ref, *refs = refs
@@ -135,10 +137,7 @@ def _prefill_kernel(
                           block_tables_ref, layer, b_pre, c_pre,
                           jax.lax.rem(g_pre, ring), P)
 
-    @pl.when(chunk_start < prefix)
-    def _compute():
-        _wait_chunk_copy(k_hbm_ref, v_hbm_ref, k_buf, v_buf, sems,
-                         block_tables_ref, layer, b, c, slot, P)
+    def attend_chunk():
         for h in range(kvh):  # static unroll over kv heads
             rows = slice(h * gq, (h + 1) * gq)
             q = q_ref[0, 0, rows, :].astype(jnp.float32)  # [gq, D]
@@ -162,12 +161,24 @@ def _prefill_kernel(
             jnp.int32, (1, span_tokens), 1
         )
         valid = span < prefix  # [1, span]
+        if window is not None:
+            # Row (h * group + g) * TQ + t of the tile is the query at
+            # position prefix + qi * TQ + t, which sees the keys after
+            # position - window.
+            t = jax.lax.rem(jax.lax.broadcasted_iota(
+                jnp.int32, (kvh * gq, 1), 0), q_tile)
+            valid = jnp.logical_and(
+                valid, span > prefix + qi * q_tile + t - window)
         s = jnp.where(valid, s_ref[...], NEG_INF)  # [KVH*gq, span]
         m_prev = m_ref[:, :1]
         m_cur = jnp.max(s, axis=1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
         alpha = jnp.exp(m_prev - m_new)  # [KVH*gq, 1]
         p_ = jnp.exp(s - m_new)
+        if window is not None:
+            # A row that sees nothing of this chunk still has NEG_INF as
+            # its running max: exp(0) would count each masked key as one.
+            p_ = jnp.where(valid, p_, 0.0)
         l_ref[...] = jnp.broadcast_to(
             alpha * l_ref[:, :1] + jnp.sum(p_, axis=1, keepdims=True),
             l_ref.shape,
@@ -183,6 +194,19 @@ def _prefill_kernel(
                 p_h = p_h * vs_ref[0, h:h + 1, :]
             acc_ref[rows, :] = acc_ref[rows, :] + jax.lax.dot(
                 p_h, v, preferred_element_type=jnp.float32)
+
+    @pl.when(chunk_start < prefix)
+    def _compute():
+        _wait_chunk_copy(k_hbm_ref, v_hbm_ref, k_buf, v_buf, sems,
+                         block_tables_ref, layer, b, c, slot, P)
+        if window is None:
+            attend_chunk()
+        else:
+            # The copy was started, so it is waited for; a chunk that
+            # ends before the tile's first query's window is not
+            # computed.
+            pl.when(chunk_start + span_tokens
+                    > prefix + qi * q_tile - window + 1)(attend_chunk)
 
     @pl.when(c == nc - 1)
     def _finalize():
@@ -233,7 +257,7 @@ def prefill_tile(T: int, H: int, block_size: int, kvh: int, head_dim: int,
 @functools.partial(
     jax.jit,
     static_argnames=("scale", "pages_per_block", "ring", "q_tile",
-                     "interpret"))
+                     "interpret", "window"))
 def pallas_prefill_attention(
     q: jax.Array,  # [B, T, H, D] the chunk's query tokens
     k_pages,  # [L, NB, bs, KVH, D] stacked pages (or (data, scales))
@@ -251,6 +275,7 @@ def pallas_prefill_attention(
     ring: int = 0,  # DMA ring depth; 0 -> from the VMEM budget
     q_tile: int = 0,  # query-tile width; 0 -> from the VMEM budget
     interpret: bool = False,
+    window: int | None = None,  # position p sees p - window < j <= p
 ) -> jax.Array:
     quantized = isinstance(k_pages, tuple)
     if quantized:
@@ -294,6 +319,7 @@ def pallas_prefill_attention(
     kernel = functools.partial(
         _prefill_kernel, block_size=bs, kvh=KVH, gq=gq,
         pages_per_block=P, ring=R, quantized=quantized,
+        **({} if window is None else {"window": window, "q_tile": TQ}),
     )
     layer_arr = jnp.asarray(layer, jnp.int32).reshape(1)
     in_specs = [
@@ -365,6 +391,9 @@ def pallas_prefill_attention(
     fresh = (jnp.arange(T, dtype=jnp.int32)[None, :]
              < suffix_lens[:, None])  # [B, s]
     mask = jnp.logical_and(causal, fresh[:, None, :])
+    if window is not None:
+        mask = jnp.logical_and(
+            mask, positions[:, None, :] > positions[:, :, None] - window)
     s = jnp.where(mask[:, None, None, :, :], s, NEG_INF)
     m_s = jnp.max(s, axis=-1)  # [B, KVH, group, T]
     p = jnp.exp(s - m_s[..., None])

@@ -260,3 +260,85 @@ def test_layer_scan_reads_stacked_weights_in_place(one_chip, monkeypatch,
             if np.prod([int(d) for d in m.group(2).split(",")]) >= smallest:
                 moved.append((m.group(1), m.group(2)))
     assert not moved, moved
+
+
+@pytest.mark.parametrize("tables", [4, 64])
+@pytest.mark.parametrize("heads", [48, 72])  # query groups 6 and 9
+@pytest.mark.parametrize("window", [None, 512])
+def test_decode_kernel_compiles_at_lagunas_query_groups(one_chip, heads,
+                                                        window, tables):
+    """Laguna-S-2.1's decode rows (128 of them, 8 KV heads of 128, 64-token
+    pages): 48 query heads in a full layer and 72 in a sliding one, whose
+    kernel takes the window's front bound (a first live page per row, the
+    walk starting at its chunk)."""
+    B, KVH, D = 128, 8, 128
+
+    def spec(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pages = _pages(one_chip, KVH, D, False)
+    bound = {} if window is None else {"window": window}
+    _compile_with_kernel(
+        lambda q, k, v, bt, cl, layer: pallas_paged_attention(
+            q, k, v, bt, cl, layer, scale=D ** -0.5, **bound),
+        spec((B, heads, D), jnp.bfloat16), pages, pages,
+        spec((B, tables)), spec((B,)), spec(()))
+
+
+@pytest.mark.parametrize("mode,rows,width,tables", [
+    ("decode", 128, 1, 16), ("prefill", 1, 1024, 16),
+    ("prefill_cached", 1, 1024, 32)])
+def test_laguna_programs_compile_at_the_configurations_widths(
+        one_chip, monkeypatch, tmp_path, mode, rows, width, tables):
+    """``laguna-s-2.1-l8e64`` as the benchmark serves it (the model keys of
+    its file, written to a ``config.json`` as ``chipbench.stack`` does):
+    the three forward programs with the expert layer's counts compile for
+    the v5e, with both attention kernels in them, the weights are the
+    10.1 GB the configuration states, and no copy of an expert stack (or
+    of any other weight) is made on the way to its matmul: a temporary as
+    large as one layer's routed experts would be one."""
+    import json
+    import os
+    import sys
+
+    from production_stack_tpu.models import laguna
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if repo not in sys.path:
+        sys.path.insert(0, repo)
+    from chipbench.registry import model_keys
+
+    with open(os.path.join(repo, "chipbench", "configs",
+                           "laguna-s-2.1-l8e64.json")) as f:
+        (tmp_path / "config.json").write_text(
+            json.dumps(model_keys(json.load(f))))
+    cfg = get_model_config(str(tmp_path))
+    monkeypatch.setattr(att, "_use_pallas", lambda: True)
+
+    def spec(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda x: spec(x.shape, x.dtype),
+        jax.eval_shape(lambda: laguna.init_params(cfg, jax.random.key(0))))
+    weights = sum(int(np.prod(x.shape)) * x.dtype.itemsize
+                  for x in jax.tree_util.tree_leaves(params))
+    assert abs(weights / 10.1e9 - 1) < 0.05
+    pages = spec((cfg.num_layers, NUM_BLOCKS, BLOCK_SIZE, cfg.num_kv_heads,
+                  cfg.head_dim), jnp.bfloat16)
+    last = mode != "decode"
+    program = jax.jit(
+        lambda p, kv, tok, pos, slot, bt, cl, sl: laguna.apply(
+            p, cfg, tok, pos, kv, slot, bt, cl, sl, mode=mode,
+            last_token=jnp.maximum(sl - 1, 0) if last else None,
+            with_stats=True), donate_argnums=(1,)).lower(
+        params, (pages, pages), spec((rows, width)), spec((rows, width)),
+        spec((rows, width)), spec((rows, tables)), spec((rows,)),
+        spec((rows,))).compile()
+    text = program.as_text()
+    assert "tpu_custom_call" in text
+    assert ("pallas_paged_attention" in text) == (mode == "decode")
+    assert ("pallas_prefill_attention" in text) == (mode == "prefill_cached")
+    experts_of_a_layer = 64 * 3072 * 1024 * 2
+    assert program.memory_analysis().temp_size_in_bytes < (
+        experts_of_a_layer if mode == "decode" else 2 * experts_of_a_layer)

@@ -337,3 +337,78 @@ def test_fetch_tokens_follows_the_live_pages(block_size):
     burst = ctx[:, None] + np.arange(8)
     assert fetch_tokens(burst, block_size, width + 1) == sum(
         fetch_tokens(burst[:, s], block_size, width + 1) for s in range(8))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("H", [48, 72])  # query groups 6 and 9
+@pytest.mark.parametrize("MAXB,window", [(16, 40), (16, 64), (8, 100),
+                                         (32, 64)])
+def test_window_front_bound_matches_reference(MAXB, window, H, dtype):
+    """With ``window`` a row's first live token is ``context - window``:
+    the kernel starts its walk at that token's chunk, copies from its
+    page on, and masks what lies before it in that page. One row holds
+    nothing, one fills its table, the rest are ragged; a chunk is 128
+    tokens, so the windows end inside a first chunk whose front pages
+    were not copied."""
+    B, KVH, D, L, bs = 5, 8, 128, 2, 16
+    NB = B * MAXB + 2
+    q, k_pages, v_pages, tables, ctx = _setup(
+        B, H, KVH, D, L, NB, bs, MAXB, seed=H + MAXB)
+    ctx = ctx.at[0].set(0).at[1].set(MAXB * bs)
+    k_pages, v_pages = k_pages.astype(dtype), v_pages.astype(dtype)
+    want = paged_attention_reference(
+        q, k_pages, v_pages, tables, ctx, jnp.int32(1), scale=0.1,
+        window=window)
+    got = pallas_paged_attention(
+        q, k_pages, v_pages, tables, ctx, jnp.int32(1), scale=0.1,
+        interpret=True, window=window)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-3, atol=2e-3)
+    full = paged_attention_reference(
+        q, k_pages, v_pages, tables, ctx, jnp.int32(1), scale=0.1)
+    longer = np.asarray(ctx) > window
+    assert np.abs(np.asarray(full) - np.asarray(want))[longer].max() > 1e-2
+    np.testing.assert_array_equal(np.asarray(full)[~longer],
+                                  np.asarray(want)[~longer])
+
+
+def test_nothing_before_a_window_reaches_the_result():
+    """NaN keys and Inf values in every page before the one that holds a
+    row's first live token (and in every slot past its context): the
+    same finite output, bit for bit. (The tokens before the window in
+    that first page are the row's own, which it attended to a step
+    earlier: they are masked, not zeroed.)"""
+    B, H, KVH, D, L, bs, MAXB, window = 3, 48, 8, 128, 1, 16, 16, 40
+    q, k_pages, v_pages, tables, _ = _setup(B, H, KVH, D, L, B * MAXB, bs,
+                                            MAXB, seed=9)
+    ctx = jnp.asarray([200, 77, 256], jnp.int32)
+    clean = pallas_paged_attention(
+        q, k_pages, v_pages, tables, ctx, jnp.int32(0), scale=0.1,
+        interpret=True, window=window)
+    token = np.arange(MAXB * bs)
+    k_np, v_np = np.array(k_pages), np.array(v_pages)
+    for b in range(B):
+        first_page = max(int(ctx[b]) - window, 0) // bs
+        dead = (token < first_page * bs) | (token >= int(ctx[b]))
+        for t in token[dead]:
+            k_np[0, int(tables[b, t // bs]), t % bs] = np.nan
+            v_np[0, int(tables[b, t // bs]), t % bs] = np.inf
+    dirty = pallas_paged_attention(
+        q, jnp.asarray(k_np), jnp.asarray(v_np), tables, ctx, jnp.int32(0),
+        scale=0.1, interpret=True, window=window)
+    assert np.isfinite(np.asarray(dirty)).all()
+    np.testing.assert_array_equal(np.asarray(dirty), np.asarray(clean))
+
+
+@pytest.mark.parametrize("block_size", [16, 64])
+def test_fetch_tokens_under_a_window(block_size):
+    from production_stack_tpu.ops.pallas_paged_attention import fetch_tokens
+
+    bs, window = block_size, 512
+    contexts = np.array([[0, 1, 512, 513, 700, 4096]])
+    pages = [0, 1, 512 // bs, -(-513 // bs), -(-700 // bs) - 188 // bs,
+             4096 // bs - 3584 // bs]
+    assert fetch_tokens(contexts, bs, 64 * 64 // bs, window) == sum(pages) * bs
+    assert fetch_tokens(contexts, bs, 64 * 64 // bs) == sum(
+        -(-c // bs) for c in contexts[0]) * bs

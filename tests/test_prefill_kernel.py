@@ -448,3 +448,42 @@ def test_fused_scheduler_action_emission():
             if action in ("fused", "prefill_step"):
                 exec_plan(sched, kv, plan)
         assert sched.next_action()[0] == "decode"
+
+
+@pytest.mark.parametrize("group,MAXB,bs,T,window,prefix,take,q_tile,quantized", [
+    (6, 16, 8, 24, 20, [0, 50, 100], [24, 17, 24], 8, False),
+    (9, 16, 8, 24, 40, [5, 33, 104], [24, 17, 20], 8, False),
+    (2, 32, 8, 16, 30, [0, 200, 77], [16, 9, 16], 0, True),
+    (1, 8, 16, 12, 64, [0, 9, 100], [12, 12, 5], 0, False),
+])
+def test_prefill_kernel_window_lower_bound(monkeypatch, group, MAXB, bs, T,
+                                           window, prefix, take, q_tile,
+                                           quantized):
+    """With ``window`` the query at position p sees the keys after
+    ``p - window``, in the cached prefix (a per-row bound in the kernel,
+    chunks that end before a tile's window not computed) and in the
+    chunk's own fresh keys. The kernel, the one-shot reference and the
+    reference's chunked online softmax agree (a query whose window starts
+    past a chunk sees none of it, in either), and all differ from full
+    attention."""
+    B, KVH, D, L = 3, 8, 128, 2
+    s = _setup_prefill(B, T, KVH, group, D, L, B * MAXB + 2, bs, MAXB,
+                       prefix=prefix, take=take, seed=3, quantized=quantized)
+    args = (s["q"], s["k_pages"], s["v_pages"], s["tables"], s["positions"],
+            s["total"], s["layer"])
+    want = context_prefill_attention(*args, scale=0.09, window=window)
+    got = pallas_prefill_attention(
+        *args, s["k_new"], s["v_new"], s["take"], scale=0.09, interpret=True,
+        window=window, **({"q_tile": q_tile} if q_tile else {}))
+    monkeypatch.setattr(att, "_CHUNKED_SCORE_BYTES", 1)
+    monkeypatch.setattr(att, "_CHUNKED_SCORE_SPAN", 32)
+    chunked = context_prefill_attention(*args, scale=0.09, window=window)
+    live = np.arange(T)[None, :] < np.asarray(s["take"])[:, None]
+    for other in (got, chunked):
+        np.testing.assert_allclose(np.asarray(other)[live],
+                                   np.asarray(want)[live],
+                                   rtol=2e-3, atol=2e-3)
+    assert np.isfinite(np.asarray(got)[live]).all()
+    monkeypatch.undo()
+    full = context_prefill_attention(*args, scale=0.09)
+    assert np.abs(np.asarray(full) - np.asarray(want))[live].max() > 0.1

@@ -306,16 +306,22 @@ async def test_two_worker_smoke_aggregated_scrape_and_teardown(
         env=dict(os.environ, TPU_STACK_LOG_LEVEL="warning",
                  TMPDIR=str(short_tmp_path)))
     try:
+        # Healthy is both workers answering the fan-in: the port answers
+        # as soon as one of them listens, before the other's socket is up.
         deadline = time.monotonic() + LIVENESS_S
         while True:
             try:
                 await asyncio.to_thread(_get, rurl + "/health", 2.0)
-                break
+                ready = json.loads(await asyncio.to_thread(
+                    _get, rurl + "/debug/workers", 2.0))
+                if len(ready["per_worker"]) == 2:
+                    break
             except OSError:
-                if time.monotonic() > deadline:
-                    raise RuntimeError(
-                        "2-worker router never became healthy") from None
-                await asyncio.sleep(0.2)
+                pass
+            if time.monotonic() > deadline:
+                raise RuntimeError(
+                    "2-worker router never became healthy") from None
+            await asyncio.sleep(0.2)
 
         assert len(list(short_tmp_path.glob("tpu-router-workers-*"))) == 1
         n_requests = 4
