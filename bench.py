@@ -72,10 +72,10 @@ _CONFIGS = {
     # quantize_embeddings: random-init bench weights make head quality
     # moot, and the ~1 GB embed/lm_head saving is what keeps the pool
     # off the OOM edge (real checkpoints on roomier chips should prefer
-    # the bf16-head default). prefill_batch=1: the 4-wide batched
-    # prefill programs add multi-GB activation/compile footprint that an
-    # 8 B model within ~1 GB of the 16 GB chip cannot afford (measured:
-    # all three round-5 attempts OOM'd at warmup with them on).
+    # the bf16-head default). prefill_batch=1: grouped prefill programs
+    # add activation/compile footprint that an 8 B model within ~1 GB of
+    # the 16 GB chip cannot afford (measured with round 5's 4-wide cached
+    # programs: all three attempts OOM'd at warmup with them on).
     "llama8b": dict(model="meta-llama/Llama-3-8B", users=15, rounds=6,
                     answer_tokens=100, sys_prompt_tokens=1000,
                     history_tokens=2000, max_model_len=8192,
@@ -629,10 +629,10 @@ async def _main(spec_tokens: int = SPEC,
         quantize_embeddings=bool(_cfg.get("quantize_embeddings", False)),
         prefill_chunk_size=_env_int(
             "BENCH_PREFILL_CHUNK", _cfg.get("prefill_chunk", 1024)),
-        # Storm-scoped batched prefill (round 5). BENCH_PREFILL_BATCH=1
-        # skips its warmup variants (CI's CPU smoke does: parity is
-        # covered by tests/test_prefill_batch.py, and 5 extra 1B-model
-        # compiles on a 1-core runner are minutes).
+        # Same-bucket plain-prefill groups. BENCH_PREFILL_BATCH=1 skips
+        # their warmup variants (CI's CPU smoke does: parity is covered
+        # by tests/test_prefill_batch.py, and 7 extra 1B-model compiles
+        # on a 1-core runner are minutes).
         prefill_batch=_env_int(
             "BENCH_PREFILL_BATCH", _cfg.get("prefill_batch", 4)),
         enable_chunked_prefill=bool(CHUNKED),

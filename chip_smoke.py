@@ -47,11 +47,13 @@ class Plan:
     in tests/test_chip_smoke.py steers a tiny model through the same code."""
     model: str = "tpu-llama-3b"
     platform: str = "tpu"
-    # The server's flag defaults are max_model_len 2048 and prefill_batch 1;
-    # the model's full context and the batched-prefill path are asked for.
+    # The server's flag default is max_model_len 2048; the model's full
+    # context is asked for, and the prefill group size stated.
     engine_flags: tuple = ("--max-model-len", "8192", "--prefill-batch", "4")
     long_prompt_tokens: int = 1500
-    batch_prompt_tokens: int = 1000
+    # Four of these wait together: one rung with a group of four
+    # (EngineConfig.prefill_group_rows), so one [4, 512] plain prefill.
+    batch_prompt_tokens: int = 500
     max_tokens: int = 32
     interpret: bool = False  # Pallas kernels in interpret mode
     kernel_path: str = "pallas"  # the path the engine must have taken
@@ -318,13 +320,31 @@ async def drive_requests(stack: Stack) -> None:
         check(done and "".join(pieces) != "", "empty or unfinished stream")
         emit("chat_stream", chunks=len(pieces), done=done)
 
-        outs = await asyncio.gather(*[
-            stack.complete(session,
-                           make_prompt(plan.batch_prompt_tokens, f"b{i}"))
-            for i in range(4)])
-        emit("concurrent", requests=len(outs),
+        # Four uncached prompts of one rung, held back until all of them
+        # wait (the loop takes the first and stops at the step): they run
+        # as one group. Each is then sent again alone, which reads the
+        # pages the group wrote through the cached program.
+        prompts = [make_prompt(plan.batch_prompt_tokens, f"b{i}")
+                   for i in range(4)]
+        core = stack.core
+        rows0 = core.stats()["prefill_group_rows"]
+        with core._step_lock:
+            tasks = [asyncio.ensure_future(stack.complete(session, p))
+                     for p in prompts]
+            while core.scheduler.num_waiting < len(prompts) - 1:
+                await asyncio.sleep(0.01)
+        outs = await asyncio.gather(*tasks)
+        rows = core.stats()["prefill_group_rows"] - rows0
+        alone = [compare_greedy(out, await stack.complete(session, p),
+                                "a group's row against its repeat")
+                 for out, p in zip(outs, prompts)]
+        emit("concurrent", requests=len(outs), prefill_group_rows=rows,
              completion_tokens=[o["usage"]["completion_tokens"]
-                                for o in outs])
+                                for o in outs],
+             equal_prefix=[c["equal_prefix"] for c in alone],
+             max_logprob_diff=max(c["max_logprob_diff"] for c in alone))
+        check(rows == len(prompts),
+              f"{rows} prompts prefilled in a group, not {len(prompts)}")
 
 
 async def engine_counters(stack: Stack, n_generations: int) -> None:
@@ -395,7 +415,7 @@ async def one_chip(plan: Plan, cache_dir: str, cache_cold: bool) -> None:
         await stack.start()
         emit_engine_start(stack, cache_dir, cache_cold)
         await drive_requests(stack)
-        await engine_counters(stack, n_generations=7)
+        await engine_counters(stack, n_generations=11)
         stats = jax.devices()[0].memory_stats() or {}
         emit("device_memory",
              peak_bytes_in_use=stats.get("peak_bytes_in_use"),

@@ -15,6 +15,15 @@ from typing import Optional
 # the lane width: a narrower step buys no device time.
 PLAIN_PREFILL_FINE_FROM = 256
 PLAIN_PREFILL_STEP = 128
+# Rows of a plain-prefill group, largest first: a rung has one of them or
+# none (``EngineConfig.prefill_group_rows``), so a group is never padded
+# with empty rows (at a rung of 2, 3 prompts go as 2 + 1).
+PREFILL_GROUP_SIZES = (4, 2)
+# Every (rows, rung) of a group is one more compiled program, and the bound
+# is the room for those: on the benchmark's machine, which keeps 192 MiB of
+# compiled programs, the Laguna cell's fill 195 of the 201.3 MB with four
+# (3.9 MB each; with seven, every run compiled everything: PERF.md, PR 35).
+PREFILL_GROUP_PROGRAMS = 4
 
 
 @dataclasses.dataclass
@@ -62,21 +71,16 @@ class EngineConfig:
     # decoding; after that the next step is forced to decode (the
     # decode-starvation cap). Only meaningful with chunked prefill.
     max_consecutive_prefills: int = 2
-    # Up to this many long-prompt prefills share one [prefill_batch,
-    # chunk] dispatch (the arrival-storm TTFT tail is a QUEUE of
-    # first-round prefills). Round 4 measured always-on batching
-    # throughput-neutral with WORSE p50 at steady state (padded rows
-    # waste chunk-width compute when the queue is shallow), so batching
-    # is storm-scoped: it only engages when at least
-    # ``prefill_batch_min_waiting`` other qualifying long prompts are
-    # queued — exactly the arrival-storm condition that serializes
-    # first-round prefills into the p99 TTFT tail. 1 disables; requires
-    # chunking.
+    # The largest group of a plain prefill: uncached one-span prompts of
+    # one plain-ladder rung that wait together run as ONE [R, rung]
+    # ``prefill`` program, R of ``PREFILL_GROUP_SIZES`` (see
+    # ``prefill_group_rows``), so the weights are read once for R
+    # prompts. A group pads no row and no more than its members do alone,
+    # so it costs a compute-bound model the same operations and saves a
+    # weight-bound one its weight reads. 1 disables. (The chunked-prefill
+    # step plan, ``enable_chunked_prefill``, sends up to this many rows
+    # through one [prefill_batch, chunk] ``prefill_cached`` dispatch.)
     prefill_batch: int = 4
-    # The storm gate: batch only when this many OTHER qualifying
-    # (long, uncached-span) prompts are waiting. 0 = batch whenever a
-    # group can form (round-4 always-on behavior).
-    prefill_batch_min_waiting: int = 2
     # Fused step program: when the chunked-prefill scheduler has BOTH a
     # prefill plan and running decodes, execute the prefill chunk(s) and
     # the decode burst as ONE dispatch (the device runs the already-
@@ -286,6 +290,31 @@ class EngineConfig:
                          self.max_prefill_span, PLAIN_PREFILL_STEP)
             buckets = sorted(set(buckets).union(fine))
         return buckets
+
+    def prefill_group_rows(self, rung: int) -> int:
+        """The rows of a plain-prefill group at this rung of the plain
+        ladder, or 0 where prompts go alone. A rung qualifies with the
+        largest of ``PREFILL_GROUP_SIZES`` within ``prefill_batch`` whose
+        ``[R, rung]`` holds more tokens than a chunk (at a chunk or under, a
+        dense model gained nothing: on a v5e, Mistral-7B's [2, 384] took
+        1.06 of two [1, 384], every wider group 0.84-0.93 of its singles)
+        and no more than two chunks (the f32 scores ``R x rung^2`` then at
+        most double a single chunk's, which the engine's headroom holds).
+        The ``PREFILL_GROUP_PROGRAMS`` shortest such rungs have a group:
+        at the default chunk of 1024, 4 rows at rungs 384 and 512 and 2 at
+        640 and 768; 896 and 1024 qualify, and wait for room."""
+        chunk = self.prefill_chunk_size
+        table: dict = {}
+        for b in self.prefill_buckets(plain=True):
+            if len(table) == PREFILL_GROUP_PROGRAMS or b > min(
+                    rung, self.max_prefill_span):
+                break
+            rows = next((r for r in PREFILL_GROUP_SIZES
+                         if r <= self.prefill_batch
+                         and chunk < r * b <= 2 * chunk), 0)
+            if rows:
+                table[b] = rows
+        return table.get(rung, 0)
 
     def bucket_for(self, length: int, plain: bool = False) -> int:
         for b in self.prefill_buckets(plain):

@@ -523,15 +523,15 @@ class EngineCore:
         self.prompt_tokens_total = 0
         self.cached_tokens_total = 0  # prompt tokens skipped via prefix cache
         # Token positions the prefill programs computed on: spans padded to
-        # their bucket (and batched groups to [prefill_batch, chunk]).
+        # their bucket (and the chunked step plan's rows to
+        # [prefill_batch, chunk]).
         self.prefill_padded_tokens_total = 0
         self.kv_fetch_tokens_total = 0  # copied by the decode kernel
         self.generation_tokens_total = 0
         self.requests_finished_total = 0
         self.step_count = 0
-        # Storm-scoped batched prefills: groups dispatched / prompts they
-        # carried (tail-latency diagnosis needs to know whether the storm
-        # path actually engaged).
+        # Plain-prefill groups dispatched / prompts they carried (whether
+        # same-rung prompts met in the queue: _do_prefill_group).
         self.prefill_group_count = 0
         self.prefill_group_rows = 0
         # Chunked prefill: chunks dispatched, prompt tokens deferred to a
@@ -629,6 +629,15 @@ class EngineCore:
         _counts_shape = (config.max_num_seqs, self.model_config.vocab_size)
         self._token_counts = jax.jit(
             lambda: jnp.zeros(_counts_shape, jnp.int32),
+            out_shardings=self._repl)()
+        # What a burst with no burst before it feeds back: zeros placed
+        # like a burst's own tokens, so that the first burst and warm-up
+        # run the program every later burst runs (a host array here was a
+        # second compiled variant per table width).
+        self._no_burst_tokens = jax.jit(
+            lambda: jnp.zeros(
+                (config.max_num_seqs, max(config.decode_steps, 1)),
+                jnp.int32),
             out_shardings=self._repl)()
         # Slots whose counts row must reset at the next burst (set when a
         # prefill lands in the slot; consumed by _do_decode).
@@ -907,8 +916,8 @@ class EngineCore:
                 # Structured output: grammar FSM mask (packed bitset rows;
                 # all-off for unconstrained sequences).
                 shaped = apply_fsm_mask(shaped, mask_bits, mask_on)
-                keys = make_rng_keys(
-                    seed_static, steps.max(), seq_seeds + steps)
+                # per row, so a row of a group samples as it would alone
+                keys = make_rng_keys(seed_static, steps, seq_seeds + steps)
                 sampled = sample_tokens(
                     shaped, keys, temperature, top_k, top_p,
                     max_top_k=max_top_k)
@@ -1300,13 +1309,11 @@ class EngineCore:
             K = static["K"]
             fn = self._multi_decode_fn(K)
             self._steps.note_program(fn.__name__)
-            B = self.config.max_num_seqs
             # Feedback tokens always carry the FULL decode_steps width
             # (bursts pad their output) so adaptive widths share shapes.
-            K_max = max(self.config.decode_steps, 1)
             tokens_prev = (
                 self._last_burst_tokens if static["use_prev"]
-                else np.zeros((B, K_max), np.int32))
+                else self._no_burst_tokens)
             outs, self.kv, self._token_counts = fn(
                 self.params, self.kv, self._token_counts, arrays[0],
                 tokens_prev, *arrays[1:])
@@ -1840,10 +1847,9 @@ class EngineCore:
         what warm-up compiles it with, and what a caller that wants the
         compiled program's text lowers it with."""
         B = self.config.max_num_seqs
-        K_full = max(self.config.decode_steps, 1)
         return (
             np.ones((B,), bool),         # reset_counts (warmup)
-            np.zeros((B, K_full), np.int32),  # tokens_prev
+            self._no_burst_tokens,       # tokens_prev
             np.zeros((B,), np.int32),    # tok_idx
             np.zeros((B,), np.int32),    # host_tokens
             np.ones((B,), bool),         # use_host
@@ -1877,39 +1883,44 @@ class EngineCore:
             top = cfg.bucket_for(cfg.max_prefill_span)
             cached_buckets = set(cfg.prefill_buckets())
             n_prefill = 0
+
+            def operands(rows: int, bucket: int, table: int) -> tuple:
+                """Dummy host operands of a prefill program at [rows,
+                bucket] and this table width, typed as serving's."""
+                return (
+                    np.zeros((rows, bucket), np.int32),
+                    np.tile(np.arange(bucket, dtype=np.int32), (rows, 1)),
+                    np.full((rows, bucket), -1, np.int64),
+                    np.zeros((rows, table), np.int32),
+                    np.full((rows,), min(bucket, 2), np.int32),
+                    np.full((rows,), min(bucket, 2), np.int32),
+                    np.zeros((rows,), np.int32),
+                    np.zeros((rows,), np.float32),
+                    np.zeros((rows,), np.int32),
+                    np.ones((rows,), np.float32),
+                    np.zeros((rows,), np.int64),
+                    np.ones((rows,), np.int64), np.zeros((rows,), bool),
+                    np.zeros((rows, MAX_LOGIT_BIAS), np.int32),
+                    np.zeros((rows, MAX_LOGIT_BIAS), np.float32),
+                    np.zeros((rows, MAX_STOP_IDS), np.int32),
+                    np.zeros((rows, MAX_STOP_IDS), np.float32),
+                    np.zeros((rows, self._mask_row_bytes), np.uint8),
+                    np.zeros((rows,), bool))
+
             for bucket in cfg.prefill_buckets(plain=True):
                 if bucket > top:
                     break
-                blocks_needed = (bucket + cfg.block_size - 1) // cfg.block_size
-                tight = 4
-                while tight < blocks_needed:
-                    tight *= 2
-                tight = min(tight, cfg.max_blocks_per_seq)
-                token_arr = np.zeros((1, bucket), np.int32)
-                positions = np.tile(
-                    np.arange(bucket, dtype=np.int32), (1, 1))
-                slot_mapping = np.full((1, bucket), -1, np.int64)
-                context_lens = np.asarray([min(bucket, 2)], np.int32)
-                seq_lens = np.asarray([min(bucket, 2)], np.int32)
-                adapter_ids = np.zeros((1,), np.int32)
-                samp = (np.zeros((1,), np.float32), np.zeros((1,), np.int32),
-                        np.ones((1,), np.float32), np.zeros((1,), np.int64),
-                        np.ones((1,), np.int64), np.zeros((1,), bool),
-                        np.zeros((1, MAX_LOGIT_BIAS), np.int32),
-                        np.zeros((1, MAX_LOGIT_BIAS), np.float32),
-                        np.zeros((1, MAX_STOP_IDS), np.int32),
-                        np.zeros((1, MAX_STOP_IDS), np.float32),
-                        np.zeros((1, self._mask_row_bytes), np.uint8),
-                        np.zeros((1,), bool))
                 # Plain prefill only ever sees context == span -> one tight
                 # table width per bucket, so its ladder can afford the finer
-                # rungs of prefill_buckets(plain=True).
-                _, self.kv = self._prefill_fn(
-                    self.params, self.kv, token_arr, positions,
-                    slot_mapping, np.zeros((1, tight), np.int32),
-                    context_lens, seq_lens, adapter_ids, *samp,
-                )
-                n_prefill += 1
+                # rungs of prefill_buckets(plain=True), and the [R, rung]
+                # programs of a group (serving reaches those only with R
+                # same-rung prompts waiting: no warm prompt does).
+                tight = self._table_width(bucket)
+                for rows in sorted({1, cfg.prefill_group_rows(bucket)} - {0}):
+                    _, self.kv = self._prefill_fn(
+                        self.params, self.kv,
+                        *operands(rows, bucket, tight))
+                    n_prefill += 1
                 if bucket not in cached_buckets:
                     continue
                 # Cached prefill: context (and so the table bucket) can be
@@ -1917,46 +1928,25 @@ class EngineCore:
                 maxb = tight
                 while True:
                     _, self.kv = self._prefill_cached_fn(
-                        self.params, self.kv, token_arr, positions,
-                        slot_mapping, np.zeros((1, maxb), np.int32),
-                        context_lens, seq_lens, adapter_ids, *samp,
-                    )
+                        self.params, self.kv, *operands(1, bucket, maxb))
                     n_prefill += 1
                     if maxb >= cfg.max_blocks_per_seq:
                         break
                     maxb *= 2
-            # Batched prefill ([prefill_batch, chunk] cached rows): one
-            # variant per reachable block-table width.
-            if cfg.prefill_batch > 1 and cfg.prefill_chunk_size > 0:
-                R = cfg.prefill_batch
+            # The chunked step plan's rows ([prefill_batch, chunk] cached):
+            # one variant per reachable block-table width, where that plan
+            # can run.
+            if (cfg.chunked_prefill_enabled and cfg.prefill_batch > 1
+                    and cfg.prefill_chunk_size > 0):
                 pb_bucket = cfg.bucket_for(
                     min(cfg.prefill_chunk_size, cfg.max_model_len))
-                samp_r = (np.zeros((R,), np.float32),
-                          np.zeros((R,), np.int32),
-                          np.ones((R,), np.float32),
-                          np.zeros((R,), np.int64),
-                          np.ones((R,), np.int64), np.zeros((R,), bool),
-                          np.zeros((R, MAX_LOGIT_BIAS), np.int32),
-                          np.zeros((R, MAX_LOGIT_BIAS), np.float32),
-                          np.zeros((R, MAX_STOP_IDS), np.int32),
-                          np.zeros((R, MAX_STOP_IDS), np.float32),
-                          np.zeros((R, self._mask_row_bytes), np.uint8),
-                          np.zeros((R,), bool))
                 maxb_b = 4
                 maxb_cap = self._prefill_batch_maxb()
                 while True:
                     maxb_b = min(maxb_b, maxb_cap)
                     _, self.kv = self._prefill_cached_fn(
                         self.params, self.kv,
-                        np.zeros((R, pb_bucket), np.int32),
-                        np.tile(np.arange(pb_bucket, dtype=np.int32),
-                                (R, 1)),
-                        np.full((R, pb_bucket), -1, np.int64),
-                        np.zeros((R, maxb_b), np.int32),
-                        np.full((R,), 2, np.int32),
-                        np.full((R,), 2, np.int32),
-                        np.zeros((R,), np.int32), *samp_r,
-                    )
+                        *operands(cfg.prefill_batch, pb_bucket, maxb_b))
                     n_prefill += 1
                     if maxb_b >= maxb_cap:
                         break
@@ -2561,12 +2551,11 @@ class EngineCore:
                         if pc.req in self.scheduler.prefilling:
                             self.scheduler.prefilling.remove(pc.req)
                             self.kv_mgr.free(pc.req.request_id)
-                            self.scheduler._requests.pop(
-                                pc.req.request_id, None)
+                            self.scheduler.drop(pc.req)
                             failed_reqs.append(pc.req)
             elif action == "prefill" and req is not None:
                 with self._lock:
-                    self.scheduler._requests.pop(req.request_id, None)
+                    self.scheduler.drop(req)
                 failed_reqs.append(req)
             for r in failed_reqs:
                 r.on_token(None, "error")
@@ -2692,28 +2681,16 @@ class EngineCore:
             req.trace.cached_tokens = cached
             req.trace.preemptions = req.num_preemptions
 
-        # Big uncached spans batch with other waiting long prompts: the
-        # arrival-storm TTFT tail is a QUEUE of first-round prefills, and
-        # one [PB, chunk] dispatch drains PB of them per chunk-time
-        # instead of one (see _do_prefill_group). Contexts wider than
-        # _prefill_batch_maxb() blocks stay on the single path — the
-        # batched cached-attention temp is PB x chunk x context x heads
-        # in f32 and must stay bounded. STORM-SCOPED (round 5): batching
-        # engages only when the waiting queue holds enough other
-        # qualifying long prompts — at steady state the single pipelined
-        # path has better p50, during the storm the batch drains the
-        # serial-prefill queue that round 4 measured as the whole p99
-        # TTFT tail.
-        chunk = cfg.prefill_chunk_size
-        if (cfg.prefill_batch > 1 and chunk > 0
-                and n - cached >= max(chunk // 2, 1)
-                and ((n + cfg.block_size - 1) // cfg.block_size
-                     <= self._prefill_batch_maxb())
-                and (self._qualifying_waiting()
-                     >= cfg.prefill_batch_min_waiting)):
-            group = self._gather_prefill_group(req, block_ids, cached)
-            if len(group) > 1:
-                self._do_prefill_group(group)
+        # An uncached one-span prompt takes the plain program: run it with
+        # the waiting prompts of its rung in one [R, rung] dispatch, which
+        # reads the weights once for all of them (_do_prefill_group).
+        if cached == 0 and n <= cfg.max_prefill_span:
+            rung = cfg.bucket_for(n, plain=True)
+            rows = cfg.prefill_group_rows(rung)
+            group = rows and self._gather_prefill_group(
+                req, block_ids, rung, rows)
+            if group:
+                self._do_prefill_group(group, rung)
                 return
 
         # Only the un-cached suffix runs through the model; its queries
@@ -2992,6 +2969,7 @@ class EngineCore:
         pending, self._pending_prefills = self._pending_prefills, []
         keep: "list[dict]" = []
         steps = self._steps
+        read_of = read = None
         with steps.phase("emit"):
             for entry in pending:
                 sampled = entry["sampled"]
@@ -3006,10 +2984,12 @@ class EngineCore:
                 req, seq, slot = entry["req"], entry["seq"], entry["slot"]
                 row_i = entry.get("row", 0)  # batched prefills: row per req
                 try:
-                    with steps.phase("readback"):
-                        s_arr, lp_arr, top_lp_arr, top_id_arr = (
-                            np.asarray(a)
-                            for a in jax.device_get(_unwrap_fused(sampled)))
+                    if sampled is not read_of:  # a group's: read once
+                        with steps.phase("readback"):
+                            read = [np.asarray(a) for a in jax.device_get(
+                                _unwrap_fused(sampled))]
+                        read_of = sampled
+                    s_arr, lp_arr, top_lp_arr, top_id_arr = read
                 except Exception:  # noqa: BLE001 - async device failure
                     # The deferred readback failed AFTER the dispatch
                     # succeeded: the request would otherwise hang with its
@@ -3086,158 +3066,133 @@ class EngineCore:
             i += bs
         return i
 
-    def _qualifying_waiting(self) -> int:
-        """How many WAITING requests would qualify for a prefill batch
-        row right now — the storm signal for storm-scoped batching. The
-        qualifier is the UNCACHED span, not total length: at a ~97%
-        hit rate every follow-up round is long-but-cached, and counting
-        those opened the gate at steady state, padding chunk-wide rows
-        for tiny suffixes (measured as a p50/p99 TTFT regression)."""
-        cfg = self.config
-        chunk = cfg.prefill_chunk_size
-        maxb_cap = self._prefill_batch_maxb()
-        with self._lock:
-            n = 0
-            for cand in self.scheduler.live_waiting():
-                toks = cand.all_token_ids
-                if ((len(toks) + cfg.block_size - 1)
-                        // cfg.block_size) > maxb_cap:
-                    continue
-                cached = self._cached_prefix_len(toks, cand.adapter_name)
-                if len(toks) - cached >= max(chunk // 2, 1):
-                    n += 1
-            return n
+    def _table_width(self, tokens: int, cap: Optional[int] = None) -> int:
+        """The block-table bucket of a context of ``tokens``: the power of
+        two, from 4, that holds its pages, within ``cap`` (the model's
+        longest table by default). A plain prefill's context is its span,
+        so its bucket has the one width of its ``tokens``."""
+        blocks_needed = -(-tokens // self.config.block_size)
+        width = 4
+        while width < blocks_needed:
+            width *= 2
+        return min(width, cap or self.config.max_blocks_per_seq)
 
     def _prefill_batch_maxb(self) -> int:
-        """Widest block table the batched-prefill programs compile (64
-        blocks = 4k-token contexts at the default page size): bounds the
-        PB-row cached-attention f32 temp at warmup and serving time."""
+        """Widest block table the chunked step plan's [prefill_batch,
+        chunk] cached programs compile (64 blocks = 4k-token contexts at
+        the default page size): bounds the PB-row cached-attention f32
+        temp at warmup and serving time."""
         return min(64, self.config.max_blocks_per_seq)
 
     def _gather_prefill_group(self, req: EngineRequest, block_ids,
-                              cached: int) -> "list[dict]":
-        """Collect up to prefill_batch long-prompt requests (the head
-        request plus qualifying waiters) that can be admitted NOW —
-        free slot counted per member, KV allocated eagerly. Members that
-        fail allocation are requeued by _allocate_for_prefill."""
+                              rung: int, rows: int) -> "list[dict]":
+        """The head request (uncached, one span, allocated) and ``rows`` -
+        1 waiting requests that can share its plain prefill NOW: uncached
+        one-span prompts of the same plain-ladder ``rung``, in the order
+        the scheduler would serve them, where free slots and the pool hold
+        them all. ``rows`` is the one compiled row count of the rung, so
+        it is the whole group or none ([]): no row is ever padding, and
+        nobody waits for a mate. Members leave the waiting queue with
+        their KV allocated."""
         cfg = self.config
-        chunk = cfg.prefill_chunk_size
-        group = [{"req": req, "block_ids": block_ids, "cached": cached}]
-        # Candidates already walked and rejected this gather: the slot
-        # loop rescans the deque, and re-hashing a 3k-token prompt's
-        # chain per slot would stack milliseconds of host work onto the
-        # storm path this feature exists to shorten.
-        rejected: set = set()
-        while len(group) < cfg.prefill_batch:
-            with self._lock:
-                free_slots = sum(
-                    1 for s in self.scheduler.slots if s is None)
-                if free_slots <= len(group):  # head + members need slots
+        lo = max((b for b in cfg.prefill_buckets(plain=True) if b < rung),
+                 default=0)
+        mates = []
+        bs = cfg.block_size
+        with self._lock:
+            if sum(1 for s in self.scheduler.slots if s is None) < rows:
+                return []
+            need = 0  # tokens of the mates' pages (the head has its own)
+            # Members share no first page: the second would find the
+            # first's registered and be a cached prompt after all.
+            firsts = {(req.adapter_name, tuple(req.all_token_ids[:bs]))}
+            for cand in sorted(self.scheduler.live_waiting(),
+                               key=lambda r: r.priority):
+                n_c = len(cand.prompt_token_ids) + len(cand.output_token_ids)
+                if not lo < n_c <= rung:
+                    continue
+                tokens_c = cand.all_token_ids
+                first = (cand.adapter_name, tuple(tokens_c[:bs]))
+                if first in firsts or self._cached_prefix_len(
+                        tokens_c, cand.adapter_name):
+                    continue
+                firsts.add(first)
+                mates.append(cand)
+                need += -(-(n_c + 1) // bs) * bs
+                if len(mates) == rows - 1:
                     break
-                nxt = None
-                maxb_cap = self._prefill_batch_maxb()
-                for cand in self.scheduler.live_waiting():
-                    if cand.request_id in rejected:
-                        continue
-                    n_c = len(cand.all_token_ids)
-                    # Long UNCACHED span only (short/cached follow-ups
-                    # would waste a chunk-wide row): estimate the cached
-                    # prefix with a read-only chain walk — exact at
-                    # selection time; allocation below re-derives it
-                    # authoritatively.
-                    blocks_c = (n_c + self.config.block_size - 1) \
-                        // self.config.block_size
-                    if blocks_c > maxb_cap:
-                        rejected.add(cand.request_id)
-                        continue
-                    cached_c = self._cached_prefix_len(
-                        cand.all_token_ids, cand.adapter_name)
-                    if n_c - cached_c >= max(chunk // 2, 1):
-                        nxt = cand
-                        break
-                    rejected.add(cand.request_id)
-                if nxt is None:
+            if len(mates) < rows - 1 or not self.kv_mgr.can_allocate(need):
+                return []
+            # Mates leave the queue only once all of them hold their pages:
+            # where one cannot (the pool, or a prefix that came to be
+            # cached meanwhile), all stay where they wait, pages unwritten.
+            held = []
+            for cand in mates:
+                got = self.kv_mgr.allocate_prompt(
+                    cand.request_id, cand.all_token_ids,
+                    adapter=cand.adapter_name)
+                if got is None:
                     break
-                self.scheduler.take_waiting(nxt)
-            got = self._allocate_for_prefill(nxt)
-            if got is None:
-                break  # pool tight: nxt was requeued; stop growing
-            bids_c, cached_c = got
-            if len(nxt.all_token_ids) - cached_c < max(chunk // 2, 1):
-                # Cache-hit: its span is short — release the allocation
-                # and requeue; the single-row path re-allocates next loop
-                # iteration, re-hitting the prefix cache cheaply.
-                self.kv_mgr.free(nxt.request_id)
-                with self._lock:
-                    self.scheduler.requeue(nxt)
-                break
-            group.append(
-                {"req": nxt, "block_ids": bids_c, "cached": cached_c})
-        return group
+                held.append((cand, got))
+                if got[1]:
+                    break
+            whole = len(held) == rows - 1 and not held[-1][1][1]
+            for cand, got in held:
+                if whole:
+                    self.scheduler.take_waiting(cand)
+                else:
+                    self.kv_mgr.free_unwritten(cand.request_id, got[2])
+        self._drain_offload()
+        if not whole:
+            return []
+        return [{"req": req, "block_ids": block_ids}] + [
+            {"req": cand, "block_ids": got[0]} for cand, got in held]
 
-    def _do_prefill_group(self, group: "list[dict]") -> None:
-        """Batched prefill: every member's chunk si rides ONE [PB, chunk]
-        dispatch (rows beyond the live members are padding — seq_lens 0,
-        page writes dropped). Shared prefixes across members are correct
-        within a dispatch because every layer writes all rows' K/V pages
-        before attention reads them. Each member's first token comes from
-        its LAST chunk's dispatch (per-row sampled), deferred like the
-        single-row path."""
-        cfg = self.config
-        chunk = cfg.prefill_chunk_size
+    def _do_prefill_group(self, group: "list[dict]", rung: int) -> None:
+        """One plain prefill over the group's prompts, a row each: the
+        ``prefill`` program at [len(group), rung]. Rows do not mix (no
+        reduction runs across them), so each member gets the tokens and
+        pages of the single path; its first token is its row of the
+        sample, read back deferred like a single prefill's."""
         self.prefill_group_count += 1
         self.prefill_group_rows += len(group)
-        logger.info("Storm prefill batch engaged: %d prompts in one "
-                    "[%d, %d] dispatch chain", len(group),
-                    cfg.prefill_batch, chunk)
-        spans: "dict[int, list]" = {}
-        group_start = time.time()
-        for m in group:
+        now = time.time()
+        for m in group[1:]:  # the head's trace: _do_prefill and the loop
             tr = m["req"].trace
             if tr is not None:
                 if not tr.prefill_start:
-                    tr.prefill_start = group_start
-                tr.cached_tokens = m["cached"]
+                    tr.prefill_start = now
+                tr.cached_tokens = 0
                 tr.preemptions = m["req"].num_preemptions
-            n_m = len(m["req"].all_token_ids)
-            s_list = []
-            start = m["cached"]
-            while start < n_m:
-                end = min(start + chunk, n_m)
-                s_list.append((start, end))
-                start = end
-            spans[id(m)] = s_list
-        max_spans = max(len(s) for s in spans.values())
-        finished = []  # (member, sampled ref, row)
-        for si in range(max_spans):
-            rows = [m for m in group if si < len(spans[id(m)])]
+        try:
             sampled = self._prefill_rows(
-                [(m["req"], m["req"].all_token_ids, m["block_ids"],
-                  *spans[id(m)][si]) for m in rows],
-                pad_to=cfg.prefill_batch)
-            for row_i, m in enumerate(rows):
-                if si == len(spans[id(m)]) - 1:
-                    finished.append((m, sampled, row_i))
+                [(m["req"], m["req"].all_token_ids, m["block_ids"], 0,
+                  len(m["req"].all_token_ids)) for m in group],
+                plain_rung=rung)
+        except Exception:
+            # The loop fails the head; its mates fail with it.
+            for m in group[1:]:
+                with self._lock:
+                    self.kv_mgr.free_unwritten(m["req"].request_id)
+                    self.scheduler.drop(m["req"])
+                m["req"].on_token(None, "error")
+            raise
+        new_tokens = sum(len(m["req"].all_token_ids) for m in group)
+        self._step_info = {
+            "kind": "prefill", "rows": len(group), "tokens": new_tokens,
+            "forwards": 1, "kv_read_tokens": 0,
+            "kv_write_tokens": new_tokens, "batched": True,
+        }
         # Same pipelining as the single path: settle the in-flight burst
         # and the previous prefill while the group executes on device.
         self._flush_pending_burst()
         self._flush_pending_prefills()
-        group_end = time.time()
-        new_tokens = sum(
-            len(m["req"].all_token_ids) - m["cached"] for m in group)
-        self._step_info = {
-            "kind": "prefill", "rows": len(group),
-            "tokens": new_tokens, "forwards": max_spans,
-            "kv_read_tokens": sum(
-                s for s_list in spans.values() for (s, _e) in s_list),
-            "kv_write_tokens": new_tokens, "batched": True,
-        }
-        for m, sampled, row in finished:
+        now = time.time()
+        self.prompt_tokens_total += new_tokens
+        for row, m in enumerate(group):
             req_m = m["req"]
-            if req_m.trace is not None:
-                req_m.trace.prefill_end = group_end
-            self.prompt_tokens_total += len(req_m.all_token_ids)
-            self.cached_tokens_total += m["cached"]
+            if row and req_m.trace is not None:
+                req_m.trace.prefill_end = now
             with self._lock:
                 slot = self.scheduler._free_slot()
                 seq = self.scheduler.start_running(req_m, slot)
@@ -3245,23 +3200,25 @@ class EngineCore:
                 {"req": req_m, "seq": seq, "slot": slot,
                  "sampled": sampled, "row": row})
 
-    def _prefill_rows(self, rows, pad_to: int):
+    def _prefill_rows(self, rows, pad_to: int = 0, plain_rung: int = 0):
         """One batched prefill dispatch: rows = [(req, tokens, block_ids,
-        start, end), ...], padded to ``pad_to`` rows (padding rows have
-        seq_lens 0 and dropped page writes). Always the cached-prefill
-        program at the CHUNK bucket — one compiled variant per block-
-        table width regardless of group composition. Returns the sampled
-        tuple ([pad_to]-wide rows)."""
+        start, end), ...]. With ``plain_rung`` the rows are whole uncached
+        prompts of that rung and run the plain program at [len(rows),
+        rung]. Otherwise they are chunks of the chunked step plan, padded
+        to ``pad_to`` rows (padding rows have seq_lens 0 and dropped page
+        writes), and run the cached-prefill program at the CHUNK bucket —
+        one compiled variant per block-table width regardless of the
+        step's composition. Returns the sampled tuple (a row each)."""
         cfg = self.config
-        R = pad_to
-        bucket = cfg.bucket_for(
-            min(cfg.prefill_chunk_size, cfg.max_model_len))
-        blocks_needed = max(
-            (m[4] + cfg.block_size - 1) // cfg.block_size for m in rows)
-        maxb = 4
-        while maxb < blocks_needed:
-            maxb *= 2
-        maxb = min(maxb, self._prefill_batch_maxb())
+        if plain_rung:
+            R, bucket = len(rows), plain_rung
+            maxb = self._table_width(bucket)
+        else:
+            R = pad_to
+            bucket = cfg.bucket_for(
+                min(cfg.prefill_chunk_size, cfg.max_model_len))
+            maxb = self._table_width(max(m[4] for m in rows),
+                                     self._prefill_batch_maxb())
 
         token_arr = np.zeros((R, bucket), np.int32)
         positions = np.zeros((R, bucket), np.int32)
@@ -3313,8 +3270,10 @@ class EngineCore:
             # already advanced through the automaton at emission).
             self._fill_mask_row(mask_bits, mask_on, i, req)
 
-        self.prefill_attention_dispatch_total[self._paged_attn_path()] += 1
-        return self._dispatch("prefill", {"cached": True}, [
+        if not plain_rung:
+            self.prefill_attention_dispatch_total[
+                self._paged_attn_path()] += 1
+        return self._dispatch("prefill", {"cached": not plain_rung}, [
             token_arr, positions, slot_mapping,
             block_table, context_lens, seq_lens, adapter_ids,
             temp, topk, topp, seeds, steps,
@@ -3335,11 +3294,7 @@ class EngineCore:
         # Bucket the block-table width (power of two, min 4) so
         # cached-prefill attention cost scales with the real context, not
         # max_model_len — and so warmup() can precompile every variant.
-        blocks_needed = (end + cfg.block_size - 1) // cfg.block_size
-        maxb = 4
-        while maxb < blocks_needed:
-            maxb *= 2
-        maxb = min(maxb, cfg.max_blocks_per_seq)
+        maxb = self._table_width(end)
 
         token_arr = np.zeros((1, bucket), np.int32)
         token_arr[0, :take] = tokens[start:end]

@@ -412,6 +412,23 @@ class KVCacheManager:
         if self.on_free is not None:
             self.on_free(seq_id)
 
+    def free_unwritten(self, seq_id: str, restores=()) -> None:
+        """Give back a prompt's allocation whose pages were never written:
+        the full blocks it registered (``restores``, those it meant to copy
+        back from the offload tier, among them) leave the prefix map with
+        it, else they would stay as cold cache over garbage pages."""
+        seq = self.seqs.get(seq_id)
+        if seq is not None:
+            alloc = self.allocator
+            fresh = seq.block_ids[seq.num_cached_tokens // self.block_size:]
+            for bid in [bid for bid, _ in restores] + fresh:
+                blk = alloc.blocks[bid]
+                if (blk.prefix_hash is not None
+                        and alloc.prefix_map.get(blk.prefix_hash) == bid):
+                    del alloc.prefix_map[blk.prefix_hash]
+                    blk.prefix_hash = None
+        self.free(seq_id)
+
     def block_table(self, seq_id: str) -> List[int]:
         return self.seqs[seq_id].block_ids
 

@@ -238,12 +238,14 @@ def accepted_prefix_len(draft, sampled_row) -> int:
     return j
 
 
-def make_rng_keys(seed: int, step: int, seq_seeds: jax.Array) -> jax.Array:
-    """Per-sequence PRNG keys derived from (engine seed, step, seq seed)."""
+def make_rng_keys(seed: int, step, seq_seeds: jax.Array) -> jax.Array:
+    """Per-sequence PRNG keys derived from (engine seed, step, seq seed);
+    ``step`` is one number for all sequences or one a sequence."""
     base = jax.random.key(seed)
-    base = jax.random.fold_in(base, step)
 
-    def per_seq(s):
-        return jax.random.key_data(jax.random.fold_in(base, s))
+    def per_seq(st, s):
+        return jax.random.key_data(
+            jax.random.fold_in(jax.random.fold_in(base, st), s))
 
-    return jax.vmap(per_seq)(seq_seeds)
+    return jax.vmap(per_seq, in_axes=(0 if jnp.ndim(step) else None, 0))(
+        step, seq_seeds)

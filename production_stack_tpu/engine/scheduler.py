@@ -376,8 +376,8 @@ class Scheduler:
         return [r for r in self.waiting if self._is_live(r)]
 
     def take_waiting(self, req: EngineRequest) -> None:
-        """Remove a specific live request from the waiting queue (the
-        storm-batch gatherer picks group members out of FIFO order)."""
+        """Remove a specific live request from the waiting queue (a plain
+        prefill's group takes the head's mates out of FIFO order)."""
         self.waiting.remove(req)
         self._queued.discard(req.request_id)
         if req.priority:
@@ -399,6 +399,11 @@ class Scheduler:
         self._queued.add(req.request_id)
         if req.priority:
             self._nondefault_waiting += 1
+
+    def drop(self, req: EngineRequest) -> None:
+        """Forget a request that failed in flight, out of every queue (the
+        caller frees its pages and tells its client)."""
+        self._requests.pop(req.request_id, None)
 
     def drain_waiting(self) -> List[EngineRequest]:
         """Remove every queued and mid-prefill request (fatal-error path);

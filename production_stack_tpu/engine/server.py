@@ -2872,10 +2872,12 @@ def build_arg_parser() -> argparse.ArgumentParser:
                    help="LRU capacity of the compiled structured-output "
                         "token-FSM cache (one entry per distinct "
                         "schema/regex per tokenizer)")
-    p.add_argument("--prefill-batch", type=int, default=1,
-                   help="batch up to N queued long-prompt prefills into "
-                        "one dispatch (1 disables; see EngineConfig."
-                        "prefill_batch for the measured trade-off)")
+    p.add_argument("--prefill-batch", type=int,
+                   default=EngineConfig.prefill_batch,
+                   help="largest group of waiting uncached prompts of one "
+                        "prefill bucket that share one [R, bucket] prefill "
+                        "dispatch, R 4 or 2 by bucket (1 disables; see "
+                        "EngineConfig.prefill_batch)")
     p.add_argument("--no-warmup", dest="warmup", action="store_false",
                    default=True,
                    help="skip precompiling serving programs at startup")

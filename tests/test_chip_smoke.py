@@ -25,8 +25,9 @@ import chip_smoke  # noqa: E402
 def _plan(model="tiny-llama", **overrides):
     return chip_smoke.Plan(
         model=model, platform="cpu", interpret=True, kernel_path="xla",
-        engine_flags=("--max-model-len", "512"), long_prompt_tokens=300,
-        batch_prompt_tokens=150, max_tokens=8, **overrides)
+        engine_flags=("--max-model-len", "512", "--num-blocks", "64"),
+        long_prompt_tokens=300, batch_prompt_tokens=300, max_tokens=8,
+        **overrides)
 
 
 @pytest.fixture(autouse=True)
@@ -61,7 +62,8 @@ def test_one_chip_rehearsal(capsys):
     counters = by_phase["engine_counters"]
     assert counters["prefill_attention_dispatch_total"]["xla"] > 0
     assert counters["prefill_attention_dispatch_total"]["pallas"] == 0
-    assert counters["requests_finished"] == 7
+    assert by_phase["concurrent"]["prefill_group_rows"] == 4
+    assert counters["requests_finished"] == 11
     assert counters["preemptions"] == 0
     # The last line the script prints is this object and nothing else.
     assert result == {"ok": True, "device": {

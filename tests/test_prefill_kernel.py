@@ -14,6 +14,7 @@ traffic); warmup must compile ZERO new program variants for the fused
 path; and the dispatch-path metric must export both label values.
 """
 
+import collections
 import json
 import os
 import queue
@@ -320,10 +321,32 @@ CHUNKED = dict(enable_chunked_prefill=True, max_num_batched_tokens=32)
 
 
 def _jit_cache_sizes(eng):
+    """Compiled variants of every step program but the decode bursts'
+    (those: ``_assert_decode_variants``)."""
     fns = [eng._prefill_fn, eng._prefill_cached_fn]
-    fns += list(eng._multi_decode_fns.values())
     fns += list(eng._spec_verify_fns.values())
     return sum(f._cache_size() for f in fns)
+
+
+def _decode_shapes(eng):
+    """{K: operand shapes of the decode bursts this engine dispatches from
+    now on}: one compiled variant of ``decode_k<K>`` each, and no more."""
+    seen = collections.defaultdict(set)
+    exec_op = eng._exec_op
+
+    def recording(name, static, arrays):
+        if name == "decode":
+            seen[static["K"]].add(tuple(np.shape(a) for a in arrays))
+        return exec_op(name, static, arrays)
+
+    eng._exec_op = recording
+    return seen
+
+
+def _assert_decode_variants(eng, seen):
+    assert ({K: f._cache_size() for K, f in eng._multi_decode_fns.items()}
+            == {K: len(shapes) for K, shapes in seen.items()}), (
+        "a decode program has a variant beside its operand shapes")
 
 
 def _run_mixed(eng):
@@ -366,6 +389,7 @@ def test_fused_streams_equal_alternating():
     variants, and the flag-off registry surface."""
     ref = make_engine(**CHUNKED)
     try:
+        ref_decodes = _decode_shapes(ref)
         expected = _run_mixed(ref)
         assert ref.prefill_chunks_total >= 4
         # Flag-off registry parity: the fused path exports, but at zero.
@@ -379,6 +403,10 @@ def test_fused_streams_equal_alternating():
         assert "fused" not in {
             k for k, v in s["step_kind_stats"].items() if v["count"]}
         ref_variants = dict(ref.warmup_variants)
+        # The decode programs: a variant an operand shape dispatched, in
+        # either engine (the two schedules reach different table widths);
+        # every other program is shape for shape the same in both.
+        _assert_decode_variants(ref, ref_decodes)
         ref_cache = _jit_cache_sizes(ref)
     finally:
         ref.stop()
@@ -387,7 +415,9 @@ def test_fused_streams_equal_alternating():
     try:
         assert eng.warmup_variants == ref_variants, (
             "--fused-step must not compile any new program variants")
+        decodes = _decode_shapes(eng)
         got = _run_mixed(eng)
+        _assert_decode_variants(eng, decodes)
         assert _jit_cache_sizes(eng) == ref_cache, (
             "fused traffic traced a program shape alternating "
             "dispatches did not")
