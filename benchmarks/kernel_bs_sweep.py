@@ -2,7 +2,8 @@
 """Sweep KV page size (block_size) at fixed total context: fewer, bigger
 DMAs per kernel invocation. With ``--cells``: the decode kernel alone, at
 the tile it chooses itself, on the contexts the benchmark's cells hand it
-(:data:`CELL_CASES`; the numbers of the kernel's docstring and of PERF.md
+(:data:`CELL_CASES`; ``--cells --narrow``: at eight kv heads of 64 in the
+pool's packed rows; the numbers of the kernel's docstring and of PERF.md
 section 6, PR 32).
 
 Timing methodology (benchmarks/timing.py): every timed sequence ends in
@@ -67,23 +68,33 @@ def cell_tables(contexts, width: int, bs: int, nb: int, rng) -> np.ndarray:
     return tables
 
 
-def time_cells():
+def time_cells(narrow: bool = False):
     """One JSON line a case: us a call (one layer) of the kernel alone,
     the same scan without it taken off, beside the time its live tokens'
-    bytes need at 819 GB/s."""
+    bytes need at 819 GB/s. ``narrow``: eight kv heads of 64 in the
+    pool's packed rows (``ops.attention.packed_page_dims``: pages
+    ``[2, NB, 64, 4, 128]``, lfm2-24b-a2b-l10's), through the dispatcher
+    that spreads the queries over their head's lanes and brings the
+    outputs back, so that work is in the time."""
+    from production_stack_tpu.ops import attention as att
+
     L, NB, bs, KVH, D, H = (CELL_L, CELL_NB, CELL_BS, CELL_KVH, CELL_D,
                             CELL_H)
+    if narrow:
+        L, NB, D = 2, 8192, 64
     rng = np.random.default_rng(32)
     k1, k2, k3 = jax.random.split(jax.random.key(0), 3)
-    k_pages = jax.random.normal(k1, (L, NB, bs, KVH, D), jnp.bfloat16)
-    v_pages = jax.random.normal(k2, (L, NB, bs, KVH, D), jnp.bfloat16)
-    reps = 8  # calls of each layer in one timed scan
+    page = (L, NB, bs) + att.packed_page_dims(KVH, D)
+    k_pages = jax.random.normal(k1, page, jnp.bfloat16)
+    v_pages = jax.random.normal(k2, page, jnp.bfloat16)
+    reps = 8 * CELL_L // L  # calls of each layer in one timed scan
+    kernel = att.paged_decode_attention if narrow else pallas_paged_attention
 
     def scan_of(with_kernel: bool):
         @jax.jit
         def run(q, k_pages, v_pages, bt, cl):
             def body(acc, l):
-                o = (pallas_paged_attention(
+                o = (kernel(
                     q, k_pages, v_pages, bt, cl, l % L, scale=D ** -0.5)
                     if with_kernel else q * (l % L).astype(q.dtype))
                 return acc + o.astype(jnp.float32), None
@@ -103,6 +114,7 @@ def time_cells():
         live = sum(contexts)
         print(json.dumps({
             "case": name, "rows": len(contexts), "table_pages": width,
+            "page": list(page[2:]),
             "live_tokens": live,
             "us_per_call": round(per_scan / (L * reps) * 1e6, 1),
             "floor_us": round(live * 2 * KVH * D * 2 / 819e9 * 1e6, 1),
@@ -166,6 +178,6 @@ def main():
 
 if __name__ == "__main__":
     if "--cells" in sys.argv[1:]:
-        time_cells()
+        time_cells(narrow="--narrow" in sys.argv[1:])
     else:
         main()

@@ -50,7 +50,10 @@ from production_stack_tpu.structured.tokenfsm import (
     mask_row_bytes,
 )
 from production_stack_tpu.models import build_model, get_model_config
-from production_stack_tpu.models.registry import get_family
+from production_stack_tpu.models.registry import (
+    block_state_shape,
+    get_family,
+)
 from production_stack_tpu.parallel import multihost
 from production_stack_tpu.parallel.mesh import build_mesh
 from production_stack_tpu.parallel.sharding import (
@@ -76,36 +79,62 @@ class _StagedParam:
     dtype: object
 
 
+def kv_page_dims(model_config, kv_cache_dtype: str = "bf16",
+                 packed: bool = True):
+    """(layers that hold pages, rows, lanes) of one token's page: the
+    family's own count of layers with keys and values
+    (``Family.page_layers``; every layer for most) and what a page keeps
+    of a token per layer, ``(KVH, D)`` or narrow heads side by side in
+    128-lane rows (``ops.attention.packed_page_dims``; never for int8
+    pages, nor with ``packed`` off: a pool sharded over kv heads)."""
+    from production_stack_tpu.models.registry import page_layers
+    from production_stack_tpu.ops.attention import packed_page_dims
+
+    mc = model_config
+    dims = (packed_page_dims(mc.num_kv_heads, mc.head_dim,
+                             kv_cache_dtype == "int8")
+            if packed else (mc.num_kv_heads, mc.head_dim))
+    return (page_layers(mc),) + dims
+
+
 def kv_bytes_per_block(model_config, block_size: int,
                        kv_cache_dtype: str = "bf16") -> int:
-    """Per-block HBM bytes INCLUDING XLA's tile padding. When head_dim
-    is lane-aligned (multiple of 128) the trailing (KVH, D) dims flatten
-    onto the lanes and occupy exactly their unpadded size (llama-family:
-    8x128). Otherwise the minor dim pads to 128 and the kv-head dim to
-    the sublane granularity — e.g. OPT's (12, 64) stores as (16, 128), a
-    2.7x expansion that OOMed compile when the pool was sized from
-    unpadded bytes.
+    """Per-block HBM bytes INCLUDING XLA's tile padding. When a page's
+    rows are lane-aligned (a multiple of 128 wide: llama-family 8x128,
+    or eight 64-wide heads side by side as 4x128) the trailing dims
+    occupy exactly their unpadded size. Otherwise the minor dim pads to
+    128 and the kv-head dim to the sublane granularity — e.g. OPT's
+    (12, 64) stores as (16, 128), a 2.7x expansion that OOMed compile
+    when the pool was sized from unpadded bytes.
 
     ``int8`` stores one byte per K/V element (sublane granularity 32
     when head_dim needs lane padding) plus the per-slot per-kv-head f32
     scale rows, whose flat [bs*KVH] minor dim pads to the 128-lane tile
     — ~1.94x the blocks of bf16 at an equal HBM budget for llama-family
-    shapes."""
+    shapes.
+
+    A family with a state per block (``Family.block_state``) adds its
+    ``layers x rows x width`` values in the model's dtype."""
     mc = model_config
-    kvh, d = mc.num_kv_heads, mc.head_dim
+    layers, kvh, d = kv_page_dims(mc, kv_cache_dtype)
+    itemsize = jnp.dtype(mc.dtype).itemsize
+    state = block_state_shape(mc)
+    state_bytes = 0
+    if state is not None:
+        rows = -(-state[1] // 8) * 8 if state[2] % 128 else state[1]
+        state_bytes = state[0] * rows * (-(-state[2] // 128) * 128) * itemsize
     if kv_cache_dtype == "int8":
         if d % 128 != 0:
             d = -(-d // 128) * 128
             kvh = -(-kvh // 32) * 32
         scale_lanes = -(-(block_size * mc.num_kv_heads) // 128) * 128
-        return mc.num_layers * (
+        return state_bytes + layers * (
             2 * block_size * kvh * d + 2 * scale_lanes * 4)
-    itemsize = jnp.dtype(mc.dtype).itemsize
     if d % 128 != 0:
         d = -(-d // 128) * 128
         sublane = 16 if itemsize == 2 else 8
         kvh = -(-kvh // sublane) * sublane
-    return mc.num_layers * 2 * block_size * kvh * d * itemsize
+    return state_bytes + layers * 2 * block_size * kvh * d * itemsize
 
 
 # -- KV pool leaf helpers --------------------------------------------------
@@ -289,6 +318,14 @@ class EngineCore:
 
         self._init_fn, self._apply = build_model(self.model_config)
         family = get_family(self.model_config.arch)
+        # (layers, rows, width) of the state a cache block holds beside
+        # its pages (Family.block_state), None for most families.
+        self.block_state_shape = block_state_shape(self.model_config)
+        # (layers, rows, lanes) of this engine's pages; a pool sharded
+        # over kv heads keeps one head a row.
+        self.page_dims = kv_page_dims(
+            self.model_config, config.kv_cache_dtype,
+            packed=self.mesh.shape.get("tp", 1) == 1)
         # What the family's forward counts (models/registry.py::
         # Family.stats; the expert layer's assignment counts): the step
         # programs return the sums beside their tokens, and the step that
@@ -317,6 +354,8 @@ class EngineCore:
             self._apply = make_pp_apply(
                 self.mesh, family, microbatches=config.pp_microbatches or pp
             )
+
+        self._refuse_what_the_block_state_is_not_taught()
 
         # -- parameters (sharded over the mesh) ----------------------------
         lora_kwargs = {}
@@ -398,7 +437,11 @@ class EngineCore:
         else:
             self._kv_sharding = pages_sh
             self._block_sharding = block_sh
-        self._kv_pair_sharding = (self._kv_sharding, self._kv_sharding)
+        # The pool: (k, v), and a family's state per block as a third
+        # side beside them (models/registry.py::Family.block_state).
+        self._kv_pair_sharding = (
+            (self._kv_sharding, self._kv_sharding)
+            + ((self._repl,) if self.block_state_shape else ()))
         # A pool sharded over kv heads: the paged kernels cannot be
         # partitioned by the compiler, so the dispatchers run them per
         # shard (ops/attention.py::kv_head_sharding) — or take the
@@ -433,9 +476,8 @@ class EngineCore:
         # out_shardings prefix even for int8 tuple leaves — it
         # broadcasts over the subtree.)
         self._gather_blocks_fn = jax.jit(
-            lambda kv, idx: (_kv_leaf_index(kv[0], idx),
-                             _kv_leaf_index(kv[1], idx)),
-            out_shardings=(self._repl, self._repl))
+            lambda kv, idx: tuple(_kv_leaf_index(side, idx) for side in kv),
+            out_shardings=(self._repl,) * len(self._kv_pair_sharding))
         self.kv_mgr = KVCacheManager(
             self.num_blocks, config.block_size, config.enable_prefix_caching,
             namespace=config.model,
@@ -527,6 +569,10 @@ class EngineCore:
         # [prefill_batch, chunk]).
         self.prefill_padded_tokens_total = 0
         self.kv_fetch_tokens_total = 0  # copied by the decode kernel
+        # Prefill rows that began from a block's state, and block entries
+        # written (a family with Family.block_state; 0 otherwise).
+        self.state_restores_total = 0
+        self.state_blocks_written_total = 0
         self.generation_tokens_total = 0
         self.requests_finished_total = 0
         self.step_count = 0
@@ -753,34 +799,66 @@ class EngineCore:
 
     def _alloc_kv(self):
         mc = self.model_config
-        shape = (
-            mc.num_layers, self.num_blocks, self.config.block_size,
-            mc.num_kv_heads, mc.head_dim,
-        )
+        layers, rows, lanes = self.page_dims
+        shape = (layers, self.num_blocks, self.config.block_size, rows,
+                 lanes)
+        state_shape = self.block_state_shape and (
+            self.block_state_shape[0], self.num_blocks) + self.block_state_shape[1:]
+
+        def with_state(k, v):
+            # Zeros: a block's state before anything was written into it
+            # is a sequence's before its first token.
+            return (k, v) + ((jnp.zeros(state_shape, mc.jnp_dtype),)
+                             if state_shape else ())
+
         if self.config.kv_cache_dtype == "int8":
-            sshape = (mc.num_layers, self.num_blocks,
+            sshape = (layers, self.num_blocks,
                       self.config.block_size * mc.num_kv_heads)
 
             @functools.partial(
-                jax.jit,
-                out_shardings=(self._kv_sharding, self._kv_sharding))
+                jax.jit, out_shardings=self._kv_pair_sharding)
             def zeros_q():
                 # Scales init to 1 (not 0): a never-written slot must
                 # dequantize its zero int8 data to exact zeros without
                 # a 0*0-vs-NaN hazard anywhere downstream.
-                return ((jnp.zeros(shape, jnp.int8),
-                         jnp.ones(sshape, jnp.float32)),
-                        (jnp.zeros(shape, jnp.int8),
-                         jnp.ones(sshape, jnp.float32)))
+                return with_state(
+                    (jnp.zeros(shape, jnp.int8),
+                     jnp.ones(sshape, jnp.float32)),
+                    (jnp.zeros(shape, jnp.int8),
+                     jnp.ones(sshape, jnp.float32)))
 
             return zeros_q()
 
-        @functools.partial(jax.jit, out_shardings=(self._kv_sharding, self._kv_sharding))
+        @functools.partial(jax.jit, out_shardings=self._kv_pair_sharding)
         def zeros():
             z = jnp.zeros(shape, mc.jnp_dtype)
-            return z, jnp.zeros(shape, mc.jnp_dtype)
+            return with_state(z, jnp.zeros(shape, mc.jnp_dtype))
 
         return zeros()
+
+    def _refuse_what_the_block_state_is_not_taught(self) -> None:
+        """A family whose blocks hold a state beside their pages
+        (``Family.block_state``) is served by the paths that carry the
+        state. Every other one that moves, rolls back or splits pages is
+        refused here, by the flag that asks for it: none drops the state
+        silently."""
+        if not self.block_state_shape:
+            return
+        cfg = self.config
+        asked = {
+            "--speculative-num-tokens": cfg.speculative_num_tokens > 0,
+            "--speculative-draft-model": bool(cfg.speculative_draft_model),
+            "--kv-offload-bytes": cfg.kv_offload_bytes > 0,
+            "--kv-remote-url": bool(cfg.kv_remote_url),
+            "--tensor-parallel-size / a mesh of several devices":
+                self.mesh.size > 1 or self._mh is not None,
+        }
+        refused = sorted(flag for flag, on in asked.items() if on)
+        if refused:
+            raise ValueError(
+                f"model arch {self.model_config.arch!r} keeps a state per "
+                f"cache block beside its pages, which {', '.join(refused)} "
+                "would not carry: not supported for this family yet")
 
     @staticmethod
     def _is_resource_exhausted(exc: BaseException) -> bool:
@@ -842,6 +920,27 @@ class EngineCore:
             return program
         return _StatsTap(program, self._stats_pending.append, forwards,
                          prefix)
+
+    def _note_block_state(self, first, lens) -> None:
+        """What a prefill dispatch does with the blocks' state
+        (``Family.block_state``), from its rows' first positions and
+        lengths: ``state_rows``, its rows that hold a span;
+        ``state_restores``, those of them that begin from a block's
+        state (behind a prefix hit or an earlier chunk); and
+        ``state_blocks_written``, the blocks whose entry they write. Into
+        the step's record (summed over its dispatches) and the lifetime
+        totals."""
+        first, lens = np.asarray(first), np.asarray(lens)
+        live = lens > 0
+        bs = self.config.block_size
+        restores = int((live & (first > 0)).sum())
+        written = int(np.where(
+            live, (first + lens - 1) // bs - first // bs + 1, 0).sum())
+        self.state_restores_total += restores
+        self.state_blocks_written_total += written
+        self._steps.note_sum(state_rows=int(live.sum()),
+                             state_restores=restores,
+                             state_blocks_written=written)
 
     def _note_family_stats(self) -> None:
         """The counts of the step programs that have finished since the
@@ -1170,10 +1269,10 @@ class EngineCore:
 
         @functools.partial(
             jax.jit, donate_argnums=(0,),
-            out_shardings=(self._kv_sharding, self._kv_sharding))
-        def write_block(kv, bid, k, v):
-            k_pages, v_pages = kv
-            return _kv_set(k_pages, bid, k), _kv_set(v_pages, bid, v)
+            out_shardings=self._kv_pair_sharding)
+        def write_block(kv, bid, k, v, *state):
+            return tuple(_kv_set(side, bid, new)
+                         for side, new in zip(kv, (k, v) + state))
 
         return write_block
 
@@ -1191,14 +1290,15 @@ class EngineCore:
         """Jitted BATCHED page write: all transferred blocks land in one
         dispatch (k/v are [L, N, bs, KVH, D], bids [N]) — the disagg
         receive path's scatter; per-block writes would cost one dispatch
-        per page."""
+        per page. A family's block state rides along as ``state``
+        ([layers, N, rows, width])."""
 
         @functools.partial(
             jax.jit, donate_argnums=(0,),
-            out_shardings=(self._kv_sharding, self._kv_sharding))
-        def write_blocks(kv, bids, k, v):
-            k_pages, v_pages = kv
-            return _kv_set(k_pages, bids, k), _kv_set(v_pages, bids, v)
+            out_shardings=self._kv_pair_sharding)
+        def write_blocks(kv, bids, k, v, *state):
+            return tuple(_kv_set(side, bids, new)
+                         for side, new in zip(kv, (k, v) + state))
 
         return write_blocks
 
@@ -1303,6 +1403,8 @@ class EngineCore:
             self.prefill_padded_tokens_total += arrays[0].size
             self._steps.note_program(fn.__name__,
                                      padded_tokens=arrays[0].size)
+            if self.block_state_shape:
+                self._note_block_state(arrays[1][:, 0], arrays[5])
             out, self.kv = fn(self.params, self.kv, *arrays)
             return out
         if name == "decode":
@@ -1509,16 +1611,15 @@ class EngineCore:
                 f"{self._mh.process_id if self._mh else 0}")
         k_sh, v_sh = entry
         mc = self.model_config
-        shape = (mc.num_layers, self.config.block_size,
-                 mc.num_kv_heads, mc.head_dim)
+        layers, rows, lanes = self.page_dims
+        shape = (layers, self.config.block_size, rows, lanes)
 
         def unstage(sh_dict, shp, sharding):
             return jax.make_array_from_callback(
                 shp, sharding, lambda idx: sh_dict[str(idx)])
 
         if isinstance(k_sh, tuple):
-            sshape = (mc.num_layers,
-                      self.config.block_size * mc.num_kv_heads)
+            sshape = (layers, self.config.block_size * mc.num_kv_heads)
             pg_sh, sc_sh = self._block_sharding
             k = (unstage(k_sh[0], shape, pg_sh),
                  unstage(k_sh[1], sshape, sc_sh))
@@ -1609,7 +1710,7 @@ class EngineCore:
                 k = _kv_leaf_swap01(_kv_leaf_get(out[0]))
                 v = _kv_leaf_swap01(_kv_leaf_get(out[1]))
             else:
-                k_pages, v_pages = self.kv
+                k_pages, v_pages, *state = self.kv
                 idx = jnp.asarray(bids)
                 # [L, N, bs, KVH, D] -> [N, L, bs, KVH, D] (per-block
                 # payloads)
@@ -1617,12 +1718,33 @@ class EngineCore:
                     _kv_leaf_get(_kv_leaf_index(k_pages, idx)))
                 v = _kv_leaf_swap01(
                     _kv_leaf_get(_kv_leaf_index(v_pages, idx)))
+                state = [np.asarray(jax.device_get(side[:, idx])
+                                    ).swapaxes(0, 1) for side in state]
         return {
             "hashes": hashes,
             "num_tokens": len(hashes) * bs,
-            "k": k,
-            "v": v,
+            "k": self._one_head_a_row(k),
+            "v": self._one_head_a_row(v),
+            # [N, layers, rows, width]: the blocks' state, where the
+            # family keeps one (Family.block_state).
+            **({"state": state[0]} if state else {}),
         }
+
+    def _one_head_a_row(self, side):
+        """A payload's pages in the logical layout every surface speaks,
+        ``[..., bs, KVH, D]``, whatever rows the pool keeps them in
+        (kv_page_dims); int8 payloads are never packed."""
+        if isinstance(side, tuple):
+            return side
+        mc = self.model_config
+        return side.reshape(
+            side.shape[:-2] + (mc.num_kv_heads, mc.head_dim))
+
+    def _as_pool_rows(self, side):
+        """The inverse: a logical payload in the pool's rows."""
+        if isinstance(side, tuple):
+            return side
+        return side.reshape(side.shape[:-2] + self.page_dims[1:])
 
     def extract_kv_device(self, token_ids: List[int], adapter: str = ""):
         """Device-side variant of :meth:`extract_kv` for the transfer-pipe
@@ -1659,28 +1781,38 @@ class EngineCore:
                     i += bs
             if not hashes:
                 return None
-            k_pages, v_pages = self.kv
+            k_pages, v_pages, *state = self.kv
             idx = jnp.asarray(bids)
             # Dispatched under _step_lock so the gather reads self.kv
             # before any later engine step donates the buffer.
-            k = _kv_leaf_index(k_pages, idx)
-            v = _kv_leaf_index(v_pages, idx)
+            k = self._one_head_a_row(_kv_leaf_index(k_pages, idx))
+            v = self._one_head_a_row(_kv_leaf_index(v_pages, idx))
+            state = [side[:, idx] for side in state]
         return {
             "hashes": hashes,
             "num_tokens": len(hashes) * bs,
             "k": k,  # [L, N, bs, KVH, D] device array
             "v": v,
+            # [layers, N, rows, width] device array
+            **({"state": state[0]} if state else {}),
         }
 
-    def inject_kv_blocks(self, hashes: List[int], k, v) -> int:
+    def inject_kv_blocks(self, hashes: List[int], k, v, state=None) -> int:
         """Install transferred KV pages ([L, N, bs, KVH, D] — device
         arrays from the pipe or numpy from the HTTP relay) as cached
-        (cold) prefix pages in ONE batched scatter dispatch. Returns
+        (cold) prefix pages in ONE batched scatter dispatch, with the
+        blocks' ``state`` ([layers, N, rows, width]) for a family that
+        keeps one: pages without it are refused. Returns
         #blocks installed (cache-hit blocks count as installed). In
         multi-host mode the scatter rides the op channel (numpy payload
         fans out to every process; uniform host inputs feed the global
         scatter as replicated operands)."""
         alloc = self.kv_mgr.allocator
+        if (state is None) != (self.block_state_shape is None):
+            raise ValueError(
+                "this model's cache blocks hold a state beside their pages: "
+                "a payload must bring it" if state is None else
+                "this model's cache blocks hold no state")
         with self._step_lock:
             if self.kv is None or not alloc.enable_prefix_caching:
                 return 0
@@ -1722,13 +1854,15 @@ class EngineCore:
                                     _kv_leaf_index(kk, sl),
                                     _kv_leaf_index(vv, sl)))
                     else:
-                        k_arr = _kv_leaf_jnp(k)
-                        v_arr = _kv_leaf_jnp(v)
+                        k_arr = self._as_pool_rows(_kv_leaf_jnp(k))
+                        v_arr = self._as_pool_rows(_kv_leaf_jnp(v))
                         take = np.asarray(fresh_idx)
                         self.kv = self._write_blocks_fn(
                             self.kv, np.asarray(fresh_bids, np.int32),
                             _kv_leaf_index(k_arr, take),
                             _kv_leaf_index(v_arr, take),
+                            *(() if state is None
+                              else (jnp.asarray(state)[:, take],)),
                         )
                 except Exception:
                     # Bad payload shape/dtype: give the blocks back
@@ -1805,14 +1939,13 @@ class EngineCore:
             self._drain_offload()
             if dst_bids:
                 try:
-                    src_k, src_v = src.kv
                     sel = np.asarray(
                         [src_bids[n] for n in take_idx], np.int32)
+                    # Every side of the pool: pages, and the blocks'
+                    # state where the family keeps one.
                     self.kv = self._write_blocks_fn(
                         self.kv, np.asarray(dst_bids, np.int32),
-                        _kv_leaf_index(src_k, sel),
-                        _kv_leaf_index(src_v, sel),
-                    )
+                        *(_kv_leaf_index(side, sel) for side in src.kv))
                 except Exception:
                     with self._lock:
                         for bid in dst_bids:
@@ -1824,16 +1957,21 @@ class EngineCore:
                         dst_alloc.release(bid)  # cached, ref_count 0
         return already + len(dst_bids)
 
-    def inject_kv(self, hashes: List[int], k_blocks, v_blocks) -> int:
+    def inject_kv(self, hashes: List[int], k_blocks, v_blocks,
+                  state_blocks=None) -> int:
         """Back-compat wrapper over :meth:`inject_kv_blocks` for payloads
-        shaped [N, L, bs, KVH, D] (per-block lists / the TKV2 wire layout).
-        The [N, L] -> [L, N] transpose happens on device inside the jit."""
+        shaped [N, L, bs, KVH, D] (per-block lists / the TKV2 wire layout;
+        ``state_blocks`` [N, layers, rows, width] as :meth:`extract_kv`
+        gives it). The [N, L] -> [L, N] transpose happens on device
+        inside the jit."""
         if not hashes:
             return 0
         k = _kv_leaf_np(k_blocks)
         v = _kv_leaf_np(v_blocks)
         return self.inject_kv_blocks(
-            list(hashes), _kv_leaf_swap01(k), _kv_leaf_swap01(v))
+            list(hashes), _kv_leaf_swap01(k), _kv_leaf_swap01(v),
+            None if state_blocks is None
+            else np.asarray(state_blocks).swapaxes(0, 1))
 
     # ------------------------------------------------------------------ #
     # public API (thread-safe)
@@ -2310,6 +2448,7 @@ class EngineCore:
         apply = self._apply
         cfg = self.model_config
         bs = self.config.block_size
+        page_dims, state = self.page_dims, self.block_state_shape
 
         def embed_fwd(params, token_ids, positions, slot_mapping,
                       block_tables, seq_lens):
@@ -2317,10 +2456,11 @@ class EngineCore:
             # (a host-side jnp.zeros would be committed to one process's
             # local device and could not feed a multi-host computation);
             # slot_mapping is all -1, so writes drop.
-            kv_shape = (cfg.num_layers, 1, bs, cfg.num_kv_heads,
-                        cfg.head_dim)
+            kv_shape = (page_dims[0], 1, bs) + page_dims[1:]
             kv = (jnp.zeros(kv_shape, cfg.jnp_dtype),
                   jnp.zeros(kv_shape, cfg.jnp_dtype))
+            if state is not None:
+                kv += (jnp.zeros((state[0], 1) + state[1:], cfg.jnp_dtype),)
             hidden, _ = apply(
                 params, cfg, token_ids, positions, kv, slot_mapping,
                 block_tables, seq_lens, seq_lens,
@@ -2399,6 +2539,8 @@ class EngineCore:
             "cached_tokens_total": self.cached_tokens_total,
             "prefill_padded_tokens_total": self.prefill_padded_tokens_total,
             "kv_fetch_tokens_total": self.kv_fetch_tokens_total,
+            "state_restores_total": self.state_restores_total,
+            "state_blocks_written_total": self.state_blocks_written_total,
             "family_stats_total": dict(self.family_stats_total),
             "generation_tokens_total": self.generation_tokens_total,
             "offload": self.offload.stats() if self.offload else None,
@@ -2878,10 +3020,12 @@ class EngineCore:
         ``kv_fetch_tokens``."""
         from production_stack_tpu.ops.attention import attention_path
 
+        _, rows, lanes = self.page_dims
         mc = self.model_config
         return attention_path(
-            self.config.block_size, mc.num_kv_heads, mc.head_dim,
-            self.config.kv_cache_dtype == "int8", self._kv_shards)
+            self.config.block_size, rows, lanes,
+            self.config.kv_cache_dtype == "int8", self._kv_shards,
+            packed=(rows, lanes) != (mc.num_kv_heads, mc.head_dim))
 
     def _do_fused(self, plan) -> None:
         """Execute one scheduler "fused" action: the budgeted prefill
@@ -3577,6 +3721,12 @@ class EngineCore:
                         live, cfg.block_size, maxb, window),
                     kv_live_tokens_window=int(
                         np.minimum(live, window).sum()))
+        if self.block_state_shape:
+            # A decode step writes the entry of the block it writes its
+            # token into.
+            written = int((slot_mat >= 0).sum())
+            self.state_blocks_written_total += written
+            self._steps.note_sum(state_blocks_written=written)
         outs = self._dispatch(
             "decode", {"K": K, "use_prev": prev is not None}, [
                 reset_counts, tok_idx, host_tokens, use_host, positions0,
@@ -4222,6 +4372,10 @@ class EngineCore:
                 # automaton mid-structure: the stream is not a complete
                 # member of the grammar.
                 self.structured_violations_total += 1
+            # Counted before the client is told: ``finish`` ends the
+            # stream, and whoever saw it end may read the counter next
+            # (chip_smoke's count of finished requests read one short
+            # under load when this line came after).
+            self.requests_finished_total += 1
             with self._lock:
                 self.scheduler.finish(seq, finish)
-            self.requests_finished_total += 1

@@ -650,11 +650,17 @@ class EngineServer:
         r.add_post("/v1/unload_lora_adapter", self.handle_unload_lora)
         r.add_get("/v1/lora_adapters", self.handle_list_lora)
         # KV transfer (disaggregated prefill / cross-engine KV sharing).
-        r.add_post("/kv/extract", self.handle_kv_extract)
-        r.add_post("/kv/inject", self.handle_kv_inject)
-        r.add_post("/kv/pull", self.handle_kv_pull)
-        r.add_post("/kv/prepare_pull", self.handle_kv_prepare_pull)
-        r.add_post("/kv/release", self.handle_kv_release)
+        # Its wire formats carry keys and values: a family whose blocks
+        # hold a state beside them (Family.block_state) answers 501 on
+        # every route rather than hand out pages without it.
+        for path, handler in (
+                ("/kv/extract", self.handle_kv_extract),
+                ("/kv/inject", self.handle_kv_inject),
+                ("/kv/pull", self.handle_kv_pull),
+                ("/kv/prepare_pull", self.handle_kv_prepare_pull),
+                ("/kv/release", self.handle_kv_release)):
+            r.add_post(path, self._kv_transfer_refused
+                       if self.core.block_state_shape else handler)
         r.add_post("/v1/audio/transcriptions", self.handle_transcriptions)
         # Flight recorder (engine-side stage spans per request).
         from production_stack_tpu.obs.debug import (
@@ -1931,6 +1937,12 @@ class EngineServer:
             return [int(t) for t in prompt]
         return self.core.tokenizer.encode(str(prompt))
 
+    async def _kv_transfer_refused(self, request: web.Request) -> web.Response:
+        return web.json_response(
+            {"error": "this model keeps a state per cache block beside its "
+                      "pages, which the KV transfer formats do not carry"},
+            status=501)
+
     async def handle_kv_extract(self, request: web.Request) -> web.StreamResponse:
         """Serialize the cached KV pages for a prompt's prefix. The raw
         array buffers stream straight to the socket (no payload-sized
@@ -2479,6 +2491,11 @@ class EngineServer:
             "# TYPE tpu:kv_fetch_tokens counter",
             f"tpu:kv_fetch_tokens_total{{{labels}}} "
             f"{s['kv_fetch_tokens_total']}",
+            # Prefill rows that began from a cache block's state (a
+            # family with Family.block_state; 0 for any other).
+            "# TYPE tpu:conv_state_restores counter",
+            f"tpu:conv_state_restores_total{{{labels}}} "
+            f"{s.get('state_restores_total', 0)}",
             # The expert layer's counts (models/moe.py::STATS), 0 for a
             # model without one.
             "# TYPE tpu:moe_assignments counter",

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 
 FULL_ATTENTION = "full_attention"
 SLIDING_ATTENTION = "sliding_attention"
+SHORT_CONV = "conv"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -40,7 +41,7 @@ class RopeParams:
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str = "tiny-llama"
-    arch: str = "llama"  # llama | opt | mixtral
+    arch: str = "llama"  # a key of models/registry.py::ARCH_MODULES
     vocab_size: int = 32000
     hidden_size: int = 2048
     num_layers: int = 16
@@ -72,6 +73,14 @@ class ModelConfig:
     # router scores, and this one holds block ``layer_share``.
     chips_per_layer: int = 1
     layer_share: int = 0
+    # The router (models/moe.py::route): ``softmax`` over all scores or a
+    # ``sigmoid`` of each; with ``router_bias`` a per-expert float32 bias
+    # joins the score for the selection alone (lfm2).
+    router_scoring: str = "softmax"
+    router_bias: bool = False
+    # Gated short convolution (lfm2's ``conv`` layers): the kernel's
+    # length; a layer's state is its last ``conv_kernel - 1`` inputs.
+    conv_kernel: int = 0
     dtype: str = "bfloat16"
 
     @property
@@ -142,6 +151,19 @@ _PRESETS = {
                 original_max_position_embeddings=256,
                 attention_factor=1.2079441541679836)),
             (SLIDING_ATTENTION, RopeParams(rope_theta=10000.0))),
+    ),
+    # Both operators (short convolutions 3:1 with attention layers whose
+    # eight 64-wide kv heads take the pool's packed rows), two dense
+    # layers, then sigmoid-routed experts with a selection bias, tied head.
+    "tiny-lfm2": ModelConfig(
+        name="tiny-lfm2", arch="lfm2", vocab_size=512, hidden_size=128,
+        num_layers=6, num_heads=16, num_kv_heads=8, head_dim=64,
+        intermediate_size=256, max_position=2048, rope_theta=1000000.0,
+        num_experts=8, experts_per_token=2, moe_intermediate_size=64,
+        dense_layers=2, router_scoring="sigmoid", router_bias=True,
+        conv_kernel=3, tie_word_embeddings=True,
+        layer_types=(SHORT_CONV, SHORT_CONV, FULL_ATTENTION, SHORT_CONV,
+                     SHORT_CONV, FULL_ATTENTION),
     ),
     "tiny-opt": ModelConfig(
         name="tiny-opt", arch="opt", vocab_size=512, hidden_size=128,
@@ -218,8 +240,7 @@ def _from_hf_config_json(path: str, name: str) -> ModelConfig:
     heads = cfg.get("num_attention_heads", 32)
     hidden = cfg.get("hidden_size", 4096)
     layers = cfg.get("num_hidden_layers", cfg.get("num_layers", 32))
-    return ModelConfig(
-        **_layer_kind_keys(cfg, arch, layers),
+    fields = dict(
         name=name,
         arch=arch,
         vocab_size=cfg.get("vocab_size", 32000),
@@ -238,9 +259,12 @@ def _from_hf_config_json(path: str, name: str) -> ModelConfig:
         num_experts=cfg.get("num_local_experts", cfg.get("num_experts", 0)),
         experts_per_token=cfg.get("num_experts_per_tok", 2),
     )
+    # What the family reads of its own keys goes over the common ones.
+    fields.update(_layer_kind_keys(cfg, arch, layers))
+    return ModelConfig(**fields)
 
 
-def _rope_params(block: dict) -> RopeParams:
+def rope_params(block: dict) -> RopeParams:
     kinds = {f.name: f.type for f in dataclasses.fields(RopeParams)}
     return RopeParams(**{
         k: float(v) if kinds[k] in ("float", "Optional[float]") else v
@@ -249,49 +273,25 @@ def _rope_params(block: dict) -> RopeParams:
 
 def _layer_kind_keys(cfg: dict, arch: str, layers: int) -> dict:
     """The ``ModelConfig`` fields of a family whose layers are of several
-    kinds, from the keys its config publishes. Per-layer lists are read
-    for their first ``num_hidden_layers`` entries (a cut in layers keeps
-    the lists at their published length). A family that needs them
+    kinds, which the family reads of its own keys
+    (``Family.config_fields``). Per-layer lists are read for their first
+    ``num_hidden_layers`` entries (a cut in layers keeps the lists at
+    their published length). A family that needs them
     (``Family.per_layer_keys``) refuses a file without them; any other
     family reads none of them."""
     from production_stack_tpu.models.registry import get_family
 
-    wanted = get_family(arch).per_layer_keys
-    if not wanted:
+    family = get_family(arch)
+    if not family.per_layer_keys:
         return {}
-    for key in wanted:
+    for key in family.per_layer_keys:
         held = cfg.get(key)
         if not isinstance(held, list) or len(held) < layers:
             raise ValueError(
                 f"a {cfg.get('model_type')!r} config.json needs the "
                 f"per-layer list {key!r} with at least num_hidden_layers "
                 f"= {layers} entries")
-    mlp_kinds = cfg["mlp_layer_types"][:layers]
-    dense = next((i for i, kind in enumerate(mlp_kinds) if kind != "dense"),
-                 layers)
-    if "dense" in mlp_kinds[dense:] or sorted(
-            cfg.get("mlp_only_layers", range(dense))) != list(range(dense)):
-        raise ValueError("dense MLP layers are served as a leading run "
-                         f"only; got {mlp_kinds}")
-    if cfg.get("moe_router_logit_softcapping") or cfg.get(
-            "moe_apply_router_weight_on_input"):
-        raise ValueError("router soft-capping and router weights on the "
-                         "expert's input are not implemented")
-    return dict(
-        layer_types=tuple(cfg["layer_types"][:layers]),
-        heads_per_layer=tuple(cfg["num_attention_heads_per_layer"][:layers]),
-        sliding_window=cfg.get("sliding_window") or 0,
-        rope_by_kind=tuple(
-            (kind, _rope_params(block))
-            for kind, block in sorted(cfg.get("rope_parameters", {}).items())
-            if isinstance(block, dict)),
-        moe_intermediate_size=cfg.get("moe_intermediate_size", 0),
-        shared_expert_size=cfg.get("shared_expert_intermediate_size", 0),
-        routed_scaling=float(cfg.get("moe_routed_scaling_factor", 1.0)),
-        dense_layers=dense,
-        chips_per_layer=cfg.get("chips_per_layer", 1),
-        layer_share=cfg.get("layer_share", 0),
-    )
+    return family.config_fields(cfg, layers)
 
 
 def get_model_config(model: str) -> ModelConfig:

@@ -8,8 +8,10 @@ A family (models/registry.py::Family) brings ``embed``, ``layer`` and
   (prefill), over the pages plus the fresh suffix (cached prefill) or over
   the pages alone (decode). The only code under ``models/`` that imports
   ``ops.attention`` and the only code that spells the three mode names:
-  a window mask, a latent cache or a second kind of cache state has this
-  one call site to change;
+  a window mask or a latent cache has this one call site to change. A
+  state per cache block beside the pages (a short convolution's last
+  inputs) is read and written by :func:`read_block_state` and
+  :func:`write_block_state`;
 - :func:`scan_layers`: one ``lax.scan`` over the layer-stacked leaves
   (single-layer trace, fast compiles even at 80 layers) with the carry
   convention of the paged pool;
@@ -103,6 +105,62 @@ def attend(
     return attn, (k_pages, v_pages)
 
 
+def read_block_state(state: jax.Array, at, batch: Batch, block_size: int):
+    """The state each row's chunk begins from: ``[B, rows, width]`` of
+    entry ``at`` (a layer of the pool's third side ``[layers, NB, rows,
+    width]``) of the block that holds the row's position ``p0 - 1``,
+    found through the block table; zeros where the row begins at 0.
+
+    A block's entry is the state after the last token written into it.
+    A sequence only ever appends behind its own last token (a chunk
+    follows the chunk before it, a decode step the token before it) or
+    behind a prefix hit, which ends on a block's boundary, where the
+    cached block is full and its entry is the state at that boundary: so
+    the entry of the block of ``p0 - 1`` is the state after ``p0 - 1``,
+    with no copy and no bookkeeping beyond the block table."""
+    layers, NB = state.shape[:2]
+    p0 = batch.positions[:, 0]
+    block = jnp.take_along_axis(
+        batch.block_tables,
+        (jnp.maximum(p0 - 1, 0) // block_size)[:, None], axis=1)[:, 0]
+    held = state.reshape((layers * NB,) + state.shape[2:])[at * NB + block]
+    return jnp.where((p0 > 0)[:, None, None], held, 0)
+
+
+def write_block_state(state: jax.Array, at, batch: Batch, block_size: int,
+                      inputs: jax.Array):
+    """Entry ``at`` of every block the chunk writes a token into: the
+    ``rows`` inputs up to and including the last position the chunk
+    writes there. ``inputs [B, rows + T, width]`` is what
+    :func:`read_block_state` gave followed by the chunk's own, so a block
+    the chunk enters with one token still gets the token before it. The
+    last written position of a block is found from ``seq_lens`` (a padded
+    row of a group writes its true tail) and its block from the slot of
+    that position (a slot of -1 writes nothing: padding, or a decode row
+    that holds no sequence). Scattered through the flat view, in place
+    on the scan's carry, as ``write_kv_pages`` writes the pages."""
+    layers, NB, rows, width = state.shape
+    B, T = batch.positions.shape
+    p0 = batch.positions[:, :1]
+    last = p0 + batch.seq_lens[:, None] - 1  # [B, 1] last position written
+    touched = 1 if T == 1 else -(-T // block_size) + 1  # static
+    block = p0 // block_size + jnp.arange(touched)[None, :]  # [B, touched]
+    tail = jnp.minimum((block + 1) * block_size - 1, last)
+    i = jnp.clip(tail - p0, 0, T - 1)  # the tail's index in the chunk
+    slot = jnp.take_along_axis(batch.slot_mapping, i, axis=1)
+    live = (block * block_size <= last) & (slot >= 0)
+    # inputs[:, rows + i] is position ``tail``'s.
+    take = (i + 1)[..., None] + jnp.arange(rows)  # [B, touched, rows]
+    values = jnp.take_along_axis(
+        inputs, take.reshape(B, touched * rows, 1), axis=1)
+    flat = state.reshape(layers * NB, rows, width)
+    flat = flat.at[
+        jnp.where(live, at * NB + slot // block_size, layers * NB).reshape(-1)
+    ].set(values.reshape(B * touched, rows, width).astype(state.dtype),
+          mode="drop")
+    return flat.reshape(state.shape)
+
+
 def scan_layers(layer_fn, x: jax.Array, kv_pages: Tuple, xs):
     """``layer_fn(x, per_layer, kv, l) -> (x, kv)`` over the leading axis
     of every leaf of ``xs`` (``None`` is an empty pytree: a family
@@ -113,17 +171,18 @@ def scan_layers(layer_fn, x: jax.Array, kv_pages: Tuple, xs):
     gather). Loop carries alias in place under XLA, so only the touched
     pages move: per-layer slices (or pages in the scan ys) would copy
     the entire pool every forward step. With an int8 cache each side is
-    a (data, scales) tuple that rides the carry the same way."""
-    k_all, v_all = kv_pages
+    a (data, scales) tuple that rides the carry the same way, and a
+    third side (a state per block, ``Family.block_state``) rides beside
+    the two as one more entry of ``kv_pages``."""
 
     def body(carry, per_layer):
-        x, k_all, v_all, l = carry
-        x, (k_all, v_all) = layer_fn(x, per_layer, (k_all, v_all), l)
-        return (x, k_all, v_all, l + 1), None
+        x, sides, l = carry
+        x, sides = layer_fn(x, per_layer, sides, l)
+        return (x, tuple(sides), l + 1), None
 
-    (x, k_all, v_all, _), _ = jax.lax.scan(
-        body, (x, k_all, v_all, jnp.int32(0)), xs)
-    return x, (k_all, v_all)
+    (x, sides, _), _ = jax.lax.scan(
+        body, (x, tuple(kv_pages), jnp.int32(0)), xs)
+    return x, sides
 
 
 def take_last_token(x: jax.Array, last_token: jax.Array | None):

@@ -53,6 +53,7 @@ from production_stack_tpu.models.config import (
     SLIDING_ATTENTION,
     ModelConfig,
     RopeParams,
+    rope_params,
 )
 from production_stack_tpu.models.registry import Family
 
@@ -314,6 +315,36 @@ def _no_single_layer(*args, **kwargs):
         "is its loop, and it has no pipeline stages yet")
 
 
+def config_fields(hf: dict, layers: int) -> dict:
+    """The ``ModelConfig`` fields this family reads of its own keys."""
+    mlp_kinds = hf["mlp_layer_types"][:layers]
+    dense = next((i for i, kind in enumerate(mlp_kinds) if kind != "dense"),
+                 layers)
+    if "dense" in mlp_kinds[dense:] or sorted(
+            hf.get("mlp_only_layers", range(dense))) != list(range(dense)):
+        raise ValueError("dense MLP layers are served as a leading run "
+                         f"only; got {mlp_kinds}")
+    if hf.get("moe_router_logit_softcapping") or hf.get(
+            "moe_apply_router_weight_on_input"):
+        raise ValueError("router soft-capping and router weights on the "
+                         "expert's input are not implemented")
+    return dict(
+        layer_types=tuple(hf["layer_types"][:layers]),
+        heads_per_layer=tuple(hf["num_attention_heads_per_layer"][:layers]),
+        sliding_window=hf.get("sliding_window") or 0,
+        rope_by_kind=tuple(
+            (kind, rope_params(block))
+            for kind, block in sorted(hf.get("rope_parameters", {}).items())
+            if isinstance(block, dict)),
+        moe_intermediate_size=hf.get("moe_intermediate_size", 0),
+        shared_expert_size=hf.get("shared_expert_intermediate_size", 0),
+        routed_scaling=float(hf.get("moe_routed_scaling_factor", 1.0)),
+        dense_layers=dense,
+        chips_per_layer=hf.get("chips_per_layer", 1),
+        layer_share=hf.get("layer_share", 0),
+    )
+
+
 def _replicated(*paths_and_ranks):
     return {path: P(*[None] * rank) for path, rank in paths_and_ranks}
 
@@ -342,6 +373,7 @@ FAMILY = Family(
           for leaf in ("shared_gate", "shared_up", "shared_down"))),
     per_layer_keys=("layer_types", "mlp_layer_types",
                     "num_attention_heads_per_layer"),
+    config_fields=config_fields,
     stats=moe.STATS,
 )
 

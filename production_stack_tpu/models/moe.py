@@ -1,4 +1,4 @@
-"""The routed expert layer both MoE families share (Mixtral, Laguna).
+"""The routed expert layer the MoE families share (Mixtral, Laguna, LFM2).
 
 One chip of the ``chips`` that share a layer holds a block of the
 experts: ``held = E / chips`` of them, block ``share``. The router keeps
@@ -48,14 +48,29 @@ def swiglu(h: jax.Array, w_gate, w_up, w_down) -> jax.Array:
     return (gate * (h @ w_up)) @ w_down
 
 
-def route(h: jax.Array, router: jax.Array, k: int, *, scaling: float = 1.0
-          ) -> Tuple[jax.Array, jax.Array]:
+def route(h: jax.Array, router: jax.Array, k: int, *, scaling: float = 1.0,
+          scoring: str = "softmax", bias: jax.Array | None = None,
+          eps: float = 0.0) -> Tuple[jax.Array, jax.Array]:
     """(weights [N, k] float32, experts [N, k]) of the tokens ``h [N, Hd]``
-    over all of ``router``'s outputs: float32 softmax, top k, weights
-    renormalised to sum 1 and multiplied by ``scaling``."""
+    over all of ``router``'s outputs: float32 scores (``softmax`` over
+    them all, or a ``sigmoid`` of each), top k, weights renormalised to
+    sum 1 (``+ eps``) and multiplied by ``scaling``. With ``bias`` (float32
+    ``[E]``) the experts are *selected* by score plus bias and *weighted*
+    by the score without it."""
     logits = jnp.dot(h, router, preferred_element_type=jnp.float32)
-    weights, experts = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
-    weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    if scoring == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    elif scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    else:
+        raise ValueError(f"router scoring {scoring!r} is not implemented")
+    if bias is None:
+        weights, experts = jax.lax.top_k(scores, k)
+    else:
+        _, experts = jax.lax.top_k(scores + bias, k)
+        weights = jnp.take_along_axis(scores, experts, axis=-1)
+    total = jnp.sum(weights, axis=-1, keepdims=True)
+    weights = weights / (total + eps if eps else total)
     return weights * scaling, experts
 
 
@@ -69,6 +84,7 @@ def expert_layer(
     share: int = 0,  # this chip's block of experts: [share * held, ...)
     scaling: float = 1.0,
     valid: jax.Array | None = None,  # [B, T] bool: padding routes nowhere
+    routing: Dict | None = None,  # route()'s scoring / bias / eps
 ) -> Tuple[jax.Array, jax.Array]:
     """The routed experts held here on ``h``. Returns (their weighted sum
     per token [B, T, Hd], the :data:`STATS` of the call as int32 [3]: the
@@ -79,7 +95,8 @@ def expert_layer(
     held = p["w_gate"].shape[1]
     x = h.reshape(N, Hd)
     with jax.named_scope("moe_router"):
-        weights, experts = route(x, p["router"], k, scaling=scaling)
+        weights, experts = route(x, p["router"], k, scaling=scaling,
+                                 **(routing or {}))
     with jax.named_scope("moe_experts"):
         local = experts - share * held
         mine = (local >= 0) & (local < held)

@@ -22,6 +22,7 @@ ARCH_MODULES = {
     "opt": "production_stack_tpu.models.opt",
     "mixtral": "production_stack_tpu.models.mixtral",
     "laguna": "production_stack_tpu.models.laguna",
+    "lfm2": "production_stack_tpu.models.lfm2",
 }
 
 
@@ -83,6 +84,39 @@ class Family:
     # name, which the step programs sum over their forwards and the step
     # record carries under these names (engine/core.py, obs/steps.py).
     stats: Tuple[str, ...] = ()
+    # ``(config.json as a dict, num_hidden_layers) -> ModelConfig fields``
+    # of a family with ``per_layer_keys``: what it reads of its own keys,
+    # laid over the common ones (models/config.py names no family's).
+    config_fields: Callable | None = None
+    # ``(cfg) -> int``: how many of its layers hold KV pages, where not
+    # all do: the pool's pages are ``[that many, NB, bs, ...]`` and the
+    # family numbers them itself. None: every layer.
+    page_layers: Callable | None = None
+    # ``(cfg) -> (layers, rows, width)`` of a state per cache block that
+    # rides beside the pages as the pool's third side ``[layers, NB, rows,
+    # width]`` (models/decoder.py::read_block_state; docs/engine.md):
+    # ``loop`` then takes and returns ``(k, v, state)``. A block's entry
+    # is the state after the last token written into it, so a full
+    # block's is the state at its boundary and a prefix hit brings pages
+    # and state together. None: pages only. Surfaces that move pages
+    # (engine/core.py) move the state or are refused at start-up:
+    # speculation, host offload, the cache server, int8 weights, LoRA
+    # slots, pipeline stages and a mesh of several devices are not
+    # taught it yet.
+    block_state: Callable | None = None
+
+
+def page_layers(cfg: ModelConfig) -> int:
+    """Layers of ``cfg``'s model that hold KV pages."""
+    held = get_family(cfg.arch).page_layers
+    return cfg.num_layers if held is None else held(cfg)
+
+
+def block_state_shape(cfg: ModelConfig) -> Tuple[int, int, int] | None:
+    """(layers, rows, width) of the state a cache block holds beside its
+    pages, None for a family without one."""
+    state = get_family(cfg.arch).block_state
+    return None if state is None else state(cfg)
 
 
 def _module(arch: str):

@@ -260,6 +260,11 @@ class StepRecorder:
         (``waiting``, ``running``, the pool counts)."""
         self._notes.update(fields)
 
+    def note_sum(self, **counts) -> None:
+        """Counts that add up over the dispatches of one iteration."""
+        for name, value in counts.items():
+            self._notes[name] = self._notes.get(name, 0) + value
+
     def note_program(self, name: str, padded_tokens: int = 0) -> None:
         """A step program dispatched in this iteration, and for a prefill
         program the token positions it computes on (its rows x bucket,
@@ -268,8 +273,7 @@ class StepRecorder:
         if name not in self._programs:
             self._programs.append(name)
         if padded_tokens:
-            self._notes["padded_tokens"] = (
-                self._notes.get("padded_tokens", 0) + padded_tokens)
+            self.note_sum(padded_tokens=padded_tokens)
 
     # -- recording --------------------------------------------------------
 

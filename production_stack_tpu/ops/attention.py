@@ -63,15 +63,70 @@ def _use_pallas() -> bool:
     return jax.devices()[0].platform == "tpu"
 
 
-def _page_tile_ok(block_size: int, kvh: int, head_dim: int) -> bool:
+def _page_tile_ok(block_size: int, kvh: int, head_dim: int,
+                  packed: bool = False) -> bool:
     """Trace-time tile-alignment gate shared by the paged kernels. The
     manual page DMAs slice [bs, KVH, D] out of HBM: Mosaic requires the
     sliced dims tile-aligned (KVH to the 8-row sublane, D to the 128
-    lanes, bs to 8). Misaligned models (e.g. OPT: 12 kv-heads, head_dim
-    64) take the XLA reference — and this MUST be decided at trace
-    time: a Mosaic failure surfaces when the enclosing jit compiles,
-    where no fallback is possible."""
-    return block_size % 8 == 0 and kvh % 8 == 0 and head_dim % 128 == 0
+    lanes, bs to 8). Four rows of bf16 or float32 are a tile of their own
+    (``T(4,128)(2,1)`` for bf16: the v5e compiler takes both kernels
+    there, tests/test_chip_compile.py), admitted only for the rows
+    :func:`packed_page_dims` made of eight 64-wide heads (``packed``):
+    four heads of 128, or eight sharded two ways, take the reference as
+    they did. Misaligned models (e.g. OPT: 12 kv-heads, head_dim 64)
+    take the XLA reference — and this MUST be decided at trace time: a
+    Mosaic failure surfaces when the enclosing jit compiles, where no
+    fallback is possible."""
+    rows_ok = kvh % 8 == 0 or (packed and kvh == 4)
+    return block_size % 8 == 0 and rows_ok and head_dim % 128 == 0
+
+
+def packed_page_dims(kvh: int, head_dim: int, quantized: bool = False):
+    """``(rows, lanes)`` a page keeps of one token: ``(kvh, head_dim)``,
+    or, for heads narrower than the 128 lanes, ``128 // head_dim`` heads
+    side by side in each 128-lane row where that is a layout the paged
+    kernels take (eight heads of 64: ``(4, 128)``). A row-major view of
+    the same values, so a page's bytes are what they were without a
+    lane's padding (2 KiB a token and layer at 8 x 64 in bf16, not the 8
+    of ``(16, 128)`` tiles), and the kernels run as they are on
+    ``rows`` heads of 128 (:func:`_packed_queries`). Decided from shapes
+    alone: the pool has one layout on every platform. int8 pages keep
+    ``(kvh, head_dim)`` (their scales are per head) and the reference."""
+    per_row = 128 // head_dim if head_dim < 128 and 128 % head_dim == 0 else 1
+    if (per_row > 1 and not quantized and kvh % per_row == 0
+            and _page_tile_ok(8, kvh // per_row, 128, packed=True)):
+        return kvh // per_row, 128
+    return kvh, head_dim
+
+
+def _packed_queries(q: jax.Array, rows: int, per_row: int) -> jax.Array:
+    """``q [..., H, D]`` as queries over ``rows`` heads of ``per_row * D``
+    lanes: a query head of KV head ``e`` of a row keeps its values in
+    lanes ``[e * D, (e + 1) * D)`` and zeros elsewhere, so its dot with
+    the row is its dot with its own head. ``[..., rows * per_row * G,
+    per_row * D]``, a row's ``per_row * G`` queries together."""
+    *lead, H, D = q.shape
+    G = H // (rows * per_row)
+    eye = jnp.eye(per_row, dtype=q.dtype)
+    spread = (q.reshape(*lead, rows, per_row, G, 1, D)
+              * eye[:, None, :, None])  # [..., rows, e, G, e', D]
+    return spread.reshape(*lead, H, per_row * D)
+
+
+def _unpacked_outputs(out: jax.Array, rows: int, per_row: int) -> jax.Array:
+    """The inverse on the attention output ``[..., H, per_row * D]``: a
+    query keeps the lanes of its own head (the others hold the row's
+    other heads' values under this head's probabilities)."""
+    *lead, H, lanes = out.shape
+    D, G = lanes // per_row, H // (rows * per_row)
+    out = out.reshape(*lead, rows, per_row, G, per_row, D)
+    own = jnp.stack([out[..., e, :, e, :] for e in range(per_row)], axis=-3)
+    return own.reshape(*lead, H, D)
+
+
+def _lanes_per_head(q: jax.Array, k_pages) -> int:
+    """How many of ``q``'s heads share a row of the pages (1: none)."""
+    return kv_page_data(k_pages).shape[-1] // q.shape[-1]
 
 
 # (mesh, axis) while a model whose KV pool is sharded over kv heads is
@@ -114,9 +169,12 @@ def shard_paged_kernels(apply, pages_sharding):
 
 
 def attention_path(block_size: int, kvh: int, head_dim: int,
-                   quantized: bool, kv_shards: int = 1) -> str:
+                   quantized: bool, kv_shards: int = 1,
+                   packed: bool = False) -> str:
     """Which backend a paged-attention dispatch with these (static) page
-    shapes takes: ``"pallas"`` or ``"xla"``. THE decision — both
+    shapes (the pool's own; ``packed``: its rows are narrow heads side
+    by side, :func:`packed_page_dims`) takes: ``"pallas"`` or ``"xla"``.
+    THE decision — both
     dispatchers and the engine's dispatch counter evaluate it, from
     shapes, platform and the env override only. ``kv_shards`` is the
     number of ways the pool's kv-head axis is sharded: the kernel then
@@ -125,19 +183,20 @@ def attention_path(block_size: int, kvh: int, head_dim: int,
     the heads, so a shard cannot address its own."""
     if kv_shards > 1 and quantized:
         return "xla"
-    if (_page_tile_ok(block_size, kvh // kv_shards, head_dim)
+    if (_page_tile_ok(block_size, kvh // kv_shards, head_dim,
+                      packed and not quantized and kv_shards == 1)
             and _use_pallas()):
         return "pallas"
     return "xla"
 
 
-def _traced_path(op: str, k_pages) -> str:
+def _traced_path(op: str, k_pages, packed: bool = False) -> str:
     """The dispatch decision for the trace in progress, counted."""
     _, _, bs, kvh, head_dim = kv_page_data(k_pages).shape
     shard = _KV_SHARD.get()
     path = attention_path(
         bs, kvh, head_dim, isinstance(k_pages, tuple),
-        shard[0].shape[shard[1]] if shard else 1)
+        shard[0].shape[shard[1]] if shard else 1, packed)
     TRACED_PATHS[op, path] += 1
     return path
 
@@ -195,10 +254,11 @@ def prefill_attention(
 
 
 def _gather_ctx(pages, block_tables: jax.Array, layer: jax.Array,
-                out_dtype=None):
+                out_dtype=None, head_dim: int | None = None):
     """Gather a batch's context from stacked pages [L, NB, bs, KVH, D]
     without materializing a whole layer: page-level indices into the
-    (L*NB)-page flat view. Quantized (data, scales) pages are gathered
+    (L*NB)-page flat view; with ``head_dim`` the heads come back one
+    each, ``[B, S, KVH, head_dim]``, whatever the pool's rows. Quantized (data, scales) pages are gathered
     page-wise too — int8 bytes over the wire — then dequantized (f32
     multiply, always) right before use.
 
@@ -217,6 +277,9 @@ def _gather_ctx(pages, block_tables: jax.Array, layer: jax.Array,
         flat_s = pages[1].reshape(L * NB, bs, KVH)
         ctx_s = flat_s[idx].reshape(B, MAXB * bs, KVH)
         ctx = ctx.astype(jnp.float32) * ctx_s[..., None]
+    if head_dim is not None and head_dim != D:
+        # Heads side by side in a row (packed_page_dims): one each again.
+        ctx = ctx.reshape(B, MAXB * bs, -1, head_dim)
     return ctx.astype(out_dtype if out_dtype is not None else jnp.float32)
 
 
@@ -255,8 +318,9 @@ def context_prefill_attention(
     choice never changes results beyond accumulation order. The choice
     is made once, at trace time (:func:`attention_path`); a kernel that
     then fails to compile or run raises."""
+    per_row = _lanes_per_head(q, k_pages)
     if (k_new is not None and v_new is not None and suffix_lens is not None
-            and _traced_path("prefill", k_pages) == "pallas"):
+            and _traced_path("prefill", k_pages, per_row > 1) == "pallas"):
         from production_stack_tpu.ops.pallas_prefill_attention import (
             pallas_prefill_attention,
         )
@@ -270,6 +334,17 @@ def context_prefill_attention(
                 q, k_pages, v_pages, block_tables, positions, total_lens,
                 layer, k_new, v_new, suffix_lens, scale=scale, **bound)
 
+        if per_row > 1:
+            # Narrow heads side by side in the pages' rows: the kernel
+            # runs on the rows as heads of 128, the chunk's fresh k/v
+            # viewed the same way.
+            rows = k_pages.shape[3]
+            as_rows = k_new.shape[:2] + k_pages.shape[3:]
+            out = kernel(
+                _packed_queries(q, rows, per_row), k_new.reshape(as_rows),
+                v_new.reshape(as_rows), k_pages, v_pages, block_tables,
+                positions, total_lens, layer, suffix_lens)
+            return _unpacked_outputs(out, rows, per_row)
         return _per_kv_shard(kernel, (4, 4, 4), 5)(
             q, k_new, v_new, k_pages, v_pages, block_tables, positions,
             total_lens, layer, suffix_lens)
@@ -295,13 +370,12 @@ def _context_prefill_reference(
     it was scattered to the pages by write_kv_pages one op earlier),
     mask causally against ``positions``, softmax."""
     B, T, H, D = q.shape
-    k_data = kv_page_data(k_pages)
-    bs = k_data.shape[2]
-    KVH = k_data.shape[3]
+    bs = kv_page_data(k_pages).shape[2]
     MAXB = block_tables.shape[1]
+    k_ctx = _gather_ctx(k_pages, block_tables, layer, q.dtype, D)
+    v_ctx = _gather_ctx(v_pages, block_tables, layer, q.dtype, D)
+    KVH = k_ctx.shape[2]
     group = H // KVH
-    k_ctx = _gather_ctx(k_pages, block_tables, layer, out_dtype=q.dtype)
-    v_ctx = _gather_ctx(v_pages, block_tables, layer, out_dtype=q.dtype)
     qg = q.reshape(B, T, KVH, group, D)
     S = MAXB * bs
     # The one-shot einsum materializes f32 scores [B, KVH, g, T, S] —
@@ -392,7 +466,10 @@ def write_kv_pages(
     scatter in place — slicing out a per-layer view first would copy the
     layer every step. Quantized (data, scales) pages quantize here, on
     the scatter: pages only ever hold int8 + scales, so every downstream
-    reader (reference, pallas, offload) sees one canonical encoding."""
+    reader (reference, pallas, offload) sees one canonical encoding.
+    The fresh values take the pages' own trailing dims: where narrow
+    heads lie side by side in a row (:func:`packed_page_dims`) that is a
+    row-major view of ``[KVH, D]``."""
     L, NB, bs, KVH, D = kv_page_data(k_pages).shape
     slots = slot_mapping.reshape(-1)
     # Layer offset; out-of-range slots are dropped by scatter mode="drop".
@@ -433,12 +510,12 @@ def paged_attention_reference(
     zeros for a row whose context is 0 or less (it holds nothing), as the
     kernel gives."""
     B, H, D = q.shape
-    k_data = kv_page_data(k_pages)
-    bs, KVH = k_data.shape[2], k_data.shape[3]
+    bs = kv_page_data(k_pages).shape[2]
     MAXB = block_tables.shape[1]
+    k_ctx = _gather_ctx(k_pages, block_tables, layer, q.dtype, D)
+    v_ctx = _gather_ctx(v_pages, block_tables, layer, q.dtype, D)
+    KVH = k_ctx.shape[2]
     group = H // KVH
-    k_ctx = _gather_ctx(k_pages, block_tables, layer, out_dtype=q.dtype)
-    v_ctx = _gather_ctx(v_pages, block_tables, layer, out_dtype=q.dtype)
     qg = q.reshape(B, KVH, group, D)
     scores = jnp.einsum(
         "bkgd,bskd->bkgs", qg, k_ctx, preferred_element_type=jnp.float32
@@ -466,7 +543,8 @@ def paged_decode_attention(
     window: int | None = None,  # static: the last ``window`` tokens only
 ) -> jax.Array:
     """Dispatch to the pallas kernel on TPU, XLA reference elsewhere."""
-    if _traced_path("decode", k_pages) == "pallas":
+    per_row = _lanes_per_head(q, k_pages)
+    if _traced_path("decode", k_pages, per_row > 1) == "pallas":
         from production_stack_tpu.ops.pallas_paged_attention import (
             pallas_paged_attention,
         )
@@ -477,6 +555,11 @@ def paged_decode_attention(
                 q, k_pages, v_pages, block_tables, context_lens, layer,
                 scale=scale, **bound)
 
+        if per_row > 1:
+            rows = k_pages.shape[3]
+            return _unpacked_outputs(
+                kernel(_packed_queries(q, rows, per_row), k_pages, v_pages,
+                       block_tables, context_lens, layer), rows, per_row)
         return _per_kv_shard(kernel, (3,), 3)(
             q, k_pages, v_pages, block_tables, context_lens, layer)
     return paged_attention_reference(

@@ -1,4 +1,5 @@
-"""Optimised v5e HLO of models.llama.apply at mistral-7b-l16's widths, for
+"""Optimised v5e HLO of models.llama.apply at mistral-7b-l16's widths (and
+of models.laguna.apply at laguna-s-2.1-l8e64's, bf16: PR 36), for
 a refactor that must not change the program (PR 31): run it on a copy of
 the parent and on the change and compare the digests; no chip needed.
 
@@ -50,8 +51,10 @@ def _body_without_locations(m):
     import base64
     from jax._src.interpreters import mlir as jmlir
     from jax._src.lib.mlir import ir
+    from jax._src.lib import tpu
     ctx = jmlir.make_ir_context()
     ctx.allow_unregistered_dialects = True
+    tpu.register_dialect(ctx)  # its memory-space attributes (SMEM scratch)
     with ctx:
         asm = ir.Module.parse(base64.b64decode(m.group(1))) \
             .operation.get_asm(enable_debug_info=False)
@@ -70,6 +73,24 @@ def params_spec(int8):
         lambda x: spec(x.shape, x.dtype), jax.eval_shape(init))
 
 
+def digest(name, fn, donate, *operands):
+    text = jax.jit(fn, donate_argnums=(donate,)).lower(
+        *operands).compile().as_text()
+    # The source-location tables of the header are metadata too.
+    text = re.sub(
+        r"\nFileNames\n.*?\nStackFrames\n.*?\n\n", "\n", text, flags=re.S)
+    text = re.sub(r", metadata=\{[^}]*\}", "", text)
+    text = re.sub(r"metadata=\{[^}]*\}", "", text)
+    # A Mosaic kernel's serialized body carries the MLIR locations of
+    # its call stack (file, line of every frame): parse it and print
+    # it without them.
+    text = re.sub(r'"body":"([^"]*)"', _body_without_locations, text)
+    with open(os.path.join(out, name + ".hlo"), "w") as f:
+        f.write(text)
+    digests[name] = hashlib.sha256(text.encode()).hexdigest()
+    print(name, digests[name], len(text), flush=True)
+
+
 pages = spec((cfg.num_layers, NB, BS, cfg.num_kv_heads, cfg.head_dim), jnp.bfloat16)
 digests = {}
 for weights in ("bf16", "int8"):
@@ -83,23 +104,41 @@ for weights in ("bf16", "int8"):
                 p, cfg, tok, pos, kv, slot, bt, cl, sl, mode=mode,
                 adapter_ids=aid, last_token=lt if last else None)
 
-        text = jax.jit(fn, donate_argnums=(3,)).lower(
-            params, spec((rows, width)), spec((rows, width)), (pages, pages),
-            spec((rows, width)), spec((rows, MAXB)), spec((rows,)),
-            spec((rows,)), spec((rows,)), spec((rows,))).compile().as_text()
-        # The source-location tables of the header are metadata too.
-        text = re.sub(
-            r"\nFileNames\n.*?\nStackFrames\n.*?\n\n", "\n", text, flags=re.S)
-        text = re.sub(r", metadata=\{[^}]*\}", "", text)
-        text = re.sub(r"metadata=\{[^}]*\}", "", text)
-        # A Mosaic kernel's serialized body carries the MLIR locations of
-        # its call stack (file, line of every frame): parse it and print
-        # it without them.
-        text = re.sub(r'"body":"([^"]*)"', _body_without_locations, text)
-        name = f"{mode}.{weights}"
-        with open(os.path.join(out, name + ".hlo"), "w") as f:
-            f.write(text)
-        digests[name] = hashlib.sha256(text.encode()).hexdigest()
-        print(name, digests[name], len(text), flush=True)
+        digest(f"{mode}.{weights}", fn, 3,
+               params, spec((rows, width)), spec((rows, width)), (pages, pages),
+               spec((rows, width)), spec((rows, MAXB)), spec((rows,)),
+               spec((rows,)), spec((rows,)), spec((rows,)))
+
+# laguna-s-2.1-l8e64 as the benchmark serves it: the model keys of its
+# file (read from <repo-root>), the three programs with the expert
+# layer's counts.
+from chipbench.registry import model_keys
+from production_stack_tpu.models import get_model_config, laguna
+
+with open(os.path.join(root, "chipbench", "configs",
+                       "laguna-s-2.1-l8e64.json")) as f:
+    os.makedirs(os.path.join(out, "laguna"), exist_ok=True)
+    with open(os.path.join(out, "laguna", "config.json"), "w") as g:
+        json.dump(model_keys(json.load(f)), g)
+lcfg = get_model_config(os.path.join(out, "laguna"))
+lparams = jax.tree_util.tree_map(
+    lambda x: spec(x.shape, x.dtype),
+    jax.eval_shape(lambda: laguna.init_params(lcfg, jax.random.key(0))))
+lpages = spec((lcfg.num_layers, 256, BS, lcfg.num_kv_heads, lcfg.head_dim),
+              jnp.bfloat16)
+for mode, rows, width, tables in (("decode", 128, 1, 16),
+                                  ("prefill", 4, 512, 16),
+                                  ("prefill_cached", 1, 256, 32)):
+    last = mode != "decode"
+
+    def fn(p, kv, tok, pos, slot, bt, cl, sl):
+        return laguna.apply(
+            p, lcfg, tok, pos, kv, slot, bt, cl, sl, mode=mode,
+            last_token=jnp.maximum(sl - 1, 0) if last else None,
+            with_stats=True)
+
+    digest(f"laguna.{mode}", fn, 1, lparams, (lpages, lpages),
+           spec((rows, width)), spec((rows, width)), spec((rows, width)),
+           spec((rows, tables)), spec((rows,)), spec((rows,)))
 with open(os.path.join(out, "digests.json"), "w") as f:
     json.dump(digests, f, indent=1)

@@ -342,3 +342,105 @@ def test_laguna_programs_compile_at_the_configurations_widths(
     experts_of_a_layer = 64 * 3072 * 1024 * 2
     assert program.memory_analysis().temp_size_in_bytes < (
         experts_of_a_layer if mode == "decode" else 2 * experts_of_a_layer)
+
+
+@pytest.mark.parametrize("tables", [4, 64])
+def test_decode_kernel_compiles_at_the_packed_rows_of_64_wide_heads(
+        one_chip, tables):
+    """Eight kv heads of 64 lie two to a 128-lane row
+    (``ops.attention.packed_page_dims``): the pool is ``[L, NB, bs, 4,
+    128]``, which the v5e keeps in tiles of ``(4, 128)`` with no padding,
+    and the decode kernel takes it as four heads of 128 with the queries
+    spread over the lanes of their own head."""
+    B, H, KVH, D = 32, 32, 8, 64
+    rows, lanes = att.packed_page_dims(KVH, D)
+    assert (rows, lanes) == (4, 128)
+
+    def spec(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pages = _pages(one_chip, rows, lanes, False)
+    text = _compile_with_kernel(
+        lambda q, k, v, bt, cl, layer: pallas_paged_attention(
+            att._packed_queries(q, rows, 2), k, v, bt, cl, layer,
+            scale=D ** -0.5),
+        spec((B, H, D), jnp.bfloat16), pages, pages, spec((B, tables)),
+        spec((B,)), spec(()))
+    assert re.search(r"bf16\[4,256,64,4,128\]\{4,3,2,1,0:T\(4,128\)\(2,1\)\}",
+                     text)
+
+
+@pytest.mark.parametrize("mode,rows,width,tables", [
+    ("decode", 32, 1, 64), ("prefill", 4, 512, 8),
+    ("prefill_cached", 1, 1024, 64)])
+def test_lfm2_programs_compile_at_the_configurations_widths(
+        one_chip, monkeypatch, tmp_path, mode, rows, width, tables):
+    """``lfm2-24b-a2b-l10`` as the benchmark serves it: the three forward
+    programs compile for the v5e with both attention kernels in them at
+    the packed rows (no XLA path traced), the weights are the 10.5 GB the
+    configuration states, and neither an expert stack nor a side of the
+    pool is copied on the way through the layers' ``cond``: the pool here
+    is 1,024 blocks (0.34 GB), and a temporary as large as one side of it
+    or as one layer's experts would be such a copy."""
+    import json
+    import os
+    import sys
+
+    from production_stack_tpu.engine.core import kv_page_dims
+    from production_stack_tpu.models import lfm2
+    from production_stack_tpu.models.registry import block_state_shape
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if repo not in sys.path:
+        sys.path.insert(0, repo)
+    from chipbench.registry import model_keys
+
+    with open(os.path.join(repo, "chipbench", "configs",
+                           "lfm2-24b-a2b-l10.json")) as f:
+        (tmp_path / "config.json").write_text(
+            json.dumps(model_keys(json.load(f))))
+    cfg = get_model_config(str(tmp_path))
+    monkeypatch.setattr(att, "_use_pallas", lambda: True)
+    att.TRACED_PATHS.clear()
+
+    def spec(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda x: spec(x.shape, x.dtype),
+        jax.eval_shape(lambda: lfm2.init_params(cfg, jax.random.key(0))))
+    weights = sum(int(np.prod(x.shape)) * x.dtype.itemsize
+                  for x in jax.tree_util.tree_leaves(params))
+    assert abs(weights / 10.5e9 - 1) < 0.03
+    blocks = 1024
+    layers, page_rows, lanes = kv_page_dims(cfg)
+    assert (layers, page_rows, lanes) == (2, 4, 128)
+    pages = spec((layers, blocks, BLOCK_SIZE, page_rows, lanes), jnp.bfloat16)
+    held = block_state_shape(cfg)
+    state = spec((held[0], blocks) + held[1:], jnp.bfloat16)
+    last = mode != "decode"
+    program = jax.jit(
+        lambda p, kv, tok, pos, slot, bt, cl, sl: lfm2.apply(
+            p, cfg, tok, pos, kv, slot, bt, cl, sl, mode=mode,
+            last_token=jnp.maximum(sl - 1, 0) if last else None,
+            with_stats=True), donate_argnums=(1,)).lower(
+        params, (pages, pages, state), spec((rows, width)),
+        spec((rows, width)), spec((rows, width)), spec((rows, tables)),
+        spec((rows,)), spec((rows,))).compile()
+    text = program.as_text()
+    assert ("pallas_paged_attention" in text) == (mode == "decode")
+    assert ("pallas_prefill_attention" in text) == (mode == "prefill_cached")
+    assert not [k for k in att.TRACED_PATHS if k[1] == "xla"]
+    one_side = layers * blocks * BLOCK_SIZE * page_rows * lanes * 2
+    experts_of_a_layer = 64 * 3 * 2048 * 1536 * 2
+    temp = program.memory_analysis().temp_size_in_bytes
+    assert temp < (one_side if mode == "decode" else experts_of_a_layer)
+    # A side that a branch of the layers' ``cond`` hands back as it got it
+    # is copied there in every layer, into the conditional's own result
+    # (no temporary shows it): on the chip such copies of k and v were
+    # three quarters of the device's time (PERF.md section 6, PR 36).
+    sides = (rf"bf16\[{layers},{blocks},{BLOCK_SIZE},{page_rows},{lanes}\]",
+             rf"bf16\[{held[0]},{blocks},{held[1]},{held[2]}\]")
+    copied = [line.strip()[:120] for line in text.splitlines()
+              if re.search(rf"= ({'|'.join(sides)})\S* copy(-start)?\(", line)]
+    assert not copied, copied

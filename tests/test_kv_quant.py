@@ -65,7 +65,8 @@ def collect(engine: EngineCore, prompt, sampling, rid="r1", timeout=180):
 # (tiny-llama's tiny head count is dominated by int8 sublane-32 padding
 # and does NOT show the real ratio.)
 _LLAMA8B = types.SimpleNamespace(
-    num_layers=32, num_kv_heads=8, head_dim=128, dtype="bfloat16")
+    arch="llama", num_layers=32, num_kv_heads=8, head_dim=128,
+    dtype="bfloat16")
 
 
 # Nats. Int8 KV pages perturb every logit a little; under random weights

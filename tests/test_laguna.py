@@ -235,9 +235,9 @@ def test_yarn_frequencies_are_hugging_faces():
              "original_max_position_embeddings": 8192, "beta_slow": 1,
              "beta_fast": 32, "attention_factor": 1.4852030263919618,
              "partial_rotary_factor": 0.5}
-    from production_stack_tpu.models.config import _rope_params
+    from production_stack_tpu.models.config import rope_params
 
-    mine, factor = laguna.rope_frequencies(_rope_params(block), 128)
+    mine, factor = laguna.rope_frequencies(rope_params(block), 128)
     theirs, their_factor = reference.inverse_frequencies(block, 128)
     np.testing.assert_allclose(mine, theirs, rtol=1e-6)
     assert factor == their_factor == 1.4852030263919618

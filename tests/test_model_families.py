@@ -25,7 +25,7 @@ from production_stack_tpu.parallel.pp_serving import make_pp_apply
 
 ARCHS = sorted(ARCH_MODULES)
 PRESET = {"llama": "tiny-llama", "opt": "tiny-opt", "mixtral": "tiny-mixtral",
-          "laguna": "tiny-laguna"}
+          "laguna": "tiny-laguna", "lfm2": "tiny-lfm2"}
 # What a family's config.json must hold beside the sizes every family
 # reads (``Family.per_layer_keys``: lists, one entry a layer or more).
 REQUIRED_KEYS = {"laguna": {
@@ -33,7 +33,12 @@ REQUIRED_KEYS = {"laguna": {
     "num_experts_per_tok": 2, "moe_intermediate_size": 16,
     "layer_types": ["full_attention", "sliding_attention"] * 2,
     "mlp_layer_types": ["dense"] + ["sparse"] * 3,
-    "num_attention_heads_per_layer": [4, 6] * 2, "sliding_window": 8}}
+    "num_attention_heads_per_layer": [4, 6] * 2, "sliding_window": 8},
+    "lfm2": {
+    "num_key_value_heads": 2, "num_experts": 4, "num_experts_per_tok": 2,
+    "moe_intermediate_size": 16, "num_dense_layers": 1, "conv_L_cache": 3,
+    "use_expert_bias": True,
+    "layer_types": ["conv", "full_attention"] * 2}}
 SIZES = dict(vocab_size=64, hidden_size=32, num_hidden_layers=2,
              num_attention_heads=4, max_position_embeddings=64)
 
@@ -43,8 +48,8 @@ def _hf_model(arch):
     family brings its line)."""
     import transformers as tf
 
-    if arch == "laguna":
-        return None  # transformers has no class of it, and no checkpoint
+    if arch in ("laguna", "lfm2"):
+        return None  # no class of it here (or no loader yet), no checkpoint
     return {
         "llama": lambda: tf.LlamaForCausalLM(tf.LlamaConfig(
             **SIZES, intermediate_size=48, num_key_value_heads=2)),
