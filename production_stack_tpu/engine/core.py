@@ -317,6 +317,14 @@ class EngineCore:
         self._repl = NamedSharding(self.mesh, PartitionSpec())
 
         self._init_fn, self._apply = build_model(self.model_config)
+        if self.mesh.size > 1:
+            # The compiler cannot partition a pallas_call: a program
+            # that spans devices keeps ragged_dot for its expert layers.
+            from production_stack_tpu.ops.pallas_grouped_matmul import (
+                on_devices,
+            )
+
+            self._apply = on_devices(self._apply, self.mesh.size)
         family = get_family(self.model_config.arch)
         # (layers, rows, width) of the state a cache block holds beside
         # its pages (Family.block_state), None for most families.
@@ -597,6 +605,11 @@ class EngineCore:
         # tpu:prefill_attention_dispatch_total{path=...}).
         self.fused_steps_total = 0
         self.prefill_attention_dispatch_total = {"pallas": 0, "xla": 0}
+        # Step programs of a model with an expert layer, by the path its
+        # grouped matmuls take at the program's tokens: "pallas" (the
+        # grouped-matmul kernel) or "xla" (ragged_dot); exported as
+        # tpu:expert_matmul_dispatch_total{path=...}.
+        self.expert_matmul_dispatch_total = {"pallas": 0, "xla": 0}
         # While set, _dispatch diverts prefill/decode ops into this list
         # (each entry (name, static, arrays, placeholder)) instead of
         # executing them; _do_fused then issues them as one "fused" op.
@@ -1403,6 +1416,7 @@ class EngineCore:
             self.prefill_padded_tokens_total += arrays[0].size
             self._steps.note_program(fn.__name__,
                                      padded_tokens=arrays[0].size)
+            self._count_expert_matmul_path(arrays[0].size)
             if self.block_state_shape:
                 self._note_block_state(arrays[1][:, 0], arrays[5])
             out, self.kv = fn(self.params, self.kv, *arrays)
@@ -1411,6 +1425,7 @@ class EngineCore:
             K = static["K"]
             fn = self._multi_decode_fn(K)
             self._steps.note_program(fn.__name__)
+            self._count_expert_matmul_path(arrays[0].shape[0])
             # Feedback tokens always carry the FULL decode_steps width
             # (bursts pad their output) so adaptive widths share shapes.
             tokens_prev = (
@@ -2587,6 +2602,8 @@ class EngineCore:
             "fused_steps_total": self.fused_steps_total,
             "prefill_attention_dispatch_total":
                 dict(self.prefill_attention_dispatch_total),
+            "expert_matmul_dispatch_total":
+                dict(self.expert_matmul_dispatch_total),
             "dispatch_count_total": phases["enqueue"]["count"],
             "dispatch_enqueue_s": round(phases["enqueue"]["seconds"], 3),
             "decode_forward_steps_total": self.decode_forward_steps_total,
@@ -3010,6 +3027,23 @@ class EngineCore:
             self._pending_prefills.append(
                 {"req": req, "seq": seq, "slot": slot,
                  "sampled": sampled, "row": row})
+
+    def _count_expert_matmul_path(self, tokens: int) -> None:
+        """One step program of ``tokens`` tokens a forward was dispatched:
+        count it under the path its expert layers' grouped matmuls take
+        (the trace-time choice of models/moe.py, evaluated again from the
+        same shapes). A model without experts counts nothing."""
+        mc = self.model_config
+        if not mc.is_moe:
+            return
+        from production_stack_tpu.ops.pallas_grouped_matmul import (
+            grouped_matmul_path,
+        )
+
+        self.expert_matmul_dispatch_total[grouped_matmul_path(
+            tokens * mc.experts_per_token, mc.hidden_size,
+            mc.moe_intermediate_size or mc.intermediate_size,
+            mc.dtype, mc.num_experts, devices=self.mesh.size)] += 1
 
     def _paged_attn_path(self) -> str:
         """Which attention path cached-prefill and decode dispatches take

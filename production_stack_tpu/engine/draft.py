@@ -77,6 +77,12 @@ class DraftModel:
         # -- parameters (sharded over the shared mesh; no LoRA slots —
         # the drafter proposes for every adapter, verify applies them) --
         init_fn, self._apply = build_model(mc)
+        if mesh.size > 1:  # as the target's forward: engine/core.py
+            from production_stack_tpu.ops.pallas_grouped_matmul import (
+                on_devices,
+            )
+
+            self._apply = on_devices(self._apply, mesh.size)
         rng = jax.random.key(config.seed)
         shapes = jax.eval_shape(lambda: init_fn(mc, rng))
         self._param_shardings = param_shardings(mc, mesh, shapes)

@@ -2614,6 +2614,15 @@ class EngineServer:
             f"{s.get('prefill_attention_dispatch_total', {}).get('pallas', 0)}",
             f'tpu:prefill_attention_dispatch_total{{{labels},path="xla"}} '
             f"{s.get('prefill_attention_dispatch_total', {}).get('xla', 0)}",
+            # Step programs of a model with an expert layer, by the path
+            # its grouped matmuls take: "pallas" (the grouped-matmul
+            # kernel) vs "xla" (ragged_dot: off the TPU, across devices,
+            # or a shape that does not tile). Both label values always.
+            "# TYPE tpu:expert_matmul_dispatch counter",
+            f'tpu:expert_matmul_dispatch_total{{{labels},path="pallas"}} '
+            f"{s.get('expert_matmul_dispatch_total', {}).get('pallas', 0)}",
+            f'tpu:expert_matmul_dispatch_total{{{labels},path="xla"}} '
+            f"{s.get('expert_matmul_dispatch_total', {}).get('xla', 0)}",
             # Structured output (guided_json / guided_regex /
             # response_format): grammar constraints compiled to token FSMs
             # applied inside the fused programs.

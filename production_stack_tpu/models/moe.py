@@ -4,10 +4,16 @@ One chip of the ``chips`` that share a layer holds a block of the
 experts: ``held = E / chips`` of them, block ``share``. The router keeps
 its published width and scores all ``E``; every token takes its top
 ``k``; the assignments that land on this chip's block are sorted by
-expert and go through ONE grouped matmul per projection
-(``jax.lax.ragged_dot``, which the TPU compiler lowers to a grouped
-Mosaic matmul that visits only the row tiles of the groups it is given:
-step 0 of PR 33 on the chip, PERF.md section 6); the outputs are weighted
+expert and go through ONE grouped matmul per projection: **the Pallas
+grouped matmul on the chip** (``ops/pallas_grouped_matmul.py``: each
+expert's weights stream once, in tiles of megabytes chosen from the
+shapes, only the row tiles of groups that have rows are visited, and
+the gate and up projections share one pass over the rows),
+**``jax.lax.ragged_dot`` elsewhere** (off the TPU, in a program that
+spans devices, at a shape that does not tile). One trace-time choice a
+layer (``grouped_matmul_path``), counted per dispatched step program in
+the engine's ``expert_matmul_dispatch_total{path}``; PERF.md section 6,
+PR 37 has both on the chip. Then the outputs are weighted
 and summed back per token. No capacity and no dropped token: the rows
 are as many as there are assignments, ``tokens x k``, and what lands on
 another chip's experts is sorted past the last group, where the grouped
@@ -26,8 +32,8 @@ the v5e compiler ran out of memory on the copies alone). So a family
 passes its expert leaves as they are stacked over its sparse layers,
 with the layer's index ``at``: the stack ``[layers, held, ...]`` is read
 as ``layers x held`` groups (a reshape of leading dims,
-no copy) whose sizes are zero but for this layer's block, and the kernel
-visits only the row tiles of groups that have rows.
+no copy): the Pallas kernel adds ``at x held`` to the group in its index
+map, ``ragged_dot`` gets sizes that are zero but for this layer's block.
 """
 
 from __future__ import annotations
@@ -36,6 +42,8 @@ from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+
+from production_stack_tpu.ops import pallas_grouped_matmul as gmm
 
 # What :func:`expert_layer` counts of one call, in this order.
 STATS = ("moe_assignments", "moe_experts_hit", "moe_max_expert_load")
@@ -109,19 +117,35 @@ def expert_layer(
         sizes = jnp.zeros((held + 1,), jnp.int32).at[group].add(1)[:held]
         rows = x[order // k]  # [N * k, Hd], by expert
 
-        def grouped(lhs, name):
-            w = p[name]
-            layers = w.shape[0]
+        # One trace-time choice for the layer's three matmuls (their row
+        # tile is the same, so one set of visits serves them).
+        m = N * k
+        path = gmm.traced_path(m, *p["w_up"].shape[2:], h.dtype, held)
+        if path == "pallas":
+            up_tiles, down_tiles = (
+                gmm.grouped_matmul_tiles(m, *p[name].shape[2:], h.dtype, held)
+                for name in ("w_up", "w_down"))
+            visits = gmm.group_visits(sizes, m, up_tiles[0])
+            # silu(rows Wgate) * (rows Wup) in one pass over the rows.
+            act = gmm.grouped_matmul(rows, p["w_up"], visits, at, up_tiles,
+                                     gate=p["w_gate"])
+            out = gmm.grouped_matmul(act, p["w_down"], visits, at,
+                                     down_tiles)
+        else:
+            layers = p["w_up"].shape[0]
             in_stack = jax.lax.dynamic_update_slice(
                 jnp.zeros((layers * held,), jnp.int32), sizes,
                 (jnp.asarray(at, jnp.int32) * held,))
-            return jax.lax.ragged_dot(
-                lhs, w.reshape((layers * held,) + w.shape[2:]), in_stack)
 
-        gate = grouped(rows, "w_gate")
-        up = grouped(rows, "w_up")
-        act = jax.nn.silu(gate.astype(jnp.float32)).astype(h.dtype) * up
-        out = grouped(act, "w_down")  # [N * k, Hd]
+            def grouped(lhs, name):
+                w = p[name]
+                return jax.lax.ragged_dot(
+                    lhs, w.reshape((layers * held,) + w.shape[2:]), in_stack)
+
+            gate = grouped(rows, "w_gate")
+            up = grouped(rows, "w_up")
+            act = jax.nn.silu(gate.astype(jnp.float32)).astype(h.dtype) * up
+            out = grouped(act, "w_down")  # [N * k, Hd]
         # Back to the token's order; a row past the groups is not the
         # grouped matmul's to define, so it is replaced, not multiplied.
         out = out[jnp.argsort(order)].reshape(N, k, Hd)

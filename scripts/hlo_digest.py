@@ -37,6 +37,13 @@ from production_stack_tpu.ops import attention as att
 assert os.path.realpath(llama.__file__).startswith(os.path.realpath(root)), llama.__file__
 
 att._use_pallas = lambda: True
+try:  # since PR 37 the expert layers choose a path too (a tree before it
+    # has no such module: its Laguna programs hold ragged_dot)
+    from production_stack_tpu.ops import pallas_grouped_matmul as gmm
+
+    gmm._platform = lambda: "tpu"
+except ImportError:
+    pass
 topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
 one = SingleDeviceSharding(topo.devices[0])
 

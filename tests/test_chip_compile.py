@@ -25,6 +25,7 @@ from jax.sharding import SingleDeviceSharding
 from production_stack_tpu.models import build_model, get_model_config
 from production_stack_tpu.models.config import ModelConfig
 from production_stack_tpu.ops import attention as att
+from production_stack_tpu.ops import pallas_grouped_matmul as gmm
 from production_stack_tpu.ops.pallas_paged_attention import (
     pallas_paged_attention,
 )
@@ -285,18 +286,33 @@ def test_decode_kernel_compiles_at_lagunas_query_groups(one_chip, heads,
         spec((B, tables)), spec((B,)), spec(()))
 
 
+def _holds_the_grouped_matmul_kernel(text):
+    """The expert layers' matmuls are the Pallas kernel, traced once per
+    kind of layer, nothing of ``jax.lax.ragged_dot`` is left, and no
+    expert stack (four dims, or the ``layers x held`` groups the kernel
+    reads) is copied on its way there."""
+    assert att.TRACED_PATHS["grouped_matmul", "pallas"] >= 1
+    assert att.TRACED_PATHS["grouped_matmul", "xla"] == 0
+    assert "pallas_grouped_matmul" in text and "ragged-dot" not in text
+    copied = [line.strip()[:120] for line in text.splitlines()
+              if re.search(r"= bf16\[(\d+,64|\d{3,}),\d{4},\d{4}\]\S* "
+                           r"copy(-start)?\(", line)]
+    assert not copied, copied
+
+
 @pytest.mark.parametrize("mode,rows,width,tables", [
-    ("decode", 128, 1, 16), ("prefill", 1, 1024, 16),
+    ("decode", 128, 1, 16), ("prefill", 1, 1024, 16), ("prefill", 4, 512, 8),
     ("prefill_cached", 1, 1024, 32)])
 def test_laguna_programs_compile_at_the_configurations_widths(
         one_chip, monkeypatch, tmp_path, mode, rows, width, tables):
     """``laguna-s-2.1-l8e64`` as the benchmark serves it (the model keys of
     its file, written to a ``config.json`` as ``chipbench.stack`` does):
     the three forward programs with the expert layer's counts compile for
-    the v5e, with both attention kernels in them, the weights are the
-    10.1 GB the configuration states, and no copy of an expert stack (or
-    of any other weight) is made on the way to its matmul: a temporary as
-    large as one layer's routed experts would be one."""
+    the v5e, with both attention kernels and the grouped-matmul kernel in
+    them (no ``ragged_dot`` left), the weights are the 10.1 GB the
+    configuration states, and no copy of an expert stack (or of any other
+    weight) is made on the way to its matmul: a temporary as large as one
+    layer's routed experts would be one."""
     import json
     import os
     import sys
@@ -314,6 +330,8 @@ def test_laguna_programs_compile_at_the_configurations_widths(
             json.dumps(model_keys(json.load(f))))
     cfg = get_model_config(str(tmp_path))
     monkeypatch.setattr(att, "_use_pallas", lambda: True)
+    monkeypatch.setattr(gmm, "_platform", lambda: "tpu")
+    att.TRACED_PATHS.clear()
 
     def spec(shape, dtype=jnp.int32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -339,6 +357,7 @@ def test_laguna_programs_compile_at_the_configurations_widths(
     assert "tpu_custom_call" in text
     assert ("pallas_paged_attention" in text) == (mode == "decode")
     assert ("pallas_prefill_attention" in text) == (mode == "prefill_cached")
+    _holds_the_grouped_matmul_kernel(text)
     experts_of_a_layer = 64 * 3072 * 1024 * 2
     assert program.memory_analysis().temp_size_in_bytes < (
         experts_of_a_layer if mode == "decode" else 2 * experts_of_a_layer)
@@ -401,6 +420,7 @@ def test_lfm2_programs_compile_at_the_configurations_widths(
             json.dumps(model_keys(json.load(f))))
     cfg = get_model_config(str(tmp_path))
     monkeypatch.setattr(att, "_use_pallas", lambda: True)
+    monkeypatch.setattr(gmm, "_platform", lambda: "tpu")
     att.TRACED_PATHS.clear()
 
     def spec(shape, dtype=jnp.int32):
@@ -431,6 +451,7 @@ def test_lfm2_programs_compile_at_the_configurations_widths(
     assert ("pallas_paged_attention" in text) == (mode == "decode")
     assert ("pallas_prefill_attention" in text) == (mode == "prefill_cached")
     assert not [k for k in att.TRACED_PATHS if k[1] == "xla"]
+    _holds_the_grouped_matmul_kernel(text)
     one_side = layers * blocks * BLOCK_SIZE * page_rows * lanes * 2
     experts_of_a_layer = 64 * 3 * 2048 * 1536 * 2
     temp = program.memory_analysis().temp_size_in_bytes

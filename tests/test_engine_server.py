@@ -163,6 +163,7 @@ def test_metrics_exposition(server_url):
         # export (at zero / with both label values) without --fused-step.
         assert "tpu:fused_steps_total" in text
         assert "tpu:prefill_attention_dispatch_total{" in text
+        assert "tpu:expert_matmul_dispatch_total{" in text
         assert 'path="pallas"}' in text
         assert 'path="xla"}' in text
     asyncio.run(run())
