@@ -3148,6 +3148,7 @@ class EngineCore:
         keep: "list[dict]" = []
         steps = self._steps
         read_of = read = None
+        rows, finished0 = 0, self.requests_finished_total
         with steps.phase("emit"):
             for entry in pending:
                 sampled = entry["sampled"]
@@ -3213,10 +3214,18 @@ class EngineCore:
                         # Fresh output in this slot: its penalty counts reset
                         # at the next burst (which also counts this token).
                         self._counts_reset.add(slot)
+                if req.trace is not None:
+                    req.trace.delivered(time.time(), req.output_token_ids)
                 self._emit_token(seq, token, lp)
+                rows += 1
                 # Decode position bookkeeping starts from the emitted tokens
                 # (a re-prefill after preemption carries prior outputs).
                 req.scheduled_steps = len(req.output_token_ids)
+        # ``emit_tokens`` follows ``generation_tokens_total``, which
+        # counts a burst's tokens and not a prefill's first.
+        steps.note_sum(
+            emit_tokens=0, emit_rows=rows,
+            emit_finished=self.requests_finished_total - finished0)
         if keep:
             self._pending_prefills = keep + self._pending_prefills
 
@@ -4174,6 +4183,14 @@ class EngineCore:
             "spec": True, "drafts": drafts,
         }
 
+    # ``post(fn, *args)`` runs ``fn`` on the server's event loop, in turn
+    # behind whatever the loop was handed before: the
+    # ``call_soon_threadsafe`` that the tokens of a server's stream take.
+    # The server sets it on its core; None without one. With it a burst
+    # posts two markers, one before its first token and one after its
+    # last, which time the hand-over into the step's record.
+    post_to_server_loop: Optional[Callable[..., None]] = None
+
     def _flush_pending_burst(self) -> None:
         """Read back and emit the in-flight decode burst, if any."""
         pending = self._pending_burst
@@ -4186,39 +4203,89 @@ class EngineCore:
             # placeholder before returning.)
             return
         self._pending_burst = None
-        with self._steps.phase("readback"):
-            sampled, lps, top_lps, top_idxs = (
-                np.asarray(a) for a in jax.device_get(_unwrap_fused(out))
-            )  # [B, K], [B, K], [B, K, LOGPROB_K] x2
-        with self._steps.phase("emit"):
+        steps = self._steps
+        with steps.phase("readback"):
+            arrays = [np.asarray(a)
+                      for a in jax.device_get(_unwrap_fused(out))
+                      ]  # [B, K], [B, K], [B, K, LOGPROB_K] x2
+        # The hand-over to the server's loop, by two markers a burst: the
+        # loop's queue is first in, first out, so the first runs when the
+        # loop has woken and the second when the burst's last token is in
+        # its request's queue. A stream of the server's carries a clock.
+        post, step = self.post_to_server_loop, None
+        if (post is not None and self.step_recorder is not None
+                and any(s.req.trace is not None for s in pending["active"])):
+            step = steps.open_step()
+        if step is not None:
+            post(steps.mark, step, "deliver_wake_s", time.perf_counter())
+        tokens0 = self.generation_tokens_total
+        finished0 = self.requests_finished_total
+        sample = [0.0, 0, 0]  # callback seconds, callbacks timed, rows
+        with steps.phase("emit"):
             if pending.get("spec"):
-                self._flush_spec_burst(
-                    pending, sampled, lps, top_lps, top_idxs)
+                self._flush_spec_burst(pending, arrays, sample)
             else:
-                self._emit_burst(pending, sampled, lps, top_lps, top_idxs)
+                self._emit_burst(pending, arrays, sample)
+        if step is not None:
+            post(steps.mark, step, "deliver_drain_s", time.perf_counter())
+        steps.note_sum(
+            emit_tokens=self.generation_tokens_total - tokens0,
+            emit_finished=self.requests_finished_total - finished0,
+            emit_callback_s=round(sample[0], 7),
+            emit_callback_samples=sample[1],
+            emit_rows=sample[2])
 
-    def _emit_burst(self, pending, sampled, lps, top_lps,
-                    top_idxs) -> None:
+    def _emit_seq(self, seq: RunningSeq, upto: int, arrays,
+                  sample: list) -> int:
+        """Emit up to ``upto`` of a burst's tokens to one sequence, as
+        far as it still runs; returns how many. What the recorder and the
+        request's trace learn of the stream they learn here, once per
+        sequence and burst and never per token (``obs/steps.py``, the
+        budget): one stamp of the request's clock, before its tokens so
+        that whoever sees the stream end finds it, and the callback of
+        the first token timed into ``sample`` by a stand-in that puts the
+        request's own back (the rest of ``emit``'s callback time is
+        estimated from those)."""
+        sampled, lps, top_lps, top_idxs = arrays
+        req, slot = seq.req, seq.slot
+        want_lp = req.sampling.logprobs
+        if req.trace is not None and self.scheduler.slots[slot] is seq:
+            req.trace.delivered(time.time(), req.output_token_ids)
+        callback = req.on_token
+
+        def timed(payload, finish):
+            req.on_token = callback  # stands in for one token
+            t0 = time.perf_counter()
+            callback(payload, finish)
+            sample[0] += time.perf_counter() - t0
+            sample[1] += 1
+
+        req.on_token = timed
+        emitted = 0
+        for s in range(upto):
+            if self.scheduler.slots[slot] is not seq:
+                break  # finished / aborted / preempted mid-burst
+            lp = None
+            if want_lp is not None:
+                k = min(want_lp, top_lps.shape[2])
+                lp = {"logprob": float(lps[slot, s]),
+                      "top": [(int(top_idxs[slot, s, j]),
+                               float(top_lps[slot, s, j]))
+                              for j in range(k)]}
+            self._emit_token(seq, int(sampled[slot, s]), lp)
+            emitted += 1
+        req.on_token = callback  # where it got no token
+        self.generation_tokens_total += emitted
+        sample[2] += emitted > 0
+        return emitted
+
+    def _emit_burst(self, pending, arrays, sample) -> None:
         """Emit a plain decode burst's tokens, each sequence as far as
         it was allowed and still runs."""
         emitted_seqs = []
         for seq in pending["active"]:
             allow = pending["allows"].get(seq.req.request_id, 1)
-            want_lp = seq.req.sampling.logprobs
-            emitted = 0
-            for s in range(allow):
-                if self.scheduler.slots[seq.slot] is not seq:
-                    break  # finished / aborted / preempted mid-burst
-                lp = None
-                if want_lp is not None:
-                    k = min(want_lp, top_lps.shape[2])
-                    lp = {"logprob": float(lps[seq.slot, s]),
-                          "top": [(int(top_idxs[seq.slot, s, j]),
-                                   float(top_lps[seq.slot, s, j]))
-                                  for j in range(k)]}
-                self._emit_token(seq, int(sampled[seq.slot, s]), lp)
-                emitted += 1
-            self.generation_tokens_total += emitted
+            emitted = self._emit_seq(seq, allow, arrays, sample)
             if emitted and self.scheduler.slots[seq.slot] is seq:
                 emitted_seqs.append(seq)
         if emitted_seqs:
@@ -4231,8 +4298,7 @@ class EngineCore:
                         seq.req.request_id, seq.req.all_token_ids
                     )
 
-    def _flush_spec_burst(self, pending, sampled, lps, top_lps,
-                          top_idxs) -> None:
+    def _flush_spec_burst(self, pending, arrays, sample) -> None:
         """Emit a verify burst: accept the longest draft prefix whose
         tokens match what plain decode would have sampled, then emit the
         SAMPLES themselves — the accepted drafts ARE those samples, and
@@ -4252,23 +4318,10 @@ class EngineCore:
                 # Finished/aborted/preempted between dispatch and flush:
                 # its KV was freed wholesale, nothing to roll back.
                 continue
-            j = accepted_prefix_len(draft, sampled[seq.slot])
-            want_lp = r.sampling.logprobs
-            emitted = 0
-            for s in range(j + 1):
-                if self.scheduler.slots[seq.slot] is not seq:
-                    break  # finished mid-burst (EOS / stop / max_tokens)
-                lp = None
-                if want_lp is not None:
-                    k = min(want_lp, top_lps.shape[2])
-                    lp = {"logprob": float(lps[seq.slot, s]),
-                          "top": [(int(top_idxs[seq.slot, s, jj]),
-                                   float(top_lps[seq.slot, s, jj]))
-                                  for jj in range(k)]}
-                self._emit_token(seq, int(sampled[seq.slot, s]), lp)
-                emitted += 1
+            j = accepted_prefix_len(draft, arrays[0][seq.slot])
+            # finishing mid-burst (EOS / stop / max_tokens) ends it early
+            emitted = self._emit_seq(seq, j + 1, arrays, sample)
             r.scheduled_steps += emitted
-            self.generation_tokens_total += emitted
             self.spec_proposed_tokens_total += len(draft)
             self.spec_accepted_tokens_total += j
             source = r.spec.source if r.spec is not None else "ngram"
@@ -4365,7 +4418,9 @@ class EngineCore:
         """Deliver one generated token. When the request asked for
         logprobs, the callback payload is ``(token, lp)`` with
         ``lp = {"logprob": float, "top": [(token_id, logprob), ...]}``;
-        otherwise the bare int (the common path stays allocation-free)."""
+        otherwise the bare int (the common path stays allocation-free).
+        Nothing here reads a clock: this runs a thousand times a burst,
+        and the request's trace is stamped by the caller, once."""
         req = seq.req
         req.output_token_ids.append(token)
         if req.structured is not None and not req.structured.advance(token):
@@ -4377,12 +4432,6 @@ class EngineCore:
             logger.warning(
                 "Structured request %s emitted token %d outside its "
                 "grammar", req.request_id, token)
-        if req.trace is not None:
-            now = time.time()
-            if not req.trace.first_token:
-                req.trace.first_token = now
-            req.trace.last_token = now
-            req.trace.tokens += 1
         finish = None
         eos = getattr(self.tokenizer, "eos_token_id", None)
         n_out = len(req.output_token_ids)

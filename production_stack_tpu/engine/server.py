@@ -265,6 +265,9 @@ class EngineServer:
         """Register with the router's KV controller (retried lazily on
         each admission until it succeeds) and hook eviction reporting."""
         self._loop = asyncio.get_running_loop()
+        # The way the tokens take (``_TokenStream.on_token``): the core
+        # posts two markers a burst along it to time the hand-over.
+        self.core.post_to_server_loop = self._loop.call_soon_threadsafe
         # Hooked unconditionally (no-ops on an empty registry): the
         # controller URL can be wired after startup.
         self.core.prefix_evict_listener = self._on_prefix_evict
@@ -924,9 +927,13 @@ class EngineServer:
         # step recorder's ring here, once per request and off the engine
         # thread. The three parts sum to the span.
         steps = self.core.step_recorder
-        behind = (steps.busy_between(clock.arrival, queue_end)
-                  if steps is not None
-                  else {"decode": 0.0, "prefill": 0.0, "steps": 0})
+
+        def busy_between(t0: float, t1: float) -> dict:
+            if steps is None:
+                return {"decode": 0.0, "prefill": 0.0, "steps": 0}
+            return steps.busy_between(t0, t1)
+
+        behind = busy_between(clock.arrival, queue_end)
         trace.add_span(
             "engine.queue", clock.arrival, queue_end, parent=root,
             behind_decode_s=round(behind["decode"], 6),
@@ -954,13 +961,26 @@ class EngineServer:
                     "engine.first_token", clock.prefill_end,
                     max(clock.first_token, clock.prefill_end), parent=root)
             decode_start = clock.prefill_end or clock.first_token
-            trace.add_span(
+            decode = trace.add_span(
                 "engine.decode", decode_start,
                 max(clock.last_token, decode_start), parent=root,
                 steps=clock.tokens, tokens=clock.tokens,
                 time_to_first_token_s=round(
                     clock.first_token - clock.arrival, 6),
             )
+            if clock.gap_end:
+                # The stream's longest gap, by cause: the steps that held
+                # the loop between the two deliveries around it, read off
+                # the ring like the queue's. The step that ended the gap
+                # ends after it and is left out, so the causes add up to
+                # less than the span.
+                behind = busy_between(clock.gap_start, clock.gap_end)
+                trace.add_span(
+                    "engine.stream_gap", clock.gap_start, clock.gap_end,
+                    parent=decode,
+                    behind_decode_s=round(behind["decode"], 6),
+                    behind_prefill_s=round(behind["prefill"], 6),
+                    steps=behind["steps"], at_token=clock.gap_at_token)
         root.finish(end=now, tokens=clock.tokens)
         rec.record(trace)
 

@@ -26,10 +26,33 @@ record and, under the same names, into the host plane of any
 loop iteration), so a gap on the device can be read against what the
 host was doing, on the profiler's own clock.
 
+A phase reads the thread's CPU clock beside the wall clock
+(``phases_cpu`` / ``gap_phases_cpu``, the same keys: wall less CPU is the
+time the engine thread held no processor). What a step's ``emit``
+delivered rides in its record: ``emit_tokens``, ``emit_rows``,
+``emit_finished`` (differences of counters the engine keeps anyway),
+``emit_callback_s`` over ``emit_callback_samples`` (the request's
+callback timed for one token of each sequence of a burst), and
+``deliver_wake_s`` / ``deliver_drain_s``, which the server's loop writes
+later through ``amend`` when it runs the two markers a burst posts to it
+(``EngineCore._flush_pending_burst``).
+
+**The budget: per burst, never per token.** The recorder runs in every
+run: there is no "tracing off", so what it does is in the judged path.
+The engine thread and the server's loop share one interpreter lock, and
+at 128 rows a token leaves every 0.33 ms: 15-20 us a token cost the widest
+cell 4.5% of its tokens/s (PERF_LEDGER.jsonl, PR 38). So nothing here, and
+nothing the engine does for this module, runs once per token. Per phase:
+two reads of each clock. Per sequence and burst: one wall stamp
+(``StageClock.delivered``) and two ``perf_counter`` reads around one
+callback. Per burst: two callbacks posted to the server's loop. The next
+span is added per burst too (``tests/test_emit_budget.py`` counts).
+
 Everything here is stdlib-only and cheap: one dict append under a lock
 per engine step and a dozen timed phases (steps are milliseconds to
 seconds of device time; the record is microseconds of host time — the
-recorder-overhead A/B test holds it to <1% tokens/s). JAX is imported
+recorder-overhead test holds it to <1% tokens/s at one row's pace and at
+128 rows x 8 steps a burst and 3,000 tokens/s). JAX is imported
 only when the first phase is annotated, and its absence makes the
 annotations no-ops: the router imports this package.
 """
@@ -106,20 +129,27 @@ def _profiler_annotations():
     return TraceAnnotation, StepTraceAnnotation
 
 
+def _rounded(seconds: Dict[str, float]) -> Dict[str, float]:
+    return {k: round(v, 6) for k, v in seconds.items()}
+
+
 class _Phase:
     """One timed interval of the loop (``StepRecorder.phase``). Phases
     nest, and a phase's time is its own: what the phases inside it took
     is theirs, so the phases of a step add up to no more than its wall
-    time."""
+    time. Beside the wall clock it reads the thread's CPU clock at the
+    same two edges: wall less CPU is the time the engine thread held no
+    processor (the interpreter lock, a blocking call, the kernel)."""
 
-    __slots__ = ("rec", "name", "t0", "inner", "into", "ann")
+    __slots__ = ("rec", "name", "t0", "cpu0", "inner", "inner_cpu", "into",
+                 "ann")
 
     def __init__(self, rec: "StepRecorder", name: str):
         self.rec, self.name = rec, name
 
     def __enter__(self):
         rec = self.rec
-        self.inner = 0.0
+        self.inner = self.inner_cpu = 0.0
         self.into = self.ann = None
         if threading.get_ident() == rec._loop_thread:
             self.into = rec._into
@@ -128,23 +158,30 @@ class _Phase:
                 self.ann = rec._annotate("engine." + self.name)
                 self.ann.__enter__()
         self.t0 = time.perf_counter()
+        self.cpu0 = time.thread_time()
         return self
 
     def __exit__(self, *exc):
+        # the CPU interval inside the wall interval: CPU <= wall
+        cpu = time.thread_time() - self.cpu0
         took = time.perf_counter() - self.t0
         rec, name = self.rec, self.name
-        own = took - self.inner
+        own, own_cpu = took - self.inner, cpu - self.inner_cpu
         if self.ann is not None:
             self.ann.__exit__(*exc)
         if self.into is not None:
             rec._open.pop()
             if rec._open:
                 rec._open[-1].inner += took
-            self.into[name] = self.into.get(name, 0.0) + own
+                rec._open[-1].inner_cpu += cpu
+            wall, on_cpu = self.into
+            wall[name] = wall.get(name, 0.0) + own
+            on_cpu[name] = on_cpu.get(name, 0.0) + own_cpu
         with rec._lock:
-            total = rec._phase_totals.setdefault(name, [0.0, 0])
+            total = rec._phase_totals.setdefault(name, [0.0, 0, 0.0])
             total[0] += own
             total[1] += 1
+            total[2] += own_cpu
         return False
 
 
@@ -216,10 +253,10 @@ class StepRecorder:
         self._kinds: Dict[str, List[float]] = {
             k: [0.0, 0, 0, 0] for k in STEP_KINDS}
         self.recorded_total = 0
-        # phase -> [own seconds, entries], kept whether or not the steps
-        # go to the ring.
+        # phase -> [own seconds, entries, own CPU seconds], kept whether
+        # or not the steps go to the ring.
         self._phase_totals: Dict[str, List[float]] = {
-            p: [0.0, 0] for p in PHASES}
+            p: [0.0, 0, 0.0] for p in PHASES}
         # The loop's clock (engine thread only). ``_into`` is where a
         # phase entered now adds its time: the gap before a step until
         # ``start``, the step's own phases from there to ``record``.
@@ -227,12 +264,16 @@ class StepRecorder:
         self._annotations = None  # jax.profiler's classes, found once
         self._annotate = None
         self._open: List[_Phase] = []
-        self._gap: Dict[str, float] = {}
-        self._into: Dict[str, float] = self._gap
+        # (wall seconds, CPU seconds) by phase
+        self._gap: tuple = ({}, {})
+        self._into: tuple = self._gap
         self._start: Optional[tuple] = None
         self._notes: dict = {}
         self._programs: List[str] = []
         self._last_end: Optional[float] = None  # perf_counter
+        # step -> fields that reached ``amend`` before the step's record
+        # was made (under the lock: other threads amend)
+        self._early: Dict[int, dict] = {}
 
     # -- the loop's clock -------------------------------------------------
 
@@ -252,8 +293,18 @@ class StepRecorder:
     def start(self) -> None:
         """The step proper starts here: scheduling is done, what follows
         until ``record`` is the step's wall time."""
-        self._into = {}
+        self._into = ({}, {})
         self._start = (time.perf_counter(), time.time(), self._into)
+        if self._early:
+            # left by an iteration that opened this number and made no
+            # record
+            with self._lock:
+                self._early.clear()
+
+    def open_step(self) -> Optional[int]:
+        """The number under which the step now open will be recorded, for
+        ``amend``; None between steps."""
+        return None if self._start is None else self.recorded_total + 1
 
     def note(self, **fields) -> None:
         """Fields of the record the current iteration will make
@@ -296,14 +347,13 @@ class StepRecorder:
         the gap before it. With ``wall_s`` the record stands alone.
         ``ring=False`` keeps the rollups and makes no record."""
         now_perf, now = time.perf_counter(), time.time()
-        phases: Dict[str, float] = {}
-        gap: Dict[str, float] = {}
+        phases = gap = ({}, {})
         notes: dict = {}
         gap_before = 0.0
         if wall_s is None:
             start_perf, start_unix, phases = self._start
             wall_s = now_perf - start_perf
-            gap, self._gap = self._gap, {}
+            gap, self._gap = self._gap, ({}, {})
             notes = dict(self._notes, program="+".join(self._programs))
             if self._last_end is not None:
                 gap_before = start_perf - self._last_end
@@ -334,13 +384,16 @@ class StepRecorder:
                     "batched": batched,
                     "start_unix": start_unix,
                     "end_unix": now,
-                    "phases": {k: round(v, 6) for k, v in phases.items()},
+                    "phases": _rounded(phases[0]),
+                    "phases_cpu": _rounded(phases[1]),
                     "gap_before_s": round(gap_before, 6),
-                    "gap_phases": {k: round(v, 6) for k, v in gap.items()},
+                    "gap_phases": _rounded(gap[0]),
+                    "gap_phases_cpu": _rounded(gap[1]),
                     "program": "", "padded_tokens": 0,
                     "waiting": 0, "running": 0,
                     "kv_blocks_live": 0, "kv_blocks_cached": 0,
                     "kv_blocks_free": 0, **notes,
+                    **self._early.pop(self.recorded_total, {}),
                 }
                 self._ring.append(rec)
             agg = self._kinds.setdefault(kind, [0.0, 0, 0, 0])
@@ -349,6 +402,26 @@ class StepRecorder:
             agg[2] += tokens
             agg[3] += hbm_bytes
         return rec
+
+    def amend(self, step: int, **fields) -> None:
+        """Add ``fields`` to the record numbered ``step``, from any
+        thread: what becomes known only after the step was recorded (or,
+        on another thread, even before). Fields for a step not recorded
+        yet wait for its record; for one that has left the ring they are
+        dropped."""
+        with self._lock:
+            if step > self.recorded_total:
+                self._early.setdefault(step, {}).update(fields)
+            elif self._ring:
+                at = step - self._ring[0]["step"]  # the ring's are in a row
+                if at >= 0:
+                    self._ring[at].update(fields)
+
+    def mark(self, step: int, field: str, since: float) -> None:
+        """Amend record ``step`` with the seconds from ``since``
+        (``perf_counter``) to now: what a marker posted to another
+        thread's queue runs when its turn comes."""
+        self.amend(step, **{field: round(time.perf_counter() - since, 6)})
 
     # -- retrieval --------------------------------------------------------
 
@@ -377,10 +450,11 @@ class StepRecorder:
             }
 
     def phase_stats(self) -> Dict[str, dict]:
-        """Lifetime seconds and entries of each phase (its own time: the
-        phases nested in it are counted under their names)."""
+        """Lifetime seconds, entries and CPU seconds of each phase (its
+        own time: the phases nested in it are counted under their
+        names)."""
         with self._lock:
-            return {k: {"seconds": v[0], "count": v[1]}
+            return {k: {"seconds": v[0], "count": v[1], "cpu_seconds": v[2]}
                     for k, v in self._phase_totals.items()}
 
     def busy_between(self, t0: float, t1: float) -> dict:

@@ -296,13 +296,14 @@ class StageClock:
 
     The server creates one per request and threads it through
     ``EngineCore.add_request``; the core only ever sets attributes on it
-    (no imports, no locking — single writer per field, reader runs after
-    the request finishes).
+    and calls ``delivered`` (no imports, no locking — single writer per
+    field, reader runs after the request finishes).
     """
 
     __slots__ = ("arrival", "prefill_start", "prefill_end", "first_token",
-                 "last_token", "tokens", "prompt_tokens", "cached_tokens",
-                 "preemptions", "prefill_chunks")
+                 "last_token", "output", "prompt_tokens", "cached_tokens",
+                 "preemptions", "prefill_chunks", "gap_start", "gap_end",
+                 "gap_at_token")
 
     def __init__(self, arrival: Optional[float] = None):
         self.arrival = time.time() if arrival is None else arrival
@@ -310,12 +311,39 @@ class StageClock:
         self.prefill_end = 0.0
         self.first_token = 0.0
         self.last_token = 0.0
-        self.tokens = 0
+        # The request's own list of output tokens, from its first
+        # delivery on (``tokens``).
+        self.output: "list | tuple" = ()
         self.prompt_tokens = 0
         self.cached_tokens = 0
         self.preemptions = 0
         # Chunked prefill: scheduler chunks dispatched for this prompt.
         self.prefill_chunks = 0
+        # The longest interval between two deliveries so far, and how
+        # many tokens the request had when it opened.
+        self.gap_start = 0.0
+        self.gap_end = 0.0
+        self.gap_at_token = 0
+
+    @property
+    def tokens(self) -> int:
+        """Tokens delivered so far. The engine thread extends the
+        request's list before each token's callback, so whoever saw the
+        stream end reads them all, and nobody counts per token."""
+        return len(self.output)
+
+    def delivered(self, now: float, output: list) -> None:
+        """One flush of the loop is about to hand the request tokens
+        (a burst's, or a prefill's first): stamped ``now`` (unix), once
+        per sequence and burst and never per token. ``output`` is the
+        request's list of output tokens, as long as it was before."""
+        if not self.first_token:
+            self.first_token = now
+        elif now - self.last_token > self.gap_end - self.gap_start:
+            self.gap_start, self.gap_end = self.last_token, now
+            self.gap_at_token = len(output)
+        self.last_token = now
+        self.output = output
 
 
 # ---------------------------------------------------------------------------

@@ -339,6 +339,52 @@ def test_request_trace_and_stage_metrics(server_url):
     assert re.search(r"tpu:hbm_headroom_bytes{[^}]*} \d+", metrics)
 
 
+def test_stream_gap_span_and_the_markers_of_the_hand_over(server_url):
+    """A request that gets its tokens in several deliveries (a prefill's
+    first, then bursts of 8) carries one ``engine.stream_gap``: the
+    longest interval between two of them, under its decode span, with the
+    steps that held the loop in it as causes; a request that got all in
+    one delivery carries none. The records of the steps that flushed the
+    bursts carry the two markers' times, written by the server's loop."""
+    async def run():
+        async with aiohttp.ClientSession() as s:
+            for rid, n in (("gap-many", 30), ("gap-once", 1)):
+                async with s.post(server_url + "/v1/completions", json={
+                    "model": "tiny-llama", "prompt": "mind the gap",
+                    "max_tokens": n, "temperature": 0.0,
+                    "ignore_eos": True,
+                }, headers={"X-Request-Id": rid}) as r:
+                    assert r.status == 200
+            traces = {}
+            for rid in ("gap-many", "gap-once"):
+                async with s.get(server_url + f"/debug/traces/{rid}") as r:
+                    traces[rid] = await r.json()
+            async with s.get(server_url + "/debug/steps?limit=12") as r:
+                steps = (await r.json())["steps"]
+        return traces, steps
+
+    traces, steps = asyncio.run(run())
+    spans = {sp["name"]: sp for sp in traces["gap-many"]["spans"]}
+    gap, decode = spans["engine.stream_gap"], spans["engine.decode"]
+    assert gap["parent_span_id"] == decode["span_id"]
+    assert decode["start_unix"] <= gap["start_unix"] < gap["end_unix"] \
+        <= decode["end_unix"] + 1e-6
+    attrs = gap["attributes"]
+    assert 1 <= attrs["at_token"] < 30
+    assert attrs["behind_prefill_s"] >= 0 and attrs["behind_decode_s"] >= 0
+    assert attrs["behind_prefill_s"] + attrs["behind_decode_s"] \
+        <= gap["duration_s"] + 1e-6
+    assert decode["attributes"]["tokens"] == 30
+    assert "engine.stream_gap" not in {
+        sp["name"] for sp in traces["gap-once"]["spans"]}
+
+    flushed = [r for r in steps if r.get("emit_tokens")]
+    assert flushed
+    for r in flushed:
+        assert r["deliver_wake_s"] > 0 and r["deliver_drain_s"] > 0
+        assert set(r["phases_cpu"]) == set(r["phases"])
+
+
 def test_drain_endpoint_must_stay_last(server_url):
     """Graceful drain (ISSUE 6): /drain stops admission, readiness
     flips to 503, inference answers 503 + Retry-After, the draining
