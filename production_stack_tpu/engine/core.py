@@ -2558,6 +2558,7 @@ class EngineCore:
             "state_blocks_written_total": self.state_blocks_written_total,
             "family_stats_total": dict(self.family_stats_total),
             "generation_tokens_total": self.generation_tokens_total,
+            "emit_callbacks_total": self.emit_callbacks_total,
             "offload": self.offload.stats() if self.offload else None,
             # Page residency split: HBM pages currently allocated vs
             # pages living in the offload tier (host RAM / remote L3).
@@ -4190,6 +4191,9 @@ class EngineCore:
     # posts two markers, one before its first token and one after its
     # last, which time the hand-over into the step's record.
     post_to_server_loop: Optional[Callable[..., None]] = None
+    # Deliveries that bursts' flushes made to requests' callbacks: beside
+    # ``generation_tokens_total``, the tokens they carried.
+    emit_callbacks_total = 0
 
     def _flush_pending_burst(self) -> None:
         """Read back and emit the in-flight decode burst, if any."""
@@ -4220,7 +4224,7 @@ class EngineCore:
             post(steps.mark, step, "deliver_wake_s", time.perf_counter())
         tokens0 = self.generation_tokens_total
         finished0 = self.requests_finished_total
-        sample = [0.0, 0, 0]  # callback seconds, callbacks timed, rows
+        sample = [0.0, 0, 0]  # callback seconds, rows, callbacks
         with steps.phase("emit"):
             if pending.get("spec"):
                 self._flush_spec_burst(pending, arrays, sample)
@@ -4228,55 +4232,56 @@ class EngineCore:
                 self._emit_burst(pending, arrays, sample)
         if step is not None:
             post(steps.mark, step, "deliver_drain_s", time.perf_counter())
+        self.emit_callbacks_total += sample[2]
+        tokens = self.generation_tokens_total - tokens0
+        # Every delivery is timed, so the callbacks' seconds are all of
+        # them and their sample is the tokens they carried: all.
         steps.note_sum(
-            emit_tokens=self.generation_tokens_total - tokens0,
+            emit_tokens=tokens, emit_callback_samples=tokens,
             emit_finished=self.requests_finished_total - finished0,
             emit_callback_s=round(sample[0], 7),
-            emit_callback_samples=sample[1],
-            emit_rows=sample[2])
+            emit_rows=sample[1], emit_callbacks=sample[2])
 
     def _emit_seq(self, seq: RunningSeq, upto: int, arrays,
                   sample: list) -> int:
         """Emit up to ``upto`` of a burst's tokens to one sequence, as
-        far as it still runs; returns how many. What the recorder and the
+        far as it still runs; returns how many. The request's callback
+        holds them, and the reason where the burst ends the sequence,
+        and they are handed over in one delivery after the last
+        (``scheduler.TokenDelivery``). What the recorder and the
         request's trace learn of the stream they learn here, once per
         sequence and burst and never per token (``obs/steps.py``, the
         budget): one stamp of the request's clock, before its tokens so
-        that whoever sees the stream end finds it, and the callback of
-        the first token timed into ``sample`` by a stand-in that puts the
-        request's own back (the rest of ``emit``'s callback time is
-        estimated from those)."""
+        that whoever sees the stream end finds it, and the delivery
+        timed and counted into ``sample``."""
         sampled, lps, top_lps, top_idxs = arrays
         req, slot = seq.req, seq.slot
         want_lp = req.sampling.logprobs
         if req.trace is not None and self.scheduler.slots[slot] is seq:
             req.trace.delivered(time.time(), req.output_token_ids)
-        callback = req.on_token
-
-        def timed(payload, finish):
-            req.on_token = callback  # stands in for one token
-            t0 = time.perf_counter()
-            callback(payload, finish)
-            sample[0] += time.perf_counter() - t0
-            sample[1] += 1
-
-        req.on_token = timed
         emitted = 0
-        for s in range(upto):
-            if self.scheduler.slots[slot] is not seq:
-                break  # finished / aborted / preempted mid-burst
-            lp = None
-            if want_lp is not None:
-                k = min(want_lp, top_lps.shape[2])
-                lp = {"logprob": float(lps[slot, s]),
-                      "top": [(int(top_idxs[slot, s, j]),
-                               float(top_lps[slot, s, j]))
-                              for j in range(k)]}
-            self._emit_token(seq, int(sampled[slot, s]), lp)
-            emitted += 1
-        req.on_token = callback  # where it got no token
+        req.on_token.hold()
+        try:
+            for s in range(upto):
+                if self.scheduler.slots[slot] is not seq:
+                    break  # finished / aborted / preempted mid-burst
+                lp = None
+                if want_lp is not None:
+                    k = min(want_lp, top_lps.shape[2])
+                    lp = {"logprob": float(lps[slot, s]),
+                          "top": [(int(top_idxs[slot, s, j]),
+                                   float(top_lps[slot, s, j]))
+                                  for j in range(k)]}
+                self._emit_token(seq, int(sampled[slot, s]), lp)
+                emitted += 1
+        finally:
+            with self._lock:  # an abort's sentinel keeps its place
+                t0 = time.perf_counter()
+                delivered = req.on_token.release()
+                sample[0] += time.perf_counter() - t0
         self.generation_tokens_total += emitted
-        sample[2] += emitted > 0
+        sample[1] += emitted > 0
+        sample[2] += delivered
         return emitted
 
     def _emit_burst(self, pending, arrays, sample) -> None:
@@ -4415,7 +4420,9 @@ class EngineCore:
 
     def _emit_token(self, seq: RunningSeq, token: int,
                     lp: Optional[dict] = None) -> None:
-        """Deliver one generated token. When the request asked for
+        """Emit one generated token to its request's callback (inside a
+        burst the callback holds it for the sequence's one delivery:
+        ``_emit_seq``). When the request asked for
         logprobs, the callback payload is ``(token, lp)`` with
         ``lp = {"logprob": float, "top": [(token_id, logprob), ...]}``;
         otherwise the bare int (the common path stays allocation-free).

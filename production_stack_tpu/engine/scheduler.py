@@ -148,12 +148,70 @@ class RequestStatus(enum.Enum):
     REJECTED = "rejected"
 
 
+class TokenDelivery:
+    """A request's callback as the engine calls it: ``(payload | None,
+    finish_reason | None)``, from the engine thread (a token, or the
+    sentinel that ends the stream) and, for an abort, from whichever
+    thread asked, under the core's lock.
+
+    While the core emits a burst to the sequence it ``hold``s the calls,
+    and ``release`` hands them over as one delivery, in the order they
+    came: the sequence's tokens of the burst and, where the burst ended
+    it or an abort arrived meanwhile, the sentinel last. A callback that
+    offers ``on_burst(items)`` gets the list of ``(payload, finish)``
+    pairs in that one call (the server's stream: one hand-over to its
+    loop); any other callable is called once per pair, in order, so it
+    sees what it saw when every token was a call: also where it aborts
+    its request from inside a call, which then is its last but for the
+    sentinel. Outside a burst a call is a delivery of one pair.
+    """
+
+    __slots__ = ("_deliver", "_held")
+
+    def __init__(self, on_token: Callable[[Optional[int], Optional[str]],
+                                          None]):
+        on_burst = getattr(on_token, "on_burst", None)
+        if on_burst is None:
+            ended = False
+
+            def on_burst(items):
+                nonlocal ended
+                for payload, finish in items:
+                    if ended:  # by a call inside the one before
+                        break
+                    ended = finish is not None
+                    on_token(payload, finish)
+        self._deliver = on_burst
+        self._held: Optional[list] = None
+
+    def __call__(self, payload, finish: Optional[str]) -> None:
+        held = self._held
+        if held is not None:
+            held.append((payload, finish))
+        else:
+            self._deliver([(payload, finish)])
+
+    def hold(self) -> None:
+        """Engine thread, before a burst's first token to the sequence."""
+        self._held = []
+
+    def release(self) -> bool:
+        """Deliver what was held, if anything was: under the core's lock,
+        which every caller from another thread holds, so that a sentinel
+        of theirs lands behind the tokens it followed or is delivered
+        after them, never before."""
+        items, self._held = self._held, None
+        if items:
+            self._deliver(items)
+        return bool(items)
+
+
 @dataclass
 class EngineRequest:
     request_id: str
     prompt_token_ids: List[int]
     sampling: SamplingParams
-    # Called from the engine thread: (token_id | None, finish_reason | None).
+    # Given as the caller's callback, kept as its ``TokenDelivery``.
     on_token: Callable[[Optional[int], Optional[str]], None]
     adapter_id: int = 0  # LoRA slot (engine-local, selects weights)
     adapter_name: str = ""  # stable name (namespaces the KV hash chain)
@@ -180,6 +238,9 @@ class EngineRequest:
     # TokenFSM plus this request's DFA position; set by the engine when
     # sampling carries a grammar constraint.
     structured: Optional[object] = None
+
+    def __post_init__(self):
+        self.on_token = TokenDelivery(self.on_token)
 
     @property
     def all_token_ids(self) -> List[int]:

@@ -102,21 +102,27 @@ async def _json_body(request: web.Request) -> dict:
 
 
 class _TokenStream:
-    """Bridges engine-thread token callbacks into an asyncio queue."""
+    """Bridges the engine thread's deliveries into an asyncio queue: the
+    request's callback (``__call__``), which takes what a burst gives the
+    sequence as one list (``on_burst``: one hand-over to the loop, however
+    many tokens) and yields it a ``(token, finish)`` pair at a time."""
 
     def __init__(self, loop: asyncio.AbstractEventLoop):
         self.loop = loop
         self.queue: asyncio.Queue = asyncio.Queue()
 
-    def on_token(self, token_id: Optional[int], finish: Optional[str]) -> None:
-        self.loop.call_soon_threadsafe(self.queue.put_nowait, (token_id, finish))
+    def on_burst(self, items: "List[tuple]") -> None:
+        self.loop.call_soon_threadsafe(self.queue.put_nowait, items)
+
+    def __call__(self, token_id: Optional[int], finish: Optional[str]) -> None:
+        self.on_burst([(token_id, finish)])
 
     async def __aiter__(self):
         while True:
-            token_id, finish = await self.queue.get()
-            yield token_id, finish
-            if finish is not None:
-                return
+            for token_id, finish in await self.queue.get():
+                yield token_id, finish
+                if finish is not None:
+                    return
 
 
 class EngineServer:
@@ -265,7 +271,7 @@ class EngineServer:
         """Register with the router's KV controller (retried lazily on
         each admission until it succeeds) and hook eviction reporting."""
         self._loop = asyncio.get_running_loop()
-        # The way the tokens take (``_TokenStream.on_token``): the core
+        # The way the tokens take (``_TokenStream.on_burst``): the core
         # posts two markers a burst along it to time the hand-over.
         self.core.post_to_server_loop = self._loop.call_soon_threadsafe
         # Hooked unconditionally (no-ops on an empty registry): the
@@ -728,7 +734,7 @@ class EngineServer:
                         priority: int = 0):
         stream = _TokenStream(asyncio.get_running_loop())
         self.core.add_request(
-            request_id, prompt_ids, sampling, stream.on_token,
+            request_id, prompt_ids, sampling, stream,
             adapter_name=adapter, trace=trace, priority=priority,
         )
         return stream
@@ -2464,6 +2470,10 @@ class EngineServer:
             f"vllm:prompt_tokens_total{{{labels}}} {s['prompt_tokens_total']}",
             "# TYPE vllm:generation_tokens counter",
             f"vllm:generation_tokens_total{{{labels}}} {s['generation_tokens_total']}",
+            # Deliveries of bursts' tokens to requests' callbacks: the
+            # generated tokens over these is what one hand-over carries.
+            "# TYPE tpu:emit_callbacks counter",
+            f"tpu:emit_callbacks_total{{{labels}}} {s['emit_callbacks_total']}",
             "# TYPE vllm:request_success counter",
             f"vllm:request_success_total{{{labels}}} {s['requests_finished_total']}",
             "# TYPE vllm:num_preemptions counter",

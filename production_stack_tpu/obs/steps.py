@@ -31,10 +31,13 @@ A phase reads the thread's CPU clock beside the wall clock
 time the engine thread held no processor). What a step's ``emit``
 delivered rides in its record: ``emit_tokens``, ``emit_rows``,
 ``emit_finished`` (differences of counters the engine keeps anyway),
-``emit_callback_s`` over ``emit_callback_samples`` (the request's
-callback timed for one token of each sequence of a burst), and
-``deliver_wake_s`` / ``deliver_drain_s``, which the server's loop writes
-later through ``amend`` when it runs the two markers a burst posts to it
+``emit_callbacks`` (the deliveries a burst's flush made to requests'
+callbacks: one a sequence), ``emit_callback_s`` (the time those
+deliveries took, each one timed) over ``emit_callback_samples`` (the
+tokens they carried: ``emit_tokens`` again, kept under its name for the
+readers that scale one by the other), and ``deliver_wake_s`` /
+``deliver_drain_s``, which the server's loop writes later through
+``amend`` when it runs the two markers a burst posts to it
 (``EngineCore._flush_pending_burst``).
 
 **The budget: per burst, never per token.** The recorder runs in every
@@ -45,8 +48,20 @@ cell 4.5% of its tokens/s (PERF_LEDGER.jsonl, PR 38). So nothing here, and
 nothing the engine does for this module, runs once per token. Per phase:
 two reads of each clock. Per sequence and burst: one wall stamp
 (``StageClock.delivered``) and two ``perf_counter`` reads around one
-callback. Per burst: two callbacks posted to the server's loop. The next
+delivery. Per burst: two callbacks posted to the server's loop. The next
 span is added per burst too (``tests/test_emit_budget.py`` counts).
+
+The engine keeps to the same budget towards its callers. The callback
+of ``EngineCore.add_request(request_id, prompt, sampling, on_token)`` is
+called ``on_token(payload | None, finish | None)``; what a burst gives
+one sequence is one *delivery*: its tokens of the burst in order and,
+where the burst ends the sequence, the reason behind them. A callback
+that offers ``on_burst(items)`` gets a delivery as one call with the
+list of ``(payload, finish)`` pairs (the server's stream hands it to its
+loop with one ``call_soon_threadsafe``, where a call a token cost the
+widest cell 1,024 of them a burst and the device waited for it); a plain
+callable gets the pairs one call each, inside that one delivery
+(``engine/scheduler.py::TokenDelivery``).
 
 Everything here is stdlib-only and cheap: one dict append under a lock
 per engine step and a dozen timed phases (steps are milliseconds to

@@ -1,5 +1,6 @@
 """Optimised v5e HLO of models.llama.apply at mistral-7b-l16's widths (and
-of models.laguna.apply at laguna-s-2.1-l8e64's, bf16: PR 36), for
+of models.laguna.apply at laguna-s-2.1-l8e64's, bf16: PR 36; of
+models.lfm2.apply at lfm2-24b-a2b-l10's: PR 40), for
 a refactor that must not change the program (PR 31): run it on a copy of
 the parent and on the change and compare the digests; no chip needed.
 
@@ -116,36 +117,69 @@ for weights in ("bf16", "int8"):
                spec((rows, width)), spec((rows, MAXB)), spec((rows,)),
                spec((rows,)), spec((rows,)), spec((rows,)))
 
-# laguna-s-2.1-l8e64 as the benchmark serves it: the model keys of its
-# file (read from <repo-root>), the three programs with the expert
-# layer's counts.
+# The two later families as the benchmark serves them: the model keys of
+# the configuration's file (read from <repo-root>), the three programs
+# with the expert layer's counts.
 from chipbench.registry import model_keys
-from production_stack_tpu.models import get_model_config, laguna
+from production_stack_tpu.models import get_model_config
 
-with open(os.path.join(root, "chipbench", "configs",
-                       "laguna-s-2.1-l8e64.json")) as f:
-    os.makedirs(os.path.join(out, "laguna"), exist_ok=True)
-    with open(os.path.join(out, "laguna", "config.json"), "w") as g:
-        json.dump(model_keys(json.load(f)), g)
-lcfg = get_model_config(os.path.join(out, "laguna"))
-lparams = jax.tree_util.tree_map(
-    lambda x: spec(x.shape, x.dtype),
-    jax.eval_shape(lambda: laguna.init_params(lcfg, jax.random.key(0))))
-lpages = spec((lcfg.num_layers, 256, BS, lcfg.num_kv_heads, lcfg.head_dim),
-              jnp.bfloat16)
-for mode, rows, width, tables in (("decode", 128, 1, 16),
-                                  ("prefill", 4, 512, 16),
-                                  ("prefill_cached", 1, 256, 32)):
-    last = mode != "decode"
 
-    def fn(p, kv, tok, pos, slot, bt, cl, sl):
-        return laguna.apply(
-            p, lcfg, tok, pos, kv, slot, bt, cl, sl, mode=mode,
-            last_token=jnp.maximum(sl - 1, 0) if last else None,
-            with_stats=True)
+def family_digests(name, config, module, pool, shapes):
+    """``<name>.<mode>`` for each of ``shapes`` (mode, rows, width, table
+    width); ``pool(cfg)`` gives the sides of the cache."""
+    with open(os.path.join(root, "chipbench", "configs",
+                           config + ".json")) as f:
+        os.makedirs(os.path.join(out, name), exist_ok=True)
+        with open(os.path.join(out, name, "config.json"), "w") as g:
+            json.dump(model_keys(json.load(f)), g)
+    fcfg = get_model_config(os.path.join(out, name))
+    params = jax.tree_util.tree_map(
+        lambda x: spec(x.shape, x.dtype),
+        jax.eval_shape(lambda: module.init_params(fcfg, jax.random.key(0))))
+    for mode, rows, width, tables in shapes:
+        last = mode != "decode"
 
-    digest(f"laguna.{mode}", fn, 1, lparams, (lpages, lpages),
-           spec((rows, width)), spec((rows, width)), spec((rows, width)),
-           spec((rows, tables)), spec((rows,)), spec((rows,)))
+        def fn(p, kv, tok, pos, slot, bt, cl, sl):
+            return module.apply(
+                p, fcfg, tok, pos, kv, slot, bt, cl, sl, mode=mode,
+                last_token=jnp.maximum(sl - 1, 0) if last else None,
+                with_stats=True)
+
+        digest(f"{name}.{mode}", fn, 1, params, pool(fcfg),
+               spec((rows, width)), spec((rows, width)), spec((rows, width)),
+               spec((rows, tables)), spec((rows,)), spec((rows,)))
+
+
+def laguna_pool(c):
+    pages = spec((c.num_layers, 256, BS, c.num_kv_heads, c.head_dim),
+                 jnp.bfloat16)
+    return pages, pages
+
+
+def lfm2_pool(c):
+    """Packed pages in the attention layers and, the pool's third side,
+    a state per block in the convolution layers."""
+    from production_stack_tpu.engine.core import kv_page_dims
+    from production_stack_tpu.models.registry import block_state_shape
+
+    layers, page_rows, lanes = kv_page_dims(c)
+    pages = spec((layers, 1024, BS, page_rows, lanes), jnp.bfloat16)
+    held = block_state_shape(c)
+    return pages, pages, spec((held[0], 1024) + held[1:], jnp.bfloat16)
+
+
+from production_stack_tpu.models import laguna
+
+family_digests("laguna", "laguna-s-2.1-l8e64", laguna, laguna_pool,
+               (("decode", 128, 1, 16), ("prefill", 4, 512, 16),
+                ("prefill_cached", 1, 256, 32)))
+try:  # a tree before PR 36 has no such family
+    from production_stack_tpu.models import lfm2
+except ImportError:
+    lfm2 = None
+if lfm2 is not None:  # (PR 40)
+    family_digests("lfm2", "lfm2-24b-a2b-l10", lfm2, lfm2_pool,
+                   (("decode", 32, 1, 64), ("prefill", 4, 512, 8),
+                    ("prefill_cached", 1, 1024, 64)))
 with open(os.path.join(out, "digests.json"), "w") as f:
     json.dump(digests, f, indent=1)

@@ -21,6 +21,7 @@ from production_stack_tpu.obs.steps import StepRecorder
 from production_stack_tpu.obs.trace import StageClock
 
 SEQUENCES = 4
+BURSTS = (2, 8)  # decode steps a burst
 CLOCKS = ("time", "perf_counter", "thread_time")
 # A flush of one burst to S sequences may read, by the budget: one wall
 # stamp a sequence; the two edges of ``readback`` and ``emit`` on both
@@ -32,13 +33,23 @@ BUDGET = {"time": (0, 1), "perf_counter": (6, 2), "thread_time": (4, 0),
 
 class FakeLoop:
     """Takes what is posted to it, from any thread, and runs it later in
-    the order it came: the server's loop as far as the core can tell."""
+    the order it came: the server's loop as far as the core can tell.
+    ``ended`` counts the streams whose last delivery it was handed."""
 
     def __init__(self):
         self.posted = []
+        self.ended = threading.Semaphore(0)
 
     def call_soon_threadsafe(self, fn, *args):
         self.posted.append((fn, args))
+        if _is_delivery(fn) and args[0][-1][1] is not None:
+            self.ended.release()
+
+
+def _is_delivery(fn) -> bool:
+    """A stream's ``queue.put_nowait`` of a list of (token, finish), and
+    not a marker."""
+    return getattr(fn, "__name__", "") == "put_nowait"
 
 
 def _engine(decode_steps: int) -> EngineCore:
@@ -54,25 +65,17 @@ def _serve(eng, loop, tokens: int, sequences: int = SEQUENCES):
     """``sequences`` requests of ``tokens`` tokens each through streams
     like the server's, all running before any decodes; returns their
     clocks once the engine has finished them."""
-    done = threading.Semaphore(0)
     clocks = []
     with eng._step_lock:  # every request waits before the first step
         for i in range(sequences):
-            stream = _TokenStream(loop)
-
-            def on_token(token, finish, stream=stream):
-                stream.on_token(token, finish)
-                if finish is not None:
-                    done.release()
-
             clocks.append(StageClock())
             eng.add_request(
                 f"budget-{tokens}-{i}", [1, 2, 3, 4, 5 + i],
                 SamplingParams(temperature=0.0, max_tokens=tokens,
                                ignore_eos=True),
-                on_token, trace=clocks[-1])
+                _TokenStream(loop), trace=clocks[-1])
     for _ in range(sequences):
-        assert done.acquire(timeout=120)
+        assert loop.ended.acquire(timeout=120)
     return clocks
 
 
@@ -81,10 +84,11 @@ def counted():
     """{K: [counts of one flush, ...]} for the flushes of bursts that
     delivered K tokens to each of the sequences: the calls the engine
     thread made of each clock and of the server's hook inside
-    ``_flush_pending_burst``, ``readback`` and the markers included."""
+    ``_flush_pending_burst``, ``readback`` and the markers included, and
+    under ``handed`` all that the loop was handed meanwhile."""
     out = {}
     real = {name: getattr(time, name) for name in CLOCKS}
-    for K in (1, 8):
+    for K in BURSTS:
         eng = _engine(K)
         loop = FakeLoop()
         calls = collections.Counter()
@@ -104,15 +108,16 @@ def counted():
         flush, flushes = eng._flush_pending_burst, []
 
         def flush_counted():
-            before = eng.generation_tokens_total
+            before = eng.generation_tokens_total, len(loop.posted)
             calls.clear()
             inside.on = True
             try:
                 flush()
             finally:
                 inside.on = False
-            if eng.generation_tokens_total - before == K * SEQUENCES:
-                flushes.append(dict(calls))
+            if eng.generation_tokens_total - before[0] == K * SEQUENCES:
+                flushes.append(dict(
+                    calls, handed=len(loop.posted) - before[1]))
 
         try:
             _serve(eng, loop, 2 * K + 1)  # warm: compiles
@@ -134,11 +139,20 @@ def test_a_burst_reads_no_clock_per_token(counted, name):
     """Eight times the tokens, the same reads; and no more than the
     budget allows for these sequences."""
     fixed, per_sequence = BUDGET[name]
-    assert len(counted[1]) >= 2 and len(counted[8]) >= 2
-    short = {f.get(name, 0) for f in counted[1]}
+    assert len(counted[2]) >= 2 and len(counted[8]) >= 2
+    short = {f.get(name, 0) for f in counted[2]}
     long = {f.get(name, 0) for f in counted[8]}
     assert short == long and len(short) == 1, (name, counted)
     assert 0 < short.pop() <= fixed + per_sequence * SEQUENCES
+
+
+@pytest.mark.parametrize("K", BURSTS)
+def test_a_burst_is_handed_over_once_a_sequence(counted, K):
+    """What a burst gives a sequence reaches its stream as one delivery:
+    the loop is handed one a sequence and the two markers, whether the
+    burst is of two steps or of eight."""
+    assert len(counted[K]) >= 2
+    assert {f["handed"] for f in counted[K]} == {SEQUENCES + 2}, counted[K]
 
 
 def _spin(seconds: float) -> None:
@@ -258,16 +272,26 @@ def served():
 
 
 def test_emit_counts_add_up_to_the_engines_own(served):
-    records, _, generated, clocks = served
+    records, posted, generated, clocks = served
     # a request's first token is its prefill's, and is not in the count
     assert (sum(r.get("emit_tokens", 0) for r in records) == generated
             == SEQUENCES * 20)
     assert sum(r.get("emit_finished", 0) for r in records) == SEQUENCES
     bursts = [r for r in records if r.get("emit_tokens")]
-    assert all(1 <= r["emit_rows"] <= SEQUENCES
-               and r["emit_callback_samples"] == r["emit_rows"]
+    # every delivery is timed, so the samples are the tokens themselves
+    assert all(1 <= r["emit_callbacks"] == r["emit_rows"] <= SEQUENCES
+               and r["emit_callback_samples"] == r["emit_tokens"]
                and 0.0 < r["emit_callback_s"] <= r["phases"]["emit"]
                for r in bursts)
+    # ``emit_callbacks`` is what the loop was handed, less the markers
+    # and each request's first token (a prefill's flush hands that over,
+    # and like ``emit_tokens`` the count is of bursts); a burst that ends
+    # a request hands its reason over with the tokens
+    deliveries = [args[0] for fn, args in posted if _is_delivery(fn)]
+    assert (sum(r.get("emit_callbacks", 0) for r in records)
+            == len(deliveries) - SEQUENCES == 3 * SEQUENCES)
+    assert sum(items[-1] == (None, "length") and len(items) > 1
+               for items in deliveries) == SEQUENCES
     assert [c.tokens for c in clocks] == [21] * SEQUENCES
 
 
@@ -286,8 +310,8 @@ def test_markers_amend_their_step_after_its_last_token(served):
                 assert step == open_step
                 assert tokens == by_step[step]["emit_tokens"] > 0
                 seen.append(step)
-        elif args[0][0] is not None:
-            tokens += 1
+        else:
+            tokens += sum(token is not None for token, _ in args[0])
         fn(*args)
     assert seen == [r["step"] for r in records if r.get("emit_tokens")]
     for step in seen:
@@ -305,8 +329,7 @@ def test_no_hook_no_fields():
     assert any(r.get("emit_tokens") for r in records)
     assert not any("deliver_wake_s" in r or "deliver_drain_s" in r
                    for r in records)
-    assert not any(getattr(fn, "__name__", "") == "mark"
-                   for fn, _ in loop.posted)
+    assert all(_is_delivery(fn) for fn, _ in loop.posted)
 
 
 def test_stream_gap_is_the_longest_interval_between_deliveries(served):

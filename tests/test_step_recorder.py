@@ -11,6 +11,7 @@ import math
 import os
 import subprocess
 import sys
+import threading
 import time
 import timeit
 import types
@@ -22,6 +23,7 @@ from aiohttp import web
 from production_stack_tpu.engine.config import EngineConfig
 from production_stack_tpu.engine.core import EngineCore
 from production_stack_tpu.engine.sampling import SamplingParams
+from production_stack_tpu.engine.scheduler import TokenDelivery
 from production_stack_tpu.obs.debug import add_step_debug_routes
 from production_stack_tpu.obs.steps import (
     DEVICE_PEAKS,
@@ -301,8 +303,8 @@ def test_recorder_overhead_under_one_percent(pace):
     engine has nothing to do. At the widest cell's pace the same calls
     are counted per burst, with what a burst's flush does beside them
     for each of its rows and once (``EngineCore._flush_pending_burst``,
-    ``_emit_seq``): the stamp of the request's clock, the callback timed,
-    the two markers."""
+    ``_emit_seq``): the stamp of the request's clock, the delivery held,
+    released and timed, the two markers."""
     rows, token_s = PACES[pace]
     eng = _make_engine()
     n_tokens = 64
@@ -365,21 +367,24 @@ def test_recorder_overhead_under_one_percent(pace):
         seqs = [types.SimpleNamespace(req=types.SimpleNamespace(trace=clock))
                 for _ in range(rows)]
 
-        def callback(payload, finish):
-            pass
+        class Stream:  # takes a burst, as the server's does
+            def __call__(self, payload, finish):
+                pass
 
-        req = types.SimpleNamespace(on_token=callback)
+            def on_burst(self, items):
+                pass
 
-        def one_sampled_callback():  # as ``_emit_seq`` times one
-            def timed(payload, finish):
-                req.on_token = callback
+        delivery, lock = TokenDelivery(Stream()), threading.RLock()
+
+        def one_timed_delivery():  # as ``_emit_seq`` makes and times it
+            delivery.hold()
+            delivery(7, None)
+            with lock:
                 t0 = time.perf_counter()
-                callback(payload, finish)
+                delivered = delivery.release()
                 sample[0] += time.perf_counter() - t0
-                sample[1] += 1
-            req.on_token = timed
-            req.on_token(7, None)
-            req.on_token = callback
+            sample[1] += True
+            sample[2] += delivered
 
         def markers():
             alone.mark(alone.recorded_total, "deliver_wake_s",
@@ -389,8 +394,8 @@ def test_recorder_overhead_under_one_percent(pace):
 
         per_row = {
             "stamp": _best_s(lambda: clock.delivered(time.time(), output)),
-            "sample": _best_s(one_sampled_callback)
-            - _best_s(lambda: callback(7, None)),
+            "delivery": _best_s(one_timed_delivery)
+            - _best_s(lambda: delivery(7, None)),
         }
         per_burst = {
             "traced_rows": _best_s(lambda: any(
@@ -399,7 +404,8 @@ def test_recorder_overhead_under_one_percent(pace):
             "markers": _best_s(markers),
             "note_sum": _best_s(lambda: alone.note_sum(
                 emit_tokens=1024, emit_finished=3, emit_callback_s=1e-3,
-                emit_callback_samples=128, emit_rows=128)),
+                emit_callback_samples=1024, emit_rows=128,
+                emit_callbacks=128)),
         }
         unit.update(per_row, **per_burst)
         cost += rows * sum(per_row.values()) + sum(per_burst.values())
