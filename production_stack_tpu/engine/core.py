@@ -52,6 +52,7 @@ from production_stack_tpu.structured.tokenfsm import (
 from production_stack_tpu.models import build_model, get_model_config
 from production_stack_tpu.models.registry import (
     block_state_shape,
+    page_sides,
     get_family,
 )
 from production_stack_tpu.parallel import multihost
@@ -86,15 +87,35 @@ def kv_page_dims(model_config, kv_cache_dtype: str = "bf16",
     (``Family.page_layers``; every layer for most) and what a page keeps
     of a token per layer, ``(KVH, D)`` or narrow heads side by side in
     128-lane rows (``ops.attention.packed_page_dims``; never for int8
-    pages, nor with ``packed`` off: a pool sharded over kv heads)."""
+    pages, nor with ``packed`` off: a pool sharded over kv heads). Of a
+    family whose two sides differ (``Family.page_sides``), the first
+    side's: :func:`kv_page_sides` has both."""
+    layers, first, _ = kv_page_sides(model_config, kv_cache_dtype, packed)
+    return (layers,) + first
+
+
+def kv_page_sides(model_config, kv_cache_dtype: str = "bf16",
+                  packed: bool = True):
+    """(layers that hold pages, (rows, lanes) of the pool's first side,
+    (rows, lanes) of its second). The two are the same for grouped keys
+    and values. A family that says what its sides are
+    (``Family.page_sides``: a latent and a rotated key) gets each at its
+    own width rounded up to whole 128-lane tiles: the lanes beyond the
+    width hold zeros, and a one-row side ``[.., bs, 1, lanes]`` is laid
+    out by the device as ``bs x lanes`` tiles, with no padding of its
+    own."""
     from production_stack_tpu.models.registry import page_layers
     from production_stack_tpu.ops.attention import packed_page_dims
 
     mc = model_config
+    own = page_sides(mc)
+    if own is not None:
+        return (page_layers(mc),) + tuple(
+            (rows, -(-width // 128) * 128) for rows, width in own)
     dims = (packed_page_dims(mc.num_kv_heads, mc.head_dim,
                              kv_cache_dtype == "int8")
             if packed else (mc.num_kv_heads, mc.head_dim))
-    return (page_layers(mc),) + dims
+    return page_layers(mc), dims, dims
 
 
 def kv_bytes_per_block(model_config, block_size: int,
@@ -114,15 +135,22 @@ def kv_bytes_per_block(model_config, block_size: int,
     shapes.
 
     A family with a state per block (``Family.block_state``) adds its
-    ``layers x rows x width`` values in the model's dtype."""
+    ``layers x rows x width`` values in the model's dtype. A family with
+    its own page sides (``Family.page_sides``) holds each side at its
+    stored lanes (:func:`kv_page_sides`): 512 + 128 lanes a token for a
+    512-wide latent and a 64-wide rotated key, 1,280 bytes in bf16 where
+    the values alone are 1,152."""
     mc = model_config
-    layers, kvh, d = kv_page_dims(mc, kv_cache_dtype)
+    layers, (kvh, d), second = kv_page_sides(mc, kv_cache_dtype)
     itemsize = jnp.dtype(mc.dtype).itemsize
     state = block_state_shape(mc)
     state_bytes = 0
     if state is not None:
         rows = -(-state[1] // 8) * 8 if state[2] % 128 else state[1]
         state_bytes = state[0] * rows * (-(-state[2] // 128) * 128) * itemsize
+    if page_sides(mc) is not None:
+        return state_bytes + layers * block_size * itemsize * sum(
+            rows * lanes for rows, lanes in ((kvh, d), second))
     if kv_cache_dtype == "int8":
         if d % 128 != 0:
             d = -(-d // 128) * 128
@@ -331,9 +359,14 @@ class EngineCore:
         self.block_state_shape = block_state_shape(self.model_config)
         # (layers, rows, lanes) of this engine's pages; a pool sharded
         # over kv heads keeps one head a row.
-        self.page_dims = kv_page_dims(
+        layers_held, *self.page_side_dims = kv_page_sides(
             self.model_config, config.kv_cache_dtype,
             packed=self.mesh.shape.get("tp", 1) == 1)
+        self.page_dims = (layers_held,) + self.page_side_dims[0]
+        # ``page_side_dims``: both sides' (rows, lanes); what a token
+        # keeps on each where the family says so (Family.page_sides: the
+        # pool's lanes are those widths in whole tiles), else None.
+        self.own_page_sides = page_sides(self.model_config)
         # What the family's forward counts (models/registry.py::
         # Family.stats; the expert layer's assignment counts): the step
         # programs return the sums beside their tokens, and the step that
@@ -364,6 +397,7 @@ class EngineCore:
             )
 
         self._refuse_what_the_block_state_is_not_taught()
+        self._refuse_what_the_page_sides_are_not_taught()
 
         # -- parameters (sharded over the mesh) ----------------------------
         lora_kwargs = {}
@@ -610,6 +644,11 @@ class EngineCore:
         # grouped-matmul kernel) or "xla" (ragged_dot); exported as
         # tpu:expert_matmul_dispatch_total{path=...}.
         self.expert_matmul_dispatch_total = {"pallas": 0, "xla": 0}
+        # Decode programs of a model with a latent cache, by the path
+        # the absorbed attention over its pages takes: "pallas"
+        # (ops/pallas_mla_decode.py) or "xla" (gather and einsum);
+        # exported as tpu:latent_decode_dispatch_total{path=...}.
+        self.latent_decode_dispatch_total = {"pallas": 0, "xla": 0}
         # While set, _dispatch diverts prefill/decode ops into this list
         # (each entry (name, static, arrays, placeholder)) instead of
         # executing them; _do_fused then issues them as one "fused" op.
@@ -813,8 +852,9 @@ class EngineCore:
     def _alloc_kv(self):
         mc = self.model_config
         layers, rows, lanes = self.page_dims
-        shape = (layers, self.num_blocks, self.config.block_size, rows,
-                 lanes)
+        shape, v_shape = (
+            (layers, self.num_blocks, self.config.block_size) + side
+            for side in self.page_side_dims)
         state_shape = self.block_state_shape and (
             self.block_state_shape[0], self.num_blocks) + self.block_state_shape[1:]
 
@@ -845,9 +885,24 @@ class EngineCore:
         @functools.partial(jax.jit, out_shardings=self._kv_pair_sharding)
         def zeros():
             z = jnp.zeros(shape, mc.jnp_dtype)
-            return with_state(z, jnp.zeros(shape, mc.jnp_dtype))
+            return with_state(z, jnp.zeros(v_shape, mc.jnp_dtype))
 
         return zeros()
+
+    def _asked_to_move_pages(self) -> Dict[str, bool]:
+        """flag -> whether this engine was asked for it, over the
+        surfaces that move, roll back or split pages, which a family
+        whose blocks hold more than grouped keys and values has to be
+        taught one by one."""
+        cfg = self.config
+        return {
+            "--speculative-num-tokens": cfg.speculative_num_tokens > 0,
+            "--speculative-draft-model": bool(cfg.speculative_draft_model),
+            "--kv-offload-bytes": cfg.kv_offload_bytes > 0,
+            "--kv-remote-url": bool(cfg.kv_remote_url),
+            "--tensor-parallel-size / a mesh of several devices":
+                self.mesh.size > 1 or self._mh is not None,
+        }
 
     def _refuse_what_the_block_state_is_not_taught(self) -> None:
         """A family whose blocks hold a state beside their pages
@@ -857,21 +912,36 @@ class EngineCore:
         silently."""
         if not self.block_state_shape:
             return
-        cfg = self.config
-        asked = {
-            "--speculative-num-tokens": cfg.speculative_num_tokens > 0,
-            "--speculative-draft-model": bool(cfg.speculative_draft_model),
-            "--kv-offload-bytes": cfg.kv_offload_bytes > 0,
-            "--kv-remote-url": bool(cfg.kv_remote_url),
-            "--tensor-parallel-size / a mesh of several devices":
-                self.mesh.size > 1 or self._mh is not None,
-        }
+        asked = self._asked_to_move_pages()
         refused = sorted(flag for flag, on in asked.items() if on)
         if refused:
             raise ValueError(
                 f"model arch {self.model_config.arch!r} keeps a state per "
                 f"cache block beside its pages, which {', '.join(refused)} "
                 "would not carry: not supported for this family yet")
+
+    def _refuse_what_the_page_sides_are_not_taught(self) -> None:
+        """A family whose page has two sides of its own shapes
+        (``Family.page_sides``: a latent cache) is served by the paths
+        that move block ids, and by ``extract_kv`` / ``inject_kv_blocks``,
+        which speak each side's own shape. Every other surface that moves
+        page *bytes*, or reads a page as ``num_kv_heads x head_dim`` keys
+        and values, is refused here by the flag that asks for it (the
+        KV-transfer routes of the server answer 501; pipeline stages and
+        int8 weights are refused beside this, by ``Family.pipeline`` and
+        ``Family.quant_keys``)."""
+        if self.own_page_sides is None:
+            return
+        asked = {**self._asked_to_move_pages(),
+                 "--kv-cache-dtype int8":
+                     self.config.kv_cache_dtype == "int8"}
+        refused = sorted(flag for flag, on in asked.items() if on)
+        if refused:
+            raise ValueError(
+                f"model arch {self.model_config.arch!r} keeps a latent and "
+                "a rotated key per token, two page sides of unequal width, "
+                f"which {', '.join(refused)} is not taught: not supported "
+                "for this family yet")
 
     @staticmethod
     def _is_resource_exhausted(exc: BaseException) -> bool:
@@ -1426,6 +1496,9 @@ class EngineCore:
             fn = self._multi_decode_fn(K)
             self._steps.note_program(fn.__name__)
             self._count_expert_matmul_path(arrays[0].shape[0])
+            if self.own_page_sides is not None:
+                self.latent_decode_dispatch_total[
+                    self._latent_decode_path()] += 1
             # Feedback tokens always carry the FULL decode_steps width
             # (bursts pad their output) so adaptive widths share shapes.
             tokens_prev = (
@@ -1738,27 +1811,36 @@ class EngineCore:
         return {
             "hashes": hashes,
             "num_tokens": len(hashes) * bs,
-            "k": self._one_head_a_row(k),
-            "v": self._one_head_a_row(v),
+            "k": self._one_head_a_row(k, 0),
+            "v": self._one_head_a_row(v, 1),
             # [N, layers, rows, width]: the blocks' state, where the
             # family keeps one (Family.block_state).
             **({"state": state[0]} if state else {}),
         }
 
-    def _one_head_a_row(self, side):
+    def _one_head_a_row(self, side, which: int = 0):
         """A payload's pages in the logical layout every surface speaks,
         ``[..., bs, KVH, D]``, whatever rows the pool keeps them in
-        (kv_page_dims); int8 payloads are never packed."""
+        (kv_page_dims); int8 payloads are never packed. Side ``which``
+        of a family with its own page sides (``Family.page_sides``) is
+        ``[..., bs, rows, width]``: the lanes beyond the width, zeros,
+        stay behind."""
         if isinstance(side, tuple):
             return side
+        if self.own_page_sides is not None:
+            return side[..., :self.own_page_sides[which][1]]
         mc = self.model_config
         return side.reshape(
             side.shape[:-2] + (mc.num_kv_heads, mc.head_dim))
 
-    def _as_pool_rows(self, side):
+    def _as_pool_rows(self, side, which: int = 0):
         """The inverse: a logical payload in the pool's rows."""
         if isinstance(side, tuple):
             return side
+        if self.own_page_sides is not None:
+            lanes = self.page_side_dims[which][1]
+            return jnp.pad(side, [(0, 0)] * (side.ndim - 1)
+                           + [(0, lanes - side.shape[-1])])
         return side.reshape(side.shape[:-2] + self.page_dims[1:])
 
     def extract_kv_device(self, token_ids: List[int], adapter: str = ""):
@@ -1800,8 +1882,8 @@ class EngineCore:
             idx = jnp.asarray(bids)
             # Dispatched under _step_lock so the gather reads self.kv
             # before any later engine step donates the buffer.
-            k = self._one_head_a_row(_kv_leaf_index(k_pages, idx))
-            v = self._one_head_a_row(_kv_leaf_index(v_pages, idx))
+            k = self._one_head_a_row(_kv_leaf_index(k_pages, idx), 0)
+            v = self._one_head_a_row(_kv_leaf_index(v_pages, idx), 1)
             state = [side[:, idx] for side in state]
         return {
             "hashes": hashes,
@@ -1869,8 +1951,8 @@ class EngineCore:
                                     _kv_leaf_index(kk, sl),
                                     _kv_leaf_index(vv, sl)))
                     else:
-                        k_arr = self._as_pool_rows(_kv_leaf_jnp(k))
-                        v_arr = self._as_pool_rows(_kv_leaf_jnp(v))
+                        k_arr = self._as_pool_rows(_kv_leaf_jnp(k), 0)
+                        v_arr = self._as_pool_rows(_kv_leaf_jnp(v), 1)
                         take = np.asarray(fresh_idx)
                         self.kv = self._write_blocks_fn(
                             self.kv, np.asarray(fresh_bids, np.int32),
@@ -2464,6 +2546,7 @@ class EngineCore:
         cfg = self.model_config
         bs = self.config.block_size
         page_dims, state = self.page_dims, self.block_state_shape
+        side_dims = self.page_side_dims
 
         def embed_fwd(params, token_ids, positions, slot_mapping,
                       block_tables, seq_lens):
@@ -2471,9 +2554,8 @@ class EngineCore:
             # (a host-side jnp.zeros would be committed to one process's
             # local device and could not feed a multi-host computation);
             # slot_mapping is all -1, so writes drop.
-            kv_shape = (page_dims[0], 1, bs) + page_dims[1:]
-            kv = (jnp.zeros(kv_shape, cfg.jnp_dtype),
-                  jnp.zeros(kv_shape, cfg.jnp_dtype))
+            kv = tuple(jnp.zeros((page_dims[0], 1, bs) + side,
+                                 cfg.jnp_dtype) for side in side_dims)
             if state is not None:
                 kv += (jnp.zeros((state[0], 1) + state[1:], cfg.jnp_dtype),)
             hidden, _ = apply(
@@ -2605,6 +2687,8 @@ class EngineCore:
                 dict(self.prefill_attention_dispatch_total),
             "expert_matmul_dispatch_total":
                 dict(self.expert_matmul_dispatch_total),
+            "latent_decode_dispatch_total":
+                dict(self.latent_decode_dispatch_total),
             "dispatch_count_total": phases["enqueue"]["count"],
             "dispatch_enqueue_s": round(phases["enqueue"]["seconds"], 3),
             "decode_forward_steps_total": self.decode_forward_steps_total,
@@ -3055,12 +3139,29 @@ class EngineCore:
         ``kv_fetch_tokens``."""
         from production_stack_tpu.ops.attention import attention_path
 
+        if self.own_page_sides is not None:
+            # A latent cache: cached prefill up-projects a gathered
+            # context (models/decoder.py::attend_latent), and decode has
+            # a path of its own (_latent_decode_path).
+            return "xla"
         _, rows, lanes = self.page_dims
         mc = self.model_config
         return attention_path(
             self.config.block_size, rows, lanes,
             self.config.kv_cache_dtype == "int8", self._kv_shards,
             packed=(rows, lanes) != (mc.num_kv_heads, mc.head_dim))
+
+    def _latent_decode_path(self) -> str:
+        """Which path the absorbed decode attention over a latent cache
+        takes at this engine's shapes (the trace-time choice of
+        ops/attention.py::latent_decode_attention, evaluated again from
+        the same shapes)."""
+        from production_stack_tpu.ops.attention import latent_decode_path
+
+        (_, latent), (_, lanes) = self.page_side_dims
+        mc = self.model_config
+        return latent_decode_path(self.config.block_size, mc.num_heads,
+                                  latent, lanes, mc.dtype)
 
     def _do_fused(self, plan) -> None:
         """Execute one scheduler "fused" action: the budgeted prefill
@@ -3388,6 +3489,14 @@ class EngineCore:
                 {"req": req_m, "seq": seq, "slot": slot,
                  "sampled": sampled, "row": row})
 
+    def _note_attn_pairs(self, start: int, end: int) -> None:
+        """A prefill span ``[start, end)`` of a prompt is dispatched: its
+        causal pairs (the query at position p sees p + 1 keys) join the
+        step's record as ``attn_pairs``, the model's own count of what
+        prefill attention has to compute, whatever the program pads."""
+        self._steps.note_sum(
+            attn_pairs=(end * (end + 1) - start * (start + 1)) // 2)
+
     def _prefill_rows(self, rows, pad_to: int = 0, plain_rung: int = 0):
         """One batched prefill dispatch: rows = [(req, tokens, block_ids,
         start, end), ...]. With ``plain_rung`` the rows are whole uncached
@@ -3398,6 +3507,8 @@ class EngineCore:
         one compiled variant per block-table width regardless of the
         step's composition. Returns the sampled tuple (a row each)."""
         cfg = self.config
+        for row in rows:
+            self._note_attn_pairs(*row[3:5])
         if plain_rung:
             R, bucket = len(rows), plain_rung
             maxb = self._table_width(bucket)
@@ -3478,6 +3589,7 @@ class EngineCore:
         attention over the block table sees the full prefix."""
         cfg = self.config
         take = end - start
+        self._note_attn_pairs(start, end)
         bucket = cfg.bucket_for(take, plain=start == 0)
         # Bucket the block-table width (power of two, min 4) so
         # cached-prefill attention cost scales with the real context, not
@@ -3740,7 +3852,8 @@ class EngineCore:
             self._fill_mask_row(mask_bits, mask_on, i, r)
             r.scheduled_steps += allow
 
-        if self._paged_attn_path() == "pallas":
+        if (self._latent_decode_path() if self.own_page_sides is not None
+                else self._paged_attn_path()) == "pallas":
             # What the decode kernel copies for this burst, beside what
             # it has to: per scan step, the live pages of the rows that
             # write a token in it, and the tokens they hold (the host's

@@ -659,9 +659,10 @@ class EngineServer:
         r.add_post("/v1/unload_lora_adapter", self.handle_unload_lora)
         r.add_get("/v1/lora_adapters", self.handle_list_lora)
         # KV transfer (disaggregated prefill / cross-engine KV sharing).
-        # Its wire formats carry keys and values: a family whose blocks
-        # hold a state beside them (Family.block_state) answers 501 on
-        # every route rather than hand out pages without it.
+        # Its wire formats carry keys and values of one shape: a family
+        # whose blocks hold a state beside them (Family.block_state), or
+        # whose two sides are of their own shapes (Family.page_sides),
+        # answers 501 on every route rather than hand out half of it.
         for path, handler in (
                 ("/kv/extract", self.handle_kv_extract),
                 ("/kv/inject", self.handle_kv_inject),
@@ -669,7 +670,8 @@ class EngineServer:
                 ("/kv/prepare_pull", self.handle_kv_prepare_pull),
                 ("/kv/release", self.handle_kv_release)):
             r.add_post(path, self._kv_transfer_refused
-                       if self.core.block_state_shape else handler)
+                       if self.core.block_state_shape
+                       or self.core.own_page_sides else handler)
         r.add_post("/v1/audio/transcriptions", self.handle_transcriptions)
         # Flight recorder (engine-side stage spans per request).
         from production_stack_tpu.obs.debug import (
@@ -1965,8 +1967,9 @@ class EngineServer:
 
     async def _kv_transfer_refused(self, request: web.Request) -> web.Response:
         return web.json_response(
-            {"error": "this model keeps a state per cache block beside its "
-                      "pages, which the KV transfer formats do not carry"},
+            {"error": "this model's cache blocks hold what the KV transfer "
+                      "formats do not carry (a state beside the pages, or "
+                      "two page sides of unequal width)"},
             status=501)
 
     async def handle_kv_extract(self, request: web.Request) -> web.StreamResponse:
@@ -2653,6 +2656,13 @@ class EngineServer:
             f"{s.get('expert_matmul_dispatch_total', {}).get('pallas', 0)}",
             f'tpu:expert_matmul_dispatch_total{{{labels},path="xla"}} '
             f"{s.get('expert_matmul_dispatch_total', {}).get('xla', 0)}",
+            # Decode programs of a model with a latent cache, by the path
+            # its absorbed attention takes (0 for any other model).
+            "# TYPE tpu:latent_decode_dispatch counter",
+            f'tpu:latent_decode_dispatch_total{{{labels},path="pallas"}} '
+            f"{s.get('latent_decode_dispatch_total', {}).get('pallas', 0)}",
+            f'tpu:latent_decode_dispatch_total{{{labels},path="xla"}} '
+            f"{s.get('latent_decode_dispatch_total', {}).get('xla', 0)}",
             # Structured output (guided_json / guided_regex /
             # response_format): grammar constraints compiled to token FSMs
             # applied inside the fused programs.

@@ -81,6 +81,21 @@ class ModelConfig:
     # Gated short convolution (lfm2's ``conv`` layers): the kernel's
     # length; a layer's state is its last ``conv_kernel - 1`` inputs.
     conv_kernel: int = 0
+    # Latent attention (longcat's MLA): the ranks of the query's and the
+    # cache's low-rank projections, a head's widths without and with
+    # rotary embedding and its value's, and whether each latent is
+    # multiplied by sqrt(hidden_size / its rank) after its norm. A token's
+    # page keeps the ``kv_lora_rank`` latent and the one rotated key.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    mla_scale_q_lora: bool = False
+    mla_scale_kv_lora: bool = False
+    # Zero-compute experts: router outputs behind the experts with
+    # weights that give the layer's input back (models/moe.py).
+    zero_experts: int = 0
     dtype: str = "bfloat16"
 
     @property
@@ -164,6 +179,19 @@ _PRESETS = {
         conv_kernel=3, tie_word_embeddings=True,
         layer_types=(SHORT_CONV, SHORT_CONV, FULL_ATTENTION, SHORT_CONV,
                      SHORT_CONV, FULL_ATTENTION),
+    ),
+    # Two layers of two latent-attention sublayers (a 128-wide latent and
+    # a 16-wide rotated key a token), the second of two shares of 8
+    # experts, 4 zero-compute experts behind them, top 3 not renormalised.
+    "tiny-longcat": ModelConfig(
+        name="tiny-longcat", arch="longcat", vocab_size=512,
+        hidden_size=128, num_layers=2, num_heads=8, num_kv_heads=1,
+        head_dim=48, intermediate_size=256, max_position=2048,
+        rope_theta=10000000.0, num_experts=4, experts_per_token=3,
+        moe_intermediate_size=64, routed_scaling=6.0, chips_per_layer=2,
+        layer_share=1, router_bias=True, q_lora_rank=64, kv_lora_rank=128,
+        qk_nope_head_dim=32, qk_rope_head_dim=16, v_head_dim=32,
+        mla_scale_q_lora=True, mla_scale_kv_lora=True, zero_experts=4,
     ),
     "tiny-opt": ModelConfig(
         name="tiny-opt", arch="opt", vocab_size=512, hidden_size=128,
@@ -272,17 +300,15 @@ def rope_params(block: dict) -> RopeParams:
 
 
 def _layer_kind_keys(cfg: dict, arch: str, layers: int) -> dict:
-    """The ``ModelConfig`` fields of a family whose layers are of several
-    kinds, which the family reads of its own keys
-    (``Family.config_fields``). Per-layer lists are read for their first
-    ``num_hidden_layers`` entries (a cut in layers keeps the lists at
-    their published length). A family that needs them
-    (``Family.per_layer_keys``) refuses a file without them; any other
-    family reads none of them."""
+    """The ``ModelConfig`` fields a family reads of its own keys
+    (``Family.config_fields``; {} for a family without one). Per-layer
+    lists are read for their first ``num_hidden_layers`` entries (a cut
+    in layers keeps the lists at their published length). A family that
+    needs them (``Family.per_layer_keys``) refuses a file without them."""
     from production_stack_tpu.models.registry import get_family
 
     family = get_family(arch)
-    if not family.per_layer_keys:
+    if family.config_fields is None:
         return {}
     for key in family.per_layer_keys:
         held = cfg.get(key)

@@ -8,7 +8,8 @@ A family (models/registry.py::Family) brings ``embed``, ``layer`` and
   (prefill), over the pages plus the fresh suffix (cached prefill) or over
   the pages alone (decode). The only code under ``models/`` that imports
   ``ops.attention`` and the only code that spells the three mode names:
-  a window mask or a latent cache has this one call site to change. A
+  a window mask has this one call site to change, and a latent cache
+  (MLA) has its sibling :func:`attend_latent`, the only other. A
   state per cache block beside the pages (a short convolution's last
   inputs) is read and written by :func:`read_block_state` and
   :func:`write_block_state`;
@@ -31,6 +32,9 @@ import jax.numpy as jnp
 from production_stack_tpu.models.config import ModelConfig
 from production_stack_tpu.ops.attention import (
     context_prefill_attention,
+    dense_context_attention,
+    gather_latents,
+    latent_decode_attention,
     paged_decode_attention,
     prefill_attention,
     write_kv_pages,
@@ -103,6 +107,97 @@ def attend(
                 layer, scale=scale, **bound,
             )[:, None]
     return attn, (k_pages, v_pages)
+
+
+def attend_latent(
+    mode: str,  # "prefill" | "prefill_cached" | "decode"  (static)
+    q_nope: jax.Array,  # [B, T, H, N]
+    q_rope: jax.Array,  # [B, T, H, R], rotated
+    c: jax.Array,  # [B, T, C] the chunk's normed latents
+    k_rope: jax.Array,  # [B, T, R] the one rotated key of all heads
+    w_up: jax.Array,  # [H, C, N + V]: latent -> a head's key and value
+    kv: Tuple,  # STACKED pages ([L, NB, bs, 1, C], [L, NB, bs, 1, lanes])
+    layer: jax.Array,  # scalar page-layer index
+    batch: Batch,
+    *,
+    scale: float,
+    latent_scale: float = 1.0,  # on the latent before ``w_up``
+):
+    """:func:`attend` for a latent cache (multi-head latent attention):
+    a token's page keeps ``c`` on the first side and ``k_rope`` on the
+    second (zeros in the lanes beyond it) and no head's keys or values.
+    Writes them, then attends; returns (the heads' outputs [B, T, H, V],
+    updated pages). Head ``n``'s key is ``[(s c) Wuk[n], k_rope]`` and
+    its value ``(s c) Wuv[n]`` with ``s = latent_scale`` and ``Wuk``,
+    ``Wuv`` the two halves of ``w_up``; the two forms below are that same
+    product in two orders:
+
+    - ``prefill`` and ``prefill_cached`` **up-project**: the chunk's
+      latents (prefill) or the whole context's, gathered from the pages
+      (cached prefill: the prefix and the chunk just written), become
+      per-head keys of ``N + R`` and values of ``V`` lanes, and the
+      chunk attends causally. With T queries over S keys the
+      up-projection is ``S x C x H x (N + V)`` multiply-adds beside
+      attention's ``T x S x H x (N + R + V)``, where the absorbed form
+      would make the latter ``T x S x H x (2C + R)``: at a chunk of 1,024
+      up-projecting is the cheaper by a factor of ~2.5 (PERF.md
+      section 6, PR 41).
+    - ``decode`` **absorbs**: ``q_abs[n] = s q_nope[n] Wuk[n]^T`` (C
+      wide), scores ``q_abs . c + q_rope . k_rope`` over the pages, the
+      output ``p c`` still latent, then ``s o_lat[n] Wuv[n]``. No key and
+      no value of a head is ever built, and a page is read once for all
+      heads (``ops/pallas_mla_decode.py`` on the chip). The two matmuls
+      around the kernel sit under the scope ``mla_absorb``."""
+    N = q_nope.shape[-1]
+    R = k_rope.shape[-1]
+    lanes = kv[1].shape[-1]
+    c_pages, r_pages = write_kv_pages(
+        *kv, c[:, :, None, :],
+        jnp.pad(k_rope, ((0, 0), (0, 0), (0, lanes - R)))[:, :, None, :],
+        batch.slot_mapping, layer)
+
+    def scaled(x):  # the latent's factor, applied in float32
+        return (x.astype(jnp.float32) * latent_scale).astype(x.dtype)
+
+    def up_project(latents, rotary):
+        """([.., S, H, N + R] keys, [.., S, H, V] values) of latents
+        ``[B, S, C]`` and their rotated keys ``[B, S, R]``."""
+        with jax.named_scope("mla_proj"):
+            up = jnp.einsum("bsc,hcd->bshd", scaled(latents), w_up)
+            keys = jnp.concatenate(
+                [up[..., :N], jnp.broadcast_to(
+                    rotary[:, :, None, :], up.shape[:3] + (R,))], axis=-1)
+            return keys, up[..., N:]
+
+    if mode == "decode":
+        live = jnp.where(
+            batch.slot_mapping[:, 0] >= 0, batch.context_lens, 0)
+        with jax.named_scope("mla_absorb"):
+            q_abs = jnp.einsum("bhn,hcn->bhc", scaled(q_nope[:, 0]),
+                               w_up[..., :N])
+        with jax.named_scope("attention"):
+            o_lat = latent_decode_attention(
+                q_abs, q_rope[:, 0], c_pages, r_pages, batch.block_tables,
+                live, layer, scale=scale)
+        with jax.named_scope("mla_absorb"):
+            attn = jnp.einsum("bhc,hcv->bhv", scaled(o_lat),
+                              w_up[..., N:])[:, None]
+        return attn, (c_pages, r_pages)
+    q = jnp.concatenate([q_nope, q_rope], axis=-1)
+    if mode == "prefill":
+        k, v = up_project(c, k_rope)
+        with jax.named_scope("attention"):
+            attn = prefill_attention(q, k, v, scale=scale,
+                                     seq_lens=batch.seq_lens)
+    else:
+        with jax.named_scope("attention"):  # the pages' bytes are its
+            context = gather_latents(c_pages, r_pages, batch.block_tables,
+                                     layer, R)
+        k, v = up_project(*context)
+        with jax.named_scope("attention"):
+            attn = dense_context_attention(
+                q, k, v, batch.positions, batch.context_lens, scale=scale)
+    return attn, (c_pages, r_pages)
 
 
 def read_block_state(state: jax.Array, at, batch: Batch, block_size: int):

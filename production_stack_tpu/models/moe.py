@@ -47,6 +47,10 @@ from production_stack_tpu.ops import pallas_grouped_matmul as gmm
 
 # What :func:`expert_layer` counts of one call, in this order.
 STATS = ("moe_assignments", "moe_experts_hit", "moe_max_expert_load")
+# What it counts beside them in a layer with zero-compute experts: the
+# assignments that landed on one (a family with such a layer puts
+# ``ZERO_STATS`` behind ``STATS`` in its ``Family.stats``).
+ZERO_STATS = ("moe_zero_assignments",)
 
 
 def swiglu(h: jax.Array, w_gate, w_up, w_down) -> jax.Array:
@@ -58,11 +62,13 @@ def swiglu(h: jax.Array, w_gate, w_up, w_down) -> jax.Array:
 
 def route(h: jax.Array, router: jax.Array, k: int, *, scaling: float = 1.0,
           scoring: str = "softmax", bias: jax.Array | None = None,
-          eps: float = 0.0) -> Tuple[jax.Array, jax.Array]:
+          eps: float = 0.0, renormalise: bool = True
+          ) -> Tuple[jax.Array, jax.Array]:
     """(weights [N, k] float32, experts [N, k]) of the tokens ``h [N, Hd]``
     over all of ``router``'s outputs: float32 scores (``softmax`` over
     them all, or a ``sigmoid`` of each), top k, weights renormalised to
-    sum 1 (``+ eps``) and multiplied by ``scaling``. With ``bias`` (float32
+    sum 1 (``+ eps``; the scores as they are with ``renormalise`` off)
+    and multiplied by ``scaling``. With ``bias`` (float32
     ``[E]``) the experts are *selected* by score plus bias and *weighted*
     by the score without it."""
     logits = jnp.dot(h, router, preferred_element_type=jnp.float32)
@@ -77,8 +83,9 @@ def route(h: jax.Array, router: jax.Array, k: int, *, scaling: float = 1.0,
     else:
         _, experts = jax.lax.top_k(scores + bias, k)
         weights = jnp.take_along_axis(scores, experts, axis=-1)
-    total = jnp.sum(weights, axis=-1, keepdims=True)
-    weights = weights / (total + eps if eps else total)
+    if renormalise:
+        total = jnp.sum(weights, axis=-1, keepdims=True)
+        weights = weights / (total + eps if eps else total)
     return weights * scaling, experts
 
 
@@ -92,12 +99,23 @@ def expert_layer(
     share: int = 0,  # this chip's block of experts: [share * held, ...)
     scaling: float = 1.0,
     valid: jax.Array | None = None,  # [B, T] bool: padding routes nowhere
-    routing: Dict | None = None,  # route()'s scoring / bias / eps
+    routing: Dict | None = None,  # route()'s scoring / bias / eps / ...
+    zero_experts: int = 0,  # the router's last outputs are identities
 ) -> Tuple[jax.Array, jax.Array]:
     """The routed experts held here on ``h``. Returns (their weighted sum
     per token [B, T, Hd], the :data:`STATS` of the call as int32 [3]: the
     assignments the held experts received, how many of them received one,
-    and the largest number one received)."""
+    and the largest number one received).
+
+    With ``zero_experts`` the router's outputs ``[E, E + zero_experts)``
+    behind the ``E`` experts that have weights are identities
+    (zero-compute experts): each gives the layer's input back, so their
+    part of the sum is ``(sum of their weights) * h``, added under the
+    scope ``moe_zero``; they have no weights to hold, so every chip of
+    the layer has them all and a token's home chip applies them. In the
+    grouped matmul they sort past the groups like another chip's
+    experts. The call's stats are then :data:`STATS` + :data:`ZERO_STATS`
+    (int32 [4]): the assignments that landed on one, last."""
     B, T, Hd = h.shape
     N = B * T
     held = p["w_gate"].shape[1]
@@ -151,5 +169,14 @@ def expert_layer(
         out = out[jnp.argsort(order)].reshape(N, k, Hd)
         out = jnp.where(mine[..., None], out.astype(jnp.float32), 0.0)
         y = jnp.einsum("nkh,nk->nh", out, weights).astype(h.dtype)
-    stats = jnp.stack([jnp.sum(sizes), jnp.sum(sizes > 0), jnp.max(sizes)])
-    return y.reshape(B, T, Hd), stats
+    stats = [jnp.sum(sizes), jnp.sum(sizes > 0), jnp.max(sizes)]
+    if zero_experts:
+        with jax.named_scope("moe_zero"):
+            identity = experts >= p["router"].shape[-1] - zero_experts
+            if valid is not None:
+                identity = identity & valid.reshape(N, 1)
+            y = y + (jnp.sum(jnp.where(identity, weights, 0.0), axis=-1,
+                             keepdims=True)
+                     * x.astype(jnp.float32)).astype(h.dtype)
+        stats.append(jnp.sum(identity, dtype=jnp.int32))
+    return y.reshape(B, T, Hd), jnp.stack(stats)

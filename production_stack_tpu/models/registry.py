@@ -23,6 +23,7 @@ ARCH_MODULES = {
     "mixtral": "production_stack_tpu.models.mixtral",
     "laguna": "production_stack_tpu.models.laguna",
     "lfm2": "production_stack_tpu.models.lfm2",
+    "longcat": "production_stack_tpu.models.longcat",
 }
 
 
@@ -84,9 +85,10 @@ class Family:
     # name, which the step programs sum over their forwards and the step
     # record carries under these names (engine/core.py, obs/steps.py).
     stats: Tuple[str, ...] = ()
-    # ``(config.json as a dict, num_hidden_layers) -> ModelConfig fields``
-    # of a family with ``per_layer_keys``: what it reads of its own keys,
-    # laid over the common ones (models/config.py names no family's).
+    # ``(config.json as a dict, num_hidden_layers) -> ModelConfig fields``:
+    # what the family reads of its own keys, laid over the common ones
+    # (models/config.py names no family's; called for any family that
+    # has one, with or without ``per_layer_keys``).
     config_fields: Callable | None = None
     # ``(cfg) -> int``: how many of its layers hold KV pages, where not
     # all do: the pool's pages are ``[that many, NB, bs, ...]`` and the
@@ -104,6 +106,27 @@ class Family:
     # slots, pipeline stages and a mesh of several devices are not
     # taught it yet.
     block_state: Callable | None = None
+    # ``(cfg) -> ((rows, width), (rows, width))``: what a token keeps on
+    # the two sides of a page, for a family whose pages are not grouped
+    # keys and values of ``num_kv_heads x head_dim`` each: a latent cache
+    # keeps one normed latent row on the first side and one rotated key,
+    # shared by every head, on the second, of unequal widths (models/
+    # longcat.py, docs/engine.md). The pool's sides are then ``[page
+    # layers, NB, bs, rows, lanes]`` each with its own ``lanes`` (the
+    # width rounded up to whole 128-lane tiles, zeros beyond it), the
+    # family attends through ``decoder.attend_latent``, and every surface
+    # that speaks pages speaks ``[..., bs, rows, width]`` per side. None:
+    # keys and values of the grouped heads. Surfaces that move page bytes
+    # and are not taught two shapes are refused at start-up
+    # (engine/core.py::_refuse_what_the_page_sides_are_not_taught).
+    page_sides: Callable | None = None
+
+
+def page_sides(cfg: ModelConfig):
+    """((rows, width), (rows, width)) of the two sides of a token's page
+    where the family says so (``Family.page_sides``), else None."""
+    sides = get_family(cfg.arch).page_sides
+    return None if sides is None else tuple(map(tuple, sides(cfg)))
 
 
 def page_layers(cfg: ModelConfig) -> int:

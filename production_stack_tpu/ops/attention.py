@@ -229,7 +229,9 @@ def prefill_attention(
     seq_lens: jax.Array | None = None,  # [B] valid lengths (padding masked)
     window: int | None = None,  # static: query i sees key j iff i - j < window
 ) -> jax.Array:
-    """Causal attention over a prompt chunk. Returns [B, T, H, D]."""
+    """Causal attention over a prompt chunk. Returns [B, T, H, Dv]: the
+    values may be of another width than queries and keys (a latent
+    cache's up-projected heads: 192-wide keys, 128-wide values)."""
     B, T, H, D = q.shape
     KVH = k.shape[2]
     group = H // KVH
@@ -250,7 +252,7 @@ def prefill_attention(
     out = jnp.einsum(
         "bkgts,bskd->btkgd", probs.astype(v.dtype), v,
     )
-    return out.reshape(B, T, H, D)
+    return out.reshape(B, T, H, v.shape[-1])
 
 
 def _gather_ctx(pages, block_tables: jax.Array, layer: jax.Array,
@@ -369,15 +371,31 @@ def _context_prefill_reference(
     """XLA reference: gather the whole padded context (suffix included —
     it was scattered to the pages by write_kv_pages one op earlier),
     mask causally against ``positions``, softmax."""
-    B, T, H, D = q.shape
-    bs = kv_page_data(k_pages).shape[2]
-    MAXB = block_tables.shape[1]
+    D = q.shape[-1]
     k_ctx = _gather_ctx(k_pages, block_tables, layer, q.dtype, D)
     v_ctx = _gather_ctx(v_pages, block_tables, layer, q.dtype, D)
-    KVH = k_ctx.shape[2]
+    return dense_context_attention(q, k_ctx, v_ctx, positions, total_lens,
+                                   scale=scale, window=window)
+
+
+def dense_context_attention(
+    q: jax.Array,  # [B, T, H, D]
+    k_ctx: jax.Array,  # [B, S, KVH, D] the whole padded context
+    v_ctx: jax.Array,  # [B, S, KVH, Dv]
+    positions: jax.Array,  # [B, T] absolute positions of the queries
+    total_lens: jax.Array,  # [B] full context length (cached + suffix)
+    *,
+    scale: float,
+    window: int | None = None,
+) -> jax.Array:
+    """Attention of a chunk's queries over a context laid out densely
+    (gathered from pages, or up-projected from gathered latents): query
+    at position p sees the context's positions ``<= p`` under
+    ``total_lens``. Returns [B, T, H, Dv]."""
+    B, T, H, D = q.shape
+    S, KVH, Dv = v_ctx.shape[1:]
     group = H // KVH
     qg = q.reshape(B, T, KVH, group, D)
-    S = MAXB * bs
     # The one-shot einsum materializes f32 scores [B, KVH, g, T, S] —
     # fine for single-row prefills, but multi-GB for batched-prefill
     # shapes ([4, 2048] rows over 4k contexts). Past ~1 GB, stream the
@@ -395,7 +413,7 @@ def _context_prefill_reference(
             k_ctx = jnp.pad(k_ctx, ((0, 0), (0, pad), (0, 0), (0, 0)))
             v_ctx = jnp.pad(v_ctx, ((0, 0), (0, pad), (0, 0), (0, 0)))
         k_chunks = k_ctx.reshape(B, nc, chunk, KVH, D).swapaxes(0, 1)
-        v_chunks = v_ctx.reshape(B, nc, chunk, KVH, D).swapaxes(0, 1)
+        v_chunks = v_ctx.reshape(B, nc, chunk, KVH, Dv).swapaxes(0, 1)
 
         def body(carry, inputs):
             m, l, acc, ci = carry
@@ -428,12 +446,12 @@ def _context_prefill_reference(
 
         m0 = jnp.full((B, KVH, group, T, 1), NEG_INF, jnp.float32)
         l0 = jnp.zeros((B, KVH, group, T, 1), jnp.float32)
-        a0 = jnp.zeros((B, KVH, group, T, D), jnp.float32)
+        a0 = jnp.zeros((B, KVH, group, T, Dv), jnp.float32)
         (m, l, acc, _), _ = jax.lax.scan(
             body, (m0, l0, a0, jnp.int32(0)), (k_chunks, v_chunks),
             length=nc)
         out = (acc / jnp.maximum(l, 1e-30)).astype(q.dtype)
-        return out.swapaxes(2, 3).swapaxes(1, 2).reshape(B, T, H, D)
+        return out.swapaxes(2, 3).swapaxes(1, 2).reshape(B, T, H, Dv)
 
     scores = jnp.einsum(
         "btkgd,bskd->bkgts", qg, k_ctx, preferred_element_type=jnp.float32
@@ -447,7 +465,7 @@ def _context_prefill_reference(
     scores = jnp.where(mask[:, None, None, :, :], scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bkgts,bskd->btkgd", probs.astype(v_ctx.dtype), v_ctx)
-    return out.reshape(B, T, H, D)
+    return out.reshape(B, T, H, Dv)
 
 
 @jax.named_scope("kv_write")
@@ -469,13 +487,15 @@ def write_kv_pages(
     reader (reference, pallas, offload) sees one canonical encoding.
     The fresh values take the pages' own trailing dims: where narrow
     heads lie side by side in a row (:func:`packed_page_dims`) that is a
-    row-major view of ``[KVH, D]``."""
-    L, NB, bs, KVH, D = kv_page_data(k_pages).shape
+    row-major view of ``[KVH, D]``. Each side has its own (a latent
+    cache's two sides are of unequal widths)."""
+    L, NB, bs = kv_page_data(k_pages).shape[:3]
     slots = slot_mapping.reshape(-1)
     # Layer offset; out-of-range slots are dropped by scatter mode="drop".
     slots = jnp.where(slots < 0, L * NB * bs, slots + layer * NB * bs)
 
     def scatter(pages, new):
+        KVH, D = kv_page_data(pages).shape[3:]
         if isinstance(pages, tuple):
             data, scales = pages
             q, s = quantize_kv(new)
@@ -566,3 +586,86 @@ def paged_decode_attention(
         q, k_pages, v_pages, block_tables, context_lens, layer, scale=scale,
         window=window,
     )
+
+
+# -- a latent cache (MLA): one normed latent and one rotated key a token --
+
+def latent_decode_path(block_size: int, heads: int, latent: int,
+                       rope_lanes: int, dtype) -> str:
+    """Which backend the absorbed decode attention over latent pages
+    takes at these (static) shapes: ``"pallas"``
+    (ops/pallas_mla_decode.py) or ``"xla"`` (gather and einsum). THE
+    decision, like :func:`attention_path`: the dispatcher and the
+    engine's dispatch counter evaluate it, from shapes, platform and the
+    env override only."""
+    from production_stack_tpu.ops.pallas_mla_decode import tiles_ok
+
+    if (tiles_ok(block_size, heads, latent, rope_lanes,
+                 jnp.dtype(dtype).itemsize) and _use_pallas()):
+        return "pallas"
+    return "xla"
+
+
+def gather_latents(c_pages, r_pages, block_tables, layer, rope: int):
+    """(c [B, S, latent], k_r [B, S, rope]) of a batch's padded context,
+    page-wise out of the stacked sides ``[L, NB, bs, 1, lanes]``, in the
+    pages' dtype."""
+    L, NB, bs = c_pages.shape[:3]
+    B, MAXB = block_tables.shape
+    idx = layer * NB + block_tables
+
+    def side(pages, width):
+        flat = pages.reshape(L * NB, bs, pages.shape[-1])
+        return flat[idx].reshape(B, MAXB * bs, -1)[..., :width]
+
+    return side(c_pages, c_pages.shape[-1]), side(r_pages, rope)
+
+
+def latent_decode_reference(q_abs, q_rope, c_pages, r_pages, block_tables,
+                            context_lens, layer, *, scale: float):
+    """XLA path of :func:`latent_decode_attention`: gather the padded
+    context, mask, softmax in float32. Zeros for a row whose context is
+    0 or less, as the kernel gives."""
+    c_ctx, r_ctx = gather_latents(c_pages, r_pages, block_tables, layer,
+                                   q_rope.shape[-1])
+    scores = (jnp.einsum("bhc,bsc->bhs", q_abs, c_ctx,
+                         preferred_element_type=jnp.float32)
+              + jnp.einsum("bhr,bsr->bhs", q_rope, r_ctx,
+                           preferred_element_type=jnp.float32)) * scale
+    mask = jnp.arange(c_ctx.shape[1])[None, :] < context_lens[:, None]
+    scores = jnp.where(mask[:, None, :], scores, NEG_INF)
+    probs = jax.nn.softmax(scores, axis=-1)
+    out = jnp.einsum("bhs,bsc->bhc", probs.astype(c_ctx.dtype), c_ctx)
+    return jnp.where(context_lens[:, None, None] > 0, out, 0)
+
+
+def latent_decode_attention(
+    q_abs: jax.Array,  # [B, H, latent]: the query with Wuk absorbed
+    q_rope: jax.Array,  # [B, H, rope], rotated
+    c_pages: jax.Array,  # [L, NB, bs, 1, latent]
+    r_pages: jax.Array,  # [L, NB, bs, 1, lanes >= rope]
+    block_tables: jax.Array,
+    context_lens: jax.Array,  # [B]; <= 0: the row holds nothing
+    layer: jax.Array,
+    *,
+    scale: float,
+) -> jax.Array:
+    """``o_lat [B, H, latent]``: every head's attention over the row's
+    live tokens in the latent space, where the page is key and value.
+    The Pallas kernel on the TPU, gather and einsum elsewhere; chosen at
+    trace time and counted in :data:`TRACED_PATHS` under ``latent_decode``."""
+    _, _, bs, _, lanes = r_pages.shape
+    path = latent_decode_path(bs, q_abs.shape[1], q_abs.shape[2], lanes,
+                              c_pages.dtype)
+    TRACED_PATHS["latent_decode", path] += 1
+    if path == "pallas":
+        from production_stack_tpu.ops.pallas_mla_decode import (
+            pallas_mla_decode,
+        )
+
+        return pallas_mla_decode(q_abs, q_rope, c_pages, r_pages,
+                                 block_tables, context_lens, layer,
+                                 scale=scale)
+    return latent_decode_reference(q_abs, q_rope, c_pages, r_pages,
+                                   block_tables, context_lens, layer,
+                                   scale=scale)

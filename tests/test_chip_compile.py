@@ -465,3 +465,114 @@ def test_lfm2_programs_compile_at_the_configurations_widths(
     copied = [line.strip()[:120] for line in text.splitlines()
               if re.search(rf"= ({'|'.join(sides)})\S* copy(-start)?\(", line)]
     assert not copied, copied
+
+
+@pytest.mark.parametrize("tables", [4, 8, 64, 128])
+def test_latent_decode_kernel_compiles_at_the_cells_shapes(one_chip, tables):
+    """``longcat-flash-l4e16``'s decode attention (128 rows, 64 heads
+    over a 512-wide latent and a rotated key in a 128-lane row, 64-token
+    pages, 8 page layers) at the table buckets an 8,192-token model has:
+    the v5e compiler takes the kernel, and the pool's one-row sides reach
+    it as the same bytes in four dims (a bitcast: no copy of a side, no
+    temporary at all)."""
+    from production_stack_tpu.ops.pallas_mla_decode import (
+        decode_tile,
+        pallas_mla_decode,
+    )
+
+    B, H, C, lanes = 128, 64, 512, 128
+
+    def spec(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    assert decode_tile(BLOCK_SIZE, H, C, lanes, 2, tables) == (
+        min(tables, 8), 6)
+    program = jax.jit(
+        lambda qa, qr, c, r, bt, cl, layer: pallas_mla_decode(
+            qa, qr, c, r, bt, cl, layer, scale=192 ** -0.5)).lower(
+        spec((B, H, C), jnp.bfloat16), spec((B, H, 64), jnp.bfloat16),
+        spec((8, NUM_BLOCKS, BLOCK_SIZE, 1, C), jnp.bfloat16),
+        spec((8, NUM_BLOCKS, BLOCK_SIZE, 1, lanes), jnp.bfloat16),
+        spec((B, tables)), spec((B,)), spec(())).compile()
+    text = program.as_text()
+    assert "tpu_custom_call" in text and "pallas_mla_decode" in text
+    copied = [line.strip()[:120] for line in text.splitlines()
+              if re.search(rf"= bf16\[8,{NUM_BLOCKS},{BLOCK_SIZE},\S* "
+                           r"copy(-start)?\(", line)]
+    assert not copied, copied
+    assert program.memory_analysis().temp_size_in_bytes == 0
+
+
+@pytest.mark.parametrize("mode,rows,width,tables", [
+    ("decode", 128, 1, 64), ("prefill", 1, 1024, 16),
+    ("prefill_cached", 1, 1024, 32), ("prefill_cached", 1, 1024, 128)])
+def test_longcat_programs_compile_at_the_configurations_widths(
+        one_chip, monkeypatch, tmp_path, mode, rows, width, tables):
+    """``longcat-flash-l4e16`` as the benchmark serves it (the model keys
+    of its file, written to a ``config.json`` as ``chipbench.stack``
+    does): the three forward programs with the expert layer's counts
+    compile for the v5e; decode holds the latent kernel (traced once:
+    the scan's body is one sublayer) and every mode the grouped-matmul
+    kernel (no ``ragged_dot`` left); the weights are the 10.35 GB the
+    configuration states; no expert stack and no side of the pool is
+    copied; and the temporaries stay under what the pool leaves free
+    (decode 0.08 GB: ``wq_b`` is stored [out, in] and ``wkv_b`` per head,
+    the layouts the compiler otherwise makes of the whole stacks in
+    every program, 0.44 GB; plain prefill of a whole chunk 0.81 GB, of
+    which 0.13 are ``wkv_b`` with the latent's lanes last for the
+    up-projection; cached prefill under a 128-block table 0.94 GB:
+    float32 scores of 64 heads)."""
+    import json
+    import os
+    import sys
+
+    from production_stack_tpu.models import longcat
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if repo not in sys.path:
+        sys.path.insert(0, repo)
+    from chipbench.registry import model_keys
+
+    with open(os.path.join(repo, "chipbench", "configs",
+                           "longcat-flash-l4e16.json")) as f:
+        (tmp_path / "config.json").write_text(
+            json.dumps(model_keys(json.load(f))))
+    cfg = get_model_config(str(tmp_path))
+    monkeypatch.setattr(att, "_use_pallas", lambda: True)
+    monkeypatch.setattr(gmm, "_platform", lambda: "tpu")
+    att.TRACED_PATHS.clear()
+
+    def spec(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda x: spec(x.shape, x.dtype),
+        jax.eval_shape(lambda: longcat.init_params(cfg, jax.random.key(0))))
+    weights = sum(int(np.prod(x.shape)) * x.dtype.itemsize
+                  for x in jax.tree_util.tree_leaves(params))
+    assert abs(weights / 10.35e9 - 1) < 0.01
+    blocks = 4096  # a pool of 2.7 GB
+    pages = (spec((8, blocks, BLOCK_SIZE, 1, 512), jnp.bfloat16),
+             spec((8, blocks, BLOCK_SIZE, 1, 128), jnp.bfloat16))
+    last = mode != "decode"
+    program = jax.jit(
+        lambda p, kv, tok, pos, slot, bt, cl, sl: longcat.apply(
+            p, cfg, tok, pos, kv, slot, bt, cl, sl, mode=mode,
+            last_token=jnp.maximum(sl - 1, 0) if last else None,
+            with_stats=True), donate_argnums=(1,)).lower(
+        params, pages, spec((rows, width)), spec((rows, width)),
+        spec((rows, width)), spec((rows, tables)), spec((rows,)),
+        spec((rows,))).compile()
+    text = program.as_text()
+    assert ("pallas_mla_decode" in text) == (mode == "decode")
+    assert att.TRACED_PATHS["latent_decode", "pallas"] == (
+        mode == "decode")
+    assert att.TRACED_PATHS["grouped_matmul", "pallas"] >= 1
+    assert "pallas_grouped_matmul" in text and "ragged-dot" not in text
+    copied = [line.strip()[:120] for line in text.splitlines()
+              if re.search(rf"= bf16\[(4,16,\d{{4}},\d{{4}}|8,{blocks},"
+                           rf"{BLOCK_SIZE},1,\d+)\]\S* copy(-start)?\(",
+                           line)]
+    assert not copied, copied
+    assert program.memory_analysis().temp_size_in_bytes < (
+        0.2e9 if mode == "decode" else 1.1e9)
