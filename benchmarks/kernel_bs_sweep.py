@@ -4,7 +4,8 @@ DMAs per kernel invocation. With ``--cells``: the decode kernel alone, at
 the tile it chooses itself, on the contexts the benchmark's cells hand it
 (:data:`CELL_CASES`; ``--cells --narrow``: at eight kv heads of 64 in the
 pool's packed rows; the numbers of the kernel's docstring and of PERF.md
-section 6, PR 32).
+section 6, PR 32; ``--cells --latent``: the absorbed latent kernel at
+``longcat-backlog-long``'s shapes, :func:`time_latent`, PR 42).
 
 Timing methodology (benchmarks/timing.py): every timed sequence ends in
 a real ``device_get`` readback, and per-iteration cost is recovered by
@@ -68,6 +69,24 @@ def cell_tables(contexts, width: int, bs: int, nb: int, rng) -> np.ndarray:
     return tables
 
 
+def scan_of(call, layers: int, reps: int):
+    """``run(*args)``, jitted: one ``lax.scan`` of ``layers x reps`` calls
+    of ``call(*args, layer)`` (the layer is the scanned value, the output
+    is summed into the carry); ``call`` None: the same scan without the
+    kernel, whose time is taken off."""
+    @jax.jit
+    def run(*args):
+        def body(acc, l):
+            o = (call(*args, l % layers) if call
+                 else args[0] * (l % layers).astype(args[0].dtype))
+            return acc + o.astype(jnp.float32), None
+        out, _ = jax.lax.scan(
+            body, jnp.zeros(args[0].shape, jnp.float32),
+            jnp.arange(layers * reps))
+        return out
+    return run
+
+
 def time_cells(narrow: bool = False):
     """One JSON line a case: us a call (one layer) of the kernel alone,
     the same scan without it taken off, beside the time its live tokens'
@@ -90,27 +109,16 @@ def time_cells(narrow: bool = False):
     reps = 8 * CELL_L // L  # calls of each layer in one timed scan
     kernel = att.paged_decode_attention if narrow else pallas_paged_attention
 
-    def scan_of(with_kernel: bool):
-        @jax.jit
-        def run(q, k_pages, v_pages, bt, cl):
-            def body(acc, l):
-                o = (kernel(
-                    q, k_pages, v_pages, bt, cl, l % L, scale=D ** -0.5)
-                    if with_kernel else q * (l % L).astype(q.dtype))
-                return acc + o.astype(jnp.float32), None
-            out, _ = jax.lax.scan(
-                body, jnp.zeros(q.shape, jnp.float32),
-                jnp.arange(L * reps))
-            return out
-        return run
+    def call(q, k_pages, v_pages, bt, cl, layer):
+        return kernel(q, k_pages, v_pages, bt, cl, layer, scale=D ** -0.5)
 
     for name, (contexts, width) in CELL_CASES.items():
         q = jax.random.normal(k3, (len(contexts), H, D), jnp.bfloat16)
         bt = jnp.asarray(cell_tables(contexts, width, bs, NB, rng))
         cl = jnp.asarray(contexts, jnp.int32)
         args = (q, k_pages, v_pages, bt, cl)
-        per_scan = (timed_per_call(scan_of(True), *args)
-                    - timed_per_call(scan_of(False), *args))
+        per_scan = (timed_per_call(scan_of(call, L, reps), *args)
+                    - timed_per_call(scan_of(None, L, reps), *args))
         live = sum(contexts)
         print(json.dumps({
             "case": name, "rows": len(contexts), "table_pages": width,
@@ -119,6 +127,85 @@ def time_cells(narrow: bool = False):
             "us_per_call": round(per_scan / (L * reps) * 1e6, 1),
             "floor_us": round(live * 2 * KVH * D * 2 / 819e9 * 1e6, 1),
         }), flush=True)
+
+
+# ``longcat-backlog-long``: 64 heads over a 512-wide latent and a rotated
+# key in a 128-lane row, 64-token pages, the 8-sublayer pool of 6,988
+# blocks, 128 rows.
+LATENT_L, LATENT_NB, LATENT_BS, LATENT_H = 8, 6988, 64, 64
+LATENT_C, LATENT_LANES, LATENT_ROPE, LATENT_ROWS = 512, 128, 64, 128
+LATENT_TOKEN_BYTES = (LATENT_C + LATENT_ROPE) * 2  # what the reader counts
+
+
+def latent_contexts(rows: int = LATENT_ROWS, mean: int = 2304) -> list:
+    """The cell's live contexts, fixed: a prompt (lognormal, median 1,536,
+    sigma 0.5, 512-6,144) and a uniform part of its answer (median 512,
+    sigma 0.6, 128-2,048), as ``backlog-long.json`` draws them, scaled to
+    the mean the cell's decode forwards read (~2.3k: rows with long
+    answers stay longest)."""
+    rng = np.random.default_rng(42)
+    prompt = np.clip(rng.lognormal(np.log(1536), 0.5, rows), 512, 6144)
+    answer = np.clip(rng.lognormal(np.log(512), 0.6, rows), 128, 2048)
+    ctx = prompt + rng.uniform(0, 1, rows) * answer
+    return [int(c) for c in np.minimum(ctx * mean / ctx.mean(), 8192)]
+
+
+def time_latent(tile=(0, 0)):
+    """One JSON line a case: us a call (one attention sublayer of one
+    decode forward, 128 rows) of ``pallas_mla_decode`` alone, the same
+    scan without it taken off, beside the reader's floor (live tokens x
+    1,152 B at 819 GB/s: ``chipbench/readers/mla_decode_roofline.py``)
+    and the share of it; ``max_err`` is the largest distance from the
+    XLA path on the device. ``tile``: (pages a chunk, ring), 0 = the
+    kernel's own choice."""
+    from production_stack_tpu.ops.attention import latent_decode_reference
+    from production_stack_tpu.ops.pallas_mla_decode import pallas_mla_decode
+
+    L, NB, bs, H = LATENT_L, LATENT_NB, LATENT_BS, LATENT_H
+    rng = np.random.default_rng(42)
+    k1, k2, k3, k4 = jax.random.split(jax.random.key(0), 4)
+    c_pages = jax.random.normal(k1, (L, NB, bs, 1, LATENT_C), jnp.bfloat16)
+    r_pages = jnp.pad(
+        jax.random.normal(k2, (L, NB, bs, 1, LATENT_ROPE), jnp.bfloat16),
+        ((0, 0),) * 4 + ((0, LATENT_LANES - LATENT_ROPE),))
+    q_abs = jax.random.normal(k3, (LATENT_ROWS, H, LATENT_C), jnp.bfloat16)
+    q_rope = jax.random.normal(k4, (LATENT_ROWS, H, LATENT_ROPE),
+                               jnp.bfloat16)
+    scale = 192 ** -0.5 / 8  # scores of a few units, as a trained model's
+    reps = 16  # calls of each page layer in one timed scan
+
+    def kernel(q_abs, q_rope, c_pages, r_pages, bt, cl, layer):
+        return pallas_mla_decode(
+            q_abs, q_rope, c_pages, r_pages, bt, cl, layer, scale=scale,
+            pages_per_block=tile[0], ring=tile[1])
+
+    distance = jax.jit(lambda *args: jnp.max(jnp.abs(
+        kernel(*args).astype(jnp.float32)
+        - latent_decode_reference(*args, scale=scale).astype(jnp.float32))))
+    lognormal = latent_contexts()
+    cases = {"cell_lognormal": lognormal,
+             "all_full_128x2304": [2304] * LATENT_ROWS,
+             "fixed_cost_128x1": [1] * LATENT_ROWS}
+    for name, contexts in cases.items():
+        for width in (64, 128):
+            contexts_w = [min(c, width * bs) for c in contexts]
+            bt = jnp.asarray(cell_tables(contexts_w, width, bs, NB, rng))
+            cl = jnp.asarray(contexts_w, jnp.int32)
+            args = (q_abs, q_rope, c_pages, r_pages, bt, cl)
+            err = float(distance(*args, L - 1))
+            per_scan = (timed_per_call(scan_of(kernel, L, reps), *args)
+                        - timed_per_call(scan_of(None, L, reps), *args))
+            live = sum(contexts_w)
+            us = per_scan / (L * reps) * 1e6
+            floor = live * LATENT_TOKEN_BYTES / 819e9 * 1e6
+            print(json.dumps({
+                "case": f"{name}_w{width}", "rows": len(contexts_w),
+                "table_pages": width, "tile": list(tile),
+                "live_tokens": live, "us_per_call": round(us, 1),
+                "floor_us": round(floor, 1),
+                "share_pct": round(100 * floor / us, 1),
+                "max_err": round(err, 5),
+            }), flush=True)
 
 
 def main():
@@ -177,7 +264,12 @@ def main():
 
 
 if __name__ == "__main__":
-    if "--cells" in sys.argv[1:]:
+    if "--latent" in sys.argv[1:]:
+        tiles = [a.split("=")[1] for a in sys.argv[1:]
+                 if a.startswith("--tile=")]  # --tile=16:4, pages:ring
+        time_latent(tuple(int(x) for x in tiles[0].split(":"))
+                    if tiles else (0, 0))
+    elif "--cells" in sys.argv[1:]:
         time_cells(narrow="--narrow" in sys.argv[1:])
     else:
         main()

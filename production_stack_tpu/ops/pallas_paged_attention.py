@@ -100,21 +100,21 @@ def ring_bytes(ring: int, pages: int, block_size: int, kvh: int,
 
 
 def choose_tile(fits, tables_width: int, block_size: int,
-                ring_floor: int = 3):
+                ring_floor: int = 3, max_pages: int = MAX_PAGES_PER_BLOCK,
+                max_ring: int = RING):
     """(pages_per_block, ring) for a paged kernel: the widest chunk and
-    then the deepest ring that ``fits(pages, ring)``. A chunk spans
-    whole 128-lane tiles of tokens (the scores' and the int8 scale
-    rows' last dim) and no more pages than the table, rounded up to a
-    power of two. Rings shallower than ``ring_floor`` are tried only
-    after every chunk width failed at the floor. Returns None when
-    nothing fits."""
+    then the deepest ring that ``fits(pages, ring)``; None when nothing
+    does. A chunk spans whole 128-lane tiles of tokens (the scores' and
+    the int8 scale rows' last dim) and no more pages than ``max_pages``
+    (16 at most) or the table, rounded up to a power of two. Rings under
+    ``ring_floor`` are tried only after every width failed at the floor."""
     cap = 1
-    while cap < min(tables_width, MAX_PAGES_PER_BLOCK):
+    while cap < min(tables_width, max_pages):
         cap *= 2
-    widths = [p for p in (8, 4, 2, 1)
+    widths = [p for p in (16, 8, 4, 2, 1)
               if p <= cap and (p * block_size) % 128 == 0]
     widths = widths or [-(-128 // block_size)]
-    for rings in (range(RING, ring_floor - 1, -1),
+    for rings in (range(max_ring, ring_floor - 1, -1),
                   range(ring_floor - 1, 1, -1)):
         for p in widths:
             for r in rings:

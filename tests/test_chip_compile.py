@@ -467,13 +467,15 @@ def test_lfm2_programs_compile_at_the_configurations_widths(
     assert not copied, copied
 
 
-@pytest.mark.parametrize("tables", [4, 8, 64, 128])
+@pytest.mark.parametrize("tables", [4, 8, 16, 32, 64, 128])
 def test_latent_decode_kernel_compiles_at_the_cells_shapes(one_chip, tables):
     """``longcat-flash-l4e16``'s decode attention (128 rows, 64 heads
     over a 512-wide latent and a rotated key in a 128-lane row, 64-token
-    pages, 8 page layers) at the table buckets an 8,192-token model has:
-    the v5e compiler takes the kernel, and the pool's one-row sides reach
-    it as the same bytes in four dims (a bitcast: no copy of a side, no
+    pages, 8 page layers) at each table width the cell's six decode
+    programs have: the v5e compiler takes the kernel's body (a full
+    chunk as two spans, a row's last chunk over its live sub-blocks) at
+    the tile the shapes choose, and the pool's one-row sides reach it as
+    the same bytes in four dims (a bitcast: no copy of a side, no
     temporary at all)."""
     from production_stack_tpu.ops.pallas_mla_decode import (
         decode_tile,
@@ -486,7 +488,7 @@ def test_latent_decode_kernel_compiles_at_the_cells_shapes(one_chip, tables):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     assert decode_tile(BLOCK_SIZE, H, C, lanes, 2, tables) == (
-        min(tables, 8), 6)
+        min(tables, 16), 4)
     program = jax.jit(
         lambda qa, qr, c, r, bt, cl, layer: pallas_mla_decode(
             qa, qr, c, r, bt, cl, layer, scale=192 ** -0.5)).lower(

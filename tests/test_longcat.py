@@ -396,24 +396,53 @@ def test_a_decode_row_that_holds_nothing_writes_and_attends_nothing():
 # The Pallas kernel (interpret mode) against the XLA path
 # --------------------------------------------------------------------- #
 
+# name: (block size, pages a chunk, ring, the rows' contexts). A chunk of
+# ``pages x block size`` tokens is computed in sub-blocks of 128 where it
+# holds several (the last three: the cell's 64-token pages), a full one
+# as two spans of them and a row's last over those that hold a live
+# token; 0 and -1 are rows that hold nothing, between live ones so that
+# the walk of the copies crosses rows inside a trip.
+KERNEL_CASES = {
+    "own_tile": (16, 0, 0, [37, 0, 96, 1, -1]),
+    "ring_wraps_across_rows": (16, 2, 3, [37, 0, 96, 1, -1]),
+    "one_page_chunks_nothing_ahead": (16, 1, 2, [37, 0, 96, 1, -1]),
+    "exactly_k_chunks_odd_and_even": (
+        16, 4, 3, [64, 0, 128, -1, 192, 0, 256, 320]),
+    "k_chunks_and_one_token": (16, 4, 3, [65, 0, 129, -1, 193, 0, 257]),
+    "k_chunks_less_one_token": (16, 4, 4, [63, 0, 127, -1, 191, 0, 255, 319]),
+    "a_ring_of_two": (16, 4, 2, [320, 0, 1, 129, -1, 256]),
+    "tail_in_the_first_and_the_last_sub_block": (
+        64, 8, 3, [517, 0, 1012, 1, -1, 5, 500, 1541]),
+    "tail_at_a_sub_block_edge": (
+        64, 8, 6, [640, 0, 641, 639, -1, 128, 129, 1024 + 384]),
+    "two_sub_blocks_a_chunk": (64, 4, 4, [255, 0, 256, 257, -1, 130, 771]),
+}
+
+
 @pytest.mark.parametrize("dtype, tol", [(jnp.float32, 2e-5),
                                         (jnp.bfloat16, 3e-2)])
-@pytest.mark.parametrize("pages_per_block, ring", [(0, 0), (2, 3), (1, 2)])
-def test_the_kernel_is_the_xla_path(dtype, tol, pages_per_block, ring):
-    """Ragged contexts (a page's tail, one token, several chunks), a row
-    of context 0 and one of -1 (no slot): nothing copied or computed for
-    those, zeros out; chunks narrower than the table, and a ring that
-    wraps across rows."""
+@pytest.mark.parametrize("case", KERNEL_CASES)
+def test_the_kernel_is_the_xla_path(dtype, tol, case):
+    """Every edge the kernel's loop has: contexts of exactly k chunks,
+    one token more and one less, odd and even counts, a last chunk whose
+    tail lies in its first or its last sub-block or on an edge (one,
+    two, three sub-blocks live: one span, two even ones, two uneven
+    ones), one token, rows that hold nothing (context 0, and -1: no
+    slot) between live ones: nothing copied or computed for those, zeros
+    out; chunks narrower than the table, and rings that wrap across
+    rows."""
+    bs, pages_per_block, ring, contexts = KERNEL_CASES[case]
     rng = np.random.default_rng(0)
-    L, NB, bs, C, lanes, R, H, B, MAXB = 3, 40, 16, 128, 128, 16, 8, 5, 6
+    L, C, lanes, R, H, B = 3, 128, 128, 16, 8, len(contexts)
+    MAXB = -(-max(contexts) // bs) + 1
+    NB = B * MAXB
     c = jnp.asarray(rng.normal(size=(L, NB, bs, 1, C)), dtype)
     r = jnp.zeros((L, NB, bs, 1, lanes), dtype).at[..., :R].set(
         jnp.asarray(rng.normal(size=(L, NB, bs, 1, R)), dtype))
     q_abs = jnp.asarray(rng.normal(size=(B, H, C)), dtype)
     q_rope = jnp.asarray(rng.normal(size=(B, H, R)), dtype)
-    tables = jnp.asarray(rng.permutation(NB)[:B * MAXB].reshape(B, MAXB),
-                         jnp.int32)
-    ctx = jnp.asarray([37, 0, 96, 1, -1], jnp.int32)
+    tables = jnp.asarray(rng.permutation(NB).reshape(B, MAXB), jnp.int32)
+    ctx = jnp.asarray(contexts, jnp.int32)
     got = pallas_mla_decode(q_abs, q_rope, c, r, tables, ctx, 1, scale=0.2,
                             pages_per_block=pages_per_block, ring=ring,
                             interpret=True)
@@ -422,8 +451,8 @@ def test_the_kernel_is_the_xla_path(dtype, tol, pages_per_block, ring):
     assert got.shape == (B, H, C) and got.dtype == dtype
     np.testing.assert_allclose(got.astype(jnp.float32),
                                want.astype(jnp.float32), atol=tol)
-    assert not np.asarray(got[1], np.float32).any()
-    assert not np.asarray(got[4], np.float32).any()
+    for row, n in enumerate(contexts):
+        assert (n > 0) == bool(np.asarray(got[row], np.float32).any())
 
 
 def test_stale_nans_past_the_context_do_not_reach_the_output():
@@ -445,8 +474,8 @@ def test_the_path_is_chosen_from_shapes_and_counted(monkeypatch):
     assert tiles_ok(64, 64, 512, 128, 2) and tiles_ok(16, 8, 128, 128, 4)
     assert not tiles_ok(8, 64, 512, 128, 2)  # half a bf16 tile of tokens
     assert not tiles_ok(64, 64, 512, 64, 2)  # a side off the lanes
-    assert decode_tile(64, 64, 512, 128, 2, 128) == (8, 6)
-    assert decode_tile(64, 64, 512, 128, 2, 4) == (4, 6)
+    assert decode_tile(64, 64, 512, 128, 2, 128) == (16, 4)
+    assert decode_tile(64, 64, 512, 128, 2, 4) == (4, 4)
     assert att.latent_decode_path(64, 64, 512, 128, "bfloat16") == "xla"
     monkeypatch.setattr(att, "_use_pallas", lambda: True)
     assert att.latent_decode_path(64, 64, 512, 128, "bfloat16") == "pallas"
