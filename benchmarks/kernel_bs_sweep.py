@@ -150,27 +150,34 @@ def latent_contexts(rows: int = LATENT_ROWS, mean: int = 2304) -> list:
     return [int(c) for c in np.minimum(ctx * mean / ctx.mean(), 8192)]
 
 
-def time_latent(tile=(0, 0)):
+# ``glm47flash-agent-sessions`` (``--cases=agent --heads=20``): 20 heads
+# over the same page, up to 32 rows of 4-7k tokens (a 4,096-token system
+# prompt and a history to 7,168).
+AGENT_ROWS, AGENT_CONTEXTS = 32, (4096, 7168)
+
+
+def time_latent(tile=(0, 0), heads=LATENT_H, cases="backlog"):
     """One JSON line a case: us a call (one attention sublayer of one
     decode forward, 128 rows) of ``pallas_mla_decode`` alone, the same
     scan without it taken off, beside the reader's floor (live tokens x
     1,152 B at 819 GB/s: ``chipbench/readers/mla_decode_roofline.py``)
     and the share of it; ``max_err`` is the largest distance from the
     XLA path on the device. ``tile``: (pages a chunk, ring), 0 = the
-    kernel's own choice."""
+    kernel's own choice. ``cases``: ``"backlog"``, the rows and
+    contexts of ``longcat-backlog-long``, or ``"agent"``, those of
+    ``glm47flash-agent-sessions`` (32, 16 and 8 rows spread evenly over
+    4,096-7,168 tokens, and 32 one-token rows); ``heads`` is the
+    kernel's, whatever the cases."""
     from production_stack_tpu.ops.attention import latent_decode_reference
     from production_stack_tpu.ops.pallas_mla_decode import pallas_mla_decode
 
-    L, NB, bs, H = LATENT_L, LATENT_NB, LATENT_BS, LATENT_H
+    L, NB, bs, H = LATENT_L, LATENT_NB, LATENT_BS, heads
     rng = np.random.default_rng(42)
     k1, k2, k3, k4 = jax.random.split(jax.random.key(0), 4)
     c_pages = jax.random.normal(k1, (L, NB, bs, 1, LATENT_C), jnp.bfloat16)
     r_pages = jnp.pad(
         jax.random.normal(k2, (L, NB, bs, 1, LATENT_ROPE), jnp.bfloat16),
         ((0, 0),) * 4 + ((0, LATENT_LANES - LATENT_ROPE),))
-    q_abs = jax.random.normal(k3, (LATENT_ROWS, H, LATENT_C), jnp.bfloat16)
-    q_rope = jax.random.normal(k4, (LATENT_ROWS, H, LATENT_ROPE),
-                               jnp.bfloat16)
     scale = 192 ** -0.5 / 8  # scores of a few units, as a trained model's
     reps = 16  # calls of each page layer in one timed scan
 
@@ -182,12 +189,24 @@ def time_latent(tile=(0, 0)):
     distance = jax.jit(lambda *args: jnp.max(jnp.abs(
         kernel(*args).astype(jnp.float32)
         - latent_decode_reference(*args, scale=scale).astype(jnp.float32))))
-    lognormal = latent_contexts()
-    cases = {"cell_lognormal": lognormal,
-             "all_full_128x2304": [2304] * LATENT_ROWS,
-             "fixed_cost_128x1": [1] * LATENT_ROWS}
+    if cases == "backlog":
+        cases = {"cell_lognormal": latent_contexts(),
+                 "all_full_128x2304": [2304] * LATENT_ROWS,
+                 "fixed_cost_128x1": [1] * LATENT_ROWS}
+        widths = (64, 128)
+    else:
+        low, high = AGENT_CONTEXTS
+        cases = {f"agent_{rows}_rows": [
+            low + (high - low) * i // rows for i in range(rows)]
+            for rows in (AGENT_ROWS, 16, 8)}
+        cases[f"fixed_cost_{AGENT_ROWS}x1"] = [1] * AGENT_ROWS
+        widths = (128,)
     for name, contexts in cases.items():
-        for width in (64, 128):
+        q_abs = jax.random.normal(k3, (len(contexts), H, LATENT_C),
+                                  jnp.bfloat16)
+        q_rope = jax.random.normal(k4, (len(contexts), H, LATENT_ROPE),
+                                   jnp.bfloat16)
+        for width in widths:
             contexts_w = [min(c, width * bs) for c in contexts]
             bt = jnp.asarray(cell_tables(contexts_w, width, bs, NB, rng))
             cl = jnp.asarray(contexts_w, jnp.int32)
@@ -200,7 +219,7 @@ def time_latent(tile=(0, 0)):
             floor = live * LATENT_TOKEN_BYTES / 819e9 * 1e6
             print(json.dumps({
                 "case": f"{name}_w{width}", "rows": len(contexts_w),
-                "table_pages": width, "tile": list(tile),
+                "table_pages": width, "tile": list(tile), "heads": H,
                 "live_tokens": live, "us_per_call": round(us, 1),
                 "floor_us": round(floor, 1),
                 "share_pct": round(100 * floor / us, 1),
@@ -265,10 +284,11 @@ def main():
 
 if __name__ == "__main__":
     if "--latent" in sys.argv[1:]:
-        tiles = [a.split("=")[1] for a in sys.argv[1:]
-                 if a.startswith("--tile=")]  # --tile=16:4, pages:ring
-        time_latent(tuple(int(x) for x in tiles[0].split(":"))
-                    if tiles else (0, 0))
+        given = dict(a[2:].split("=") for a in sys.argv[1:] if "=" in a)
+        time_latent(  # --tile=16:4 (pages:ring) --heads=20 --cases=agent
+            tuple(int(x) for x in given.get("tile", "0:0").split(":")),
+            int(given.get("heads", LATENT_H)),
+            given.get("cases", "backlog"))
     elif "--cells" in sys.argv[1:]:
         time_cells(narrow="--narrow" in sys.argv[1:])
     else:

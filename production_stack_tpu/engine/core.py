@@ -182,6 +182,28 @@ def _kv_set(pages, bid, new):
     return pages.at[:, bid].set(new.astype(pages.dtype))
 
 
+@jax.jit
+def _gather_blocks_flat(x, idx):
+    """``x[:, idx]`` of a side ``[L, NB, ...]`` (or of each leaf of one)
+    for block ids ``idx [n]``, as ONE program through the flat
+    ``[L * NB, ...]`` view, as the step programs read pages (inside one
+    program a reshape of leading dims is no copy; on its own it is one,
+    of the whole side). For ``extract_kv``, which reads a prompt's blocks
+    beside a full pool: the TPU compiler gives ``x[:, idx]`` on a
+    47-layer latent side a copy of the whole side as a temporary (3.5 GB:
+    the check ran out of memory on the chip, PERF.md section 6, PR 44),
+    and this gather none at any of the pools' shapes
+    (tests/test_chip_compile.py)."""
+    def leaf(e):
+        L, NB = e.shape[:2]
+        rows = (jnp.arange(L, dtype=jnp.int32)[:, None] * NB
+                + idx.astype(jnp.int32)[None, :]).reshape(-1)
+        return e.reshape((L * NB,) + e.shape[2:])[rows].reshape(
+            (L, idx.shape[0]) + e.shape[2:])
+
+    return jax.tree.map(leaf, x)
+
+
 def _kv_leaf_index(x, idx):
     """``x[:, idx]`` over a leaf (the block axis is axis 1 for both the
     pages and the scale layouts)."""
@@ -649,6 +671,12 @@ class EngineCore:
         # (ops/pallas_mla_decode.py) or "xla" (gather and einsum);
         # exported as tpu:latent_decode_dispatch_total{path=...}.
         self.latent_decode_dispatch_total = {"pallas": 0, "xla": 0}
+        # Cached-prefill programs of such a model, by the form their
+        # attention over the gathered latents takes at the program's
+        # shapes (models/decoder.py::latent_prefill_form); exported as
+        # tpu:latent_prefill_form_total{form=...}, and on the step's
+        # record as latent_prefill_<form>.
+        self.latent_prefill_form_total = {"absorbed": 0, "up_projected": 0}
         # While set, _dispatch diverts prefill/decode ops into this list
         # (each entry (name, static, arrays, placeholder)) instead of
         # executing them; _do_fused then issues them as one "fused" op.
@@ -1489,6 +1517,9 @@ class EngineCore:
             self._count_expert_matmul_path(arrays[0].size)
             if self.block_state_shape:
                 self._note_block_state(arrays[1][:, 0], arrays[5])
+            if static["cached"] and self.own_page_sides is not None:
+                self._count_latent_prefill_form(arrays[0].shape[1],
+                                                arrays[3].shape[1])
             out, self.kv = fn(self.params, self.kv, *arrays)
             return out
         if name == "decode":
@@ -1803,11 +1834,12 @@ class EngineCore:
                 # [L, N, bs, KVH, D] -> [N, L, bs, KVH, D] (per-block
                 # payloads)
                 k = _kv_leaf_swap01(
-                    _kv_leaf_get(_kv_leaf_index(k_pages, idx)))
+                    _kv_leaf_get(_gather_blocks_flat(k_pages, idx)))
                 v = _kv_leaf_swap01(
-                    _kv_leaf_get(_kv_leaf_index(v_pages, idx)))
-                state = [np.asarray(jax.device_get(side[:, idx])
-                                    ).swapaxes(0, 1) for side in state]
+                    _kv_leaf_get(_gather_blocks_flat(v_pages, idx)))
+                state = [np.asarray(jax.device_get(
+                    _gather_blocks_flat(side, idx))).swapaxes(0, 1)
+                    for side in state]
         return {
             "hashes": hashes,
             "num_tokens": len(hashes) * bs,
@@ -1882,9 +1914,9 @@ class EngineCore:
             idx = jnp.asarray(bids)
             # Dispatched under _step_lock so the gather reads self.kv
             # before any later engine step donates the buffer.
-            k = self._one_head_a_row(_kv_leaf_index(k_pages, idx), 0)
-            v = self._one_head_a_row(_kv_leaf_index(v_pages, idx), 1)
-            state = [side[:, idx] for side in state]
+            k = self._one_head_a_row(_gather_blocks_flat(k_pages, idx), 0)
+            v = self._one_head_a_row(_gather_blocks_flat(v_pages, idx), 1)
+            state = [_gather_blocks_flat(side, idx) for side in state]
         return {
             "hashes": hashes,
             "num_tokens": len(hashes) * bs,
@@ -2689,6 +2721,8 @@ class EngineCore:
                 dict(self.expert_matmul_dispatch_total),
             "latent_decode_dispatch_total":
                 dict(self.latent_decode_dispatch_total),
+            "latent_prefill_form_total":
+                dict(self.latent_prefill_form_total),
             "dispatch_count_total": phases["enqueue"]["count"],
             "dispatch_enqueue_s": round(phases["enqueue"]["seconds"], 3),
             "decode_forward_steps_total": self.decode_forward_steps_total,
@@ -3162,6 +3196,22 @@ class EngineCore:
         mc = self.model_config
         return latent_decode_path(self.config.block_size, mc.num_heads,
                                   latent, lanes, mc.dtype)
+
+    def _count_latent_prefill_form(self, bucket: int, table: int) -> None:
+        """A cached-prefill program of a model with a latent cache is
+        dispatched at ``[rows, bucket]`` tokens under a table of
+        ``table`` blocks: count the form its attention takes (the
+        trace-time choice of models/decoder.py::attend_latent, evaluated
+        again from the same shapes)."""
+        from production_stack_tpu.models.decoder import latent_prefill_form
+
+        mc = self.model_config
+        form = latent_prefill_form(
+            bucket, table * self.config.block_size, mc.num_heads,
+            mc.kv_lora_rank, mc.qk_nope_head_dim, mc.qk_rope_head_dim,
+            mc.v_head_dim)
+        self.latent_prefill_form_total[form] += 1
+        self._steps.note_sum(**{f"latent_prefill_{form}": 1})
 
     def _do_fused(self, plan) -> None:
         """Execute one scheduler "fused" action: the budgeted prefill

@@ -2663,6 +2663,13 @@ class EngineServer:
             f"{s.get('latent_decode_dispatch_total', {}).get('pallas', 0)}",
             f'tpu:latent_decode_dispatch_total{{{labels},path="xla"}} '
             f"{s.get('latent_decode_dispatch_total', {}).get('xla', 0)}",
+            # Cached-prefill programs of such a model, by the form their
+            # attention over the gathered latents takes (a rule of the
+            # program's shapes; 0 for any other model).
+            "# TYPE tpu:latent_prefill_form counter",
+            *(f'tpu:latent_prefill_form_total{{{labels},form="{form}"}} '
+              f"{s.get('latent_prefill_form_total', {}).get(form, 0)}"
+              for form in ("absorbed", "up_projected")),
             # Structured output (guided_json / guided_regex /
             # response_format): grammar constraints compiled to token FSMs
             # applied inside the fused programs.

@@ -81,11 +81,12 @@ class ModelConfig:
     # Gated short convolution (lfm2's ``conv`` layers): the kernel's
     # length; a layer's state is its last ``conv_kernel - 1`` inputs.
     conv_kernel: int = 0
-    # Latent attention (longcat's MLA): the ranks of the query's and the
-    # cache's low-rank projections, a head's widths without and with
-    # rotary embedding and its value's, and whether each latent is
-    # multiplied by sqrt(hidden_size / its rank) after its norm. A token's
-    # page keeps the ``kv_lora_rank`` latent and the one rotated key.
+    # Latent attention (MLA: longcat, glm4_moe_lite): the ranks of the
+    # query's and the cache's low-rank projections, a head's widths
+    # without and with rotary embedding and its value's, and whether each
+    # latent is multiplied by sqrt(hidden_size / its rank) after its norm.
+    # A token's page keeps the ``kv_lora_rank`` latent and the one rotated
+    # key.
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
@@ -192,6 +193,21 @@ _PRESETS = {
         layer_share=1, router_bias=True, q_lora_rank=64, kv_lora_rank=128,
         qk_nope_head_dim=32, qk_rope_head_dim=16, v_head_dim=32,
         mla_scale_q_lora=True, mla_scale_kv_lora=True, zero_experts=4,
+    ),
+    # Four layers of one latent-attention sublayer each, five heads (not a
+    # multiple of the kernels' eight) whose values are wider than their
+    # keys' nope part, a dense first layer, then the first of two shares
+    # of 8 sigmoid-routed experts with a selection bias beside a shared one.
+    "tiny-glm4-moe-lite": ModelConfig(
+        name="tiny-glm4-moe-lite", arch="glm4_moe_lite", vocab_size=512,
+        hidden_size=128, num_layers=4, num_heads=5, num_kv_heads=1,
+        head_dim=40, intermediate_size=256, max_position=2048,
+        rope_theta=1000000.0, num_experts=4, experts_per_token=3,
+        moe_intermediate_size=64, shared_expert_size=64, routed_scaling=1.8,
+        dense_layers=1, chips_per_layer=2, layer_share=0,
+        router_scoring="sigmoid", router_bias=True, q_lora_rank=48,
+        kv_lora_rank=128, qk_nope_head_dim=24, qk_rope_head_dim=16,
+        v_head_dim=32,
     ),
     "tiny-opt": ModelConfig(
         name="tiny-opt", arch="opt", vocab_size=512, hidden_size=128,

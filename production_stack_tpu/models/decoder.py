@@ -132,22 +132,31 @@ def attend_latent(
     ``Wuv`` the two halves of ``w_up``; the two forms below are that same
     product in two orders:
 
-    - ``prefill`` and ``prefill_cached`` **up-project**: the chunk's
-      latents (prefill) or the whole context's, gathered from the pages
-      (cached prefill: the prefix and the chunk just written), become
-      per-head keys of ``N + R`` and values of ``V`` lanes, and the
-      chunk attends causally. With T queries over S keys the
-      up-projection is ``S x C x H x (N + V)`` multiply-adds beside
-      attention's ``T x S x H x (N + R + V)``, where the absorbed form
-      would make the latter ``T x S x H x (2C + R)``: at a chunk of 1,024
-      up-projecting is the cheaper by a factor of ~2.5 (PERF.md
-      section 6, PR 41).
+    - ``prefill`` **up-projects**: the chunk's latents become per-head
+      keys of ``N + R`` and values of ``V`` lanes, and the chunk attends
+      causally within itself.
+    - ``prefill_cached`` gathers the whole context's latents from the
+      pages (the prefix and the chunk just written) and takes **whichever
+      form is fewer multiply-adds at its shapes**
+      (:func:`latent_prefill_form`, a rule of the static shapes alone:
+      the chunk's bucket T, the table's S tokens, H, C, N, R, V).
+      Up-projecting costs ``S H C (N + V)`` once and ``T S H (N + R + V)``
+      of attention; absorbing costs ``T H C (N + V)`` around an attention
+      of ``T S H (2C + R)`` over the latents themselves (one key and one
+      value for all heads: multi-query attention ``C + R`` wide). They
+      cross at ``T* = S C (N + V) / (S (2C - N - V) + C (N + V))``, which
+      for a long context is ``C (N + V) / (2C - N - V)``: 171 new tokens
+      at C 512, N 128, V 128 and 398 at C 512, N 192, V 256. Under it
+      the chunk absorbs, over it it up-projects; the context's
+      up-projection lies under the scopes ``mla_up_context`` and
+      ``mla_proj``, the absorbing matmuls under ``mla_absorb``.
     - ``decode`` **absorbs**: ``q_abs[n] = s q_nope[n] Wuk[n]^T`` (C
       wide), scores ``q_abs . c + q_rope . k_rope`` over the pages, the
       output ``p c`` still latent, then ``s o_lat[n] Wuv[n]``. No key and
       no value of a head is ever built, and a page is read once for all
-      heads (``ops/pallas_mla_decode.py`` on the chip). The two matmuls
-      around the kernel sit under the scope ``mla_absorb``."""
+      heads (``ops/pallas_mla_decode.py`` on the chip, whatever the
+      number of heads). The two matmuls around the kernel sit under the
+      scope ``mla_absorb``."""
     N = q_nope.shape[-1]
     R = k_rope.shape[-1]
     lanes = kv[1].shape[-1]
@@ -189,15 +198,60 @@ def attend_latent(
         with jax.named_scope("attention"):
             attn = prefill_attention(q, k, v, scale=scale,
                                      seq_lens=batch.seq_lens)
-    else:
-        with jax.named_scope("attention"):  # the pages' bytes are its
-            context = gather_latents(c_pages, r_pages, batch.block_tables,
-                                     layer, R)
-        k, v = up_project(*context)
+        return attn, (c_pages, r_pages)
+    with jax.named_scope("attention"):  # the pages' bytes are its
+        context = gather_latents(c_pages, r_pages, batch.block_tables,
+                                 layer, R)
+    form = latent_prefill_form(
+        q.shape[1], context[0].shape[1], *w_up.shape[:2], N, R,
+        w_up.shape[-1] - N)
+    if form == "up_projected":
+        with jax.named_scope("mla_up_context"):
+            k, v = up_project(*context)
         with jax.named_scope("attention"):
             attn = dense_context_attention(
                 q, k, v, batch.positions, batch.context_lens, scale=scale)
+        return attn, (c_pages, r_pages)
+    with jax.named_scope("mla_absorb"):
+        q_abs = jnp.einsum("bthn,hcn->bthc", scaled(q_nope), w_up[..., :N])
+    with jax.named_scope("attention"):
+        # The latents are every head's key (beside the rotated key) and
+        # every head's value: multi-query attention over one kv head.
+        o_lat = dense_context_attention(
+            jnp.concatenate([q_abs, q_rope], axis=-1),
+            jnp.concatenate(context, axis=-1)[:, :, None, :],
+            context[0][:, :, None, :], batch.positions, batch.context_lens,
+            scale=scale)
+    with jax.named_scope("mla_absorb"):
+        attn = jnp.einsum("bthc,hcv->bthv", scaled(o_lat), w_up[..., N:])
     return attn, (c_pages, r_pages)
+
+
+def latent_prefill_form(new_tokens: int, context: int, heads: int,
+                        latent: int, nope: int, rope: int, value: int) -> str:
+    """``"absorbed"`` or ``"up_projected"``: the form of a cached prefill
+    over a latent cache that is fewer multiply-adds a layer, for a chunk
+    of ``new_tokens`` queries (its bucket) over a gathered context of
+    ``context`` tokens (the table's width). THE decision, from static
+    shapes alone: :func:`attend_latent` takes it at trace time and the
+    engine counts it per dispatched program
+    (``tpu:latent_prefill_form_total{form}``). Read on the chip, a layer
+    alone under a 128-block table (``benchmarks/latent_prefill_forms.py``,
+    PR 44): wherever the rule says absorbed, absorbed is the faster, at
+    20 heads of 192 + 64 / 256 by 3.6 / 2.3 / 1.65 times at buckets 64 /
+    128 / 256 and at 64 heads of 128 + 64 / 128 by 2.5 / 2.2 at 64 / 128.
+    The rule is the cautious side of the chip's own crossover: the bucket
+    above it (512, 256) still reads 1.27 / 1.53 times faster absorbed,
+    because the up-projected form also writes and reads back ``S H (N + R
+    + V)`` keys and values that no multiply-add counts; at a whole chunk
+    of 1,024 absorbed leads by 1.10 at the first widths and up-projected
+    by 1.04 at the second (PERF.md section 7)."""
+    pairs = new_tokens * context * heads
+    up_projected = (context * heads * latent * (nope + value)
+                    + pairs * (nope + rope + value))
+    absorbed = (new_tokens * heads * latent * (nope + value)
+                + pairs * (2 * latent + rope))
+    return "absorbed" if absorbed < up_projected else "up_projected"
 
 
 def read_block_state(state: jax.Array, at, batch: Batch, block_size: int):

@@ -24,6 +24,7 @@ ARCH_MODULES = {
     "laguna": "production_stack_tpu.models.laguna",
     "lfm2": "production_stack_tpu.models.lfm2",
     "longcat": "production_stack_tpu.models.longcat",
+    "glm4_moe_lite": "production_stack_tpu.models.glm4_moe_lite",
 }
 
 

@@ -6,11 +6,25 @@ shares. In decode the up-projection is absorbed into the query
 (``models/decoder.py::attend_latent``), so a head's score over a token
 is ``q_abs . c + q_rope . k_r`` and its output is ``p c``, still in the
 latent space: **the page is the key and the value**, each byte of it is
-read once, and all 64 heads work on the one copy. That is 64 x (576 +
-512) x 2 FLOPs over 1,152 bytes a token, 121 FLOPs a byte where the
-v5e's ridge is ~240, but 64 heads fill half of the 128-row MXU, so the
-dots cost it about as long as the copies cost the DMA engines; the
-operands stay bf16 (an f32 matmul is several passes of the MXU).
+read once, and all ``H`` heads work on the one copy. That is ``H x (576
++ 512) x 2`` FLOPs over 1,152 bytes a token: at 64 heads (LongCat-Flash)
+121 FLOPs a byte where the v5e's ridge is ~240, and 64 heads fill half
+of the 128-row MXU, so the dots cost it about as long as the copies cost
+the DMA engines; at 20 heads (GLM-4.7-Flash) 38 FLOPs a byte on a sixth
+of the MXU's rows, the same number of passes over a chunk's tokens with
+fewer rows in each, so the core has more slack and the copies bound it
+the more. The operands stay bf16 (an f32 matmul is several passes of the
+MXU). **The heads need no alignment**: the query block, the output block
+and the accumulator take all of them as the whole of their dimension,
+and the compiler rounds the rows up to whole sublane tiles in VMEM (20
+heads lie in 24 rows of float32 and in 32 of bf16) whose padding no dot
+reads and no store writes; :func:`decode_tile` counts those rows. Read
+alone on the chip at 20 heads (PR 44, ``kernel_bs_sweep.py --cells
+--latent --cases=agent --heads=20``: 32, 16 and 8 rows over 4,096-7,168
+tokens): 305.2, 152.4 and 75.8 us a call, **82.4, 81.8 and 80.7% of the
+live tokens' floor**, within 4e-4 of the XLA path, and the same to 0.2
+us with the queries padded to 24 or to 32 heads outside the kernel: at
+the same tile (16 pages, ring 4) the copies bound it as they do at 64.
 
 The shape of the kernel is ``pallas_paged_attention``'s (PR 32), with
 its rule kept: **a row that holds nothing does nothing, and nothing is
@@ -76,8 +90,9 @@ GB/s of the 1,280 bytes a token that the two 128-lane-aligned sides hold
 cannot pass 90). Rows a grid step (2, 4, 8) changed nothing.
 
 Correctness: tests/test_longcat.py (interpret mode against the XLA path
-on the CPU at every edge the loop has, a slot of -1 and ragged contexts)
-and tests/test_chip_compile.py (the v5e compiler at the cell's shapes).
+on the CPU at every edge the loop has, a slot of -1 and ragged
+contexts), tests/test_glm4_moe_lite.py (5 and 20 heads) and
+tests/test_chip_compile.py (the v5e compiler at both cells' shapes).
 """
 
 from __future__ import annotations
@@ -103,10 +118,14 @@ def tiles_ok(block_size: int, heads: int, latent: int, rope_lanes: int,
     """Trace-time gate: what the page copies and the dots need aligned.
     A page ``[bs, lanes]`` is sliced out of HBM whole, so ``bs`` has to
     fill the sublane tile of the dtype (16 rows of bf16, 8 of float32)
-    and both sides whole 128-lane tiles; the heads are the rows of the
-    query block."""
+    and both sides whole 128-lane tiles. The heads are the rows of the
+    query block and of the accumulator, and any number of them will do:
+    a block takes all of them (the whole of its array's dimension, which
+    needs no alignment), and the compiler rounds the rows up to whole
+    sublane tiles in VMEM (20 heads lie in 24 rows of float32 and 32 of
+    bf16), whose padding no dot reads and no store writes."""
     return (block_size % (32 // itemsize) == 0 and latent % 128 == 0
-            and rope_lanes % 128 == 0 and heads % 8 == 0
+            and rope_lanes % 128 == 0 and heads >= 1
             and itemsize in (2, 4))
 
 
@@ -129,12 +148,16 @@ def decode_tile(block_size: int, heads: int, latent: int, rope_lanes: int,
     """(pages_per_block, ring) at these shapes within
     :data:`VMEM_BUDGET`; None when nothing fits."""
 
+    # The heads as VMEM lays them out: whole tiles of 8 float32 rows
+    # (the same number at a multiple of 8: 64 heads count as before).
+    rows = -(-heads // 8) * 8
+
     def fits(pages: int, ring: int) -> bool:
         span = pages * block_size
         total = ring * span * (latent + rope_lanes) * itemsize  # rings
-        total += 4 * heads * latent  # acc
-        total += 3 * 4 * heads * span  # scores, probabilities, their bf16
-        total += 2 * 2 * heads * (2 * latent + rope_lanes) * itemsize
+        total += 4 * rows * latent  # acc
+        total += 3 * 4 * rows * span  # scores, probabilities, their bf16
+        total += 2 * 2 * rows * (2 * latent + rope_lanes) * itemsize
         return total <= VMEM_BUDGET
 
     return choose_tile(fits, tables_width, block_size,

@@ -1,6 +1,7 @@
 """Optimised v5e HLO of models.llama.apply at mistral-7b-l16's widths (and
 of models.laguna.apply at laguna-s-2.1-l8e64's, bf16: PR 36; of
-models.lfm2.apply at lfm2-24b-a2b-l10's: PR 40), for
+models.lfm2.apply at lfm2-24b-a2b-l10's: PR 40; of models.longcat.apply
+at longcat-flash-l4e16's: PR 44), for
 a refactor that must not change the program (PR 31): run it on a copy of
 the parent and on the change and compare the digests; no chip needed.
 
@@ -181,5 +182,24 @@ if lfm2 is not None:  # (PR 40)
     family_digests("lfm2", "lfm2-24b-a2b-l10", lfm2, lfm2_pool,
                    (("decode", 32, 1, 64), ("prefill", 4, 512, 8),
                     ("prefill_cached", 1, 1024, 64)))
+
+
+def latent_pool(c):
+    """The two unequal sides of a latent cache (Family.page_sides)."""
+    from production_stack_tpu.engine.core import kv_page_sides
+
+    layers, *sides = kv_page_sides(c)
+    return tuple(spec((layers, 1024, BS) + side, jnp.bfloat16)
+                 for side in sides)
+
+
+try:  # a tree before PR 41 has no such family
+    from production_stack_tpu.models import longcat
+except ImportError:
+    longcat = None
+if longcat is not None:  # (PR 44) the cell's chunk is 1,024 positions
+    family_digests("longcat", "longcat-flash-l4e16", longcat, latent_pool,
+                   (("decode", 128, 1, 64), ("prefill", 1, 1024, 16),
+                    ("prefill_cached", 1, 1024, 128)))
 with open(os.path.join(out, "digests.json"), "w") as f:
     json.dump(digests, f, indent=1)

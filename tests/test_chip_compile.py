@@ -578,3 +578,140 @@ def test_longcat_programs_compile_at_the_configurations_widths(
     assert not copied, copied
     assert program.memory_analysis().temp_size_in_bytes < (
         0.2e9 if mode == "decode" else 1.1e9)
+
+
+@pytest.mark.parametrize("rows, tables", [(32, 128), (32, 64), (8, 16)])
+def test_latent_decode_kernel_compiles_at_twenty_heads(one_chip, rows,
+                                                       tables):
+    """``glm-4.7-flash-e8v8``'s decode attention: 20 heads (no multiple
+    of the eight rows of a sublane tile, nor of the sixteen a bf16 tile
+    packs) over the same page as LongCat's, 47 page layers. The query
+    block and the accumulator take all 20 heads as the whole of their
+    dimension, the v5e compiler pads them in VMEM, and the kernel keeps
+    LongCat's tile (16 pages, ring 4)."""
+    from production_stack_tpu.ops.pallas_mla_decode import (
+        decode_tile,
+        pallas_mla_decode,
+        tiles_ok,
+    )
+
+    H, C, lanes = 20, 512, 128
+
+    def spec(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    assert tiles_ok(BLOCK_SIZE, H, C, lanes, 2)
+    assert decode_tile(BLOCK_SIZE, H, C, lanes, 2, tables) == (
+        min(tables, 16), 4)
+    program = jax.jit(
+        lambda qa, qr, c, r, bt, cl, layer: pallas_mla_decode(
+            qa, qr, c, r, bt, cl, layer, scale=256 ** -0.5)).lower(
+        spec((rows, H, C), jnp.bfloat16), spec((rows, H, 64), jnp.bfloat16),
+        spec((47, NUM_BLOCKS, BLOCK_SIZE, 1, C), jnp.bfloat16),
+        spec((47, NUM_BLOCKS, BLOCK_SIZE, 1, lanes), jnp.bfloat16),
+        spec((rows, tables)), spec((rows,)), spec(())).compile()
+    text = program.as_text()
+    assert "tpu_custom_call" in text and "pallas_mla_decode" in text
+    assert program.memory_analysis().temp_size_in_bytes == 0
+
+
+@pytest.mark.parametrize("mode,rows,width,tables", [
+    ("decode", 32, 1, 128), ("prefill", 1, 1024, 16),
+    ("prefill_cached", 1, 512, 128), ("prefill_cached", 1, 256, 128)])
+def test_glm47_flash_programs_compile_at_the_configurations_widths(
+        one_chip, monkeypatch, tmp_path, mode, rows, width, tables):
+    """``glm-4.7-flash-e8v8`` as the benchmark serves it (the model keys
+    of its file, written to a ``config.json`` as ``chipbench.stack``
+    does), all 47 layers: the three forward programs with the expert
+    layer's counts compile for the v5e; decode holds the latent kernel at
+    20 heads (traced once: the scan's body is one layer) and every mode
+    the grouped-matmul kernel; the weights are the 10.16 GB the
+    configuration states; no expert stack and no side of the pool is
+    copied; a cached prefill of 512 positions under a 128-block table
+    up-projects the gathered context and one of 256 absorbs (the rule of
+    the shapes crosses at 398); and the temporaries stay under what the
+    pool leaves free."""
+    import json
+    import os
+    import sys
+
+    from production_stack_tpu.models import decoder, glm4_moe_lite
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if repo not in sys.path:
+        sys.path.insert(0, repo)
+    from chipbench.registry import model_keys
+
+    with open(os.path.join(repo, "chipbench", "configs",
+                           "glm-4.7-flash-e8v8.json")) as f:
+        (tmp_path / "config.json").write_text(
+            json.dumps(model_keys(json.load(f))))
+    cfg = get_model_config(str(tmp_path))
+    assert (cfg.num_layers, cfg.num_heads, cfg.dense_layers) == (47, 20, 1)
+    monkeypatch.setattr(att, "_use_pallas", lambda: True)
+    monkeypatch.setattr(gmm, "_platform", lambda: "tpu")
+    att.TRACED_PATHS.clear()
+
+    def spec(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda x: spec(x.shape, x.dtype),
+        jax.eval_shape(
+            lambda: glm4_moe_lite.init_params(cfg, jax.random.key(0))))
+    weights = sum(int(np.prod(x.shape)) * x.dtype.itemsize
+                  for x in jax.tree_util.tree_leaves(params))
+    assert abs(weights / 10.16e9 - 1) < 0.01
+    blocks = 1280  # a pool of 4.9 GB: 81,920 tokens of 60,160 bytes
+    pages = (spec((47, blocks, BLOCK_SIZE, 1, 512), jnp.bfloat16),
+             spec((47, blocks, BLOCK_SIZE, 1, 128), jnp.bfloat16))
+    last = mode != "decode"
+    program = jax.jit(
+        lambda p, kv, tok, pos, slot, bt, cl, sl: glm4_moe_lite.apply(
+            p, cfg, tok, pos, kv, slot, bt, cl, sl, mode=mode,
+            last_token=jnp.maximum(sl - 1, 0) if last else None,
+            with_stats=True), donate_argnums=(1,)).lower(
+        params, pages, spec((rows, width)), spec((rows, width)),
+        spec((rows, width)), spec((rows, tables)), spec((rows,)),
+        spec((rows,))).compile()
+    text = program.as_text()
+    assert ("pallas_mla_decode" in text) == (mode == "decode")
+    assert att.TRACED_PATHS["latent_decode", "pallas"] == (
+        mode == "decode")
+    assert att.TRACED_PATHS["grouped_matmul", "pallas"] >= 1
+    assert "pallas_grouped_matmul" in text and "ragged-dot" not in text
+    if mode == "prefill_cached":
+        form = decoder.latent_prefill_form(
+            width, tables * BLOCK_SIZE, 20, 512, 192, 64, 256)
+        assert form == ("up_projected" if width == 512 else "absorbed")
+    copied = [line.strip()[:120] for line in text.splitlines()
+              if re.search(rf"= bf16\[(46,8,\d{{4}},\d{{4}}|47,{blocks},"
+                           rf"{BLOCK_SIZE},1,\d+)\]\S* copy(-start)?\(",
+                           line)]
+    assert not copied, copied
+    assert program.memory_analysis().temp_size_in_bytes < (
+        0.2e9 if mode == "decode" else 1.2e9)
+
+
+@pytest.mark.parametrize("shape", [
+    (47, 1140, BLOCK_SIZE, 1, 512), (47, 1140, BLOCK_SIZE, 1, 128),
+    (8, 6988, BLOCK_SIZE, 1, 512), (16, 1559, BLOCK_SIZE, 8, 128)],
+    ids=["glm_latent", "glm_rope", "longcat_latent", "mistral_keys"])
+def test_reading_a_few_blocks_of_a_full_pool_copies_no_side(one_chip, shape):
+    """``extract_kv`` reads a prompt's blocks out of a pool that fills the
+    chip: the gather through the flat view has no temporary at any pool's
+    shape, where ``x[:, idx]`` on the 47-layer latent side is given a
+    copy of the whole side (3.5 GB: the check ran out of memory on the
+    chip, PR 44)."""
+    from production_stack_tpu.engine.core import _gather_blocks_flat
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    program = _gather_blocks_flat.lower(
+        spec(shape, jnp.bfloat16), spec((11,), jnp.int32)).compile()
+    assert program.memory_analysis().temp_size_in_bytes < 1 << 20
+    if shape[0] == 47 and shape[-1] == 512:
+        plain = jax.jit(lambda x, idx: x[:, idx]).lower(
+            spec(shape, jnp.bfloat16), spec((11,), jnp.int32)).compile()
+        assert plain.memory_analysis().temp_size_in_bytes > 3e9

@@ -26,7 +26,8 @@ from production_stack_tpu.parallel.pp_serving import make_pp_apply
 ARCHS = sorted(ARCH_MODULES)
 PRESET = {"llama": "tiny-llama", "opt": "tiny-opt", "mixtral": "tiny-mixtral",
           "laguna": "tiny-laguna", "lfm2": "tiny-lfm2",
-          "longcat": "tiny-longcat"}
+          "longcat": "tiny-longcat",
+          "glm4_moe_lite": "tiny-glm4-moe-lite"}
 # What a family's config.json must hold beside the sizes every family
 # reads (``Family.per_layer_keys``: lists, one entry a layer or more).
 REQUIRED_KEYS = {"laguna": {
@@ -44,7 +45,14 @@ REQUIRED_KEYS = {"laguna": {
     "num_layers": 2, "ffn_hidden_size": 48, "expert_ffn_hidden_size": 16,
     "q_lora_rank": 24, "kv_lora_rank": 128, "qk_nope_head_dim": 8,
     "qk_rope_head_dim": 8, "v_head_dim": 8, "n_routed_experts": 4,
-    "zero_expert_num": 2, "moe_topk": 2, "routed_scaling_factor": 6}}
+    "zero_expert_num": 2, "moe_topk": 2, "routed_scaling_factor": 6},
+    "glm4_moe_lite": {
+    "intermediate_size": 48, "moe_intermediate_size": 16,
+    "q_lora_rank": 24, "kv_lora_rank": 128, "qk_nope_head_dim": 8,
+    "qk_rope_head_dim": 8, "v_head_dim": 16, "n_routed_experts": 4,
+    "n_shared_experts": 1, "num_experts_per_tok": 2, "n_group": 1,
+    "topk_group": 1, "first_k_dense_replace": 1, "norm_topk_prob": True,
+    "routed_scaling_factor": 1.8, "num_nextn_predict_layers": 1}}
 SIZES = dict(vocab_size=64, hidden_size=32, num_hidden_layers=2,
              num_attention_heads=4, max_position_embeddings=64)
 
@@ -54,7 +62,7 @@ def _hf_model(arch):
     family brings its line)."""
     import transformers as tf
 
-    if arch in ("laguna", "lfm2", "longcat"):
+    if arch in ("laguna", "lfm2", "longcat", "glm4_moe_lite"):
         return None  # no class of it here (or no loader yet), no checkpoint
     return {
         "llama": lambda: tf.LlamaForCausalLM(tf.LlamaConfig(
