@@ -2,9 +2,11 @@
 """A cached prefill's attention over a latent cache, alone, in each of its
 two forms (``models/decoder.py::attend_latent``, ``prefill_cached``): what
 :func:`decoder.latent_prefill_form` chooses between, read on the chip at
-the shapes two configurations hand it (PR 44; PERF.md section 6).
+the shapes two configurations hand it (PRs 44 and 45; PERF.md section 6;
+the readings the rule was fitted to are ``chiprun_out/pr45/`` and the cases
+of ``tests/test_glm4_moe_lite.py``).
 
-    chiprun -- python benchmarks/latent_prefill_forms.py   # ~2 chip-minutes
+    chiprun -- python benchmarks/latent_prefill_forms.py   # ~6 chip-minutes
 
 One JSON line a case (geometry, the chunk's bucket, the table's blocks):
 ms a call of one layer, ``up_projected`` and ``absorbed`` (the rule set
@@ -43,14 +45,26 @@ GEOMETRIES = {
     "longcat-flash": (64, 512, 128, 64, 128, (6144 / 512) ** 0.5,
                       192 ** -0.5),
 }
-# (geometry, bucket, table blocks, live context): the agent cell's turns
+# geometry: {table blocks: the buckets read under it}. The agent cell's turns
 # (~5.7k of context under the 128-block table) at each bucket they take,
-# LongCat's tails at and around its crossover of 171, and a whole chunk
-# (1,024) of each.
-CASES = [("glm-4.7-flash", t, 128, 5700)
-         for t in (64, 128, 256, 512, 1024)] + [
-    ("longcat-flash", t, 128, 5700) for t in (64, 128, 256, 1024)] + [
-    ("longcat-flash", 128, 32, 1500)]
+# LongCat's tails around its crossover, and a whole chunk (1,024) of each
+# (PR 44); then, for PR 45's rule, every (bucket, table) pair whose form it
+# changes and the pairs beside them that must stay: LongCat's tails and
+# whole chunks under the tables its prompts of 1-6k take (32, 64, 128
+# blocks), both models' widest buckets under shorter tables, and short
+# tables whose float32 scores stay on the chip.
+BUCKETS = {
+    "glm-4.7-flash": {128: (64, 128, 256, 512, 1024), 64: (512, 1024),
+                      32: (512, 1024), 16: (512, 1024), 8: (256, 512),
+                      4: (256,)},
+    "longcat-flash": {128: (64, 128, 256, 512, 1024), 64: (256, 512, 1024),
+                      32: (128, 256, 512, 1024), 16: (256, 512, 1024),
+                      8: (128, 256, 512), 4: (128, 256)},
+}
+LIVE = {4: 256, 8: 512, 16: 800, 32: 1500, 64: 3000, 128: 5700}  # context
+CASES = [(geometry, t, table, LIVE[table])
+         for geometry, tables in BUCKETS.items()
+         for table, buckets in tables.items() for t in buckets]
 if TINY:
     CASES = [("glm-4.7-flash", 16, 4, 200), ("longcat-flash", 16, 4, 200)]
 

@@ -9,10 +9,9 @@ A family (models/registry.py::Family) brings ``embed``, ``layer`` and
   the pages alone (decode). The only code under ``models/`` that imports
   ``ops.attention`` and the only code that spells the three mode names:
   a window mask has this one call site to change, and a latent cache
-  (MLA) has its sibling :func:`attend_latent`, the only other. A
-  state per cache block beside the pages (a short convolution's last
-  inputs) is read and written by :func:`read_block_state` and
-  :func:`write_block_state`;
+  (MLA) has its sibling :func:`attend_latent`, the only other. A state
+  per cache block beside the pages (a short convolution's last inputs)
+  goes through :func:`read_block_state` and :func:`write_block_state`;
 - :func:`scan_layers`: one ``lax.scan`` over the layer-stacked leaves
   (single-layer trace, fast compiles even at 80 layers) with the carry
   convention of the paged pool;
@@ -30,6 +29,7 @@ import jax
 import jax.numpy as jnp
 
 from production_stack_tpu.models.config import ModelConfig
+from production_stack_tpu.ops import attention as paged
 from production_stack_tpu.ops.attention import (
     context_prefill_attention,
     dense_context_attention,
@@ -109,6 +109,16 @@ def attend(
     return attn, (k_pages, v_pages)
 
 
+# Multiply-adds a byte of HBM traffic at a v5e's ridge: 197e12 / 2 / 819e9
+# (Google Cloud "TPU v5e"; ``obs/steps.py::DEVICE_PEAKS``). A constant of
+# :func:`latent_prefill_form`, never the attached device's: the CPU names
+# the form the chip compiles.
+MACS_PER_HBM_BYTE = 120
+# An intermediate up to this size stays on the chip between the steps that
+# write and read it: 84 MB of scores read as staying, 134 MB as not (PR 45).
+ON_CHIP_BYTES = 96 << 20
+
+
 def attend_latent(
     mode: str,  # "prefill" | "prefill_cached" | "decode"  (static)
     q_nope: jax.Array,  # [B, T, H, N]
@@ -124,39 +134,29 @@ def attend_latent(
     latent_scale: float = 1.0,  # on the latent before ``w_up``
 ):
     """:func:`attend` for a latent cache (multi-head latent attention):
-    a token's page keeps ``c`` on the first side and ``k_rope`` on the
-    second (zeros in the lanes beyond it) and no head's keys or values.
-    Writes them, then attends; returns (the heads' outputs [B, T, H, V],
-    updated pages). Head ``n``'s key is ``[(s c) Wuk[n], k_rope]`` and
-    its value ``(s c) Wuv[n]`` with ``s = latent_scale`` and ``Wuk``,
-    ``Wuv`` the two halves of ``w_up``; the two forms below are that same
-    product in two orders:
+    a token's page keeps ``c`` and ``k_rope`` (zeros in the lanes beyond
+    it) and no head's keys or values. Writes them, then attends; returns
+    (outputs [B, T, H, V], updated pages). Head ``n``'s key is ``[(s c)
+    Wuk[n], k_rope]``, its value ``(s c) Wuv[n]`` (``s = latent_scale``;
+    ``Wuk``, ``Wuv`` the halves of ``w_up``): one product, two orders.
 
-    - ``prefill`` **up-projects**: the chunk's latents become per-head
-      keys of ``N + R`` and values of ``V`` lanes, and the chunk attends
-      causally within itself.
-    - ``prefill_cached`` gathers the whole context's latents from the
-      pages (the prefix and the chunk just written) and takes **whichever
-      form is fewer multiply-adds at its shapes**
-      (:func:`latent_prefill_form`, a rule of the static shapes alone:
-      the chunk's bucket T, the table's S tokens, H, C, N, R, V).
-      Up-projecting costs ``S H C (N + V)`` once and ``T S H (N + R + V)``
-      of attention; absorbing costs ``T H C (N + V)`` around an attention
-      of ``T S H (2C + R)`` over the latents themselves (one key and one
-      value for all heads: multi-query attention ``C + R`` wide). They
-      cross at ``T* = S C (N + V) / (S (2C - N - V) + C (N + V))``, which
-      for a long context is ``C (N + V) / (2C - N - V)``: 171 new tokens
-      at C 512, N 128, V 128 and 398 at C 512, N 192, V 256. Under it
-      the chunk absorbs, over it it up-projects; the context's
-      up-projection lies under the scopes ``mla_up_context`` and
-      ``mla_proj``, the absorbing matmuls under ``mla_absorb``.
-    - ``decode`` **absorbs**: ``q_abs[n] = s q_nope[n] Wuk[n]^T`` (C
-      wide), scores ``q_abs . c + q_rope . k_rope`` over the pages, the
-      output ``p c`` still latent, then ``s o_lat[n] Wuv[n]``. No key and
-      no value of a head is ever built, and a page is read once for all
-      heads (``ops/pallas_mla_decode.py`` on the chip, whatever the
-      number of heads). The two matmuls around the kernel sit under the
-      scope ``mla_absorb``."""
+    - ``prefill`` **up-projects** the chunk's latents into per-head keys
+      (``N + R``) and values (``V``) and attends causally within itself.
+    - ``prefill_cached`` gathers the context's S latents from the pages and
+      takes **the form the chip runs faster** (:func:`latent_prefill_form`,
+      from T, S, H, C, N, R, V alone). A step costs the larger of its
+      multiply-adds and its HBM bytes x :data:`MACS_PER_HBM_BYTE`; with P =
+      T S H, *up-projected* (scope ``mla_up_context``): ``S H C (N + V)``
+      writing ``up``; keys and values cut from it, ``2 S H (2N + R + 2V)``
+      bytes; ``P (N + R)`` reading the keys; ``P V`` reading the values.
+      *Absorbed* (multi-query attention over the latents, ``mla_absorb``
+      around it): ``T H C (N + V)``, ``P (C + R)``, ``P C``. Either form's
+      two attention matmuls also move float32 scores, 4 bytes a pair. Bytes
+      count past :data:`ON_CHIP_BYTES` (the scores; ``up`` with its keys and
+      values); a streamed context carries a float32 accumulator a span.
+    - ``decode`` **absorbs** around ``ops/pallas_mla_decode.py``: no key or
+      value of a head is built, a page is read once for all heads; the two
+      matmuls beside the kernel lie under ``mla_absorb``."""
     N = q_nope.shape[-1]
     R = k_rope.shape[-1]
     lanes = kv[1].shape[-1]
@@ -230,27 +230,27 @@ def attend_latent(
 def latent_prefill_form(new_tokens: int, context: int, heads: int,
                         latent: int, nope: int, rope: int, value: int) -> str:
     """``"absorbed"`` or ``"up_projected"``: the form of a cached prefill
-    over a latent cache that is fewer multiply-adds a layer, for a chunk
-    of ``new_tokens`` queries (its bucket) over a gathered context of
-    ``context`` tokens (the table's width). THE decision, from static
-    shapes alone: :func:`attend_latent` takes it at trace time and the
-    engine counts it per dispatched program
-    (``tpu:latent_prefill_form_total{form}``). Read on the chip, a layer
-    alone under a 128-block table (``benchmarks/latent_prefill_forms.py``,
-    PR 44): wherever the rule says absorbed, absorbed is the faster, at
-    20 heads of 192 + 64 / 256 by 3.6 / 2.3 / 1.65 times at buckets 64 /
-    128 / 256 and at 64 heads of 128 + 64 / 128 by 2.5 / 2.2 at 64 / 128.
-    The rule is the cautious side of the chip's own crossover: the bucket
-    above it (512, 256) still reads 1.27 / 1.53 times faster absorbed,
-    because the up-projected form also writes and reads back ``S H (N + R
-    + V)`` keys and values that no multiply-add counts; at a whole chunk
-    of 1,024 absorbed leads by 1.10 at the first widths and up-projected
-    by 1.04 at the second (PERF.md section 7)."""
-    pairs = new_tokens * context * heads
-    up_projected = (context * heads * latent * (nope + value)
-                    + pairs * (nope + rope + value))
-    absorbed = (new_tokens * heads * latent * (nope + value)
-                + pairs * (2 * latent + rope))
+    over a latent cache that the chip runs faster, for ``new_tokens``
+    queries (the bucket) over ``context`` gathered tokens (the table).
+    THE decision, from static shapes alone: :func:`attend_latent` takes it
+    at trace time and states its terms, the engine counts it a dispatch;
+    read on the chip by ``benchmarks/latent_prefill_forms.py`` (PERF.md)."""
+    pairs, kv = new_tokens * context * heads, 2 * context * heads  # B a lane
+    built = kv * (2 * nope + rope + 2 * value)  # ``up``, its keys and values
+    far = MACS_PER_HBM_BYTE * (built > ON_CHIP_BYTES)  # a byte of those
+    scores = MACS_PER_HBM_BYTE * 4 * pairs * (4 * pairs > ON_CHIP_BYTES)
+    projection = heads * latent * (nope + value)  # multiply-adds a token
+    up_projected = (
+        max(context * projection, far * kv * (nope + value)) + far * built
+        + max(pairs * (nope + rope), far * kv * (nope + rope) + scores)
+        + max(pairs * value, far * kv * value + scores))
+    absorbed = (new_tokens * projection + max(pairs * latent, scores)
+                + max(pairs * (latent + rope), scores))
+    spans = -(-context // paged._CHUNKED_SCORE_SPAN)
+    if 4 * pairs > paged._CHUNKED_SCORE_BYTES and spans > 1:  # streamed
+        carried = MACS_PER_HBM_BYTE * 8 * spans * new_tokens * heads
+        up_projected += carried * value  # the accumulator's lanes
+        absorbed += carried * latent
     return "absorbed" if absorbed < up_projected else "up_projected"
 
 

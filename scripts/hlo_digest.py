@@ -1,7 +1,8 @@
 """Optimised v5e HLO of models.llama.apply at mistral-7b-l16's widths (and
 of models.laguna.apply at laguna-s-2.1-l8e64's, bf16: PR 36; of
 models.lfm2.apply at lfm2-24b-a2b-l10's: PR 40; of models.longcat.apply
-at longcat-flash-l4e16's: PR 44), for
+at longcat-flash-l4e16's: PR 44; of models.glm4_moe_lite.apply at
+glm-4.7-flash-e8v8's and LongCat's cached tails: PR 45), for
 a refactor that must not change the program (PR 31): run it on a copy of
 the parent and on the change and compare the digests; no chip needed.
 
@@ -137,8 +138,14 @@ def family_digests(name, config, module, pool, shapes):
     params = jax.tree_util.tree_map(
         lambda x: spec(x.shape, x.dtype),
         jax.eval_shape(lambda: module.init_params(fcfg, jax.random.key(0))))
+    seen = set()
     for mode, rows, width, tables in shapes:
         last = mode != "decode"
+        # A mode's first shape keeps the plain name (what earlier trees'
+        # digests are compared by); a further one names its shape.
+        label = (f"{name}.{mode}" if mode not in seen
+                 else f"{name}.{mode}.{width}x{tables}")
+        seen.add(mode)
 
         def fn(p, kv, tok, pos, slot, bt, cl, sl):
             return module.apply(
@@ -146,7 +153,7 @@ def family_digests(name, config, module, pool, shapes):
                 last_token=jnp.maximum(sl - 1, 0) if last else None,
                 with_stats=True)
 
-        digest(f"{name}.{mode}", fn, 1, params, pool(fcfg),
+        digest(label, fn, 1, params, pool(fcfg),
                spec((rows, width)), spec((rows, width)), spec((rows, width)),
                spec((rows, tables)), spec((rows,)), spec((rows,)))
 
@@ -197,9 +204,27 @@ try:  # a tree before PR 41 has no such family
     from production_stack_tpu.models import longcat
 except ImportError:
     longcat = None
-if longcat is not None:  # (PR 44) the cell's chunk is 1,024 positions
+if longcat is not None:  # (PR 44) the cell's chunk is 1,024 positions;
+    # (PR 45) a whole chunk under the two shorter tables its prompts take,
+    # and the tails on both sides of the cached prefill's crossover
     family_digests("longcat", "longcat-flash-l4e16", longcat, latent_pool,
                    (("decode", 128, 1, 64), ("prefill", 1, 1024, 16),
+                    ("prefill_cached", 1, 1024, 128),
+                    ("prefill_cached", 1, 1024, 64),
+                    ("prefill_cached", 1, 1024, 32),
+                    ("prefill_cached", 1, 512, 32),
+                    ("prefill_cached", 1, 256, 32),
+                    ("prefill_cached", 1, 128, 32)))
+try:  # a tree before PR 44 has no such family
+    from production_stack_tpu.models import glm4_moe_lite
+except ImportError:
+    glm4_moe_lite = None
+if glm4_moe_lite is not None:  # (PR 45) every cached bucket the agent
+    # cell's turns take under its 128-block table
+    family_digests("glm", "glm-4.7-flash-e8v8", glm4_moe_lite, latent_pool,
+                   (("decode", 32, 1, 128), ("prefill", 1, 512, 8),
+                    ("prefill_cached", 1, 256, 128),
+                    ("prefill_cached", 1, 512, 128),
                     ("prefill_cached", 1, 1024, 128)))
 with open(os.path.join(out, "digests.json"), "w") as f:
     json.dump(digests, f, indent=1)

@@ -32,6 +32,7 @@ from production_stack_tpu.engine.core import (
 from production_stack_tpu.engine.sampling import SamplingParams
 from production_stack_tpu.models import (
     build_model,
+    decoder,
     get_model_config,
     glm4_moe_lite,
     moe,
@@ -276,6 +277,39 @@ def test_the_two_cached_forms_are_one_product_in_two_orders():
     np.testing.assert_allclose(outs[8], outs[48][:, :8], atol=2e-5)
 
 
+def test_the_two_forms_agree_where_the_rule_now_absorbs(monkeypatch):
+    """LongCat's widths, a tail of 256 queries under a 32-block table of
+    2,048 tokens: fewer multiply-adds said up-projected, the chip read
+    absorbed 1.25 times faster and the rule absorbs since PR 45. The
+    rule's own form and the up-projected one forced read the same within
+    the file's tolerance."""
+    H, C, N, R, V = WIDTHS["longcat"]
+    T, S = 256, 2048
+    assert _fewer_multiply_adds(T, S, H, C, N, R, V) == "up_projected"
+    assert latent_prefill_form(T, S, H, C, N, R, V) == "absorbed"
+    case = _latent_case(np.random.default_rng(8), B=1, H=H, N=N, R=R, C=C,
+                        V=V, blocks=S // BS)
+    rng = np.random.default_rng(9)
+    draw = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)  # noqa: E731
+    pos = 1500 - T + jnp.arange(T)[None, :]
+    slot = (jnp.take_along_axis(case["tables"], pos // BS, axis=1) * BS
+            + pos % BS)
+    batch = Batch(pos, slot, case["tables"], jnp.asarray([1500], jnp.int32),
+                  jnp.asarray([T], jnp.int32))
+    operands = (draw(1, T, H, N), draw(1, T, H, R), draw(1, T, C),
+                draw(1, T, R), case["w_up"] * 11.0 * C ** -0.5, case["kv"],
+                jnp.int32(1), batch)
+
+    def attended():
+        return attend_latent("prefill_cached", *operands,
+                             scale=(N + R) ** -0.5, latent_scale=12 ** 0.5)[0]
+
+    absorbed = attended()
+    monkeypatch.setattr(decoder, "latent_prefill_form",
+                        lambda *shapes: "up_projected")
+    np.testing.assert_allclose(absorbed, attended(), atol=TOL)
+
+
 def test_decode_through_the_latent_pages_holds_the_reference(
         cfg, params, sequences, wanted):
     """Absorbed decode over the pages at five heads, two rows of ragged
@@ -381,29 +415,100 @@ def test_the_gate_follows_the_heads_and_longcats_tile_stays(monkeypatch):
     assert att.latent_decode_path(64, 20, 512, 128, "bfloat16") == "pallas"
 
 
-@pytest.mark.parametrize("widths, crossover", [
-    ((64, 512, 128, 64, 128), 171),  # LongCat-Flash
-    ((20, 512, 192, 64, 256), 398),  # GLM-4.7-Flash
-])
-def test_the_cached_prefills_form_is_a_rule_of_the_shapes(widths,
-                                                          crossover):
-    """Under the crossover a chunk absorbs, over it it up-projects; the
-    crossover is C (N + V) / (2C - N - V) for a long context and lower
-    for a short one (the absorbing matmuls are per query)."""
-    heads, latent, nope, rope, value = widths
-    assert crossover == round(latent * (nope + value)
-                              / (2 * latent - nope - value))
-    long_context = 1 << 22
-    assert latent_prefill_form(crossover - 2, long_context,
-                               *widths) == "absorbed"
-    assert latent_prefill_form(crossover + 2, long_context,
-                               *widths) == "up_projected"
-    forms = [latent_prefill_form(t, 8192, *widths)
-             for t in (64, 128, 256, 512, 1024)]
-    assert forms == sorted(forms)  # absorbed first, one switch
-    assert forms[-1] == "up_projected" and forms[0] == "absorbed"
-    # a short context crosses earlier
-    assert latent_prefill_form(crossover - 2, 256, *widths) == "up_projected"
+WIDTHS = {"glm": (20, 512, 192, 64, 256),  # GLM-4.7-Flash: H, C, N, R, V
+          "longcat": (64, 512, 128, 64, 128)}  # LongCat-Flash
+# (widths, bucket, table blocks, ms a layer up-projected, absorbed): every
+# reading of ``benchmarks/latent_prefill_forms.py`` on a TPU v5e (PR 44's
+# ten again and PR 45's; the second of two calls that agree within 1%,
+# ``chiprun_out/pr45/prefill_forms_2.jsonl``, PERF.md section 6).
+READINGS = [
+    ("glm", 64, 128, 0.7479, 0.2117),
+    ("glm", 128, 128, 0.9055, 0.4009),
+    ("glm", 256, 128, 1.4254, 0.8552),
+    ("glm", 512, 128, 2.2479, 1.7652),
+    ("glm", 1024, 128, 3.7221, 3.3781),
+    ("longcat", 64, 128, 2.2289, 0.8933),
+    ("longcat", 128, 128, 2.8185, 1.2921),
+    ("longcat", 256, 128, 3.9414, 2.588),
+    ("longcat", 512, 128, 6.5082, 5.4674),
+    ("longcat", 1024, 128, 13.4225, 13.9393),
+    ("longcat", 128, 32, 0.5057, 0.2782),
+    ("longcat", 256, 32, 0.8806, 0.7029),
+    ("longcat", 512, 32, 1.606, 1.4072),
+    ("longcat", 1024, 32, 3.0208, 2.7089),
+    ("longcat", 256, 64, 1.783, 1.2966),
+    ("longcat", 512, 64, 2.9423, 2.7006),
+    ("longcat", 1024, 64, 5.4555, 5.1731),
+    ("glm", 512, 64, 1.0634, 0.8725),
+    ("glm", 1024, 64, 1.8873, 1.7325),
+    ("glm", 512, 32, 0.3858, 0.3742),
+    ("glm", 1024, 32, 0.9149, 0.9178),
+    ("longcat", 256, 16, 0.2932, 0.2844),
+    ("longcat", 512, 16, 0.7699, 0.7449),
+    ("longcat", 1024, 16, 1.3681, 1.4038),
+    ("glm", 512, 16, 0.1724, 0.2107),
+    ("glm", 1024, 16, 0.3089, 0.402),
+    ("longcat", 128, 8, 0.118, 0.1141),
+    ("longcat", 256, 8, 0.1514, 0.1824),
+    ("longcat", 512, 8, 0.2013, 0.3257),
+    ("glm", 256, 8, 0.0947, 0.0929),
+    ("glm", 512, 8, 0.117, 0.1445),
+    ("longcat", 128, 4, 0.0793, 0.0895),
+    ("longcat", 256, 4, 0.0993, 0.1364),
+    ("glm", 256, 4, 0.0667, 0.0754),
+]
+
+
+def _fewer_multiply_adds(T, S, H, C, N, R, V):
+    """PR 44's rule: what the rule still says where nothing spills."""
+    up_projected = S * H * C * (N + V) + T * S * H * (N + R + V)
+    absorbed = T * H * C * (N + V) + T * S * H * (2 * C + R)
+    return "absorbed" if absorbed < up_projected else "up_projected"
+
+
+@pytest.mark.parametrize(
+    "widths, bucket, table, up_projected_ms, absorbed_ms", READINGS,
+    ids=[f"{r[0]}-{r[1]}-under-{r[2]}" for r in READINGS])
+def test_the_rule_names_the_form_the_chip_read_faster(
+        widths, bucket, table, up_projected_ms, absorbed_ms):
+    """Every reading: the rule names the faster form; within 4% of a tie
+    (six readings under short tables, where neither form's intermediates
+    leave the chip) it may keep the form with fewer multiply-adds instead,
+    and a pair whose form PR 45 changed is always the faster one."""
+    shapes = (bucket, table * 64) + WIDTHS[widths]
+    faster = ("absorbed" if absorbed_ms < up_projected_ms
+              else "up_projected")
+    form = latent_prefill_form(*shapes)
+    if max(up_projected_ms, absorbed_ms) > 1.04 * min(
+            up_projected_ms, absorbed_ms):
+        assert form == faster
+    else:
+        assert form in (faster, _fewer_multiply_adds(*shapes))
+    if form != _fewer_multiply_adds(*shapes):
+        assert form == faster == "absorbed"
+
+
+@pytest.mark.parametrize("widths", sorted(WIDTHS))
+def test_the_cached_prefills_form_is_a_rule_of_the_shapes(widths):
+    """Under every table a chunk absorbs up to some bucket and
+    up-projects from there on: one switch as the bucket grows, and a
+    short context crosses no later than a longer one until the
+    up-projected keys and values no longer stay on the chip. Under the
+    128-block table GLM's widths then absorb at every bucket a chunk can
+    have, and LongCat's up to a whole chunk, whose context
+    ``dense_context_attention`` streams (a float32 accumulator of the
+    latent's 512 lanes a span)."""
+    buckets = (16, 32, 64, 128, 256, 512, 1024)
+    absorbing = []
+    for table in (4, 8, 16, 32, 64, 128):
+        forms = [latent_prefill_form(t, table * 64, *WIDTHS[widths])
+                 for t in buckets if t <= table * 64]
+        assert forms == sorted(forms), (table, forms)  # absorbed first
+        assert forms[0] == "absorbed"
+        absorbing.append(forms.count("absorbed"))
+    assert absorbing[:5] == sorted(absorbing[:5])  # to 64 blocks: unstreamed
+    assert absorbing == {"glm": [4, 4, 5, 5, 7, 7],
+                         "longcat": [3, 3, 4, 7, 7, 6]}[widths]
 
 
 def test_the_forms_reading_runs_at_its_tiny_size():
