@@ -31,10 +31,16 @@ What the skeleton owns stays the skeleton's: ``decoder.attend_latent``
 absorbed decode: what models/longcat.py attends through too),
 ``models/moe.py`` (``route`` and ``expert_layer``: this chip holds
 ``cfg.num_experts`` of the router's outputs, block ``cfg.layer_share``),
-``llama.rms_norm``, embedding and head. The layer loop is ONE
-``lax.scan`` over the layers whose body holds the attention once and
-each kind of MLP once behind a ``lax.cond`` (as models/laguna.py's: a
-program holds one of each whatever the depth, 47 layers here).
+``llama.rms_norm``, embedding and head. The layer loop is two
+``lax.scan``s, one over the leading dense layers and one over the sparse
+layers after them (46 of the 47 here), each body the attention and its
+own kind of MLP with no ``lax.cond``: a program holds the attention
+twice and each MLP once whatever the depth. The dense MLP is NOT a
+branch of one scan over all layers as models/laguna.py's is: its three
+matrices (126 MB) are small enough for the compiler to prefetch whole
+into VMEM ahead of the ``conditional``, which it did in every layer of
+the engine's decode burst though only layer 0 reads them (84 MB a
+layer, a quarter of the device's time: PR 46, docs/engine.md).
 
 **The prediction module** (``num_nextn_predict_layers`` 1) is
 mathematics only: :func:`init_mtp_params` and :func:`mtp_logits`. The
@@ -55,11 +61,10 @@ from typing import Dict
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from production_stack_tpu.models import decoder, llama, moe
 from production_stack_tpu.models.config import ModelConfig
-from production_stack_tpu.models.laguna import _by_layer, _replicated, _take
+from production_stack_tpu.models.laguna import _replicated, _take
 from production_stack_tpu.models.longcat import page_sides, rope_pairs
 from production_stack_tpu.models.registry import Family
 
@@ -181,8 +186,14 @@ def init_mtp_params(cfg: ModelConfig, rng: jax.Array) -> Dict:
 # One layer
 # --------------------------------------------------------------------- #
 
+@functools.partial(jax.jit, static_argnums=(0, 1))
 def _mla(cfg: ModelConfig, mode: str, x, p: Dict, kv, page_layer, batch):
-    """``x + MLA(RMS(x))`` of one layer on its own leaves."""
+    """``x + MLA(RMS(x))`` of one layer on its own leaves. Jitted so that
+    a step program traces and lowers it once though :func:`run_layers`
+    calls it from two scans (the compiler inlines both calls): lowering
+    is what a warm start pays for each of a server's ~50 step programs
+    whether the compile cache holds them or not, and a second copy of
+    the attention cost the agent cell's set-up 25 s (PR 46)."""
     B, T, _ = x.shape
     H, C = cfg.num_heads, cfg.kv_lora_rank
     N, R = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
@@ -226,12 +237,13 @@ def _experts(cfg: ModelConfig, h, layers: Dict, at, valid):
 
 def run_layers(cfg: ModelConfig, mode: str, x, params: Dict, kv_pages,
                batch: decoder.Batch):
-    """The layer loop (the module's docstring): one scan over the layers,
-    the attention leaves as its ``xs``, the dense and the sparse MLP each
-    once in its body. Returns (x, kv_pages, the expert layers' stats
-    summed over layers)."""
+    """The layer loop (the module's docstring): a scan over the
+    ``cfg.dense_layers`` leading layers, then one over the sparse layers
+    after them, each body the attention on its layer's entry of the
+    stacked leaves and its own kind of MLP, with no ``lax.cond``; the
+    layer's number runs on from one scan to the other. Returns (x,
+    kv_pages, the expert layers' stats summed over layers)."""
     L, d = cfg.num_layers, cfg.dense_layers
-    dense = np.arange(L) < d
     valid = batch.slot_mapping >= 0
 
     def dense_mlp(h, layer):
@@ -243,17 +255,28 @@ def run_layers(cfg: ModelConfig, mode: str, x, params: Dict, kv_pages,
     def sparse_mlp(h, layer):
         return _experts(cfg, h, params["moe"], layer - d, valid)
 
-    def body(carry, per_layer):
-        x, sides, stats, layer = carry
-        x, sides = _mla(cfg, mode, x, per_layer, sides, layer, batch)
-        with jax.named_scope("mlp"):
-            h = llama.rms_norm(x, per_layer["post_norm"], cfg.rms_norm_eps)
-        out, s = _by_layer(dense, layer, dense_mlp, sparse_mlp, h, layer)
-        return (x + out, tuple(sides), stats + s, layer + 1), None
+    def stretch(mlp, carry, layers):
+        """``layers`` layers on from the carry's, all of the kind ``mlp``."""
+        def body(carry, _):
+            x, sides, stats, layer = carry
+            # A slice of the stacked leaves as the scan's ``xs`` is a
+            # copy of them (2 GB of temporaries at 47 layers).
+            p = _take(params["attn"], layer)
+            x, sides = _mla(cfg, mode, x, p, sides, layer, batch)
+            with jax.named_scope("mlp"):
+                h = llama.rms_norm(x, p["post_norm"], cfg.rms_norm_eps)
+            out, s = mlp(h, layer)
+            return (x + out, tuple(sides), stats + s, layer + 1), None
+
+        # A depth with no layer of this kind has no weights of it either.
+        if not layers:
+            return carry
+        return jax.lax.scan(body, carry, None, length=layers)[0]
 
     carry = (x, tuple(kv_pages), jnp.zeros((len(moe.STATS),), jnp.int32),
              jnp.int32(0))
-    (x, sides, stats, _), _ = jax.lax.scan(body, carry, params["attn"])
+    carry = stretch(dense_mlp, carry, d)
+    x, sides, stats, _ = stretch(sparse_mlp, carry, L - d)
     return x, sides, stats
 
 

@@ -5,6 +5,12 @@ at longcat-flash-l4e16's: PR 44; of models.glm4_moe_lite.apply at
 glm-4.7-flash-e8v8's and LongCat's cached tails: PR 45), for
 a refactor that must not change the program (PR 31): run it on a copy of
 the parent and on the change and compare the digests; no chip needed.
+Each of the four later families also as the engine's decode burst nests
+it (``<name>.decode_k8``: ``apply(mode="decode")`` inside an outer scan
+of 8 steps, the pool in the carry: PR 46), because the compiler places
+operands differently in the nested program, and ``apply`` alone hid 25%
+of a cell's device time for two PRs (GLM's dense weights prefetched into
+VMEM in every layer of the burst and in no layer of ``glm.decode``).
 
     JAX_PLATFORMS=cpu python scripts/hlo_digest.py <repo-root> <out-dir>
 
@@ -126,9 +132,15 @@ from chipbench.registry import model_keys
 from production_stack_tpu.models import get_model_config
 
 
+BURST = "decode_k8"
+
+
 def family_digests(name, config, module, pool, shapes):
     """``<name>.<mode>`` for each of ``shapes`` (mode, rows, width, table
-    width); ``pool(cfg)`` gives the sides of the cache."""
+    width); ``pool(cfg)`` gives the sides of the cache. The mode
+    ``decode_k8`` is the decode step inside a scan over ``width`` steps
+    (engine/core.py::_make_multi_decode without its sampling: each
+    step's argmax is the next one's token)."""
     with open(os.path.join(root, "chipbench", "configs",
                            config + ".json")) as f:
         os.makedirs(os.path.join(out, name), exist_ok=True)
@@ -153,6 +165,25 @@ def family_digests(name, config, module, pool, shapes):
                 last_token=jnp.maximum(sl - 1, 0) if last else None,
                 with_stats=True)
 
+        def burst(p, kv, tok, pos, slots, bt, cl):
+            def step(carry, step_slots):
+                tokens, kv, s = carry
+                logits, kv, stats = module.apply(
+                    p, fcfg, tokens[:, None], (pos + s)[:, None], kv,
+                    step_slots[:, None], bt, cl + s, jnp.ones_like(cl),
+                    mode="decode", with_stats=True)
+                sampled = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
+                return (sampled, kv, s + 1), (sampled, stats)
+
+            (_, kv, _), (out, stats) = jax.lax.scan(
+                step, (tok, kv, jnp.int32(0)), slots.T)
+            return out.T, kv, stats.sum(axis=0)
+
+        if mode == BURST:
+            digest(label, burst, 1, params, pool(fcfg), spec((rows,)),
+                   spec((rows,)), spec((rows, width)), spec((rows, tables)),
+                   spec((rows,)))
+            continue
         digest(label, fn, 1, params, pool(fcfg),
                spec((rows, width)), spec((rows, width)), spec((rows, width)),
                spec((rows, tables)), spec((rows,)), spec((rows,)))
@@ -180,7 +211,7 @@ from production_stack_tpu.models import laguna
 
 family_digests("laguna", "laguna-s-2.1-l8e64", laguna, laguna_pool,
                (("decode", 128, 1, 16), ("prefill", 4, 512, 16),
-                ("prefill_cached", 1, 256, 32)))
+                ("prefill_cached", 1, 256, 32), (BURST, 128, 8, 16)))
 try:  # a tree before PR 36 has no such family
     from production_stack_tpu.models import lfm2
 except ImportError:
@@ -188,7 +219,7 @@ except ImportError:
 if lfm2 is not None:  # (PR 40)
     family_digests("lfm2", "lfm2-24b-a2b-l10", lfm2, lfm2_pool,
                    (("decode", 32, 1, 64), ("prefill", 4, 512, 8),
-                    ("prefill_cached", 1, 1024, 64)))
+                    ("prefill_cached", 1, 1024, 64), (BURST, 32, 8, 64)))
 
 
 def latent_pool(c):
@@ -214,7 +245,7 @@ if longcat is not None:  # (PR 44) the cell's chunk is 1,024 positions;
                     ("prefill_cached", 1, 1024, 32),
                     ("prefill_cached", 1, 512, 32),
                     ("prefill_cached", 1, 256, 32),
-                    ("prefill_cached", 1, 128, 32)))
+                    ("prefill_cached", 1, 128, 32), (BURST, 128, 8, 64)))
 try:  # a tree before PR 44 has no such family
     from production_stack_tpu.models import glm4_moe_lite
 except ImportError:
@@ -225,6 +256,6 @@ if glm4_moe_lite is not None:  # (PR 45) every cached bucket the agent
                    (("decode", 32, 1, 128), ("prefill", 1, 512, 8),
                     ("prefill_cached", 1, 256, 128),
                     ("prefill_cached", 1, 512, 128),
-                    ("prefill_cached", 1, 1024, 128)))
+                    ("prefill_cached", 1, 1024, 128), (BURST, 32, 8, 128)))
 with open(os.path.join(out, "digests.json"), "w") as f:
     json.dump(digests, f, indent=1)

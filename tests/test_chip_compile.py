@@ -615,28 +615,16 @@ def test_latent_decode_kernel_compiles_at_twenty_heads(one_chip, rows,
     assert program.memory_analysis().temp_size_in_bytes == 0
 
 
-@pytest.mark.parametrize("mode,rows,width,tables", [
-    ("decode", 32, 1, 128), ("prefill", 1, 1024, 16),
-    ("prefill_cached", 1, 1024, 128), ("prefill_cached", 1, 512, 128),
-    ("prefill_cached", 1, 256, 128)])
-def test_glm47_flash_programs_compile_at_the_configurations_widths(
-        one_chip, monkeypatch, tmp_path, mode, rows, width, tables):
-    """``glm-4.7-flash-e8v8`` as the benchmark serves it (the model keys
-    of its file, written to a ``config.json`` as ``chipbench.stack``
-    does), all 47 layers: the three forward programs with the expert
-    layer's counts compile for the v5e; decode holds the latent kernel at
-    20 heads (traced once: the scan's body is one layer) and every mode
-    the grouped-matmul kernel; the weights are the 10.16 GB the
-    configuration states; no expert stack and no side of the pool is
-    copied; a cached prefill under a 128-block table absorbs at every
-    bucket to a whole chunk of 1,024 (the form the chip read faster, PR
-    45), float32 scores of 0.67 GB among its temporaries; and the
-    temporaries stay under what the pool leaves free."""
+def _glm47_flash(tmp_path, monkeypatch, sharding):
+    """(cfg, the weights' shapes, ``spec``) of ``glm-4.7-flash-e8v8`` as
+    the benchmark serves it (the model keys of its file, written to a
+    ``config.json`` as ``chipbench.stack`` does), all 47 layers, with the
+    kernels the chip takes."""
     import json
     import os
     import sys
 
-    from production_stack_tpu.models import decoder, glm4_moe_lite
+    from production_stack_tpu.models import glm4_moe_lite
 
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     if repo not in sys.path:
@@ -654,12 +642,36 @@ def test_glm47_flash_programs_compile_at_the_configurations_widths(
     att.TRACED_PATHS.clear()
 
     def spec(shape, dtype=jnp.int32):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
     params = jax.tree_util.tree_map(
         lambda x: spec(x.shape, x.dtype),
         jax.eval_shape(
             lambda: glm4_moe_lite.init_params(cfg, jax.random.key(0))))
+    return cfg, params, spec
+
+
+@pytest.mark.parametrize("mode,rows,width,tables", [
+    ("decode", 32, 1, 128), ("prefill", 1, 1024, 16),
+    ("prefill_cached", 1, 1024, 128), ("prefill_cached", 1, 512, 128),
+    ("prefill_cached", 1, 256, 128)])
+def test_glm47_flash_programs_compile_at_the_configurations_widths(
+        one_chip, monkeypatch, tmp_path, mode, rows, width, tables):
+    """``glm-4.7-flash-e8v8`` as the benchmark serves it, all 47 layers:
+    the three forward programs with the expert layer's counts compile
+    for the v5e; decode holds the latent kernel at 20 heads (traced once
+    a program: the layer loop is two scans, the dense layer's and the
+    46 sparse layers', and both call the one jitted ``_mla``) and every
+    mode the grouped-matmul kernel; the weights are the 10.16 GB the configuration states; no
+    expert stack and no side of the pool is copied; a cached prefill
+    under a 128-block table absorbs at every bucket to a whole chunk of
+    1,024 (the form the chip read faster, PR 45), float32 scores of
+    0.67 GB among its temporaries; and the temporaries stay under what
+    the pool leaves free (the attention stacks sliced ``[1:]`` for the
+    sparse layers' scan were 2 GB of them, PR 46)."""
+    from production_stack_tpu.models import decoder, glm4_moe_lite
+
+    cfg, params, spec = _glm47_flash(tmp_path, monkeypatch, one_chip)
     weights = sum(int(np.prod(x.shape)) * x.dtype.itemsize
                   for x in jax.tree_util.tree_leaves(params))
     assert abs(weights / 10.16e9 - 1) < 0.01
@@ -693,6 +705,94 @@ def test_glm47_flash_programs_compile_at_the_configurations_widths(
     assert not copied, copied
     assert program.memory_analysis().temp_size_in_bytes < (
         0.2e9 if mode == "decode" else 1.2e9)
+
+
+def _while_bodies(text):
+    """{name: lines} of the computations that some ``while`` of the
+    optimised HLO ``text`` names as its body."""
+    computations, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$", line)
+        if head:
+            name = head.group(1)
+            computations[name] = []
+        elif name is not None:
+            computations[name].append(line)
+    return {body: computations[body]
+            for body in set(re.findall(r"body=%?([\w.\-]+)", text))}
+
+
+def _bytes_copied(line):
+    """Bytes of the buffer a ``copy-start`` line moves (its first result)."""
+    dtype, dims = re.search(r"= \((\w+)\[([\d,]*)\]", line).groups()
+    bits = int(re.sub(r"\D", "", dtype) or 8)  # pred: a byte
+    return bits // 8 * int(np.prod([int(d) for d in dims.split(",") if d]))
+
+
+def test_glm47_flash_decode_burst_prefetches_no_weight_in_the_layer_loop(
+        one_chip, monkeypatch, tmp_path):
+    """``glm-4.7-flash-e8v8``'s decode step **nested as the engine nests
+    it** (engine/core.py::_make_multi_decode: ``apply(mode="decode")``
+    inside a scan over the burst's 8 steps, the pool in the carry, each
+    step's token fed to the next), at the configuration's full depth
+    and pool (a compile of ~10 s, a scan's body compiles once; at 1 + 2
+    layers the parent's compiler made no such prefetch, so the case
+    cannot be cut in depth): no ``while`` body holds a ``copy-start``
+    over 1 MB but the step loop's own one prefetch of the dense layer's
+    matrices, and the layer loop holds no ``conditional``. With the
+    dense MLP behind a ``lax.cond`` in one scan over all 47 layers the
+    compiler prefetched two of its matrices into VMEM ahead of the
+    ``conditional`` in every layer, 2 x 41.9 MB x 47 = 3.9 GB a forward,
+    a quarter of the cell's device time, and ``apply`` compiled alone
+    never showed it (PR 46)."""
+    from production_stack_tpu.models import glm4_moe_lite
+
+    cfg, params, spec = _glm47_flash(tmp_path, monkeypatch, one_chip)
+    rows, steps, tables, blocks = 32, 8, 128, 1140
+
+    def burst(p, kv, tokens, positions, slots, block_tables, contexts):
+        def step(carry, step_slots):
+            tokens, kv, s = carry
+            logits, kv, stats = glm4_moe_lite.apply(
+                p, cfg, tokens[:, None], (positions + s)[:, None], kv,
+                step_slots[:, None], block_tables, contexts + s,
+                jnp.ones_like(contexts), mode="decode", with_stats=True)
+            sampled = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
+            return (sampled, kv, s + 1), (sampled, stats)
+
+        (_, kv, _), (out, stats) = jax.lax.scan(
+            step, (tokens, kv, jnp.int32(0)), slots.T)
+        return out.T, kv, stats.sum(axis=0)
+
+    pages = (spec((47, blocks, BLOCK_SIZE, 1, 512), jnp.bfloat16),
+             spec((47, blocks, BLOCK_SIZE, 1, 128), jnp.bfloat16))
+    program = jax.jit(burst, donate_argnums=(1,)).lower(
+        params, pages, spec((rows,)), spec((rows,)), spec((rows, steps)),
+        spec((rows, tables)), spec((rows,))).compile()
+    text = program.as_text()
+    assert "pallas_mla_decode" in text
+    bodies = _while_bodies(text)
+    # the burst's step loop holds the layer loop; the layer loop holds
+    # the latent decode kernel and no further loop
+    layer_loops = [lines for lines in bodies.values()
+                   if any("pallas_mla_decode" in line for line in lines)
+                   and not any(" while(" in line for line in lines)]
+    assert len(layer_loops) == 1 and len(bodies) == 2
+
+    def large_copies(lines):
+        return [line.strip()[:100] for line in lines
+                if " copy-start(" in line and _bytes_copied(line) > 1 << 20]
+
+    in_layer_loop = {
+        "conditional": [line.strip()[:100] for line in layer_loops[0]
+                        if " conditional(" in line],
+        "copy-start over 1 MB": large_copies(layer_loops[0])}
+    assert not any(in_layer_loop.values()), in_layer_loop
+    # what the step loop holds is the dense layer's own read, once a
+    # forward: the bytes the model owes
+    once = [line for lines in bodies.values() for line in large_copies(lines)]
+    assert all("10240" in line for line in once) and len(once) <= 3, once
+    assert program.memory_analysis().temp_size_in_bytes < 0.2e9
 
 
 @pytest.mark.parametrize("shape", [

@@ -13,6 +13,8 @@ the test dtype fails by two orders of magnitude
 (``test_bf16_fails_the_tolerance``).
 """
 
+import dataclasses
+import functools
 import json
 import os
 import queue
@@ -35,6 +37,8 @@ from production_stack_tpu.models import (
     decoder,
     get_model_config,
     glm4_moe_lite,
+    laguna,
+    llama,
     moe,
 )
 from production_stack_tpu.models.decoder import (
@@ -56,6 +60,7 @@ if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
 from chipbench.reference import glm4_moe_lite as reference  # noqa: E402
+import test_longcat  # noqa: E402
 from test_longcat import BS, _latent_case, _Rows  # noqa: E402
 
 SEED = 13
@@ -323,6 +328,88 @@ def test_decode_through_the_latent_pages_holds_the_reference(
     for t in range(57):
         np.testing.assert_allclose(rows.logp[0][t], wanted[0][0, t],
                                    atol=TOL, rtol=0)
+
+
+def _one_scan_behind_a_cond(cfg, mode, x, params, kv_pages, batch):
+    """``glm4_moe_lite.run_layers`` as it was before PR 46, kept here:
+    ONE scan over all layers, the attention leaves as its ``xs``, the
+    dense and the sparse MLP each behind a ``lax.cond`` in its body."""
+    L, d = cfg.num_layers, cfg.dense_layers
+    dense = np.arange(L) < d
+    valid = batch.slot_mapping >= 0
+
+    def dense_mlp(h, layer):
+        w = laguna._take(params["dense"], layer)
+        return (moe.swiglu(h, w["w_gate"], w["w_up"], w["w_down"]),
+                jnp.zeros((len(moe.STATS),), jnp.int32))
+
+    def sparse_mlp(h, layer):
+        return glm4_moe_lite._experts(cfg, h, params["moe"], layer - d,
+                                      valid)
+
+    def body(carry, per_layer):
+        x, sides, stats, layer = carry
+        x, sides = glm4_moe_lite._mla(cfg, mode, x, per_layer, sides, layer,
+                                      batch)
+        h = llama.rms_norm(x, per_layer["post_norm"], cfg.rms_norm_eps)
+        out, s = laguna._by_layer(dense, layer, dense_mlp, sparse_mlp, h,
+                                  layer)
+        return (x + out, tuple(sides), stats + s, layer + 1), None
+
+    carry = (x, tuple(kv_pages), jnp.zeros((len(moe.STATS),), jnp.int32),
+             jnp.int32(0))
+    (x, sides, stats, _), _ = jax.lax.scan(body, carry, params["attn"])
+    return x, sides, stats
+
+
+def _through_the_three_modes(cfg, params, sequences):
+    """Rows 0 and 2 driven through every mode: a plain prefill, cached
+    chunks in both forms (16 wide absorbs, 32 wide up-projects: the last
+    one padded), decode steps beside an idle row."""
+    rows = _Rows(cfg, params, sequences)
+    rows.span("prefill", [(0, 0, 24), (2, 0, 30)], width=32)
+    rows.span("prefill_cached", [(0, 24, 40)], width=16)
+    rows.span("prefill_cached", [(0, 40, 61)], width=32)
+    for step in range(7):
+        rows.span("decode", [(2, 30 + step, 31 + step)], idle_rows=1)
+    return rows
+
+
+@pytest.mark.parametrize("dense_layers", [1, 2])
+def test_a_dense_prefix_then_a_scan_is_the_one_scan_behind_a_cond(
+        tmp_path, monkeypatch, sequences, dense_layers):
+    """The layer loop as two scans (the dense prefix, then the sparse
+    layers with no ``lax.cond``: PR 46) in the three modes, at one
+    leading dense layer and at two (a prefix longer than one): held to
+    the reference, and bit for bit what the single scan with both MLPs
+    behind a ``cond`` gives, logits and both sides of the pool."""
+    cfg = get_model_config(_model_dir(
+        tmp_path, first_k_dense_replace=dense_layers)).replace(
+        dtype="float32")
+    assert (cfg.dense_layers, cfg.num_layers) == (dense_layers, LAYERS)
+    params = build_model(cfg)[0](cfg, jax.random.key(SEED))
+    assert params["dense"]["w_up"].shape[0] == dense_layers
+    assert params["moe"]["router"].shape[0] == LAYERS - dense_layers
+    wanted = reference.forward(
+        {**HF, "first_k_dense_replace": dense_layers}, SEED,
+        _padded(sequences), [len(s) for s in sequences], keep_from=0,
+        dtype="float32", kv_layers=tuple(range(LAYERS)))
+    rows = _through_the_three_modes(cfg, params, sequences)
+    _hold(rows, wanted, 0, range(61))
+    _hold(rows, wanted, 2, range(37))
+
+    before = functools.partial(decoder.apply, dataclasses.replace(
+        glm4_moe_lite.FAMILY, loop=_one_scan_behind_a_cond))
+    monkeypatch.setattr(test_longcat, "_jitted_apply", lambda cfg: jax.jit(
+        lambda params, *args, mode: before(params, cfg, *args, mode=mode),
+        static_argnames=("mode",)))
+    single = _through_the_three_modes(cfg, params, sequences)
+    for mine, theirs in zip(rows.kv, single.kv):
+        np.testing.assert_array_equal(mine, theirs)
+    for row in (0, 2):
+        assert rows.logp[row].keys() == single.logp[row].keys()
+        for t, logp in rows.logp[row].items():
+            np.testing.assert_array_equal(logp, single.logp[row][t])
 
 
 def test_bf16_fails_the_tolerance(params, sequences, wanted):
