@@ -12,21 +12,30 @@ A family (models/registry.py::Family) brings ``embed``, ``layer`` and
   (MLA) has its sibling :func:`attend_latent`, the only other. A state
   per cache block beside the pages (a short convolution's last inputs)
   goes through :func:`read_block_state` and :func:`write_block_state`;
-- :func:`scan_layers`: one ``lax.scan`` over the layer-stacked leaves
-  (single-layer trace, fast compiles even at 80 layers) with the carry
-  convention of the paged pool;
-- :func:`apply`: embed -> layers (that scan, or the loop of a family
-  whose layers are of several kinds) -> the ``last_token`` slice -> head.
+- :func:`scan_layers`: THE layer loop, one ``lax.scan`` over a stretch
+  of consecutive layers (single-layer trace, fast compiles even at 80
+  layers) with the carry convention of the paged pool. A family whose
+  layers are of several kinds says what they are (``Family.loop``) and
+  hands them to it: :func:`by_layer` chooses between two kinds by the
+  layer's number, :func:`take` reads a layer's leaves of a stack,
+  :func:`index_in_kind` numbers the layers of each kind, and the rules
+  the chip taught about each are written beside them, once;
+- :func:`latent_attention`: the layer part both latent families share;
+- :func:`apply`: embed -> layers (that scan, stepped by ``Family.layer``
+  or by the family's own ``loop``) -> the ``last_token`` slice -> head.
   ``models.<family>.apply`` is this function bound to the family, and
   ``parallel/pp_serving.py`` runs the same three parts as pipeline stages.
 """
 
 from __future__ import annotations
 
-from typing import NamedTuple, Tuple
+import functools
+import math
+from typing import Dict, NamedTuple, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from production_stack_tpu.models.config import ModelConfig
 from production_stack_tpu.ops import attention as paged
@@ -254,6 +263,72 @@ def latent_prefill_form(new_tokens: int, context: int, heads: int,
     return "absorbed" if absorbed < up_projected else "up_projected"
 
 
+def rms_norm(x: jax.Array, weight: jax.Array, eps: float) -> jax.Array:
+    dtype = x.dtype
+    x = x.astype(jnp.float32)
+    var = jnp.mean(x * x, axis=-1, keepdims=True)
+    x = x * jax.lax.rsqrt(var + eps)
+    return (x * weight.astype(jnp.float32)).astype(dtype)
+
+
+def rope_pairs(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+    """Rotary embedding over all of the last axis, adjacent lanes
+    ``(2j, 2j + 1)`` rotating together and staying where they are.
+    ``x [B, T, ..., R]``, ``positions [B, T]``."""
+    rot = x.shape[-1]
+    inv_freq = (theta ** (-np.arange(0, rot, 2, dtype=np.float64) / rot)
+                ).astype(np.float32)
+    angles = positions[..., None].astype(jnp.float32) * inv_freq
+    angles = angles.reshape(angles.shape[:2] + (1,) * (x.ndim - 3)
+                            + angles.shape[-1:])
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    pairs = x.astype(jnp.float32).reshape(x.shape[:-1] + (rot // 2, 2))
+    even, odd = pairs[..., 0], pairs[..., 1]
+    out = jnp.stack([even * cos - odd * sin, odd * cos + even * sin], -1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
+def latent_page_sides(cfg: ModelConfig):
+    """``Family.page_sides`` of a latent cache: one normed latent and one
+    rotated key a token and page layer."""
+    return (1, cfg.kv_lora_rank), (1, cfg.qk_rope_head_dim)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def latent_attention(cfg: ModelConfig, mode: str, x, p: Dict, kv, page_layer,
+                     batch: Batch):
+    """``x + MLA(RMS(x))`` of one (sub)layer on its own leaves ``p``,
+    through :func:`attend_latent`; ``cfg.mla_scale_*`` put LongCat's
+    factors on the two latents. Jitted so that a step program traces and
+    lowers it once though a family calls it from two stretches (the
+    compiler inlines the calls): a warm start lowers each of a server's
+    ~50 step programs, cache hit or not, and a second copy of the
+    attention cost GLM's cell 25 s of ``setup_s`` (PR 46)."""
+    B, T, Hd = x.shape
+    H, C = cfg.num_heads, cfg.kv_lora_rank
+    N, R = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    with jax.named_scope("mla_proj"):
+        h = rms_norm(x, p["in_norm"], cfg.rms_norm_eps)
+        cq = rms_norm(h @ p["wq_a"], p["q_norm"], cfg.rms_norm_eps)
+        q = jnp.einsum("btq,oq->bto", cq, p["wq_b"]).reshape(
+            B, T, H, N + R)
+        if cfg.mla_scale_q_lora:
+            q = (q.astype(jnp.float32)
+                 * math.sqrt(Hd / cfg.q_lora_rank)).astype(x.dtype)
+        t = h @ p["wkv_a"]
+        c = rms_norm(t[..., :C], p["kv_norm"], cfg.rms_norm_eps)
+        # assumed (both families): adjacent lanes rotate, in place.
+        q_rope = rope_pairs(q[..., N:], batch.positions, cfg.rope_theta)
+        k_rope = rope_pairs(t[..., C:], batch.positions, cfg.rope_theta)
+    attn, kv = attend_latent(
+        mode, q[..., :N], q_rope, c, k_rope, p["wkv_b"], kv, page_layer,
+        batch, scale=(N + R) ** -0.5,
+        latent_scale=(math.sqrt(Hd / C) if cfg.mla_scale_kv_lora else 1.0))
+    with jax.named_scope("mla_proj"):
+        x = x + attn.reshape(B, T, -1) @ p["wo"]
+    return x, kv
+
+
 def read_block_state(state: jax.Array, at, batch: Batch, block_size: int):
     """The state each row's chunk begins from: ``[B, rows, width]`` of
     entry ``at`` (a layer of the pool's third side ``[layers, NB, rows,
@@ -310,28 +385,111 @@ def write_block_state(state: jax.Array, at, batch: Batch, block_size: int,
     return flat.reshape(state.shape)
 
 
-def scan_layers(layer_fn, x: jax.Array, kv_pages: Tuple, xs):
-    """``layer_fn(x, per_layer, kv, l) -> (x, kv)`` over the leading axis
-    of every leaf of ``xs`` (``None`` is an empty pytree: a family
-    without LoRA slots scans ``(layers, None)``).
+def first_carry(x: jax.Array, sides: Tuple, counted: Sequence[str] = (),
+                *extra):
+    """The carry :func:`scan_layers` starts a forward from, ``(x, sides,
+    *extra, counts, layer)``: layer 0, and a zero for each name of
+    ``counted`` (``Family.stats``; None where nothing is counted)."""
+    counts = jnp.zeros((len(counted),), jnp.int32) if counted else None
+    return (x, tuple(sides), *extra, counts, jnp.int32(0))
 
-    The STACKED KV pages ride the scan carry whole; every op addresses
-    them through the scalar layer index (flat scatter / page-level
-    gather). Loop carries alias in place under XLA, so only the touched
-    pages move: per-layer slices (or pages in the scan ys) would copy
-    the entire pool every forward step. With an int8 cache each side is
-    a (data, scales) tuple that rides the carry the same way, and a
-    third side (a state per block, ``Family.block_state``) rides beside
-    the two as one more entry of ``kv_pages``."""
+
+def scan_layers(step, carry: Tuple, layers: int | None = None, xs=None):
+    """One *stretch* of the layer loop: ``layers`` consecutive layers on
+    from the carry's (:func:`first_carry`), ONE ``lax.scan`` whose body is
+    ``step(x, sides, layer, per_layer, *extra) -> (x, sides, counts,
+    *extra)``. Returns the carry, for the next stretch to go on from; a
+    stretch of no layers returns it as it is.
+
+    - The layer's number is counted in the carry; ``counts`` (an int32
+      entry a name of ``Family.stats``, or None) are summed over the
+      stretch; ``extra`` is what one layer hands the next beside ``x``.
+    - ``sides``: the STACKED KV pages ride the carry whole; every op
+      addresses them through the scalar layer index (flat scatter /
+      page-level gather). Loop carries alias in place under XLA, so only
+      the touched pages move: per-layer slices (or pages in the scan's
+      ys) would copy the entire pool every forward step. An int8 side is
+      a (data, scales) tuple and a third side (``Family.block_state``)
+      one more entry, riding the same way.
+    - ``xs``: WHOLE ``[L, ...]`` stacks the scan slices a layer at a time
+      into ``per_layer`` (Llama's leaves and LoRA slots, LongCat's ``[2L,
+      ...]`` sublayers). A part of a stack, ``leaf[d:]``, is a COPY of the
+      leaves there (2 GB of temporaries at GLM's 47 layers, PR 46): read
+      it with :func:`take` at the layer's number.
+
+    **Stretches.** Kinds of layer that do not interleave can each be a
+    stretch with no ``lax.cond`` between them (GLM: the dense prefix, then
+    the sparse layers). Cut where a kind few layers take has small
+    operands: the compiler prefetches a ``conditional``'s operands into
+    VMEM in EVERY layer, read or not (GLM's dense MLP, 84 MB a layer, a
+    quarter of the device's time: ``tpot_p50_s`` 0.017064 -> 0.011392, PR
+    46), seen only in the program nested as the engine's burst nests it
+    (``scripts/hlo_digest.py``'s ``<name>.decode_k8``: ``copy-start`` with
+    ``S(1)`` in the loop's body). Do not cut without that reading: every
+    stretch is one more body in every step program, and the machine's
+    compile cache holds 192 MiB (PR 33). A part two stretches share goes
+    under ``jax.jit`` (:func:`latent_attention`)."""
+    if layers == 0:
+        return carry
 
     def body(carry, per_layer):
-        x, sides, l = carry
-        x, sides = layer_fn(x, per_layer, sides, l)
-        return (x, tuple(sides), l + 1), None
+        x, sides, *extra, counts, layer = carry
+        x, sides, counted, *extra = step(x, sides, layer, per_layer, *extra)
+        if counts is not None:
+            counts = counts + counted
+        return (x, tuple(sides), *extra, counts, layer + 1), None
 
-    (x, sides, _), _ = jax.lax.scan(
-        body, (x, tuple(kv_pages), jnp.int32(0)), xs)
-    return x, sides
+    return jax.lax.scan(body, carry, xs, length=layers)[0]
+
+
+def take(stack: Dict, index) -> Dict:
+    """One layer's leaves of a stack ``{name: [n, ...]}`` at a traced
+    index: a dynamic slice of the whole stack, read in place."""
+    return {k: jax.lax.dynamic_index_in_dim(v, index, 0, keepdims=False)
+            for k, v in stack.items()}
+
+
+def index_in_kind(kinds: Sequence) -> jax.Array:
+    """``at [L]``: layer ``l`` is the ``at[l]``-th of its kind, the entry
+    it reads of its kind's stack, pages or state."""
+    return jnp.asarray([list(kinds[:l]).count(kind)
+                        for l, kind in enumerate(kinds)], jnp.int32)
+
+
+def by_layer(flags: np.ndarray, layer, if_true, if_false, *operands):
+    """``if_true(*operands)`` for the layers ``flags [L]`` marks,
+    ``if_false`` for the others: the one branch itself where only one
+    kind occurs, ``lax.cond`` on the traced ``layer`` where both do.
+
+    An operand that a branch of the ``cond`` hands back as it got it (a
+    side of the pool its kind does not use) is COPIED there, whole, in
+    every layer: the conditional's result is a buffer of its own unless
+    every branch updates the operand in place, and ``memory_analysis()``
+    shows nothing of it (1.5 s of a 2 s trace in LFM2's first run on the
+    chip, PR 36). So what a branch returns as the very object it received
+    goes through a scatter whose one index is dropped, which updates in
+    place and moves nothing; the branch need not know."""
+    if flags.all() or not flags.any():
+        return (if_true if flags.all() else if_false)(*operands)
+
+    def written_nowhere(side):
+        flat = side.reshape((-1,) + side.shape[2:])
+        return flat.at[jnp.full((1,), flat.shape[0])].set(
+            jnp.zeros((1,) + flat.shape[1:], flat.dtype),
+            mode="drop").reshape(side.shape)
+
+    def in_place(branch):
+        def run(*operands):
+            received = {id(leaf)
+                        for leaf in jax.tree_util.tree_leaves(operands)}
+            return jax.tree_util.tree_map(
+                lambda leaf: (written_nowhere(leaf)
+                              if id(leaf) in received else leaf),
+                branch(*operands))
+        return run
+
+    return jax.lax.cond(jnp.asarray(flags)[layer], in_place(if_true),
+                        in_place(if_false), *operands)
 
 
 def take_last_token(x: jax.Array, last_token: jax.Array | None):
@@ -385,14 +543,13 @@ def apply(
         whole = {k: layers[k] for k in family.whole_leaves}
         sliced = {k: v for k, v in layers.items() if k not in whole}
 
-        def layer_fn(x, per_layer, kv, l):
+        def step(x, kv, l, per_layer):
             p, lora = per_layer
-            return family.layer(cfg, mode, x, ({**p, **whole}, lora), kv, l,
-                                batch)
+            return *family.layer(cfg, mode, x, ({**p, **whole}, lora), kv, l,
+                                 batch), None
 
-        x, kv_pages = scan_layers(layer_fn, x, kv_pages,
-                                  (sliced, lora_layers))
-        stats = None
+        x, kv_pages, stats, _ = scan_layers(
+            step, first_carry(x, kv_pages), xs=(sliced, lora_layers))
     x = take_last_token(x, last_token)
     out = family.head(params, cfg, x, output_hidden)
     return (out, kv_pages, stats) if with_stats else (out, kv_pages)

@@ -31,16 +31,13 @@ What the skeleton owns stays the skeleton's: ``decoder.attend_latent``
 absorbed decode: what models/longcat.py attends through too),
 ``models/moe.py`` (``route`` and ``expert_layer``: this chip holds
 ``cfg.num_experts`` of the router's outputs, block ``cfg.layer_share``),
-``llama.rms_norm``, embedding and head. The layer loop is two
-``lax.scan``s, one over the leading dense layers and one over the sparse
-layers after them (46 of the 47 here), each body the attention and its
-own kind of MLP with no ``lax.cond``: a program holds the attention
-twice and each MLP once whatever the depth. The dense MLP is NOT a
-branch of one scan over all layers as models/laguna.py's is: its three
-matrices (126 MB) are small enough for the compiler to prefetch whole
-into VMEM ahead of the ``conditional``, which it did in every layer of
-the engine's decode burst though only layer 0 reads them (84 MB a
-layer, a quarter of the device's time: PR 46, docs/engine.md).
+``llama.rms_norm``, embedding and head, and ``decoder.latent_attention``
+(the layer part LongCat shares). The layers are two stretches of
+``decoder.scan_layers``, the leading dense layers and the sparse layers
+after them (46 of the 47 here), with no ``lax.cond``: behind one, the
+dense MLP's three matrices (126 MB) were prefetched into VMEM in every
+layer of the engine's decode burst though only layer 0 reads them (PR
+46; the rule is ``scan_layers``').
 
 **The prediction module** (``num_nextn_predict_layers`` 1) is
 mathematics only: :func:`init_mtp_params` and :func:`mtp_logits`. The
@@ -64,9 +61,8 @@ import jax.numpy as jnp
 
 from production_stack_tpu.models import decoder, llama, moe
 from production_stack_tpu.models.config import ModelConfig
-from production_stack_tpu.models.laguna import _replicated, _take
-from production_stack_tpu.models.longcat import page_sides, rope_pairs
-from production_stack_tpu.models.registry import Family
+from production_stack_tpu.models.moe import EXPERT_STACKS
+from production_stack_tpu.models.registry import Family, replicated
 
 ROUTER_EPS = 1e-20
 # The spread of the router's selection bias around zero and of every
@@ -78,7 +74,6 @@ SPREAD = 0.1
 ATTN_LEAVES = (("in_norm", 2), ("post_norm", 2), ("wq_a", 3), ("q_norm", 2),
                ("wq_b", 3), ("wkv_a", 3), ("kv_norm", 2), ("wkv_b", 4),
                ("wo", 3))
-EXPERT_STACKS = ("w_gate", "w_up", "w_down")
 
 
 # --------------------------------------------------------------------- #
@@ -186,45 +181,14 @@ def init_mtp_params(cfg: ModelConfig, rng: jax.Array) -> Dict:
 # One layer
 # --------------------------------------------------------------------- #
 
-@functools.partial(jax.jit, static_argnums=(0, 1))
-def _mla(cfg: ModelConfig, mode: str, x, p: Dict, kv, page_layer, batch):
-    """``x + MLA(RMS(x))`` of one layer on its own leaves. Jitted so that
-    a step program traces and lowers it once though :func:`run_layers`
-    calls it from two scans (the compiler inlines both calls): lowering
-    is what a warm start pays for each of a server's ~50 step programs
-    whether the compile cache holds them or not, and a second copy of
-    the attention cost the agent cell's set-up 25 s (PR 46)."""
-    B, T, _ = x.shape
-    H, C = cfg.num_heads, cfg.kv_lora_rank
-    N, R = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
-    with jax.named_scope("mla_proj"):
-        h = llama.rms_norm(x, p["in_norm"], cfg.rms_norm_eps)
-        cq = llama.rms_norm(h @ p["wq_a"], p["q_norm"], cfg.rms_norm_eps)
-        q = jnp.einsum("btq,oq->bto", cq, p["wq_b"]).reshape(
-            B, T, H, N + R)
-        t = h @ p["wkv_a"]
-        c = llama.rms_norm(t[..., :C], p["kv_norm"], cfg.rms_norm_eps)
-        # assumed: the rotary convention (adjacent lanes, in place).
-        q_rope = rope_pairs(q[..., N:], batch.positions, cfg.rope_theta)
-        k_rope = rope_pairs(t[..., C:], batch.positions, cfg.rope_theta)
-    attn, kv = decoder.attend_latent(
-        mode, q[..., :N], q_rope, c, k_rope, p["wkv_b"], kv, page_layer,
-        batch, scale=(N + R) ** -0.5)
-    with jax.named_scope("mla_proj"):
-        x = x + attn.reshape(B, T, -1) @ p["wo"]
-    return x, kv
-
-
 def _experts(cfg: ModelConfig, h, layers: Dict, at, valid):
     """Expert layer ``at`` of the stacked leaves ``layers`` on the normed
     ``h``: the held experts' weighted sum (the experts' stacks reach the
     grouped matmul whole, with the layer's index: models/moe.py), the
     shared expert whole."""
-    p = _take({k: v for k, v in layers.items() if k not in EXPERT_STACKS},
-              at)
+    p, weights = moe.sparse_leaves(layers, at)
     routed, stats = moe.expert_layer(
-        h, {"router": p["router"], **{k: layers[k] for k in EXPERT_STACKS}},
-        at=at, k=cfg.experts_per_token, share=cfg.layer_share,
+        h, weights, at=at, k=cfg.experts_per_token, share=cfg.layer_share,
         scaling=cfg.routed_scaling, valid=valid,
         routing={"scoring": cfg.router_scoring, "bias": p["router_bias"],
                  "eps": ROUTER_EPS})
@@ -237,46 +201,35 @@ def _experts(cfg: ModelConfig, h, layers: Dict, at, valid):
 
 def run_layers(cfg: ModelConfig, mode: str, x, params: Dict, kv_pages,
                batch: decoder.Batch):
-    """The layer loop (the module's docstring): a scan over the
-    ``cfg.dense_layers`` leading layers, then one over the sparse layers
-    after them, each body the attention on its layer's entry of the
-    stacked leaves and its own kind of MLP, with no ``lax.cond``; the
-    layer's number runs on from one scan to the other. Returns (x,
-    kv_pages, the expert layers' stats summed over layers)."""
+    """What the layers are (``Family.loop``; the module's docstring): the
+    latent attention, then the dense MLP in the ``cfg.dense_layers``
+    leading layers and the expert layer after them, a stretch each.
+    Returns (x, kv_pages, the expert layers' stats summed over layers)."""
     L, d = cfg.num_layers, cfg.dense_layers
     valid = batch.slot_mapping >= 0
 
     def dense_mlp(h, layer):
         with jax.named_scope("mlp"):
-            w = _take(params["dense"], layer)
-            return (moe.swiglu(h, w["w_gate"], w["w_up"], w["w_down"]),
-                    jnp.zeros((len(moe.STATS),), jnp.int32))
+            return moe.dense_layer(h, params["dense"], layer)
 
     def sparse_mlp(h, layer):
         return _experts(cfg, h, params["moe"], layer - d, valid)
 
-    def stretch(mlp, carry, layers):
-        """``layers`` layers on from the carry's, all of the kind ``mlp``."""
-        def body(carry, _):
-            x, sides, stats, layer = carry
-            # A slice of the stacked leaves as the scan's ``xs`` is a
-            # copy of them (2 GB of temporaries at 47 layers).
-            p = _take(params["attn"], layer)
-            x, sides = _mla(cfg, mode, x, p, sides, layer, batch)
+    def layer_with(mlp):
+        def layer_step(x, sides, layer, _):
+            p = decoder.take(params["attn"], layer)
+            x, sides = decoder.latent_attention(cfg, mode, x, p, sides,
+                                                layer, batch)
             with jax.named_scope("mlp"):
                 h = llama.rms_norm(x, p["post_norm"], cfg.rms_norm_eps)
             out, s = mlp(h, layer)
-            return (x + out, tuple(sides), stats + s, layer + 1), None
+            return x + out, sides, s
+        return layer_step
 
-        # A depth with no layer of this kind has no weights of it either.
-        if not layers:
-            return carry
-        return jax.lax.scan(body, carry, None, length=layers)[0]
-
-    carry = (x, tuple(kv_pages), jnp.zeros((len(moe.STATS),), jnp.int32),
-             jnp.int32(0))
-    carry = stretch(dense_mlp, carry, d)
-    x, sides, stats, _ = stretch(sparse_mlp, carry, L - d)
+    carry = decoder.first_carry(x, kv_pages, moe.STATS)
+    carry = decoder.scan_layers(layer_with(dense_mlp), carry, d)
+    x, sides, stats, _ = decoder.scan_layers(layer_with(sparse_mlp), carry,
+                                             L - d)
     return x, sides, stats
 
 
@@ -314,8 +267,9 @@ def mtp_logits(params: Dict, mtp: Dict, cfg: ModelConfig, next_tokens,
         positions, jnp.full((B, T), -1, jnp.int32),
         jnp.zeros((B, 1), jnp.int32), jnp.full((B,), T, jnp.int32),
         jnp.full((B,), T, jnp.int32))
-    layer = _take(mtp["attn"], 0)
-    x, _ = _mla(cfg, "prefill", x, layer, sides, jnp.int32(0), batch)
+    layer = decoder.take(mtp["attn"], 0)
+    x, _ = decoder.latent_attention(cfg, "prefill", x, layer, sides,
+                                    jnp.int32(0), batch)
     h = llama.rms_norm(x, layer["post_norm"], eps)
     out, _ = _experts(cfg, h, mtp["moe"], 0, None)
     return llama.project_out(
@@ -326,21 +280,6 @@ def mtp_logits(params: Dict, mtp: Dict, cfg: ModelConfig, next_tokens,
 # --------------------------------------------------------------------- #
 # The record
 # --------------------------------------------------------------------- #
-
-def load_checkpoint(cfg: ModelConfig, path: str) -> Dict:
-    raise NotImplementedError(
-        "no checkpoint loader for the glm4_moe_lite family yet: it waits "
-        "until a checkpoint is in the repository or on the machine; a "
-        "directory with config.json alone is served with random weights "
-        "from --seed")
-
-
-def _no_single_layer(*args, **kwargs):
-    raise NotImplementedError(
-        "glm4_moe_lite's layers are of two kinds (a leading dense MLP, "
-        "then expert layers): models/glm4_moe_lite.py::run_layers is its "
-        "loop, and it has no pipeline stages yet")
-
 
 def config_fields(hf: dict, layers: int) -> dict:
     """The ``ModelConfig`` fields this family reads of its own keys. A
@@ -386,15 +325,13 @@ FAMILY = Family(
     model_types=("glm4_moe_lite",),
     init_params=init_params,
     embed=llama.FAMILY.embed,
-    layer=_no_single_layer,
     loop=run_layers,
     head=llama.project_out,
-    load=load_checkpoint,
     # Every leaf replicated: no tensor-parallel rules yet, and the engine
     # refuses a mesh of several devices for a family with its own page
     # sides (a latent has no heads to shard; the ``ep`` axis of ROADMAP
     # M1 would split the expert stacks' second axis).
-    specs=_replicated(
+    specs=replicated(
         (("embed",), 2), (("final_norm",), 1), (("lm_head",), 2),
         *((("attn", leaf), rank) for leaf, rank in ATTN_LEAVES),
         *((("dense", leaf), 3) for leaf in EXPERT_STACKS),
@@ -404,7 +341,7 @@ FAMILY = Family(
           for leaf in ("shared_gate", "shared_up", "shared_down"))),
     stats=moe.STATS,
     config_fields=config_fields,
-    page_sides=page_sides,
+    page_sides=decoder.latent_page_sides,
 )
 
 apply = functools.partial(decoder.apply, FAMILY)

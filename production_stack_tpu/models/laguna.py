@@ -16,15 +16,10 @@ its ``_split_qkv`` barrier, embedding and head. What this module brings:
   ``dense`` ``[dense_layers, ...]``; router, routed and shared experts
   under ``moe`` ``[sparse layers, ...]``. No kind is padded to another's
   width.
-- **One program body per kind of layer, whatever the depth.** The layer
-  loop is ONE ``lax.scan`` over the layers. Its body holds each kind of
-  attention and each kind of MLP once, and a layer picks its own by its
-  number (``lax.cond``), reading its leaves at its own index of its
-  kind's stack (a dynamic slice, read in place). A program so holds one
-  full and one sliding attention, the dense MLP and one expert layer
-  however many layers there are: compile time and the executable's size
-  (which the persistent compile cache has to hold beside the other
-  configurations' programs, PERF.md section 6) do not grow with depth.
+- **One program body per kind of layer, whatever the depth**: one
+  stretch of ``decoder.scan_layers`` whose body holds each kind of
+  attention and each kind of MLP once behind ``decoder.by_layer``, a
+  layer reading its leaves at its own index of its kind's stack.
 - **The expert layer** is models/moe.py's: this chip holds
   ``cfg.num_experts`` of the ``cfg.published_experts`` the router scores,
   block ``cfg.layer_share``. The shared expert and the dense layer are
@@ -46,8 +41,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from production_stack_tpu.models import decoder, llama, moe
-from jax.sharding import PartitionSpec as P
-
 from production_stack_tpu.models.config import (
     FULL_ATTENTION,
     SLIDING_ATTENTION,
@@ -55,7 +48,7 @@ from production_stack_tpu.models.config import (
     RopeParams,
     rope_params,
 )
-from production_stack_tpu.models.registry import Family
+from production_stack_tpu.models.registry import Family, replicated
 
 
 # --------------------------------------------------------------------- #
@@ -200,12 +193,6 @@ def rope(x: jax.Array, positions: jax.Array, rp: RopeParams) -> jax.Array:
 # One layer
 # --------------------------------------------------------------------- #
 
-def _take(stack: Dict, index) -> Dict:
-    """One layer's leaves of a stack, at a traced index."""
-    return {k: jax.lax.dynamic_index_in_dim(v, index, 0, keepdims=False)
-            for k, v in stack.items()}
-
-
 def _attention(cfg: ModelConfig, mode: str, x, p: Dict, kv, layer, batch,
                kind: str, H: int):
     B, T, _ = x.shape
@@ -230,55 +217,39 @@ def _attention(cfg: ModelConfig, mode: str, x, p: Dict, kv, layer, batch,
     return x, kv
 
 
-def _by_layer(flags: np.ndarray, layer, if_true, if_false, *operands):
-    """``if_true(*operands)`` for the layers ``flags`` marks, ``if_false``
-    for the others: ``lax.cond`` on the traced ``layer`` where both
-    occur, the one branch itself where only one does."""
-    if flags.all() or not flags.any():
-        return (if_true if flags.all() else if_false)(*operands)
-    return jax.lax.cond(jnp.asarray(flags)[layer], if_true, if_false,
-                        *operands)
-
-
 def run_layers(cfg: ModelConfig, mode: str, x, params: Dict, kv_pages,
                batch: decoder.Batch):
-    """The layer loop: one scan over the layers whose body holds each
-    kind of attention and each kind of MLP once (the module's docstring).
-    Returns (x, kv_pages, the expert layers' stats summed over layers)."""
+    """What the layers are (``Family.loop``): full or sliding attention,
+    then the dense MLP in the ``cfg.dense_layers`` leading layers and the
+    expert layer after them, as one stretch. Returns (x, kv_pages, the
+    expert layers' stats summed over layers)."""
     L, d = cfg.num_layers, cfg.dense_layers
     kinds = [cfg.layer_kind(l) for l in range(L)]
     full = np.asarray([kind == FULL_ATTENTION for kind in kinds])
     dense = np.arange(L) < d
-    # Layer l reads entry at[l] of its kind's stack.
-    at = jnp.asarray([kinds[:l].count(kinds[l]) for l in range(L)], jnp.int32)
+    at = decoder.index_in_kind(kinds)
 
     def attention(kind):
         def run(x, kv, layer):
-            p = _take(params["attn"][kind], at[layer])
+            p = decoder.take(params["attn"][kind], at[layer])
             x, kv = _attention(cfg, mode, x, p, kv, layer, batch, kind,
                                p["wg"].shape[-1])
             return x, kv, p["mlp_norm"]
         return run
 
     def dense_mlp(x, h, layer):
-        w = _take(params["dense"], layer)
         # assumed (c): hidden_act is silu.
-        x = x + moe.swiglu(h, w["w_gate"], w["w_up"], w["w_down"])
-        return x, jnp.zeros((len(moe.STATS),), jnp.int32)
+        out, s = moe.dense_layer(h, params["dense"], layer)
+        return x + out, s
 
     def sparse_mlp(x, h, layer):
-        # The routed experts' stacks go to the grouped matmul whole, with
-        # the layer's index (models/moe.py): a slice of them would be
-        # copied out in every forward.
-        stacks = {k: params["moe"][k] for k in ("w_gate", "w_up", "w_down")}
-        w = _take({k: v for k, v in params["moe"].items()
-                   if k not in stacks}, layer - d)
+        w, p = moe.sparse_leaves(params["moe"], layer - d)
         # assumed (b): the router's scores are a softmax over all
         # published experts.
         routed, stats = moe.expert_layer(
-            h, {"router": w["router"], **stacks}, at=layer - d,
-            k=cfg.experts_per_token, share=cfg.layer_share,
-            scaling=cfg.routed_scaling, valid=batch.slot_mapping >= 0)
+            h, p, at=layer - d, k=cfg.experts_per_token,
+            share=cfg.layer_share, scaling=cfg.routed_scaling,
+            valid=batch.slot_mapping >= 0)
         x = x + routed
         if "shared_gate" in w:
             with jax.named_scope("moe_shared"):
@@ -286,33 +257,19 @@ def run_layers(cfg: ModelConfig, mode: str, x, params: Dict, kv_pages,
                                    w["shared_down"])
         return x, stats
 
-    def body(carry, layer):
-        x, k_all, v_all, stats = carry
-        x, kv, mlp_norm = _by_layer(
+    def layer_step(x, kv, layer, _):
+        x, kv, mlp_norm = decoder.by_layer(
             full, layer, attention(FULL_ATTENTION),
-            attention(SLIDING_ATTENTION), x, (k_all, v_all), layer)
+            attention(SLIDING_ATTENTION), x, kv, layer)
         with jax.named_scope("mlp"):
             h = llama.rms_norm(x, mlp_norm, cfg.rms_norm_eps)
-            x, s = _by_layer(dense, layer, dense_mlp, sparse_mlp, x, h, layer)
-        return (x, *kv, stats + s), None
+            x, s = decoder.by_layer(dense, layer, dense_mlp, sparse_mlp,
+                                    x, h, layer)
+        return x, kv, s
 
-    carry = (x, *kv_pages, jnp.zeros((len(moe.STATS),), jnp.int32))
-    (x, k_all, v_all, stats), _ = jax.lax.scan(
-        body, carry, jnp.arange(L, dtype=jnp.int32))
-    return x, (k_all, v_all), stats
-
-
-def load_checkpoint(cfg: ModelConfig, path: str) -> Dict:
-    raise NotImplementedError(
-        "no checkpoint loader for the laguna family yet: its tensor names "
-        "are not published where this repo can read them; a directory with "
-        "config.json alone is served with random weights from --seed")
-
-
-def _no_single_layer(*args, **kwargs):
-    raise NotImplementedError(
-        "laguna's layers are of several kinds: models/laguna.py::run_layers "
-        "is its loop, and it has no pipeline stages yet")
+    x, kv, stats, _ = decoder.scan_layers(
+        layer_step, decoder.first_carry(x, kv_pages, moe.STATS), L)
+    return x, kv, stats
 
 
 def config_fields(hf: dict, layers: int) -> dict:
@@ -345,22 +302,16 @@ def config_fields(hf: dict, layers: int) -> dict:
     )
 
 
-def _replicated(*paths_and_ranks):
-    return {path: P(*[None] * rank) for path, rank in paths_and_ranks}
-
-
 FAMILY = Family(
     model_types=("laguna",),
     init_params=init_params,
     embed=llama.FAMILY.embed,
-    layer=_no_single_layer,
     loop=run_layers,
     head=llama.project_out,
-    load=load_checkpoint,
     # Every leaf, each replicated over a mesh: no tensor-parallel rules
     # yet (the ``ep`` axis of ROADMAP M1 would split ``moe/w_*``'s second
     # axis, as ``layer_share`` splits it across processes today).
-    specs=_replicated(
+    specs=replicated(
         (("embed",), 2), (("final_norm",), 1), (("lm_head",), 2),
         *(((("attn", kind, leaf), rank))
           for kind in (FULL_ATTENTION, SLIDING_ATTENTION)

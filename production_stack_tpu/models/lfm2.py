@@ -35,9 +35,8 @@ groups know blocks only, as before. Only the ``full_attention`` layers
 hold pages (``Family.page_layers``), numbered by their own count.
 
 Leaves are stacked per kind (``conv``, ``attn``, ``dense``, ``moe``) and
-the layer loop is ONE ``lax.scan`` whose body holds each operator and
-each MLP once behind ``lax.cond`` (as models/laguna.py's: the programs
-do not grow with depth).
+the layers are one stretch of ``decoder.scan_layers`` whose body holds
+each operator and each MLP once behind ``decoder.by_layer``.
 
 Not yet, and refused at start-up by the engine (``Family.block_state``'s
 note): LoRA slots, int8 weights, pipeline stages, tensor-parallel rules,
@@ -55,7 +54,6 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
 
 from production_stack_tpu.models import decoder, llama, moe
 from production_stack_tpu.models.config import (
@@ -63,8 +61,7 @@ from production_stack_tpu.models.config import (
     SHORT_CONV,
     ModelConfig,
 )
-from production_stack_tpu.models.laguna import _by_layer, _take
-from production_stack_tpu.models.registry import Family
+from production_stack_tpu.models.registry import Family, replicated
 from production_stack_tpu.ops.attention import kv_page_data
 
 ROUTER_EPS = 1e-6
@@ -253,112 +250,72 @@ def _attention(cfg: ModelConfig, mode: str, h, p: Dict, kv, at, batch):
 
 def run_layers(cfg: ModelConfig, mode: str, x, params: Dict, kv_pages,
                batch: decoder.Batch):
-    """One scan over the layers whose body holds each operator and each
-    MLP once. ``kv_pages`` is ``(k, v, state)``; returns (x, the three
-    updated, the expert layers' stats summed over layers)."""
+    """What the layers are (``Family.loop``): a short convolution or an
+    attention (each hands back the sides of the pool it does not use as
+    it got them: ``decoder.by_layer``), then the dense MLP in the
+    ``cfg.dense_layers`` leading layers and the expert layer after them,
+    as one stretch. ``kv_pages`` is ``(k, v, state)``; returns (x, the
+    three updated, the expert layers' stats summed over layers)."""
     L, d = cfg.num_layers, cfg.dense_layers
     kinds = _kinds(cfg)
     is_conv = np.asarray([kind == SHORT_CONV for kind in kinds])
     dense = np.arange(L) < d
-    # Layer l reads entry at[l] of its operator's stack, pages and state.
-    at = jnp.asarray([kinds[:l].count(kinds[l]) for l in range(L)], jnp.int32)
-    k_all, v_all, state = kv_pages
-    block_size = kv_page_data(k_all).shape[2]
-
-    # A side of the pool that a branch of the ``cond`` hands back as it
-    # got it is COPIED there, whole, in every layer (the conditional's
-    # result is a buffer of its own unless every branch updates the
-    # operand in place: 1.5 s of a 2 s trace were such copies of k and v
-    # on the chip, PERF.md section 6, PR 36). So each branch writes into
-    # every side: the sides it does not use through a scatter whose every
-    # index is dropped, which updates in place and moves nothing.
-    def untouched(side):
-        flat = side.reshape((-1,) + side.shape[2:])
-        return flat.at[jnp.full((1,), flat.shape[0])].set(
-            jnp.zeros((1,) + flat.shape[1:], flat.dtype),
-            mode="drop").reshape(side.shape)
+    at = decoder.index_in_kind(kinds)
+    block_size = kv_page_data(kv_pages[0]).shape[2]
 
     def conv_op(h, sides, layer):
         k_all, v_all, state = sides
         with jax.named_scope("short_conv"):
             out, state = _short_conv(
-                cfg, h, _take(params["conv"], at[layer]), state, at[layer],
-                batch, block_size)
-        return out, (jax.tree_util.tree_map(untouched, k_all),
-                     jax.tree_util.tree_map(untouched, v_all), state)
+                cfg, h, decoder.take(params["conv"], at[layer]), state,
+                at[layer], batch, block_size)
+        return out, (k_all, v_all, state)
 
     def attention_op(h, sides, layer):
         k_all, v_all, state = sides
         out, (k_all, v_all) = _attention(
-            cfg, mode, h, _take(params["attn"], at[layer]), (k_all, v_all),
-            at[layer], batch)
-        return out, (k_all, v_all, untouched(state))
+            cfg, mode, h, decoder.take(params["attn"], at[layer]),
+            (k_all, v_all), at[layer], batch)
+        return out, (k_all, v_all, state)
 
     def dense_mlp(h, layer):
-        w = _take(params["dense"], layer)
         # assumed (b): the activation is silu.
-        return (moe.swiglu(h, w["w_gate"], w["w_up"], w["w_down"]),
-                jnp.zeros((len(moe.STATS),), jnp.int32))
+        return moe.dense_layer(h, params["dense"], layer)
 
     def sparse_mlp(h, layer):
-        # The experts' stacks reach the grouped matmul whole, with the
-        # layer's index (models/moe.py).
-        stacks = {k: params["moe"][k] for k in ("w_gate", "w_up", "w_down")}
-        w = _take({k: v for k, v in params["moe"].items()
-                   if k not in stacks}, layer - d)
+        w, p = moe.sparse_leaves(params["moe"], layer - d)
         return moe.expert_layer(
-            h, {"router": w["router"], **stacks}, at=layer - d,
-            k=cfg.experts_per_token, scaling=cfg.routed_scaling,
-            valid=batch.slot_mapping >= 0,
+            h, p, at=layer - d, k=cfg.experts_per_token,
+            scaling=cfg.routed_scaling, valid=batch.slot_mapping >= 0,
             routing={"scoring": cfg.router_scoring,
                      "bias": w.get("router_bias"), "eps": ROUTER_EPS})
 
-    def body(carry, layer):
-        x, sides, stats = carry
-        norms = _take(params["norms"], layer)
+    def layer_step(x, sides, layer, _):
+        norms = decoder.take(params["norms"], layer)
         h = llama.rms_norm(x, norms["op_norm"], cfg.rms_norm_eps)
-        out, sides = _by_layer(is_conv, layer, conv_op, attention_op,
-                               h, sides, layer)
+        out, sides = decoder.by_layer(is_conv, layer, conv_op, attention_op,
+                                      h, sides, layer)
         x = x + out
         with jax.named_scope("mlp"):
             h = llama.rms_norm(x, norms["ffn_norm"], cfg.rms_norm_eps)
-            out, s = _by_layer(dense, layer, dense_mlp, sparse_mlp, h, layer)
-        return (x + out, sides, stats + s), None
+            out, s = decoder.by_layer(dense, layer, dense_mlp, sparse_mlp,
+                                      h, layer)
+        return x + out, sides, s
 
-    carry = (x, (k_all, v_all, state),
-             jnp.zeros((len(moe.STATS),), jnp.int32))
-    (x, sides, stats), _ = jax.lax.scan(
-        body, carry, jnp.arange(L, dtype=jnp.int32))
+    x, sides, stats, _ = decoder.scan_layers(
+        layer_step, decoder.first_carry(x, kv_pages, moe.STATS), L)
     return x, sides, stats
-
-
-def load_checkpoint(cfg: ModelConfig, path: str) -> Dict:
-    raise NotImplementedError(
-        "no checkpoint loader for the lfm2 family yet; a directory with "
-        "config.json alone is served with random weights from --seed")
-
-
-def _no_single_layer(*args, **kwargs):
-    raise NotImplementedError(
-        "lfm2's layers are of several kinds: models/lfm2.py::run_layers is "
-        "its loop, and it has no pipeline stages yet")
-
-
-def _replicated(*paths_and_ranks):
-    return {path: P(*[None] * rank) for path, rank in paths_and_ranks}
 
 
 FAMILY = Family(
     model_types=("lfm2_moe",),
     init_params=init_params,
     embed=llama.FAMILY.embed,
-    layer=_no_single_layer,
     loop=run_layers,
     head=llama.project_out,
-    load=load_checkpoint,
     # Every leaf replicated: no tensor-parallel rules yet, and the engine
     # refuses a mesh of several devices for a family with a block state.
-    specs=_replicated(
+    specs=replicated(
         (("embed",), 2), (("final_norm",), 1), (("lm_head",), 2),
         (("norms", "op_norm"), 2), (("norms", "ffn_norm"), 2),
         *((("conv", leaf), 3) for leaf in ("w_in", "w_conv", "w_out")),

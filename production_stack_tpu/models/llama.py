@@ -44,12 +44,7 @@ from production_stack_tpu.models.weights import (
 )
 
 
-def rms_norm(x: jax.Array, weight: jax.Array, eps: float) -> jax.Array:
-    dtype = x.dtype
-    x = x.astype(jnp.float32)
-    var = jnp.mean(x * x, axis=-1, keepdims=True)
-    x = x * jax.lax.rsqrt(var + eps)
-    return (x * weight.astype(jnp.float32)).astype(dtype)
+rms_norm = decoder.rms_norm  # the skeleton's own layer parts norm with it
 
 
 def rope(

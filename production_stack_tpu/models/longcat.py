@@ -34,9 +34,10 @@ head. What this module brings:
   float32 bias (zeros at init), weights ``routed_scaling x`` the softmax
   score, not renormalised. This chip holds ``cfg.num_experts`` experts,
   block ``cfg.layer_share``, and every identity.
-- **One scan over the sublayers**, the layer-stacked leaves (a layer's
-  two sublayers are the second axis of every leaf) as its ``xs``; the
-  expert stacks reach ``expert_layer`` whole, with the layer's index.
+- **One stretch of ``decoder.scan_layers`` over the sublayers**, the
+  layer-stacked leaves (a layer's two sublayers are the second axis of
+  every leaf) as its ``xs``; the expert stacks reach ``expert_layer``
+  whole, with the layer's index.
 
 No LoRA slots, no pipeline stages, no int8 weights, no tensor-parallel
 rules, no checkpoint loader yet: the record at the foot says so, and the
@@ -47,17 +48,14 @@ shapes (engine/core.py).
 from __future__ import annotations
 
 import functools
-import math
 from typing import Dict
 
 import jax
 import jax.numpy as jnp
-import numpy as np
-from jax.sharding import PartitionSpec as P
 
 from production_stack_tpu.models import decoder, llama, moe
 from production_stack_tpu.models.config import ModelConfig
-from production_stack_tpu.models.registry import Family
+from production_stack_tpu.models.registry import Family, replicated
 
 EXPERT_STACKS = ("e_gate", "e_up", "e_down")
 
@@ -119,64 +117,20 @@ def init_params(cfg: ModelConfig, rng: jax.Array, **_unused) -> Dict:
 
 
 # --------------------------------------------------------------------- #
-# One layer
+# The layers
 # --------------------------------------------------------------------- #
-
-def rope_pairs(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """Rotary embedding over all of the last axis, adjacent lanes
-    ``(2j, 2j + 1)`` rotating together and staying where they are.
-    ``x [B, T, ..., R]``, ``positions [B, T]``."""
-    rot = x.shape[-1]
-    inv_freq = (theta ** (-np.arange(0, rot, 2, dtype=np.float64) / rot)
-                ).astype(np.float32)
-    angles = positions[..., None].astype(jnp.float32) * inv_freq
-    angles = angles.reshape(angles.shape[:2] + (1,) * (x.ndim - 3)
-                            + angles.shape[-1:])
-    cos, sin = jnp.cos(angles), jnp.sin(angles)
-    pairs = x.astype(jnp.float32).reshape(x.shape[:-1] + (rot // 2, 2))
-    even, odd = pairs[..., 0], pairs[..., 1]
-    out = jnp.stack([even * cos - odd * sin, odd * cos + even * sin], -1)
-    return out.reshape(x.shape).astype(x.dtype)
-
-
-def _mla(cfg: ModelConfig, mode: str, x, p: Dict, kv, page_layer, batch):
-    """``x + MLA(RMS(x))`` of one sublayer on its own leaves."""
-    B, T, Hd = x.shape
-    H, C = cfg.num_heads, cfg.kv_lora_rank
-    N, R = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
-    with jax.named_scope("mla_proj"):
-        h = llama.rms_norm(x, p["in_norm"], cfg.rms_norm_eps)
-        cq = llama.rms_norm(h @ p["wq_a"], p["q_norm"], cfg.rms_norm_eps)
-        q = jnp.einsum("btq,oq->bto", cq, p["wq_b"]).reshape(
-            B, T, H, N + R)
-        if cfg.mla_scale_q_lora:
-            q = (q.astype(jnp.float32)
-                 * math.sqrt(Hd / cfg.q_lora_rank)).astype(x.dtype)
-        t = h @ p["wkv_a"]
-        c = llama.rms_norm(t[..., :C], p["kv_norm"], cfg.rms_norm_eps)
-        q_rope = rope_pairs(q[..., N:], batch.positions, cfg.rope_theta)
-        k_rope = rope_pairs(t[..., C:], batch.positions, cfg.rope_theta)
-    attn, kv = decoder.attend_latent(
-        mode, q[..., :N], q_rope, c, k_rope, p["wkv_b"], kv, page_layer,
-        batch, scale=(N + R) ** -0.5,
-        latent_scale=(math.sqrt(Hd / C) if cfg.mla_scale_kv_lora else 1.0))
-    with jax.named_scope("mla_proj"):
-        x = x + attn.reshape(B, T, -1) @ p["wo"]
-    return x, kv
-
 
 def run_layers(cfg: ModelConfig, mode: str, x, params: Dict, kv_pages,
                batch: decoder.Batch):
-    """The layer loop (the module's docstring): ONE ``lax.scan`` over the
-    ``2 x layers`` sublayers, whose body holds the latent attention and
-    the dense MLP once and the expert layer once behind a ``lax.cond``
-    that only a layer's first sublayer takes; the shortcut's branch rides
-    the carry to the second, where it joins. A program so holds one of
-    each whatever the depth, which is what the persistent compile cache
-    has to hold beside every other program of the cell (PERF.md section
-    6, PR 41: with the two sublayers unrolled the cell's 53 step programs
-    and its check wrote 213 MB of a 201.3 MB cache). Returns (x, kv_pages,
-    the expert layers' stats summed over layers)."""
+    """What the layers are (``Family.loop``; the module's docstring): one
+    stretch over the ``2 x layers`` sublayers, the ``[2L, ...]`` leaves
+    its ``xs``, whose body holds the latent attention and the dense MLP
+    once and the expert layer once behind a ``lax.cond`` that only a
+    layer's first sublayer takes; the shortcut's branch rides the carry
+    to the second, where it joins. One body a sublayer, not a layer's two
+    unrolled: those programs wrote 213 MB of a 201.3 MB compile cache (PR
+    41). Returns (x, kv_pages, the expert layers' stats summed over
+    layers)."""
     layers = params["layers"]
     stacks = {"w_gate": layers["e_gate"], "w_up": layers["e_up"],
               "w_down": layers["e_down"]}
@@ -187,8 +141,7 @@ def run_layers(cfg: ModelConfig, mode: str, x, params: Dict, kv_pages,
     no_stats = jnp.zeros((len(FAMILY.stats),), jnp.int32)
 
     def experts(h, layer):
-        w = {k: jax.lax.dynamic_index_in_dim(v, layer, 0, keepdims=False)
-             for k, v in routers.items()}
+        w = decoder.take(routers, layer)
         return moe.expert_layer(
             h, {"router": w["router"], **stacks}, at=layer,
             k=cfg.experts_per_token, share=cfg.layer_share,
@@ -197,39 +150,27 @@ def run_layers(cfg: ModelConfig, mode: str, x, params: Dict, kv_pages,
                      "renormalise": False},  # assumed: weights as scored
             zero_experts=cfg.zero_experts)
 
-    def body(carry, p):
-        x, sides, shortcut, stats, j = carry
+    def sublayer(x, sides, j, p, shortcut):
         first = j % 2 == 0
-        x, sides = _mla(cfg, mode, x, p, sides, j, batch)
+        x, sides = decoder.latent_attention(cfg, mode, x, p, sides, j, batch)
         with jax.named_scope("mlp"):
             h = llama.rms_norm(x, p["post_norm"], cfg.rms_norm_eps)
         # The shortcut's branch: from the first sublayer's state, joined
-        # after the second sublayer's MLP.
+        # after the second sublayer's MLP. Not ``decoder.by_layer``: no
+        # choice between kinds of layer but whether the one expert layer
+        # starts here; the other side hands on the carry's ``shortcut``.
         shortcut, s = jax.lax.cond(
             first, experts, lambda h, layer: (shortcut, no_stats), h, j // 2)
         with jax.named_scope("mlp"):
             # assumed: hidden_act is silu.
             x = x + moe.swiglu(h, p["w_gate"], p["w_up"], p["w_down"])
             x = jnp.where(first, x, x + shortcut)
-        return (x, tuple(sides), shortcut, stats + s, j + 1), None
+        return x, sides, s, shortcut
 
-    carry = (x, tuple(kv_pages), jnp.zeros_like(x), no_stats, jnp.int32(0))
-    (x, sides, _, stats, _), _ = jax.lax.scan(body, carry, sliced)
+    x, sides, _, stats, _ = decoder.scan_layers(
+        sublayer, decoder.first_carry(x, kv_pages, FAMILY.stats,
+                                      jnp.zeros_like(x)), xs=sliced)
     return x, sides, stats
-
-
-def load_checkpoint(cfg: ModelConfig, path: str) -> Dict:
-    raise NotImplementedError(
-        "no checkpoint loader for the longcat family yet: it waits until a "
-        "checkpoint is in the repository or on the machine; a directory "
-        "with config.json alone is served with random weights from --seed")
-
-
-def _no_single_layer(*args, **kwargs):
-    raise NotImplementedError(
-        "a longcat layer is two sublayers and a shortcut across them: "
-        "models/longcat.py::run_layers is its loop, and it has no pipeline "
-        "stages yet")
 
 
 def config_fields(hf: dict, layers: int) -> dict:
@@ -269,28 +210,17 @@ def config_fields(hf: dict, layers: int) -> dict:
     )
 
 
-def page_sides(cfg: ModelConfig):
-    """One normed latent and one rotated key a token and sublayer."""
-    return (1, cfg.kv_lora_rank), (1, cfg.qk_rope_head_dim)
-
-
-def _replicated(*paths_and_ranks):
-    return {path: P(*[None] * rank) for path, rank in paths_and_ranks}
-
-
 FAMILY = Family(
     model_types=("longcat_flash",),
     init_params=init_params,
     embed=llama.FAMILY.embed,
-    layer=_no_single_layer,
     loop=run_layers,
     head=llama.project_out,
-    load=load_checkpoint,
     # Every leaf replicated: no tensor-parallel rules yet, and the engine
     # refuses a mesh of several devices for a family with its own page
     # sides (a latent has no heads to shard; the ``ep`` axis of ROADMAP
     # M1 would split the expert stacks' second axis).
-    specs=_replicated(
+    specs=replicated(
         (("embed",), 2), (("final_norm",), 1), (("lm_head",), 2),
         *((("layers", leaf), 3)
           for leaf in ("in_norm", "post_norm", "q_norm", "kv_norm",
@@ -302,7 +232,7 @@ FAMILY = Family(
     stats=moe.STATS + moe.ZERO_STATS,
     config_fields=config_fields,
     page_layers=lambda cfg: 2 * cfg.num_layers,
-    page_sides=page_sides,
+    page_sides=decoder.latent_page_sides,
 )
 
 apply = functools.partial(decoder.apply, FAMILY)

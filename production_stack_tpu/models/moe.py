@@ -43,6 +43,7 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 
+from production_stack_tpu.models import decoder
 from production_stack_tpu.ops import pallas_grouped_matmul as gmm
 
 # What :func:`expert_layer` counts of one call, in this order.
@@ -51,6 +52,8 @@ STATS = ("moe_assignments", "moe_experts_hit", "moe_max_expert_load")
 # assignments that landed on one (a family with such a layer puts
 # ``ZERO_STATS`` behind ``STATS`` in its ``Family.stats``).
 ZERO_STATS = ("moe_zero_assignments",)
+# The experts' leaves, ``[sparse layers, held, ...]`` each.
+EXPERT_STACKS = ("w_gate", "w_up", "w_down")
 
 
 def swiglu(h: jax.Array, w_gate, w_up, w_down) -> jax.Array:
@@ -58,6 +61,28 @@ def swiglu(h: jax.Array, w_gate, w_up, w_down) -> jax.Array:
     expert, and what each routed expert computes on its rows."""
     gate = jax.nn.silu((h @ w_gate).astype(jnp.float32)).astype(h.dtype)
     return (gate * (h @ w_up)) @ w_down
+
+
+def dense_layer(h: jax.Array, stack: Dict, at) -> Tuple[jax.Array, jax.Array]:
+    """The other kind of MLP of a family with leading dense layers:
+    :func:`swiglu` on entry ``at`` of the stack ``{w_gate, w_up, w_down:
+    [dense layers, ...]}``, and the zeros its layer adds to the expert
+    layers' :data:`STATS`."""
+    w = decoder.take(stack, at)
+    return (swiglu(h, w["w_gate"], w["w_up"], w["w_down"]),
+            jnp.zeros((len(STATS),), jnp.int32))
+
+
+def sparse_leaves(layers: Dict, at) -> Tuple[Dict, Dict]:
+    """Of the leaves stacked over a family's sparse layers: (layer
+    ``at``'s own small ones, read at its index: router, bias, shared
+    expert; what :func:`expert_layer` takes as ``p``: that router and the
+    experts' stacks WHOLE, for ``at`` to index: a slice of them would be
+    copied out in every forward, the module's docstring)."""
+    stacks = {k: layers[k] for k in EXPERT_STACKS}
+    w = decoder.take(
+        {k: v for k, v in layers.items() if k not in stacks}, at)
+    return w, {"router": w["router"], **stacks}
 
 
 def route(h: jax.Array, router: jax.Array, k: int, *, scaling: float = 1.0,

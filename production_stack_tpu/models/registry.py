@@ -14,6 +14,8 @@ import dataclasses
 import importlib
 from typing import Callable, Mapping, Tuple
 
+from jax.sharding import PartitionSpec as P
+
 from production_stack_tpu.models.config import ModelConfig
 
 # arch -> module, imported on first use (an engine pays for one family).
@@ -39,7 +41,8 @@ class Family:
       last three ``None`` for a tree without LoRA slots;
     - ``layer(cfg, mode, x, (layer_params, lora), kv, l, batch)`` ->
       ``(x, kv)``: one layer on its un-stacked leaves, attention through
-      ``decoder.attend``;
+      ``decoder.attend`` (None for a family whose layers are of several
+      kinds: it brings ``loop``, and has no pipeline stages yet);
     - ``head(params, cfg, x, output_hidden)`` -> logits, or the normed
       hidden states.
     """
@@ -49,15 +52,17 @@ class Family:
     model_types: Tuple[str, ...]
     init_params: Callable  # (cfg, rng, **lora_kwargs) -> params
     embed: Callable
-    layer: Callable
     head: Callable
-    # (cfg, path) -> the tree of an HF checkpoint directory, in
-    # ``init_params``' layout (file formats: models/weights.py).
-    load: Callable
     # leaf path -> PartitionSpec template, "tp" substituted; the leading
     # axis of a "layers" leaf is the stacked layer axis
     # (parallel/sharding.py applies the rules).
     specs: Mapping[Tuple[str, ...], object]
+    layer: Callable | None = None
+    # (cfg, path) -> the tree of an HF checkpoint directory, in
+    # ``init_params``' layout (file formats: models/weights.py). None: no
+    # loader yet; a directory with ``config.json`` alone is served with
+    # random weights from ``--seed``.
+    load: Callable | None = None
     # ``layers`` leaves that weight-only int8 takes; empty = unsupported.
     quant_keys: Tuple[str, ...] = ()
     lora: bool = False  # init_params takes lora_slots / lora_rank
@@ -65,11 +70,11 @@ class Family:
     # A checkpoint without ``lm_head`` ties the head to ``embed``: the
     # random head of the init is dropped and ``head`` reads ``embed.T``.
     head_may_tie: bool = False
-    # The family's own layer loop, where its layers are of several kinds
-    # and one scan over one stack cannot run them (models/laguna.py):
-    # ``loop(cfg, mode, x, params, kv_pages, batch)`` -> ``(x, kv_pages,
-    # counts)``. None: ``decoder.scan_layers`` over ``params["layers"]``
-    # with ``layer``.
+    # What the family's layers are, where they are of several kinds and
+    # one stack cannot hold them: ``loop(cfg, mode, x, params, kv_pages,
+    # batch)`` -> ``(x, kv_pages, counts)`` steps them through
+    # ``decoder.scan_layers`` (docs/engine.md, "Layers of several
+    # kinds"). None: that scan over ``params["layers"]`` with ``layer``.
     loop: Callable | None = None
     # ``layers`` leaves that ``layer`` is handed whole, as the stack
     # ``[L, ...]``, beside the layer's own slice of every other leaf: the
@@ -121,6 +126,20 @@ class Family:
     # and are not taught two shapes are refused at start-up
     # (engine/core.py::_refuse_what_the_page_sides_are_not_taught).
     page_sides: Callable | None = None
+
+    def __post_init__(self):
+        if (self.layer is None) == (self.loop is None) or (
+                self.pipeline and self.layer is None):
+            raise ValueError(
+                "a family brings ``layer`` (layers of one kind: the shared "
+                "scan steps it, and pipeline stages run it) or ``loop`` "
+                "(layers of several kinds, and no pipeline stages yet)")
+
+
+def replicated(*paths_and_ranks) -> dict:
+    """``specs`` of a family without tensor-parallel rules yet: every
+    ``(leaf path, rank)`` replicated over a mesh."""
+    return {path: P(*[None] * rank) for path, rank in paths_and_ranks}
 
 
 def page_sides(cfg: ModelConfig):

@@ -74,8 +74,14 @@ def report_incomplete(path: str, missing: List[str],
 
 def load_checkpoint(cfg: ModelConfig, path: str) -> Dict:
     """Load HF weights at ``path`` into the arch's parameter pytree."""
+    load = get_family(cfg.arch).load
+    if load is None:
+        raise NotImplementedError(
+            f"no checkpoint loader for the {cfg.arch} family yet: a "
+            "directory with config.json alone is served with random "
+            "weights from --seed")
     logger.info("Loading %s checkpoint from %s", cfg.arch, path)
-    return get_family(cfg.arch).load(cfg, path)
+    return load(cfg, path)
 
 
 def load_whisper_checkpoint(cfg, path: str) -> Dict:

@@ -37,6 +37,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from production_stack_tpu.models.config import ModelConfig
 from production_stack_tpu.models.decoder import (
     Batch,
+    first_carry,
     scan_layers,
     take_last_token,
 )
@@ -131,13 +132,14 @@ def make_pp_apply(mesh: Mesh, family, microbatches: int = 1):
                         jnp.asarray(-1, batch.slot_mapping.dtype)),
                     lora_scaling=scaling)
 
-                def layer_fn(x, per_layer, kv, l):
-                    return family.layer(cfg, mode, x, per_layer, kv, l, batch)
+                def step(x, kv, l, per_layer):
+                    return *family.layer(cfg, mode, x, per_layer, kv, l,
+                                         batch), None
 
                 # This stage's layers only: the local index addresses the
                 # local shard of the pool.
-                y, (k_loc, v_loc) = scan_layers(
-                    layer_fn, x_in, (k_loc, v_loc), xs_loc)
+                y, (k_loc, v_loc), _, _ = scan_layers(
+                    step, first_carry(x_in, (k_loc, v_loc)), xs=xs_loc)
                 commit = jnp.logical_and(idx == pp - 1, active)
                 outputs = jax.lax.cond(
                     commit,

@@ -58,7 +58,7 @@ def place(tree: str) -> None:
                 shutil.copy(a, b)
 
 
-seed, pair_seed, in_pair = seed0, {}, 0
+seed, made = seed0, {"P": 0, "C": 0}
 results = []
 queue = list(order)
 n = -1
@@ -67,9 +67,8 @@ while queue:
     kind = queue.pop(0)
     n += 1
     if kind in "PC":
-        pair = in_pair // 2
-        in_pair += 1
-        s = pair_seed.setdefault(pair, seed0 + 10 + pair)
+        s = seed0 + 10 + made[kind]
+        made[kind] += 1
     else:
         s = seed
         seed += 1
@@ -101,7 +100,7 @@ while queue:
                 and trees[kind] not in repeated):
             repeated.add(trees[kind])
             rec["left_out"] = "compiled"
-            in_pair -= 1
+            made[kind] -= 1
             queue.insert(0, kind)
     with open(os.path.join(out, f"{cell}.{order}.json"), "w") as f:
         json.dump(results, f, indent=1)

@@ -12,22 +12,72 @@ operands differently in the nested program, and ``apply`` alone hid 25%
 of a cell's device time for two PRs (GLM's dense weights prefetched into
 VMEM in every layer of the burst and in no layer of ``glm.decode``).
 
-    JAX_PLATFORMS=cpu python scripts/hlo_digest.py <repo-root> <out-dir>
+    JAX_PLATFORMS=cpu python scripts/hlo_digest.py <repo-root> <out-dir> \
+        [--only mistral|laguna|lfm2|longcat|glm]
 
 Three modes (decode 32 x 1, plain prefill 1 x 512, cached prefill 1 x 256,
 the server's default 8 LoRA slots) x {bf16, int8 weights}, compiled by the
 TPU compiler for a described v5e. Stripped before hashing, as metadata:
 ``metadata={...}`` of every instruction, the header's source-location
 tables, and the MLIR locations inside each Mosaic kernel's serialized body.
-Writes ``<mode>.<weights>.hlo`` and ``digests.json``.
+Writes ``<mode>.<weights>.hlo``, ``<name>.ops.json`` and ``digests.json``,
+which holds three maps by program (PR 47):
+
+- ``text``: the sha of the stripped text, instruction names and their
+  numbering included. Equal digests prove the compiler was handed the same
+  program and made the same one of it; unequal ones prove nothing (a part
+  put under ``jax.jit`` renumbers every instruction behind it).
+- ``ops``: the sha of the sorted list of (opcode, result type with its
+  layout) of every instruction, fused ones included, a tuple type's
+  entries sorted too: blind to names, numbering, operand order and the
+  order of a loop's carry. Equal ``ops`` under unequal ``text`` is the
+  same work laid out the same way; where they differ ``<name>.ops.json``
+  (count by ``"opcode type"``) of both trees names each instruction.
+- ``memory``: ``temp_size_in_bytes`` and ``generated_code_size_in_bytes``
+  of ``compiled.memory_analysis()`` (the second is what the machine's
+  192 MiB compile cache has to hold).
+
+``--only`` hashes one family's programs (under a minute, GLM's ~2) and
+writes only those into ``digests.json``.
+
+    python scripts/hlo_digest.py --compare <out-dir-a> <out-dir-b>
+
+prints, for each program of both, whether ``text`` and ``ops`` agree, b's
+``memory`` over a's, and each ``"opcode type"`` whose count differs (b's
+count less a's).
 """
+import collections
 import hashlib
 import json
 import os
 import re
 import sys
 
+if sys.argv[1] == "--compare":
+    dirs = sys.argv[2:4]
+
+    def read(directory, name):
+        with open(os.path.join(directory, name)) as f:
+            return json.load(f)
+
+    a, b = (read(d, "digests.json") for d in dirs)
+    for name in sorted(set(a["text"]) & set(b["text"])):
+        same = ["%s %s" % (key, "same" if a[key][name] == b[key][name]
+                           else "DIFFERS") for key in ("text", "ops")]
+        sizes = ["%s %d -> %d (%+.2f%%)" % (
+            key.split("_size")[0], was, b["memory"][name][key],
+            100.0 * (b["memory"][name][key] - was) / max(was, 1))
+            for key, was in a["memory"][name].items()]
+        print(name, *same, *sizes)
+        if a["ops"][name] != b["ops"][name]:
+            was, now = (collections.Counter(read(d, name + ".ops.json"))
+                        for d in dirs)
+            for op in sorted(set(was) | set(now)):
+                if was[op] != now[op]:
+                    print("   %+d  %s" % (now[op] - was[op], op))
+    sys.exit(0)
 root, out = sys.argv[1], sys.argv[2]
+only = sys.argv[sys.argv.index("--only") + 1] if "--only" in sys.argv else None
 sys.path.insert(0, root)
 os.makedirs(out, exist_ok=True)
 
@@ -89,9 +139,32 @@ def params_spec(int8):
         lambda x: spec(x.shape, x.dtype), jax.eval_shape(init))
 
 
+# ``[ROOT] %name = <result type> <opcode>(``: a type holds no lower-case
+# word before a parenthesis (tiles and memory spaces are ``T(8,128)S(1)``).
+INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%?[\w.\-]+ = (.*?) ([a-z][a-z0-9\-]*)\(",
+                         re.M)
+
+
+def _unordered(kind):
+    """A result type with the entries of a tuple type sorted."""
+    if not kind.startswith("("):
+        return kind
+    entries, depth, start = [], 0, 1
+    for i, char in enumerate(kind):
+        depth += (char in "([{") - (char in ")]}")
+        if (char == "," and depth == 1) or depth == 0:
+            entries.append(_unordered(kind[start:i].strip()))
+            start = i + 1
+    return "(%s)" % ", ".join(sorted(entries))
+
+
 def digest(name, fn, donate, *operands):
-    text = jax.jit(fn, donate_argnums=(donate,)).lower(
-        *operands).compile().as_text()
+    compiled = jax.jit(fn, donate_argnums=(donate,)).lower(
+        *operands).compile()
+    text = compiled.as_text()
+    held = compiled.memory_analysis()
+    memory[name] = {key: getattr(held, key) for key in (
+        "temp_size_in_bytes", "generated_code_size_in_bytes")}
     # The source-location tables of the header are metadata too.
     text = re.sub(
         r"\nFileNames\n.*?\nStackFrames\n.*?\n\n", "\n", text, flags=re.S)
@@ -104,12 +177,20 @@ def digest(name, fn, donate, *operands):
     with open(os.path.join(out, name + ".hlo"), "w") as f:
         f.write(text)
     digests[name] = hashlib.sha256(text.encode()).hexdigest()
-    print(name, digests[name], len(text), flush=True)
+    counted = collections.Counter(
+        f"{opcode} {_unordered(kind)}" for kind, opcode in INSTRUCTION.findall(
+            re.sub(r"/\*.*?\*/", "", text)))
+    with open(os.path.join(out, name + ".ops.json"), "w") as f:
+        json.dump(dict(sorted(counted.items())), f, indent=0)
+    ops[name] = hashlib.sha256(
+        "\n".join(sorted(counted.elements())).encode()).hexdigest()
+    print(name, digests[name][:16], ops[name][:16], sum(counted.values()),
+          memory[name], flush=True)
 
 
 pages = spec((cfg.num_layers, NB, BS, cfg.num_kv_heads, cfg.head_dim), jnp.bfloat16)
-digests = {}
-for weights in ("bf16", "int8"):
+digests, ops, memory = {}, {}, {}
+for weights in ("bf16", "int8") if only in (None, "mistral") else ():
     params = params_spec(weights == "int8")
     for mode, rows, width in (("decode", 32, 1), ("prefill", 1, 512),
                               ("prefill_cached", 1, 256)):
@@ -141,6 +222,8 @@ def family_digests(name, config, module, pool, shapes):
     ``decode_k8`` is the decode step inside a scan over ``width`` steps
     (engine/core.py::_make_multi_decode without its sampling: each
     step's argmax is the next one's token)."""
+    if only not in (None, name):
+        return
     with open(os.path.join(root, "chipbench", "configs",
                            config + ".json")) as f:
         os.makedirs(os.path.join(out, name), exist_ok=True)
@@ -258,4 +341,4 @@ if glm4_moe_lite is not None:  # (PR 45) every cached bucket the agent
                     ("prefill_cached", 1, 512, 128),
                     ("prefill_cached", 1, 1024, 128), (BURST, 32, 8, 128)))
 with open(os.path.join(out, "digests.json"), "w") as f:
-    json.dump(digests, f, indent=1)
+    json.dump({"text": digests, "ops": ops, "memory": memory}, f, indent=1)

@@ -817,3 +817,39 @@ def test_reading_a_few_blocks_of_a_full_pool_copies_no_side(one_chip, shape):
         plain = jax.jit(lambda x, idx: x[:, idx]).lower(
             spec(shape, jnp.bfloat16), spec((11,), jnp.int32)).compile()
         assert plain.memory_analysis().temp_size_in_bytes > 3e9
+
+
+def test_a_side_a_branch_hands_back_as_it_got_it_is_not_copied(one_chip):
+    """PR 36's rule, held by ``decoder.by_layer`` and not by a family's
+    memory (``tests/test_layer_loop.py``'s toy: each of its two operators
+    returns the other's side of the pool as it got it): the scan compiled
+    for the chip copies neither side, and the plain ``lax.cond`` copies
+    one in every layer, which is what makes the reading worth something."""
+    import test_layer_loop as toy
+    from production_stack_tpu.models import decoder
+
+    def plain(flags, layer, if_true, if_false, *operands):
+        return jax.lax.cond(jnp.asarray(flags)[layer], if_true, if_false,
+                            *operands)
+
+    blocks = 8192  # a side of 0.1 GB: nothing the compiler keeps in VMEM
+
+    def spec(x, blocks=None):
+        shape = x.shape if blocks is None else x.shape[:1] + (
+            blocks,) + x.shape[2:]
+        return jax.ShapeDtypeStruct(shape, x.dtype, sharding=one_chip)
+
+    def copies_of_a_side(choose):
+        x, sides, params = toy._toy()
+        text = jax.jit(lambda x, sides, params: decoder.scan_layers(
+            toy._step(params, choose=choose),
+            decoder.first_carry(x, sides, toy.COUNTED), toy.L),
+            donate_argnums=(1,)).lower(
+            spec(x), tuple(spec(side, blocks) for side in sides),
+            jax.tree_util.tree_map(spec, params)).compile().as_text()
+        side = rf"s32\[\d+,{blocks},{toy.ROWS},{toy.WIDTH}\]"
+        return [line.strip()[:120] for line in text.splitlines()
+                if re.search(rf"= {side}\S* copy(-start)?\(", line)]
+
+    assert not copies_of_a_side(decoder.by_layer)
+    assert copies_of_a_side(plain)

@@ -37,7 +37,6 @@ from production_stack_tpu.models import (
     decoder,
     get_model_config,
     glm4_moe_lite,
-    laguna,
     llama,
     moe,
 )
@@ -52,6 +51,7 @@ from production_stack_tpu.models.registry import (
     page_layers,
     page_sides,
 )
+from production_stack_tpu.models.weights import load_checkpoint
 from production_stack_tpu.ops import attention as att
 from production_stack_tpu.ops import pallas_mla_decode as kernel
 
@@ -165,7 +165,7 @@ def test_the_record_says_what_a_page_is(cfg):
     assert family.stats == moe.STATS and not family.quant_keys
     assert not family.lora and not family.pipeline
     with pytest.raises(NotImplementedError, match="checkpoint"):
-        family.load(cfg, "/nowhere")
+        load_checkpoint(cfg, "/nowhere")
 
 
 def test_own_recipe_draws_the_programs_weights():
@@ -339,7 +339,7 @@ def _one_scan_behind_a_cond(cfg, mode, x, params, kv_pages, batch):
     valid = batch.slot_mapping >= 0
 
     def dense_mlp(h, layer):
-        w = laguna._take(params["dense"], layer)
+        w = decoder.take(params["dense"], layer)
         return (moe.swiglu(h, w["w_gate"], w["w_up"], w["w_down"]),
                 jnp.zeros((len(moe.STATS),), jnp.int32))
 
@@ -349,10 +349,10 @@ def _one_scan_behind_a_cond(cfg, mode, x, params, kv_pages, batch):
 
     def body(carry, per_layer):
         x, sides, stats, layer = carry
-        x, sides = glm4_moe_lite._mla(cfg, mode, x, per_layer, sides, layer,
+        x, sides = decoder.latent_attention(cfg, mode, x, per_layer, sides, layer,
                                       batch)
         h = llama.rms_norm(x, per_layer["post_norm"], cfg.rms_norm_eps)
-        out, s = laguna._by_layer(dense, layer, dense_mlp, sparse_mlp, h,
+        out, s = decoder.by_layer(dense, layer, dense_mlp, sparse_mlp, h,
                                   layer)
         return (x + out, tuple(sides), stats + s, layer + 1), None
 
@@ -697,9 +697,9 @@ def test_the_programs_layer_is_a_share_plus_the_shared_expert(cfg, params):
     h = jnp.asarray(rng.normal(size=(1, 24, 128)), jnp.float32)
     p = params["moe"]
     own = {k: v[1] for k, v in p.items()
-           if k not in glm4_moe_lite.EXPERT_STACKS}
+           if k not in moe.EXPERT_STACKS}
     out, stats = glm4_moe_lite._experts(cfg, h, p, 1, None)
-    experts = [tuple(p[k][1, e] for k in glm4_moe_lite.EXPERT_STACKS)
+    experts = [tuple(p[k][1, e] for k in moe.EXPERT_STACKS)
                for e in range(4)]
     with jax.default_matmul_precision("highest"):
         routed, shared = reference.moe_layer(

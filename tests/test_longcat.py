@@ -38,6 +38,7 @@ from production_stack_tpu.models.registry import (
     page_layers,
     page_sides,
 )
+from production_stack_tpu.models.weights import load_checkpoint
 from production_stack_tpu.ops import attention as att
 from production_stack_tpu.ops.pallas_mla_decode import (
     decode_tile,
@@ -229,7 +230,7 @@ def test_the_record_says_what_a_page_is(cfg):
         layers, first, second = kv_page_sides(other)
         assert first == second and (layers,) + first == kv_page_dims(other)
     with pytest.raises(NotImplementedError, match="checkpoint"):
-        get_family("longcat").load(cfg, "/nowhere")
+        load_checkpoint(cfg, "/nowhere")
 
 
 def test_own_recipe_draws_the_programs_weights():
