@@ -49,7 +49,7 @@ from production_stack_tpu.structured.tokenfsm import (
     StructuredCache,
     mask_row_bytes,
 )
-from production_stack_tpu.models import build_model, get_model_config
+from production_stack_tpu.models import build_model, get_model_config, registry
 from production_stack_tpu.models.registry import (
     block_state_shape,
     page_sides,
@@ -529,7 +529,7 @@ class EngineCore:
             pp_ = self.mesh.shape.get("pp", 1)
             shard_factor = (
                 (tp_ if tp_ > 1 and mc_.num_kv_heads % tp_ == 0 else 1)
-                * (pp_ if pp_ > 1 and mc_.num_layers % pp_ == 0 else 1))
+                * (pp_ if pp_ > 1 and self.page_dims[0] % pp_ == 0 else 1))
             pool_per_device = (
                 self.num_blocks * self._kv_bytes_per_block()
                 // shard_factor)
@@ -861,7 +861,7 @@ class EngineCore:
             tp = self.mesh.shape.get("tp", 1)
             pp = self.mesh.shape.get("pp", 1)
             tp_factor = tp if tp > 1 and mc.num_kv_heads % tp == 0 else 1
-            pp_factor = pp if pp > 1 and mc.num_layers % pp == 0 else 1
+            pp_factor = pp if pp > 1 and self.page_dims[0] % pp == 0 else 1
             # Explicit per-device headroom reserve comes off the top:
             # residual allocations that memory_stats misses (checkpoint
             # staging remnants, XLA autotuning scratch) repeatedly OOMed
@@ -2872,10 +2872,10 @@ class EngineCore:
         if rec.param_bytes == 0 and self.params is not None:
             # Weight bytes for the roofline: resolved lazily because the
             # checkpoint may replace the init tree after construction.
-            try:
-                rec.param_bytes = sum(
-                    int(np.prod(leaf.shape)) * jnp.dtype(leaf.dtype).itemsize
-                    for leaf in jax.tree_util.tree_leaves(self.params))
+            try:  # ONE forward's reads: a stack run again counts again
+                rec.param_bytes = registry.forward_weight_bytes(
+                    self.model_config, self.params)
+                # (models/registry.py; obs/steps.py's roofline model)
             except (TypeError, ValueError, AttributeError):
                 rec.param_bytes = 0
         rec.record(info.pop("kind"), wall_s,

@@ -41,6 +41,7 @@ import numpy as np
 from production_stack_tpu.engine.kvcache import KVCacheManager
 from production_stack_tpu.engine.sampling import apply_fsm_mask
 from production_stack_tpu.models import build_model, get_model_config
+from production_stack_tpu.models.registry import page_layers
 from production_stack_tpu.ops.attention import shard_paged_kernels
 from production_stack_tpu.parallel.sharding import (
     kv_pages_sharding,
@@ -97,7 +98,7 @@ class DraftModel:
             config.max_blocks_per_seq * config.max_num_seqs + 1)
         self._kv_sharding = kv_pages_sharding(mc, mesh)
         self._apply, _ = shard_paged_kernels(self._apply, self._kv_sharding)
-        kv_shape = (mc.num_layers, self.num_blocks, config.block_size,
+        kv_shape = (page_layers(mc), self.num_blocks, config.block_size,
                     mc.num_kv_heads, mc.head_dim)
 
         def _zeros():

@@ -2537,6 +2537,11 @@ class EngineServer:
             "# TYPE tpu:moe_experts_hit counter",
             f"tpu:moe_experts_hit_total{{{labels}}} "
             f"{s['family_stats_total'].get('moe_experts_hit', 0)}",
+            # Passes over the layer stack (models/ouro.py::STATS), 0 for
+            # a model whose layers run once.
+            "# TYPE tpu:loop_passes counter",
+            f"tpu:loop_passes_total{{{labels}}} "
+            f"{s['family_stats_total'].get('loop_passes', 0)}",
             # Disaggregated-prefill KV handoff (the NIXL-pipe equivalent).
             "# TYPE tpu:kv_transfer_tx_bytes counter",
             f"tpu:kv_transfer_tx_bytes_total{{{labels}}} {self.kv_transfer_tx_bytes}",

@@ -97,6 +97,11 @@ class ModelConfig:
     # Zero-compute experts: router outputs behind the experts with
     # weights that give the layer's input back (models/moe.py).
     zero_experts: int = 0
+    # A stack applied several times (ouro): how many passes a forward
+    # makes over the same layers, and the exit gate's threshold (1.0:
+    # every token takes every pass; the programs serve no other).
+    loop_passes: int = 1
+    early_exit_threshold: float = 1.0
     dtype: str = "bfloat16"
 
     @property
@@ -208,6 +213,14 @@ _PRESETS = {
         router_scoring="sigmoid", router_bias=True, q_lora_rank=48,
         kv_lora_rank=128, qk_nope_head_dim=24, qk_rope_head_dim=16,
         v_head_dim=32,
+    ),
+    # Three sandwich-normed layers run twice over the same weights, a
+    # query group of one (as Ouro's), a page layer for every pass.
+    "tiny-ouro": ModelConfig(
+        name="tiny-ouro", arch="ouro", vocab_size=512, hidden_size=128,
+        num_layers=3, num_heads=4, num_kv_heads=4, head_dim=32,
+        intermediate_size=256, max_position=2048, rope_theta=1000000.0,
+        rms_norm_eps=1e-6, loop_passes=2,
     ),
     "tiny-opt": ModelConfig(
         name="tiny-opt", arch="opt", vocab_size=512, hidden_size=128,

@@ -418,17 +418,17 @@ def scan_layers(step, carry: Tuple, layers: int | None = None, xs=None):
       it with :func:`take` at the layer's number.
 
     **Stretches.** Kinds of layer that do not interleave can each be a
-    stretch with no ``lax.cond`` between them (GLM: the dense prefix, then
+    stretch with no ``lax.cond`` between them (GLM: a dense prefix, then
     the sparse layers). Cut where a kind few layers take has small
     operands: the compiler prefetches a ``conditional``'s operands into
     VMEM in EVERY layer, read or not (GLM's dense MLP, 84 MB a layer, a
-    quarter of the device's time: ``tpot_p50_s`` 0.017064 -> 0.011392, PR
-    46), seen only in the program nested as the engine's burst nests it
-    (``scripts/hlo_digest.py``'s ``<name>.decode_k8``: ``copy-start`` with
-    ``S(1)`` in the loop's body). Do not cut without that reading: every
-    stretch is one more body in every step program, and the machine's
-    compile cache holds 192 MiB (PR 33). A part two stretches share goes
-    under ``jax.jit`` (:func:`latent_attention`)."""
+    quarter of the device's time, PR 46), seen only in the program nested
+    as the engine's burst nests it (``scripts/hlo_digest.py``'s
+    ``<name>.decode_k8``: ``copy-start`` with ``S(1)`` in the loop's
+    body). Every stretch is one more body in every step program, and the
+    compile cache holds 192 MiB (PR 33); a part two stretches share goes
+    under ``jax.jit`` (:func:`latent_attention`). **A stack applied
+    several times** is this scan inside :func:`scan_passes`, not a cut."""
     if layers == 0:
         return carry
 
@@ -553,3 +553,36 @@ def apply(
     x = take_last_token(x, last_token)
     out = family.head(params, cfg, x, output_hidden)
     return (out, kv_pages, stats) if with_stats else (out, kv_pages)
+
+
+def scan_passes(step, x: jax.Array, sides: Tuple, passes: int, xs, close):
+    """A stack of layers applied ``passes`` times over the same weights
+    (models/ouro.py): ONE ``lax.scan`` over the passes around ONE
+    :func:`scan_layers` over ``xs``, ``step(x, sides, layer, per_layer,
+    u) -> (x, sides)`` with ``layer`` counted from 0 in every pass and
+    ``u`` the pass, ``close(x)`` after a pass's last layer. Returns (x,
+    sides, passes run as an int32 scalar, counted on the carry).
+
+    The rule (PR 48, read in ``scripts/hlo_digest.py``'s ``ouro.*``
+    programs): nest, do not unroll and do not flatten. A Python loop of
+    ``passes`` stretches is ``passes`` bodies a step program. One stretch
+    of ``passes x L`` steps reading layer ``n % L`` with :func:`take`
+    needs a ``cond`` or a select for ``close`` in every step and gives up
+    the scan's own slices. Nested, a program holds one body of the
+    layer, the inner scan slices the stack in place as Llama's does
+    (the weights are loop constants of the outer scan: no copy, the
+    temporaries of ``memory_analysis()`` are a single pass's), and the
+    pool rides both carries in place, as it rides the engine's burst
+    around them: a family numbers its page layers by ``(layer, u)``."""
+    def one_pass(carry, u):
+        x, sides, done = carry
+
+        def layer_step(x, sides, layer, per_layer):
+            return *step(x, sides, layer, per_layer, u), None
+
+        x, sides, _, _ = scan_layers(layer_step, first_carry(x, sides),
+                                     xs=xs)
+        return (close(x), sides, done + 1), None
+
+    return jax.lax.scan(one_pass, (x, tuple(sides), jnp.int32(0)),
+                        jnp.arange(passes, dtype=jnp.int32))[0]

@@ -188,13 +188,13 @@ def attention_half(
     p: Dict,  # one layer's un-stacked leaves
     lora: Dict | None,  # un-stacked per-layer LoRA leaves, or None
     kv: Tuple,  # STACKED pages [L, NB, bs, KVH, D]
-    layer: jax.Array,  # scalar layer index
-    batch: decoder.Batch,
+    layer: jax.Array,  # scalar PAGE-layer index
+    batch: decoder.Batch, *, out=lambda y: y,  # on wo's output (ouro)
 ):
     """RMS norm, the fused ``wqkv`` projection behind the barrier, LoRA
-    deltas if slots are given, rotary, ``decoder.attend``, ``wo``: the
-    residual stream after attention, and the pages. Mixtral's layer is
-    this plus its expert MLP (models/mixtral.py)."""
+    deltas if slots are given, rotary, ``decoder.attend``, ``wo``, ``out``
+    before the residual: the stream after attention, and the pages.
+    Mixtral's layer is this plus its expert MLP (models/mixtral.py)."""
     B, T, Hd = x.shape
     H, KVH, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
 
@@ -221,7 +221,7 @@ def attention_half(
     attn, kv = decoder.attend(
         mode, q, k, v, kv, layer, batch, scale=1.0 / (D ** 0.5))
     with jax.named_scope("attn_proj"):
-        x = x + _proj(attn.reshape(B, T, H * D), p, "wo")
+        x = x + out(_proj(attn.reshape(B, T, H * D), p, "wo"))
     return x, kv
 
 
@@ -266,9 +266,9 @@ def embed_tokens(params: Dict, cfg: ModelConfig, token_ids: jax.Array,
 
 @jax.named_scope("head")
 def project_out(params: Dict, cfg: ModelConfig, x: jax.Array,
-                output_hidden: bool) -> jax.Array:
-    """Shared forward tail: final norm, then hidden states or logits."""
-    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+                output_hidden: bool, norm: bool = True) -> jax.Array:
+    """Shared forward tail: final norm (``norm``), then states or logits."""
+    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps) if norm else x
     if output_hidden:
         return x.astype(jnp.float32)
     head = params.get("lm_head")

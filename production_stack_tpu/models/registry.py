@@ -14,6 +14,8 @@ import dataclasses
 import importlib
 from typing import Callable, Mapping, Tuple
 
+import jax
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from production_stack_tpu.models.config import ModelConfig
@@ -27,6 +29,7 @@ ARCH_MODULES = {
     "lfm2": "production_stack_tpu.models.lfm2",
     "longcat": "production_stack_tpu.models.longcat",
     "glm4_moe_lite": "production_stack_tpu.models.glm4_moe_lite",
+    "ouro": "production_stack_tpu.models.ouro",
 }
 
 
@@ -96,10 +99,18 @@ class Family:
     # (models/config.py names no family's; called for any family that
     # has one, with or without ``per_layer_keys``).
     config_fields: Callable | None = None
-    # ``(cfg) -> int``: how many of its layers hold KV pages, where not
-    # all do: the pool's pages are ``[that many, NB, bs, ...]`` and the
-    # family numbers them itself. None: every layer.
+    # ``(cfg) -> int``: how many page layers a token's cache has, where
+    # that is not one a layer: fewer (only some layers hold keys and
+    # values) or more (a stack applied several times keeps a page layer
+    # for every pass of every layer, models/ouro.py). The pool's pages are
+    # ``[that many, NB, bs, ...]`` and the family numbers them itself.
+    # None: one a layer.
     page_layers: Callable | None = None
+    # ``(cfg) -> int``: how many times one forward runs (and so reads)
+    # the stack ``params["layers"]``. None: once. What a forward reads of
+    # the weights is :func:`forward_weight_bytes`, which the step
+    # recorder's roofline model counts (obs/steps.py).
+    layer_passes: Callable | None = None
     # ``(cfg) -> (layers, rows, width)`` of a state per cache block that
     # rides beside the pages as the pool's third side ``[layers, NB, rows,
     # width]`` (models/decoder.py::read_block_state; docs/engine.md):
@@ -153,6 +164,19 @@ def page_layers(cfg: ModelConfig) -> int:
     """Layers of ``cfg``'s model that hold KV pages."""
     held = get_family(cfg.arch).page_layers
     return cfg.num_layers if held is None else held(cfg)
+
+
+def forward_weight_bytes(cfg: ModelConfig, params) -> int:
+    """Bytes of weights one forward reads from ``params`` (arrays or
+    shapes): every leaf once, the stack ``params["layers"]`` as many
+    times as the family runs it (``Family.layer_passes``)."""
+    def held(tree) -> int:
+        return sum(int(np.prod(leaf.shape)) * np.dtype(leaf.dtype).itemsize
+                   for leaf in jax.tree_util.tree_leaves(tree))
+
+    passes = get_family(cfg.arch).layer_passes
+    again = 0 if passes is None else passes(cfg) - 1
+    return held(params) + again * held(params.get("layers", {}))
 
 
 def block_state_shape(cfg: ModelConfig) -> Tuple[int, int, int] | None:

@@ -12,6 +12,14 @@ byte count from a small roofline model:
           + kv_read_tokens  × kv_token_bytes  (paged-attention KV reads)
           + kv_write_tokens × kv_token_bytes  (KV page writes)
 
+``param_bytes`` is what ONE forward reads of the weights
+(``models/registry.py::forward_weight_bytes``): the parameter tree's
+bytes, the layer stack counted once for every pass a family makes over it
+(``Family.layer_passes``: 4 x the 48 layers + the rest = 20.1 GB of
+Ouro-2.6B's 5.34 GB tree), so the utilization means for a looped model
+what it means for the others. ``kv_token_bytes`` covers every page layer
+of a token (a page layer a pass and layer there).
+
 That is the same weights+KV traffic model behind
 ``BENCH_DECODE_PROFILE_r05.json``'s floors, so the derived
 ``tpu:model_bandwidth_utilization`` gauge (achieved bytes/s over the
@@ -253,9 +261,10 @@ class StepRecorder:
         window_s: float = 60.0,
     ):
         self.capacity = max(1, int(capacity))
-        # Roofline constants. param_bytes is often unknown at construction
-        # (weights load after the recorder exists); the core fills it in
-        # lazily before the first record.
+        # Roofline constants. param_bytes (the weight bytes one forward
+        # reads) is often unknown at construction (weights load after the
+        # recorder exists); the core fills it in lazily before the first
+        # record.
         self.param_bytes = int(param_bytes)
         self.kv_token_bytes = int(kv_token_bytes)
         # None: no peak is known for this device (the CPU), and the

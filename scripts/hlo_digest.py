@@ -2,7 +2,8 @@
 of models.laguna.apply at laguna-s-2.1-l8e64's, bf16: PR 36; of
 models.lfm2.apply at lfm2-24b-a2b-l10's: PR 40; of models.longcat.apply
 at longcat-flash-l4e16's: PR 44; of models.glm4_moe_lite.apply at
-glm-4.7-flash-e8v8's and LongCat's cached tails: PR 45), for
+glm-4.7-flash-e8v8's and LongCat's cached tails: PR 45; of
+models.ouro.apply at ouro-2.6b's, 48 layers x 4 passes: PR 48), for
 a refactor that must not change the program (PR 31): run it on a copy of
 the parent and on the change and compare the digests; no chip needed.
 Each of the four later families also as the engine's decode burst nests
@@ -13,7 +14,7 @@ of a cell's device time for two PRs (GLM's dense weights prefetched into
 VMEM in every layer of the burst and in no layer of ``glm.decode``).
 
     JAX_PLATFORMS=cpu python scripts/hlo_digest.py <repo-root> <out-dir> \
-        [--only mistral|laguna|lfm2|longcat|glm]
+        [--only mistral|laguna|lfm2|longcat|glm|ouro]
 
 Three modes (decode 32 x 1, plain prefill 1 x 512, cached prefill 1 x 256,
 the server's default 8 LoRA slots) x {bf16, int8 weights}, compiled by the
@@ -340,5 +341,26 @@ if glm4_moe_lite is not None:  # (PR 45) every cached bucket the agent
                     ("prefill_cached", 1, 256, 128),
                     ("prefill_cached", 1, 512, 128),
                     ("prefill_cached", 1, 1024, 128), (BURST, 32, 8, 128)))
+try:  # a tree before PR 48 has no such family
+    from production_stack_tpu.models import ouro
+except ImportError:
+    ouro = None
+
+
+def ouro_pool(c):
+    """A page layer for every pass of every layer (192 at Ouro-2.6B's
+    sizes), 72 blocks of 64 tokens: about what one chip holds."""
+    from production_stack_tpu.engine.core import kv_page_dims
+
+    layers, rows, lanes = kv_page_dims(c)
+    pages = spec((layers, 72, BS, rows, lanes), jnp.bfloat16)
+    return pages, pages
+
+
+if ouro is not None:  # (PR 48) the reasoning cell's rows, its usual
+    # cached bucket under the 32-block table, a whole plain chunk
+    family_digests("ouro", "ouro-2.6b", ouro, ouro_pool,
+                   (("decode", 8, 1, 32), ("prefill", 1, 512, 32),
+                    ("prefill_cached", 1, 256, 32), (BURST, 8, 8, 32)))
 with open(os.path.join(out, "digests.json"), "w") as f:
     json.dump({"text": digests, "ops": ops, "memory": memory}, f, indent=1)

@@ -615,16 +615,12 @@ def test_latent_decode_kernel_compiles_at_twenty_heads(one_chip, rows,
     assert program.memory_analysis().temp_size_in_bytes == 0
 
 
-def _glm47_flash(tmp_path, monkeypatch, sharding):
-    """(cfg, the weights' shapes, ``spec``) of ``glm-4.7-flash-e8v8`` as
-    the benchmark serves it (the model keys of its file, written to a
-    ``config.json`` as ``chipbench.stack`` does), all 47 layers, with the
-    kernels the chip takes."""
+def _benchmark_config(tmp_path, name):
+    """The ``ModelConfig`` of ``chipbench/configs/<name>.json``: its model
+    keys written to a ``config.json`` as ``chipbench.stack`` does."""
     import json
     import os
     import sys
-
-    from production_stack_tpu.models import glm4_moe_lite
 
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     if repo not in sys.path:
@@ -632,10 +628,20 @@ def _glm47_flash(tmp_path, monkeypatch, sharding):
     from chipbench.registry import model_keys
 
     with open(os.path.join(repo, "chipbench", "configs",
-                           "glm-4.7-flash-e8v8.json")) as f:
+                           name + ".json")) as f:
         (tmp_path / "config.json").write_text(
             json.dumps(model_keys(json.load(f))))
-    cfg = get_model_config(str(tmp_path))
+    return get_model_config(str(tmp_path))
+
+
+def _glm47_flash(tmp_path, monkeypatch, sharding):
+    """(cfg, the weights' shapes, ``spec``) of ``glm-4.7-flash-e8v8`` as
+    the benchmark serves it (the model keys of its file, written to a
+    ``config.json`` as ``chipbench.stack`` does), all 47 layers, with the
+    kernels the chip takes."""
+    from production_stack_tpu.models import glm4_moe_lite
+
+    cfg = _benchmark_config(tmp_path, "glm-4.7-flash-e8v8")
     assert (cfg.num_layers, cfg.num_heads, cfg.dense_layers) == (47, 20, 1)
     monkeypatch.setattr(att, "_use_pallas", lambda: True)
     monkeypatch.setattr(gmm, "_platform", lambda: "tpu")
@@ -853,3 +859,118 @@ def test_a_side_a_branch_hands_back_as_it_got_it_is_not_copied(one_chip):
 
     assert not copies_of_a_side(decoder.by_layer)
     assert copies_of_a_side(plain)
+
+
+def _ouro_26b(tmp_path, monkeypatch, sharding):
+    """(cfg, the weights' shapes, ``spec``, the pool's sides) of
+    ``ouro-2.6b`` as the benchmark serves it: all 48 layers, 4 passes,
+    192 page layers, a pool of 78 blocks of 96 MiB."""
+    from production_stack_tpu.models import ouro
+
+    cfg = _benchmark_config(tmp_path, "ouro-2.6b")
+    assert (cfg.num_layers, cfg.loop_passes, cfg.num_kv_heads) == (48, 4, 16)
+    monkeypatch.setattr(att, "_use_pallas", lambda: True)
+    att.TRACED_PATHS.clear()
+
+    def spec(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    params = jax.tree_util.tree_map(
+        lambda x: spec(x.shape, x.dtype),
+        jax.eval_shape(lambda: ouro.init_params(cfg, jax.random.key(0))))
+    pages = spec((192, 78, BLOCK_SIZE, 16, 128), jnp.bfloat16)
+    return cfg, params, spec, (pages, pages)
+
+
+def _copies_of_the_stack_or_the_pool(text):
+    """Lines of the optimised HLO that copy a 48-layer leaf or a side of
+    the 192-layer pool."""
+    return [line.strip()[:120] for line in text.splitlines()
+            if re.search(r"= \(?bf16\[(48,\d{4},\d{4}|192,78,64,16,128)\]"
+                         r"\S* copy(-start)?\(", line)]
+
+
+@pytest.mark.parametrize("mode,rows,width,tables", [
+    ("decode", 8, 1, 32), ("prefill", 1, 512, 8),
+    ("prefill_cached", 1, 256, 32), ("prefill_cached", 1, 1024, 32)])
+def test_ouro_programs_compile_at_the_published_sizes(
+        one_chip, monkeypatch, tmp_path, mode, rows, width, tables):
+    """``ouro-2.6b`` as the benchmark serves it, nothing cut: the tree is
+    the 5.34 GB the configuration states; each forward program compiles
+    for the v5e with the Pallas kernel of its mode at a query group of
+    one, holds ONE body of the layer (two ``while``s: the passes around
+    the layers) however many passes run, copies neither a leaf of the
+    48-layer stack nor a side of the 192-layer pool, and keeps its
+    temporaries a single pass's (megabytes beside a 4.9 GB stack)."""
+    from production_stack_tpu.models import ouro
+
+    cfg, params, spec, pages = _ouro_26b(tmp_path, monkeypatch, one_chip)
+    weights = sum(int(np.prod(x.shape)) * x.dtype.itemsize
+                  for x in jax.tree_util.tree_leaves(params))
+    assert abs(weights / 5.34e9 - 1) < 0.005
+    last = mode != "decode"
+    program = jax.jit(
+        lambda p, kv, tok, pos, slot, bt, cl, sl: ouro.apply(
+            p, cfg, tok, pos, kv, slot, bt, cl, sl, mode=mode,
+            last_token=jnp.maximum(sl - 1, 0) if last else None,
+            with_stats=True), donate_argnums=(1,)).lower(
+        params, pages, spec((rows, width)), spec((rows, width)),
+        spec((rows, width)), spec((rows, tables)), spec((rows,)),
+        spec((rows,))).compile()
+    text = program.as_text()
+    assert ("pallas_paged_attention" in text) == (mode == "decode")
+    assert ("pallas_prefill_attention" in text) == (mode == "prefill_cached")
+    bodies = _while_bodies(text)
+    assert len(bodies) == 2
+    layer_loop = [lines for lines in bodies.values()
+                  if not any(" while(" in line for line in lines)]
+    assert len(layer_loop) == 1
+    assert not _copies_of_the_stack_or_the_pool(text)
+    assert program.memory_analysis().temp_size_in_bytes < (
+        4e6 if mode == "decode" else 64e6)
+
+
+def test_ouro_decode_burst_keeps_stack_and_pool_in_place(
+        one_chip, monkeypatch, tmp_path):
+    """The decode step nested as the engine nests it (a scan over the
+    burst's 8 steps around the passes around the layers, the pool on all
+    three carries): three ``while``s, the kernel in the innermost alone,
+    no ``conditional``, no copy of a leaf or of a side, and no
+    ``copy-start`` over 1 MB in any loop's body (GLM's lesson, PR 46:
+    ``apply`` compiled alone does not show what the burst prefetches)."""
+    from production_stack_tpu.models import ouro
+
+    cfg, params, spec, pages = _ouro_26b(tmp_path, monkeypatch, one_chip)
+    rows, steps, tables = 8, 8, 32
+
+    def burst(p, kv, tokens, positions, slots, block_tables, contexts):
+        def step(carry, step_slots):
+            tokens, kv, s = carry
+            logits, kv, stats = ouro.apply(
+                p, cfg, tokens[:, None], (positions + s)[:, None], kv,
+                step_slots[:, None], block_tables, contexts + s,
+                jnp.ones_like(contexts), mode="decode", with_stats=True)
+            sampled = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
+            return (sampled, kv, s + 1), (sampled, stats)
+
+        (_, kv, _), (out, stats) = jax.lax.scan(
+            step, (tokens, kv, jnp.int32(0)), slots.T)
+        return out.T, kv, stats.sum(axis=0)
+
+    program = jax.jit(burst, donate_argnums=(1,)).lower(
+        params, pages, spec((rows,)), spec((rows,)), spec((rows, steps)),
+        spec((rows, tables)), spec((rows,))).compile()
+    text = program.as_text()
+    bodies = _while_bodies(text)
+    assert len(bodies) == 3
+    with_kernel = [lines for lines in bodies.values()
+                   if any("pallas_paged_attention" in line for line in lines)
+                   and not any(" while(" in line for line in lines)]
+    assert len(with_kernel) == 1
+    assert " conditional(" not in text
+    assert not _copies_of_the_stack_or_the_pool(text)
+    large = [line.strip()[:100] for lines in bodies.values()
+             for line in lines
+             if " copy-start(" in line and _bytes_copied(line) > 1 << 20]
+    assert not large, large
+    assert program.memory_analysis().temp_size_in_bytes < 4e6

@@ -27,7 +27,7 @@ ARCHS = sorted(ARCH_MODULES)
 PRESET = {"llama": "tiny-llama", "opt": "tiny-opt", "mixtral": "tiny-mixtral",
           "laguna": "tiny-laguna", "lfm2": "tiny-lfm2",
           "longcat": "tiny-longcat",
-          "glm4_moe_lite": "tiny-glm4-moe-lite"}
+          "glm4_moe_lite": "tiny-glm4-moe-lite", "ouro": "tiny-ouro"}
 # What a family's config.json must hold beside the sizes every family
 # reads (``Family.per_layer_keys``: lists, one entry a layer or more).
 REQUIRED_KEYS = {"laguna": {
@@ -52,7 +52,10 @@ REQUIRED_KEYS = {"laguna": {
     "qk_rope_head_dim": 8, "v_head_dim": 16, "n_routed_experts": 4,
     "n_shared_experts": 1, "num_experts_per_tok": 2, "n_group": 1,
     "topk_group": 1, "first_k_dense_replace": 1, "norm_topk_prob": True,
-    "routed_scaling_factor": 1.8, "num_nextn_predict_layers": 1}}
+    "routed_scaling_factor": 1.8, "num_nextn_predict_layers": 1},
+    "ouro": {
+    "intermediate_size": 48, "num_key_value_heads": 4, "total_ut_steps": 2,
+    "early_exit_threshold": 1}}
 SIZES = dict(vocab_size=64, hidden_size=32, num_hidden_layers=2,
              num_attention_heads=4, max_position_embeddings=64)
 
@@ -62,7 +65,7 @@ def _hf_model(arch):
     family brings its line)."""
     import transformers as tf
 
-    if arch in ("laguna", "lfm2", "longcat", "glm4_moe_lite"):
+    if arch in ("laguna", "lfm2", "longcat", "glm4_moe_lite", "ouro"):
         return None  # no class of it here (or no loader yet), no checkpoint
     return {
         "llama": lambda: tf.LlamaForCausalLM(tf.LlamaConfig(
