@@ -118,13 +118,11 @@ def _ablate(core, *, attn=None, write=None, sample=False):
         saved[("decoder", "write_kv_pages")] = decoder.write_kv_pages
         decoder.write_kv_pages = write
     if sample:
-        saved[("core", "sample_tokens")] = core_mod.sample_tokens
-        saved[("core", "logprob_outputs")] = core_mod.logprob_outputs
-        core_mod.sample_tokens = (
-            lambda logits, keys, t, k, p, max_top_k=64:
-            jnp.argmax(logits, axis=-1))
-        core_mod.logprob_outputs = (
-            lambda logits, sampled, k=8: (
+        saved[("core", "sample_with_logprobs")] = (
+            core_mod.sample_with_logprobs)
+        core_mod.sample_with_logprobs = (
+            lambda logits, keys, t, k, p, max_top_k=64: (
+                jnp.argmax(logits, axis=-1),
                 jnp.zeros(logits.shape[0], jnp.float32),
                 jnp.zeros((logits.shape[0], 8), jnp.float32),
                 jnp.zeros((logits.shape[0], 8), jnp.int32)))
@@ -181,9 +179,8 @@ def _bench_sampling_standalone(core, K=16, reps=REPS):
     import numpy as np
 
     from production_stack_tpu.engine.sampling import (
-        logprob_outputs,
         make_rng_keys,
-        sample_tokens,
+        sample_with_logprobs,
     )
 
     B, V = core.config.max_num_seqs, core.model_config.vocab_size
@@ -203,8 +200,8 @@ def _bench_sampling_standalone(core, K=16, reps=REPS):
             penalized = (logits - fp[:, None] * counts
                          - pp[:, None] * (counts > 0))
             keys = make_rng_keys(0, 0, jnp.zeros((B,), jnp.int64) + s)
-            sampled = sample_tokens(penalized, keys, temp, topk, topp)
-            lp, top_lp, top_ids = logprob_outputs(penalized, sampled)
+            sampled, lp, top_lp, top_ids = sample_with_logprobs(
+                penalized, keys, temp, topk, topp)
             counts = counts.at[jnp.arange(B), sampled].add(1)
             return (counts, acc + sampled), None
         (counts, acc), _ = jax.lax.scan(

@@ -30,10 +30,12 @@ from production_stack_tpu.engine.sampling import (
     MAX_STOP_IDS,
     SamplingParams,
     accepted_prefix_len,
-    apply_fsm_mask,
-    logprob_outputs,
+    allowed_tokens,
+    apply_penalties,
+    burst_terms,
     make_rng_keys,
-    sample_tokens,
+    sample_with_logprobs,
+    shape_logits,
 )
 from production_stack_tpu.engine.scheduler import (
     EngineRequest,
@@ -1109,33 +1111,22 @@ class EngineCore:
             )
             with jax.named_scope("sample"):
                 last = logits[:, 0]
-                B = last.shape[0]
-                shaped = last.at[
-                    jnp.arange(B)[:, None], bias_ids].add(bias_vals)
-                if eos_id >= 0:  # min_tokens: mask EOS for the first token
-                    shaped = jnp.where(
-                        suppress_eos[:, None]
-                        & (jnp.arange(shaped.shape[1])[None, :]
-                           == eos_id),
-                        -jnp.inf, shaped)
-                # stop_token_ids share the min_tokens mask (finite
-                # sentinel: -inf * 0 padding would make NaNs).
-                shaped = shaped.at[jnp.arange(B)[:, None], stop_ids].add(
-                    -1e30 * stop_valid
-                    * suppress_eos.astype(jnp.float32)[:, None])
-                # Structured output: grammar FSM mask (packed bitset rows;
-                # all-off for unconstrained sequences).
-                shaped = apply_fsm_mask(shaped, mask_bits, mask_on)
+                # logit_bias, then (min_tokens: for the first token) EOS
+                # and the stop ids masked, then the grammar's FSM mask
+                # (all-off for unconstrained sequences).
+                shaped = shape_logits(
+                    last, burst_terms(
+                        last.shape[1], bias_ids, bias_vals, stop_ids,
+                        stop_valid, mask_bits, mask_on),
+                    suppress_eos, eos_id)
                 # per row, so a row of a group samples as it would alone
                 keys = make_rng_keys(seed_static, steps, seq_seeds + steps)
-                sampled = sample_tokens(
-                    shaped, keys, temperature, top_k, top_p,
-                    max_top_k=max_top_k)
                 # Logprobs reflect the distribution actually sampled from
                 # (logit_bias + min_tokens masking applied), matching
                 # OpenAI/vLLM post-processor logprob semantics.
-                lp, top_lp, top_ids = logprob_outputs(shaped, sampled)
-                return ((sampled, lp, top_lp, top_ids), kv, *stats)
+                return (sample_with_logprobs(
+                    shaped, keys, temperature, top_k, top_p,
+                    max_top_k=max_top_k), kv, *stats)
 
         # The program's name in a profiler trace (the XLA Modules line)
         # and in the step records: ``prefill`` or ``prefill_cached``.
@@ -1193,6 +1184,16 @@ class EngineCore:
                 B = tokens0.shape[0]
                 counts = counts.at[jnp.arange(B), tokens0].add(
                     reset_counts.astype(jnp.int32))
+                # logit_bias, the stop ids and the structured mask are
+                # constant across the scan (the host advances a grammar's
+                # automaton only at burst boundaries, so structured rows
+                # are scheduled with allow=1 — steps past the first are
+                # discarded at emission and their stale mask never
+                # reaches a stream): their dense forms are built once
+                # here, not in each step.
+                terms = burst_terms(
+                    counts.shape[1], bias_ids, bias_vals, stop_ids,
+                    stop_valid, mask_bits, mask_on)
 
             def body(carry, step_slots):
                 tokens, kv, counts, s = carry
@@ -1203,43 +1204,19 @@ class EngineCore:
                     adapter_ids=adapter_ids, **with_stats,
                 )
                 with jax.named_scope("sample"):
-                    raw = logits[:, 0]
                     # OpenAI presence/frequency penalties over the slot's
-                    # OUTPUT tokens, plus sparse logit_bias and min_tokens
-                    # EOS masking. Logprobs are computed from these shaped
-                    # logits (OpenAI/vLLM post-processor semantics).
-                    penalized = (
-                        raw
-                        - frequency_penalty[:, None] * counts
-                        - presence_penalty[:, None] * (counts > 0)
-                    )
-                    penalized = penalized.at[
-                        jnp.arange(B)[:, None], bias_ids].add(bias_vals)
-                    suppress = (out_len0 + s) < min_tokens  # [B]
-                    if eos_id >= 0:
-                        penalized = jnp.where(
-                            suppress[:, None]
-                            & (jnp.arange(penalized.shape[1])[None, :]
-                               == eos_id),
-                            -jnp.inf, penalized)
-                    # stop_token_ids share the min_tokens mask (finite
-                    # sentinel: -inf * 0 padding would make NaNs).
-                    penalized = penalized.at[
-                        jnp.arange(B)[:, None], stop_ids].add(
-                        -1e30 * stop_valid
-                        * suppress.astype(jnp.float32)[:, None])
-                    # Structured output: the FSM mask is constant across the
-                    # scan (the host advances the automaton only at burst
-                    # boundaries), so structured rows are scheduled with
-                    # allow=1 — steps past the first are discarded at
-                    # emission and their stale mask never reaches a stream.
-                    penalized = apply_fsm_mask(penalized, mask_bits, mask_on)
+                    # OUTPUT tokens, plus logit_bias, min_tokens EOS /
+                    # stop-id masking and the structured mask. Logprobs
+                    # are computed from these shaped logits (OpenAI/vLLM
+                    # post-processor semantics).
+                    penalized = shape_logits(
+                        apply_penalties(logits[:, 0], counts,
+                                        frequency_penalty, presence_penalty),
+                        terms, (out_len0 + s) < min_tokens, eos_id)
                     keys = make_rng_keys(seed, 0, seed_base + s)
-                    sampled = sample_tokens(
+                    sampled, lp, top_lp, top_ids = sample_with_logprobs(
                         penalized, keys, temperature, top_k, top_p,
-                        max_top_k=max_top_k,
-                    )
-                    lp, top_lp, top_ids = logprob_outputs(penalized, sampled)
+                        max_top_k=max_top_k)
                     # Only steps whose page slot is live count (masked
                     # speculative steps are discarded at emission).
                     live = (step_slots >= 0).astype(jnp.int32)
@@ -1327,35 +1304,28 @@ class EngineCore:
             )
             with jax.named_scope("sample"):
                 # Per-position logit shaping + sampling, identical to the
-                # decode scan body (K is small — unrolled).
+                # decode scan body (K is small — unrolled); what the
+                # positions share is built once.
+                shared = burst_terms(
+                    logits.shape[-1], bias_ids, bias_vals, stop_ids,
+                    stop_valid, mask_bits[:, 0], mask_on[:, 0])
                 outs, lp_l, top_lp_l, top_id_l = [], [], [], []
                 for s in range(K):
-                    penalized = logits[:, s].at[
-                        jnp.arange(B)[:, None], bias_ids].add(bias_vals)
-                    suppress = (out_len0 + s) < min_tokens  # [B]
-                    if eos_id >= 0:
-                        penalized = jnp.where(
-                            suppress[:, None]
-                            & (jnp.arange(penalized.shape[1])[None, :]
-                               == eos_id),
-                            -jnp.inf, penalized)
-                    penalized = penalized.at[
-                        jnp.arange(B)[:, None], stop_ids].add(
-                        -1e30 * stop_valid
-                        * suppress.astype(jnp.float32)[:, None])
                     # Structured output: position s's mask is precomputed on
                     # the host from the FSM state AFTER drafts 0..s-1 —
                     # exactly the mask plain decode would apply at that step,
                     # so drafts that exit the language are rejected here by
                     # the same term (mask_bits [B, K, MB], mask_on [B, K]).
-                    penalized = apply_fsm_mask(
-                        penalized, mask_bits[:, s], mask_on[:, s])
+                    terms = shared if s == 0 else shared._replace(
+                        allowed=allowed_tokens(mask_bits[:, s], mask_on[:, s],
+                                               logits.shape[-1]))
+                    penalized = shape_logits(
+                        logits[:, s], terms, (out_len0 + s) < min_tokens,
+                        eos_id)
                     keys = make_rng_keys(seed, 0, seed_base + s)
-                    sampled = sample_tokens(
+                    sampled, lp, top_lp, top_ids = sample_with_logprobs(
                         penalized, keys, temperature, top_k, top_p,
-                        max_top_k=max_top_k,
-                    )
-                    lp, top_lp, top_ids = logprob_outputs(penalized, sampled)
+                        max_top_k=max_top_k)
                     outs.append(sampled)
                     lp_l.append(lp)
                     top_lp_l.append(top_lp)

@@ -1,17 +1,42 @@
-"""On-device batched sampling: greedy / temperature / top-k / top-p.
+"""On-device batched sampling: greedy / temperature / top-k / top-p, the
+logit shaping before it and the logprob outputs beside it.
 
-One jitted function with static batch width samples the whole decode batch:
-per-sequence temperature, top-k, top-p and seeds are *data*, not trace
-constants, so mixed sampling configs never recompile. Top-k/top-p operate on
-the top ``max_top_k`` logits only (one ``lax.top_k``), which keeps the
-sort lane-friendly and bounds VMEM.
+Per-sequence temperature, top-k, top-p, seeds, ``logit_bias``, stop ids,
+penalties and the structured mask are *data*, not trace constants, so
+mixed sampling configs never recompile, and every step computes all of
+it for all ``B`` rows whether or not a request asked. At one live row of a
+65,536-entry vocabulary that is not "nothing next to the forward": on a
+v5e the ``sample`` scope of ``lfm2-sessions`` took 610 us of a 3.15 ms
+decode step (the head's own 268 MB another 359 us; PERF.md section 6,
+PR 49), because a step passed over its ``[B, V]`` logits a dozen times.
+So the tail of a step is held to this shape:
+
+- what a burst holds constant is built once a burst (``burst_terms``:
+  the bias, the stop ids and the mask in dense ``[B, V]`` forms, before
+  the step loop), and a step applies it in ONE element-wise pass
+  (``shape_logits``), which the compiler fuses behind the head's dot;
+- ONE selection, the ``max(max_top_k, LOGPROB_K)`` best in order
+  (``sample_with_logprobs``), answers the greedy token, the sampler's
+  candidates and the top logprobs; top-k/top-p operate on those
+  candidates only, which keeps the sort lane-friendly and bounds VMEM.
+  The chip's ``TopK`` of 64 over the whole row was 460 of those 610 us,
+  so the selection reads the row once for a maximum a group of 128 ids
+  and hands ``TopK`` the 64 best groups' candidates (``exact_top_k``:
+  the same list to the bit and to the tie);
+- ONE reduction, the log-sum-exp's sum (its maximum is the selection's
+  rank 0);
+- the only scatter left in a step is the penalty counts' one entry a row.
+
+``tests/test_sampling_tail.py`` holds this tail to the one it replaced bit
+for bit; ``tests/test_chip_compile.py`` holds the compiled step loop to
+a ``TopK`` of candidates, one scatter and one float32 ``[B, V]``.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -125,25 +150,222 @@ class SamplingParams:
         )
 
 
+# Static top-K for logprob outputs baked into the serving programs
+# (requests clamp their top_logprobs to this, so no recompile per request).
+# The outputs are computed for every row of every step whether or not a
+# request asked: they ride on the sampler's one selection and the one
+# log-sum-exp reduction, see ``sample_with_logprobs``.
+LOGPROB_K = 8
+
+# Sparse logit_bias capacity baked into the serving programs (OpenAI caps
+# requests at 300 entries; 32 covers real use — requests exceeding it are
+# rejected with a 400 at the API layer rather than silently truncated).
+MAX_LOGIT_BIAS = 32
+
+# stop_token_ids capacity in the serving programs (masked alongside EOS
+# while min_tokens is unmet, vLLM semantics).
+MAX_STOP_IDS = 8
+
+# Structured-output FSM mask: finite large-negative (like the stop-id
+# term) so temperature scaling can't produce NaNs the way -inf can.
+FSM_MASK_NEG = -1e30
+
+# What a suppressed stop id's logit is lowered by (finite: ``-inf * 0``
+# padding would make NaNs).
+STOP_ID_NEG = -1e30
+
+
+def allowed_tokens(mask_bits: jax.Array, mask_on: jax.Array,
+                   vocab: int) -> jax.Array:
+    """The structured-output mask unpacked: ``bool [B, vocab]``, token
+    ``v`` of row ``b`` allowed.
+
+    ``mask_bits`` is ``uint8 [B, ceil(V/8)]`` with bit ``v`` of row
+    ``b`` (little bitorder, ``numpy.packbits`` layout) = token ``v``
+    allowed; ``mask_on [B] bool`` gates rows so unconstrained sequences
+    allow everything. Dense rather than sparse: a grammar state
+    routinely allows hundreds of tokens, far past the ``MAX_LOGIT_BIAS``
+    sparse capacity, and the packed row is only ``V/8`` bytes of
+    host->device traffic. A data-shaped input, so adding it compiles
+    zero new program variants."""
+    B, MB = mask_bits.shape
+    # Shift-and-reshape unpack (no gather): byte v//8 bit v%8 -> token v.
+    shifts = jnp.arange(8, dtype=jnp.uint8)
+    bits = (mask_bits[:, :, None] >> shifts[None, None, :]) & jnp.uint8(1)
+    bits = bits.reshape(B, MB * 8)[:, :vocab]
+    return (bits != 0) | (~mask_on)[:, None]
+
+
+def apply_fsm_mask(logits: jax.Array, mask_bits: jax.Array,
+                   mask_on: jax.Array) -> jax.Array:
+    """``logits`` with the tokens the grammar forbids at ``FSM_MASK_NEG``;
+    rows with ``mask_on`` False pass through bit-identically."""
+    allowed = allowed_tokens(mask_bits, mask_on, logits.shape[-1])
+    return jnp.where(allowed, logits, FSM_MASK_NEG)
+
+
+class BurstTerms(NamedTuple):
+    """What a burst holds constant of a request's logit shaping, in the
+    dense ``[B, V]`` forms a step applies element-wise."""
+    bias: jax.Array     # f32: the row's logit_bias, -0.0 where it has none
+    stops: jax.Array    # u8: how many of the row's stop ids name the token
+    allowed: jax.Array  # bool: ``allowed_tokens``
+
+
+def burst_terms(vocab: int, bias_ids, bias_vals, stop_ids, stop_valid,
+                mask_bits, mask_on) -> BurstTerms:
+    """The sparse ``logit_bias`` (``[B, MAX_LOGIT_BIAS]`` ids and values,
+    padded with id 0 / 0.0) and ``stop_token_ids`` (``[B, MAX_STOP_IDS]``
+    ids, ``stop_valid`` 1.0 / 0.0) and the packed structured mask, each
+    scattered or unpacked to ``[B, vocab]`` ONCE: they are arguments of a
+    burst, not of a step (the host advances a grammar's automaton only
+    between bursts), so the step loop calls this before ``lax.scan`` and
+    its body holds no scatter and no unpack of them. The bias starts
+    from -0.0, the one float that changes no bit of what it is added to,
+    so ``x + bias`` is ``x`` wherever a row has no entry and ``x + v``
+    where it has one, as the in-place scatter gave (ids are distinct:
+    they are a dict's keys)."""
+    rows = jnp.arange(bias_ids.shape[0])[:, None]
+    bias = jnp.full((bias_ids.shape[0], vocab), -0.0, jnp.float32)
+    bias = bias.at[rows, bias_ids].add(bias_vals)
+    stops = jnp.zeros((stop_ids.shape[0], vocab), jnp.uint8)
+    stops = stops.at[rows, stop_ids].add(stop_valid.astype(jnp.uint8))
+    # Behind a barrier: what a loop holds constant and could fuse into its
+    # body the compiler sinks into the loop again (the mask's unpack, with
+    # its transposing copy, in every step).
+    return jax.lax.optimization_barrier(BurstTerms(
+        bias, stops, allowed_tokens(mask_bits, mask_on, vocab)))
+
+
+def apply_penalties(raw: jax.Array, counts: jax.Array,
+                    frequency_penalty: jax.Array,
+                    presence_penalty: jax.Array) -> jax.Array:
+    """OpenAI presence / frequency penalties (``[B]`` each) over the
+    slot's OUTPUT tokens so far (``counts [B, V]`` int32)."""
+    return (raw - frequency_penalty[:, None] * counts
+            - presence_penalty[:, None] * (counts > 0))
+
+
+def shape_logits(logits: jax.Array, terms: BurstTerms, suppress: jax.Array,
+                 eos_id: int) -> jax.Array:
+    """One element-wise pass from ``[B, V]`` float32 logits (the head's,
+    penalised where the program keeps counts) to the distribution a step
+    samples from and reports logprobs of (OpenAI/vLLM post-processor
+    semantics), in this order: ``logit_bias``, then while ``suppress
+    [B]`` (min_tokens unmet) EOS at -inf and the stop ids lowered by
+    ``STOP_ID_NEG`` each, then the structured mask. ``eos_id`` < 0: the
+    tokenizer has none."""
+    shaped = logits + terms.bias
+    if eos_id >= 0:
+        shaped = jnp.where(
+            suppress[:, None]
+            & (jnp.arange(shaped.shape[1])[None, :] == eos_id),
+            -jnp.inf, shaped)
+    # 0 stops or not suppressed: + -0.0, which changes no bit
+    shaped = shaped + STOP_ID_NEG * (
+        terms.stops * suppress[:, None]).astype(jnp.float32)
+    return jnp.where(terms.allowed, shaped, FSM_MASK_NEG)
+
+
+# A vocabulary is selected from in groups of this many consecutive ids:
+# one row of lanes of a float32 tile.
+TOP_K_GROUP = 128
+
+
+def exact_top_k(logits: jax.Array, k: int):
+    """``lax.top_k(logits, k)`` over ``[B, V]``, values and ids, to the
+    bit and to the tie (of equal values the lower id first), at a
+    fraction of its cost where the vocabulary is wide. On a v5e the
+    TPU's ``TopK`` of the 64 best of ``[32, 65536]`` takes 460 us (of
+    8 there 62 us, of 64 of ``[32, 8192]`` 159 us, of ``[32, 512]``
+    nothing that shows): three quarters of what the ``sample`` scope of
+    ``lfm2-sessions`` cost (PERF.md section 6, PR 49). So it is handed
+    ``k x 128`` candidates a row instead of ``V``.
+
+    The ids are cut into groups of ``TOP_K_GROUP`` consecutive ones.
+    The ``k`` groups with the largest maxima (ties to the lower group)
+    hold the whole answer: an element of any other group is preceded,
+    in the order (value falling, id rising), by the maximum of each of
+    those ``k`` groups (a larger value, or an equal one at a lower id,
+    since a lower group is lower ids). So: one max a group, ``top_k`` of
+    the maxima, the chosen groups gathered in the vocabulary's order
+    (which keeps ties among candidates on the lower id), ``top_k`` of
+    the ``k x 128`` candidates, and their positions mapped back to ids
+    (by comparison with the ``k`` chosen groups: a gather of ``B x k``
+    single elements is a loop on the chip, 97 us at ``B`` 32). A ``V``
+    that is no multiple of the group is padded with -inf, which no id
+    of the vocabulary loses a tie to. Where the groups are fewer than
+    ``2 k`` the candidates would be the row: plain ``lax.top_k``."""
+    B, V = logits.shape
+    groups = -(-V // TOP_K_GROUP)
+    if groups < 2 * k:
+        return jax.lax.top_k(logits, k)
+    rows = jnp.pad(logits, ((0, 0), (0, groups * TOP_K_GROUP - V)),
+                   constant_values=-jnp.inf).reshape(B, groups, TOP_K_GROUP)
+    _, chosen = jax.lax.top_k(rows.max(axis=-1), k)
+    chosen = jnp.sort(chosen, axis=-1)
+    candidates = jnp.take_along_axis(rows, chosen[:, :, None], axis=1)
+    vals, at = jax.lax.top_k(candidates.reshape(B, k * TOP_K_GROUP), k)
+    of_group = (at // TOP_K_GROUP)[:, :, None] == jnp.arange(k)
+    group = jnp.sum(jnp.where(of_group, chosen[:, None, :], 0), axis=-1)
+    return vals, group * TOP_K_GROUP + at % TOP_K_GROUP
+
+
 @functools.partial(jax.jit, static_argnames=("max_top_k",))
-def sample_tokens(
-    logits: jax.Array,       # [B, V] float32
+def sample_with_logprobs(
+    logits: jax.Array,       # [B, V] float32, shaped
     rng_keys: jax.Array,     # [B, 2] uint32 (one PRNG key per sequence)
     temperature: jax.Array,  # [B] float32; <=0 means greedy
     top_k: jax.Array,        # [B] int32; 0 disables
     top_p: jax.Array,        # [B] float32
     *,
     max_top_k: int = 64,
-) -> jax.Array:
-    """Return sampled token ids [B]."""
-    B, V = logits.shape
-    greedy_ids = jnp.argmax(logits, axis=-1)
+):
+    """``(sampled [B], chosen_lp [B], top_lp [B, LOGPROB_K], top_ids
+    [B, LOGPROB_K])``: the sampled token ids and the raw log-softmax
+    stats of the OpenAI logprobs surface, from ONE selection and ONE
+    reduction over ``[B, V]``.
 
-    # Work on the top max_top_k candidates only.
-    top_vals, top_idx = jax.lax.top_k(logits, max_top_k)  # [B, K]
+    The selection is the ``max(max_top_k, LOGPROB_K)`` best in order
+    (``exact_top_k``: ``lax.top_k``'s answer, from the candidates of the
+    chosen groups where the vocabulary is wide), a list that answers
+    three consumers. Its rank 0 is the
+    greedy token. Its first ``max_top_k`` are the sampler's candidates.
+    Its first ``LOGPROB_K`` values less the log-sum-exp, and their ids,
+    are the top logprobs: ``x - lse`` is monotone in ``x`` and is the
+    same float subtraction on the same operands as a log-softmax
+    written out at ``[B, V]`` and selected from, so the values are
+    those bit for bit (two logits that round to ONE logprob keep the
+    logits' order here, where a selection from the rounded values
+    ordered them by id). The reduction is the log-sum-exp's sum of
+    exponentials, stabilised by the selection's rank 0 (the row's
+    maximum), as ``jax.scipy.special.logsumexp`` stabilises it by a
+    ``max`` pass of its own. The chosen token's logprob is one gathered
+    logit a row less the same ``lse``.
+
+    **Exact ties.** ``lax.top_k`` is stable: of equal values the lower
+    id comes first (JAX's contract; the ``TopK`` custom call of the
+    TPU compiler keeps it, read on a v5e at ``[32, 65536]`` with the
+    maximum tied 2 to 4,096 times, ``benchmarks/sampling_tail_step0.py``,
+    PR 49), and ``exact_top_k`` keeps that order through its groups.
+    It is ``argmax``'s rule, so rank 0 is the ``argmax`` token;
+    ``tests/test_sampling_tail.py`` holds both."""
+    # [B, K], best first. Behind barriers: the compiler makes a top-k of
+    # a sort and a slice and then looks for that pair to put its ``TopK``
+    # in; a consumer's slice of the list, merged into the pair's, hides
+    # it, and the whole row is sorted. (The temperatures ride along so
+    # that the barrier takes the top-k's two results apart: as the only
+    # user of the pair it is something the CPU's partitioner aborts on,
+    # and one barrier an array hides the pair again.)
+    top_vals, top_idx, temperature = jax.lax.optimization_barrier(
+        (*exact_top_k(logits, max(max_top_k, LOGPROB_K)), temperature))
+    greedy_ids = top_idx[:, 0]
+
+    # Top-k/top-p work on the top max_top_k candidates only.
     K = max_top_k
+    cand_vals, cand_idx = top_vals[:, :K], top_idx[:, :K]
     temp = jnp.maximum(temperature, 1e-6)[:, None]
-    scaled = top_vals / temp
+    scaled = cand_vals / temp
 
     # Per-sequence top-k mask (0 = disabled = keep all K candidates).
     ranks = jnp.arange(K)[None, :]
@@ -160,63 +382,18 @@ def sample_tokens(
         return jax.random.categorical(key, row)
 
     choice = jax.vmap(sample_one)(rng_keys, masked)  # [B] in [0, K)
-    sampled_ids = jnp.take_along_axis(top_idx, choice[:, None], axis=-1)[:, 0]
-    return jnp.where(temperature <= 0.0, greedy_ids, sampled_ids)
+    sampled_ids = jnp.take_along_axis(cand_idx, choice[:, None], axis=-1)[:, 0]
+    sampled = jnp.where(temperature <= 0.0, greedy_ids, sampled_ids)
 
-
-# Sparse logit_bias capacity baked into the serving programs (OpenAI caps
-# requests at 300 entries; 32 covers real use — requests exceeding it are
-# rejected with a 400 at the API layer rather than silently truncated).
-MAX_LOGIT_BIAS = 32
-
-# stop_token_ids capacity in the serving programs (masked alongside EOS
-# while min_tokens is unmet, vLLM semantics).
-MAX_STOP_IDS = 8
-
-
-# Structured-output FSM mask: finite large-negative (like the stop-id
-# term) so temperature scaling can't produce NaNs the way -inf can.
-FSM_MASK_NEG = -1e30
-
-
-def apply_fsm_mask(logits: jax.Array, mask_bits: jax.Array,
-                   mask_on: jax.Array) -> jax.Array:
-    """Dense packed-bitmask grammar term for the fused programs.
-
-    ``mask_bits`` is ``uint8 [B, ceil(V/8)]`` with bit ``v`` of row
-    ``b`` (little bitorder, ``numpy.packbits`` layout) = token ``v``
-    allowed; ``mask_on [B] bool`` gates rows so unconstrained sequences
-    pass through bit-identically. Dense rather than sparse: a grammar
-    state routinely allows hundreds of tokens, far past the
-    ``MAX_LOGIT_BIAS`` sparse capacity, and the packed row is only
-    ``V/8`` bytes of host->device traffic. A data-shaped input, so
-    adding it compiles zero new program variants."""
-    V = logits.shape[-1]
-    B, MB = mask_bits.shape
-    # Shift-and-reshape unpack (no gather): byte v//8 bit v%8 -> token v.
-    shifts = jnp.arange(8, dtype=jnp.uint8)
-    bits = (mask_bits[:, :, None] >> shifts[None, None, :]) & jnp.uint8(1)
-    bits = bits.reshape(B, MB * 8)[:, :V]
-    allowed = (bits != 0) | (~mask_on)[:, None]
-    return jnp.where(allowed, logits, FSM_MASK_NEG)
-
-
-# Static top-K for logprob outputs baked into the serving programs
-# (requests clamp their top_logprobs to this; computing it always costs
-# ~nothing next to the forward, so no recompile per request).
-LOGPROB_K = 8
-
-
-def logprob_outputs(logits: jax.Array, sampled: jax.Array,
-                    k: int = LOGPROB_K):
-    """Raw log-softmax stats for the OpenAI logprobs surface:
-    (chosen_lp [B], top_lp [B, k], top_ids [B, k])."""
-    lse = jax.scipy.special.logsumexp(
-        logits.astype(jnp.float32), axis=-1, keepdims=True)
-    lp = logits.astype(jnp.float32) - lse
-    chosen = jnp.take_along_axis(lp, sampled[:, None], axis=-1)[:, 0]
-    top_lp, top_ids = jax.lax.top_k(lp, k)
-    return chosen, top_lp, top_ids
+    # log-sum-exp as jax.scipy.special.logsumexp computes it, its
+    # maximum read off the selection
+    amax = top_vals[:, :1]
+    amax = jnp.where(jnp.isfinite(amax), amax, 0)
+    lse = jnp.log(jnp.sum(jnp.exp(logits - amax), axis=-1,
+                          keepdims=True)) + amax
+    chosen = jnp.take_along_axis(logits, sampled[:, None], axis=-1) - lse
+    return (sampled, chosen[:, 0], top_vals[:, :LOGPROB_K] - lse,
+            top_idx[:, :LOGPROB_K])
 
 
 def accepted_prefix_len(draft, sampled_row) -> int:

@@ -12,9 +12,11 @@ of 8 steps, the pool in the carry: PR 46), because the compiler places
 operands differently in the nested program, and ``apply`` alone hid 25%
 of a cell's device time for two PRs (GLM's dense weights prefetched into
 VMEM in every layer of the burst and in no layer of ``glm.decode``).
+The bursts take each step's argmax; the sampling tail the engine runs
+there is hashed alone (``tail.decode_k8.<rows>x<vocab>``: PR 49).
 
     JAX_PLATFORMS=cpu python scripts/hlo_digest.py <repo-root> <out-dir> \
-        [--only mistral|laguna|lfm2|longcat|glm|ouro]
+        [--only mistral|laguna|lfm2|longcat|glm|ouro|tail]
 
 Three modes (decode 32 x 1, plain prefill 1 x 512, cached prefill 1 x 256,
 the server's default 8 LoRA slots) x {bf16, int8 weights}, compiled by the
@@ -362,5 +364,43 @@ if ouro is not None:  # (PR 48) the reasoning cell's rows, its usual
     family_digests("ouro", "ouro-2.6b", ouro, ouro_pool,
                    (("decode", 8, 1, 32), ("prefill", 1, 512, 32),
                     ("prefill_cached", 1, 256, 32), (BURST, 8, 8, 32)))
+
+
+def tail_digests(shapes):
+    """``tail.decode_k8.<rows>x<vocab>`` (PR 49): the sampling tail of a
+    decode burst alone, 8 steps from ``[rows, vocab]`` logits to a token
+    and its logprobs a row, which the families' ``decode_k8`` leave out.
+    The bursts are those of this script's ``tests/test_sampling_tail.py``:
+    ``_burst`` drives the root's ``engine/sampling.py``; a root from
+    before PR 49 has no such functions and is given the tail it ran,
+    which that file keeps as its reference."""
+    if only not in (None, "tail"):
+        return
+    sys.path.append(os.path.join(
+        os.path.dirname(os.path.abspath(__file__)), "..", "tests"))
+    import test_sampling_tail as tail
+    from production_stack_tpu.engine import sampling
+
+    burst = (tail._burst if hasattr(sampling, "burst_terms")
+             else tail._reference_burst)
+    f32 = jnp.float32
+    for rows, vocab in shapes:
+        digest(f"tail.{BURST}.{rows}x{vocab}",
+               lambda *operands: burst(*operands, max_top_k=64), 1,
+               spec((8, rows, vocab), f32), spec((rows, vocab)),
+               spec((rows, 8)), spec((rows,), f32), spec((rows,)),
+               spec((rows,), f32), spec((rows,)), spec((rows,), f32),
+               spec((rows,), f32), spec((rows,)), spec((rows,)),
+               spec((rows, sampling.MAX_LOGIT_BIAS)),
+               spec((rows, sampling.MAX_LOGIT_BIAS), f32),
+               spec((rows, sampling.MAX_STOP_IDS)),
+               spec((rows, sampling.MAX_STOP_IDS), f32),
+               spec((rows, (vocab + 7) // 8), jnp.uint8),
+               spec((rows,), jnp.bool_))
+
+
+# the widest vocabulary (lfm2-sessions), the largest logits (Laguna's
+# 128 rows), the fullest chip (LongCat's)
+tail_digests(((32, 65536), (128, 25088), (128, 16384)))
 with open(os.path.join(out, "digests.json"), "w") as f:
     json.dump({"text": digests, "ops": ops, "memory": memory}, f, indent=1)

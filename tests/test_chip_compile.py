@@ -389,6 +389,33 @@ def test_decode_kernel_compiles_at_the_packed_rows_of_64_wide_heads(
                      text)
 
 
+def _lfm2_24b(tmp_path, monkeypatch, sharding, blocks=1024):
+    """(cfg, the weights' shapes, ``spec``, the pool's three sides at
+    ``blocks`` blocks) of ``lfm2-24b-a2b-l10`` as the benchmark serves
+    it, with the kernels the chip takes."""
+    from production_stack_tpu.engine.core import kv_page_dims
+    from production_stack_tpu.models import lfm2
+    from production_stack_tpu.models.registry import block_state_shape
+
+    cfg = _benchmark_config(tmp_path, "lfm2-24b-a2b-l10")
+    monkeypatch.setattr(att, "_use_pallas", lambda: True)
+    monkeypatch.setattr(gmm, "_platform", lambda: "tpu")
+    att.TRACED_PATHS.clear()
+
+    def spec(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    params = jax.tree_util.tree_map(
+        lambda x: spec(x.shape, x.dtype),
+        jax.eval_shape(lambda: lfm2.init_params(cfg, jax.random.key(0))))
+    layers, page_rows, lanes = kv_page_dims(cfg)
+    assert (layers, page_rows, lanes) == (2, 4, 128)
+    pages = spec((layers, blocks, BLOCK_SIZE, page_rows, lanes), jnp.bfloat16)
+    held = block_state_shape(cfg)
+    state = spec((held[0], blocks) + held[1:], jnp.bfloat16)
+    return cfg, params, spec, (pages, pages, state)
+
+
 @pytest.mark.parametrize("mode,rows,width,tables", [
     ("decode", 32, 1, 64), ("prefill", 4, 512, 8),
     ("prefill_cached", 1, 1024, 64)])
@@ -401,50 +428,25 @@ def test_lfm2_programs_compile_at_the_configurations_widths(
     pool is copied on the way through the layers' ``cond``: the pool here
     is 1,024 blocks (0.34 GB), and a temporary as large as one side of it
     or as one layer's experts would be such a copy."""
-    import json
-    import os
-    import sys
-
     from production_stack_tpu.engine.core import kv_page_dims
     from production_stack_tpu.models import lfm2
     from production_stack_tpu.models.registry import block_state_shape
 
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    if repo not in sys.path:
-        sys.path.insert(0, repo)
-    from chipbench.registry import model_keys
-
-    with open(os.path.join(repo, "chipbench", "configs",
-                           "lfm2-24b-a2b-l10.json")) as f:
-        (tmp_path / "config.json").write_text(
-            json.dumps(model_keys(json.load(f))))
-    cfg = get_model_config(str(tmp_path))
-    monkeypatch.setattr(att, "_use_pallas", lambda: True)
-    monkeypatch.setattr(gmm, "_platform", lambda: "tpu")
-    att.TRACED_PATHS.clear()
-
-    def spec(shape, dtype=jnp.int32):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
-    params = jax.tree_util.tree_map(
-        lambda x: spec(x.shape, x.dtype),
-        jax.eval_shape(lambda: lfm2.init_params(cfg, jax.random.key(0))))
+    blocks = 1024
+    cfg, params, spec, pool = _lfm2_24b(
+        tmp_path, monkeypatch, one_chip, blocks)
     weights = sum(int(np.prod(x.shape)) * x.dtype.itemsize
                   for x in jax.tree_util.tree_leaves(params))
     assert abs(weights / 10.5e9 - 1) < 0.03
-    blocks = 1024
     layers, page_rows, lanes = kv_page_dims(cfg)
-    assert (layers, page_rows, lanes) == (2, 4, 128)
-    pages = spec((layers, blocks, BLOCK_SIZE, page_rows, lanes), jnp.bfloat16)
     held = block_state_shape(cfg)
-    state = spec((held[0], blocks) + held[1:], jnp.bfloat16)
     last = mode != "decode"
     program = jax.jit(
         lambda p, kv, tok, pos, slot, bt, cl, sl: lfm2.apply(
             p, cfg, tok, pos, kv, slot, bt, cl, sl, mode=mode,
             last_token=jnp.maximum(sl - 1, 0) if last else None,
             with_stats=True), donate_argnums=(1,)).lower(
-        params, (pages, pages, state), spec((rows, width)),
+        params, pool, spec((rows, width)),
         spec((rows, width)), spec((rows, width)), spec((rows, tables)),
         spec((rows,)), spec((rows,))).compile()
     text = program.as_text()
@@ -974,3 +976,112 @@ def test_ouro_decode_burst_keeps_stack_and_pool_in_place(
              if " copy-start(" in line and _bytes_copied(line) > 1 << 20]
     assert not large, large
     assert program.memory_analysis().temp_size_in_bytes < 4e6
+
+
+def test_lfm2_decode_burst_selects_once_and_reduces_once_a_step(
+        one_chip, monkeypatch, tmp_path):
+    """``lfm2-24b-a2b-l10``'s ``decode_k8`` at the cell's ``[32, 65536]``
+    logits, nested as the engine nests it (engine/core.py::
+    _make_multi_decode: what the burst holds constant built before the
+    scan, ``apply(mode="decode")`` and the sampling tail in its body).
+    The step loop's own body selects from no more than the 64 chosen
+    groups' 8,192 candidates a row (``sampling.exact_top_k``: on the
+    chip a ``TopK`` of 64 over all 65,536 took 460 us of a 3.19 ms
+    forward, PERF.md section 6, PR 49) and sorts nothing of the
+    vocabulary's width (a consumer's slice merged into a top-k's own
+    hides the pair the compiler makes its ``TopK`` of, and it sorts the
+    row); it makes ONE float32 ``[32, 65536]`` (the shaped logits: no
+    log-softmax written out) beside the re-tiled view of it the groups'
+    maxima are read from, ONE int32 one (the penalty counts' scatter,
+    the only scatter left) and no 8-bit one (the structured mask is
+    unpacked before the loop, and the compiler does not sink the unpack
+    back into it). Until PR 49 the body held two ``TopK``s over the whole
+    row, three scatters, the unpack with its transposing copy and seven
+    float32 ``[32, 65536]`` results."""
+    from production_stack_tpu.engine import sampling
+    from production_stack_tpu.models import lfm2
+
+    cfg, params, spec, pool = _lfm2_24b(tmp_path, monkeypatch, one_chip)
+    rows, steps, tables, vocab = 32, 8, 64, cfg.vocab_size
+    assert vocab == 65536
+
+    def burst(p, kv, counts, tokens0, positions0, slot_mat, block_tables,
+              context0, temperature, top_k, top_p, seed_base, presence,
+              frequency, min_tokens, out_len0, bias_ids, bias_vals,
+              stop_ids, stop_valid, mask_bits, mask_on):
+        with jax.named_scope("sample"):
+            terms = sampling.burst_terms(
+                vocab, bias_ids, bias_vals, stop_ids, stop_valid,
+                mask_bits, mask_on)
+
+        def body(carry, step_slots):
+            tokens, kv, counts, s = carry
+            logits, kv, stats = lfm2.apply(
+                p, cfg, tokens[:, None], (positions0 + s)[:, None], kv,
+                step_slots[:, None], block_tables, context0 + s,
+                jnp.ones_like(context0), mode="decode", with_stats=True)
+            with jax.named_scope("sample"):
+                shaped = sampling.shape_logits(
+                    sampling.apply_penalties(
+                        logits[:, 0], counts, frequency, presence),
+                    terms, (out_len0 + s) < min_tokens, 2)
+                keys = sampling.make_rng_keys(0, 0, seed_base + s)
+                sampled, lp, top_lp, top_ids = sampling.sample_with_logprobs(
+                    shaped, keys, temperature, top_k, top_p, max_top_k=64)
+                live = (step_slots >= 0).astype(jnp.int32)
+                counts = counts.at[jnp.arange(rows), sampled].add(live)
+            return ((sampled, kv, counts, s + 1),
+                    (sampled, lp, top_lp, top_ids, stats))
+
+        (_, kv, counts, _), outs = jax.lax.scan(
+            body, (tokens0, kv, counts, jnp.int32(0)), slot_mat.T)
+        return outs, kv, counts
+
+    f32 = jnp.float32
+    text = jax.jit(burst, donate_argnums=(1, 2)).lower(
+        params, pool, spec((rows, vocab)), spec((rows,)),
+        spec((rows,)), spec((rows, steps)), spec((rows, tables)),
+        spec((rows,)), spec((rows,), f32), spec((rows,)), spec((rows,), f32),
+        spec((rows,)), spec((rows,), f32), spec((rows,), f32), spec((rows,)),
+        spec((rows,)), spec((rows, sampling.MAX_LOGIT_BIAS)),
+        spec((rows, sampling.MAX_LOGIT_BIAS), f32),
+        spec((rows, sampling.MAX_STOP_IDS)),
+        spec((rows, sampling.MAX_STOP_IDS), f32),
+        spec((rows, vocab // 8), jnp.uint8),
+        spec((rows,), jnp.bool_)).compile().as_text()
+    step_loops = [lines for lines in _while_bodies(text).values()
+                  if any("sample_with_logprobs" in line for line in lines)
+                  and any(" while(" in line for line in lines)]
+    assert len(step_loops) == 1
+    # the selections: every ``TopK`` of the program reads candidates
+    read = re.findall(r'custom-call\(%([\w.\-]+)\), custom_call_target="TopK"',
+                      text)
+    widths = [int(re.search(
+        rf"%{re.escape(name)} = f32\[{rows},(\d+)\]", text).group(1))
+        for name in read]
+    assert widths and max(widths) <= 64 * sampling.TOP_K_GROUP, widths
+    made = {}  # dtype -> the body's own results of rows x vocab elements
+    for line in step_loops[0]:
+        if " sort(" in line:  # the groups' maxima (512 a row) at the widest
+            sorted_dims = re.findall(r"\[\d+,(\d+)\]", line.split(" sort(")[0])
+            assert max(int(d) for d in sorted_dims) <= 512, line[:160]
+        found = re.match(
+            r"\s*(?:ROOT )?%([\w.\-]+) = (\w+)\[([\d,]+)\]\S* ([\w\-]+)\(",
+            line)
+        if not found:
+            continue
+        name, dtype, dims, op = found.groups()
+        dims = [int(d) for d in dims.split(",")]
+        # a prefetch into VMEM or an alias reads an array, it makes none
+        if (np.prod(dims) == rows * vocab
+                and op not in ("copy-start", "copy-done", "bitcast",
+                               "get-tuple-element")):
+            made.setdefault(dtype, []).append(f"{name} {op}")
+    assert sorted(made) == ["f32", "s32"], made
+    assert len(made["s32"]) == 1, made
+    assert [m.split()[1] for m in made["f32"]] in (
+        ["fusion"], ["fusion", "copy"]), made
+    scatters = [line for line in step_loops[0]
+                if re.search(r'op_name="[^"]*/scatter-add"', line)
+                and "65536" in line.split(" = ")[1].split(" ")[0]]
+    assert len(scatters) == 1 and " s32[" in scatters[0], scatters
