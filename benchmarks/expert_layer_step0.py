@@ -14,11 +14,14 @@ line per measurement: us a call, the experts hit, the assignments, and
 the share of the floor (the larger of the hit experts' three matrices
 over 819 GB/s and ``2 x 3 x hidden x width`` operations an assignment
 over 197 TFLOP/s). ``what`` is ``layer`` (whole), ``matmuls`` (alone) or
-``visits`` (the kernel's metadata alone); ``path`` ``xla`` or ``pallas``;
-``err`` the kernel's largest difference from ``ragged_dot`` on the live
-rows of the down projection.
+``visits`` (the kernel's metadata alone); ``path`` ``xla``, ``pallas`` or,
+where the case's row slots are one row tile and the tree has that path
+(PR 50), ``pallas_one_tile`` (the rows in the tokens' order; the whole
+layer then takes it under ``pallas``, and ``pallas_sorted`` is the layer
+forced down the sorted path); ``err`` the kernel's largest difference
+from ``ragged_dot`` on the live rows of the down projection.
 
-    chiprun -- python benchmarks/expert_layer_step0.py [laguna lfm2]
+    chiprun -- python benchmarks/expert_layer_step0.py [laguna lfm2 glm]
         [--tiles 128:512:1536,64:1024:3072]   # tm:tn of gate/up:tn of down
 """
 
@@ -59,6 +62,13 @@ CONFIGS = {
                "decode_32_live_0": (32, 1, 0), "cached_1x128": (1, 128, None)},
         sweep=[(tm, up, down) for tm in (128, 64, 16)
                for up, down in ((384, 512), (768, 1024), (1536, 2048))]),
+    # GLM-4.7-Flash on one chip of eight (PR 50 step 0): a decode forward
+    # of ~1.4 live rows leaves about half of the 46 sparse layers idle.
+    "glm": dict(
+        hidden=2048, width=1536, held=8, published=64, top=4, layers=46,
+        cases={"decode_32_live_1": (32, 1, 1), "decode_32_live_2": (32, 1, 2),
+               "decode_32_live_0": (32, 1, 0)},
+        sweep=[(128, 768, 1024), (128, 1536, 2048)]),
 }
 TINY = dict(hidden=128, width=256, held=4, published=8, top=2, layers=2,
             cases={"decode_16": (16, 1, None), "live_1": (16, 1, 1)},
@@ -108,16 +118,18 @@ def measure(name: str, c: dict, tilings, dev) -> None:
                 return out.astype(jnp.float32), s
 
             return scan_calls(step, (jnp.zeros(h.shape, jnp.float32),
-                                     jnp.zeros((3,), jnp.int32)))
+                                     jnp.zeros((len(moe.STATS),), jnp.int32)))
 
         def call(*args):
             # The choice is made while tracing: hold it for the trace.
-            real = gmm._use_pallas
-            gmm._use_pallas = lambda: forced == "pallas"
+            real = gmm._use_pallas, gmm.grouped_matmul_path
+            gmm._use_pallas = lambda: forced != "xla"
+            if forced == "pallas_sorted":
+                gmm.grouped_matmul_path = lambda *a, **k: "pallas"
             try:
                 return run(*args)
             finally:
-                gmm._use_pallas = real
+                gmm._use_pallas, gmm.grouped_matmul_path = real
 
         return call
 
@@ -132,7 +144,7 @@ def measure(name: str, c: dict, tilings, dev) -> None:
         group = jnp.where(group < held, group, held).reshape(-1)
         order = jnp.argsort(group, stable=True)
         sizes = jnp.zeros((held + 1,), jnp.int32).at[group].add(1)[:held]
-        return x[order // top], sizes
+        return x[order // top], sizes, jnp.repeat(x, top, axis=0), group
 
     def as_groups(stacks):  # inside a jit: a view, no copy
         return {k: w.reshape((layers * held,) + w.shape[2:])
@@ -168,6 +180,24 @@ def measure(name: str, c: dict, tilings, dev) -> None:
 
         return scan_calls(step, jnp.zeros(rows.shape, jnp.float32))
 
+    @functools.partial(jax.jit, static_argnums=(3, 4))
+    def matmuls_one_tile(stacks, rows, group, tn_up, tn_down):
+        stacks = as_groups(stacks)  # ``rows`` in the tokens' order
+
+        def step(i):
+            sizes = gmm.group_sizes(group, held)
+            g = functools.partial(
+                gmm.pallas_grouped_matmul,
+                visits=gmm.one_tile_visits(sizes, group),
+                first_group=(i % layers) * held, interpret=not on_tpu)
+            act = g(rows, stacks["w_up"], gate=stacks["w_gate"],
+                    tiles=(rows.shape[0], hidden, tn_up))
+            return g(act, stacks["w_down"],
+                     tiles=(rows.shape[0], width, tn_down)
+                     ).astype(jnp.float32)
+
+        return scan_calls(step, jnp.zeros(rows.shape, jnp.float32))
+
     @functools.partial(jax.jit, static_argnums=(1, 2))
     def visits_alone(sizes, m, tm):
         def step(i):  # ``+ i`` keeps the scan from hoisting it
@@ -177,6 +207,14 @@ def measure(name: str, c: dict, tilings, dev) -> None:
 
         return scan_calls(step, jnp.zeros((m // tm + held - 1,), jnp.int32))
 
+    @jax.jit
+    def visits_one_tile(sizes, group):
+        def step(i):
+            v = gmm.one_tile_visits(sizes + i, group)
+            return v.by_rank + v.count + v.group_of_row.sum()
+
+        return scan_calls(step, jnp.zeros((held,), jnp.int32))
+
     for case, (rows_n, span, live) in c["cases"].items():
         tokens = rows_n * span
         m = tokens * top
@@ -184,7 +222,7 @@ def measure(name: str, c: dict, tilings, dev) -> None:
         valid = (jnp.arange(rows_n)[:, None] < (rows_n if live is None
                                                 else live)
                  ) & jnp.ones((rows_n, span), bool)
-        rows, sizes = routed(p, h, valid)
+        rows, sizes, by_token, group = routed(p, h, valid)
         of_rows = (int(sizes.sum()), int((sizes > 0).sum()))
 
         def line(what, path, seconds, stats=None, **more):
@@ -230,6 +268,20 @@ def measure(name: str, c: dict, tilings, dev) -> None:
                  chosen=(tm, tn_up, tn_down) == default, err=round(err, 5))
         seconds, _ = timed(visits_alone, sizes, m, chosen[0])
         line("visits", "pallas", seconds, tiles=[chosen[0]])
+        if chosen[0] != m or not hasattr(gmm, "one_tile_visits"):
+            continue
+        seconds, (_, stats) = timed(layer_fn("pallas_sorted"), p, h, valid)
+        line("layer", "pallas_sorted", seconds, stats)
+        seconds, got = timed(matmuls_one_tile, stacks, by_token, group,
+                             default[1], default[2])
+        live = (group < held)[:, None]
+        err = float(jnp.max(jnp.abs(jnp.where(
+            live, got - want[jnp.argsort(jnp.argsort(group, stable=True))],
+            0.0)))) / CALLS
+        line("matmuls", "pallas_one_tile", seconds, tiles=list(default),
+             err=round(err, 5))
+        seconds, _ = timed(visits_one_tile, sizes, group)
+        line("visits", "pallas_one_tile", seconds, tiles=[chosen[0]])
     del p, stacks
     gc.collect()
 

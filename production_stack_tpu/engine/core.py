@@ -663,11 +663,11 @@ class EngineCore:
         # tpu:prefill_attention_dispatch_total{path=...}).
         self.fused_steps_total = 0
         self.prefill_attention_dispatch_total = {"pallas": 0, "xla": 0}
-        # Step programs of a model with an expert layer, by the path its
-        # grouped matmuls take at the program's tokens: "pallas" (the
-        # grouped-matmul kernel) or "xla" (ragged_dot); exported as
-        # tpu:expert_matmul_dispatch_total{path=...}.
-        self.expert_matmul_dispatch_total = {"pallas": 0, "xla": 0}
+        # Step programs of a model with an expert layer, by the path that
+        # ops/pallas_grouped_matmul.py::grouped_matmul_path names at their
+        # tokens; exported as tpu:expert_matmul_dispatch_total{path=...}.
+        self.expert_matmul_dispatch_total = dict.fromkeys(
+            ("pallas", "pallas_one_tile", "xla"), 0)
         # Decode programs of a model with a latent cache, by the path
         # the absorbed attention over its pages takes: "pallas"
         # (ops/pallas_mla_decode.py) or "xla" (gather and einsum);

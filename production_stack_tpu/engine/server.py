@@ -2537,6 +2537,11 @@ class EngineServer:
             "# TYPE tpu:moe_experts_hit counter",
             f"tpu:moe_experts_hit_total{{{labels}}} "
             f"{s['family_stats_total'].get('moe_experts_hit', 0)}",
+            # Expert layers of the forwards in which no held expert
+            # received a row.
+            "# TYPE tpu:moe_idle_layers counter",
+            f"tpu:moe_idle_layers_total{{{labels}}} "
+            f"{s['family_stats_total'].get('moe_idle_layers', 0)}",
             # Passes over the layer stack (models/ouro.py::STATS), 0 for
             # a model whose layers run once.
             "# TYPE tpu:loop_passes counter",
@@ -2654,13 +2659,14 @@ class EngineServer:
             f"{s.get('prefill_attention_dispatch_total', {}).get('xla', 0)}",
             # Step programs of a model with an expert layer, by the path
             # its grouped matmuls take: "pallas" (the grouped-matmul
-            # kernel) vs "xla" (ragged_dot: off the TPU, across devices,
-            # or a shape that does not tile). Both label values always.
+            # kernel), "pallas_one_tile" (the kernel over rows that are
+            # one row tile, in the tokens' order) or "xla" (ragged_dot:
+            # off the TPU, across devices, or a shape that does not
+            # tile). Every label value always.
             "# TYPE tpu:expert_matmul_dispatch counter",
-            f'tpu:expert_matmul_dispatch_total{{{labels},path="pallas"}} '
-            f"{s.get('expert_matmul_dispatch_total', {}).get('pallas', 0)}",
-            f'tpu:expert_matmul_dispatch_total{{{labels},path="xla"}} '
-            f"{s.get('expert_matmul_dispatch_total', {}).get('xla', 0)}",
+            *(f'tpu:expert_matmul_dispatch_total{{{labels},path="{path}"}} '
+              f"{s.get('expert_matmul_dispatch_total', {}).get(path, 0)}"
+              for path in ("pallas", "pallas_one_tile", "xla")),
             # Decode programs of a model with a latent cache, by the path
             # its absorbed attention takes (0 for any other model).
             "# TYPE tpu:latent_decode_dispatch counter",

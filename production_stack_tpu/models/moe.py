@@ -10,10 +10,16 @@ expert's weights stream once, in tiles of megabytes chosen from the
 shapes, only the row tiles of groups that have rows are visited, and
 the gate and up projections share one pass over the rows),
 **``jax.lax.ragged_dot`` elsewhere** (off the TPU, in a program that
-spans devices, at a shape that does not tile). One trace-time choice a
-layer (``grouped_matmul_path``), counted per dispatched step program in
-the engine's ``expert_matmul_dispatch_total{path}``; PERF.md section 6,
-PR 37 has both on the chip. Then the outputs are weighted
+spans devices, at a shape that does not tile). **Where the assignments
+are one row tile** (``tokens x k`` = the row tile: a decode step of 32
+rows x top 4) **nothing is sorted**: the rows stay in the tokens' order,
+the kernel visits the experts that have rows under the mask "this row's
+expert", and a layer whose held experts received no row copies and
+multiplies nothing (``pallas_one_tile``; PERF.md section 6, PR 50). One
+trace-time choice a layer (``grouped_matmul_path``), counted per
+dispatched step program in the engine's
+``expert_matmul_dispatch_total{path}``; PERF.md section 6, PR 37 has
+both on the chip. Then the outputs are weighted
 and summed back per token. No capacity and no dropped token: the rows
 are as many as there are assignments, ``tokens x k``, and what lands on
 another chip's experts is sorted past the last group, where the grouped
@@ -46,8 +52,10 @@ import jax.numpy as jnp
 from production_stack_tpu.models import decoder
 from production_stack_tpu.ops import pallas_grouped_matmul as gmm
 
-# What :func:`expert_layer` counts of one call, in this order.
-STATS = ("moe_assignments", "moe_experts_hit", "moe_max_expert_load")
+# What :func:`expert_layer` counts of one call, in this order; the last is
+# 1 where no held expert received a row (the layer had nothing to do).
+STATS = ("moe_assignments", "moe_experts_hit", "moe_max_expert_load",
+         "moe_idle_layers")
 # What it counts beside them in a layer with zero-compute experts: the
 # assignments that landed on one (a family with such a layer puts
 # ``ZERO_STATS`` behind ``STATS`` in its ``Family.stats``).
@@ -128,9 +136,9 @@ def expert_layer(
     zero_experts: int = 0,  # the router's last outputs are identities
 ) -> Tuple[jax.Array, jax.Array]:
     """The routed experts held here on ``h``. Returns (their weighted sum
-    per token [B, T, Hd], the :data:`STATS` of the call as int32 [3]: the
+    per token [B, T, Hd], the :data:`STATS` of the call as int32 [4]: the
     assignments the held experts received, how many of them received one,
-    and the largest number one received).
+    the largest number one received, and 1 if none received any).
 
     With ``zero_experts`` the router's outputs ``[E, E + zero_experts)``
     behind the ``E`` experts that have weights are identities
@@ -140,7 +148,7 @@ def expert_layer(
     the layer has them all and a token's home chip applies them. In the
     grouped matmul they sort past the groups like another chip's
     experts. The call's stats are then :data:`STATS` + :data:`ZERO_STATS`
-    (int32 [4]): the assignments that landed on one, last."""
+    (int32 [5]): the assignments that landed on one, last."""
     B, T, Hd = h.shape
     N = B * T
     held = p["w_gate"].shape[1]
@@ -156,19 +164,27 @@ def expert_layer(
         # ``held`` is the group of everything that is not computed here:
         # it sorts last, past the rows the grouped matmul is given.
         group = jnp.where(mine, local, held).reshape(N * k)
-        order = jnp.argsort(group, stable=True)
-        sizes = jnp.zeros((held + 1,), jnp.int32).at[group].add(1)[:held]
-        rows = x[order // k]  # [N * k, Hd], by expert
-
         # One trace-time choice for the layer's three matmuls (their row
         # tile is the same, so one set of visits serves them).
         m = N * k
         path = gmm.traced_path(m, *p["w_up"].shape[2:], h.dtype, held)
-        if path == "pallas":
+        one_tile = path == "pallas_one_tile"
+        if one_tile:
+            # One row tile needs no order: the tokens' rows, each ``k``
+            # times, and the kernel's mask is the row's own group.
+            sizes = gmm.group_sizes(group, held)
+            rows = jnp.repeat(x, k, axis=0)  # [N * k, Hd], by token
+        else:
+            order = jnp.argsort(group, stable=True)
+            sizes = jnp.zeros((held + 1,), jnp.int32).at[group].add(1)[:held]
+            rows = x[order // k]  # [N * k, Hd], by expert
+
+        if path != "xla":
             up_tiles, down_tiles = (
                 gmm.grouped_matmul_tiles(m, *p[name].shape[2:], h.dtype, held)
                 for name in ("w_up", "w_down"))
-            visits = gmm.group_visits(sizes, m, up_tiles[0])
+            visits = (gmm.one_tile_visits(sizes, group) if one_tile else
+                      gmm.group_visits(sizes, m, up_tiles[0]))
             # silu(rows Wgate) * (rows Wup) in one pass over the rows.
             act = gmm.grouped_matmul(rows, p["w_up"], visits, at, up_tiles,
                                      gate=p["w_gate"])
@@ -191,10 +207,14 @@ def expert_layer(
             out = grouped(act, "w_down")  # [N * k, Hd]
         # Back to the token's order; a row past the groups is not the
         # grouped matmul's to define, so it is replaced, not multiplied.
-        out = out[jnp.argsort(order)].reshape(N, k, Hd)
-        out = jnp.where(mine[..., None], out.astype(jnp.float32), 0.0)
+        if not one_tile:
+            out = out[jnp.argsort(order)]
+        out = jnp.where(mine[..., None],
+                        out.reshape(N, k, Hd).astype(jnp.float32), 0.0)
         y = jnp.einsum("nkh,nk->nh", out, weights).astype(h.dtype)
-    stats = [jnp.sum(sizes), jnp.sum(sizes > 0), jnp.max(sizes)]
+    assignments = jnp.sum(sizes)
+    stats = [assignments, jnp.sum(sizes > 0), jnp.max(sizes),
+             (assignments == 0).astype(jnp.int32)]
     if zero_experts:
         with jax.named_scope("moe_zero"):
             identity = experts >= p["router"].shape[-1] - zero_experts

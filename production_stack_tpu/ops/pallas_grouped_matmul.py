@@ -38,6 +38,14 @@ What differs from ``gmm``:
   product is stored under the group's row mask as it is made.
 - The tiles are :func:`grouped_matmul_tiles` of the shapes alone, and the
   scoped VMEM limit follows from them.
+- **Rows that are one row tile need no order** (``m == tm``: a decode
+  step of 32 rows x top 4). Every visit would read the same block of
+  rows, so the rows stay in the tokens' order, a visit is a group that
+  has rows (:func:`one_tile_visits`) and its mask is "this row's group
+  is mine" from a ``[m, 1]`` block beside the rows; with no row at all
+  the one step the grid runs starts no copy and makes no product
+  (PERF.md section 6, PR 50). :func:`grouped_matmul_path` names it
+  ``pallas_one_tile``.
 
 bf16 (or float32) operands, float32 accumulation, the operands' dtype
 out. Correctness is pinned by tests/test_grouped_matmul.py (interpret
@@ -156,7 +164,9 @@ def grouped_matmul_path(m: int, k: int, n: int, dtype, groups: int,
                         devices: int = 1) -> str:
     """Which backend the grouped matmuls of an expert layer take whose
     experts are ``[k, n]`` up and ``[n, k]`` down, at these (static)
-    shapes: ``"pallas"`` (this kernel) or ``"xla"``
+    shapes: ``"pallas"`` (this kernel), ``"pallas_one_tile"`` (this
+    kernel where the rows are one row tile: no sort,
+    :func:`one_tile_visits`) or ``"xla"``
     (``jax.lax.ragged_dot``). THE decision, from shapes, dtype and
     platform only: ``models/moe.py`` and the engine's
     ``expert_matmul_dispatch_total`` both evaluate it. ``"xla"`` off the
@@ -165,11 +175,13 @@ def grouped_matmul_path(m: int, k: int, n: int, dtype, groups: int,
     finds no tiling for either orientation: a width that is not a
     multiple of 128 (the tiny test models), rows that are not a multiple
     of 16 (fewer than 16 row slots in bf16)."""
-    if (devices == 1 and _use_pallas()
-            and grouped_matmul_tiles(m, k, n, dtype, groups)
+    if not (devices == 1 and _use_pallas()
             and grouped_matmul_tiles(m, n, k, dtype, groups)):
-        return "pallas"
-    return "xla"
+        return "xla"
+    tiles = grouped_matmul_tiles(m, k, n, dtype, groups)
+    if not tiles:
+        return "xla"
+    return "pallas_one_tile" if tiles[0] == m else "pallas"
 
 
 def traced_path(m: int, k: int, n: int, dtype, groups: int) -> str:
@@ -200,6 +212,16 @@ def _running_sums(x: jax.Array) -> jax.Array:
                    axis=-1, dtype=jnp.int32)
 
 
+def _groups_by_rank(index: jax.Array, with_rows: jax.Array,
+                    ranks: jax.Array) -> jax.Array:
+    """``[groups]`` int32: the groups (``index``: 0, 1, ...) that have
+    rows, in order (the entries past them are 0), from each group's
+    ``ranks`` among them."""
+    return jnp.sum(
+        jnp.where(with_rows[None, :] & (ranks[None, :] == index[:, None]),
+                  index[None, :], 0), axis=1, dtype=jnp.int32)
+
+
 def group_visits(sizes: jax.Array, m: int, tm: int) -> GroupVisits:
     """The (group, row tile) pairs that hold rows, in row order, of groups
     of ``sizes`` rows laid end to end from row 0 of ``m`` (``tm`` divides
@@ -227,31 +249,47 @@ def group_visits(sizes: jax.Array, m: int, tm: int) -> GroupVisits:
         jnp.where(group[:, None] == index[None, :],
                   (first - (visit_ends - tiles))[None, :], 0),
         axis=1, dtype=jnp.int32)
-    by_rank = jnp.sum(
-        jnp.where((sizes > 0)[None, :] & (ranks[None, :] == index[:, None]),
-                  index[None, :], 0), axis=1, dtype=jnp.int32)
+    by_rank = _groups_by_rank(index, sizes > 0, ranks)
     return GroupVisits(ends, group, jnp.clip(row_tile, 0, tiles_m - 1),
                        ranks, by_rank, visit_ends)
 
 
-def _kernel(ends, groups, row_tiles, ranks, by_rank, visit_ends, first_group,
-            lhs, *refs, tm: int, tn: int, tiles_n: int, stacks: int):
-    del visit_ends  # the grid's
-    rhs_hbm, (out, rhs, sems) = refs[:stacks], refs[stacks:]
-    n_i, visit = pl.program_id(0), pl.program_id(1)
-    group = groups[visit]
-    last = ends.shape[0] - 1
-    # With no row at all the one visit run is the last group's, which
-    # fetches group 0's blocks and stores nothing under its empty mask.
-    with_rows = jnp.maximum(
-        ranks[last] + (ends[last] > (ends[last - 1] if last else 0)
-                       ).astype(jnp.int32), 1)
-    # The weights' blocks are fetched in one order: n tile by n tile, the
-    # groups that have rows by rank; block ``fetch`` (of each stack) lands
-    # in slot ``fetch % RING``.
-    fetch = n_i * with_rows + ranks[group]
-    slot = jax.lax.rem(fetch, RING)
+class OneTileVisits(NamedTuple):
+    """The kernel's visits where the rows are one row tile and stay in
+    the tokens' order: a visit is a group that has rows."""
+    by_rank: jax.Array  # [groups] int32: the groups that have rows, in order
+    count: jax.Array  # [1] int32: how many there are; 0 in an idle layer
+    group_of_row: jax.Array  # [m, 1] int32; ``groups`` or more: nobody's
 
+
+def group_sizes(group_of_row: jax.Array, groups: int) -> jax.Array:
+    """``[groups]`` int32: how many of the rows ``[m]`` each group has, as
+    one compare-and-reduce (a scatter-add of 128 elements is a loop on
+    the chip); a row of group ``groups`` or more counts nowhere."""
+    return jnp.sum(group_of_row[:, None] == jnp.arange(groups)[None, :],
+                   axis=0, dtype=jnp.int32)
+
+
+def one_tile_visits(sizes: jax.Array, group_of_row: jax.Array
+                    ) -> OneTileVisits:
+    """The part of :func:`group_visits` that no row tile enters: which
+    groups have rows (``sizes [groups]``), and each row's group as the
+    kernel's mask reads it. Two small fusions on the chip."""
+    with_rows = (sizes > 0).astype(jnp.int32)
+    by_rank = _groups_by_rank(
+        jnp.arange(sizes.shape[0], dtype=jnp.int32), sizes > 0,
+        _running_sums(with_rows) - with_rows)
+    return OneTileVisits(by_rank, jnp.sum(with_rows).reshape(1),
+                         group_of_row.astype(jnp.int32).reshape(-1, 1))
+
+
+def _await_block(fetch, rhs_hbm, rhs, sems, by_rank, first_group, with_rows,
+                 *, tn: int, tiles_n: int):
+    """What a group's first visit in an n tile does about the weights.
+    Their blocks are fetched in one order: n tile by n tile, the
+    ``with_rows`` groups that have rows by rank; block ``fetch`` (of each
+    stack) lands in slot ``fetch % RING``. Start the copies ``RING - 1``
+    blocks ahead of ``fetch`` and wait for its own."""
     def copies(fetch):
         of_n = fetch // with_rows
         at = first_group[0] + by_rank[fetch - of_n * with_rows]
@@ -266,23 +304,22 @@ def _kernel(ends, groups, row_tiles, ranks, by_rank, visit_ends, first_group,
             for copy in copies(fetch):
                 copy.start()
 
-    @pl.when((visit == 0) | (groups[jnp.maximum(visit - 1, 0)] != group))
-    def _():  # the group's first visit in this n tile
-        @pl.when(fetch == 0)
-        def _():
-            for ahead in range(RING - 1):
-                start(ahead)
+    @pl.when(fetch == 0)
+    def _():
+        for ahead in range(RING - 1):
+            start(ahead)
 
-        # Into the slot the group before has just done with: the copies
-        # of the next RING - 1 blocks run while this one is worked on.
-        start(fetch + RING - 1)
-        for copy in copies(fetch):
-            copy.wait()
+    # Into the slot the group before has just done with: the copies of
+    # the next RING - 1 blocks run while this one is worked on.
+    start(fetch + RING - 1)
+    for copy in copies(fetch):
+        copy.wait()
 
-    row = row_tiles[visit] * tm + jax.lax.broadcasted_iota(
-        jnp.int32, out.shape, 0)
-    begin = jnp.where(group > 0, ends[jnp.maximum(group - 1, 0)], 0)
-    mine = (row >= begin) & (row < ends[group])
+
+def _store_product(mine, lhs, rhs, slot, out, stacks: int):
+    """``out`` under the mask ``mine`` = the rows ``lhs`` times the
+    block(s) in ``slot``: the tile's other rows are another visit's (a
+    neighbouring group's) or nobody's."""
     rows = lhs[...]
     # Each product rounded to the operands' dtype, as a matmul of its own
     # would hand it on.
@@ -293,55 +330,127 @@ def _kernel(ends, groups, row_tiles, ranks, by_rank, visit_ends, first_group,
                        ).astype(out.dtype).astype(jnp.float32)
         product = (jax.nn.silu(gate).astype(out.dtype).astype(jnp.float32)
                    * product.astype(jnp.float32)).astype(out.dtype)
-    # Under the group's rows only: the tile's other rows are another
-    # visit's (a neighbouring group's) or nobody's.
     out[...] = jnp.where(mine, product.astype(jnp.float32),
                          out[...].astype(jnp.float32)).astype(out.dtype)
 
 
+def _kernel(ends, groups, row_tiles, ranks, by_rank, visit_ends, first_group,
+            lhs, *refs, tm: int, tn: int, tiles_n: int, stacks: int):
+    del visit_ends  # the grid's
+    rhs_hbm, (out, rhs, sems) = refs[:stacks], refs[stacks:]
+    n_i, visit = pl.program_id(0), pl.program_id(1)
+    group = groups[visit]
+    last = ends.shape[0] - 1
+    # With no row at all the one visit run is the last group's, which
+    # fetches group 0's blocks and stores nothing under its empty mask
+    # (no layer is idle where the rows are many tiles; where they are one,
+    # :func:`_one_tile_kernel` runs and fetches nothing).
+    with_rows = jnp.maximum(
+        ranks[last] + (ends[last] > (ends[last - 1] if last else 0)
+                       ).astype(jnp.int32), 1)
+    fetch = n_i * with_rows + ranks[group]
+    slot = jax.lax.rem(fetch, RING)
+
+    @pl.when((visit == 0) | (groups[jnp.maximum(visit - 1, 0)] != group))
+    def _():  # the group's first visit in this n tile
+        _await_block(fetch, rhs_hbm, rhs, sems, by_rank, first_group,
+                     with_rows, tn=tn, tiles_n=tiles_n)
+
+    row = row_tiles[visit] * tm + jax.lax.broadcasted_iota(
+        jnp.int32, out.shape, 0)
+    begin = jnp.where(group > 0, ends[jnp.maximum(group - 1, 0)], 0)
+    mine = (row >= begin) & (row < ends[group])
+    _store_product(mine, lhs, rhs, slot, out, stacks)
+
+
+def _one_tile_kernel(by_rank, count, first_group, lhs, group_of_row, *refs,
+                     tn: int, tiles_n: int, stacks: int):
+    rhs_hbm, (out, rhs, sems) = refs[:stacks], refs[stacks:]
+    n_i, rank = pl.program_id(0), pl.program_id(1)
+    with_rows = count[0]
+
+    # No rows, no work: the one step an idle layer's grid runs starts no
+    # copy and makes no product, and ``out`` is whatever the buffer held.
+    @pl.when(with_rows > 0)
+    def _():
+        fetch = n_i * with_rows + rank
+        # Every visit is its group's first (and only) in this n tile.
+        _await_block(fetch, rhs_hbm, rhs, sems, by_rank, first_group,
+                     with_rows, tn=tn, tiles_n=tiles_n)
+        mine = jnp.broadcast_to(group_of_row[...], out.shape) == by_rank[rank]
+        _store_product(mine, lhs, rhs, jax.lax.rem(fetch, RING), out, stacks)
+
+
 @functools.partial(jax.jit, static_argnames=("tiles", "interpret"))
 def pallas_grouped_matmul(
-    lhs: jax.Array,  # [m, k], rows sorted by group
+    lhs: jax.Array,  # [m, k], rows sorted by group (one tile: as they come)
     rhs: jax.Array,  # [all groups, k, n]: a whole stack
-    visits: GroupVisits,  # of the groups' sizes at ``tiles[0]``
+    visits,  # GroupVisits of the groups' sizes at ``tiles[0]``, or
+    #          OneTileVisits where ``m == tiles[0]``
     first_group: jax.Array,  # () int32: group 0 is ``rhs[first_group]``
     *,
     tiles: Tuple[int, int, int],
     gate: Optional[jax.Array] = None,  # a second stack, shaped like ``rhs``
     interpret: bool = False,
 ) -> jax.Array:
-    """``[m, n]`` in ``lhs``'s dtype; rows past the last group undefined.
-    With ``gate`` the two matmuls of a gated unit in one pass over the
-    rows: ``silu(lhs @ gate[g]) * (lhs @ rhs[g])``, each factor rounded
-    to the dtype as the matmuls alone would round it."""
+    """``[m, n]`` in ``lhs``'s dtype; rows past the last group (one tile:
+    rows of no group) undefined. With ``gate`` the two matmuls of a gated
+    unit in one pass over the rows: ``silu(lhs @ gate[g]) * (lhs @
+    rhs[g])``, each factor rounded to the dtype as the matmuls alone
+    would round it."""
     m, k = lhs.shape
     n = rhs.shape[2]
     tm, tk, tn = tiles
     if m % tm or tk != k or n % tn:
         raise ValueError(f"tiles {tiles} do not fit ({m}, {k}, {n})")
     size = lhs.dtype.itemsize
-    groups = visits.ranks.shape[0]
     stacks = (rhs,) if gate is None else (gate, rhs)
+    one_tile = isinstance(visits, OneTileVisits)
+    if one_tile:
+        if m != tm:
+            raise ValueError(f"{m} rows are not one row tile of {tm}")
+        groups = visits.by_rank.shape[0]
+        kernel = functools.partial(_one_tile_kernel, tn=tn, tiles_n=n // tn,
+                                   stacks=len(stacks))
+        prefetch = (visits.by_rank, visits.count)
+        rows = (lhs, visits.group_of_row)
+        steps = jnp.maximum(visits.count[0], 1)
 
-    def lhs_index(n_i, visit, ends, groups, row_tiles, *_):
-        return row_tiles[visit], 0
+        def lhs_index(n_i, rank, *_):
+            return 0, 0
 
-    def out_index(n_i, visit, ends, groups, row_tiles, *_):
-        return row_tiles[visit], n_i
+        def out_index(n_i, rank, *_):
+            return 0, n_i
+
+        rows_specs = [pl.BlockSpec((tm, k), lhs_index),
+                      pl.BlockSpec((tm, 1), lhs_index)]
+    else:
+        groups = visits.ranks.shape[0]
+        kernel = functools.partial(_kernel, tm=tm, tn=tn, tiles_n=n // tn,
+                                   stacks=len(stacks))
+        prefetch, rows = tuple(visits), (lhs,)
+        # An idle step (no row routed here) still runs one visit.
+        steps = jnp.maximum(visits.visit_ends[-1], 1)
+
+        def lhs_index(n_i, visit, ends, groups, row_tiles, *_):
+            return row_tiles[visit], 0
+
+        def out_index(n_i, visit, ends, groups, row_tiles, *_):
+            return row_tiles[visit], n_i
+
+        rows_specs = [pl.BlockSpec((tm, k), lhs_index)]
 
     vmem = ((RING * len(stacks) * k * tn + 2 * tm * k + 2 * tm * tn) * size
             + (1 + len(stacks)) * tm * tn * 4)
     call = pl.pallas_call(
-        functools.partial(_kernel, tm=tm, tn=tn, tiles_n=n // tn,
-                          stacks=len(stacks)),
+        kernel,
         out_shape=jax.ShapeDtypeStruct((m, n), lhs.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=7,
-            in_specs=[pl.BlockSpec((tm, k), lhs_index)]
+            num_scalar_prefetch=len(prefetch) + 1,
+            in_specs=rows_specs
             + [pl.BlockSpec(memory_space=pl.ANY)] * len(stacks),
             out_specs=pl.BlockSpec((tm, tn), out_index),
-            # An idle step (no row routed here) still runs one visit.
-            grid=(n // tn, jnp.maximum(visits.visit_ends[-1], 1)),
+            grid=(n // tn, steps),
             scratch_shapes=[
                 pltpu.VMEM((RING, len(stacks), k, tn), rhs.dtype),
                 pltpu.SemaphoreType.DMA((RING, len(stacks)))],
@@ -358,15 +467,16 @@ def pallas_grouped_matmul(
         interpret=interpret,
         name="pallas_grouped_matmul",
     )
-    return call(*visits, jnp.asarray(first_group, jnp.int32).reshape(1),
-                lhs, *stacks)
+    return call(*prefetch, jnp.asarray(first_group, jnp.int32).reshape(1),
+                *rows, *stacks)
 
 
-def grouped_matmul(lhs: jax.Array, stack: jax.Array, visits: GroupVisits,
-                   at, tiles: Tuple[int, int, int],
+def grouped_matmul(lhs: jax.Array, stack: jax.Array, visits, at,
+                   tiles: Tuple[int, int, int],
                    gate: Optional[jax.Array] = None) -> jax.Array:
     """The kernel on layer ``at`` of ``stack [layers, held, k, n]`` (and of
-    ``gate``, shaped like it), read as ``layers x held`` groups (a reshape
+    ``gate``, shaped like it) under ``visits`` (:class:`GroupVisits` or
+    :class:`OneTileVisits`), read as ``layers x held`` groups (a reshape
     of leading dims: no copy). Off the TPU (a test that forced the path)
     it runs interpreted."""
     layers, held = stack.shape[:2]

@@ -286,13 +286,23 @@ def test_decode_kernel_compiles_at_lagunas_query_groups(one_chip, heads,
         spec((B, tables)), spec((B,)), spec(()))
 
 
-def _holds_the_grouped_matmul_kernel(text):
+def _traced_the_grouped_matmul_kernel(one_tile):
+    """Every expert layer traced took the Pallas kernel: in the tokens'
+    order where the program's row slots are ``one_tile`` (no ``sort`` is
+    then left under ``moe_experts``), sorted by expert elsewhere."""
+    path, other = (("pallas_one_tile", "pallas") if one_tile else
+                   ("pallas", "pallas_one_tile"))
+    assert att.TRACED_PATHS["grouped_matmul", path] >= 1
+    assert att.TRACED_PATHS["grouped_matmul", other] == 0
+    assert att.TRACED_PATHS["grouped_matmul", "xla"] == 0
+
+
+def _holds_the_grouped_matmul_kernel(text, one_tile=False):
     """The expert layers' matmuls are the Pallas kernel, traced once per
     kind of layer, nothing of ``jax.lax.ragged_dot`` is left, and no
     expert stack (four dims, or the ``layers x held`` groups the kernel
     reads) is copied on its way there."""
-    assert att.TRACED_PATHS["grouped_matmul", "pallas"] >= 1
-    assert att.TRACED_PATHS["grouped_matmul", "xla"] == 0
+    _traced_the_grouped_matmul_kernel(one_tile)
     assert "pallas_grouped_matmul" in text and "ragged-dot" not in text
     copied = [line.strip()[:120] for line in text.splitlines()
               if re.search(r"= bf16\[(\d+,64|\d{3,}),\d{4},\d{4}\]\S* "
@@ -453,7 +463,8 @@ def test_lfm2_programs_compile_at_the_configurations_widths(
     assert ("pallas_paged_attention" in text) == (mode == "decode")
     assert ("pallas_prefill_attention" in text) == (mode == "prefill_cached")
     assert not [k for k in att.TRACED_PATHS if k[1] == "xla"]
-    _holds_the_grouped_matmul_kernel(text)
+    # 32 rows x top 4 are one row tile: nothing is sorted by expert
+    _holds_the_grouped_matmul_kernel(text, one_tile=mode == "decode")
     one_side = layers * blocks * BLOCK_SIZE * page_rows * lanes * 2
     experts_of_a_layer = 64 * 3 * 2048 * 1536 * 2
     temp = program.memory_analysis().temp_size_in_bytes
@@ -571,7 +582,7 @@ def test_longcat_programs_compile_at_the_configurations_widths(
     assert ("pallas_mla_decode" in text) == (mode == "decode")
     assert att.TRACED_PATHS["latent_decode", "pallas"] == (
         mode == "decode")
-    assert att.TRACED_PATHS["grouped_matmul", "pallas"] >= 1
+    _traced_the_grouped_matmul_kernel(one_tile=False)
     assert "pallas_grouped_matmul" in text and "ragged-dot" not in text
     copied = [line.strip()[:120] for line in text.splitlines()
               if re.search(rf"= bf16\[(4,16,\d{{4}},\d{{4}}|8,{blocks},"
@@ -699,7 +710,8 @@ def test_glm47_flash_programs_compile_at_the_configurations_widths(
     assert ("pallas_mla_decode" in text) == (mode == "decode")
     assert att.TRACED_PATHS["latent_decode", "pallas"] == (
         mode == "decode")
-    assert att.TRACED_PATHS["grouped_matmul", "pallas"] >= 1
+    # 32 rows x top 4 are one row tile: nothing is sorted by expert
+    _traced_the_grouped_matmul_kernel(one_tile=mode == "decode")
     assert "pallas_grouped_matmul" in text and "ragged-dot" not in text
     if mode == "prefill_cached":
         form = decoder.latent_prefill_form(

@@ -165,6 +165,8 @@ def test_metrics_exposition(server_url):
         assert "tpu:prefill_attention_dispatch_total{" in text
         assert "tpu:expert_matmul_dispatch_total{" in text
         assert 'path="pallas"}' in text
+        assert 'path="pallas_one_tile"}' in text
+        assert "tpu:moe_idle_layers_total{" in text
         assert 'path="xla"}' in text
     asyncio.run(run())
 

@@ -109,14 +109,142 @@ def test_group_visits_are_the_pairs_that_hold_rows(sizes, m, tm):
     assert 0 <= int(visits.groups.min()) and int(visits.groups.max()) < len(sizes)
     assert 0 <= int(visits.row_tiles.min())
     assert int(visits.row_tiles.max()) < m // tm
+    # The part of them that no row tile enters is what one tile takes.
+    one = gmm.one_tile_visits(jnp.asarray(sizes, jnp.int32),
+                              jnp.zeros((m,), jnp.int32))
+    assert np.asarray(one.count).tolist() == [len(ranked)]
+    assert np.asarray(one.by_rank).tolist() == np.asarray(
+        visits.by_rank).tolist()
+    assert one.group_of_row.shape == (m, 1)
 
 
-@pytest.mark.parametrize("share,tokens", [(0, 16), (1, 16), (0, 3)])
+# One row tile of 32 rows in the tokens' order over 4 held groups: each
+# row's group, ``4`` for a row that is nobody's here (another chip's
+# expert, a padding row).
+ONE_TILE_ROWS = {
+    "no_row_at_all": [4] * 32,
+    "one_group": [2 if row in (3, 9, 20) else 4 for row in range(32)],
+    "every_group": [row % 4 for row in range(32)],  # more than the ring
+    "two_rows_on_one_expert": [1 if row in (5, 6) else 4 for row in range(32)],
+    "other_chips_and_padding_mixed_in":
+        [(0, 4, 3, 4, 4, 1, 4)[row % 7] for row in range(24)] + [4] * 8,
+    "the_first_and_the_last_group":
+        [(0, 3, 4)[row % 3] for row in range(32)],
+}
+
+
+def _one_tile_case(case, dtype, gated):
+    """(kernel arguments, the rows' groups, live rows) of a case of
+    :data:`ONE_TILE_ROWS`: 2 layers of 4 groups ``[128, 256]``, layer 1,
+    two n tiles."""
+    m, k, n, held, layers, at = 32, 128, 256, 4, 2, 1
+    keys = jax.random.split(jax.random.key(len(case)), 3)
+    lhs = jax.random.normal(keys[0], (m, k), F32).astype(dtype)
+    stack, gate = ((jax.random.normal(key, (layers * held, k, n), F32)
+                    / np.sqrt(k)).astype(dtype) for key in keys[1:])
+    group = jnp.asarray(ONE_TILE_ROWS[case], jnp.int32)
+    sizes = gmm.group_sizes(group, held)
+    kwargs = dict(first_group=jnp.int32(at * held), tiles=(m, k, 128),
+                  interpret=True, gate=gate if gated else None)
+    return lhs, stack, group, sizes, kwargs
+
+
+@pytest.mark.parametrize("gated", [False, True], ids=["plain", "gated"])
+@pytest.mark.parametrize("dtype", [BF16, F32], ids=["bf16", "f32"])
+@pytest.mark.parametrize("case", ONE_TILE_ROWS)
+def test_one_tile_in_the_tokens_order_is_the_sorted_path_bit_for_bit(
+        case, dtype, gated):
+    """Where the rows are one row tile the kernel takes them as they
+    come, with each row's group beside them: on every row that has a
+    group the same bits as the sorted path permuted back, and
+    ``ragged_dot``'s values."""
+    lhs, stack, group, sizes, kwargs = _one_tile_case(case, dtype, gated)
+    m, held = lhs.shape[0], sizes.shape[0]
+    live = np.asarray(group) < held
+    got = gmm.pallas_grouped_matmul(
+        lhs, stack, gmm.one_tile_visits(sizes, group), **kwargs)
+    assert got.shape == (m, stack.shape[2]) and got.dtype == dtype
+
+    order = jnp.argsort(group, stable=True)
+    back = jnp.argsort(order)
+    by_group = gmm.pallas_grouped_matmul(
+        lhs[order], stack, gmm.group_visits(sizes, m, m), **kwargs)[back]
+    assert np.array_equal(np.asarray(got, np.float32)[live],
+                          np.asarray(by_group, np.float32)[live])
+
+    def ragged(w):
+        return jax.lax.ragged_dot(
+            lhs[order], w[held:], sizes, preferred_element_type=F32
+        ).astype(dtype)[back]
+
+    want = ragged(stack)
+    if gated:
+        want = jax.nn.silu(ragged(kwargs["gate"]).astype(F32)
+                           ).astype(dtype) * want
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32)[live], np.asarray(want, np.float32)[live],
+        rtol=2e-2 if dtype == BF16 else 1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("gated", [False, True], ids=["plain", "gated"])
+@pytest.mark.parametrize("case", ONE_TILE_ROWS)
+def test_one_tile_copies_the_groups_that_have_rows_and_no_other(
+        monkeypatch, case, gated):
+    """The copies the kernel starts, counted as they run: one a stack, n
+    tile and group that has rows, so none at all in an idle layer, where
+    the sorted path reads a whole expert (``rhs[first_group]``) for
+    nothing. Every group without rows is poisoned, that one among them,
+    and no row that has a group sees it."""
+    lhs, stack, group, sizes, kwargs = _one_tile_case(case, BF16, gated)
+    held = sizes.shape[0]
+    hit = int((sizes > 0).sum())
+    poison = jnp.concatenate([jnp.ones((held,), bool), sizes == 0])
+    stack = jnp.where(poison[:, None, None], jnp.nan, stack)
+    if gated:
+        kwargs["gate"] = jnp.where(poison[:, None, None], jnp.nan,
+                                   kwargs["gate"])
+    started = []
+    make_copy = gmm.pltpu.make_async_copy
+
+    class Counted:
+        def __init__(self, *args):
+            self.copy = make_copy(*args)
+            self.wait = self.copy.wait
+
+        def start(self):
+            jax.debug.callback(lambda: started.append(1))
+            self.copy.start()
+
+    monkeypatch.setattr(gmm.pltpu, "make_async_copy", Counted)
+
+    def copies(visits, lhs):
+        started.clear()
+        # not the jitted entry point: its cache would hold the real copies
+        out = jax.block_until_ready(gmm.pallas_grouped_matmul.__wrapped__(
+            lhs, stack, visits, **kwargs))
+        jax.effects_barrier()
+        return len(started), out
+
+    tiles_n, stacks = stack.shape[2] // 128, 1 + gated
+    count, out = copies(gmm.one_tile_visits(sizes, group), lhs)
+    assert count == tiles_n * stacks * hit
+    live = np.asarray(group) < held
+    assert not np.isnan(np.asarray(out, np.float32)[live]).any()
+    order = jnp.argsort(group, stable=True)
+    count, _ = copies(gmm.group_visits(sizes, lhs.shape[0], lhs.shape[0]),
+                      lhs[order])
+    assert count == tiles_n * stacks * max(hit, 1)
+
+
+@pytest.mark.parametrize("share,tokens", [(0, 16), (1, 16), (0, 3), (0, 24),
+                                          (1, 24)])
 def test_expert_layer_is_the_same_on_either_path(monkeypatch, share, tokens):
     """``expert_layer`` with the kernel forced (interpreted here) and with
     ``ragged_dot``: the same ``y`` and the same ``STATS``, for a chip that
     holds every expert and for one that holds a block of them, with
-    padding rows; three tokens' six row slots do not tile and take
+    padding rows; 16 tokens' 32 row slots are one row tile and stay in
+    the tokens' order, 24 tokens' 48 are three tiles of 16 and are
+    sorted; three tokens' six row slots do not tile and take
     ``ragged_dot`` whatever the platform."""
     hidden, width, held, layers, top = 128, 256, 4, 3, 2
     published = held * (2 if share else 1)
@@ -143,8 +271,8 @@ def test_expert_layer_is_the_same_on_either_path(monkeypatch, share, tokens):
     y_xla, stats_xla, traced = run(False)
     assert traced == {("grouped_matmul", "xla"): 1}
     y, stats, traced = run(True)
-    tiles = tokens * top % 16 == 0
-    assert traced == {("grouped_matmul", "pallas" if tiles else "xla"): 1}
+    assert traced == {("grouped_matmul", {
+        16: "pallas_one_tile", 24: "pallas", 3: "xla"}[tokens]): 1}
     assert np.asarray(stats).tolist() == np.asarray(stats_xla).tolist()
     assert int(stats[0]) > 0
     np.testing.assert_allclose(np.asarray(y, np.float32),
@@ -176,8 +304,9 @@ def test_every_shape_of_the_families_tiles(monkeypatch, widths):
         m = tokens * top
         row_tiles = set()
         for k, n in ((hidden, width), (width, hidden)):
-            assert gmm.grouped_matmul_path(m, k, n, BF16, held) == "pallas"
             tm, tk, tn = gmm.grouped_matmul_tiles(m, k, n, BF16, held)
+            assert gmm.grouped_matmul_path(m, k, n, BF16, held) == (
+                "pallas_one_tile" if m == tm else "pallas")
             assert m % tm == 0 and tk == k and n % tn == 0
             assert tm % 16 == 0 and tn % 128 == 0
             assert tk * tn * 2 <= gmm.RHS_TILE_BYTES
@@ -192,6 +321,11 @@ def test_every_shape_of_the_families_tiles(monkeypatch, widths):
         128, 2048, 768)
     assert gmm.grouped_matmul_tiles(128, 1536, 2048, BF16, 64) == (
         128, 1536, 1024)
+    # One row tile: the sessions cells' decode programs (32 rows x top 4),
+    # and nothing of Laguna's (its 32 rows x top 10 are five tiles of 64).
+    one_tile = [tokens for tokens in TOKENS if gmm.grouped_matmul_path(
+        tokens * top, hidden, width, BF16, held) == "pallas_one_tile"]
+    assert one_tile == {LAGUNA: [], LFM2: [32], MIXTRAL: [32, 64]}[widths]
 
 
 @pytest.mark.parametrize("m,k,n,dtype,why", [
@@ -235,9 +369,10 @@ def test_the_engine_counts_step_programs_by_their_path(model, counts):
         if r.get("program", "").startswith(("prefill", "decode")))
     assert programs >= 3
     assert stats["expert_matmul_dispatch_total"] == {
-        "pallas": 0, "xla": programs if counts else 0}
+        "pallas": 0, "pallas_one_tile": 0, "xla": programs if counts else 0}
     assert (gmm.TRACED_PATHS["grouped_matmul", "xla"] > 0) == counts
     assert gmm.TRACED_PATHS["grouped_matmul", "pallas"] == 0
+    assert gmm.TRACED_PATHS["grouped_matmul", "pallas_one_tile"] == 0
 
 
 def test_a_program_that_spans_devices_keeps_ragged_dot(monkeypatch):

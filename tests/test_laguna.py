@@ -292,6 +292,10 @@ def test_step_records_carry_the_expert_layers_counts(monkeypatch):
         assert 0 <= r["moe_assignments"] <= 3 * per_layer
         assert r["moe_experts_hit"] == r["moe_assignments"]
         assert r["moe_max_expert_load"] <= per_layer
+        # a layer is idle or hits an expert, never both
+        assert 0 <= r["moe_idle_layers"] <= per_layer - (
+            r["moe_experts_hit"] + 2) // 3
+        assert per_layer - r["moe_idle_layers"] <= r["moe_experts_hit"]
     assert prefill[0]["prefill_moe_assignments"] <= 30 * 3 * sparse
     assert prefill[0]["prefill_moe_max_expert_load"] <= 30 * sparse
     totals = eng.stats()["family_stats_total"]

@@ -551,7 +551,8 @@ def test_zero_experts_against_a_loop():
                 want[n] += w * out
                 loads += 1
     np.testing.assert_allclose(y[0], want, atol=2e-4)
-    assert stats.shape == (4,) and int(stats[3]) == zero > 0
+    assert stats.shape == (5,) and int(stats[-1]) == zero > 0
+    assert int(stats[moe.STATS.index("moe_idle_layers")]) == 0
     assert int(stats[0]) == loads
     assert moe.STATS + moe.ZERO_STATS == get_family("longcat").stats
 
@@ -585,7 +586,7 @@ def test_the_shares_add_up_to_the_whole_layer(chips):
             routing={"bias": case["bias"], "renormalise": False})
         total += np.asarray(y[0]) - np.asarray(identity)
         hits += int(stats[0])
-        assert int(stats[3]) == zero_picks  # the same on every chip
+        assert int(stats[-1]) == zero_picks  # the same on every chip
         # the reference's own share is the program's
         mine_ref, _ = reference.moe_layer(
             case["h"][0], case["router"], case["bias"],
@@ -625,7 +626,7 @@ def test_the_other_moe_families_count_what_they_counted(preset):
                 np.asarray([[1, 2, 3, 4]]), np.asarray([T]),
                 np.asarray([T]), mode="prefill", with_stats=True)
     if family.stats:
-        assert family.stats == moe.STATS and out[2].shape == (3,)
+        assert family.stats == moe.STATS and out[2].shape == (4,)
         sparse = mc.num_layers - mc.dense_layers
         held_share = mc.num_experts / mc.published_experts
         assert 0 < int(out[2][0]) <= T * mc.experts_per_token * sparse
