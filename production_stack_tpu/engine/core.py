@@ -635,6 +635,13 @@ class EngineCore:
         # [prefill_batch, chunk]).
         self.prefill_padded_tokens_total = 0
         self.kv_fetch_tokens_total = 0  # copied by the decode kernel
+        # The share of the page layers that belong to layers with a
+        # window (0 for a model without one): what ``kv_window_dead_tokens``
+        # weighs a token past the window by.
+        mc = self.model_config
+        self._window_layer_share = sum(
+            mc.window_of(mc.layer_kind(l)) is not None
+            for l in range(mc.num_layers)) / max(self.page_dims[0], 1)
         # Prefill rows that began from a block's state, and block entries
         # written (a family with Family.block_state; 0 otherwise).
         self.state_restores_total = 0
@@ -2614,9 +2621,26 @@ class EngineCore:
         needed = (n_tokens + 1 + bs - 1) // bs
         return needed > self.num_blocks
 
+    def _window_dead_tokens(self, contexts) -> int:
+        """Of the live sequences' pages, how many tokens' worth no kernel
+        will read again: a window layer reads a sequence's last
+        ``sliding_window`` tokens and the uniform pool keeps every token
+        in every layer, so ``max(0, context - window)`` tokens a
+        sequence are dead in the window layers' share of the page layers
+        (what a per-kind allocator or a ring would free; ROADMAP M2/M3).
+        0 for a model without a window."""
+        window = self.model_config.sliding_window
+        if not (window and self._window_layer_share):
+            return 0
+        past = np.maximum(np.asarray(contexts, np.int64) - window, 0).sum()
+        return int(round(float(past) * self._window_layer_share))
+
     # -- stats -------------------------------------------------------------
     def stats(self) -> dict:
         alloc = self.kv_mgr.allocator
+        with self._lock:
+            contexts = [len(s.req.prompt_token_ids) + s.req.scheduled_steps
+                        for s in self.scheduler.running()]
         budget = self.scheduler.token_budget if \
             self.scheduler.chunked_prefill else 0
         # Wall-clock split of the engine thread, from the loop's one
@@ -2638,6 +2662,7 @@ class EngineCore:
             "cached_tokens_total": self.cached_tokens_total,
             "prefill_padded_tokens_total": self.prefill_padded_tokens_total,
             "kv_fetch_tokens_total": self.kv_fetch_tokens_total,
+            "kv_window_dead_tokens": self._window_dead_tokens(contexts),
             "state_restores_total": self.state_restores_total,
             "state_blocks_written_total": self.state_blocks_written_total,
             "family_stats_total": dict(self.family_stats_total),
@@ -3898,6 +3923,9 @@ class EngineCore:
                         live, cfg.block_size, maxb, window),
                     kv_live_tokens_window=int(
                         np.minimum(live, window).sum()))
+        if self._window_layer_share:
+            self._steps.note(kv_window_dead_tokens=self._window_dead_tokens(
+                [context0[s.slot] for s in active]))
         if self.block_state_shape:
             # A decode step writes the entry of the block it writes its
             # token into.

@@ -2465,6 +2465,9 @@ class EngineServer:
             f"vllm:gpu_cache_usage_perc{{{labels}}} {s['kv_usage']:.6f}",
             "# TYPE tpu:hbm_kv_usage_perc gauge",
             f"tpu:hbm_kv_usage_perc{{{labels}}} {s['kv_usage']:.6f}",
+            "# TYPE tpu:kv_window_dead_tokens gauge",
+            f"tpu:kv_window_dead_tokens{{{labels}}} "
+            f"{s.get('kv_window_dead_tokens', 0)}",
             "# TYPE vllm:gpu_prefix_cache_hits counter",
             f"vllm:gpu_prefix_cache_hits_total{{{labels}}} {s['prefix_cache_hits']}",
             "# TYPE vllm:gpu_prefix_cache_queries counter",

@@ -20,6 +20,11 @@ import jax.numpy as jnp
 FULL_ATTENTION = "full_attention"
 SLIDING_ATTENTION = "sliding_attention"
 SHORT_CONV = "conv"
+# The two attention kinds again for a layer that takes NO positional
+# encoding (NoPE: its queries and keys go unrotated; smallthinker). A
+# kind names both of a layer's switches, the window and the rotation.
+NOPE_FULL_ATTENTION = "full_attention_nope"
+NOPE_SLIDING_ATTENTION = "sliding_attention_nope"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -63,7 +68,8 @@ class ModelConfig:
     layer_types: Tuple[str, ...] = ()
     heads_per_layer: Tuple[int, ...] = ()
     sliding_window: int = 0  # of the ``sliding_attention`` layers
-    rope_by_kind: Tuple[Tuple[str, RopeParams], ...] = ()
+    # A kind's rotary block; None for a kind that takes no rotation.
+    rope_by_kind: Tuple[Tuple[str, Optional[RopeParams]], ...] = ()
     moe_intermediate_size: int = 0  # a routed expert's width
     shared_expert_size: int = 0  # 0 = no shared expert
     routed_scaling: float = 1.0
@@ -128,10 +134,13 @@ class ModelConfig:
         """The static window bound ``decoder.attend`` takes for a layer
         of this kind: None where it sees its whole context."""
         return (self.sliding_window
-                if kind == SLIDING_ATTENTION and self.sliding_window > 0
-                else None)
+                if kind in (SLIDING_ATTENTION, NOPE_SLIDING_ATTENTION)
+                and self.sliding_window > 0 else None)
 
-    def rope_of(self, kind: str) -> RopeParams:
+    def rope_of(self, kind: str) -> Optional[RopeParams]:
+        """The rotary block of a layer of this kind; None where the kind
+        takes no positional encoding (nothing is rotated: not a rotation
+        by zero angles, which would still multiply)."""
         return dict(self.rope_by_kind).get(
             kind, RopeParams(rope_theta=self.rope_theta))
 
@@ -213,6 +222,19 @@ _PRESETS = {
         router_scoring="sigmoid", router_bias=True, q_lora_rank=48,
         kv_lora_rank=128, qk_nope_head_dim=24, qk_rope_head_dim=16,
         v_head_dim=32,
+    ),
+    # Two periods of SmallThinker's layout at a window shorter than the
+    # tests' contexts: a NoPE full layer, then rotary window layers; 8
+    # ReGLU experts top 3 routed from the layer's input, 7 query heads a
+    # kv head as the published 28 / 4.
+    "tiny-smallthinker": ModelConfig(
+        name="tiny-smallthinker", arch="smallthinker", vocab_size=512,
+        hidden_size=128, num_layers=4, num_heads=14, num_kv_heads=2,
+        head_dim=32, intermediate_size=0, max_position=2048,
+        rope_theta=1500000.0, rms_norm_eps=1e-6, num_experts=8,
+        experts_per_token=3, moe_intermediate_size=64, sliding_window=24,
+        layer_types=(NOPE_FULL_ATTENTION, SLIDING_ATTENTION) * 2,
+        rope_by_kind=((NOPE_FULL_ATTENTION, None),),
     ),
     # Three sandwich-normed layers run twice over the same weights, a
     # query group of one (as Ouro's), a page layer for every pass.

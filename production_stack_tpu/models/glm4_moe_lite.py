@@ -65,6 +65,9 @@ from production_stack_tpu.models.moe import EXPERT_STACKS
 from production_stack_tpu.models.registry import Family, replicated
 
 ROUTER_EPS = 1e-20
+# assumed: hidden_act silu in every SwiGLU of the model (``config_fields``
+# refuses another): dense MLP, routed experts, shared expert.
+ACTIVATION = "silu"
 # The spread of the router's selection bias around zero and of every
 # norm weight around one in a random tree: a trained checkpoint's are
 # not zero and one, and a program that dropped the bias, or applied a
@@ -191,11 +194,10 @@ def _experts(cfg: ModelConfig, h, layers: Dict, at, valid):
         h, weights, at=at, k=cfg.experts_per_token, share=cfg.layer_share,
         scaling=cfg.routed_scaling, valid=valid,
         routing={"scoring": cfg.router_scoring, "bias": p["router_bias"],
-                 "eps": ROUTER_EPS})
+                 "eps": ROUTER_EPS}, activation=ACTIVATION)
     with jax.named_scope("moe_shared"):
-        # assumed: hidden_act silu in every SwiGLU of the model.
         out = routed + moe.swiglu(h, p["shared_gate"], p["shared_up"],
-                                  p["shared_down"])
+                                  p["shared_down"], activation=ACTIVATION)
     return out, stats
 
 
@@ -210,7 +212,8 @@ def run_layers(cfg: ModelConfig, mode: str, x, params: Dict, kv_pages,
 
     def dense_mlp(h, layer):
         with jax.named_scope("mlp"):
-            return moe.dense_layer(h, params["dense"], layer)
+            return moe.dense_layer(h, params["dense"], layer,
+                                   activation=ACTIVATION)
 
     def sparse_mlp(h, layer):
         return _experts(cfg, h, params["moe"], layer - d, valid)

@@ -50,6 +50,9 @@ from production_stack_tpu.models.config import (
 )
 from production_stack_tpu.models.registry import Family, replicated
 
+# assumed (c): hidden_act is silu (the catalog's copy of the config has no
+# such key): the gate of the dense MLP, the routed and the shared experts.
+ACTIVATION = "silu"
 
 # --------------------------------------------------------------------- #
 # Which layers there are
@@ -238,8 +241,8 @@ def run_layers(cfg: ModelConfig, mode: str, x, params: Dict, kv_pages,
         return run
 
     def dense_mlp(x, h, layer):
-        # assumed (c): hidden_act is silu.
-        out, s = moe.dense_layer(h, params["dense"], layer)
+        out, s = moe.dense_layer(h, params["dense"], layer,
+                                 activation=ACTIVATION)
         return x + out, s
 
     def sparse_mlp(x, h, layer):
@@ -249,12 +252,12 @@ def run_layers(cfg: ModelConfig, mode: str, x, params: Dict, kv_pages,
         routed, stats = moe.expert_layer(
             h, p, at=layer - d, k=cfg.experts_per_token,
             share=cfg.layer_share, scaling=cfg.routed_scaling,
-            valid=batch.slot_mapping >= 0)
+            valid=batch.slot_mapping >= 0, activation=ACTIVATION)
         x = x + routed
         if "shared_gate" in w:
             with jax.named_scope("moe_shared"):
                 x = x + moe.swiglu(h, w["shared_gate"], w["shared_up"],
-                                   w["shared_down"])
+                                   w["shared_down"], activation=ACTIVATION)
         return x, stats
 
     def layer_step(x, kv, layer, _):

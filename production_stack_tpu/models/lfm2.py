@@ -65,6 +65,9 @@ from production_stack_tpu.models.registry import Family, replicated
 from production_stack_tpu.ops.attention import kv_page_data
 
 ROUTER_EPS = 1e-6
+# assumed (b): the activation is silu (the config names none): the gate
+# of the dense MLP and of every routed expert.
+ACTIVATION = "silu"
 # The spread of the router's selection bias and of the q/k norm weights
 # around one in a random tree: a trained checkpoint's are not zero and
 # one, and a program that dropped either would pass with those.
@@ -279,8 +282,8 @@ def run_layers(cfg: ModelConfig, mode: str, x, params: Dict, kv_pages,
         return out, (k_all, v_all, state)
 
     def dense_mlp(h, layer):
-        # assumed (b): the activation is silu.
-        return moe.dense_layer(h, params["dense"], layer)
+        return moe.dense_layer(h, params["dense"], layer,
+                               activation=ACTIVATION)
 
     def sparse_mlp(h, layer):
         w, p = moe.sparse_leaves(params["moe"], layer - d)
@@ -288,7 +291,8 @@ def run_layers(cfg: ModelConfig, mode: str, x, params: Dict, kv_pages,
             h, p, at=layer - d, k=cfg.experts_per_token,
             scaling=cfg.routed_scaling, valid=batch.slot_mapping >= 0,
             routing={"scoring": cfg.router_scoring,
-                     "bias": w.get("router_bias"), "eps": ROUTER_EPS})
+                     "bias": w.get("router_bias"), "eps": ROUTER_EPS},
+            activation=ACTIVATION)
 
     def layer_step(x, sides, layer, _):
         norms = decoder.take(params["norms"], layer)

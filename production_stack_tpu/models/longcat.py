@@ -58,6 +58,9 @@ from production_stack_tpu.models.config import ModelConfig
 from production_stack_tpu.models.registry import Family, replicated
 
 EXPERT_STACKS = ("e_gate", "e_up", "e_down")
+# assumed: hidden_act is silu (``config_fields`` refuses another): the
+# gate of both dense MLPs and of every routed expert.
+ACTIVATION = "silu"
 
 
 # --------------------------------------------------------------------- #
@@ -148,7 +151,7 @@ def run_layers(cfg: ModelConfig, mode: str, x, params: Dict, kv_pages,
             scaling=cfg.routed_scaling, valid=batch.slot_mapping >= 0,
             routing={"bias": w["router_bias"],
                      "renormalise": False},  # assumed: weights as scored
-            zero_experts=cfg.zero_experts)
+            zero_experts=cfg.zero_experts, activation=ACTIVATION)
 
     def sublayer(x, sides, j, p, shortcut):
         first = j % 2 == 0
@@ -162,8 +165,8 @@ def run_layers(cfg: ModelConfig, mode: str, x, params: Dict, kv_pages,
         shortcut, s = jax.lax.cond(
             first, experts, lambda h, layer: (shortcut, no_stats), h, j // 2)
         with jax.named_scope("mlp"):
-            # assumed: hidden_act is silu.
-            x = x + moe.swiglu(h, p["w_gate"], p["w_up"], p["w_down"])
+            x = x + moe.swiglu(h, p["w_gate"], p["w_up"], p["w_down"],
+                               activation=ACTIVATION)
             x = jnp.where(first, x, x + shortcut)
         return x, sides, s, shortcut
 

@@ -8,7 +8,8 @@ expert and go through ONE grouped matmul per projection: **the Pallas
 grouped matmul on the chip** (``ops/pallas_grouped_matmul.py``: each
 expert's weights stream once, in tiles of megabytes chosen from the
 shapes, only the row tiles of groups that have rows are visited, and
-the gate and up projections share one pass over the rows),
+the gate and up projections share one pass over the rows, under the
+gate activation the family names: ``silu`` or ``relu``),
 **``jax.lax.ragged_dot`` elsewhere** (off the TPU, in a program that
 spans devices, at a shape that does not tile). **Where the assignments
 are one row tile** (``tokens x k`` = the row tile: a decode step of 32
@@ -64,20 +65,26 @@ ZERO_STATS = ("moe_zero_assignments",)
 EXPERT_STACKS = ("w_gate", "w_up", "w_down")
 
 
-def swiglu(h: jax.Array, w_gate, w_up, w_down) -> jax.Array:
-    """``(silu(h Wgate) * (h Wup)) Wdown``: the dense MLP, the shared
-    expert, and what each routed expert computes on its rows."""
-    gate = jax.nn.silu((h @ w_gate).astype(jnp.float32)).astype(h.dtype)
+def swiglu(h: jax.Array, w_gate, w_up, w_down, *,
+           activation: str = "silu") -> jax.Array:
+    """``(act(h Wgate) * (h Wup)) Wdown``, the gated unit: the dense MLP,
+    the shared expert, and what each routed expert computes on its rows.
+    ``activation`` names ``act`` (``gmm.ACTIVATIONS``): ``silu`` is
+    SwiGLU, ``relu`` ReGLU."""
+    gate = gmm.ACTIVATIONS[activation](
+        (h @ w_gate).astype(jnp.float32)).astype(h.dtype)
     return (gate * (h @ w_up)) @ w_down
 
 
-def dense_layer(h: jax.Array, stack: Dict, at) -> Tuple[jax.Array, jax.Array]:
+def dense_layer(h: jax.Array, stack: Dict, at, *, activation: str = "silu"
+                ) -> Tuple[jax.Array, jax.Array]:
     """The other kind of MLP of a family with leading dense layers:
-    :func:`swiglu` on entry ``at`` of the stack ``{w_gate, w_up, w_down:
-    [dense layers, ...]}``, and the zeros its layer adds to the expert
-    layers' :data:`STATS`."""
+    :func:`swiglu` (under ``activation``) on entry ``at`` of the stack
+    ``{w_gate, w_up, w_down: [dense layers, ...]}``, and the zeros its
+    layer adds to the expert layers' :data:`STATS`."""
     w = decoder.take(stack, at)
-    return (swiglu(h, w["w_gate"], w["w_up"], w["w_down"]),
+    return (swiglu(h, w["w_gate"], w["w_up"], w["w_down"],
+                   activation=activation),
             jnp.zeros((len(STATS),), jnp.int32))
 
 
@@ -134,11 +141,21 @@ def expert_layer(
     valid: jax.Array | None = None,  # [B, T] bool: padding routes nowhere
     routing: Dict | None = None,  # route()'s scoring / bias / eps / ...
     zero_experts: int = 0,  # the router's last outputs are identities
+    activation: str = "silu",  # of each expert's gate: silu or relu
+    routed: Tuple[jax.Array, jax.Array] | None = None,  # route()'s result
 ) -> Tuple[jax.Array, jax.Array]:
-    """The routed experts held here on ``h``. Returns (their weighted sum
+    """The routed experts held here on ``h``, each a gated unit
+    ``(activation(h Wgate) * (h Wup)) Wdown``. Returns (their weighted sum
     per token [B, T, Hd], the :data:`STATS` of the call as int32 [4]: the
     assignments the held experts received, how many of them received one,
     the largest number one received, and 1 if none received any).
+
+    ``routed``: the (weights [N, k] float32, experts [N, k]) of
+    :func:`route` where the family computed them elsewhere, under its
+    own ``moe_router`` scope (a router that reads another tensor than
+    the experts compute on: the layer's input, an attention earlier).
+    ``p`` then needs no ``router``, and ``k``, ``scaling`` and
+    ``routing`` are the caller's to have applied.
 
     With ``zero_experts`` the router's outputs ``[E, E + zero_experts)``
     behind the ``E`` experts that have weights are identities
@@ -153,9 +170,12 @@ def expert_layer(
     N = B * T
     held = p["w_gate"].shape[1]
     x = h.reshape(N, Hd)
-    with jax.named_scope("moe_router"):
-        weights, experts = route(x, p["router"], k, scaling=scaling,
-                                 **(routing or {}))
+    if routed is None:
+        with jax.named_scope("moe_router"):
+            weights, experts = route(x, p["router"], k, scaling=scaling,
+                                     **(routing or {}))
+    else:
+        weights, experts = routed
     with jax.named_scope("moe_experts"):
         local = experts - share * held
         mine = (local >= 0) & (local < held)
@@ -185,9 +205,9 @@ def expert_layer(
                 for name in ("w_up", "w_down"))
             visits = (gmm.one_tile_visits(sizes, group) if one_tile else
                       gmm.group_visits(sizes, m, up_tiles[0]))
-            # silu(rows Wgate) * (rows Wup) in one pass over the rows.
+            # act(rows Wgate) * (rows Wup) in one pass over the rows.
             act = gmm.grouped_matmul(rows, p["w_up"], visits, at, up_tiles,
-                                     gate=p["w_gate"])
+                                     gate=p["w_gate"], activation=activation)
             out = gmm.grouped_matmul(act, p["w_down"], visits, at,
                                      down_tiles)
         else:
@@ -203,7 +223,8 @@ def expert_layer(
 
             gate = grouped(rows, "w_gate")
             up = grouped(rows, "w_up")
-            act = jax.nn.silu(gate.astype(jnp.float32)).astype(h.dtype) * up
+            act = gmm.ACTIVATIONS[activation](
+                gate.astype(jnp.float32)).astype(h.dtype) * up
             out = grouped(act, "w_down")  # [N * k, Hd]
         # Back to the token's order; a row past the groups is not the
         # grouped matmul's to define, so it is replaced, not multiplied.

@@ -30,6 +30,7 @@ ARCH_MODULES = {
     "longcat": "production_stack_tpu.models.longcat",
     "glm4_moe_lite": "production_stack_tpu.models.glm4_moe_lite",
     "ouro": "production_stack_tpu.models.ouro",
+    "smallthinker": "production_stack_tpu.models.smallthinker",
 }
 
 

@@ -70,10 +70,11 @@ def _page_tile_ok(block_size: int, kvh: int, head_dim: int,
     sliced dims tile-aligned (KVH to the 8-row sublane, D to the 128
     lanes, bs to 8). Four rows of bf16 or float32 are a tile of their own
     (``T(4,128)(2,1)`` for bf16: the v5e compiler takes both kernels
-    there, tests/test_chip_compile.py), admitted only for the rows
-    :func:`packed_page_dims` made of eight 64-wide heads (``packed``):
-    four heads of 128, or eight sharded two ways, take the reference as
-    they did. Misaligned models (e.g. OPT: 12 kv-heads, head_dim 64)
+    there, tests/test_chip_compile.py), admitted (``packed``) for a pool
+    whose own rows are four: the rows :func:`packed_page_dims` made of
+    eight 64-wide heads, or a model of four kv heads of 128
+    (:func:`attention_path`); eight heads sharded two ways take the
+    reference as they did. Misaligned models (e.g. OPT: 12 kv-heads, head_dim 64)
     take the XLA reference — and this MUST be decided at trace time: a
     Mosaic failure surfaces when the enclosing jit compiles, where no
     fallback is possible."""
@@ -183,8 +184,12 @@ def attention_path(block_size: int, kvh: int, head_dim: int,
     the heads, so a shard cannot address its own."""
     if kv_shards > 1 and quantized:
         return "xla"
-    if (_page_tile_ok(block_size, kvh // kv_shards, head_dim,
-                      packed and not quantized and kv_shards == 1)
+    # Four rows are a tile where the pool itself holds four: packed of
+    # eight narrow heads, or a model's own four kv heads (smallthinker:
+    # 4 x 128, the same ``[bs, 4, 128]`` pages); never a shard's four of a
+    # wider pool, never int8 (whose tile is 32 rows).
+    four_rows = (packed or kvh == 4) and not quantized and kv_shards == 1
+    if (_page_tile_ok(block_size, kvh // kv_shards, head_dim, four_rows)
             and _use_pallas()):
         return "pallas"
     return "xla"

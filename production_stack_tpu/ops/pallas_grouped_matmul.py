@@ -28,10 +28,12 @@ What differs from ``gmm``:
   ``[groups, groups]`` and ``[visits, groups]``, where ``gmm`` repeats
   and histograms; one call serves the matmuls of a layer.
 - With ``gate`` the kernel makes both matmuls of a gated unit in one pass
-  over the rows, ``silu(lhs @ gate[g]) * (lhs @ rhs[g])``, each factor
+  over the rows, ``act(lhs @ gate[g]) * (lhs @ rhs[g])``, each factor
   rounded as a matmul of its own would round it: an expert layer is two
   kernels, not three (a third less to trace in every step program's
-  warm-up, and no ``[m, n]`` gate and up written and read back).
+  warm-up, and no ``[m, n]`` gate and up written and read back). ``act``
+  is the static argument ``activation`` (:data:`ACTIVATIONS`: ``silu``
+  for SwiGLU, ``relu`` for ReGLU), the family's to choose.
 - Rows past the last group are not visited and their output is whatever
   the buffer held: the caller replaces them (``expert_layer`` does).
 - The whole contraction is one block, so there is no accumulator: the
@@ -80,6 +82,10 @@ RING = 3
 ROW_TILES = (128, 64, 32, 16, 8)
 LANES = 128
 VMEM_LIMIT_CAP = 96 << 20  # of a v5e core's 128 MiB
+# The gate's function in a gated unit ``act(x Wgate) * (x Wup)``, by the
+# name a family passes (static: a kernel a name): on float32, in the
+# kernel's epilogue and in ``models/moe.py``'s other paths alike.
+ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 
 
 # How many devices the program being traced spans; set by the engine
@@ -316,26 +322,28 @@ def _await_block(fetch, rhs_hbm, rhs, sems, by_rank, first_group, with_rows,
         copy.wait()
 
 
-def _store_product(mine, lhs, rhs, slot, out, stacks: int):
+def _store_product(mine, lhs, rhs, slot, out, stacks: int, activation: str):
     """``out`` under the mask ``mine`` = the rows ``lhs`` times the
-    block(s) in ``slot``: the tile's other rows are another visit's (a
+    block(s) in ``slot`` (two stacks: the gated unit under
+    ``activation``): the tile's other rows are another visit's (a
     neighbouring group's) or nobody's."""
     rows = lhs[...]
     # Each product rounded to the operands' dtype, as a matmul of its own
     # would hand it on.
     product = jnp.dot(rows, rhs[slot, stacks - 1],
                       preferred_element_type=jnp.float32).astype(out.dtype)
-    if stacks == 2:  # silu(rows @ gate) * (rows @ up)
+    if stacks == 2:  # act(rows @ gate) * (rows @ up)
         gate = jnp.dot(rows, rhs[slot, 0], preferred_element_type=jnp.float32
                        ).astype(out.dtype).astype(jnp.float32)
-        product = (jax.nn.silu(gate).astype(out.dtype).astype(jnp.float32)
-                   * product.astype(jnp.float32)).astype(out.dtype)
+        product = (ACTIVATIONS[activation](gate).astype(out.dtype).astype(
+            jnp.float32) * product.astype(jnp.float32)).astype(out.dtype)
     out[...] = jnp.where(mine, product.astype(jnp.float32),
                          out[...].astype(jnp.float32)).astype(out.dtype)
 
 
 def _kernel(ends, groups, row_tiles, ranks, by_rank, visit_ends, first_group,
-            lhs, *refs, tm: int, tn: int, tiles_n: int, stacks: int):
+            lhs, *refs, tm: int, tn: int, tiles_n: int, stacks: int,
+            activation: str):
     del visit_ends  # the grid's
     rhs_hbm, (out, rhs, sems) = refs[:stacks], refs[stacks:]
     n_i, visit = pl.program_id(0), pl.program_id(1)
@@ -360,11 +368,11 @@ def _kernel(ends, groups, row_tiles, ranks, by_rank, visit_ends, first_group,
         jnp.int32, out.shape, 0)
     begin = jnp.where(group > 0, ends[jnp.maximum(group - 1, 0)], 0)
     mine = (row >= begin) & (row < ends[group])
-    _store_product(mine, lhs, rhs, slot, out, stacks)
+    _store_product(mine, lhs, rhs, slot, out, stacks, activation)
 
 
 def _one_tile_kernel(by_rank, count, first_group, lhs, group_of_row, *refs,
-                     tn: int, tiles_n: int, stacks: int):
+                     tn: int, tiles_n: int, stacks: int, activation: str):
     rhs_hbm, (out, rhs, sems) = refs[:stacks], refs[stacks:]
     n_i, rank = pl.program_id(0), pl.program_id(1)
     with_rows = count[0]
@@ -378,10 +386,12 @@ def _one_tile_kernel(by_rank, count, first_group, lhs, group_of_row, *refs,
         _await_block(fetch, rhs_hbm, rhs, sems, by_rank, first_group,
                      with_rows, tn=tn, tiles_n=tiles_n)
         mine = jnp.broadcast_to(group_of_row[...], out.shape) == by_rank[rank]
-        _store_product(mine, lhs, rhs, jax.lax.rem(fetch, RING), out, stacks)
+        _store_product(mine, lhs, rhs, jax.lax.rem(fetch, RING), out, stacks,
+                       activation)
 
 
-@functools.partial(jax.jit, static_argnames=("tiles", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("tiles", "interpret", "activation"))
 def pallas_grouped_matmul(
     lhs: jax.Array,  # [m, k], rows sorted by group (one tile: as they come)
     rhs: jax.Array,  # [all groups, k, n]: a whole stack
@@ -392,12 +402,16 @@ def pallas_grouped_matmul(
     tiles: Tuple[int, int, int],
     gate: Optional[jax.Array] = None,  # a second stack, shaped like ``rhs``
     interpret: bool = False,
+    activation: str = "silu",  # of the gate: a key of ACTIVATIONS
 ) -> jax.Array:
     """``[m, n]`` in ``lhs``'s dtype; rows past the last group (one tile:
     rows of no group) undefined. With ``gate`` the two matmuls of a gated
-    unit in one pass over the rows: ``silu(lhs @ gate[g]) * (lhs @
+    unit in one pass over the rows: ``activation(lhs @ gate[g]) * (lhs @
     rhs[g])``, each factor rounded to the dtype as the matmuls alone
     would round it."""
+    if activation not in ACTIVATIONS:
+        raise ValueError(f"gate activation {activation!r} is not one of "
+                         f"{sorted(ACTIVATIONS)}")
     m, k = lhs.shape
     n = rhs.shape[2]
     tm, tk, tn = tiles
@@ -411,7 +425,7 @@ def pallas_grouped_matmul(
             raise ValueError(f"{m} rows are not one row tile of {tm}")
         groups = visits.by_rank.shape[0]
         kernel = functools.partial(_one_tile_kernel, tn=tn, tiles_n=n // tn,
-                                   stacks=len(stacks))
+                                   stacks=len(stacks), activation=activation)
         prefetch = (visits.by_rank, visits.count)
         rows = (lhs, visits.group_of_row)
         steps = jnp.maximum(visits.count[0], 1)
@@ -427,7 +441,7 @@ def pallas_grouped_matmul(
     else:
         groups = visits.ranks.shape[0]
         kernel = functools.partial(_kernel, tm=tm, tn=tn, tiles_n=n // tn,
-                                   stacks=len(stacks))
+                                   stacks=len(stacks), activation=activation)
         prefetch, rows = tuple(visits), (lhs,)
         # An idle step (no row routed here) still runs one visit.
         steps = jnp.maximum(visits.visit_ends[-1], 1)
@@ -461,7 +475,9 @@ def pallas_grouped_matmul(
             vmem_limit_bytes=min(VMEM_LIMIT_CAP, vmem + (8 << 20))),
         cost_estimate=pl.CostEstimate(
             flops=2 * m * k * n * len(stacks),
-            transcendentals=m * n * (len(stacks) - 1),
+            # silu's exponential; relu has none
+            transcendentals=(m * n * (len(stacks) - 1)
+                             if activation == "silu" else 0),
             bytes_accessed=(len(stacks) * groups * k * n
                             + m * k * (n // tn) + m * n) * size),
         interpret=interpret,
@@ -473,12 +489,13 @@ def pallas_grouped_matmul(
 
 def grouped_matmul(lhs: jax.Array, stack: jax.Array, visits, at,
                    tiles: Tuple[int, int, int],
-                   gate: Optional[jax.Array] = None) -> jax.Array:
+                   gate: Optional[jax.Array] = None,
+                   activation: str = "silu") -> jax.Array:
     """The kernel on layer ``at`` of ``stack [layers, held, k, n]`` (and of
-    ``gate``, shaped like it) under ``visits`` (:class:`GroupVisits` or
-    :class:`OneTileVisits`), read as ``layers x held`` groups (a reshape
-    of leading dims: no copy). Off the TPU (a test that forced the path)
-    it runs interpreted."""
+    ``gate``, shaped like it, under ``activation``) under ``visits``
+    (:class:`GroupVisits` or :class:`OneTileVisits`), read as ``layers x
+    held`` groups (a reshape of leading dims: no copy). Off the TPU (a
+    test that forced the path) it runs interpreted."""
     layers, held = stack.shape[:2]
 
     def as_groups(w):
@@ -487,4 +504,4 @@ def grouped_matmul(lhs: jax.Array, stack: jax.Array, visits, at,
     return pallas_grouped_matmul(
         lhs, as_groups(stack), visits, jnp.asarray(at, jnp.int32) * held,
         tiles=tiles, gate=None if gate is None else as_groups(gate),
-        interpret=_platform() != "tpu")
+        interpret=_platform() != "tpu", activation=activation)

@@ -3,7 +3,8 @@ of models.laguna.apply at laguna-s-2.1-l8e64's, bf16: PR 36; of
 models.lfm2.apply at lfm2-24b-a2b-l10's: PR 40; of models.longcat.apply
 at longcat-flash-l4e16's: PR 44; of models.glm4_moe_lite.apply at
 glm-4.7-flash-e8v8's and LongCat's cached tails: PR 45; of
-models.ouro.apply at ouro-2.6b's, 48 layers x 4 passes: PR 48), for
+models.ouro.apply at ouro-2.6b's, 48 layers x 4 passes: PR 48; of
+models.smallthinker.apply at smallthinker-21b-a3b-l8's: PR 51), for
 a refactor that must not change the program (PR 31): run it on a copy of
 the parent and on the change and compare the digests; no chip needed.
 Each of the four later families also as the engine's decode burst nests
@@ -16,7 +17,7 @@ The bursts take each step's argmax; the sampling tail the engine runs
 there is hashed alone (``tail.decode_k8.<rows>x<vocab>``: PR 49).
 
     JAX_PLATFORMS=cpu python scripts/hlo_digest.py <repo-root> <out-dir> \
-        [--only mistral|laguna|lfm2|longcat|glm|ouro|tail]
+        [--only mistral|laguna|lfm2|longcat|glm|ouro|smallthinker|tail]
 
 Three modes (decode 32 x 1, plain prefill 1 x 512, cached prefill 1 x 256,
 the server's default 8 LoRA slots) x {bf16, int8 weights}, compiled by the
@@ -364,6 +365,30 @@ if ouro is not None:  # (PR 48) the reasoning cell's rows, its usual
     family_digests("ouro", "ouro-2.6b", ouro, ouro_pool,
                    (("decode", 8, 1, 32), ("prefill", 1, 512, 32),
                     ("prefill_cached", 1, 256, 32), (BURST, 8, 8, 32)))
+
+
+try:  # a tree before PR 51 has no such family
+    from production_stack_tpu.models import smallthinker
+except ImportError:
+    smallthinker = None
+
+
+def smallthinker_pool(c):
+    """Four kv heads of 128 a token and layer, 5,120 blocks of 64 tokens:
+    about what one chip holds beside the 7.93 GB of weights."""
+    pages = spec((c.num_layers, 5120, BS, c.num_kv_heads, c.head_dim),
+                 jnp.bfloat16)
+    return pages, pages
+
+
+if smallthinker is not None:  # (PR 51) the mixed-sessions cell's rows
+    # under the tables of a 4k and a 16k context, a whole plain chunk, a
+    # whole cached chunk under the widest table
+    family_digests("smallthinker", "smallthinker-21b-a3b-l8", smallthinker,
+                   smallthinker_pool,
+                   (("decode", 32, 1, 64), ("prefill", 1, 1024, 16),
+                    ("prefill_cached", 1, 1024, 256),
+                    ("decode", 32, 1, 256), (BURST, 32, 8, 256)))
 
 
 def tail_digests(shapes):

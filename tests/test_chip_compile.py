@@ -1097,3 +1097,100 @@ def test_lfm2_decode_burst_selects_once_and_reduces_once_a_step(
                 if re.search(r'op_name="[^"]*/scatter-add"', line)
                 and "65536" in line.split(" = ")[1].split(" ")[0]]
     assert len(scatters) == 1 and " s32[" in scatters[0], scatters
+
+
+@pytest.mark.parametrize("tables", [64, 256])
+@pytest.mark.parametrize("window", [None, 4096])
+def test_decode_kernel_compiles_at_seven_query_heads_a_kv_head(one_chip,
+                                                               window, tables):
+    """SmallThinker-21BA3B's decode rows (PR 51): 32 of them, 28 query
+    heads over 4 kv heads of 128 (a query group of 7), 64-token pages
+    whose four rows are a bf16 tile of their own (the layout LFM2's
+    packed rows have), under tables of 64 blocks and of the 256 that
+    ``--max-model-len 16384`` brings, with and without the 4,096-token
+    window's front bound."""
+    B, H, KVH, D = 32, 28, 4, 128
+
+    def spec(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pages = _pages(one_chip, KVH, D, False)
+    bound = {} if window is None else {"window": window}
+    text = _compile_with_kernel(
+        lambda q, k, v, bt, cl, layer: pallas_paged_attention(
+            q, k, v, bt, cl, layer, scale=D ** -0.5, **bound),
+        spec((B, H, D), jnp.bfloat16), pages, pages,
+        spec((B, tables)), spec((B,)), spec(()))
+    assert re.search(r"bf16\[4,256,64,4,128\]\{4,3,2,1,0:T\(4,128\)\(2,1\)\}",
+                     text)
+
+
+def _smallthinker_21b(tmp_path, monkeypatch, sharding, blocks=5120):
+    """(cfg, the weights' shapes, ``spec``, the pool's sides) of
+    ``smallthinker-21b-a3b-l8`` as the benchmark serves it, with the
+    kernels the chip takes; a pool of 5,120 blocks is the ~330k tokens
+    (5.4 GB) the chip holds beside the weights."""
+    from production_stack_tpu.models import smallthinker
+
+    cfg = _benchmark_config(tmp_path, "smallthinker-21b-a3b-l8")
+    assert (cfg.num_layers, cfg.num_heads, cfg.num_kv_heads,
+            cfg.num_experts, cfg.experts_per_token, cfg.sliding_window) == (
+        8, 28, 4, 64, 6, 4096)
+    monkeypatch.setattr(att, "_use_pallas", lambda: True)
+    monkeypatch.setattr(gmm, "_platform", lambda: "tpu")
+    att.TRACED_PATHS.clear()
+
+    def spec(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    params = jax.tree_util.tree_map(
+        lambda x: spec(x.shape, x.dtype),
+        jax.eval_shape(
+            lambda: smallthinker.init_params(cfg, jax.random.key(0))))
+    pages = spec((8, blocks, BLOCK_SIZE, 4, 128), jnp.bfloat16)
+    return cfg, params, spec, (pages, pages)
+
+
+@pytest.mark.parametrize("mode,rows,width,tables", [
+    ("decode", 32, 1, 64), ("decode", 32, 1, 256),
+    ("prefill", 1, 1024, 16), ("prefill_cached", 1, 1024, 256)])
+def test_smallthinker_programs_compile_at_the_configurations_widths(
+        one_chip, monkeypatch, tmp_path, mode, rows, width, tables):
+    """``smallthinker-21b-a3b-l8`` as the benchmark serves it (PR 51): the
+    tree is the 7.93 GB the configuration states; the three forward
+    programs compile for the v5e under tables up to the 256 blocks of
+    ``--max-model-len 16384``, with the attention kernel of their mode at
+    four kv heads of 128 (both kinds of layer: two calls, one with the
+    window) and the grouped-matmul kernel with its ``relu`` epilogue (no
+    ``ragged_dot``, no logistic left under ``moe_experts``); no expert
+    stack and no side of the pool is copied, and the temporaries stay far
+    under what the pool leaves free."""
+    from production_stack_tpu.models import smallthinker
+
+    cfg, params, spec, pages = _smallthinker_21b(tmp_path, monkeypatch,
+                                                 one_chip)
+    weights = sum(int(np.prod(x.shape)) * x.dtype.itemsize
+                  for x in jax.tree_util.tree_leaves(params))
+    assert abs(weights / 7.93e9 - 1) < 0.005
+    last = mode != "decode"
+    program = jax.jit(
+        lambda p, kv, tok, pos, slot, bt, cl, sl: smallthinker.apply(
+            p, cfg, tok, pos, kv, slot, bt, cl, sl, mode=mode,
+            last_token=jnp.maximum(sl - 1, 0) if last else None,
+            with_stats=True), donate_argnums=(1,)).lower(
+        params, pages, spec((rows, width)), spec((rows, width)),
+        spec((rows, width)), spec((rows, tables)), spec((rows,)),
+        spec((rows,))).compile()
+    text = program.as_text()
+    assert ("pallas_paged_attention" in text) == (mode == "decode")
+    assert ("pallas_prefill_attention" in text) == (mode == "prefill_cached")
+    # 32 rows x top 6 = 192 assignments: sorted by expert, not one tile
+    _traced_the_grouped_matmul_kernel(one_tile=False)
+    assert "pallas_grouped_matmul" in text and "ragged-dot" not in text
+    copied = [line.strip()[:120] for line in text.splitlines()
+              if re.search(r"= bf16\[(8,64,\d{3,4},\d{3,4}|512,\d{3,4},"
+                           r"\d{3,4}|8,5120,64,4,128)\]\S* copy(-start)?\(",
+                           line)]
+    assert not copied, copied
+    assert program.memory_analysis().temp_size_in_bytes < (
+        0.1e9 if mode == "decode" else 0.6e9)

@@ -47,13 +47,26 @@ CASES = {
 }
 
 
-@pytest.mark.parametrize("gated", [False, True], ids=["plain", "gated"])
+# The gate of a gated unit: none (one matmul), the kernel's default
+# ``silu``, and ``relu`` (ReGLU: models/smallthinker.py, PR 51).
+GATED, GATED_IDS = [None, "silu", "relu"], ["plain", "gated", "relu"]
+ACT = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
+def _activation(gated):
+    """The kernel's keyword: left out for ``silu``, which is its default
+    (the accepted families' programs name none)."""
+    return {"activation": gated} if gated == "relu" else {}
+
+
+@pytest.mark.parametrize("gated", GATED, ids=GATED_IDS)
 @pytest.mark.parametrize("dtype", [BF16, F32], ids=["bf16", "f32"])
 @pytest.mark.parametrize("case", CASES)
 def test_kernel_matches_ragged_dot(case, dtype, gated):
     """The kernel against ``ragged_dot`` on the live rows; ``gated``: the
-    two matmuls of a gated unit in one pass, against ``silu`` of one
-    ``ragged_dot`` times another."""
+    two matmuls of a gated unit in one pass under that activation,
+    against ``silu`` (or ``relu``: PR 51) of one ``ragged_dot`` times
+    another."""
     m, k, n, sizes, layers, at, tiles = CASES[case]
     held = len(sizes)
     keys = jax.random.split(jax.random.key(len(case)), 3)
@@ -67,7 +80,8 @@ def test_kernel_matches_ragged_dot(case, dtype, gated):
     got = gmm.pallas_grouped_matmul(
         lhs, stack.reshape(layers * held, k, n), visits,
         jnp.int32(at * held), tiles=tiles, interpret=True,
-        gate=gate.reshape(layers * held, k, n) if gated else None)
+        gate=gate.reshape(layers * held, k, n) if gated else None,
+        **_activation(gated))
 
     def ragged(w):
         return jax.lax.ragged_dot(lhs, w[at], sizes,
@@ -75,7 +89,7 @@ def test_kernel_matches_ragged_dot(case, dtype, gated):
 
     want = ragged(stack)
     if gated:
-        want = jax.nn.silu(ragged(gate).astype(F32)).astype(dtype) * want
+        want = ACT[gated](ragged(gate).astype(F32)).astype(dtype) * want
     assert got.shape == (m, n) and got.dtype == dtype
     # Float32 accumulation on both sides: the same values but for the
     # order of the sums.
@@ -145,11 +159,12 @@ def _one_tile_case(case, dtype, gated):
     group = jnp.asarray(ONE_TILE_ROWS[case], jnp.int32)
     sizes = gmm.group_sizes(group, held)
     kwargs = dict(first_group=jnp.int32(at * held), tiles=(m, k, 128),
-                  interpret=True, gate=gate if gated else None)
+                  interpret=True, gate=gate if gated else None,
+                  **_activation(gated))
     return lhs, stack, group, sizes, kwargs
 
 
-@pytest.mark.parametrize("gated", [False, True], ids=["plain", "gated"])
+@pytest.mark.parametrize("gated", GATED, ids=GATED_IDS)
 @pytest.mark.parametrize("dtype", [BF16, F32], ids=["bf16", "f32"])
 @pytest.mark.parametrize("case", ONE_TILE_ROWS)
 def test_one_tile_in_the_tokens_order_is_the_sorted_path_bit_for_bit(
@@ -179,14 +194,14 @@ def test_one_tile_in_the_tokens_order_is_the_sorted_path_bit_for_bit(
 
     want = ragged(stack)
     if gated:
-        want = jax.nn.silu(ragged(kwargs["gate"]).astype(F32)
-                           ).astype(dtype) * want
+        want = ACT[gated](ragged(kwargs["gate"]).astype(F32)
+                          ).astype(dtype) * want
     np.testing.assert_allclose(
         np.asarray(got, np.float32)[live], np.asarray(want, np.float32)[live],
         rtol=2e-2 if dtype == BF16 else 1e-5, atol=1e-5)
 
 
-@pytest.mark.parametrize("gated", [False, True], ids=["plain", "gated"])
+@pytest.mark.parametrize("gated", GATED, ids=GATED_IDS)
 @pytest.mark.parametrize("case", ONE_TILE_ROWS)
 def test_one_tile_copies_the_groups_that_have_rows_and_no_other(
         monkeypatch, case, gated):
@@ -225,7 +240,7 @@ def test_one_tile_copies_the_groups_that_have_rows_and_no_other(
         jax.effects_barrier()
         return len(started), out
 
-    tiles_n, stacks = stack.shape[2] // 128, 1 + gated
+    tiles_n, stacks = stack.shape[2] // 128, 1 + bool(gated)
     count, out = copies(gmm.one_tile_visits(sizes, group), lhs)
     assert count == tiles_n * stacks * hit
     live = np.asarray(group) < held
@@ -236,11 +251,17 @@ def test_one_tile_copies_the_groups_that_have_rows_and_no_other(
     assert count == tiles_n * stacks * max(hit, 1)
 
 
+@pytest.mark.parametrize("activation", ["silu", "relu"])
 @pytest.mark.parametrize("share,tokens", [(0, 16), (1, 16), (0, 3), (0, 24),
                                           (1, 24)])
-def test_expert_layer_is_the_same_on_either_path(monkeypatch, share, tokens):
+def test_expert_layer_is_the_same_on_either_path(monkeypatch, share, tokens,
+                                                 activation):
     """``expert_layer`` with the kernel forced (interpreted here) and with
-    ``ragged_dot``: the same ``y`` and the same ``STATS``, for a chip that
+    ``ragged_dot``, under either gate activation (``relu``: PR 51; all
+    three paths, ``pallas``, ``pallas_one_tile`` and ``xla``, against the
+    experts applied densely in ``jax.numpy``, with the routing handed in
+    by the caller as models/smallthinker.py hands it): the same ``y`` and
+    the same ``STATS``, for a chip that
     holds every expert and for one that holds a block of them, with
     padding rows; 16 tokens' 32 row slots are one row tile and stay in
     the tokens' order, 24 tokens' 48 are three tiles of 16 and are
@@ -265,7 +286,8 @@ def test_expert_layer_is_the_same_on_either_path(monkeypatch, share, tokens):
         gmm.TRACED_PATHS.clear()
         y, stats = jax.jit(lambda h: moe.expert_layer(
             h, p, k=top, at=jnp.int32(1), share=share, scaling=2.5,
-            valid=valid))(h)
+            valid=valid, **({} if activation == "silu" else
+                            {"activation": activation})))(h)
         return y, stats, dict(gmm.TRACED_PATHS)
 
     y_xla, stats_xla, traced = run(False)
@@ -279,6 +301,32 @@ def test_expert_layer_is_the_same_on_either_path(monkeypatch, share, tokens):
                                np.asarray(y_xla, np.float32),
                                rtol=2e-2, atol=2e-2)
     assert not np.asarray(jnp.isnan(y.astype(F32))).any()
+    # against jnp: every held expert applied densely to every token under
+    # the routing, handed to ``expert_layer`` by its caller this time
+    x = h.reshape(tokens, hidden)
+    weights, experts = moe.route(x, p["router"], top, scaling=2.5)
+    handed, handed_stats = jax.jit(lambda h: moe.expert_layer(
+        h, {k: v for k, v in p.items() if k != "router"}, k=top,
+        at=jnp.int32(1), share=share, valid=valid, activation=activation,
+        routed=(weights, experts)))(h)
+    assert np.array_equal(np.asarray(handed, np.float32),
+                          np.asarray(y, np.float32))
+    assert np.asarray(handed_stats).tolist() == np.asarray(stats).tolist()
+    dense = jnp.zeros((tokens, hidden), F32)
+    for e in range(held):
+        unit = (ACT[activation]((x @ p["w_gate"][1, e]).astype(F32)
+                                ).astype(BF16) * (x @ p["w_up"][1, e])
+                ) @ p["w_down"][1, e]
+        w = jnp.sum(jnp.where(experts == share * held + e, weights, 0.0), -1)
+        dense = dense + (w * valid[0])[:, None] * unit.astype(F32)
+    np.testing.assert_allclose(np.asarray(y, np.float32)[0],
+                               np.asarray(dense), rtol=3e-2, atol=3e-2)
+    if activation == "relu":  # and it is not the other function
+        other, _ = jax.jit(lambda h: moe.expert_layer(
+            h, p, k=top, at=jnp.int32(1), share=share, scaling=2.5,
+            valid=valid))(h)
+        assert np.abs(np.asarray(other, np.float32)
+                      - np.asarray(y, np.float32)).max() > 0.1
 
 
 # (hidden, expert width, held experts, top k) and the tokens of the step

@@ -27,7 +27,8 @@ ARCHS = sorted(ARCH_MODULES)
 PRESET = {"llama": "tiny-llama", "opt": "tiny-opt", "mixtral": "tiny-mixtral",
           "laguna": "tiny-laguna", "lfm2": "tiny-lfm2",
           "longcat": "tiny-longcat",
-          "glm4_moe_lite": "tiny-glm4-moe-lite", "ouro": "tiny-ouro"}
+          "glm4_moe_lite": "tiny-glm4-moe-lite", "ouro": "tiny-ouro",
+          "smallthinker": "tiny-smallthinker"}
 # What a family's config.json must hold beside the sizes every family
 # reads (``Family.per_layer_keys``: lists, one entry a layer or more).
 REQUIRED_KEYS = {"laguna": {
@@ -55,7 +56,12 @@ REQUIRED_KEYS = {"laguna": {
     "routed_scaling_factor": 1.8, "num_nextn_predict_layers": 1},
     "ouro": {
     "intermediate_size": 48, "num_key_value_heads": 4, "total_ut_steps": 2,
-    "early_exit_threshold": 1}}
+    "early_exit_threshold": 1},
+    "smallthinker": {
+    "head_dim": 8, "num_key_value_heads": 2, "moe_ffn_hidden_size": 16,
+    "moe_num_primary_experts": 4, "moe_num_active_primary_experts": 2,
+    "rope_layout": [0, 1], "sliding_window_layout": [0, 1],
+    "sliding_window_size": 8}}
 SIZES = dict(vocab_size=64, hidden_size=32, num_hidden_layers=2,
              num_attention_heads=4, max_position_embeddings=64)
 
@@ -65,7 +71,8 @@ def _hf_model(arch):
     family brings its line)."""
     import transformers as tf
 
-    if arch in ("laguna", "lfm2", "longcat", "glm4_moe_lite", "ouro"):
+    if arch in ("laguna", "lfm2", "longcat", "glm4_moe_lite", "ouro",
+                "smallthinker"):
         return None  # no class of it here (or no loader yet), no checkpoint
     return {
         "llama": lambda: tf.LlamaForCausalLM(tf.LlamaConfig(

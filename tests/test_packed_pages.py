@@ -52,13 +52,17 @@ def test_the_layout_is_a_view_of_the_same_values():
     ((8, 128), 2),   # eight of 128, the pool sharded two ways
     ((16, 128), 4),
 ], ids=["4x128", "8x128-tp2", "16x128-tp4"])
-def test_four_rows_take_the_kernels_only_where_the_pool_packed_them(
+def test_four_rows_take_the_kernels_only_where_the_pool_holds_four(
         monkeypatch, page, kv_shards):
-    """The gate admits four rows for the rows ``packed_page_dims`` made,
-    and for nothing else: every other pool that leaves four heads to a
-    chip takes the reference, as it did before the packed layout."""
+    """The gate admits four rows for the rows ``packed_page_dims`` made
+    and for a model's own four kv heads of 128 (PR 51: the same ``[bs, 4,
+    128]`` pages), and for nothing else: a shard that is left four heads
+    of a wider pool takes the reference, as it did before the packed
+    layout, and so do four rows of int8."""
     monkeypatch.setattr(att, "_use_pallas", lambda: True)
-    assert att.attention_path(64, *page, False, kv_shards) == "xla"
+    assert att.attention_path(64, *page, False, kv_shards) == (
+        "pallas" if kv_shards == 1 else "xla")
+    assert att.attention_path(64, *page, True, kv_shards) == "xla"
     assert not att._page_tile_ok(64, page[0] // kv_shards, 128)
     assert att._page_tile_ok(64, 4, 128, packed=True)
     assert att.attention_path(64, 4, 128, False, packed=True) == "pallas"
