@@ -613,8 +613,8 @@ class EngineCore:
         self._prefill_fn = self._make_forward("prefill")
         self._prefill_cached_fn = self._make_forward("prefill_cached")
         self._set_counts_row_fn = self._make_set_counts_row()
-        # Decode always runs through the fused burst program (K ==
-        # decode_steps; K=1 degenerates to single-step).
+        self._init_first_token_feed()
+        # Decode always runs the fused burst program (K == decode_steps).
         self._multi_decode_fns: Dict[int, Callable] = {}
         # Speculative verify program (prompt-lookup decoding): one jit fn
         # for the configured verify width; XLA lowers one variant per
@@ -1374,6 +1374,41 @@ class EngineCore:
 
         return set_row
 
+    def _init_first_token_feed(self) -> None:
+        """The state of the hand-over from a prefill to the burst behind
+        it (``_exec_op``, ``_do_decode``). Set up from ``__init__`` in one
+        line, and defined here, behind the step programs: a line added
+        above them renumbers every operation they trace, and the compile
+        cache keys a Mosaic kernel by its body's source lines."""
+        # Jitted hand-over of a prefill's sampled first tokens to the next
+        # decode burst, on the device: ``sampled[r]`` fills the whole row
+        # ``slots[r]`` of the [B, K] feedback array a burst reads its
+        # input tokens from (any ``tok_idx`` finds it), and a row of the
+        # prefill that takes no decode slot carries ``B``, past the last
+        # row, and is dropped. Not donated: the burst whose tokens the
+        # array holds may not have been read back yet.
+
+        @functools.partial(jax.jit, out_shardings=self._repl)
+        def feed_first_tokens(tokens_prev, sampled, slots):
+            return tokens_prev.at[slots].set(
+                sampled[:, None].astype(tokens_prev.dtype), mode="drop")
+
+        self._feed_first_tokens_fn = feed_first_tokens
+        # (slot, seq) of every row whose prefill wrote its first token
+        # into row ``slot`` of the feedback array (_last_burst_tokens)
+        # since the last burst was built: the next burst takes those
+        # tokens on the device (_do_decode).
+        self._first_on_device: "list[tuple]" = []
+        # Prefilled rows by where their first decode burst took their
+        # first token from: "device" (the prefill's sample, scattered
+        # into the burst's feedback array behind the prefill program, so
+        # the burst was built and enqueued while the prefill still ran)
+        # or "host" (read back first: _feeds_first_token says which rows,
+        # _do_decode which bursts; a row that left before any burst took
+        # it counts here too). Exported as
+        # tpu:first_token_feed_total{path=...}.
+        self.first_token_feed_total = {"device": 0, "host": 0}
+
     def _make_write_blocks(self):
         """Jitted BATCHED page write: all transferred blocks land in one
         dispatch (k/v are [L, N, bs, KVH, D], bids [N]) — the disagg
@@ -1498,6 +1533,18 @@ class EngineCore:
                 self._count_latent_prefill_form(arrays[0].shape[1],
                                                 arrays[3].shape[1])
             out, self.kv = fn(self.params, self.kv, *arrays)
+            feed = static.get("feed")
+            if feed is not None:
+                # The rows' first tokens, into the feedback array the next
+                # burst reads (``feed``: a decode slot a row of the
+                # sample). Behind the prefill on the device's queue, and
+                # so behind any burst still in flight, whose output that
+                # array is: a slot's former owner, which such a burst may
+                # still cover, is overwritten and not the other way round.
+                prev = self._last_burst_tokens
+                self._last_burst_tokens = self._feed_first_tokens_fn(
+                    self._no_burst_tokens if prev is None else prev,
+                    out[0], np.asarray(feed, np.int32))
             return out
         if name == "decode":
             K = static["K"]
@@ -2127,6 +2174,7 @@ class EngineCore:
             top = cfg.bucket_for(cfg.max_prefill_span)
             cached_buckets = set(cfg.prefill_buckets())
             n_prefill = 0
+            firsts = {}  # a prefill's sampled tokens, by its row count
 
             def operands(rows: int, bucket: int, table: int) -> tuple:
                 """Dummy host operands of a prefill program at [rows,
@@ -2161,9 +2209,10 @@ class EngineCore:
                 # same-rung prompts waiting: no warm prompt does).
                 tight = self._table_width(bucket)
                 for rows in sorted({1, cfg.prefill_group_rows(bucket)} - {0}):
-                    _, self.kv = self._prefill_fn(
+                    out, self.kv = self._prefill_fn(
                         self.params, self.kv,
                         *operands(rows, bucket, tight))
+                    firsts[rows] = out[0]
                     n_prefill += 1
                 if bucket not in cached_buckets:
                     continue
@@ -2188,13 +2237,21 @@ class EngineCore:
                 maxb_cap = self._prefill_batch_maxb()
                 while True:
                     maxb_b = min(maxb_b, maxb_cap)
-                    _, self.kv = self._prefill_cached_fn(
+                    out, self.kv = self._prefill_cached_fn(
                         self.params, self.kv,
                         *operands(cfg.prefill_batch, pb_bucket, maxb_b))
+                    firsts[cfg.prefill_batch] = out[0]
                     n_prefill += 1
                     if maxb_b >= maxb_cap:
                         break
                     maxb_b *= 2
+            # The hand-over of a prefill's first tokens to the next burst
+            # (_exec_op): one tiny program a row count of the prefill
+            # programs, here with every row dropped.
+            for sampled in firsts.values():
+                self._feed_first_tokens_fn(
+                    self._no_burst_tokens, sampled,
+                    np.full(sampled.shape, cfg.max_num_seqs, np.int32))
 
             # Compile-phase boundary: the prefill warmups above staged
             # host-side dummy operands and XLA left per-compile host
@@ -2718,6 +2775,7 @@ class EngineCore:
                 dict(self.latent_decode_dispatch_total),
             "latent_prefill_form_total":
                 dict(self.latent_prefill_form_total),
+            "first_token_feed_total": dict(self.first_token_feed_total),
             "dispatch_count_total": phases["enqueue"]["count"],
             "dispatch_enqueue_s": round(phases["enqueue"]["seconds"], 3),
             "decode_forward_steps_total": self.decode_forward_steps_total,
@@ -2931,6 +2989,74 @@ class EngineCore:
             block_ids, cached, _ = alloc
         return block_ids, cached
 
+    def _feeds_first_token(self, req: EngineRequest) -> bool:
+        """Whether the row's first decode burst can take its first token
+        on the device, unseen by the host (``_exec_op`` scatters it into
+        the burst's feedback array behind the prefill, and ``_do_decode``
+        builds the burst while the prefill still runs). Told by what the
+        request carries: not where the token's VALUE is needed before the
+        burst is built (drafts under speculation, a grammar's mask, the
+        penalty counts of a resumed row, which are rebuilt from its
+        prior outputs and this token), not where the token ends the
+        request whatever it is (no decode row is ever built for it), and
+        not inside a captured fused pair, whose final-chunk row sits its
+        burst out."""
+        s = req.sampling
+        return not (
+            self._fused_capture is not None
+            or self.config.speculative_num_tokens > 0
+            or (req.structured is not None and req.structured.masking)
+            or (req.output_token_ids
+                and (s.presence_penalty or s.frequency_penalty))
+            or s.max_tokens - len(req.output_token_ids) <= 1
+            or len(req.all_token_ids) + 1 >= self.config.max_model_len)
+
+    def _first_token_slots(self, reqs) -> "tuple[list, list]":
+        """The decode slot each of ``reqs`` takes once its (final) prefill
+        is dispatched, chosen BEFORE the dispatch, because the prefill op
+        writes each first token into its slot's row of the feedback
+        array: ``(slots, feed)``, ``feed[i]`` the slot again, or None
+        where the row keeps the host path. Only this thread ever fills a
+        slot (the scheduler guaranteed these), so a slot chosen here is
+        still free when ``_start_prefilled`` takes it."""
+        with self._lock:
+            free = [i for i, s in enumerate(self.scheduler.slots)
+                    if s is None]
+        slots = free[:len(reqs)]
+        return slots, [slot if self._feeds_first_token(r) else None
+                       for r, slot in zip(reqs, slots)]
+
+    def _feed_static(self, cached: bool, feed, rows: int) -> dict:
+        """A prefill op's static part: ``feed`` (a slot or None a row of
+        ``_first_token_slots``) as the op takes it, one slot for each of
+        the program's ``rows``, past the last slot where the row feeds
+        none; left out where no row does."""
+        static = {"cached": cached}
+        if any(slot is not None for slot in feed):
+            drop = self.config.max_num_seqs
+            static["feed"] = tuple(
+                drop if slot is None else slot for slot in feed
+            ) + (drop,) * (rows - len(feed))
+        return static
+
+    def _start_prefilled(self, req: EngineRequest, slot: int, fed: bool,
+                         sampled, row: int = 0) -> None:
+        """The request's last prefill is dispatched: it takes its decode
+        slot, and its first token's readback is deferred
+        (``_flush_pending_prefills``). ``fed``: the op also wrote the
+        token into the feedback array, and the next burst's build says
+        whether it took it there; a row that was not fed counts as
+        ``host`` now."""
+        with self._lock:
+            seq = self.scheduler.start_running(req, slot)
+        if fed:
+            self._first_on_device.append((slot, seq))
+        else:
+            self.first_token_feed_total["host"] += 1
+        self._pending_prefills.append(
+            {"req": req, "seq": seq, "slot": slot, "sampled": sampled,
+             "row": row, "fed": fed, "in_burst": False})
+
     def _do_prefill(self, req: EngineRequest) -> None:
         """Block accounting is host-only, so the prompt's chunk forwards are
         dispatched BEFORE the in-flight decode burst is read back: XLA
@@ -2938,7 +3064,11 @@ class EngineCore:
         host readback then overlaps the chunks' device execution. (A page
         freed by a finished sequence may still receive the burst's
         speculative write, but the burst was dispatched first, so the
-        prefill's own writes land after it — device order.)"""
+        prefill's own writes land after it — device order.) The row's
+        decode slot is chosen before the dispatch, which hands the first
+        token to the next burst on the device (``_feeds_first_token``);
+        the host reads it when the next step has dispatched, be that a
+        prefill or the burst."""
         cfg = self.config
         tokens = req.all_token_ids
         n = len(tokens)
@@ -2972,12 +3102,14 @@ class EngineCore:
         # O(chunk * context) instead of O(len^2) — the engine-level
         # long-context path (single chip; ring attention covers multi-chip).
         chunk = cfg.prefill_chunk_size or (n - cached)
+        (slot,), feed = self._first_token_slots([req])
         sampled = None
         start = cached
         while start < n:
             end = min(start + chunk, n)
             sampled = self._prefill_span(
-                req, tokens, block_ids, start, end)
+                req, tokens, block_ids, start, end,
+                feed=feed if end == n else ())
             start = end
         n_chunks = max(1, -(-(n - cached) // max(chunk, 1)))
         self._step_info = {
@@ -3000,15 +3132,9 @@ class EngineCore:
         self._flush_pending_prefills()
         self.prompt_tokens_total += n
         self.cached_tokens_total += cached
-        # Reserve the slot now (next_action guaranteed a free one);
-        # the sampled-token readback is deferred as above. Deferred seqs
-        # are settled before any decode burst is built (they carry no
-        # output token until then).
-        with self._lock:
-            slot = self.scheduler._free_slot()
-            seq = self.scheduler.start_running(req, slot)
-        self._pending_prefills.append(
-            {"req": req, "seq": seq, "slot": slot, "sampled": sampled})
+        # The slot chosen above (next_action guaranteed a free one); the
+        # sampled-token readback is deferred as above.
+        self._start_prefilled(req, slot, feed[0] is not None, sampled)
 
     def _do_prefill_step(self, plan) -> None:
         """Execute one budgeted chunked-prefill step plan: advance each
@@ -3016,8 +3142,9 @@ class EngineCore:
         one batched [PB, chunk] dispatch when the batched-prefill program
         covers them (consecutive chunks of ONE prompt never share a
         dispatch — chunk N+1's queries attend to chunk N's pages).
-        Final chunks claim a decode slot and defer their first-token
-        readback exactly like the unchunked path (_pending_prefills)."""
+        Final chunks take a decode slot, chosen before the dispatch,
+        and defer their first-token readback exactly like the unchunked
+        path (_start_prefilled)."""
         cfg = self.config
         ready = []  # (req, tokens, block_ids, start, end)
         step_tokens = 0
@@ -3075,6 +3202,12 @@ class EngineCore:
         # Dispatch: one batched [PB, chunk-bucket] program when compiled
         # and every row fits its block-table cap, else sequential spans.
         sampled_for: "dict[int, tuple]" = {}  # id(req) -> (sampled, row)
+        finals = [req for req, tokens, _b, _s, end in ready
+                  if end >= len(tokens)]
+        slots, fed_slots = self._first_token_slots(finals)
+        slot_of = {id(req): (slot, fed)
+                   for req, slot, fed in zip(finals, slots, fed_slots)}
+        feed = [slot_of.get(id(req), (None, None))[1] for req, *_ in ready]
         batched = (
             cfg.prefill_batch > 1 and cfg.prefill_chunk_size > 0
             and len(ready) > 1
@@ -3082,13 +3215,15 @@ class EngineCore:
                     <= self._prefill_batch_maxb()
                     for (_, _, _, _, end) in ready))
         if batched:
-            sampled = self._prefill_rows(ready, pad_to=cfg.prefill_batch)
+            sampled = self._prefill_rows(ready, pad_to=cfg.prefill_batch,
+                                         feed=feed)
             for row_i, (req, *_rest) in enumerate(ready):
                 sampled_for[id(req)] = (sampled, row_i)
         else:
-            for req, tokens, block_ids, start, end in ready:
+            for (req, tokens, block_ids, start, end), slot in zip(ready,
+                                                                  feed):
                 sampled_for[id(req)] = (self._prefill_span(
-                    req, tokens, block_ids, start, end), 0)
+                    req, tokens, block_ids, start, end, feed=[slot]), 0)
         self.prefill_chunks_total += len(ready)
         self.last_step_batched_tokens = step_tokens
         path = self._paged_attn_path()
@@ -3125,22 +3260,20 @@ class EngineCore:
                         req.num_computed_tokens = end
                 continue
             # Final chunk: the sampled token of this dispatch is the
-            # request's first generated token. Claim the decode slot now
-            # (admission guaranteed one stays free per mid-prefill seq).
+            # request's first generated token. It takes the decode slot
+            # chosen for it before the dispatch (admission guaranteed
+            # one stays free per mid-prefill seq).
             sampled, row = sampled_for[id(req)]
             with self._lock:
                 if req not in self.scheduler.prefilling:
                     continue  # aborted while the chunk was in flight
                 self.scheduler.prefilling.remove(req)
                 req.num_computed_tokens = n
-                slot = self.scheduler._free_slot()
-                seq = self.scheduler.start_running(req, slot)
             if req.trace is not None:
                 req.trace.prefill_end = now
             self.prompt_tokens_total += n
-            self._pending_prefills.append(
-                {"req": req, "seq": seq, "slot": slot,
-                 "sampled": sampled, "row": row})
+            slot, fed = slot_of[id(req)]
+            self._start_prefilled(req, slot, fed is not None, sampled, row)
 
     def _count_expert_matmul_path(self, tokens: int) -> None:
         """One step program of ``tokens`` tokens a forward was dispatched:
@@ -3287,8 +3420,12 @@ class EngineCore:
 
     def _flush_pending_prefills(self) -> None:
         """Read back and emit deferred prefill first tokens, in dispatch
-        order. Must run before a decode burst is built (the burst's
-        feedback/position bookkeeping needs each seq's first token)."""
+        order: after the next step's dispatch, so that the readback
+        overlaps it on the device. The next step may be the burst that
+        takes these tokens on the device (``_do_decode`` marks such an
+        entry ``in_burst`` and has done the row's bookkeeping as if the
+        token were emitted); any other entry must be settled here before
+        a burst is built, which needs the token's value for it."""
         if not self._pending_prefills:
             return
         pending, self._pending_prefills = self._pending_prefills, []
@@ -3308,7 +3445,7 @@ class EngineCore:
                     keep.append(entry)
                     continue
                 req, seq, slot = entry["req"], entry["seq"], entry["slot"]
-                row_i = entry.get("row", 0)  # batched prefills: row per req
+                row_i = entry["row"]  # batched prefills: row per req
                 try:
                     if sampled is not read_of:  # a group's: read once
                         with steps.phase("readback"):
@@ -3340,8 +3477,14 @@ class EngineCore:
                                    float(top_lp_arr[row_i, j]))
                                   for j in range(k)]}
                 prior = req.output_token_ids
-                if prior and (req.sampling.presence_penalty
-                              or req.sampling.frequency_penalty):
+                in_burst = entry["in_burst"]
+                if in_burst:
+                    # The burst in flight took this token on the device:
+                    # it reset the slot's counts and counted the token,
+                    # and it is scheduled from the position behind it.
+                    pass
+                elif prior and (req.sampling.presence_penalty
+                                or req.sampling.frequency_penalty):
                     # Resume after preemption with penalties active: rebuild
                     # the slot's count row from the carried-forward outputs
                     # instead of resetting it (the row may hold another
@@ -3367,7 +3510,8 @@ class EngineCore:
                 rows += 1
                 # Decode position bookkeeping starts from the emitted tokens
                 # (a re-prefill after preemption carries prior outputs).
-                req.scheduled_steps = len(req.output_token_ids)
+                if not in_burst:
+                    req.scheduled_steps = len(req.output_token_ids)
         # ``emit_tokens`` follows ``generation_tokens_total``, which
         # counts a burst's tokens and not a prefill's first.
         steps.note_sum(
@@ -3498,11 +3642,12 @@ class EngineCore:
                     tr.prefill_start = now
                 tr.cached_tokens = 0
                 tr.preemptions = m["req"].num_preemptions
+        slots, feed = self._first_token_slots([m["req"] for m in group])
         try:
             sampled = self._prefill_rows(
                 [(m["req"], m["req"].all_token_ids, m["block_ids"], 0,
                   len(m["req"].all_token_ids)) for m in group],
-                plain_rung=rung)
+                plain_rung=rung, feed=feed)
         except Exception:
             # The loop fails the head; its mates fail with it.
             for m in group[1:]:
@@ -3527,12 +3672,8 @@ class EngineCore:
             req_m = m["req"]
             if row and req_m.trace is not None:
                 req_m.trace.prefill_end = now
-            with self._lock:
-                slot = self.scheduler._free_slot()
-                seq = self.scheduler.start_running(req_m, slot)
-            self._pending_prefills.append(
-                {"req": req_m, "seq": seq, "slot": slot,
-                 "sampled": sampled, "row": row})
+            self._start_prefilled(req_m, slots[row], feed[row] is not None,
+                                  sampled, row)
 
     def _note_attn_pairs(self, start: int, end: int) -> None:
         """A prefill span ``[start, end)`` of a prompt is dispatched: its
@@ -3542,7 +3683,8 @@ class EngineCore:
         self._steps.note_sum(
             attn_pairs=(end * (end + 1) - start * (start + 1)) // 2)
 
-    def _prefill_rows(self, rows, pad_to: int = 0, plain_rung: int = 0):
+    def _prefill_rows(self, rows, pad_to: int = 0, plain_rung: int = 0,
+                      feed=()):
         """One batched prefill dispatch: rows = [(req, tokens, block_ids,
         start, end), ...]. With ``plain_rung`` the rows are whole uncached
         prompts of that rung and run the plain program at [len(rows),
@@ -3550,7 +3692,9 @@ class EngineCore:
         to ``pad_to`` rows (padding rows have seq_lens 0 and dropped page
         writes), and run the cached-prefill program at the CHUNK bucket —
         one compiled variant per block-table width regardless of the
-        step's composition. Returns the sampled tuple (a row each)."""
+        step's composition. ``feed``: the decode slot a row whose first
+        token the op hands to the next burst (_first_token_slots).
+        Returns the sampled tuple (a row each)."""
         cfg = self.config
         for row in rows:
             self._note_attn_pairs(*row[3:5])
@@ -3617,7 +3761,8 @@ class EngineCore:
         if not plain_rung:
             self.prefill_attention_dispatch_total[
                 self._paged_attn_path()] += 1
-        return self._dispatch("prefill", {"cached": not plain_rung}, [
+        return self._dispatch("prefill", self._feed_static(
+                not plain_rung, feed, R), [
             token_arr, positions, slot_mapping,
             block_table, context_lens, seq_lens, adapter_ids,
             temp, topk, topp, seeds, steps,
@@ -3626,12 +3771,14 @@ class EngineCore:
         ])
 
     def _prefill_span(self, req: EngineRequest, tokens, block_ids,
-                      start: int, end: int):
+                      start: int, end: int, feed=()):
         """Dispatch one prefill chunk (tokens[start:end]) and return its
         on-device sampled next token (only the LAST chunk's sample is read
-        back). Spans after the first attend to earlier tokens through the
-        pages (prefill_cached); the span's own K/V is written first, so
-        attention over the block table sees the full prefix."""
+        back, and handed to the next burst: ``feed``, its decode slot as
+        _first_token_slots gives it). Spans after the first attend to
+        earlier tokens through the pages (prefill_cached); the span's own
+        K/V is written first, so attention over the block table sees the
+        full prefix."""
         cfg = self.config
         take = end - start
         self._note_attn_pairs(start, end)
@@ -3676,7 +3823,8 @@ class EngineCore:
         if start > 0:
             self.prefill_attention_dispatch_total[
                 self._paged_attn_path()] += 1
-        return self._dispatch("prefill", {"cached": start > 0}, [
+        return self._dispatch("prefill", self._feed_static(
+                start > 0, feed, 1), [
             token_arr, positions, slot_mapping,
             block_table, context_lens, seq_lens, adapter_ids,
             np.asarray([t], np.float32), np.asarray([k_], np.int32),
@@ -3696,11 +3844,24 @@ class EngineCore:
         their extra tokens are discarded at emission and their stray page
         writes are overwritten before ever becoming readable (pages freed by
         the finish are re-written by any later owner before its attention
-        can read them — device dispatch order guarantees it)."""
+        can read them — device dispatch order guarantees it).
+
+        The hand-over from a prefill is pipelined the same way: a row's
+        first token reaches its first burst on the device (the prefill op
+        wrote it into the feedback array, ``_first_on_device``), so the
+        burst is built and enqueued while the prefill program still runs,
+        with the row's positions, seeds and penalty counts as if the
+        token had been emitted, and the token is read back and emitted
+        after this dispatch, before any token of the burst. A first token
+        that ends its request has cost one burst's speculative cover, as
+        above. A burst that needs a token's value first (a structured
+        row's mask, drafts under speculation) and a row that does
+        (``_feeds_first_token``) settle the prefills before the build,
+        as every burst did before."""
         cfg = self.config
-        # Deferred prefill first-tokens must land before the burst is
-        # built (feedback tokens / positions depend on them).
-        self._flush_pending_prefills()
+        if any(not e["fed"] for e in self._pending_prefills):
+            # A first token whose value this build needs on the host.
+            self._flush_pending_prefills()
         if cfg.speculative_num_tokens > 0:
             # Prompt-lookup speculation: host drafts need the TRUE last
             # token, so spec mode collapses the dispatch/readback
@@ -3732,6 +3893,13 @@ class EngineCore:
             self._abort_fused_capture()
             self._flush_pending_prefills()
             self._flush_pending_burst()
+        # The rows the prefills since the last burst fed (a burst that
+        # settled them above for a token's value takes none of them on the
+        # device), and of them those whose token the host has not read.
+        fed, self._first_on_device = self._first_on_device, []
+        plain = not (has_structured or cfg.speculative_num_tokens > 0)
+        unread = {id(e["seq"]): e for e in self._pending_prefills
+                  if e["fed"]}
         B = cfg.max_num_seqs
         K = max(cfg.decode_steps, 1)
         # Prompts waiting AND admissible (free slot — a slot-blocked
@@ -3754,17 +3922,19 @@ class EngineCore:
         # block-table width instead of one per burst-width combination.
         # Bounds use all_token_ids which may lag the in-flight burst, so
         # this over-schedules at most one extra burst near the end caps.
-        def seq_allow(r: EngineRequest) -> int:
+        def seq_allow(r: EngineRequest, ahead: int) -> int:
             if r.structured is not None and r.structured.masking:
                 # The FSM mask is constant across the scan (the host
                 # advances the automaton only at burst boundaries):
                 # schedule one usable step — later steps would sample
                 # under a stale mask — and discard the rest at emission.
                 return 1
+            # ``ahead``: the first token, where the host has not read it
+            # yet and the lists below lack it.
             return max(1, min(
                 K,
-                r.sampling.max_tokens - len(r.output_token_ids),
-                cfg.max_model_len - len(r.all_token_ids) + 1,
+                r.sampling.max_tokens - len(r.output_token_ids) - ahead,
+                cfg.max_model_len - len(r.all_token_ids) - ahead + 1,
             ))
 
         prev = self._pending_burst
@@ -3792,7 +3962,7 @@ class EngineCore:
                     continue  # already preempted this pass
                 if seq.req.request_id in pending_first:
                     continue  # first token still in the fused capture
-                need = seq_allow(seq.req)
+                need = seq_allow(seq.req, id(seq) in unread)
                 allows[seq.req.request_id] = need
                 while need > 0:
                     ok = self.kv_mgr.append_token(
@@ -3809,10 +3979,19 @@ class EngineCore:
             active = [
                 s for s in self.scheduler.running() if id(s) in active0_ids
             ]
+            # Fed rows this burst takes on the device: those still in
+            # their slot. One that left before any burst (its first token
+            # ended it, an abort, a preemption) took the host's path.
+            on_device = {id(seq) for slot, seq in fed
+                         if plain and self.scheduler.slots[slot] is seq}
+        self.first_token_feed_total["device"] += len(on_device)
+        self.first_token_feed_total["host"] += len(fed) - len(on_device)
         self._drain_offload()  # spill pages evicted during block accounting
         if not active:
             self._flush_pending_burst()
+            self._flush_pending_prefills()
             return
+        self._steps.note(first_on_device_rows=len(on_device))
 
         # Bucket the block-table width (power of two over the widest live
         # sequence) so the gather in paged attention scales with real
@@ -3865,6 +4044,18 @@ class EngineCore:
                 # device.
                 use_host[i] = False
                 tok_idx[i] = prev_slots[id(seq)] - 1
+            elif id(seq) in on_device:
+                # Its first token, which its prefill wrote over the whole
+                # of row i of the feedback array: any tok_idx finds it.
+                use_host[i] = False
+                entry = unread.get(id(seq))
+                if entry is not None:
+                    # Not read back yet: the row is built as it would be
+                    # with the token emitted, and the flush behind this
+                    # dispatch leaves its bookkeeping alone.
+                    entry["in_burst"] = True
+                    r.scheduled_steps = len(r.output_token_ids) + 1
+                    reset_counts[i] = True
             else:
                 host_tokens[i] = r.all_token_ids[-1]
             base = len(r.prompt_token_ids) + r.scheduled_steps
@@ -3933,7 +4124,8 @@ class EngineCore:
             self.state_blocks_written_total += written
             self._steps.note_sum(state_blocks_written=written)
         outs = self._dispatch(
-            "decode", {"K": K, "use_prev": prev is not None}, [
+            "decode", {"K": K,
+                       "use_prev": prev is not None or bool(on_device)}, [
                 reset_counts, tok_idx, host_tokens, use_host, positions0,
                 slot_mat, block_table, context0, adapter_ids, temperature,
                 top_k, top_p, seed_base, presence, frequency,
@@ -3952,8 +4144,11 @@ class EngineCore:
                 sum(context0[s.slot] for s in active)),
             "kv_write_tokens": sched,
         }
-        # Read back the PREVIOUS burst (overlaps this burst's execution).
+        # Read back the PREVIOUS burst (overlaps this burst's execution),
+        # then the first tokens this burst took on the device: a stream's
+        # first token is delivered before any token of its burst.
         self._flush_pending_burst()
+        self._flush_pending_prefills()
         self._pending_burst = {
             "out": outs, "active": active, "allows": allows,
         }

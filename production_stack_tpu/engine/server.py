@@ -2684,6 +2684,14 @@ class EngineServer:
             *(f'tpu:latent_prefill_form_total{{{labels},form="{form}"}} '
               f"{s.get('latent_prefill_form_total', {}).get(form, 0)}"
               for form in ("absorbed", "up_projected")),
+            # Prefilled rows by where their first decode burst took their
+            # first token from: the device (handed over behind the
+            # prefill program, the burst enqueued while it still ran) or
+            # the host (read back first). Both label values always.
+            "# TYPE tpu:first_token_feed counter",
+            *(f'tpu:first_token_feed_total{{{labels},path="{path}"}} '
+              f"{s.get('first_token_feed_total', {}).get(path, 0)}"
+              for path in ("device", "host")),
             # Structured output (guided_json / guided_regex /
             # response_format): grammar constraints compiled to token FSMs
             # applied inside the fused programs.

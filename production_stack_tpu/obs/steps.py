@@ -46,7 +46,10 @@ tokens they carried: ``emit_tokens`` again, kept under its name for the
 readers that scale one by the other), and ``deliver_wake_s`` /
 ``deliver_drain_s``, which the server's loop writes later through
 ``amend`` when it runs the two markers a burst posts to it
-(``EngineCore._flush_pending_burst``).
+(``EngineCore._flush_pending_burst``). A ``decode_burst`` record also
+says how many of its rows took their first token on the device, the
+burst built while their prefill still ran (``first_on_device_rows``,
+noted once a burst by ``EngineCore._do_decode``).
 
 **The budget: per burst, never per token.** The recorder runs in every
 run: there is no "tracing off", so what it does is in the judged path.

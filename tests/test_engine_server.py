@@ -168,6 +168,8 @@ def test_metrics_exposition(server_url):
         assert 'path="pallas_one_tile"}' in text
         assert "tpu:moe_idle_layers_total{" in text
         assert 'path="xla"}' in text
+        assert 'tpu:first_token_feed_total{' in text
+        assert 'path="device"}' in text and 'path="host"}' in text
     asyncio.run(run())
 
 
